@@ -20,10 +20,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -35,9 +31,11 @@ from paddle_tpu.parallel import (DistStrategy, MeshConfig, MeshTrainer,
                                  ReduceStrategy, make_mesh)
 from paddle_tpu.parallel.distributed import init_distributed
 from paddle_tpu.parallel.sharding import fsdp_rules
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dp", type=int, default=0, help="0 = all devices")
     ap.add_argument("--fsdp", type=int, default=1)

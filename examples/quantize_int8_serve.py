@@ -19,10 +19,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -34,6 +30,7 @@ from paddle_tpu.ops import functional as F
 from paddle_tpu.optim.optimizer import Adam
 from paddle_tpu.quant.int8_compute import freeze_int8
 from paddle_tpu.testing import export_servable
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def batches(reader, bs):
@@ -46,6 +43,7 @@ def batches(reader, bs):
 
 
 def main():
+    enable_compile_cache()
     model = LeNet(num_classes=10)
     loss = supervised_loss(
         lambda lg, y: F.softmax_with_cross_entropy(

@@ -20,10 +20,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -31,6 +27,7 @@ from paddle_tpu.core.executor import Trainer
 from paddle_tpu.models.transformer import CausalLM
 from paddle_tpu.ops.fused_ce import linear_cross_entropy
 from paddle_tpu.optim.optimizer import Adam
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def sequence_batch(rs, batch, seq, vocab):
@@ -41,6 +38,7 @@ def sequence_batch(rs, batch, seq, vocab):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)

@@ -18,10 +18,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -30,6 +26,7 @@ from paddle_tpu.data.datafeed import write_slot_file
 from paddle_tpu.models.nlp import DeepFM
 from paddle_tpu.ops import functional as F
 from paddle_tpu.optim.optimizer import Adam
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 CONFIG = "label:int64:dense:1;dense:float:dense:13;ids:int64:sparse"
 FIELDS, VOCAB, DENSE = 26, 1000, 13
@@ -54,6 +51,7 @@ def synthesize(datadir: str, rows: int, n_files: int = 4) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--datadir", default="/tmp/ptpu_ctr")
     ap.add_argument("--rows", type=int, default=20000)

@@ -21,10 +21,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -34,6 +30,7 @@ from paddle_tpu.parallel import (DistStrategy, MeshConfig, MeshTrainer,
                                  pipelined_lm_loss)
 from paddle_tpu.parallel.moe import (init_moe_params, load_balancing_loss,
                                      moe_ffn_a2a)
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def sequence_batch(rs, batch, seq, vocab):
@@ -44,6 +41,7 @@ def sequence_batch(rs, batch, seq, vocab):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--pp", type=int, default=0,
                     help="pipeline stages (0 = largest divisor of the "

@@ -56,6 +56,8 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from paddle_tpu.benchmark.models import MODELS, run_model
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.infer and args.scaling:
         p.error("--infer and --scaling are mutually exclusive")

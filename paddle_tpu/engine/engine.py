@@ -110,6 +110,10 @@ def serve_metadata(model) -> dict:
         "max_len": model.max_len,
         "tie_embeddings": model.tie_embeddings,
         "fused_qkv": attn.fused_qkv,
+        # compute dtype: the rebuilt model's activations AND the KV
+        # pool's element type (a bf16 export must not come back float32
+        # with a pool twice the size)
+        "dtype": jnp.dtype(model.dtype).name,
     }
 
 
@@ -228,6 +232,11 @@ class ServeEngine:
             self._tp_rules = serve_tp_rules()
             variables = shard_variables(self._mesh, variables,
                                         self._tp_rules)
+        else:
+            # weights live on the device: from_saved_model hands over
+            # the checkpoint's host arrays, and a host operand would be
+            # uploaded again by every call of the compiled step
+            variables = jax.device_put(variables)
         self.variables = variables
         self.max_seq_len = min(max_seq_len or model.max_len, model.max_len)
         self.max_batch_size = max_batch_size
@@ -469,7 +478,9 @@ class ServeEngine:
             ffn_dim=meta["ffn_dim"], dropout=0.0, max_len=meta["max_len"],
             tie_embeddings=meta["tie_embeddings"],
             fused_qkv=meta["fused_qkv"],
-            num_kv_heads=meta["num_kv_heads"])
+            num_kv_heads=meta["num_kv_heads"],
+            # exports older than the field were all float32
+            dtype=jnp.dtype(meta.get("dtype", "float32")))
         variables = load_checkpoint(os.path.join(model_dir, "params"))
         engine_kwargs.setdefault("max_seq_len", meta["max_len"])
         return cls(model, variables, **engine_kwargs)

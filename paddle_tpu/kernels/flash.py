@@ -49,12 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only resolves on TPU builds; interpret mode needs no TPU.
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128     # f32 lane width: m/l/lse scratch is lane-broadcast
@@ -102,25 +97,15 @@ def _default_blocks(t_q: int, t_k: int):
 
 
 def _scratch(shape):
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError(
-            "Pallas TPU support unavailable in this jax build; force the "
-            "XLA reference path with FLAGS_flash_attention=0")
-    return _VMEM(shape, jnp.float32)
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _compiler_params(*semantics):
-    if pltpu is None:  # pragma: no cover
-        return None
-    # jax <= 0.4.x spells it TPUCompilerParams; newer jax CompilerParams
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _smem_spec():
     """Whole-array scalar input (the dropout seed) in SMEM."""
-    if pltpu is None:  # pragma: no cover
-        return pl.BlockSpec((1, 1), lambda b, i, j: (0, 0))
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 

@@ -40,13 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports without TPU hardware; interpret mode needs no TPU.
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.kernels.attention import reference_attention
 
@@ -163,11 +157,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, context_lens,
 
 
 def _scratch(shape):
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError(
-            "Pallas TPU support unavailable in this jax build; use "
-            "paged_attention_reference (use_kernel=False)")
-    return _VMEM(shape, jnp.float32)
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
@@ -254,17 +244,12 @@ def _paged_kernel_call(q, k_pool, v_pool, block_tables, context_lens, scale,
     )
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
                                groups=h // hkv)
-    compiler_params = None
-    if pltpu is not None:
-        # jax <= 0.4.x spells it TPUCompilerParams; newer jax CompilerParams
-        cls = (getattr(pltpu, "CompilerParams", None)
-               or pltpu.TPUCompilerParams)
-        compiler_params = cls(dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       q, k_pool, v_pool)
@@ -384,6 +369,29 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
                                scale=scale)[:, 0].astype(q.dtype)
 
 
+def _heads_to_kv_major(x, hkv: int, groups: int):
+    """[TQ, H, X] -> [Hkv, TQ*G, X]: the batched-over-kv-heads operand
+    layout of the two matmuls below. With one query head per kv head
+    (MHA) it is a plain transpose; going through the grouped 4-D shape
+    there would insert a unit second-minor dimension, a shape cast the
+    TPU compiler cannot lay out for packed (bf16) operands."""
+    if groups == 1:
+        return jnp.transpose(x, (1, 0, 2))
+    tq, _, last = x.shape
+    return x.reshape(tq, hkv, groups, last).transpose(1, 0, 2, 3) \
+            .reshape(hkv, tq * groups, last)
+
+
+def _kv_major_to_rows(x, tq: int, groups: int):
+    """Inverse of _heads_to_kv_major, flattened: [Hkv, TQ*G, X] ->
+    [TQ*H, X] (the scratch row order)."""
+    hkv, _, last = x.shape
+    if groups == 1:
+        return jnp.transpose(x, (1, 0, 2)).reshape(tq * hkv, last)
+    return x.reshape(hkv, tq, groups, last).transpose(1, 0, 2, 3) \
+            .reshape(tq * hkv * groups, last)
+
+
 def _ragged_tile_update(q, k, v, q0, ctx, j, m_scr, l_scr, acc_scr, *,
                         scale: float, block_size: int, groups: int):
     """Online-softmax update for one (query-tile, kv-block) cell —
@@ -392,14 +400,12 @@ def _ragged_tile_update(q, k, v, q0, ctx, j, m_scr, l_scr, acc_scr, *,
     tq, h, d = q.shape
     hkv = k.shape[1]
     # batch over kv heads: [Hkv, TQ*G, D] x [Hkv, BS, D]
-    qg = q.reshape(tq, hkv, groups, d).transpose(1, 0, 2, 3) \
-          .reshape(hkv, tq * groups, d)
+    qg = _heads_to_kv_major(q, hkv, groups)
     kt = jnp.transpose(k, (1, 0, 2))                # [Hkv, BS, D]
     s = jax.lax.dot_general(
         qg, kt, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale  # [Hkv, TQ*G, BS]
-    s = s.reshape(hkv, tq, groups, block_size).transpose(1, 0, 2, 3) \
-         .reshape(tq * h, block_size)
+    s = _kv_major_to_rows(s, tq, groups)            # [TQ*H, BS]
     qpos = q0 + jax.lax.broadcasted_iota(
         jnp.int32, (tq, h, block_size), 0).reshape(tq * h, block_size)
     kpos = j * block_size + jax.lax.broadcasted_iota(
@@ -412,15 +418,12 @@ def _ragged_tile_update(q, k, v, q0, ctx, j, m_scr, l_scr, acc_scr, *,
     p = jnp.exp(s - m_new)                          # [TQ*H, BS]
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    pg = p.reshape(tq, hkv, groups, block_size).transpose(1, 0, 2, 3) \
-          .reshape(hkv, tq * groups, block_size)
+    pg = _heads_to_kv_major(p.reshape(tq, h, block_size), hkv, groups)
     vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, BS, D]
     pv = jax.lax.dot_general(
         pg.astype(v.dtype), vt, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)         # [Hkv, TQ*G, D]
-    pv = pv.reshape(hkv, tq, groups, d).transpose(1, 0, 2, 3) \
-           .reshape(tq * h, d)
-    acc_scr[...] = alpha * acc_scr[...] + pv
+    acc_scr[...] = alpha * acc_scr[...] + _kv_major_to_rows(pv, tq, groups)
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -589,16 +592,12 @@ def _ragged_kernel_call(q, k_pool, v_pool, block_tables, context_lens,
     )
     kernel = functools.partial(kernel_fn, scale=scale, block_size=bs,
                                tile_q=tq, groups=h // hkv)
-    compiler_params = None
-    if pltpu is not None:
-        cls = (getattr(pltpu, "CompilerParams", None)
-               or pltpu.TPUCompilerParams)
-        compiler_params = cls(dimension_semantics=("parallel", "arbitrary"))
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )
     scalars = (block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
@@ -674,7 +673,7 @@ def ragged_paged_attention_tp(mesh, q, k_pool, v_pool, block_tables,
     per-block scales are head-independent scalars, replicated."""
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     if kq_pool is None:
         def body(q_, kp, vp, bt, cl, qs, tr, to):
@@ -719,7 +718,7 @@ def paged_prefill_attention_tp(mesh, q, k_pool, v_pool, block_tables,
     H."""
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     def body(q_, kp, vp, bt, cl, qp):
         return paged_prefill_attention(q_, kp, vp, bt, cl, qp, scale=scale)

@@ -5,7 +5,7 @@ they execute on a real TPU is the benchmark. A wrong-but-fast kernel
 would ship silently, so the bench calls `flash_selfcheck()` on the real
 device: it runs the flash path and the XLA reference path on the same
 batch — forward AND backward — asserts the flash branch was actually
-taken, and compares numerics (VERDICT r2 weak #2 / next-step #2).
+taken, and compares numerics.
 """
 
 from __future__ import annotations

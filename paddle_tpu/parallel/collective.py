@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 AxisName = Union[str, Tuple[str, ...]]
 

@@ -112,7 +112,7 @@ class ShardedEmbedding(Module):
         return out
 
     def _shard_map_lookup(self, table, ids):
-        from paddle_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         mesh, axis = self.mesh, self.axis
         batch_axes = tuple(a for a in self.batch_axes if a in mesh.shape
@@ -159,8 +159,7 @@ def shard_table(mesh: Mesh, table: jax.Array, axis: str = "fsdp"):
 # The padded table ([num_embeddings, padded_vocab) rows) is saved in
 # checkpoints; if num_embeddings or the shard axis size changes between save
 # and load, the same on-disk shape can hold differently-aligned rows. These
-# helpers stamp/verify the logical geometry in the checkpoint manifest
-# (VERDICT r2 weak #7).
+# helpers stamp/verify the logical geometry in the checkpoint manifest.
 
 def checkpoint_meta(*embeddings: "ShardedEmbedding") -> dict:
     """Metadata dict for io.checkpoint.save_checkpoint(metadata=...)."""

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.parallel.compat import shard_map
+from jax import shard_map
 from paddle_tpu.core.module import Context, Module, PARAMS
 
 Pytree = Any
@@ -104,6 +104,22 @@ def _strided(xs: jax.Array, s: int) -> Tuple[jax.Array, int]:
     return xs.reshape((mp // s, s) + xs.shape[1:]), m
 
 
+def _deliver(masked: jax.Array, axis: str) -> jax.Array:
+    """psum that hands the owner's microbatch (everyone else passes
+    zeros) to all stages, with an optimization barrier in front of it.
+
+    The barrier is for the BACKWARD pass, where it lands between the
+    transposed psum and the per-device owner mask that follows it. The
+    TPU compiler otherwise hoists that psum out of the tick loop
+    (while-loop all-reduce code motion), across the mask, which depends
+    on the device: every device then accumulates stage 0's cotangents
+    for ITS OWN ticks only and the sum after the loop hands all of them
+    the same buffer — input gradients wrong for every microbatch stage 0
+    does not own, with the loss exact. Seen on four v5e chips (PR 21);
+    the CPU backend does not run that pass."""
+    return lax.psum(lax.optimization_barrier(masked), axis)
+
+
 def pipeline_apply(stage_fn: Callable[[Pytree, jax.Array], jax.Array],
                    stacked_params: Pytree, microbatches: jax.Array,
                    mesh: Mesh, axis: str = "pp"):
@@ -137,7 +153,7 @@ def pipeline_apply(stage_fn: Callable[[Pytree, jax.Array], jax.Array],
             # the owner (t mod S) of microbatch t injects it; one
             # activation-sized psum delivers it to stage 0
             cand = xs_l[jnp.minimum(t, m - 1) // s]
-            x_in = lax.psum(
+            x_in = _deliver(
                 jnp.where((stage == t % s) & (t < m), cand, zero), axis)
             x_t = jnp.where(stage == 0, x_in, buf)
             y, _ = _chain_stages(stage_fn, params, x_t)
@@ -214,7 +230,7 @@ def pipeline_stream(stage_fn: Callable[[Pytree, jax.Array], jax.Array],
             def tick(carry, t):
                 buf, acc, sacc = carry
                 cand = xs_l[jnp.minimum(t, m - 1) // s]
-                x_in = lax.psum(
+                x_in = _deliver(
                     jnp.where((stage == t % s) & (t < m), cand, zero), axis)
                 x_t = jnp.where(stage == 0, x_in, buf)
                 # this device's v virtual stages, chained; their summed
@@ -228,7 +244,7 @@ def pipeline_stream(stage_fn: Callable[[Pytree, jax.Array], jax.Array],
                 j = t - (s - 1)
                 jc = jnp.clip(j, 0, m - 1)
                 t_cand = ys_l[jc // s]
-                tgt = lax.psum(
+                tgt = _deliver(
                     jnp.where((stage == jc % s) & (j >= 0), t_cand,
                               jnp.zeros_like(t_cand)), axis)
                 li = consume_fn(aux, y, tgt)
@@ -413,7 +429,7 @@ def pipeline_stream_1f1b(stage_fn: Callable,
                 j = t - (s - 1)
                 jc = jnp.clip(j, 0, m - 1)
                 t_cand = ys_l[jc // s]
-                tgt = lax.psum(
+                tgt = _deliver(
                     jnp.where((stage == jc % s) & (j >= 0), t_cand,
                               jnp.zeros_like(t_cand)), axis)
                 # unlike the gpipe scan, this one runs s-1 extra drain

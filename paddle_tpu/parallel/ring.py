@@ -23,7 +23,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 

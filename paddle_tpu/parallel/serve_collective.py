@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 #: blockwise-quantization granularity: one fp32 scale per CHUNK scalars.
 #: 256 keeps the scale overhead at 1/64 of the fp payload while staying

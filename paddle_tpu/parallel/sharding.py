@@ -37,7 +37,7 @@ class ShardingRules:
     is set — to ZeRO-style sharding of the largest dim of any parameter
     with prod(shape) >= fsdp_min_size and rank >= fsdp_min_rank. The
     fallback is a constructor feature so rule tables compose (an earlier
-    design patched spec_for per instance; VERDICT r2 weak #4).
+    design patched spec_for per instance).
     """
 
     def __init__(self, rules: Sequence[Tuple[str, Sequence]] = (),

@@ -248,8 +248,8 @@ class MeshTrainer:
             (loss, aux), _ = loss_fn(module, variables, batch, None, False)
             return {"loss": loss, **aux}
         # in_shardings pins the state to its training sharding so an
-        # fsdp-sharded TrainState is NOT silently gathered for eval
-        # (VERDICT r2 weak #5); fetches are replicated scalars.
+        # fsdp-sharded TrainState is NOT silently gathered for eval;
+        # fetches are replicated scalars.
         return jax.jit(step_fn,
                        in_shardings=(self._state_shardings, None))
 

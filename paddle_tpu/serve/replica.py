@@ -237,6 +237,8 @@ def build_frontend(a: argparse.Namespace):
 def main(argv: Optional[List[str]] = None) -> int:
     a = build_parser().parse_args(argv)
     _ensure_device_visibility(a.tp_size)
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     frontend = build_frontend(a)
     frontend.start().install_signals()
     code = frontend.wait()      # blocks until a drain completes

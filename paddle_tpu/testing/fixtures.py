@@ -43,10 +43,12 @@ def export_servable(path: str, model, variables,
 def export_causal_lm(path: str, vocab: int = 61, model_dim: int = 16,
                      num_heads: int = 2, num_layers: int = 2,
                      ffn_dim: int = 32, max_len: int = 64,
-                     num_kv_heads: Optional[int] = None, seed: int = 0):
-    """Tiny servable CausalLM for engine tests/benches: init with a
-    fixed seed, export with the manifest `serve` block, return
-    (path, model, variables)."""
+                     num_kv_heads: Optional[int] = None, seed: int = 0,
+                     dtype=None):
+    """Servable CausalLM for engine tests/benches and chip_smoke.py
+    (tiny by default): init with a fixed seed, export with the manifest
+    `serve` block, return (path, model, variables). dtype is the
+    compute dtype (None: the model's float32 default)."""
     import jax
     import jax.numpy as jnp
 
@@ -56,7 +58,8 @@ def export_causal_lm(path: str, vocab: int = 61, model_dim: int = 16,
 
     model = CausalLM(vocab=vocab, model_dim=model_dim, num_heads=num_heads,
                      num_layers=num_layers, ffn_dim=ffn_dim, dropout=0.0,
-                     max_len=max_len, num_kv_heads=num_kv_heads)
+                     max_len=max_len, num_kv_heads=num_kv_heads,
+                     dtype=dtype or jnp.float32)
     variables = model.init(jax.random.PRNGKey(seed),
                            jnp.zeros((1, 4), jnp.int32))
     save_inference_model(  # export the forward; engine rebuilds from serve

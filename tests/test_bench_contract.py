@@ -1,6 +1,6 @@
-"""Bench driver contract (BENCH_r05 audit, ISSUE 13 satellite).
+"""Bench driver contract (ISSUE 13 satellite).
 
-The r5 artifact recorded rc=124 with parsed:null: the driver killed
+A driver run once recorded rc=124 with nothing parsed: it killed
 bench.py before its first flushed JSON line, because that line only
 printed after backend init plus the full resnet50 build/compile.
 The contract under test: `python bench.py` must flush a parseable

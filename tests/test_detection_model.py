@@ -1,4 +1,4 @@
-"""End-to-end SSD-lite detection (VERDICT r3 #7): matching, loss descent
+"""End-to-end SSD-lite detection: matching, loss descent
 on the voc2012 reader, and above-chance mAP via DetectionMAP
 (reference layers/detection.py ssd_loss / detection_output +
 metrics.py:566)."""

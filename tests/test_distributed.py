@@ -136,7 +136,7 @@ def _env(extra=None):
 
 
 def test_fault_injection_and_elastic_restart(tmp_path):
-    """SURVEY §5.3 / VERDICT r3 #4: kill one proc mid-run; survivors fail
+    """SURVEY §5.3: kill one proc mid-run; survivors fail
     fast with a clear peer-death report; a restart resumes from the last
     committed checkpoint and reproduces the uninterrupted loss curve."""
     ckpt = str(tmp_path / "elastic")
@@ -249,7 +249,7 @@ def test_two_process_async_checkpoint(tmp_path):
 
 
 def test_two_process_sharded_embedding_deepfm():
-    """VERDICT r3 #8: DeepFM + ShardedEmbedding through the launcher
+    """DeepFM + ShardedEmbedding through the launcher
     (2 procs x 2 devices) matches the single-process run, with the table
     row-sharded across process boundaries (pserver capability e2e)."""
     outs = []

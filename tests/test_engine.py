@@ -408,6 +408,29 @@ def test_from_saved_model_roundtrip(model_and_vars, tmp_path):
     assert got == want
 
 
+def test_from_saved_model_keeps_compute_dtype(tmp_path):
+    """A bf16 export comes back bf16 — model and KV pool — and a
+    manifest written before the `dtype` field loads as float32."""
+    from paddle_tpu.testing import export_causal_lm
+    path, _, _ = export_causal_lm(str(tmp_path / "m"), dtype=jnp.bfloat16)
+    kw = dict(max_batch_size=2, block_size=4, num_blocks=32)
+    eng = ServeEngine.from_saved_model(path, **kw)
+    assert eng.model.dtype == jnp.bfloat16
+    assert eng.cache.pools[0][0].dtype == jnp.bfloat16
+    # the checkpoint's host arrays were placed on the device once
+    assert all(isinstance(x, jax.Array)
+               for x in jax.tree.leaves(eng.variables))
+    got = eng.generate([[3, 1, 4]], max_new_tokens=4)
+    assert len(got[0]) == 4
+    sig_path = tmp_path / "m" / "signature.json"
+    sig = json.loads(sig_path.read_text())
+    assert sig["serve"].pop("dtype") == "bfloat16"
+    sig_path.write_text(json.dumps(sig))
+    old = ServeEngine.from_saved_model(path, **kw)
+    assert old.model.dtype == jnp.float32
+    assert old.cache.pools[0][0].dtype == jnp.float32
+
+
 def test_old_manifest_without_serve_block(model_and_vars, tmp_path):
     """Pre-serve manifests stay loadable by the predictor, and the engine
     fails with a clear message instead of a KeyError."""

@@ -108,7 +108,7 @@ def test_moe_a2a_gradients_flow(params):
 
 def test_moe_routing_diversifies_under_training(params):
     """The aux loss must actively rebalance a collapsed router during
-    training, not just look fine at init (r3 VERDICT weak #4)."""
+    training, not just look fine at init."""
     from paddle_tpu.optim.optimizer import Adam
     rs = np.random.RandomState(7)
     # positive-mean tokens: the gate has no bias term, so a column-0
@@ -162,7 +162,7 @@ def test_moe_trains_router_and_experts(params):
 
 def test_moe_a2a_under_capacity_pressure(params):
     """The under-capacity regime the capacity contract exists for
-    (r4 VERDICT weak #5): with a skewed router at capacity_factor=1.0,
+    : with a skewed router at capacity_factor=1.0,
     tokens ARE dropped (reported via dropped_fraction), training still
     improves the loss, and the balancing loss drives the drop-rate down
     as the router spreads load."""

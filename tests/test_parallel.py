@@ -188,10 +188,6 @@ def _seq_loss(module, variables, batch, rng, training):
     return (loss, {}), mut.get("state", {})
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="legacy experimental.shard_map places the tp collectives "
-           "differently and misses single-device parity tolerance")
 def test_transformer_tp_matches_single_device():
     """Megatron-style TP (transformer_tp_rules) end-to-end: a dp×tp mesh
     train run must match single-device numerics AND actually shard the
@@ -239,7 +235,7 @@ def test_shard_variables_roundtrip():
 
 def test_sharding_rules_fsdp_fallback_composes():
     """fsdp fallback is a constructor feature (not an instance patch), so
-    rule tables compose and subclass/copy safely (VERDICT r2 weak #4)."""
+    rule tables compose and subclass/copy safely."""
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.parallel.sharding import (ShardingRules, fsdp_rules,
                                               transformer_tp_rules)
@@ -262,8 +258,7 @@ def test_sharding_rules_fsdp_fallback_composes():
 
 
 def test_eval_step_keeps_state_sharded():
-    """eval_step pins in_shardings so fsdp state is not gathered
-    (VERDICT r2 weak #5)."""
+    """eval_step pins in_shardings so fsdp state is not gathered."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -306,8 +301,7 @@ def test_eval_step_keeps_state_sharded():
 
 
 def test_sharded_embedding_checkpoint_guard(tmp_path):
-    """Geometry stamp catches num_embeddings drift on restore
-    (VERDICT r2 weak #7)."""
+    """Geometry stamp catches num_embeddings drift on restore."""
     import jax.numpy as jnp
     import pytest as _pytest
     from paddle_tpu.io.checkpoint import (read_metadata, save_checkpoint)

@@ -7,19 +7,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.parallel.compat import HAS_MODERN_SHARD_MAP
 from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.parallel.pipeline import (PipelinedLM, pipeline_apply,
                                           pipeline_loss_fn, pipeline_rules,
                                           pipelined_lm_loss,
                                           stack_stage_params)
-
-needs_modern_shard_map = pytest.mark.skipif(
-    not HAS_MODERN_SHARD_MAP,
-    reason="installed jax predates top-level jax.shard_map: this test "
-           "exercises varying-manual-axes transpose semantics or "
-           "lax.pcast, which legacy experimental.shard_map rejects "
-           "(_SpecError) or lacks (AttributeError)")
 
 S = 4
 
@@ -61,7 +53,6 @@ def test_pipeline_matches_sequential(mesh):
                                rtol=2e-5, atol=2e-5)
 
 
-@needs_modern_shard_map
 def test_pipeline_grads_flow_to_all_stages(mesh):
     rs = np.random.RandomState(1)
     d = 8
@@ -89,7 +80,6 @@ def test_pipeline_grads_flow_to_all_stages(mesh):
     assert float(loss) == pytest.approx(want, rel=1e-5)
 
 
-@needs_modern_shard_map
 def test_pipeline_grad_matches_sequential_grad(mesh):
     rs = np.random.RandomState(2)
     d = 8
@@ -135,7 +125,6 @@ def _lm_trainer(model, mesh, m=2 * S):
         rules=pipeline_rules())
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_trains_on_pp_dp(mesh):
     model, batch = _lm_and_batch()
     tr = _lm_trainer(model, mesh)
@@ -153,7 +142,6 @@ def test_pipelined_lm_trains_on_pp_dp(mesh):
     assert float(f["loss"]) < first, (first, float(f["loss"]))
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_loss_matches_dense_forward(mesh):
     """Pipelined streaming loss == dense forward CE on the same params."""
     from paddle_tpu.ops import functional as F
@@ -168,7 +156,6 @@ def test_pipelined_lm_loss_matches_dense_forward(mesh):
     assert float(f["loss"]) == pytest.approx(want, rel=2e-4, abs=2e-4)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_parity_vs_single_device(mesh):
     """pp×dp pipelined first-step loss == unsharded dense-forward loss
     computed by the plain single-device Trainer (same seed/params)."""
@@ -189,7 +176,6 @@ def test_pipelined_lm_parity_vs_single_device(mesh):
                                              rel=2e-4, abs=2e-4)
 
 
-@needs_modern_shard_map
 def test_pipeline_virtual_stages_deeper_than_axis(mesh):
     """A model DEEPER than the pp axis pipelines via virtual stages
     (v = S_total/S_mesh consecutive stages chained per device per tick):
@@ -206,7 +192,6 @@ def test_pipeline_virtual_stages_deeper_than_axis(mesh):
     assert float(f["loss"]) == pytest.approx(want, rel=2e-4, abs=2e-4)
 
 
-@needs_modern_shard_map
 def test_pipeline_single_device_runs_all_stages():
     """On a 1-device mesh every stage is a virtual stage — the pipelined
     loss must equal the dense forward (the old 1:1 restriction is gone)."""
@@ -240,7 +225,6 @@ def test_pipeline_rejects_non_divisible_stage_stack(mesh):
         jax.jit(loss)(bad, jnp.zeros((8, 4)), jnp.zeros((8, 4)))
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_checkpoint_roundtrip(mesh, tmp_path):
     """Save mid-training, restore onto the pp shardings, continue: the
     stitched run matches the uninterrupted one exactly."""
@@ -265,7 +249,6 @@ def test_pipelined_lm_checkpoint_roundtrip(mesh, tmp_path):
                                    rtol=1e-5, atol=1e-6)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_trains_with_remat(mesh):
     """strategy.remat composes with the pipeline scan: activations are
     recomputed in backward (O(1-tick) liveness at 2x forward FLOPs), the
@@ -287,7 +270,6 @@ def test_pipelined_lm_trains_with_remat(mesh):
     assert losses["plain"] == pytest.approx(losses["remat"], rel=1e-6)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_3d_pp_tp_dp():
     """3D parallelism: pp=2 × tp=2 × dp=2 — Megatron tensor parallelism
     INSIDE each pipeline stage, data parallelism across the batch. The
@@ -330,7 +312,6 @@ def test_pipelined_lm_3d_pp_tp_dp():
 
 # -- PipelinedMoELM: pp×ep×dp --------------------------------------------
 
-@needs_modern_shard_map
 def test_pipelined_moe_lm_trains_pp_ep_dp():
     """GShard-style MoE transformer through the pipeline: pp=2 × ep=2 ×
     dp=2. Expert stacks (and their Adam moments) shard over BOTH pp and
@@ -366,7 +347,6 @@ def test_pipelined_moe_lm_trains_pp_ep_dp():
     assert float(f["loss"]) < first, (first, float(f["loss"]))
 
 
-@needs_modern_shard_map
 def test_pipelined_moe_lm_ce_parity_vs_dense():
     """With lb_weight=0 and ample capacity, the pp×ep streamed CE equals
     the dense single-device forward CE on the same params exactly."""
@@ -403,7 +383,6 @@ def test_pipelined_moe_lm_ce_parity_vs_dense():
                                              rel=2e-4, abs=2e-4)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_sp_ring_attention():
     """Sequence parallelism inside the pipeline: pp=2 × sp=2 × dp=2 —
     stages run ring attention over sp on sequence shards. First-step
@@ -437,7 +416,6 @@ def test_pipelined_lm_sp_ring_attention():
                                    rtol=2e-3, atol=2e-3)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_4d_pp_tp_sp():
     """All structural axes at once: pp=2 × tp=2 × sp=2 — tensor-parallel
     weights AND ring attention over sequence shards inside pipeline
@@ -501,7 +479,6 @@ def test_pipeline_apply_virtual_stages(mesh):
                                rtol=2e-5, atol=2e-5)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_generate_and_export(mesh, tmp_path):
     """Train the (+1 mod V) stream on the pipeline, then (a) generate a
     continuation with the dense decode and check it follows the pattern,
@@ -547,7 +524,6 @@ def test_pipelined_lm_generate_and_export(mesh, tmp_path):
                                atol=2e-5)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_sp_ulysses():
     """Ulysses sequence parallelism inside the pipeline (all_to_all
     seq↔heads regroup): pp=2 × sp=2 × dp=2 first-step loss must match
@@ -578,7 +554,6 @@ def test_pipelined_lm_sp_ulysses():
                                              rel=2e-4, abs=2e-4)
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_ulysses_composes_with_tp():
     """Ulysses × tensor parallelism: pp=2 × tp=2 × sp=2 with 4 heads
     (2 per tp shard, sp=2 divides them — the all_to_all regroups LOCAL
@@ -628,7 +603,6 @@ def test_pipelined_lm_ulysses_composes_with_tp():
         bad.train_step(bts, bad.put_batch(batch))
 
 
-@needs_modern_shard_map
 def test_pipelined_lm_fused_ce_matches_plain(mesh):
     """fused_ce=True (chunked linear+CE, no [N,V] logits) must produce
     the same pipelined loss as the plain head@CE path on pp×dp."""
@@ -649,7 +623,6 @@ def test_pipelined_lm_fused_ce_matches_plain(mesh):
     assert losses[True] == pytest.approx(losses[False], rel=1e-5, abs=1e-5)
 
 
-@needs_modern_shard_map
 def test_pipelined_moe_lm_fused_ce_matches_plain():
     """Same parity bar for the MoE pipeline's streamed CE."""
     from paddle_tpu.parallel.mesh import MeshConfig
@@ -693,7 +666,6 @@ def _lm_trainer_1f1b(model, mesh, m=2 * S, tp_axis=None):
         rules=pipeline_rules(tp_axis=tp_axis))
 
 
-@needs_modern_shard_map
 def test_1f1b_loss_and_grads_match_gpipe_and_dense(mesh):
     """The 1F1B in-scan backward must produce the SAME loss and the SAME
     post-step parameters as both the GPipe schedule (jax.grad through
@@ -733,7 +705,6 @@ def test_1f1b_loss_and_grads_match_gpipe_and_dense(mesh):
                                    rtol=5e-3, atol=2e-3)
 
 
-@needs_modern_shard_map
 def test_1f1b_trains(mesh):
     model, batch = _lm_and_batch(seed=12)
     tr = _lm_trainer_1f1b(model, mesh)
@@ -747,7 +718,6 @@ def test_1f1b_trains(mesh):
     assert float(f["loss"]) < first, (first, float(f["loss"]))
 
 
-@needs_modern_shard_map
 def test_1f1b_composes_with_tp():
     """pp=2 × tp=2 × dp=2 under the 1F1B schedule: the in-tick jax.vjp
     transposes the stage's tp psums; post-step params match dense."""
@@ -775,7 +745,6 @@ def test_1f1b_composes_with_tp():
                                    rtol=5e-3, atol=2e-3)
 
 
-@needs_modern_shard_map
 def test_1f1b_virtual_stages_and_fused_ce(mesh):
     """8 stages on pp=4 (v=2 virtual stages per device) under 1F1B with
     the fused-CE consume: loss matches the gpipe schedule."""
@@ -810,7 +779,6 @@ def test_1f1b_rejects_sp():
         pipelined_lm_loss(mesh4, sp_axis="sp", schedule="1f1b")
 
 
-@needs_modern_shard_map
 def test_1f1b_activation_liveness_below_gpipe(mesh):
     """The reason 1F1B exists: per-device activation liveness O(S) vs
     GPipe-through-jax.grad's O(M). XLA's compiled memory analysis at
@@ -838,7 +806,6 @@ def test_1f1b_activation_liveness_below_gpipe(mesh):
     assert temp_bytes("1f1b") * 2 < temp_bytes("gpipe")
 
 
-@needs_modern_shard_map
 def test_1f1b_moe_matches_gpipe():
     """PipelinedMoELM under the 1F1B schedule (pp=2 x ep=2 x dp=2): the
     stage-aux (load-balance) cotangent and the in-stage ep psums ride

@@ -89,7 +89,7 @@ class TestServeCollective:
         """The quantized collective is psum within per-chunk int8
         quantization error: |err| <= tp * chunk_absmax / 127 per
         element (each shard rounds once)."""
-        from paddle_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
@@ -113,7 +113,7 @@ class TestServeCollective:
     def test_int8_allreduce_handles_ragged_and_zero_chunks(self):
         """Lengths not divisible by the chunk pad internally; an
         all-zero chunk must not divide by zero (scale floor)."""
-        from paddle_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])

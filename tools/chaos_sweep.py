@@ -45,7 +45,7 @@ import json
 import os
 import sys
 
-import _bootstrap  # noqa: F401  (repo path + cpu override)
+import _bootstrap  # noqa: F401  (repo path)
 
 import numpy as np
 

@@ -1,4 +1,4 @@
-"""Flash-kernel roofline at long sequence lengths (r4 VERDICT #3).
+"""Flash-kernel roofline at long sequence lengths.
 
 Measures the Pallas flash attention kernels IN ISOLATION — forward, and
 the two backward kernels via the custom-vjp — at the lm_longctx

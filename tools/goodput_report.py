@@ -18,7 +18,7 @@ import json
 import math
 import sys
 
-import _bootstrap  # noqa: F401  (repo path + cpu override)
+import _bootstrap  # noqa: F401  (repo path)
 
 
 def _is_histogram_entry(value) -> bool:

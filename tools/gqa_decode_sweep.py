@@ -16,7 +16,7 @@ moving exactly as designed (models/transformer.init_kv_caches).
 Run: python tools/gqa_decode_sweep.py
 """
 
-import _bootstrap  # noqa: F401  (repo path + JAX cpu-override workaround)
+import _bootstrap  # noqa: F401  (repo path)
 
 import jax
 import jax.numpy as jnp

@@ -26,7 +26,7 @@ import importlib
 import sys
 from collections import Counter
 
-import _bootstrap  # noqa: F401  (repo path + JAX cpu-override workaround)
+import _bootstrap  # noqa: F401  (repo path)
 
 # (ref_op, status, paddle_tpu symbol or rationale)
 TABLE = [

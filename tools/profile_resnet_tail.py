@@ -1,4 +1,4 @@
-"""ResNet-50 non-conv-tail attack kit (r3 VERDICT #6).
+"""ResNet-50 non-conv-tail attack kit.
 
 Round-3 device traces attributed ~8.1 ms of the 47.4 ms bs=128 train step
 to non-conv work: ~5.8 ms loop fusions + ~2.3 ms layout copies. This tool
@@ -18,7 +18,7 @@ Usage: python tools/profile_resnet_tail.py [--bs 128] [--min-time 2.5]
 
 import argparse
 
-import _bootstrap  # noqa: F401  (repo path + JAX cpu-override workaround)
+import _bootstrap  # noqa: F401  (repo path)
 import jax
 
 

@@ -1,4 +1,4 @@
-"""Seq2seq Transformer MFU attack kit (r3 VERDICT #3: 48.6% -> >=55%).
+"""Seq2seq Transformer MFU attack kit (48.6% -> >=55%).
 
 Run ON TPU. Sweeps structural variants of the Transformer-base train step
 and prints tokens/s + MFU per variant, then dumps the device-tier op
@@ -14,7 +14,7 @@ Usage: python tools/profile_transformer.py [--bs 64] [--seq 256]
 import argparse
 import sys
 
-import _bootstrap  # noqa: F401  (repo path + JAX cpu-override workaround)
+import _bootstrap  # noqa: F401  (repo path)
 import jax
 
 
@@ -41,7 +41,6 @@ def main():
     # raw_ce), so sweep fused_qkv x {plain, raw_ce, fused_ce}
     variants = [(f, r, c) for f in (False, True)
                 for r, c in ((False, False), (True, False), (False, True))]
-    from paddle_tpu.benchmark.harness import retry_transient as _retry
 
     results = {}
     for fused, raw, fce in variants:
@@ -49,10 +48,10 @@ def main():
                                          ("raw_ce", raw),
                                          ("fused_ce", fce)) if on) or "baseline"
         try:
-            r = _retry(lambda: run_model(
+            r = run_model(
                 "transformer", batch_size=args.bs, dtype=dtype,
                 min_time=args.min_time, seq_len=args.seq,
-                fused_qkv=fused, raw_ce=raw, fused_ce=fce))
+                fused_qkv=fused, raw_ce=raw, fused_ce=fce)
         except Exception as e:  # a dead variant shouldn't kill the sweep
             print(f"{label:24s} FAILED: {type(e).__name__}: {e}")
             continue

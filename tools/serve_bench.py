@@ -154,8 +154,8 @@ import threading
 import time
 
 # tp scenario: the CPU mesh needs >= 2 virtual devices, and XLA's
-# device-count flag only takes effect BEFORE jax initializes — which
-# `import _bootstrap` below does. Harmless for every other scenario
+# device-count flag only takes effect BEFORE jax initializes its
+# backends. Harmless for every other scenario
 # (tp=1 engines stay on device 0).
 if ("xla_force_host_platform_device_count"
         not in os.environ.get("XLA_FLAGS", "")):
@@ -163,7 +163,7 @@ if ("xla_force_host_platform_device_count"
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=2").strip()
 
-import _bootstrap  # noqa: F401  (repo path + cpu override)
+import _bootstrap  # noqa: F401  (repo path)
 
 import numpy as np
 

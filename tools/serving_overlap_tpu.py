@@ -1,4 +1,4 @@
-"""Serving clone-thread overlap ON THE REAL TPU (r4 VERDICT #8).
+"""Serving clone-thread overlap ON THE REAL TPU.
 
 The README's serving-concurrency number was measured on a tiny CPU MLP
 (1.09x — dispatch-bound); the claim that bigger models overlap more
