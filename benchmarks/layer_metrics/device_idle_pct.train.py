@@ -1,0 +1,3 @@
+"""Share of the traced slice in which no operation ran on the device."""
+
+from benchmarks.tracing import idle_pct as read  # noqa: F401
