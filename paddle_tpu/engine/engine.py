@@ -63,7 +63,6 @@ Two features ride that determinism with zero new compiled paths:
 from __future__ import annotations
 
 import functools
-import time
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -77,6 +76,7 @@ from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
 from paddle_tpu.obs.tracing import RequestTracer
+from paddle_tpu.profiler.profiler import annotate, now_us
 from paddle_tpu.quant.int8_compute import dequantize_block, quantize_block
 from paddle_tpu.utils.log import serve_event
 
@@ -377,6 +377,7 @@ class ServeEngine:
         self.scheduler.on_admit = self._on_admit
         self.finished: Dict[int, Request] = {}
         self.steps = 0
+        self._plan_us = 0.0     # `engine.plan`'s opening stamp (step())
         self.prefill_tokens_computed = 0
         self.peak_occupancy = 0.0
         self.max_chunk_tokens = 0       # largest prefill step actually run
@@ -573,12 +574,15 @@ class ServeEngine:
     def _on_admit(self, req: Request) -> None:
         """Scheduler hook: a request left the wait queue. Queue-wait is
         observed only on FIRST admission (a preemption re-admission is
-        a scheduling artifact, not arrival latency)."""
-        now = time.monotonic()
+        a scheduling artifact, not arrival latency). The scheduler
+        admits inside `engine.plan`, whose opening stamp is this
+        boundary's one clock reading."""
+        now = self._plan_us / 1e6
         if req.admit_time == 0.0:
             self._m_queue_wait.observe((now - req.enqueue_time) * 1e3)
         req.admit_time = now
-        self.tracer.on_admit(req.req_id)
+        self.tracer.on_admit(req.req_id, self._plan_us, self.steps + 1,
+                             req.cached_tokens)
         self._set_sched_gauges()
 
     def _set_sched_gauges(self) -> None:
@@ -602,8 +606,12 @@ class ServeEngine:
                     callback: Optional[Callable[[int], None]] = None,
                     deadline_ms: Optional[float] = None,
                     n: int = 1,
-                    fork_callback: Optional[Callable] = None) -> Request:
-        """Enqueue one completion. `n > 1` is parallel sampling: when
+                    fork_callback: Optional[Callable] = None,
+                    arrival_us: Optional[float] = None) -> Request:
+        """Enqueue one completion. `arrival_us` is the front door's
+        `now_us` stamp of the request's arrival: the tracer's `queued`
+        span starts there (queue-wait and TTFT histograms still start
+        here, at the enqueue). `n > 1` is parallel sampling: when
         this request's prefill finishes, the engine forks n - 1 sibling
         candidates off its prompt blocks (refcount bump, zero copies —
         PagedKVCache.fork_sequence), each sampling with seed + i, and
@@ -629,14 +637,15 @@ class ServeEngine:
                       temperature=temperature, top_k=top_k, seed=seed,
                       eos_id=eos_id, callback=callback,
                       n_candidates=n, fork_callback=fork_callback)
-        req.enqueue_time = time.monotonic()
+        ts_us = now_us()
+        req.enqueue_time = ts_us / 1e6
         if deadline_ms is not None:
             # absolute completion deadline: the scheduler preempts the
             # slackest request first, so a tight deadline shields KV
             # state under pool pressure
             req.deadline = req.enqueue_time + deadline_ms / 1e3
         self.scheduler.add(req)
-        self.tracer.on_enqueue(req.req_id)
+        self.tracer.on_enqueue(req.req_id, ts_us, arrival_us, len(prompt))
         self._set_sched_gauges()
         serve_event("serve_admit", req_id=req.req_id,
                     prompt_len=len(prompt),
@@ -652,13 +661,14 @@ class ServeEngine:
         serve loop (serve/frontend.py)."""
         if not self.scheduler.cancel(req):
             return False
-        req.finish_time = time.monotonic()
+        ts_us = now_us()
+        req.finish_time = ts_us / 1e6
         req.finish_reason = reason
         self.finished[req.req_id] = req
         self._m_reqs.labels(reason=reason).inc()
         self._set_sched_gauges()
         self._m_occ.set(self.cache.occupancy())
-        self.tracer.on_finish(req.req_id, reason)
+        self.tracer.on_finish(req.req_id, reason, ts_us)
         serve_event("serve_cancel", req_id=req.req_id, reason=reason,
                     tokens=req.num_generated,
                     occupancy=round(self.cache.occupancy(), 4))
@@ -678,31 +688,78 @@ class ServeEngine:
     # -- serve loop --------------------------------------------------------
     def step(self) -> bool:
         """Advance one scheduler plan (one mixed batch through the
-        single compiled step). Returns False when idle."""
-        t0 = time.perf_counter()
-        rows = self.scheduler.next_batch()
-        if rows is None:
-            return False
-        self.steps += 1
-        # publish the coldness clock, then sweep: blocks the plan just
-        # admitted are hot (touched at step_now), so only genuinely
-        # idle prefix content stages quantize lanes for this step's
-        # _flush_compress
-        self.cache.step_now = self.steps
-        if self.cache.compress_enabled:
-            self.cache.compress_cold(_COMPRESS_IDLE_STEPS)
-        n_chunks, n_decodes, chunk_tokens, n_drafted = \
-            self._step_mixed(rows)
+        single compiled step). Returns False when idle. `engine.step`
+        and its seven children (OBSERVABILITY.md "Host spans") bracket
+        every phase; an idle call leaves no span."""
+        step = self.steps + 1
+        with annotate("engine.step", step=step) as span:
+            with annotate("engine.plan", step=step) as plan:
+                # admissions and preemptions inside the plan are
+                # stamped with its opening reading (_on_admit)
+                self._plan_us = plan.ts
+                rows = self.scheduler.next_batch()
+                if rows is None:
+                    plan.discard()
+                    span.discard()
+                    return False
+                self.steps = step
+                # publish the coldness clock, then sweep: blocks the
+                # plan just admitted are hot (touched at step_now), so
+                # only genuinely idle prefix content stages quantize
+                # lanes for this step's _flush_compress
+                self.cache.step_now = step
+                if self.cache.compress_enabled:
+                    self.cache.compress_cold(_COMPRESS_IDLE_STEPS)
+            chunks, decodes, chunk_tokens, drafted, accepted = \
+                self._step_mixed(rows)
+            with annotate("engine.publish", step=step):
+                self._publish(chunks, decodes, chunk_tokens, drafted,
+                              accepted)
+            span.set(decode_rows=len(decodes), chunk_rows=len(chunks),
+                     chunk_tokens=chunk_tokens,
+                     queue_depth=self.scheduler.queue_depth,
+                     used_blocks=self.cache.used_blocks)
+        # "spec" wins over mixed/decode so the speculation-on latency
+        # distribution is separable from plain decode's
+        kind = ("spec" if drafted
+                else "mixed" if chunks and decodes
+                else "prefill" if chunks else "decode")
+        self._m_step.labels(kind=kind).observe(span.dur / 1e3)
+        return True
+
+    def _publish(self, chunks: List[StepRow], decodes: List[StepRow],
+                 computed: int, drafted: int, accepted: int) -> None:
+        """Per-step telemetry: the step's `serve_event` lines and
+        host-side counter and gauge writes."""
+        if chunks:
+            # per-event field: a request's prefix-hit tokens are
+            # attributed to the step its FIRST chunk runs
+            # (start == cached_tokens) and 0 on later chunks, so summing
+            # `cached` over a drain equals hit_tokens; cumulative rates
+            # ride `hit_rate`/stats()
+            cached = sum(w.req.cached_tokens for w in chunks
+                         if w.start == w.req.cached_tokens)
+            self.prefill_tokens_computed += computed
+            self.max_chunk_tokens = max(self.max_chunk_tokens, computed)
+            self._m_tokens.labels(kind="prefill").inc(computed)
+            if cached:
+                self._m_tokens.labels(kind="cached").inc(cached)
+            serve_event("serve_prefill", batch=len(chunks),
+                        flat_t=self.flat_tokens, tokens=computed,
+                        cached=cached,
+                        step=self.steps, cow=self.cache.cow_copies,
+                        shared_blocks=self.cache.shared_blocks,
+                        hit_rate=round(self.cache.hit_rate(), 4),
+                        occupancy=round(self.cache.occupancy(), 4),
+                        queue_depth=self.scheduler.queue_depth)
+        if decodes:
+            serve_event("serve_decode", batch=len(decodes),
+                        step=self.steps, drafted=drafted,
+                        accepted=accepted,
+                        occupancy=round(self.cache.occupancy(), 4),
+                        queue_depth=self.scheduler.queue_depth)
         self.peak_occupancy = max(self.peak_occupancy,
                                   self.cache.occupancy())
-        # per-step telemetry: host-side gauge/histogram writes only
-        # ("spec" wins over mixed/decode so the speculation-on latency
-        # distribution is separable from plain decode's)
-        kind = ("spec" if n_drafted
-                else "mixed" if n_chunks and n_decodes
-                else "prefill" if n_chunks else "decode")
-        self._m_step.labels(kind=kind).observe(
-            (time.perf_counter() - t0) * 1e3)
         self._m_steps.inc()
         self._m_compiles.set(self._step_fn._cache_size())
         self._m_occ.set(self.cache.occupancy())
@@ -712,12 +769,11 @@ class ServeEngine:
         self._m_pool_eff.set(float(self.cache.effective_pool_bytes()))
         self._m_queue_depth.set(self.scheduler.queue_depth)
         self._m_running.set(len(self.scheduler.running))
-        self._m_decode_rows.set(n_decodes)
-        self._m_prefill_rows.set(n_chunks)
-        if n_chunks:
+        self._m_decode_rows.set(len(decodes))
+        self._m_prefill_rows.set(len(chunks))
+        if chunks:
             self._m_budget_util.set(
-                chunk_tokens / self.scheduler.max_prefill_tokens)
-        return True
+                computed / self.scheduler.max_prefill_tokens)
 
     def run(self) -> Dict[int, List[int]]:
         """Drain the queue; returns {req_id: generated token ids}."""
@@ -727,7 +783,7 @@ class ServeEngine:
                 for rid, r in self.finished.items()}
 
     # -- internals ---------------------------------------------------------
-    def _flush_cow(self) -> None:
+    def _flush_cow(self) -> int:
         """Replay queued copy-on-write block copies on the device pools
         BEFORE the step that writes the fresh blocks, through one
         fixed-shape compiled call per _COPY_LANES batch."""
@@ -740,8 +796,9 @@ class ServeEngine:
                 src[j], dst[j] = s, d
             self.cache.pools = self._copy_blocks(
                 self.cache.pools, jnp.asarray(src), jnp.asarray(dst))
+        return len(copies)
 
-    def _flush_tier_loads(self) -> None:
+    def _flush_tier_loads(self) -> int:
         """Write staged host-tier revivals into the device pools —
         BEFORE _flush_cow (a just-revived block can be a same-plan COW
         src) and before the step reads them. Eager functional
@@ -767,8 +824,9 @@ class ServeEngine:
                 self.cache.pools[li] = (
                     kp.at[blocks].set(jnp.asarray(kd, kp.dtype)),
                     vp.at[blocks].set(jnp.asarray(vd, vp.dtype)))
+        return len(loads)
 
-    def _flush_compress(self) -> None:
+    def _flush_compress(self) -> int:
         """Quantize staged cold fp blocks into the int8 pool — FIRST
         among the pre-step flushes, so the quantize lanes read every
         src block's content before promotions, host loads, or COW
@@ -793,8 +851,9 @@ class ServeEngine:
                                          vq.at[bdst].set(vq8))
                 self.cache.qscales[li] = (ks.at[bdst].set(ksc),
                                           vs.at[bdst].set(vsc))
+        return len(jobs)
 
-    def _flush_promote(self) -> None:
+    def _flush_promote(self) -> int:
         """Dequantize staged compressed-tier hits into their claimed fp
         blocks — after _flush_compress (a promotion may read a slot the
         same plan just filled) and BEFORE host loads, COW copies, and
@@ -815,6 +874,7 @@ class ServeEngine:
                 vfp = dequantize_block(vq[bsrc], vs[bsrc], vp.dtype)
                 self.cache.pools[li] = (kp.at[bdst].set(kfp),
                                         vp.at[bdst].set(vfp))
+        return len(jobs)
 
     @property
     def kv_direct_int8(self) -> bool:
@@ -892,7 +952,7 @@ class ServeEngine:
         return out
 
     def _step_mixed(self, rows: List[StepRow]
-                    ) -> "tuple[int, int, int, int]":
+                    ) -> "tuple[list, list, int, int, int]":
         """Pack the plan's rows — decode rows AND prefill chunks — into
         the flat ragged layout and run ONE compiled step. Row i's token
         window [start, start+length) lands in a tile_q-aligned segment
@@ -911,150 +971,139 @@ class ServeEngine:
         speculative rows gather one hidden state per window position
         for verification; every other row repeats its single real
         index across the columns."""
-        self._flush_compress()
-        self._flush_promote()
-        self._flush_tier_loads()
-        self._flush_cow()
-        t_flat, tq, nt = self.flat_tokens, self.tile_q, self.num_tiles
-        b = self.max_batch_size
-        mb = self.max_blocks_per_seq
-        tokens = np.zeros((t_flat,), np.int32)
-        positions = np.zeros((t_flat,), np.int32)
-        # pad positions scatter into scratch block 0 (slot < bs)
-        slots = np.zeros((t_flat,), np.int32)
-        block_tables = np.zeros((b + 1, mb), np.int32)
-        context_lens = np.ones((b + 1,), np.int32)   # null/pad rows: scratch
-        q_starts = np.zeros((b + 1,), np.int32)
-        tile_rows = np.full((nt,), b, np.int32)      # pad tiles -> null row
-        tile_offs = np.zeros((nt,), np.int32)
-        last_idx = np.zeros((b, self.spec_len), np.int32)
-        cursor = 0
-        for i, row in enumerate(rows):
-            r = row.req
-            toks = r.tokens
-            if row.draft:
-                # draft tokens live only in the plan, not in req.tokens
-                window = [toks[row.start]] + row.draft
-            else:
-                window = toks[row.start:row.start + row.length]
-            tokens[cursor:cursor + row.length] = window
-            positions[cursor:cursor + row.length] = np.arange(
-                row.start, row.start + row.length, dtype=np.int32)
-            for p in range(row.length):
-                slots[cursor + p] = self.cache.slot_of(r.req_id,
-                                                       row.start + p)
-            block_tables[i] = self.cache.padded_table(r.req_id, mb)
-            context_lens[i] = row.start + row.length
-            q_starts[i] = row.start
-            if row.decode:
-                # verification gathers per-position logits (plain
-                # decode rows have length 1: every column clamps to
-                # the one real index)
-                for j in range(self.spec_len):
-                    last_idx[i, j] = cursor + min(j, row.length - 1)
-            else:
-                last_idx[i, :] = cursor + row.length - 1
-            ntiles = -(-row.length // tq)
-            t0 = cursor // tq
-            for k in range(ntiles):
-                tile_rows[t0 + k] = i
-                tile_offs[t0 + k] = k * tq
-            cursor += ntiles * tq
-        logits, self.cache.pools = self._step_fn(
-            self.variables, jnp.asarray(tokens), jnp.asarray(positions),
-            self.cache.pools, self.cache.qpools, self.cache.qscales,
-            jnp.asarray(block_tables), jnp.asarray(context_lens),
-            jnp.asarray(q_starts), jnp.asarray(tile_rows),
-            jnp.asarray(tile_offs), jnp.asarray(slots),
-            jnp.asarray(last_idx))
-        logits = np.asarray(logits)
-        chunks = [w for w in rows if not w.decode]
-        decodes = [w for w in rows if w.decode]
-        computed = sum(w.length for w in chunks)
-        now = time.monotonic()
-        drafted = accepted = 0
-        for i, row in enumerate(rows):
-            r = row.req
-            if row.decode:
-                # the step wrote r.generated[-1]'s k/v at the reserved
-                # slot
-                self.cache.advance(r.req_id, r.generated[-1])
-                row_accepted = 0
-                for j in range(len(row.draft) + 1):
-                    # logits[i, j] scored window position start+j, i.e.
-                    # it predicts the token at cache seq_len (which the
-                    # advances below keep in lockstep with j)
-                    tok, lp = _sample(logits[i, j], r,
-                                      self.cache.seq_len(r.req_id))
-                    r.logprob_sum += lp
-                    self._emit_token(r, tok)
-                    if r.finish_reason or j >= len(row.draft):
-                        break
-                    if row.draft[j] != tok:
-                        # first rejection: everything past seq_len is
-                        # dead weight — rollback is simply NOT
-                        # advancing; the stale k/v beyond _lens gets
-                        # re-reserved and overwritten by later appends
-                        break
-                    # draft j verified: its k/v (scattered this launch)
-                    # IS the true token's k/v, so advancing onto it
-                    # lets the next column's logits be consumed too
-                    self.cache.advance(r.req_id, tok)
-                    row_accepted += 1
+        step = self.steps
+        with annotate("engine.flush", step=step) as span:
+            span.set(compress=self._flush_compress(),
+                     promote=self._flush_promote(),
+                     loads=self._flush_tier_loads(),
+                     cow=self._flush_cow())
+        with annotate("engine.pack", step=step):
+            chunks = [w for w in rows if not w.decode]
+            decodes = [w for w in rows if w.decode]
+            computed = sum(w.length for w in chunks)
+            t_flat, tq, nt = self.flat_tokens, self.tile_q, self.num_tiles
+            b = self.max_batch_size
+            mb = self.max_blocks_per_seq
+            tokens = np.zeros((t_flat,), np.int32)
+            positions = np.zeros((t_flat,), np.int32)
+            # pad positions scatter into scratch block 0 (slot < bs)
+            slots = np.zeros((t_flat,), np.int32)
+            block_tables = np.zeros((b + 1, mb), np.int32)
+            # null/pad rows: scratch
+            context_lens = np.ones((b + 1,), np.int32)
+            q_starts = np.zeros((b + 1,), np.int32)
+            tile_rows = np.full((nt,), b, np.int32)  # pad tiles -> null row
+            tile_offs = np.zeros((nt,), np.int32)
+            last_idx = np.zeros((b, self.spec_len), np.int32)
+            cursor = 0
+            for i, row in enumerate(rows):
+                r = row.req
+                toks = r.tokens
                 if row.draft:
-                    drafted += len(row.draft)
-                    accepted += row_accepted
-                    self._m_spec_drafted.inc(len(row.draft))
-                    self._m_spec_accepted.inc(row_accepted)
-                    self._m_spec_rejected.inc(
-                        len(row.draft) - row_accepted)
-                    self._m_spec_ratio.observe(
-                        row_accepted / len(row.draft))
-            else:
-                self.cache.commit_prefill(r.req_id, row.start + row.length)
-                self.tracer.on_chunk(r.req_id, row.start, row.length)
-                if row.start + row.length == len(r.prompt):  # final chunk
-                    if r.n_candidates > 1 and not r.forks:
-                        # fork BEFORE the primary consumes the logits:
-                        # each sibling samples its first token from the
-                        # same final-chunk row under its own seed
-                        self._fork_candidates(r, logits[i, 0], now)
-                    tok, lp = _sample(logits[i, 0], r, len(r.prompt))
-                    r.logprob_sum += lp
-                    if not r.first_token_time:
-                        r.first_token_time = now
-                    self.tracer.on_first_token(r.req_id)
-                    self._emit_token(r, tok)
-        if chunks:
-            # per-event field: a request's prefix-hit tokens are
-            # attributed to the step its FIRST chunk runs
-            # (start == cached_tokens) and 0 on later chunks, so summing
-            # `cached` over a drain equals hit_tokens; cumulative rates
-            # ride `hit_rate`/stats()
-            cached = sum(w.req.cached_tokens for w in chunks
-                         if w.start == w.req.cached_tokens)
-            self.prefill_tokens_computed += computed
-            self.max_chunk_tokens = max(self.max_chunk_tokens, computed)
-            self._m_tokens.labels(kind="prefill").inc(computed)
-            if cached:
-                self._m_tokens.labels(kind="cached").inc(cached)
-            serve_event("serve_prefill", batch=len(chunks),
-                        flat_t=t_flat, tokens=computed, cached=cached,
-                        step=self.steps, cow=self.cache.cow_copies,
-                        shared_blocks=self.cache.shared_blocks,
-                        hit_rate=round(self.cache.hit_rate(), 4),
-                        occupancy=round(self.cache.occupancy(), 4),
-                        queue_depth=self.scheduler.queue_depth)
-        if decodes:
-            serve_event("serve_decode", batch=len(decodes),
-                        step=self.steps, drafted=drafted,
-                        accepted=accepted,
-                        occupancy=round(self.cache.occupancy(), 4),
-                        queue_depth=self.scheduler.queue_depth)
-        return len(chunks), len(decodes), computed, drafted
+                    # draft tokens live only in the plan, not in req.tokens
+                    window = [toks[row.start]] + row.draft
+                else:
+                    window = toks[row.start:row.start + row.length]
+                tokens[cursor:cursor + row.length] = window
+                positions[cursor:cursor + row.length] = np.arange(
+                    row.start, row.start + row.length, dtype=np.int32)
+                for p in range(row.length):
+                    slots[cursor + p] = self.cache.slot_of(r.req_id,
+                                                           row.start + p)
+                block_tables[i] = self.cache.padded_table(r.req_id, mb)
+                context_lens[i] = row.start + row.length
+                q_starts[i] = row.start
+                if row.decode:
+                    # verification gathers per-position logits (plain
+                    # decode rows have length 1: every column clamps to
+                    # the one real index)
+                    for j in range(self.spec_len):
+                        last_idx[i, j] = cursor + min(j, row.length - 1)
+                else:
+                    last_idx[i, :] = cursor + row.length - 1
+                ntiles = -(-row.length // tq)
+                t0 = cursor // tq
+                for k in range(ntiles):
+                    tile_rows[t0 + k] = i
+                    tile_offs[t0 + k] = k * tq
+                cursor += ntiles * tq
+        with annotate("engine.dispatch", step=step):
+            logits, self.cache.pools = self._step_fn(
+                self.variables, jnp.asarray(tokens), jnp.asarray(positions),
+                self.cache.pools, self.cache.qpools, self.cache.qscales,
+                jnp.asarray(block_tables), jnp.asarray(context_lens),
+                jnp.asarray(q_starts), jnp.asarray(tile_rows),
+                jnp.asarray(tile_offs), jnp.asarray(slots),
+                jnp.asarray(last_idx))
+        with annotate("engine.fetch", step=step) as span:
+            logits = np.asarray(logits)
+            span.set(bytes=logits.nbytes)
+        with annotate("engine.sample", step=step) as span:
+            # the logits reached the host: every first token and finish
+            # of this step is stamped with the span's opening reading
+            ts_us = span.ts
+            generated = self._m_tokens.labels(kind="generated")
+            emitted, finished = generated.value, len(self.finished)
+            drafted = accepted = 0
+            for i, row in enumerate(rows):
+                r = row.req
+                if row.decode:
+                    # the step wrote r.generated[-1]'s k/v at the reserved
+                    # slot
+                    self.cache.advance(r.req_id, r.generated[-1])
+                    row_accepted = 0
+                    for j in range(len(row.draft) + 1):
+                        # logits[i, j] scored window position start+j, i.e.
+                        # it predicts the token at cache seq_len (which the
+                        # advances below keep in lockstep with j)
+                        tok, lp = _sample(logits[i, j], r,
+                                          self.cache.seq_len(r.req_id))
+                        r.logprob_sum += lp
+                        self._emit_token(r, tok, ts_us)
+                        if r.finish_reason or j >= len(row.draft):
+                            break
+                        if row.draft[j] != tok:
+                            # first rejection: everything past seq_len is
+                            # dead weight — rollback is simply NOT
+                            # advancing; the stale k/v beyond _lens gets
+                            # re-reserved and overwritten by later appends
+                            break
+                        # draft j verified: its k/v (scattered this launch)
+                        # IS the true token's k/v, so advancing onto it
+                        # lets the next column's logits be consumed too
+                        self.cache.advance(r.req_id, tok)
+                        row_accepted += 1
+                    if row.draft:
+                        drafted += len(row.draft)
+                        accepted += row_accepted
+                        self._m_spec_drafted.inc(len(row.draft))
+                        self._m_spec_accepted.inc(row_accepted)
+                        self._m_spec_rejected.inc(
+                            len(row.draft) - row_accepted)
+                        self._m_spec_ratio.observe(
+                            row_accepted / len(row.draft))
+                else:
+                    self.cache.commit_prefill(r.req_id, row.start + row.length)
+                    self.tracer.on_chunk(r.req_id, row.start, row.length,
+                                         ts_us, step)
+                    if row.start + row.length == len(r.prompt):  # final chunk
+                        if r.n_candidates > 1 and not r.forks:
+                            # fork BEFORE the primary consumes the logits:
+                            # each sibling samples its first token from the
+                            # same final-chunk row under its own seed
+                            self._fork_candidates(r, logits[i, 0], ts_us)
+                        tok, lp = _sample(logits[i, 0], r, len(r.prompt))
+                        r.logprob_sum += lp
+                        if not r.first_token_time:
+                            r.first_token_time = ts_us / 1e6
+                        self.tracer.on_first_token(r.req_id, ts_us, step)
+                        self._emit_token(r, tok, ts_us)
+            span.set(emitted=int(generated.value - emitted),
+                     finished=len(self.finished) - finished)
+        return chunks, decodes, computed, drafted, accepted
 
     def _fork_candidates(self, primary: Request, logits_row: np.ndarray,
-                         now: float) -> None:
+                         ts_us: float) -> None:
         """Split a finished prefill into n parallel-sampling candidates.
         Each sibling's cache sequence shares EVERY prompt block with the
         primary — fork_sequence only bumps refcounts; COW peels a
@@ -1088,20 +1137,22 @@ class ServeEngine:
             self.cache.fork_sequence(primary.req_id, sib.req_id)
             self.scheduler.running.append(sib)
             primary.forks.append(sib)
-            self.tracer.on_enqueue(sib.req_id)
-            self.tracer.on_admit(sib.req_id)
+            self.tracer.on_enqueue(sib.req_id, ts_us,
+                                   prompt=len(sib.prompt))
+            self.tracer.on_admit(sib.req_id, ts_us, self.steps,
+                                 len(sib.prompt))
             tok, lp = _sample(logits_row, sib, len(sib.prompt))
             sib.logprob_sum += lp
-            sib.first_token_time = now
-            self.tracer.on_first_token(sib.req_id)
-            self._emit_token(sib, tok)
+            sib.first_token_time = ts_us / 1e6
+            self.tracer.on_first_token(sib.req_id, ts_us, self.steps)
+            self._emit_token(sib, tok, ts_us)
         self._set_sched_gauges()
         serve_event("serve_fork", req_id=primary.req_id,
                     candidates=primary.n_candidates,
                     shared_blocks=self.cache.shared_blocks,
                     occupancy=round(self.cache.occupancy(), 4))
 
-    def _emit_token(self, req: Request, tok: int) -> None:
+    def _emit_token(self, req: Request, tok: int, ts_us: float) -> None:
         req.generated.append(tok)
         self._m_tokens.labels(kind="generated").inc()
         if req.callback is not None:
@@ -1109,10 +1160,10 @@ class ServeEngine:
         hit_eos = req.eos_id is not None and tok == req.eos_id
         out_of_room = (len(req.tokens) >= self.max_seq_len - 1)
         if hit_eos or req.num_generated >= req.max_new_tokens or out_of_room:
-            self._finish(req, "eos" if hit_eos else "length")
+            self._finish(req, "eos" if hit_eos else "length", ts_us)
 
-    def _finish(self, req: Request, reason: str) -> None:
-        req.finish_time = time.monotonic()
+    def _finish(self, req: Request, reason: str, ts_us: float) -> None:
+        req.finish_time = ts_us / 1e6
         if self.demote_finished and self.host_tier is not None:
             # demote BEFORE the scheduler frees the blocks: the decode
             # replica pulls exactly the prefix this request committed
@@ -1131,7 +1182,7 @@ class ServeEngine:
             self._m_tpot.observe(decode_s * 1e3 / (n_gen - 1))
         self._m_reqs.labels(reason=reason).inc()
         self._set_sched_gauges()
-        self.tracer.on_finish(req.req_id, reason)
+        self.tracer.on_finish(req.req_id, reason, ts_us)
         serve_event("serve_done", req_id=req.req_id, reason=reason,
                     tokens=n_gen, ttft_ms=round(ttft_ms, 3),
                     decode_tok_s=round(max(n_gen - 1, 0) / decode_s, 2),
@@ -1141,7 +1192,7 @@ class ServeEngine:
     def _on_preempt(self, req: Request) -> None:
         self._m_preempts.inc()
         self._set_sched_gauges()
-        self.tracer.on_preempt(req.req_id)
+        self.tracer.on_preempt(req.req_id, self._plan_us)
         serve_event("serve_preempt", req_id=req.req_id,
                     kept_tokens=len(req.prompt),
                     occupancy=round(self.cache.occupancy(), 4))

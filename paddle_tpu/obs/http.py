@@ -42,6 +42,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, Optional, Tuple
 
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
+from paddle_tpu.profiler.profiler import annotate
 
 CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 
@@ -76,7 +77,10 @@ def obs_response(path: str, registry: MetricsRegistry,
             if path.startswith(pfx):
                 return prefix_routes[pfx](path)
     if path == "/metrics":
-        return 200, CONTENT_TYPE, registry.render_prometheus().encode()
+        with annotate("obs.scrape") as span:
+            body = registry.render_prometheus().encode()
+            span.set(bytes=len(body))
+        return 200, CONTENT_TYPE, body
     if path == "/healthz":
         return 200, "text/plain", b"ok\n"
     if path == "/readyz":

@@ -6,8 +6,13 @@ instant marks (per prefill chunk, first token, preempt, done).
 ServeEngine/Scheduler drive the transitions (engine/engine.py), and
 the tracer turns them into:
 
-- derived latencies (`durations_ms`) — what feeds the TTFT / TPOT /
-  queue-wait / e2e histograms in the metrics registry;
+- derived latencies (`durations_ms`) per phase (the TTFT / TPOT /
+  queue-wait / e2e histograms are fed by the engine from the SAME
+  clock readings it hands these hooks, not from this method);
+- one `request` record per finished request in the profiler's span
+  ring (`profiler.record`), beside the serving loop's host spans and
+  carrying the engine steps that admitted it and gave its first
+  token — it outlives the engine (OBSERVABILITY.md "Host spans");
 - a Chrome-trace JSON (`to_chrome_trace`) with one trace-row (tid)
   per request, timestamped on the SAME epoch-anchored clock as the
   host profiler's spans (profiler.now_us), so
@@ -37,7 +42,7 @@ import threading
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
-from paddle_tpu.profiler.profiler import now_us
+from paddle_tpu.profiler.profiler import now_us, record
 
 # span names, in lifecycle order
 QUEUED, PREFILL, DECODE = "queued", "prefill", "decode"
@@ -45,7 +50,10 @@ QUEUED, PREFILL, DECODE = "queued", "prefill", "decode"
 
 class RequestTracer:
     """Records span transitions per req_id; every hook is a no-op when
-    `enabled` is False (flip at runtime — no engine restart)."""
+    `enabled` is False (flip at runtime — no engine restart). Each
+    hook takes the clock reading (`ts`, on `now_us`) its caller made at
+    that boundary, so a boundary is read once; without one it reads
+    the clock itself (the router's relay rows)."""
 
     def __init__(self, keep_last: int = 2048, enabled: bool = True,
                  process_name: str = "serve requests"):
@@ -57,46 +65,108 @@ class RequestTracer:
         self._done: Deque[Tuple[int, List[dict]]] = deque(maxlen=keep_last)  # guarded-by: self._lock
         self._trace_of: Dict[int, str] = {}          # guarded-by: self._lock
         self._req_of: Dict[str, int] = {}            # guarded-by: self._lock
+        # the `request` record of each live engine request, filed in
+        # the profiler's ring when it finishes
+        self._records: Dict[int, dict] = {}          # guarded-by: self._lock
 
     # -- lifecycle hooks (engine-facing) ----------------------------------
-    def on_enqueue(self, req_id: int) -> None:
+    def on_enqueue(self, req_id: int, ts: Optional[float] = None,
+                   arrival: Optional[float] = None,
+                   prompt: int = 0) -> None:
+        """`arrival` is the front door's stamp (body parsed, before the
+        submit queue): `queued` starts there, so the wait for the
+        engine loop to drain that queue is inside it."""
         if not self.enabled:
             return
+        ts = now_us() if ts is None else ts
+        arrival = ts if arrival is None else arrival
         with self._lock:
-            self._open_span(req_id, QUEUED)
+            self._open_span(req_id, QUEUED, arrival)
+            self._records[req_id] = {
+                "req": req_id, "prompt": prompt, "cached": 0,
+                "arrival": arrival, "enqueued": ts, "admitted": None,
+                "first_token": None, "first_write": None,
+                "finished": None, "admit_step": None,
+                "first_token_step": None, "chunk_steps": 0,
+                "preemptions": 0, "reason": ""}
 
-    def on_admit(self, req_id: int) -> None:
+    def on_admit(self, req_id: int, ts: Optional[float] = None,
+                 step: Optional[int] = None, cached: int = 0) -> None:
         if not self.enabled:
             return
+        ts = now_us() if ts is None else ts
         with self._lock:
-            self._open_span(req_id, PREFILL)
+            self._open_span(req_id, PREFILL, ts)
+            rec = self._records.get(req_id)
+            if rec is not None and rec["admitted"] is None:
+                # the first admission: a re-admission after a
+                # preemption is the scheduler's doing, not the queue's
+                rec.update(admitted=ts, admit_step=step, cached=cached)
 
-    def on_chunk(self, req_id: int, start: int, length: int) -> None:
+    def on_chunk(self, req_id: int, start: int, length: int,
+                 ts: Optional[float] = None,
+                 step: Optional[int] = None) -> None:
         if not self.enabled:
             return
+        ts = now_us() if ts is None else ts
         with self._lock:
-            self._mark(req_id, "chunk", start=start, length=length)
+            self._mark(req_id, "chunk", ts, start=start, length=length,
+                       step=step)
+            rec = self._records.get(req_id)
+            if rec is not None and rec["first_token"] is None:
+                rec["chunk_steps"] += 1
 
-    def on_first_token(self, req_id: int) -> None:
+    def on_first_token(self, req_id: int, ts: Optional[float] = None,
+                       step: Optional[int] = None) -> None:
         if not self.enabled:
             return
+        ts = now_us() if ts is None else ts
         with self._lock:
-            self._mark(req_id, "first_token")
-            self._open_span(req_id, DECODE)
+            self._mark(req_id, "first_token", ts, step=step)
+            self._open_span(req_id, DECODE, ts)
+            rec = self._records.get(req_id)
+            if rec is not None and rec["first_token"] is None:
+                rec.update(first_token=ts, first_token_step=step)
 
-    def on_preempt(self, req_id: int) -> None:
+    def on_first_write(self, req_id: int,
+                       ts: Optional[float] = None) -> None:
+        """The front door wrote the request's first token frame (any
+        thread). A request that finished before its first frame went
+        out (a one-token answer), or that nothing streams, keeps
+        `first_write` None."""
         if not self.enabled:
             return
+        ts = now_us() if ts is None else ts
         with self._lock:
-            self._mark(req_id, "preempt")
-            self._open_span(req_id, QUEUED)   # back to the wait queue
+            self._mark(req_id, "first_write", ts)
+            rec = self._records.get(req_id)
+            if rec is not None and rec["first_write"] is None:
+                rec["first_write"] = ts
 
-    def on_finish(self, req_id: int, reason: str = "") -> None:
+    def on_preempt(self, req_id: int, ts: Optional[float] = None) -> None:
         if not self.enabled:
             return
+        ts = now_us() if ts is None else ts
         with self._lock:
-            self._mark(req_id, "done", reason=reason)
-            self._close_span(req_id)
+            self._mark(req_id, "preempt", ts)
+            self._open_span(req_id, QUEUED, ts)   # back to the wait queue
+            rec = self._records.get(req_id)
+            if rec is not None:
+                rec["preemptions"] += 1
+
+    def on_finish(self, req_id: int, reason: str = "",
+                  ts: Optional[float] = None) -> None:
+        if not self.enabled:
+            return
+        ts = now_us() if ts is None else ts
+        with self._lock:
+            self._mark(req_id, "done", ts, reason=reason)
+            self._close_span(req_id, ts)
+            rec = self._records.pop(req_id, None)
+            if rec is not None:
+                rec.update(finished=ts, reason=reason)
+                record("request", rec["arrival"], ts - rec["arrival"],
+                       **rec)
             evs = self._events.pop(req_id, None)
             if evs is not None:
                 if len(self._done) == self._done.maxlen:
@@ -136,38 +206,38 @@ class RequestTracer:
         if not self.enabled:
             return
         with self._lock:
-            self._open_span(req_id, name)
+            self._open_span(req_id, name, now_us())
 
     def span_end(self, req_id: int) -> None:
         if not self.enabled:
             return
         with self._lock:
-            self._close_span(req_id)
+            self._close_span(req_id, now_us())
 
     def mark(self, req_id: int, name: str, **args) -> None:
         if not self.enabled:
             return
         with self._lock:
-            self._mark(req_id, name, **args)
+            self._mark(req_id, name, now_us(), **args)
 
     # -- internals (lock held) --------------------------------------------
     # requires-lock: self._lock
-    def _open_span(self, req_id: int, name: str) -> None:
-        self._close_span(req_id)
-        ev = {"name": name, "ph": "X", "ts": now_us(), "dur": None}
+    def _open_span(self, req_id: int, name: str, ts: float) -> None:
+        self._close_span(req_id, ts)
+        ev = {"name": name, "ph": "X", "ts": ts, "dur": None}
         self._open[req_id] = ev
         self._events.setdefault(req_id, []).append(ev)
 
     # requires-lock: self._lock
-    def _close_span(self, req_id: int) -> None:
+    def _close_span(self, req_id: int, ts: float) -> None:
         ev = self._open.pop(req_id, None)
         if ev is not None:
-            ev["dur"] = now_us() - ev["ts"]
+            ev["dur"] = ts - ev["ts"]
 
     # requires-lock: self._lock
-    def _mark(self, req_id: int, name: str, **args) -> None:
+    def _mark(self, req_id: int, name: str, ts: float, **args) -> None:
         self._events.setdefault(req_id, []).append(
-            {"name": name, "ph": "i", "ts": now_us(), "args": args})
+            {"name": name, "ph": "i", "ts": ts, "args": args})
 
     # -- reads ------------------------------------------------------------
     def _events_of(self, req_id: int) -> List[dict]:
@@ -261,6 +331,7 @@ class RequestTracer:
             self._done.clear()
             self._trace_of.clear()
             self._req_of.clear()
+            self._records.clear()
 
     def save(self, path: str) -> None:
         with open(path, "w") as f:
@@ -270,9 +341,10 @@ class RequestTracer:
 def merged_chrome_trace(tracer: RequestTracer,
                         include_host_spans: bool = True,
                         path: Optional[str] = None) -> dict:
-    """Merge the request-lifecycle trace with the host profiler's
-    recorded spans (profiler.get_events between start/stop_profiler)
-    into ONE Chrome trace via the multi-process timeline merger —
+    """Merge the request-lifecycle trace with the profiler's span ring
+    (profiler.get_events: the serving loop's host spans, always; plus
+    RecordEvent spans between start/stop_profiler) into ONE Chrome
+    trace via the multi-process timeline merger —
     request rows and engine host spans share the epoch-anchored
     clock, so they line up without shifting."""
     from paddle_tpu.profiler.profiler import events_to_chrome_trace

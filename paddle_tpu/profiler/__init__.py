@@ -10,7 +10,9 @@ Two tiers, mirroring the reference's design split:
    data feed); device-op granularity comes from tier 2.
 2. Device tracer — `start_profiler`/`stop_profiler`/`profiler` wrap
    `jax.profiler.start_trace/stop_trace` (≈ CUPTI device_tracer.h:39);
-   `annotate` / `TraceAnnotation` name regions inside the device timeline.
+   `annotate` / `TraceAnnotation` name regions inside the device timeline;
+   `annotate` spans also land, always, in the one bounded span ring that
+   `get_events` reads (the serving loop's host spans, OBSERVABILITY.md).
 
 `timeline.py` converts recorded host events to Chrome trace format and can
 merge multiple processes' profiles (≈ tools/timeline.py:25-36).

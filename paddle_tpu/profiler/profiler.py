@@ -5,6 +5,13 @@ Host tier ≈ reference RecordEvent/EnableProfiler
 printed by DisableProfiler with a sort key). Device tier wraps
 jax.profiler (≈ CUPTI device tracer, platform/device_tracer.h:39) — the
 captured trace dir is TensorBoard/perfetto-loadable.
+
+ONE span store: a process-wide bounded ring (`_events`). `annotate`
+spans — the serving loop's layer boundaries (OBSERVABILITY.md "Host
+spans") and the request tracer's `request` records — land in it
+ALWAYS, so it outlives the engine and front end that wrote it;
+`RecordEvent` spans land in it only between `start_profiler` and
+`stop_profiler` (which clear it first: the table is of that interval).
 """
 
 from __future__ import annotations
@@ -15,14 +22,20 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Deque, Dict, List, Optional
 
 import jax
 
 from paddle_tpu.utils.log import vlog
 
+# 90 s of the busiest serving cell (about 650 steps of 12 spans, 700
+# request records) fit twice over; at 50 idle `frontdoor.wait` spans a
+# second the ring still reaches back five minutes
+RING_SPANS = 16384
 _lock = threading.Lock()
-_events: List[dict] = []          # completed spans: name/ts/dur/tid (us)
+# completed spans: name/ts/dur/tid (us) and args
+_events: Deque[dict] = deque(maxlen=RING_SPANS)   # guarded-by: _lock
 _enabled = False
 _trace_dir: Optional[str] = None
 # Wall-clock anchor for the monotonic counter: timestamps are epoch-based
@@ -38,7 +51,14 @@ def now_us() -> float:
     return (_EPOCH_NS + time.perf_counter_ns()) / 1e3
 
 
-_now_us = now_us
+def record(name: str, ts: float, dur: float, **args) -> None:
+    """Append one finished span (or record: `obs/tracing.py` files a
+    `request` here when it finishes) to the ring, stamps on `now_us`,
+    under the calling thread's id."""
+    ev = {"name": name, "ts": ts, "dur": dur,
+          "tid": threading.get_ident() & 0xFFFF, "args": args}
+    with _lock:
+        _events.append(ev)
 
 
 class RecordEvent:
@@ -54,20 +74,12 @@ class RecordEvent:
         self._start = 0.0
 
     def __enter__(self):
-        self._start = _now_us()
+        self._start = now_us()
         return self
 
     def __exit__(self, *exc):
-        if not _enabled:
-            return False
-        end = _now_us()
-        with _lock:
-            _events.append({
-                "name": self.name,
-                "ts": self._start,
-                "dur": end - self._start,
-                "tid": threading.get_ident() & 0xFFFF,
-            })
+        if _enabled:
+            record(self.name, self._start, now_us() - self._start)
         return False
 
 
@@ -90,18 +102,48 @@ def record_function(name: Optional[str] = None):
     return deco
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in the DEVICE trace (jax.profiler.TraceAnnotation) and
-    the host event list — the named_scope analog of the reference's
-    RecordEvent-around-kernel-launch."""
-    with jax.profiler.TraceAnnotation(name), RecordEvent(name):
-        yield
+class annotate:
+    """A span at a layer boundary, written twice. To the profiler's own
+    trace (`jax.profiler.TraceAnnotation(name, **args)`: on the
+    timeline of the device's operations whenever a `jax.profiler`
+    session is on, a flag test when none is), and ALWAYS to the ring,
+    on `now_us`: two clock readings, `ts` when it opens and `dur` when
+    it closes, both readable afterwards. `args` are what is known when
+    it opens; `set()` adds the counts known only when it closes (those
+    reach the ring's entry alone). `discard()` keeps a span that turned
+    out to bracket nothing (an idle engine step) out of the ring."""
+
+    __slots__ = ("name", "args", "ts", "dur", "_trace", "_keep")
+
+    def __init__(self, name: str, **args):
+        self.name, self.args = name, args
+        self.ts = self.dur = 0.0
+        self._keep = True
+        self._trace = jax.profiler.TraceAnnotation(name, **args)
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def discard(self) -> None:
+        self._keep = False
+
+    def __enter__(self) -> "annotate":
+        self._trace.__enter__()
+        self.ts = now_us()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur = now_us() - self.ts
+        self._trace.__exit__(*exc)
+        if self._keep:
+            record(self.name, self.ts, self.dur, **self.args)
+        return False
 
 
 def start_profiler(trace_dir: Optional[str] = None) -> None:
-    """Enable host-span recording; if trace_dir is given, also start a
-    jax.profiler device trace into it (≈ EnableProfiler(kAll))."""
+    """Enable `RecordEvent` recording, from an empty ring; if trace_dir
+    is given, also start a jax.profiler device trace into it
+    (≈ EnableProfiler(kAll))."""
     global _enabled, _trace_dir
     with _lock:
         _events.clear()
@@ -204,7 +246,7 @@ def events_to_chrome_trace(events: Optional[List[dict]] = None,
     trace = [{
         "name": ev["name"], "ph": "X", "cat": "host",
         "ts": ev["ts"], "dur": ev["dur"], "pid": pid, "tid": ev["tid"],
-        "args": {},
+        "args": dict(ev.get("args") or {}),
     } for ev in events]
     meta = [{"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
              "args": {"name": f"process {pid}"}}]

@@ -101,6 +101,7 @@ from paddle_tpu.engine.scheduler import Request
 from paddle_tpu.obs.flightrec import FlightRecorder
 from paddle_tpu.obs.http import json_route, obs_response
 from paddle_tpu.obs.slo import SLOMonitor
+from paddle_tpu.profiler.profiler import annotate, now_us
 from paddle_tpu.resilience.errors import PREEMPT_EXIT_CODE
 from paddle_tpu.resilience.supervisor import RunSupervisor
 from paddle_tpu.serve.aio import AioConnection, AioRequest, \
@@ -129,11 +130,14 @@ class _Stream:
     parked consumer resumes without polling. `gone` is flipped in-loop
     by the transport disconnect watcher."""
 
-    __slots__ = ("params", "q", "req", "streamed", "cand_pos",
-                 "loop", "ev", "gone")
+    __slots__ = ("params", "arrival_us", "q", "req", "streamed",
+                 "cand_pos", "loop", "ev", "gone")
 
-    def __init__(self, params: dict):
+    def __init__(self, params: dict, arrival_us: Optional[float] = None):
         self.params = params
+        # `now_us` when the body was parsed, before the submit queue
+        # (None for the warm-up request, which has no arrival)
+        self.arrival_us = arrival_us
         self.q: "queue.Queue" = queue.Queue()
         self.req: Optional[Request] = None
         self.streamed = 0
@@ -517,12 +521,13 @@ class ServeFrontend:
                 now = time.monotonic()
                 if now >= self._dir_next:
                     self._dir_next = now + self.dir_interval_s
-                    snapshot = eng.kv_prefix_directory()
-                    debug = eng.debug_state()
-                    with self._lock:
-                        self._directory = snapshot
-                        self._debug_snapshot = debug
-                    self._check_slo_burn()
+                    with annotate("frontdoor.snapshot"):
+                        snapshot = eng.kv_prefix_directory()
+                        debug = eng.debug_state()
+                        with self._lock:
+                            self._directory = snapshot
+                            self._debug_snapshot = debug
+                        self._check_slo_burn()
                 if (self.tier_spill_interval_s > 0
                         and now >= self._spill_next):
                     self._spill_next = now + self.tier_spill_interval_s
@@ -534,8 +539,9 @@ class ServeFrontend:
                     self._abort_active("shutdown")
                     break
                 if not progressed:
-                    self._work.wait(0.02)
-                    self._work.clear()
+                    with annotate("frontdoor.wait"):
+                        self._work.wait(0.02)
+                        self._work.clear()
         except Exception as e:
             # an engine-loop crash is exactly what the flight recorder
             # exists for: freeze the event ring + engine state before
@@ -617,43 +623,53 @@ class ServeFrontend:
         """Apply handler-thread intents on the engine thread: new
         submissions, then cancellations (a disconnect may target a
         request submitted moments ago)."""
-        while self._submit:
-            stream = self._submit.popleft()
-            p = stream.params
-            n_stream = p.get("n", 1)        # candidates the client sees
+        with annotate("frontdoor.control") as span:
+            submitted = cancelled = 0
+            while self._submit:
+                stream = self._submit.popleft()
+                submitted += 1
+                p = stream.params
+                n_stream = p.get("n", 1)        # candidates the client sees
 
-            def _fork_cb(i, s=stream, n_stream=n_stream):
-                # candidates in [n, best_of) decode silently: they only
-                # compete in the best-of ranking, never reach the wire
-                if i >= n_stream:
-                    return None
-                return lambda tok, s=s, i=i: s.push(("token", tok, i))
+                def _fork_cb(i, s=stream, n_stream=n_stream):
+                    # candidates in [n, best_of) decode silently: they only
+                    # compete in the best-of ranking, never reach the wire
+                    if i >= n_stream:
+                        return None
+                    return lambda tok, s=s, i=i: s.push(("token", tok, i))
 
-            try:
-                req = self.engine.add_request(
-                    p["prompt"], max_new_tokens=p["max_new_tokens"],
-                    temperature=p["temperature"], top_k=p["top_k"],
-                    seed=p["seed"], eos_id=p["eos_id"],
-                    deadline_ms=p["deadline_ms"],
-                    n=p.get("best_of", 1),
-                    fork_callback=_fork_cb,
-                    callback=lambda tok, s=stream: s.push(("token", tok, 0)))
-                stream.req = req
-                self.engine.tracer.set_trace_id(
-                    req.req_id, p.get("trace_id"))
-                with self._lock:
-                    self._active[req.req_id] = stream
-            except Exception as e:       # bad prompt: surface as 400
-                stream.push(("error", str(e)))
-        while self._cancel:
-            stream = self._cancel.popleft()
-            if stream.req is not None:
-                # a disconnect tears down the WHOLE group: every
-                # candidate's block refs drop, shared prompt refcounts
-                # return to baseline
-                self.engine.cancel_group(stream.req)
-                with self._lock:
-                    self._active.pop(stream.req.req_id, None)
+                try:
+                    req = self.engine.add_request(
+                        p["prompt"], max_new_tokens=p["max_new_tokens"],
+                        temperature=p["temperature"], top_k=p["top_k"],
+                        seed=p["seed"], eos_id=p["eos_id"],
+                        deadline_ms=p["deadline_ms"],
+                        n=p.get("best_of", 1),
+                        fork_callback=_fork_cb,
+                        callback=lambda tok, s=stream: s.push(
+                            ("token", tok, 0)),
+                        arrival_us=stream.arrival_us)
+                    stream.req = req
+                    self.engine.tracer.set_trace_id(
+                        req.req_id, p.get("trace_id"))
+                    with self._lock:
+                        self._active[req.req_id] = stream
+                except Exception as e:       # bad prompt: surface as 400
+                    stream.push(("error", str(e)))
+            while self._cancel:
+                stream = self._cancel.popleft()
+                cancelled += 1
+                if stream.req is not None:
+                    # a disconnect tears down the WHOLE group: every
+                    # candidate's block refs drop, shared prompt refcounts
+                    # return to baseline
+                    self.engine.cancel_group(stream.req)
+                    with self._lock:
+                        self._active.pop(stream.req.req_id, None)
+            if submitted or cancelled:
+                span.set(submitted=submitted, cancelled=cancelled)
+            else:       # an empty look at both queues is not work
+                span.discard()
 
     @staticmethod
     def _group_done(req: Request) -> bool:
@@ -689,24 +705,26 @@ class ServeFrontend:
     def _flush_finished(self) -> None:
         """Push done frames for request GROUPS the last step finished
         (for n > 1 the frame waits until every candidate is done)."""
-        with self._lock:
-            done = [(rid, s) for rid, s in self._active.items()
-                    if s.req is not None and self._group_done(s.req)]
-            for rid, _ in done:
-                del self._active[rid]
-        for rid, s in done:
-            if s.req.n_candidates == 1:
-                s.push(("done", s.req.finish_reason,
-                        ServeEngine._generated_of(s.req), None))
-            else:
-                best_idx, cands = self._rank_group(s.req)
-                best = cands[best_idx]
-                n_stream = s.params.get("n", 1)
-                s.push(("done", best["reason"], best["tokens"],
-                        {"best_index": best_idx,
-                         # silent best_of-only candidates stay
-                         # server-side; the wire sees n candidates
-                         "candidates": cands[:n_stream]}))
+        with annotate("frontdoor.finish") as span:
+            with self._lock:
+                done = [(rid, s) for rid, s in self._active.items()
+                        if s.req is not None and self._group_done(s.req)]
+                for rid, _ in done:
+                    del self._active[rid]
+            for rid, s in done:
+                if s.req.n_candidates == 1:
+                    s.push(("done", s.req.finish_reason,
+                            ServeEngine._generated_of(s.req), None))
+                else:
+                    best_idx, cands = self._rank_group(s.req)
+                    best = cands[best_idx]
+                    n_stream = s.params.get("n", 1)
+                    s.push(("done", best["reason"], best["tokens"],
+                            {"best_index": best_idx,
+                             # silent best_of-only candidates stay
+                             # server-side; the wire sees n candidates
+                             "candidates": cands[:n_stream]}))
+            span.set(closed=len(done))
 
     def _drain_finished(self) -> bool:
         """True once every in-flight stream completed (or the deadline
@@ -985,6 +1003,7 @@ class ServeFrontend:
             await conn.send(404, "text/plain", b"not found\n")
             return
         params, err = self._parse_completion(req)
+        arrival_us = now_us()
         if params is None:
             await conn.send(400, "application/json", err)
             return
@@ -996,7 +1015,7 @@ class ServeFrontend:
             # blocking peer pull: off the loop, into the executor
             await asyncio.get_running_loop().run_in_executor(
                 None, self._maybe_pull_kv, req, params["prompt"])
-        stream = _Stream(params)
+        stream = _Stream(params, arrival_us)
         # bind the wake-up bridge BEFORE the engine can see the stream
         stream.attach(asyncio.get_running_loop(), asyncio.Event())
         with self._lock:
@@ -1070,10 +1089,14 @@ class ServeFrontend:
                     # `index` tags the CANDIDATE (parallel sampling);
                     # `pos` is the token's position within that
                     # candidate's stream
-                    t0 = time.perf_counter()
+                    t0 = now_us()
                     await conn.write(sse_event(
                         {"token": tok, "index": cand, "pos": pos}))
-                    self._m_token_write.observe(time.perf_counter() - t0)
+                    t1 = now_us()
+                    self._m_token_write.observe((t1 - t0) / 1e6)
+                    if not stream.streamed:
+                        self.engine.tracer.on_first_write(
+                            stream.req.req_id, t1)
                     stream.cand_pos[cand] = pos + 1
                     stream.streamed += 1
                 elif item[0] == "done":

@@ -1,0 +1,269 @@
+"""Host spans of the serving loop (OBSERVABILITY.md "Host spans"): the
+`annotate` primitive writes every layer boundary of `ServeEngine.step`
+and of the front door's loop to the profiler's trace and to one bounded
+ring that outlives both; the request tracer files one `request` record
+a finished request there. On the CPU toy engine, so counts and order
+are checked, never a time.
+"""
+
+import gc
+import glob
+import importlib
+import inspect
+import json
+import threading
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.engine.engine import ServeEngine
+from paddle_tpu.models.transformer import CausalLM
+from paddle_tpu.obs.metrics import MetricsRegistry
+from paddle_tpu.serve.frontend import ServeFrontend
+from paddle_tpu.serve.sse import collect_stream, http_get
+
+import paddle_tpu.engine.engine as engine_mod
+
+# the package re-exports a function named `profiler` over the submodule
+prof = importlib.import_module("paddle_tpu.profiler.profiler")
+
+pytestmark = pytest.mark.serve
+
+VOCAB = 61
+CHILDREN = ("engine.plan", "engine.flush", "engine.pack", "engine.dispatch",
+            "engine.fetch", "engine.sample", "engine.publish")
+PROMPTS = [[5, 9, 2, 7, 1, 3], [4, 4, 8], [11, 12, 13, 14, 15, 16, 17, 18]]
+
+
+@pytest.fixture(scope="module")
+def model_and_vars():
+    model = CausalLM(vocab=VOCAB, model_dim=16, num_heads=4, num_layers=2,
+                     ffn_dim=32, dropout=0.0, max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    return model, variables
+
+
+def _engine(model, variables, **kw):
+    kw.setdefault("max_batch_size", 4)
+    kw.setdefault("block_size", 4)
+    kw.setdefault("num_blocks", 64)
+    kw.setdefault("registry", MetricsRegistry())
+    return ServeEngine(model, variables, **kw)
+
+
+@pytest.fixture
+def drained(model_and_vars):
+    """A fresh engine that served PROMPTS (a prefill budget of 4 tokens:
+    several chunk steps a prompt), over an emptied ring."""
+    eng = _engine(*model_and_vars, max_prefill_tokens=4)
+    eng.generate([[1, 2]], max_new_tokens=2)      # the one compilation
+    eng.reset_stats()
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    return eng, prof.get_events()
+
+
+def _spans(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def _end(ev):
+    return ev["ts"] + ev["dur"]
+
+
+def test_children_nest_in_their_step_and_cover_it(drained):
+    _, events = drained
+    steps = _spans(events, "engine.step")
+    assert len(steps) >= 6
+    for st in steps:
+        kids = sorted((e for e in events if e["name"] in CHILDREN
+                       and e["args"]["step"] == st["args"]["step"]),
+                      key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == list(CHILDREN)
+        assert all(k["tid"] == st["tid"] for k in kids)
+        assert kids[0]["ts"] >= st["ts"] and _end(kids[-1]) <= _end(st)
+        for a, b in zip(kids, kids[1:]):
+            assert _end(a) <= b["ts"], (a["name"], b["name"])
+    covered = sum(e["dur"] for e in events if e["name"] in CHILDREN)
+    assert covered >= 0.95 * sum(st["dur"] for st in steps)
+
+
+def test_spans_carry_the_step_and_agree_with_the_counters(drained):
+    eng, events = drained
+    steps = _spans(events, "engine.step")
+    n = int(eng.obs.get("ptpu_engine_steps_total").value)
+    assert [st["args"]["step"] for st in steps] == list(range(1, n + 1))
+    for name in CHILDREN:
+        assert [e["args"]["step"] for e in _spans(events, name)] == \
+            list(range(1, n + 1)), name
+    tokens = eng.obs.get("ptpu_serve_tokens_total")
+    samples = _spans(events, "engine.sample")
+    assert sum(e["args"]["emitted"] for e in samples) == \
+        tokens.labels(kind="generated").value == 6 * len(PROMPTS)
+    assert sum(e["args"]["finished"] for e in samples) == len(PROMPTS)
+    assert sum(st["args"]["chunk_tokens"] for st in steps) == \
+        tokens.labels(kind="prefill").value
+    assert all(e["args"]["bytes"] > 0
+               for e in _spans(events, "engine.fetch"))
+    assert {"cow", "loads", "compress", "promote"} <= \
+        set(_spans(events, "engine.flush")[0]["args"])
+    assert {"decode_rows", "chunk_rows", "queue_depth", "used_blocks"} <= \
+        set(steps[0]["args"])
+    # the histogram is fed from the span: one observation a step, and
+    # the idle call that ends `run()` left neither
+    assert sum(c.count for c in eng.obs.get(
+        "ptpu_serve_step_ms").children().values()) == n
+
+
+def test_ring_is_bounded_and_outlives_engine_and_front_end(model_and_vars):
+    prof.reset_profiler()
+    fe = ServeFrontend(_engine(*model_and_vars)).start()
+    out = collect_stream(fe.url, {"prompt": PROMPTS[0],
+                                  "max_new_tokens": 5})
+    assert out["done"]
+    fe.stop()
+    del fe
+    gc.collect()
+    names = {e["name"] for e in prof.get_events()}
+    assert {"engine.step", "frontdoor.control", "frontdoor.finish",
+            "request"} <= names
+    for _ in range(prof.RING_SPANS + 10):
+        prof.record("filler", 0.0, 0.0)
+    events = prof.get_events()
+    assert len(events) == prof.RING_SPANS
+    assert {e["name"] for e in events} == {"filler"}
+    prof.reset_profiler()
+
+
+def test_profiler_trace_holds_fetch_with_its_step(drained, tmp_path):
+    """The harness's options (benchmarks/tracing.py): Python tracer off,
+    host tracer at 1. The annotation lies on the host plane, on the
+    line of the thread that opened it, with `step` as a stat."""
+    eng, _ = drained
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    for p in PROMPTS:
+        eng.add_request(p, max_new_tokens=3)
+    first = eng.steps + 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            assert eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    eng.run()
+    from jax.profiler import ProfileData
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            found += [dict(ev.stats)["step"] for ev in line.events
+                      if ev.name == "engine.fetch"]
+    assert sorted(found) == [first, first + 1, first + 2]
+
+
+@pytest.fixture(scope="module")
+def served(model_and_vars):
+    """One request over HTTP through a started front end; the ring as
+    it was when the stream had ended, and the front end still up."""
+    prof.reset_profiler()
+    fe = ServeFrontend(_engine(*model_and_vars)).start()
+    out = collect_stream(fe.url, {"prompt": PROMPTS[2],
+                                  "max_new_tokens": 16})
+    assert out["done"] and len(out["tokens"]) == 16
+    yield fe, out, prof.get_events()
+    fe.stop()
+
+
+def test_request_record_is_monotone_and_names_its_steps(served):
+    _, out, events = served
+    (rec,) = [e["args"] for e in _spans(events, "request")
+              if e["args"]["req"] == out["final"]["req_id"]]
+    order = ["arrival", "enqueued", "admitted", "first_token",
+             "first_write", "finished"]
+    stamps = [rec[k] for k in order]
+    assert None not in stamps
+    assert stamps == sorted(stamps), dict(zip(order, stamps))
+    assert rec["prompt"] == len(PROMPTS[2]) and rec["reason"] == "length"
+    assert rec["chunk_steps"] >= 1 and rec["preemptions"] == 0
+    in_ring = {e["args"]["step"]: e for e in _spans(events, "engine.step")}
+    for step_key, stamp in (("admit_step", "admitted"),
+                            ("first_token_step", "first_token")):
+        st = in_ring[rec[step_key]]
+        assert st["ts"] <= rec[stamp] <= _end(st)
+    # the front door drained it inside a `frontdoor.control` span
+    assert any(c["ts"] <= rec["enqueued"] <= _end(c)
+               and c["args"]["submitted"] >= 1
+               for c in _spans(events, "frontdoor.control"))
+
+
+def test_queued_starts_at_the_arrival(served):
+    fe, out, events = served
+    (rec,) = [e["args"] for e in _spans(events, "request")
+              if e["args"]["req"] == out["final"]["req_id"]]
+    frag = fe.engine.tracer.trace_fragment(out["final"]["trace_id"])
+    queued = [e for e in frag["traceEvents"] if e["name"] == "queued"]
+    assert queued[0]["ts"] == rec["arrival"] < rec["enqueued"]
+    assert queued[0]["ts"] + queued[0]["dur"] == rec["admitted"]
+    marks = {e["name"]: e for e in frag["traceEvents"] if e["ph"] == "i"}
+    assert marks["first_token"]["args"]["step"] == rec["first_token_step"]
+    assert marks["first_write"]["ts"] == rec["first_write"]
+
+
+def test_trace_route_still_serves_its_fragment(served):
+    fe, out, _ = served
+    status, body = http_get(fe.url + "/trace/" + out["final"]["trace_id"])
+    assert status == 200
+    frag = json.loads(body)
+    assert frag["req_id"] == out["final"]["req_id"]
+    assert {"queued", "prefill", "decode"} <= {
+        e["name"] for e in frag["traceEvents"] if e["ph"] == "X"}
+    assert http_get(fe.url + "/trace/unknown")[0] == 404
+
+
+def test_scrape_is_a_span_on_the_handlers_thread(served):
+    fe, _, _ = served
+    status, body = http_get(fe.url + "/metrics")
+    assert status == 200
+    scrape = _spans(prof.get_events(), "obs.scrape")[-1]
+    assert scrape["args"]["bytes"] == len(body.encode())
+    step = _spans(prof.get_events(), "engine.step")[-1]
+    assert scrape["tid"] != step["tid"]
+
+
+def test_step_reads_the_clock_at_most_twice_a_span(drained):
+    """Every boundary of a step is read once: the request stamps take
+    the readings of the spans they fall in."""
+    eng, _ = drained
+    for p in PROMPTS:
+        eng.add_request(p, max_new_tokens=4)
+    prof.reset_profiler()
+    reads = []
+
+    def counted():
+        reads.append(threading.get_ident())
+        return real()
+    real = prof.now_us
+    with mock.patch.object(prof, "now_us", counted), \
+            mock.patch.object(engine_mod, "now_us", counted), \
+            mock.patch("paddle_tpu.obs.tracing.now_us", counted):
+        steps = 0
+        while eng.step():
+            steps += 1
+    spans = [e for e in prof.get_events() if e["name"].startswith("engine.")]
+    assert steps >= 4 and len(spans) == 8 * steps
+    assert _spans(prof.get_events(), "request")
+    # the idle call that ended the loop opened two spans it discarded;
+    # another test's front end may be waiting on its own thread
+    mine = reads.count(threading.get_ident())
+    assert 0 < mine <= 2 * len(spans) + 4
+    # and the engine has no other clock
+    source = inspect.getsource(engine_mod)
+    assert "perf_counter" not in source and "time.monotonic" not in source
