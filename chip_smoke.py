@@ -112,17 +112,42 @@ def _lowered_text(jitted, *args) -> str:
     return jitted.lower(*jax.tree.map(spec, args)).as_text()
 
 
-def _engine_step_text(eng) -> str:
+def pool_sized_copies(program_text: str, pool_elements: int) -> list:
+    """The `copy` instructions of a compiled program whose result has a
+    pool's element count (on one chip): a whole-pool relayout. The
+    step writes its K/V into the donated pool in place, so there are
+    none; a pool whose default device layout is not the one its
+    scatter and kernel use brings two per pool per step back
+    (tests/test_chip_compile.py holds the same count without a chip)."""
+    import math
+    import re
+    found = []
+    for line in program_text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) == pool_elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _engine_step_compiled(eng):
+    """The engine's one step, compiled for operands of its own shapes
+    on the devices it runs on."""
+    import jax
     import jax.numpy as jnp
     t, nt, b = eng.flat_tokens, eng.num_tiles, eng.max_batch_size
 
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
     def i32(*shape):
-        return np.zeros(shape, jnp.int32)
-    return _lowered_text(
-        eng._step_fn, eng.variables, i32(t), i32(t), eng.cache.pools,
-        eng.cache.qpools, eng.cache.qscales,
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    return eng._step_fn.lower(
+        jax.tree.map(spec, eng.variables), i32(t), i32(t),
+        jax.tree.map(spec, eng.cache.pools),
+        jax.tree.map(spec, eng.cache.qpools),
+        jax.tree.map(spec, eng.cache.qscales),
         i32(b + 1, eng.max_blocks_per_seq), i32(b + 1), i32(b + 1),
-        i32(nt), i32(nt), i32(t), i32(b, eng.spec_len))
+        i32(nt), i32(nt), i32(t), i32(b, eng.spec_len)).compile()
 
 
 def _placement_failures(eng, tp_size: int) -> list:
@@ -134,11 +159,11 @@ def _placement_failures(eng, tp_size: int) -> list:
     devs = jax.devices()[:tp_size]
     qkv = eng.variables["params"]["blocks_0"]["attn"]["q_proj"]["weight"]
     for name, arr in (("q_proj weight", qkv),
-                      ("k pool", eng.cache.pools[0][0])):
+                      ("kv pool", eng.cache.pools[0])):
         on = {s.device for s in arr.addressable_shards}
         if on != set(devs):
             failed.append(f"{name} on {len(on)} devices, want {tp_size}")
-    whole = sum(kp.nbytes + vp.nbytes for kp, vp in eng.cache.pools)
+    whole = sum(pool.nbytes for pool in eng.cache.pools)
     if eng.cache.per_chip_pool_bytes() * tp_size != whole:
         failed.append("per-chip pool bytes are not 1/tp of the pool")
     in_use = [_device_bytes(d, "bytes_in_use") for d in devs]
@@ -264,9 +289,23 @@ def serve_phase(widths: dict, engine: dict, prompt_lens, shared_prefix: int,
     if not stats["hit_tokens"] > 0:
         failed.append("the prefix-sharing pair hit no cached tokens")
 
-    pallas_in_step = "tpu_custom_call" in _engine_step_text(eng)
+    # read off the compiled step: the kernel tier it holds, and that
+    # it updates the donated pools in place (no whole-pool relayout,
+    # the pools' bytes aliased to its outputs)
+    step = _engine_step_compiled(eng)
+    step_text = step.as_text()
+    pallas_in_step = "tpu_custom_call" in step_text
     if not pallas_in_step:
         failed.append("no Pallas kernel in the engine's step program")
+    pool_copies = pool_sized_copies(
+        step_text, int(np.prod(eng.cache.pool_shape())))
+    if pool_copies:
+        failed.append(f"{len(pool_copies)} whole-pool copies in the "
+                      f"engine's step program: {pool_copies[0]}")
+    aliased = step.memory_analysis().alias_size_in_bytes
+    if aliased < eng.cache.per_chip_pool_bytes():
+        failed.append(f"the step aliases {aliased} bytes, less than the "
+                      f"pools' {eng.cache.per_chip_pool_bytes()}")
 
     # the ragged step's logits at the prompt's last position, as the
     # engine itself fetched them, against the dense forward
@@ -314,6 +353,8 @@ def serve_phase(widths: dict, engine: dict, prompt_lens, shared_prefix: int,
         "max_chunk_tokens": stats["max_chunk_tokens"],
         "cache_quiesced": True, "drain_exit_code": exit_code,
         "pallas_in_step": pallas_in_step,
+        "step_pool_sized_copies": len(pool_copies),
+        "step_aliased_bytes": aliased,
         "logit_err_share_of_max": round(logit_err, 5),
         "logit_tol": LOGIT_TOL,
         "tokens_equal_to_generate": f"{agree}/{ref.size}",

@@ -71,7 +71,7 @@ import numpy as np
 
 from paddle_tpu.core.module import Context, _CtxCore
 from paddle_tpu.engine.kvtier import HostKVTier, prefix_digest
-from paddle_tpu.engine.paged_cache import PagedKVCache
+from paddle_tpu.engine.paged_cache import PagedKVCache, pack_kv, unpack_kv
 from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
@@ -115,6 +115,62 @@ def serve_metadata(model) -> dict:
         # with a pool twice the size)
         "dtype": jnp.dtype(model.dtype).name,
     }
+
+
+def compile_steps(model, variables, compress: bool, serve_tp=None):
+    """The engine's two compiled entry points, `(step, copy_blocks)`:
+    the ONE ragged step for all traffic and the fixed-width COW replay.
+    Both take the pools DONATED: the call writes the buffers it was
+    handed and returns them, so a step holds one pool, not two, and the
+    handles passed in are dead afterwards (the engine assigns the
+    returned ones back before anything else runs). `variables` may be
+    shapes; `compress` says whether the int8 pools ride along.
+
+    Under tensor parallelism (`serve_tp`) the operand shardings are
+    pinned so every call reuses the same executable (TP004 / the
+    one-compile invariant): weights per serve_tp_rules, KV pools
+    sharded over their rows' kv-heads, int32 packing operands
+    replicated. Model code sees GLOBAL shapes; XLA partitions the ops,
+    and the explicit islands (sharded attention, the quantized fc2
+    reduce) run inside."""
+    step_sh, copy_sh = {}, {}
+    if serve_tp is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from paddle_tpu.parallel.sharding import serve_tp_rules
+        mesh = serve_tp.mesh
+        rep = NamedSharding(mesh, P())
+        nl = len(model.blocks)
+        pools_sh = [NamedSharding(mesh, P(None, None, "tp"))] * nl
+        # int8 pools shard like the fp pools; the per-block scales are
+        # head-independent scalars, replicated. Compression off -> empty
+        # lists, a stable pytree prefix.
+        step_sh = dict(
+            in_shardings=(serve_tp_rules().tree_shardings(mesh, variables),
+                          rep, rep, pools_sh, pools_sh if compress else [],
+                          [(rep, rep)] * nl if compress else [],
+                          rep, rep, rep, rep, rep, rep, rep),
+            out_shardings=(rep, pools_sh))
+        copy_sh = dict(in_shardings=(pools_sh, rep, rep),
+                       out_shardings=pools_sh)
+
+    @functools.partial(jax.jit, donate_argnums=(3,), **step_sh)
+    def _step_fn(variables, tokens, positions, pools, qpools, qscales,
+                 block_tables, context_lens, q_starts, tile_rows,
+                 tile_offs, slots, last_idx):
+        return model.ragged_step_paged(
+            _fresh_cx(variables), tokens, positions, pools,
+            block_tables, context_lens, q_starts, tile_rows,
+            tile_offs, slots, last_idx, tp=serve_tp,
+            qpools=qpools, qscales=qscales)
+
+    @functools.partial(jax.jit, donate_argnums=(0,), **copy_sh)
+    def _copy_blocks(pools, src, dst):
+        # COW replay: dst blocks take src blocks' contents, every
+        # layer; padding lanes are (0, 0) — scratch onto itself
+        return [pool.at[dst].set(pool[src]) for pool in pools]
+
+    return _step_fn, _copy_blocks
 
 
 def _sample(logits: np.ndarray, req: Request, pos: int
@@ -229,9 +285,8 @@ class ServeEngine:
                                    devices=devs[:self.tp_size])
             self._serve_tp = ServeTP(self._mesh, self.tp_size,
                                      mode=resolve_mode())
-            self._tp_rules = serve_tp_rules()
             variables = shard_variables(self._mesh, variables,
-                                        self._tp_rules)
+                                        serve_tp_rules())
         else:
             # weights live on the device: from_saved_model hands over
             # the checkpoint's host arrays, and a host operand would be
@@ -334,13 +389,11 @@ class ServeEngine:
             # scatter (_TIER_LANES-wide .at[].set) — with no-op writes
             # to scratch block 0, so the first real demotion/revival
             # never pays their one-time XLA compile mid-request.
-            kp0, vp0 = self.cache.pools[0]
+            pool0 = self.cache.pools[0]
             lanes = jnp.zeros((_TIER_LANES,), jnp.int32)
-            zero = jnp.zeros((_TIER_LANES,) + tuple(kp0.shape[1:]),
-                             kp0.dtype)
-            np.asarray(kp0[0])        # the demote gather's signature
-            self.cache.pools[0] = (kp0.at[lanes].set(zero),
-                                   vp0.at[lanes].set(zero))
+            np.asarray(pool0[0])      # the demote gather's signature
+            self.cache.pools[0] = pool0.at[lanes].set(
+                jnp.zeros((_TIER_LANES,) + pool0.shape[1:], pool0.dtype))
         if self.cache.compress_enabled:
             # prime the compressed tier's fixed-lane eager kernels —
             # the quantize scatter (compress), the dequantize scatter
@@ -350,23 +403,10 @@ class ServeEngine:
             # Eager fixed-shape ops like the _TIER_LANES revival path:
             # no new jit entry points, the step's cache stays at 1.
             lanes = jnp.zeros((_TIER_LANES,), jnp.int32)
-            kp0, vp0 = self.cache.pools[0]
-            kq0, vq0 = self.cache.qpools[0]
-            ks0, vs0 = self.cache.qscales[0]
-            kq8, ksc = quantize_block(kp0[lanes])
-            vq8, vsc = quantize_block(vp0[lanes])
-            self.cache.qpools[0] = (kq0.at[lanes].set(kq8),
-                                    vq0.at[lanes].set(vq8))
-            self.cache.qscales[0] = (ks0.at[lanes].set(ksc),
-                                     vs0.at[lanes].set(vsc))
-            kq0, vq0 = self.cache.qpools[0]
-            ks0, vs0 = self.cache.qscales[0]
-            kfp = dequantize_block(kq0[lanes], ks0[lanes], kp0.dtype)
-            vfp = dequantize_block(vq0[lanes], vs0[lanes], vp0.dtype)
-            self.cache.pools[0] = (kp0.at[lanes].set(kfp),
-                                   vp0.at[lanes].set(vfp))
-            np.asarray(kq0[0])        # the host-spill gather signatures
-            float(ks0[0])
+            self._quantize_lanes(0, lanes, lanes)
+            self._dequantize_lanes(0, lanes, lanes)
+            np.asarray(self.cache.qpools[0][0])   # the host-spill gathers
+            float(self.cache.qscales[0][0][0])
         self.max_blocks_per_seq = self.cache.blocks_for(self.max_seq_len)
         self.scheduler = Scheduler(
             self.cache, max_batch_size=max_batch_size,
@@ -395,64 +435,9 @@ class ServeEngine:
             self._m_allreduce.labels(mode=self._serve_tp.mode).observe(
                 self._allreduce_probe_ms)
 
-        model_ = model
-        serve_tp = self._serve_tp
-
-        if serve_tp is None:
-            jit_step = jax.jit
-            jit_copy = jax.jit
-        else:
-            # pin the ONE compiled step's operand shardings so every
-            # call reuses the same executable (TP004 / the one-compile
-            # invariant): weights per serve_tp_rules, KV pools sharded
-            # over kv-heads, int32 packing operands replicated. Model
-            # code sees GLOBAL shapes; XLA partitions the ops, and the
-            # explicit islands (sharded attention, the quantized fc2
-            # reduce) run inside.
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            rep = NamedSharding(self._mesh, P())
-            pool_s = NamedSharding(self._mesh, P(None, None, "tp", None))
-            nl = len(model.blocks)
-            var_sh = self._tp_rules.tree_shardings(self._mesh,
-                                                   self.variables)
-            pools_sh = [(pool_s, pool_s)] * nl
-            # int8 pools shard over kv-heads like the fp pools; the
-            # per-block scales are head-independent scalars, replicated.
-            # Compression off -> empty lists, a stable pytree prefix.
-            qpools_sh = ([(pool_s, pool_s)] * nl
-                         if self.cache.compress_enabled else [])
-            qscales_sh = ([(rep, rep)] * nl
-                          if self.cache.compress_enabled else [])
-            jit_step = functools.partial(
-                jax.jit,
-                in_shardings=(var_sh, rep, rep, pools_sh, qpools_sh,
-                              qscales_sh, rep, rep, rep, rep, rep, rep,
-                              rep),
-                out_shardings=(rep, pools_sh))
-            jit_copy = functools.partial(
-                jax.jit,
-                in_shardings=(pools_sh, rep, rep),
-                out_shardings=pools_sh)
-
-        @jit_step
-        def _step_fn(variables, tokens, positions, pools, qpools, qscales,
-                     block_tables, context_lens, q_starts, tile_rows,
-                     tile_offs, slots, last_idx):
-            return model_.ragged_step_paged(
-                _fresh_cx(variables), tokens, positions, pools,
-                block_tables, context_lens, q_starts, tile_rows,
-                tile_offs, slots, last_idx, tp=serve_tp,
-                qpools=qpools, qscales=qscales)
-
-        @jit_copy
-        def _copy_blocks(pools, src, dst):
-            # COW replay: dst blocks take src blocks' contents, every
-            # layer; padding lanes are (0, 0) — scratch onto itself
-            return [(kp.at[dst].set(kp[src]), vp.at[dst].set(vp[src]))
-                    for kp, vp in pools]
-
-        self._step_fn = _step_fn
-        self._copy_blocks = _copy_blocks
+        self._step_fn, self._copy_blocks = compile_steps(
+            model, self.variables, self.cache.compress_enabled,
+            self._serve_tp)
 
     # -- construction from an exported artifact ---------------------------
     @classmethod
@@ -794,9 +779,28 @@ class ServeEngine:
             dst = np.zeros((_COPY_LANES,), np.int32)
             for j, (s, d) in enumerate(batch):
                 src[j], dst[j] = s, d
-            self.cache.pools = self._copy_blocks(
-                self.cache.pools, jnp.asarray(src), jnp.asarray(dst))
+            self.cache.pools = self._donating(
+                self._copy_blocks, self.cache.pools, jnp.asarray(src),
+                jnp.asarray(dst))
         return len(copies)
+
+    def _donating(self, compiled, *operands):
+        """Call a compiled function that takes the pools donated. A
+        call that raises after it consumed them leaves deleted handles
+        and no KV on the device: rebuild the pools and send every
+        running request back to the queue to re-prefill (the preemption
+        path), then let the error through — the engine behind it serves
+        on (RESILIENCE.md "A failed donated step")."""
+        try:
+            return compiled(*operands)
+        except Exception:
+            if any(pool.is_deleted() for pool in self.cache.pools):
+                self.cache.reset_pools()
+                for req in reversed(list(self.scheduler.running)):
+                    self.scheduler.preempt(req)
+                serve_event("serve_pools_rebuilt", step=self.steps,
+                            requeued=self.scheduler.queue_depth)
+            raise
 
     def _flush_tier_loads(self) -> int:
         """Write staged host-tier revivals into the device pools —
@@ -814,16 +818,12 @@ class ServeEngine:
             for j, (b, _) in enumerate(batch):
                 idx[j] = b       # pad lanes write zeros to scratch block 0
             blocks = jnp.asarray(idx)
-            for li, (kp, vp) in enumerate(self.cache.pools):
-                kd = np.zeros((_TIER_LANES,) + tuple(kp.shape[1:]),
-                              np.float32)
-                vd = np.zeros((_TIER_LANES,) + tuple(vp.shape[1:]),
-                              np.float32)
+            for li, pool in enumerate(self.cache.pools):
+                rows = np.zeros((_TIER_LANES,) + pool.shape[1:], np.float32)
                 for j, (_, layers) in enumerate(batch):
-                    kd[j], vd[j] = layers[li]
-                self.cache.pools[li] = (
-                    kp.at[blocks].set(jnp.asarray(kd, kp.dtype)),
-                    vp.at[blocks].set(jnp.asarray(vd, vp.dtype)))
+                    rows[j] = pack_kv(*map(np.asarray, layers[li]))
+                self.cache.pools[li] = pool.at[blocks].set(
+                    jnp.asarray(rows, pool.dtype))
         return len(loads)
 
     def _flush_compress(self) -> int:
@@ -842,16 +842,32 @@ class ServeEngine:
             for j, (b, s) in enumerate(batch):
                 src[j], dst[j] = b, s
             bsrc, bdst = jnp.asarray(src), jnp.asarray(dst)
-            for li, (kp, vp) in enumerate(self.cache.pools):
-                kq, vq = self.cache.qpools[li]
-                ks, vs = self.cache.qscales[li]
-                kq8, ksc = quantize_block(kp[bsrc])
-                vq8, vsc = quantize_block(vp[bsrc])
-                self.cache.qpools[li] = (kq.at[bdst].set(kq8),
-                                         vq.at[bdst].set(vq8))
-                self.cache.qscales[li] = (ks.at[bdst].set(ksc),
-                                          vs.at[bdst].set(vsc))
+            for li in range(len(self.cache.pools)):
+                self._quantize_lanes(li, bsrc, bdst)
         return len(jobs)
+
+    def _quantize_lanes(self, li: int, blocks, slots) -> None:
+        """Layer `li`: fp blocks `blocks` -> int8 slots `slots`, k and v
+        each under its own per-block scale (eager, fixed width)."""
+        ks, vs = self.cache.qscales[li]
+        k, v = unpack_kv(self.cache.pools[li][blocks], self.cache.head_dim)
+        kq8, ksc = quantize_block(k)
+        vq8, vsc = quantize_block(v)
+        self.cache.qpools[li] = self.cache.qpools[li].at[slots].set(
+            pack_kv(kq8, vq8))
+        self.cache.qscales[li] = (ks.at[slots].set(ksc),
+                                  vs.at[slots].set(vsc))
+
+    def _dequantize_lanes(self, li: int, slots, blocks) -> None:
+        """Layer `li`: int8 slots `slots` -> fp blocks `blocks`, the
+        inverse of _quantize_lanes."""
+        pool = self.cache.pools[li]
+        ks, vs = self.cache.qscales[li]
+        kq, vq = unpack_kv(self.cache.qpools[li][slots],
+                           self.cache.head_dim)
+        self.cache.pools[li] = pool.at[blocks].set(pack_kv(
+            dequantize_block(kq, ks[slots], pool.dtype),
+            dequantize_block(vq, vs[slots], pool.dtype)))
 
     def _flush_promote(self) -> int:
         """Dequantize staged compressed-tier hits into their claimed fp
@@ -867,13 +883,8 @@ class ServeEngine:
             for j, (b, s) in enumerate(batch):
                 dst[j], src[j] = b, s
             bsrc, bdst = jnp.asarray(src), jnp.asarray(dst)
-            for li, (kp, vp) in enumerate(self.cache.pools):
-                kq, vq = self.cache.qpools[li]
-                ks, vs = self.cache.qscales[li]
-                kfp = dequantize_block(kq[bsrc], ks[bsrc], kp.dtype)
-                vfp = dequantize_block(vq[bsrc], vs[bsrc], vp.dtype)
-                self.cache.pools[li] = (kp.at[bdst].set(kfp),
-                                        vp.at[bdst].set(vfp))
+            for li in range(len(self.cache.pools)):
+                self._dequantize_lanes(li, bsrc, bdst)
         return len(jobs)
 
     @property
@@ -1028,7 +1039,8 @@ class ServeEngine:
                     tile_offs[t0 + k] = k * tq
                 cursor += ntiles * tq
         with annotate("engine.dispatch", step=step):
-            logits, self.cache.pools = self._step_fn(
+            logits, self.cache.pools = self._donating(
+                self._step_fn,
                 self.variables, jnp.asarray(tokens), jnp.asarray(positions),
                 self.cache.pools, self.cache.qpools, self.cache.qscales,
                 jnp.asarray(block_tables), jnp.asarray(context_lens),

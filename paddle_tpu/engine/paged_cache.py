@@ -3,12 +3,12 @@
 The HBM side of continuous batching (ENGINE.md): instead of one dense
 [B, Tmax, Hkv, hd] cache per batch slot — which reserves worst-case
 HBM for every request and welds batch membership to allocation — KV
-state lives in ONE pool of fixed-size token blocks per layer
-([num_blocks, block_size, Hkv, hd] for k and for v). A sequence owns a
-BLOCK TABLE (ordered list of pool block ids); growing a sequence
-appends a block from the free list, finishing/evicting one returns its
-blocks in O(blocks). Fragmentation is bounded at block_size-1 wasted
-slots per sequence, and admission capacity is a pure free-list check.
+state lives in ONE pool of fixed-size token blocks per layer. A
+sequence owns a BLOCK TABLE (ordered list of pool block ids); growing
+a sequence appends a block from the free list, finishing/evicting one
+returns its blocks in O(blocks). Fragmentation is bounded at
+block_size-1 wasted slots per sequence, and admission capacity is a
+pure free-list check.
 
 Prefix sharing (vLLM-style): blocks carry REFCOUNTS, and every FULL
 block whose KV content is actually in the pool is registered in a
@@ -51,12 +51,29 @@ quantize/dequantize run as the engine's fixed-lane eager scatters
 compressed entry ships its int8 payload + scales straight into the
 host tier without a second quantization.
 
+Pool layout (this module owns it; nothing else states a pool's shape):
+one array per layer, [num_blocks, block_size, Hkv * head_lanes(hd)].
+A token's row holds each kv head's K in lanes [0, hd) of the head's
+`head_lanes` and its V in lanes [hd, 2*hd), zero-padded up to a
+multiple of the TPU's 128 lanes (hd 64 -> 128 lanes a head, hd 128 ->
+256). With a lane-dense minor dimension the TPU's default device layout
+of that shape is plain row-major, which is what the step's flat scatter
+(`write_kv`) and the ragged kernel's per-block DMA both use: the step
+updates a donated pool IN PLACE. (A [blocks, bs, Hkv, 64] pool's default
+layout puts the blocks in the lanes, and every step transposed each
+pool into a padded row-major temporary and back.) `pack_kv` /
+`unpack_kv` translate between that row and per-head [.., Hkv, hd] K and
+V; the int8 pools take the same rule, their scales stay per block.
+Under tensor parallelism the row shards over its heads:
+P(None, None, "tp").
+
 Host/device split: this class is the HOST-side allocator + bookkeeping
 (free list, refcounts, per-sequence tables/lengths/tokens, prefix
-index). The device-side pools are jnp arrays held in `self.pools` and
-are updated FUNCTIONALLY — the jitted prefill-scatter / decode step /
-COW block copy return new pool arrays and the engine assigns them
-back. Nothing here traces into XLA; block tables cross into jit as
+index). The device-side pools are jnp arrays held in `self.pools`; the
+engine's compiled step and COW block copy take them DONATED and return
+them updated in place, and the engine assigns the new handles back (a
+handle the step consumed is deleted: nothing may hold one across a
+step). Nothing here traces into XLA; block tables cross into jit as
 plain int32 operands.
 
 Block 0 is reserved as a scratch block: padded batch rows (the engine
@@ -83,12 +100,51 @@ class CacheExhausted(Exception):
     """No free blocks; the scheduler must evict (preempt) a sequence."""
 
 
+_LANES = 128    # the TPU's lane count: a pool row is a multiple of it
+
+
+def head_lanes(head_dim: int) -> int:
+    """Lanes one kv head takes in a pool row: K then V side by side,
+    padded up to whole 128-lane tiles."""
+    return -(-2 * head_dim // _LANES) * _LANES
+
+
+def pack_kv(k, v):
+    """Per-head k and v, each [..., Hkv, hd], as pool rows
+    [..., Hkv * head_lanes(hd)] (numpy in, numpy out; jax in, jax
+    out)."""
+    xp = np if isinstance(k, np.ndarray) else jnp
+    hd = k.shape[-1]
+    pad = head_lanes(hd) - 2 * hd
+    parts = [k, v] + ([xp.zeros(k.shape[:-1] + (pad,), k.dtype)]
+                      if pad else [])
+    rows = xp.concatenate(parts, axis=-1)
+    return rows.reshape(k.shape[:-2] + (-1,))
+
+
+def unpack_kv(rows, head_dim: int):
+    """Inverse of pack_kv: pool rows [..., Hkv * head_lanes(hd)] ->
+    (k, v), each [..., Hkv, hd]."""
+    heads = rows.reshape(rows.shape[:-1] + (-1, head_lanes(head_dim)))
+    return heads[..., :head_dim], heads[..., head_dim:2 * head_dim]
+
+
+def write_kv(pool, slots, k, v):
+    """The step's write: token i's k/v [T, Hkv, hd] land in the pool's
+    flat row `slots[i]` (block_id * block_size + offset). One scatter
+    of whole rows; on a donated pool it runs in place."""
+    nb, bs, lanes = pool.shape
+    return pool.reshape(nb * bs, lanes).at[slots].set(
+        pack_kv(k, v).astype(pool.dtype)).reshape(pool.shape)
+
+
 class PagedKVCache:
     """Refcounted block-pool KV cache shared by all layers of one model.
 
     All layers allocate in lockstep (a token occupies the same slot in
     every layer's pool), so ONE free list / block table set serves the
-    whole stack; `pools` holds per-layer (k_pool, v_pool) arrays.
+    whole stack; `pools` holds one array per layer in the layout
+    `pool_shape` gives (module docstring, "Pool layout").
     """
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
@@ -123,17 +179,13 @@ class PagedKVCache:
         self.tp_size = tp_size
         self.enable_prefix_cache = enable_prefix_cache
         # pools are allocated at the GLOBAL shape; under tp the mesh
-        # shards the kv-head dim so each chip HOLDS pool_shape() bytes
-        shape = (num_blocks, block_size, num_kv_heads, head_dim)
-        self.pools: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
-            (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(num_layers)]
+        # shards the row's heads so each chip HOLDS pool_shape() bytes
+        self._num_layers = num_layers
+        self._sharding = None
         if mesh is not None and tp_size > 1:
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
-            ns = NamedSharding(mesh, P(None, None, "tp", None))
-            self.pools = [(jax.device_put(kp, ns), jax.device_put(vp, ns))
-                          for kp, vp in self.pools]
+            self._sharding = NamedSharding(mesh, P(None, None, "tp"))
+        self.pools: List[jnp.ndarray] = self.fresh_pools()
         # optional in-device compressed tier: a parallel int8 block pool
         # (+ per-block k/v scales) cold prefix content quantizes into at
         # ~half the bytes. Slot 0 is scratch (the fixed-lane flushes pad
@@ -141,26 +193,17 @@ class PagedKVCache:
         # updated FUNCTIONALLY by the engine's eager lane scatters.
         self.compress_blocks = int(compress_blocks)
         self._compress_on = self.compress_blocks > 0 and enable_prefix_cache
-        self.qpools: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
+        self.qpools: List[jnp.ndarray] = []
         self.qscales: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
         if self._compress_on:
-            qshape = (self.compress_blocks + 1, block_size,
-                      num_kv_heads, head_dim)
-            self.qpools = [(jnp.zeros(qshape, jnp.int8),
-                            jnp.zeros(qshape, jnp.int8))
+            qshape = (self.compress_blocks + 1,) + self.pool_shape(1)[1:]
+            self.qpools = [self._place(jnp.zeros(qshape, jnp.int8))
                            for _ in range(num_layers)]
             self.qscales = [(jnp.ones((self.compress_blocks + 1,),
                                       jnp.float32),
                              jnp.ones((self.compress_blocks + 1,),
                                       jnp.float32))
                             for _ in range(num_layers)]
-            if mesh is not None and tp_size > 1:
-                import jax
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                qns = NamedSharding(mesh, P(None, None, "tp", None))
-                self.qpools = [(jax.device_put(kq, qns),
-                                jax.device_put(vq, qns))
-                               for kq, vq in self.qpools]
         # compressed-tier bookkeeping (host-side): slot free list,
         # content-keyed LRU index (OrderedDict end = hottest), reverse
         # map, staged fixed-lane traffic, and the last-hit clock the
@@ -255,33 +298,69 @@ class PagedKVCache:
 
     # -- capacity ---------------------------------------------------------
     def pool_shape(self, tp_size: Optional[int] = None) -> Tuple[int, ...]:
-        """PER-CHIP shape of one k (or v) pool under `tp_size`-way
+        """PER-CHIP shape of one layer's pool under `tp_size`-way
         tensor parallelism (defaults to this cache's own tp_size): the
-        kv-head dim divides by tp, everything else replicates. tp=1 is
+        row's heads divide by tp, everything else replicates. tp=1 is
         the global shape. Sizing math (engine HBM planning,
         tools/paged_roofline.py --tp-size) goes through here so the
-        divisibility contract lives in ONE place."""
+        layout and the divisibility contract live in ONE place."""
         tp = self.tp_size if tp_size is None else tp_size
         if tp < 1 or self.num_kv_heads % tp != 0:
             raise ValueError(
                 f"num_kv_heads={self.num_kv_heads} not divisible by "
                 f"tp_size={tp}")
         return (self.num_blocks, self.block_size,
-                self.num_kv_heads // tp, self.head_dim)
+                self.num_kv_heads // tp * head_lanes(self.head_dim))
+
+    def _place(self, pool):
+        if self._sharding is None:
+            return pool
+        import jax
+        return jax.device_put(pool, self._sharding)
+
+    def fresh_pools(self) -> List[jnp.ndarray]:
+        """Zeroed pools of this cache's shape and placement: what the
+        constructor holds, and what the engine rebuilds after a step
+        that failed with the pools already donated."""
+        return [self._place(jnp.zeros(self.pool_shape(1), self.dtype))
+                for _ in range(self._num_layers)]
+
+    def reset_pools(self) -> None:
+        """The fp pools' content is lost (a step failed after it had
+        consumed the donated pools): fresh zeroed pools, and nothing on
+        them counts as committed any more — no sequence's prefix, no
+        cached-free block. The int8 pool and the host tier were not
+        donated and keep their content. The engine then preempts every
+        running sequence, and each re-prefills."""
+        self.pools = self.fresh_pools()
+        self._index.clear()
+        self._key_of.clear()
+        self._last_hit.clear()
+        self._committed = dict.fromkeys(self._committed, 0)
+
+    def _host_kv(self, rows) -> Tuple[np.ndarray, np.ndarray]:
+        """Device rows of one block -> its (k, v) on the host, each
+        [block_size, Hkv, hd] and contiguous (the tiers keep them)."""
+        k, v = unpack_kv(np.asarray(rows), self.head_dim)
+        return np.ascontiguousarray(k), np.ascontiguousarray(v)
+
+    def read_block(self, block: int) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """One block's per-layer (k, v) on the host: the host tier's
+        and the transfer plane's encoding."""
+        return [self._host_kv(pool[block]) for pool in self.pools]
 
     def per_chip_pool_bytes(self) -> int:
-        """Measured HBM bytes ONE chip holds across every layer's k+v
+        """Measured HBM bytes ONE chip holds across every layer's
         pool — read off the arrays' addressable shards, not computed,
         so the serve_bench tp gate checks what XLA actually allocated.
         Falls back to the full array size for unsharded pools."""
         total = 0
-        for kp, vp in self.pools:
-            for arr in (kp, vp):
-                shards = getattr(arr, "addressable_shards", None)
-                if shards:
-                    total += max(s.data.nbytes for s in shards)
-                else:
-                    total += arr.nbytes
+        for arr in self.pools:
+            shards = getattr(arr, "addressable_shards", None)
+            if shards:
+                total += max(s.data.nbytes for s in shards)
+            else:
+                total += arr.nbytes
         return total
 
     @property
@@ -350,9 +429,7 @@ class PagedKVCache:
                 return True
         if self.host_tier is None or self.host_tier.contains(key):
             return False
-        layers = [(np.asarray(kp[block]), np.asarray(vp[block]))
-                  for kp, vp in self.pools]
-        return self.host_tier.put(key, layers, reason=reason)
+        return self.host_tier.put(key, self.read_block(block), reason=reason)
 
     # -- in-device compressed tier ----------------------------------------
     def _stage_compress(self, block: int, key: tuple, slot: int) -> None:
@@ -409,10 +486,9 @@ class PagedKVCache:
         """One int8 slot's per-layer (kq, kscale, vq, vscale) payload —
         the device_int8 wire/tier encoding (kvtier.put_device_int8)."""
         qlayers = []
-        for li, (kq, vq) in enumerate(self.qpools):
-            ks, vs = self.qscales[li]
-            qlayers.append((np.asarray(kq[slot]), float(ks[slot]),
-                            np.asarray(vq[slot]), float(vs[slot])))
+        for qpool, (ks, vs) in zip(self.qpools, self.qscales):
+            kq, vq = self._host_kv(qpool[slot])
+            qlayers.append((kq, float(ks[slot]), vq, float(vs[slot])))
         return qlayers
 
     def compress_cold(self, idle_steps: int = 4,

@@ -2,11 +2,11 @@
 
 The serving-side sibling of kernels/flash.py. Online inference
 (engine/) stores each sequence's KV history as a list of fixed-size
-token blocks inside one shared pool ([num_blocks, block_size, Hkv, Dh]
-per layer), so admission/eviction never copies KV state and a ragged
-batch wastes at most block_size-1 slots per sequence ("Ragged Paged
-Attention", arxiv 2604.15464). Decode attention then has to gather K/V
-through the block table instead of slicing a dense [B, Tmax] cache.
+token blocks inside one shared pool per layer, so admission/eviction
+never copies KV state and a ragged batch wastes at most block_size-1
+slots per sequence ("Ragged Paged Attention", arxiv 2604.15464).
+Decode attention then has to gather K/V through the block table
+instead of slicing a dense [B, Tmax] cache.
 
 Two implementations with one contract (mirroring attention.py's
 flash/reference split):
@@ -25,9 +25,13 @@ flash/reference split):
   hardware (same policy as kernels/flash.py).
 
 Layout: q is [B, H, Dh] (one query token per sequence — decode);
-pools are [NB, BS, Hkv, Dh]; block_tables [B, MB] int32 pool-block
-ids; context_lens [B] int32 valid-token counts. GQA/MQA: Hkv may
-divide H; the grouped einsum reads each kv head once.
+the decode kernel and the chunked-prefill gather take per-head pools
+[NB, BS, Hkv, Dh] (views of the engine's pool: paged_cache.unpack_kv;
+no serving path runs them any more); block_tables [B, MB] int32
+pool-block ids; context_lens [B] int32 valid-token counts. GQA/MQA:
+Hkv may divide H; the grouped einsum reads each kv head once. The
+ragged kernel below — the engine's one step — reads the engine's pool
+as it lies, [NB, BS, Hkv * W] (its section comment has the row).
 """
 
 from __future__ import annotations
@@ -298,6 +302,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 #   row), and first-query positions. A decode row is simply q_len=1:
 #   q_start = ctx - 1.
 #
+# Pool operand: the engine's pool as it lies in HBM (engine/paged_cache.py
+# owns the layout): [NB, BS, Hkv * W], a token's row holding each kv
+# head's K in lanes [0, D) of the head's W lanes and its V in lanes
+# [D, 2D), W a multiple of 128. The kernel reads W off the shape
+# (lanes / (H / groups)); one DMA fetches a block's K and V together.
+# With q zero-padded to W lanes, q'.[k|v]^T = q.k^T, and p.[k|v]
+# carries p.v in lanes [D, 2D): no sub-tile lane slicing per block, and
+# a 128-deep contraction where head_dim 64 half-filled it.
+#
 # Masking is absolute-position causal AND context-bounded
 # (kv_pos <= q_pos, kv_pos < ctx — the paged_prefill_attention
 # contract), so decode rows, mid-prompt chunks and pad queries all fall
@@ -306,43 +319,58 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 # ---------------------------------------------------------------------------
 
 
-def _gather_mixed(pool, q_pool, scales, ids, neg):
+def _split_kv(rows, hkv: int, d: int):
+    """Pool rows [..., Hkv * W] -> (k, v), each [..., Hkv, D]."""
+    heads = rows.reshape(rows.shape[:-1] + (hkv, rows.shape[-1] // hkv))
+    return heads[..., :d], heads[..., d:2 * d]
+
+
+def _gather_mixed(pool, q_pool, k_scales, v_scales, ids, hkv: int, d: int):
     """Dense mixed-tier gather for the reference oracle: fp pool rows
     where the (bias-decoded) table entry is non-negative, per-block
-    dequantized int8 rows where it is. ids: [...] raw table entries;
-    neg = ids < 0. Dequant is the dequantize_block identity —
+    dequantized int8 rows where it is negative. ids: [...] raw table
+    entries. Dequant is the dequantize_block identity —
     (int8 -> f32) * (scale / QMAX), cast to the fp pool dtype — so a
     direct read returns exactly the bytes a promote would have
-    scattered."""
+    scattered. Returns (k, v), each [..., BS, Hkv, D]."""
+    neg = ids < 0
     fp_ids = jnp.where(neg, 0, ids)
     q_ids = jnp.where(neg, -ids - 1, 0)
-    dense = pool[fp_ids]                       # [..., BS, Hkv, D]
-    deq = (q_pool[q_ids].astype(jnp.float32)
-           * (scales[q_ids] * _RQMAX)[..., None, None, None]
-           ).astype(pool.dtype)
-    return jnp.where(neg[..., None, None, None], deq, dense)
+    sel = neg[..., None, None, None]
+    out = []
+    for dense, q8, scales in zip(
+            _split_kv(pool[fp_ids], hkv, d),
+            _split_kv(q_pool[q_ids], hkv, d), (k_scales, v_scales)):
+        deq = (q8.astype(jnp.float32)
+               * (scales[q_ids] * _RQMAX)[..., None, None, None]
+               ).astype(pool.dtype)
+        out.append(jnp.where(sel, deq, dense))
+    return tuple(out)
 
 
-def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
+def ragged_paged_attention_reference(q, kv_pool, block_tables,
                                      context_lens, q_starts, tile_rows,
                                      tile_offs,
                                      scale: Optional[float] = None,
-                                     kq_pool=None, vq_pool=None,
+                                     groups: int = 1,
+                                     kvq_pool=None,
                                      k_scales=None, v_scales=None):
     """XLA oracle for the ragged layout: expand tile metadata to
     per-token rows and run the dense gather + masked attention.
-    q: [T, H, D] flat-packed; returns [T, H, D].
+    q: [T, H, D] flat-packed; kv_pool: [NB, BS, Hkv * W] (the section
+    comment above); returns [T, H, D].
 
     Gathers [T, MB*BS, Hkv, D] — heavier than the per-row [B, ...]
     gathers above (every token re-gathers its row's blocks), but it is
     the off-TPU dispatch tier where T stays small (CPU smoke + tests),
     and XLA's masked softmax keeps it exactly batch-invariant.
 
-    With kq_pool/vq_pool (+[NQ] per-block k_scales/v_scales) the table
+    With kvq_pool (+[NQ] per-block k_scales/v_scales) the table
     entries are bias-encoded: id >= 0 reads the fp pool, id < 0 reads
     int8 slot -id-1 and dequantizes in place."""
     t, h, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    nb, bs, _ = kv_pool.shape
+    hkv = h // groups
     nt = tile_rows.shape[0]
     if t % nt:
         raise ValueError(f"flat length {t} not a multiple of {nt} tiles")
@@ -352,15 +380,13 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, block_tables,
     qpos = (jnp.repeat(q_starts[tile_rows] + tile_offs, tq)
             + jnp.tile(jnp.arange(tq, dtype=jnp.int32), nt))  # [T]
     bt = block_tables[row_of]                                # [T, MB]
-    if kq_pool is None:
-        k = k_pool[bt].reshape(t, mb * bs, hkv, d)
-        v = v_pool[bt].reshape(t, mb * bs, hkv, d)
+    if kvq_pool is None:
+        k, v = _split_kv(kv_pool[bt], hkv, d)
     else:
-        neg = bt < 0
-        k = _gather_mixed(k_pool, kq_pool, k_scales, bt, neg
-                          ).reshape(t, mb * bs, hkv, d)
-        v = _gather_mixed(v_pool, vq_pool, v_scales, bt, neg
-                          ).reshape(t, mb * bs, hkv, d)
+        k, v = _gather_mixed(kv_pool, kvq_pool, k_scales, v_scales, bt,
+                             hkv, d)
+    k = k.reshape(t, mb * bs, hkv, d)
+    v = v.reshape(t, mb * bs, hkv, d)
     kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
     ctx = context_lens[row_of]
     mask = ((kv_pos[None, :] <= qpos[:, None])
@@ -392,18 +418,28 @@ def _kv_major_to_rows(x, tq: int, groups: int):
             .reshape(tq * hkv * groups, last)
 
 
-def _ragged_tile_update(q, k, v, q0, ctx, j, m_scr, l_scr, acc_scr, *,
+def _block_heads(rows, hkv: int):
+    """One pool block [BS, Hkv * W] -> [Hkv, BS, W]: the lanes split
+    at whole 128-lane tiles, then kv heads lead (the batched matmuls'
+    operand layout)."""
+    bs, lanes = rows.shape
+    return jnp.transpose(rows.reshape(bs, hkv, lanes // hkv), (1, 0, 2))
+
+
+def _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr, *,
                         scale: float, block_size: int, groups: int):
     """Online-softmax update for one (query-tile, kv-block) cell —
     shared by the fp-only and mixed-precision ragged kernels. q:
-    [TQ, H, D]; k/v: [BS, Hkv, D]; scratch rows are flattened TQ*H."""
-    tq, h, d = q.shape
-    hkv = k.shape[1]
-    # batch over kv heads: [Hkv, TQ*G, D] x [Hkv, BS, D]
+    [TQ, H, W], zero beyond lane D; kv: [Hkv, BS, W], each head's
+    [k | v | pad]; scratch rows are flattened TQ*H, the accumulator W
+    lanes wide with p.v in lanes [D, 2D)."""
+    tq, h, _ = q.shape
+    hkv = kv.shape[0]
+    # batch over kv heads: [Hkv, TQ*G, W] x [Hkv, BS, W]; q's zero
+    # lanes drop v out of the contraction
     qg = _heads_to_kv_major(q, hkv, groups)
-    kt = jnp.transpose(k, (1, 0, 2))                # [Hkv, BS, D]
     s = jax.lax.dot_general(
-        qg, kt, (((2,), (2,)), ((0,), (0,))),
+        qg, kv, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale  # [Hkv, TQ*G, BS]
     s = _kv_major_to_rows(s, tq, groups)            # [TQ*H, BS]
     qpos = q0 + jax.lax.broadcasted_iota(
@@ -419,22 +455,31 @@ def _ragged_tile_update(q, k, v, q0, ctx, j, m_scr, l_scr, acc_scr, *,
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
     pg = _heads_to_kv_major(p.reshape(tq, h, block_size), hkv, groups)
-    vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, BS, D]
     pv = jax.lax.dot_general(
-        pg.astype(v.dtype), vt, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)         # [Hkv, TQ*G, D]
+        pg.astype(kv.dtype), kv, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)         # [Hkv, TQ*G, W]
     acc_scr[...] = alpha * acc_scr[...] + _kv_major_to_rows(pv, tq, groups)
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
+def _ragged_finalize(o_ref, l_scr, acc_scr):
+    """Normalize the accumulator's v lanes into the output tile
+    [TQ, H, D]."""
+    d = o_ref.shape[-1]
+    l = l_scr[...][:, :1]
+    o_ref[...] = (acc_scr[...][:, d:2 * d] / jnp.maximum(l, 1e-30)
+                  ).reshape(o_ref.shape).astype(o_ref.dtype)
+
+
 def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
-                   q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                   q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr, *,
                    scale: float, block_size: int, tile_q: int, groups: int):
-    """One (query-tile, kv-block) grid cell. q_ref: [TQ, H, D] — one
-    tile of the flat packing; k/v_ref: the pool block the index map
-    selected, [BS, Hkv, D]. Online-softmax scratch is flattened to
-    (TQ*H, ·) rows and persists across the sequential kv axis."""
+    """One (query-tile, kv-block) grid cell. q_ref: [TQ, H, W] — one
+    tile of the flat packing, lane-padded; kv_ref: the pool block the
+    index map selected, [BS, Hkv * W]. Online-softmax scratch is
+    flattened to (TQ*H, ·) rows and persists across the sequential kv
+    axis."""
     t, j = pl.program_id(0), pl.program_id(1)
     nblk = pl.num_programs(1)
     row = tr_ref[t]
@@ -451,20 +496,20 @@ def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
     # causal future of the tile's LAST query (position q0 + tile_q - 1)
     @pl.when((j * block_size < ctx) & (j * block_size <= q0 + tile_q - 1))
     def _compute():
-        _ragged_tile_update(q_ref[...], k_ref[...], v_ref[...], q0, ctx, j,
-                            m_scr, l_scr, acc_scr, scale=scale,
+        q = q_ref[...]
+        _ragged_tile_update(q, _block_heads(kv_ref[...],
+                                            q.shape[1] // groups),
+                            q0, ctx, j, m_scr, l_scr, acc_scr, scale=scale,
                             block_size=block_size, groups=groups)
 
     @pl.when(j == nblk - 1)
     def _finalize():
-        l = l_scr[...][:, :1]
-        o_ref[...] = (acc_scr[...] / jnp.maximum(l, 1e-30)).reshape(
-            o_ref.shape).astype(o_ref.dtype)
+        _ragged_finalize(o_ref, l_scr, acc_scr)
 
 
 def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
                          ksc_ref, vsc_ref,
-                         q_ref, k_ref, v_ref, kq_ref, vq_ref, o_ref,
+                         q_ref, kv_ref, kvq_ref, o_ref,
                          m_scr, l_scr, acc_scr, *,
                          scale: float, block_size: int, tile_q: int,
                          groups: int):
@@ -473,9 +518,10 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
     pools ride their own BlockSpec — each index map degenerates to slot
     0 for the tier it does NOT serve, so only the selected tier's DMA
     changes block-to-block — and the kernel dequantizes the int8 block
-    in registers with the per-block scale from scalar prefetch. The
-    dequant is bit-identical to quant.dequantize_block, which is what
-    pins direct-read output to the promote path's bytes."""
+    in registers with the per-block k and v scales from scalar
+    prefetch, each over its own lanes of a head. The dequant is
+    bit-identical to quant.dequantize_block, which is what pins
+    direct-read output to the promote path's bytes."""
     t, j = pl.program_id(0), pl.program_id(1)
     nblk = pl.num_programs(1)
     row = tr_ref[t]
@@ -493,40 +539,45 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
 
     @pl.when((j * block_size < ctx) & (j * block_size <= q0 + tile_q - 1))
     def _compute():
-        kf = k_ref[...]                                 # [BS, Hkv, D]
-        vf = v_ref[...]
-        kd = (kq_ref[...].astype(jnp.float32)
-              * (ksc_ref[slot] * _RQMAX)).astype(kf.dtype)
-        vd = (vq_ref[...].astype(jnp.float32)
-              * (vsc_ref[slot] * _RQMAX)).astype(vf.dtype)
-        k = jnp.where(is8, kd, kf)
-        v = jnp.where(is8, vd, vf)
-        _ragged_tile_update(q_ref[...], k, v, q0, ctx, j,
+        q = q_ref[...]
+        hkv, d = q.shape[1] // groups, o_ref.shape[-1]
+        fp = _block_heads(kv_ref[...], hkv)             # [Hkv, BS, W]
+        q8 = _block_heads(kvq_ref[...].astype(jnp.float32), hkv)
+        lane = jax.lax.broadcasted_iota(jnp.int32, q8.shape, 2)
+        sc = jnp.where(lane < d, ksc_ref[slot] * _RQMAX,
+                       vsc_ref[slot] * _RQMAX)
+        kv = jnp.where(is8, (q8 * sc).astype(fp.dtype), fp)
+        _ragged_tile_update(q, kv, q0, ctx, j,
                             m_scr, l_scr, acc_scr, scale=scale,
                             block_size=block_size, groups=groups)
 
     @pl.when(j == nblk - 1)
     def _finalize():
-        l = l_scr[...][:, :1]
-        o_ref[...] = (acc_scr[...] / jnp.maximum(l, 1e-30)).reshape(
-            o_ref.shape).astype(o_ref.dtype)
+        _ragged_finalize(o_ref, l_scr, acc_scr)
 
 
-def _ragged_kernel_call(q, k_pool, v_pool, block_tables, context_lens,
+def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
                         q_starts, tile_rows, tile_offs, scale,
-                        interpret: bool,
-                        kq_pool=None, vq_pool=None,
-                        k_scales=None, v_scales=None):
+                        interpret: bool, groups: int,
+                        kvq_pool=None, k_scales=None, v_scales=None):
     t, h, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    nb, bs, lanes = kv_pool.shape
     mb = block_tables.shape[1]
     nt = tile_rows.shape[0]
     if t % nt:
         raise ValueError(f"flat length {t} not a multiple of {nt} tiles")
     tq = t // nt
-    if h % hkv:
-        raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
-    mixed = kq_pool is not None
+    if h % groups:
+        raise ValueError(f"q heads {h} not a multiple of groups {groups}")
+    w = lanes // (h // groups)
+    if w < 2 * d or w * (h // groups) != lanes:
+        raise ValueError(
+            f"pool rows of {lanes} lanes do not hold {h // groups} kv "
+            f"heads of [k | v] at head_dim {d}")
+    mixed = kvq_pool is not None
+    # q in the head's full lane width: zero lanes meet v in the
+    # contraction
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, w - d)))
 
     def _active(ti, j, cl, qs, tr, to):
         # skip predicate shared by every kv index map: inactive cells
@@ -539,19 +590,19 @@ def _ragged_kernel_call(q, k_pool, v_pool, block_tables, context_lens,
 
     def _kv_block(ti, j, bt, cl, qs, tr, to):
         return (jnp.where(_active(ti, j, cl, qs, tr, to),
-                          bt[tr[ti], j], 0), 0, 0, 0)
+                          bt[tr[ti], j], 0), 0, 0)
 
     def _kv_fp(ti, j, bt, cl, qs, tr, to, ksc, vsc):
         # bias-encoded entry: only non-negative ids live in the fp pool
         e = bt[tr[ti], j]
         act = _active(ti, j, cl, qs, tr, to) & (e >= 0)
-        return (jnp.where(act, e, 0), 0, 0, 0)
+        return (jnp.where(act, e, 0), 0, 0)
 
     def _kv_q(ti, j, bt, cl, qs, tr, to, ksc, vsc):
         # negative ids decode to int8 pool slot -id-1
         e = bt[tr[ti], j]
         act = _active(ti, j, cl, qs, tr, to) & (e < 0)
-        return (jnp.where(act, -e - 1, 0), 0, 0, 0)
+        return (jnp.where(act, -e - 1, 0), 0, 0)
 
     if mixed:
         def _q_map(ti, j, bt, cl, qs, tr, to, ksc, vsc):
@@ -559,39 +610,34 @@ def _ragged_kernel_call(q, k_pool, v_pool, block_tables, context_lens,
         # block_tables, ctx_lens, q_starts, tiles x2, k/v scales
         num_prefetch = 7
         in_specs = [
-            pl.BlockSpec((tq, h, d), _q_map),
-            pl.BlockSpec((None, bs, hkv, d), _kv_fp),
-            pl.BlockSpec((None, bs, hkv, d), _kv_fp),
-            pl.BlockSpec((None, bs, hkv, d), _kv_q),
-            pl.BlockSpec((None, bs, hkv, d), _kv_q),
+            pl.BlockSpec((tq, h, w), _q_map),
+            pl.BlockSpec((None, bs, lanes), _kv_fp),
+            pl.BlockSpec((None, bs, lanes), _kv_q),
         ]
-        out_specs = pl.BlockSpec((tq, h, d), _q_map)
         kernel_fn = _ragged_kernel_mixed
     else:
         def _q_map(ti, j, bt, cl, qs, tr, to):
             return (ti, 0, 0)
         num_prefetch = 5  # block_tables, ctx_lens, q_starts, tiles x2
         in_specs = [
-            pl.BlockSpec((tq, h, d), _q_map),
-            pl.BlockSpec((None, bs, hkv, d), _kv_block),
-            pl.BlockSpec((None, bs, hkv, d), _kv_block),
+            pl.BlockSpec((tq, h, w), _q_map),
+            pl.BlockSpec((None, bs, lanes), _kv_block),
         ]
-        out_specs = pl.BlockSpec((tq, h, d), _q_map)
         kernel_fn = _ragged_kernel
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
         grid=(nt, mb),
         in_specs=in_specs,
-        out_specs=out_specs,
+        out_specs=pl.BlockSpec((tq, h, d), _q_map),
         scratch_shapes=[
             _scratch((tq * h, LANES)),
             _scratch((tq * h, LANES)),
-            _scratch((tq * h, d)),
+            _scratch((tq * h, w)),
         ],
     )
     kernel = functools.partial(kernel_fn, scale=scale, block_size=bs,
-                               tile_q=tq, groups=h // hkv)
+                               tile_q=tq, groups=groups)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -605,109 +651,96 @@ def _ragged_kernel_call(q, k_pool, v_pool, block_tables, context_lens,
                tile_offs.astype(jnp.int32))
     if mixed:
         return call(*scalars, k_scales.astype(jnp.float32),
-                    v_scales.astype(jnp.float32),
-                    q, k_pool, v_pool, kq_pool, vq_pool)
-    return call(*scalars, q, k_pool, v_pool)
+                    v_scales.astype(jnp.float32), q, kv_pool, kvq_pool)
+    return call(*scalars, q, kv_pool)
 
 
-def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
+def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
                            q_starts, tile_rows, tile_offs,
                            scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None,
-                           kq_pool=None, vq_pool=None,
-                           k_scales=None, v_scales=None):
+                           groups: int = 1,
+                           kvq_pool=None, k_scales=None, v_scales=None):
     """Mixed prefill+decode attention over the flat ragged packing —
-    the engine's single-step entry point. Dispatch tiers mirror
-    paged_attention: Pallas kernel on TPU, XLA reference elsewhere,
-    PTPU_PAGED_KERNEL / explicit flags override.
+    the engine's single-step entry point. q: [T, H, D]; kv_pool: one
+    layer's pool as the cache lays it out, [NB, BS, Hkv * W]; `groups`
+    is H / Hkv (the same on every tensor-parallel shard). Dispatch
+    tiers mirror paged_attention: Pallas kernel on TPU, XLA reference
+    elsewhere, PTPU_PAGED_KERNEL / explicit flags override.
 
-    When the engine's compressed tier is live it passes the int8 pools
-    (kq_pool/vq_pool [NQ, BS, Hkv, D]) and per-block scales ([NQ] f32),
-    and bias-encodes int8-resident blocks into block_tables (id < 0 ->
-    slot -id-1): those blocks are read in place — dequantized per block
-    inside the gather — instead of being promoted to fp first. The
-    signature is shape-stable across fp-only / mixed / all-int8 batches
-    so the jit cache stays at one entry (TP004)."""
+    When the engine's compressed tier is live it passes the int8 pool
+    (kvq_pool [NQ, BS, Hkv * W]) and per-block scales ([NQ] f32 each
+    for k and v), and bias-encodes int8-resident blocks into
+    block_tables (id < 0 -> slot -id-1): those blocks are read in
+    place — dequantized per block inside the gather — instead of being
+    promoted to fp first. The signature is shape-stable across fp-only
+    / mixed / all-int8 batches so the jit cache stays at one entry
+    (TP004)."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     use_kernel, interpret = _resolve_dispatch(use_kernel, interpret)
     if not use_kernel:
         return ragged_paged_attention_reference(
-            q, k_pool, v_pool, block_tables, context_lens, q_starts,
-            tile_rows, tile_offs, scale=scale,
-            kq_pool=kq_pool, vq_pool=vq_pool,
-            k_scales=k_scales, v_scales=v_scales)
-    return _ragged_kernel_call(q, k_pool, v_pool, block_tables,
+            q, kv_pool, block_tables, context_lens, q_starts,
+            tile_rows, tile_offs, scale=scale, groups=groups,
+            kvq_pool=kvq_pool, k_scales=k_scales, v_scales=v_scales)
+    return _ragged_kernel_call(q, kv_pool, block_tables,
                                context_lens, q_starts, tile_rows, tile_offs,
-                               scale, interpret,
-                               kq_pool=kq_pool, vq_pool=vq_pool,
+                               scale, interpret, groups,
+                               kvq_pool=kvq_pool,
                                k_scales=k_scales, v_scales=v_scales)
 
 
 # -- tensor-parallel wrappers (engine tp_size knob, ENGINE.md) ------------
 #
-# The ragged kernel derives num_heads / num_kv_heads / groups from its
-# INPUT shapes, so it runs unmodified on per-shard slices: shard q over
-# heads and the pools over kv-heads on the "tp" mesh axis and each chip
-# computes attention for its own contiguous head block. With both H and
-# Hkv divisible by tp, shard s's q-head block [s·H/tp, (s+1)·H/tp) maps
-# exactly onto its kv-head block (the local `head // groups` lookup is
-# unchanged: groups = H/Hkv is the same locally), so GQA groups stay
-# device-local and NO collective runs inside attention. Block tables /
-# context lens / packing metadata are tiny int32 operands — replicated.
+# The ragged kernel derives its head counts from its INPUT shapes and
+# `groups`, so it runs unmodified on per-shard slices: shard q over
+# heads and the pool rows over their kv heads on the "tp" mesh axis and
+# each chip computes attention for its own contiguous head block. With
+# both H and Hkv divisible by tp, shard s's q-head block
+# [s·H/tp, (s+1)·H/tp) maps exactly onto its kv-head block (the local
+# `head // groups` lookup is unchanged: groups = H/Hkv is the same
+# locally), so GQA groups stay device-local and NO collective runs
+# inside attention. Block tables / context lens / packing metadata are
+# tiny int32 operands — replicated.
 
 
-def ragged_paged_attention_tp(mesh, q, k_pool, v_pool, block_tables,
+def ragged_paged_attention_tp(mesh, q, kv_pool, block_tables,
                               context_lens, q_starts, tile_rows, tile_offs,
                               scale: Optional[float] = None,
                               use_kernel: Optional[bool] = None,
                               interpret: Optional[bool] = None,
-                              kq_pool=None, vq_pool=None,
-                              k_scales=None, v_scales=None):
+                              groups: int = 1,
+                              kvq_pool=None, k_scales=None, v_scales=None):
     """`ragged_paged_attention` as an explicit shard_map island over
-    the "tp" axis of `mesh` — q [T, H, D] sharded on H, pools sharded
-    on Hkv, everything else replicated; output [T, H, D] stays sharded
-    on H (the downstream out_proj is row-parallel over the same
-    axis). The int8 pools shard on Hkv exactly like the fp pools;
-    per-block scales are head-independent scalars, replicated."""
+    the "tp" axis of `mesh` — q [T, H, D] sharded on H, pool rows
+    sharded on their heads, everything else replicated; output
+    [T, H, D] stays sharded on H (the downstream out_proj is
+    row-parallel over the same axis). The int8 pool shards exactly
+    like the fp pool; per-block scales are head-independent scalars,
+    replicated."""
     from jax.sharding import PartitionSpec as P
 
     from jax import shard_map
 
-    if kq_pool is None:
-        def body(q_, kp, vp, bt, cl, qs, tr, to):
-            return ragged_paged_attention(q_, kp, vp, bt, cl, qs, tr, to,
-                                          scale=scale, use_kernel=use_kernel,
-                                          interpret=interpret)
+    heads, rows = P(None, "tp", None), P(None, None, "tp")
+    quant = () if kvq_pool is None else (kvq_pool, k_scales, v_scales)
 
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P(None, "tp", None),
-                                P(None, None, "tp", None),
-                                P(None, None, "tp", None),
-                                P(), P(), P(), P(), P()),
-                      out_specs=P(None, "tp", None), check_vma=False)
-        return f(q, k_pool, v_pool, block_tables, context_lens, q_starts,
-                 tile_rows, tile_offs)
-
-    def body(q_, kp, vp, bt, cl, qs, tr, to, kq, vq, ks, vs):
-        return ragged_paged_attention(q_, kp, vp, bt, cl, qs, tr, to,
+    def body(q_, pool, bt, cl, qs, tr, to, *quant_):
+        kvq, ks, vs = quant_ or (None,) * 3
+        return ragged_paged_attention(q_, pool, bt, cl, qs, tr, to,
                                       scale=scale, use_kernel=use_kernel,
-                                      interpret=interpret,
-                                      kq_pool=kq, vq_pool=vq,
-                                      k_scales=ks, v_scales=vs)
+                                      interpret=interpret, groups=groups,
+                                      kvq_pool=kvq, k_scales=ks,
+                                      v_scales=vs)
 
     f = shard_map(body, mesh=mesh,
-                  in_specs=(P(None, "tp", None),
-                            P(None, None, "tp", None),
-                            P(None, None, "tp", None),
-                            P(), P(), P(), P(), P(),
-                            P(None, None, "tp", None),
-                            P(None, None, "tp", None),
-                            P(), P()),
-                  out_specs=P(None, "tp", None), check_vma=False)
-    return f(q, k_pool, v_pool, block_tables, context_lens, q_starts,
-             tile_rows, tile_offs, kq_pool, vq_pool, k_scales, v_scales)
+                  in_specs=(heads, rows, P(), P(), P(), P(), P())
+                  + ((rows, P(), P()) if quant else ()),
+                  out_specs=heads, check_vma=False)
+    return f(q, kv_pool, block_tables, context_lens, q_starts,
+             tile_rows, tile_offs, *quant)
 
 
 def paged_prefill_attention_tp(mesh, q, k_pool, v_pool, block_tables,

@@ -21,6 +21,7 @@ TPU-first design:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -183,14 +184,16 @@ class MultiHeadAttention(Module):
         out = self.out_proj(cx, out)
         return (out, cache) if cache is not None else (out, None)
 
-    def decode_paged(self, cx: Context, x, k_pool, v_pool, block_tables,
+    def decode_paged(self, cx: Context, x, kv_pool, block_tables,
                      context_lens, slots):
         """Single-token decode through a PAGED KV cache (engine/ serving
-        path). x: [B, 1, D]; k_pool/v_pool: [NB, BS, Hkv, hd] shared block
-        pools; block_tables: [B, MB] int32; context_lens: [B] int32 valid
+        path). x: [B, 1, D]; kv_pool: this layer's shared block pool in
+        the cache's layout (engine/paged_cache.py owns it: write_kv
+        scatters into it, unpack_kv views it per head); block_tables:
+        [B, MB] int32; context_lens: [B] int32 valid
         tokens per sequence INCLUDING this one; slots: [B] int32 flat pool
         slot (block_id * BS + offset) where this token's k/v lands.
-        Returns (out [B, 1, D], (new_k_pool, new_v_pool)). Unlike the
+        Returns (out [B, 1, D], new_kv_pool). Unlike the
         dense `cache=` path, every sequence in the batch may sit at a
         DIFFERENT position — the whole point of continuous batching."""
         # self-scope like Embedding.attend: this is not routed through
@@ -205,21 +208,17 @@ class MultiHeadAttention(Module):
             qh = self._split(self.q_proj(cx, x))
             kh = self._split_kv(self.k_proj(cx, x))
             vh = self._split_kv(self.v_proj(cx, x))
-        nb, bs = k_pool.shape[:2]
-        flat = (nb * bs,) + k_pool.shape[2:]
-        k_pool = k_pool.reshape(flat).at[slots].set(
-            kh[:, 0].astype(k_pool.dtype)).reshape(k_pool.shape)
-        v_pool = v_pool.reshape(flat).at[slots].set(
-            vh[:, 0].astype(v_pool.dtype)).reshape(v_pool.shape)
+        from paddle_tpu.engine.paged_cache import unpack_kv, write_kv
         from paddle_tpu.kernels import paged_attention as paged
-        out = paged.paged_attention(qh[:, 0], k_pool, v_pool, block_tables,
-                                    context_lens)        # [B, H, hd]
+        kv_pool = write_kv(kv_pool, slots, kh[:, 0], vh[:, 0])
+        out = paged.paged_attention(
+            qh[:, 0], *unpack_kv(kv_pool, self.head_dim), block_tables,
+            context_lens)                                # [B, H, hd]
         out = self.out_proj(cx, out.reshape(x.shape[0], 1, self.model_dim))
-        return out, (k_pool, v_pool)
+        return out, kv_pool
 
-    def prefill_chunk_paged(self, cx: Context, x, q_positions, k_pool,
-                            v_pool, block_tables, context_lens, slots,
-                            tp=None):
+    def prefill_chunk_paged(self, cx: Context, x, q_positions, kv_pool,
+                            block_tables, context_lens, slots, tp=None):
         """CHUNKED prefill through a paged KV cache (the serving path's
         suffix-only prefill). x: [B, C, D] — a window of each prompt,
         not necessarily starting at position 0 (prefix-cache hits skip
@@ -229,7 +228,7 @@ class MultiHeadAttention(Module):
         k/v is scattered into the pool FIRST, then every chunk query
         attends causally through the block table — over the cached
         prefix and the chunk itself in one go. Returns
-        (out [B, C, D], (new_k_pool, new_v_pool)).
+        (out [B, C, D], new_kv_pool).
 
         `tp` (parallel.serve_collective.ServeTP or None) routes the
         attention through an explicit shard_map island over the mesh's
@@ -245,15 +244,12 @@ class MultiHeadAttention(Module):
             qh = self._split(self.q_proj(cx, x))
             kh = self._split_kv(self.k_proj(cx, x))
             vh = self._split_kv(self.v_proj(cx, x))
-        nb, bs = k_pool.shape[:2]
-        flat = (nb * bs,) + k_pool.shape[2:]
-        k_pool = k_pool.reshape(flat).at[slots].set(
-            kh.reshape((-1,) + kh.shape[2:]).astype(k_pool.dtype)
-        ).reshape(k_pool.shape)
-        v_pool = v_pool.reshape(flat).at[slots].set(
-            vh.reshape((-1,) + vh.shape[2:]).astype(v_pool.dtype)
-        ).reshape(v_pool.shape)
+        from paddle_tpu.engine.paged_cache import unpack_kv, write_kv
         from paddle_tpu.kernels import paged_attention as paged
+        kv_pool = write_kv(kv_pool, slots,
+                           kh.reshape((-1,) + kh.shape[2:]),
+                           vh.reshape((-1,) + vh.shape[2:]))
+        k_pool, v_pool = unpack_kv(kv_pool, self.head_dim)
         if tp is not None:
             out = paged.paged_prefill_attention_tp(
                 tp.mesh, qh, k_pool, v_pool, block_tables, context_lens,
@@ -264,9 +260,9 @@ class MultiHeadAttention(Module):
                 q_positions)                               # [B, C, H, hd]
         b, c = x.shape[:2]
         out = self.out_proj(cx, out.reshape(b, c, self.model_dim))
-        return out, (k_pool, v_pool)
+        return out, kv_pool
 
-    def ragged_step_paged(self, cx: Context, x, k_pool, v_pool,
+    def ragged_step_paged(self, cx: Context, x, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
                           tile_offs, slots, tp=None, qpool=None):
         """Mixed prefill+decode step over the FLAT ragged packing
@@ -274,10 +270,11 @@ class MultiHeadAttention(Module):
         — decode rows and prefill chunks packed into tile-aligned
         segments, no batch axis. The step's k/v is scattered into the
         pool at `slots` [T] first (pad positions land in scratch
-        block 0), then one attention launch serves every row. Returns
-        (out [T, D], (new_k_pool, new_v_pool)). `tp` routes attention
+        block 0; in place when the caller donates the pool), then one
+        attention launch reads the pool as it lies and serves every
+        row. Returns (out [T, D], new_kv_pool). `tp` routes attention
         through the sharded island (see prefill_chunk_paged).
-        `qpool` = (kq, vq, k_scales, v_scales) threads this layer's
+        `qpool` = (kvq, k_scales, v_scales) threads this layer's
         int8 compressed tier into the launch: bias-encoded (negative)
         block-table entries read it in place. Writes always target the
         fp pool — slots never point at int8 blocks."""
@@ -294,28 +291,20 @@ class MultiHeadAttention(Module):
                                             self.head_dim)
             vh = self.v_proj(cx, x).reshape(t, self.num_kv_heads,
                                             self.head_dim)
-        nb, bs = k_pool.shape[:2]
-        flat = (nb * bs,) + k_pool.shape[2:]
-        k_pool = k_pool.reshape(flat).at[slots].set(
-            kh.astype(k_pool.dtype)).reshape(k_pool.shape)
-        v_pool = v_pool.reshape(flat).at[slots].set(
-            vh.astype(v_pool.dtype)).reshape(v_pool.shape)
+        from paddle_tpu.engine.paged_cache import write_kv
         from paddle_tpu.kernels import paged_attention as paged
-        kq, vq, ksc, vsc = qpool if qpool is not None else (None,) * 4
-        if tp is not None:
-            out = paged.ragged_paged_attention_tp(
-                tp.mesh, qh, k_pool, v_pool, block_tables, context_lens,
-                q_starts, tile_rows, tile_offs,
-                kq_pool=kq, vq_pool=vq,
-                k_scales=ksc, v_scales=vsc)                # [T, H, hd]
-        else:
-            out = paged.ragged_paged_attention(
-                qh, k_pool, v_pool, block_tables, context_lens, q_starts,
-                tile_rows, tile_offs,
-                kq_pool=kq, vq_pool=vq,
-                k_scales=ksc, v_scales=vsc)                # [T, H, hd]
+        kv_pool = write_kv(kv_pool, slots, kh, vh)
+        kvq, ksc, vsc = qpool if qpool is not None else (None,) * 3
+        attend = (paged.ragged_paged_attention if tp is None else
+                  functools.partial(paged.ragged_paged_attention_tp,
+                                    tp.mesh))
+        out = attend(qh, kv_pool, block_tables, context_lens, q_starts,
+                     tile_rows, tile_offs,
+                     groups=self.num_heads // self.num_kv_heads,
+                     kvq_pool=kvq, k_scales=ksc,
+                     v_scales=vsc)                         # [T, H, hd]
         out = self.out_proj(cx, out.reshape(t, self.model_dim))
-        return out, (k_pool, v_pool)
+        return out, kv_pool
 
 
 class FeedForward(Module):
@@ -402,15 +391,14 @@ class DecoderLayer(Module):
         x = x + self.drop(cx, self.ffn(cx, self.ln3(cx, x)))
         return x, new_cache
 
-    def decode_paged(self, cx: Context, x, memory, k_pool, v_pool,
+    def decode_paged(self, cx: Context, x, memory, kv_pool,
                      block_tables, context_lens, slots, cross_mask=None):
         """Paged self-attention decode step + dense cross-attention over
         `memory` (encoder states stay dense — they are written once at
         admission and never grow)."""
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        h, pools = self.self_attn.decode_paged(cx, self.ln1(cx, x), k_pool,
-                                               v_pool, block_tables,
-                                               context_lens, slots)
+        h, pools = self.self_attn.decode_paged(
+            cx, self.ln1(cx, x), kv_pool, block_tables, context_lens, slots)
         x = x + self.drop(cx, h)
         h, _ = self.cross_attn(cx, self.ln2(cx, x), kv=memory,
                                mask=cross_mask)
@@ -516,7 +504,7 @@ class Transformer(Module):
                           pools, block_tables, context_lens, slots,
                           src_mask=None):
         """Continuous-batching decode for the encoder-decoder stack:
-        paged self-attention KV (per-layer (k_pool, v_pool) in `pools`),
+        paged self-attention KV (one pool per layer in `pools`),
         per-sequence `positions` [B] int32, dense cross-attention over
         `memory`. Returns (logits [B, V], new pools). The Transformer
         analog of CausalLM.decode_step_paged."""
@@ -524,8 +512,8 @@ class Transformer(Module):
         pe = sinusoid_position_encoding(self.max_len, self.model_dim)
         x = x + pe[positions.astype(jnp.int32)].astype(x.dtype)[:, None]
         new_pools = []
-        for layer, (k_pool, v_pool) in zip(self.dec_layers, pools):
-            x, np_ = layer.decode_paged(cx, x, memory, k_pool, v_pool,
+        for layer, kv_pool in zip(self.dec_layers, pools):
+            x, np_ = layer.decode_paged(cx, x, memory, kv_pool,
                                         block_tables, context_lens, slots,
                                         cross_mask=src_mask)
             new_pools.append(np_)
@@ -560,22 +548,21 @@ class CausalBlock(Module):
         x = x + self.drop(cx, self.ffn(cx, self.ln2(cx, x)))
         return x, nc
 
-    def decode_paged(self, cx: Context, x, k_pool, v_pool, block_tables,
+    def decode_paged(self, cx: Context, x, kv_pool, block_tables,
                      context_lens, slots):
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        h, pools = self.attn.decode_paged(cx, self.ln1(cx, x), k_pool,
-                                          v_pool, block_tables,
-                                          context_lens, slots)
+        h, pools = self.attn.decode_paged(cx, self.ln1(cx, x), kv_pool,
+                                          block_tables, context_lens,
+                                          slots)
         x = x + self.drop(cx, h)
         x = x + self.drop(cx, self.ffn(cx, self.ln2(cx, x)))
         return x, pools
 
-    def prefill_chunk_paged(self, cx: Context, x, q_positions, k_pool,
-                            v_pool, block_tables, context_lens, slots,
-                            tp=None):
+    def prefill_chunk_paged(self, cx: Context, x, q_positions, kv_pool,
+                            block_tables, context_lens, slots, tp=None):
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
         h, pools = self.attn.prefill_chunk_paged(
-            cx, self.ln1(cx, x), q_positions, k_pool, v_pool,
+            cx, self.ln1(cx, x), q_positions, kv_pool,
             block_tables, context_lens, slots, tp=tp)
         x = x + self.drop(cx, h)
         f = (self.ffn.forward_serve_tp(cx, self.ln2(cx, x), tp)
@@ -583,12 +570,12 @@ class CausalBlock(Module):
         x = x + self.drop(cx, f)
         return x, pools
 
-    def ragged_step_paged(self, cx: Context, x, k_pool, v_pool,
+    def ragged_step_paged(self, cx: Context, x, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
                           tile_offs, slots, tp=None, qpool=None):
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
         h, pools = self.attn.ragged_step_paged(
-            cx, self.ln1(cx, x), k_pool, v_pool, block_tables,
+            cx, self.ln1(cx, x), kv_pool, block_tables,
             context_lens, q_starts, tile_rows, tile_offs, slots, tp=tp,
             qpool=qpool)
         x = x + self.drop(cx, h)
@@ -753,8 +740,8 @@ class CausalLM(Module):
         pos_safe = jnp.clip(pos, 0, self.max_len - 1)
         x = x + pe[pos_safe].astype(x.dtype)
         new_pools = []
-        for blk, (k_pool, v_pool) in zip(self.blocks, pools):
-            x, np_ = blk.prefill_chunk_paged(cx, x, pos, k_pool, v_pool,
+        for blk, kv_pool in zip(self.blocks, pools):
+            x, np_ = blk.prefill_chunk_paged(cx, x, pos, kv_pool,
                                              block_tables, context_lens,
                                              slots, tp=tp)
             new_pools.append(np_)
@@ -792,14 +779,9 @@ class CausalLM(Module):
         pos_safe = jnp.clip(positions.astype(jnp.int32), 0, self.max_len - 1)
         x = x + pe[pos_safe].astype(x.dtype)
         new_pools = []
-        for li, (blk, (k_pool, v_pool)) in enumerate(zip(self.blocks,
-                                                         pools)):
-            qpool = None
-            if qpools:
-                kq, vq = qpools[li]
-                ksc, vsc = qscales[li]
-                qpool = (kq, vq, ksc, vsc)
-            x, np_ = blk.ragged_step_paged(cx, x, k_pool, v_pool,
+        for li, (blk, kv_pool) in enumerate(zip(self.blocks, pools)):
+            qpool = (qpools[li],) + tuple(qscales[li]) if qpools else None
+            x, np_ = blk.ragged_step_paged(cx, x, kv_pool,
                                            block_tables, context_lens,
                                            q_starts, tile_rows, tile_offs,
                                            slots, tp=tp, qpool=qpool)
@@ -814,15 +796,15 @@ class CausalLM(Module):
                           block_tables, context_lens, slots):
         """Continuous-batching decode step: tokens [B] ids, positions [B]
         int32 (PER-SEQUENCE positions — rows decode at different depths),
-        pools: per-layer (k_pool, v_pool) block pools, block_tables
+        pools: one block pool per layer, block_tables
         [B, MB], context_lens [B] (= positions + 1), slots [B] flat pool
         slots for this token's k/v. Returns (logits [B, V], new pools)."""
         x = self.embed(cx, tokens[:, None]) * math.sqrt(self.model_dim)
         pe = sinusoid_position_encoding(self.max_len, self.model_dim)
         x = x + pe[positions.astype(jnp.int32)].astype(x.dtype)[:, None]
         new_pools = []
-        for blk, (k_pool, v_pool) in zip(self.blocks, pools):
-            x, np_ = blk.decode_paged(cx, x, k_pool, v_pool, block_tables,
+        for blk, kv_pool in zip(self.blocks, pools):
+            x, np_ = blk.decode_paged(cx, x, kv_pool, block_tables,
                                       context_lens, slots)
             new_pools.append(np_)
         return self._head(cx, self.ln_f(cx, x))[:, 0], new_pools

@@ -50,6 +50,13 @@ def _compiles_with_kernel(fn, *args) -> bool:
     return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _pool_rows(nb, bs, kv_heads, head_dim, dtype, sharding):
+    """One layer's pool as the cache lays it out, as a shape."""
+    from paddle_tpu.engine.paged_cache import head_lanes
+    return jax.ShapeDtypeStruct((nb, bs, kv_heads * head_lanes(head_dim)),
+                                dtype, sharding=sharding)
+
+
 @pytest.mark.parametrize("heads,kv_heads,head_dim,mixed", [
     (16, 16, 64, False),     # GPT-2 medium: bf16 MHA at head_dim 64
     (16, 16, 64, True),
@@ -67,21 +74,147 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, head_dim,
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    pool = s((nb, bs, kv_heads, head_dim), jnp.bfloat16)
-    args = [s((t, heads, head_dim), jnp.bfloat16), pool, pool,
+    args = [s((t, heads, head_dim), jnp.bfloat16),
+            _pool_rows(nb, bs, kv_heads, head_dim, jnp.bfloat16, one_chip),
             s((rows, mb), jnp.int32), s((rows,), jnp.int32),
             s((rows,), jnp.int32), s((t // tq,), jnp.int32),
             s((t // tq,), jnp.int32)]
     if mixed:
-        qpool = s((nq, bs, kv_heads, head_dim), jnp.int8)
-        args += [qpool, qpool, s((nq,), jnp.float32), s((nq,), jnp.float32)]
+        args += [_pool_rows(nq, bs, kv_heads, head_dim, jnp.int8, one_chip),
+                 s((nq,), jnp.float32), s((nq,), jnp.float32)]
 
-    def fn(q, kp, vp, bt, cl, qs, tr, to, kq=None, vq=None, ks=None,
-           vs=None):
+    def fn(q, kv, bt, cl, qs, tr, to, kvq=None, ks=None, vs=None):
         return ragged_paged_attention(
-            q, kp, vp, bt, cl, qs, tr, to, use_kernel=True, interpret=False,
-            kq_pool=kq, vq_pool=vq, k_scales=ks, v_scales=vs)
+            q, kv, bt, cl, qs, tr, to, use_kernel=True, interpret=False,
+            groups=heads // kv_heads, kvq_pool=kvq, k_scales=ks,
+            v_scales=vs)
     assert _compiles_with_kernel(fn, *args)
+
+
+def _pool_sized_copies(text: str, pool) -> list:
+    """chip_smoke.py's reading of a compiled program (it prints the same
+    count on the real chip): the `copy` instructions whose result has
+    a pool's element count, each a whole-pool relayout."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+    return chip_smoke.pool_sized_copies(text, pool.size)
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,blocks,batch,compress", [
+    (16, 16, 64, 3072, 32, 0),    # gpt2m-chat's pool
+    (20, 20, 64, 1280, 16, 0),    # gpt2l-docs's pool
+    (4, 4, 64, 3072, 32, 0),      # medium's tp=4 per-chip slice
+    (5, 5, 64, 1280, 16, 0),      # large's: 5 heads x 128 lanes a chip
+    (32, 4, 128, 2048, 8, 0),     # GQA, 256 lanes a head
+    (16, 16, 64, 3072, 32, 256),  # with the int8 tier's pools beside
+], ids=["gpt2m_chat", "gpt2l_docs", "gpt2m_tp4_slice", "gpt2l_tp4_slice",
+        "gqa32_4x128", "gpt2m_chat-int8_mixed"])
+def test_engine_step_updates_the_pool_in_place(one_chip, heads, kv_heads,
+                                               head_dim, blocks, batch,
+                                               compress):
+    """The engine's real step, compiled for the described chip at the
+    serving cells' pool sizes (two layers, so it compiles in seconds):
+    the pools are aliased to the step's outputs and no whole-pool
+    relayout is in the program. A [blocks, 16, heads, 64] pool cost two
+    transposes through a padded temporary per pool per step (96 copies
+    in gpt2m-chat's 24 layers, 144 in gpt2l-docs's 36), donated or
+    not; this is the guard against their return."""
+    from unittest import mock
+
+    from paddle_tpu.engine.engine import ServeEngine
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.transformer import CausalLM
+
+    model = CausalLM(vocab=512, model_dim=heads * head_dim, num_heads=heads,
+                     num_kv_heads=kv_heads, num_layers=2,
+                     ffn_dim=2 * heads * head_dim, dropout=0.0, max_len=1024,
+                     dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    # the engine itself lives on the CPU with a few blocks: the step's
+    # program depends on the pools' sizes only through its operands
+    eng = ServeEngine(model,
+                      jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
+                                   shapes),
+                      max_batch_size=batch, block_size=16, num_blocks=8,
+                      max_prefill_tokens=512, tile_q=8,
+                      kv_compress_blocks=compress)
+
+    def on_chip(x, lead=None):
+        return jax.ShapeDtypeStruct(
+            ((lead or x.shape[0]),) + x.shape[1:], x.dtype,
+            sharding=one_chip)
+    t, nt, b = eng.flat_tokens, eng.num_tiles, eng.max_batch_size
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    pools = [on_chip(x, blocks) for x in eng.cache.pools]
+    # this process sees the CPU and the dispatcher would take its XLA
+    # tier: steered here, in the test, to the tier the chip takes
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = eng._step_fn.lower(
+            jax.tree.map(on_chip, eng.variables), i32(t), i32(t), pools,
+            jax.tree.map(on_chip, eng.cache.qpools),
+            jax.tree.map(on_chip, eng.cache.qscales),
+            i32(b + 1, eng.max_blocks_per_seq), i32(b + 1), i32(b + 1),
+            i32(nt), i32(nt), i32(t), i32(b, eng.spec_len)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _pool_sized_copies(text, pools[0]) == []
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+@pytest.mark.parametrize("heads,blocks,batch,mode", [
+    (16, 3072, 32, "fp"),       # medium over four chips: 4 heads a chip
+    (20, 1280, 16, "int8"),     # large: 5 heads x 128 lanes a chip
+], ids=["gpt2m-fp_allreduce", "gpt2l-int8_allreduce"])
+def test_tp4_step_updates_its_pool_shards_in_place(topo, heads, blocks,
+                                                   batch, mode):
+    """The same guard for the tensor-parallel step: the engine's own
+    `compile_steps` over a mesh of the four described chips. Each chip's
+    shard of every pool (its heads' lanes) is aliased, and no chip
+    relays its shard out."""
+    from unittest import mock
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.engine.engine import compile_steps
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.transformer import CausalLM
+    from paddle_tpu.parallel.mesh import MeshConfig, make_mesh
+    from paddle_tpu.parallel.serve_collective import ServeTP
+
+    mesh = make_mesh(MeshConfig(tp=4), devices=topo.devices)
+    model = CausalLM(vocab=512, model_dim=heads * 64, num_heads=heads,
+                     num_layers=2, ffn_dim=128 * heads, dropout=0.0,
+                     max_len=1024, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    step, _ = compile_steps(model, shapes, False, ServeTP(mesh, 4, mode=mode))
+    t = 512 + batch * 8
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    pools = [_pool_rows(blocks, 16, heads, 64, jnp.bfloat16,
+                        NamedSharding(mesh, P(None, None, "tp")))] * 2
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = step.lower(
+            shapes, i32(t), i32(t), pools, [], [], i32(batch + 1, 64),
+            i32(batch + 1), i32(batch + 1), i32(t // 8), i32(t // 8), i32(t),
+            i32(batch, 1)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    shard = jax.ShapeDtypeStruct((blocks, 16, pools[0].shape[2] // 4),
+                                 jnp.bfloat16)
+    assert _pool_sized_copies(text, shard) == []
+    assert _pool_sized_copies(text, pools[0]) == []
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= 2 * shard.size * 2)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

@@ -1,7 +1,8 @@
 """chip_smoke.py cannot rot between chip runs: its phase functions run
 here at toy widths on the CPU, and its main() refuses to run off the
 chip. On the CPU the Pallas tiers are not compiled in, so exactly the
-two kernel-presence gates fail — which also shows that they fire."""
+two kernel-presence gates fail — which also shows that they fire.
+The reader of whole-pool copies is shown to fire on a relayout's line."""
 
 import os
 import sys
@@ -37,6 +38,11 @@ def test_phases_run_at_toy_widths_on_cpu(monkeypatch):
         "no Pallas kernel in the engine's step program"], serve
     assert serve["streams_complete"] == serve["requests"] == 7
     assert serve["engine_compiles"] == 1 and serve["mixed_steps"] > 0
+    # the compiled step's own account: the donated pools updated in
+    # place (the CPU compiles them so too; the relayout this guards
+    # against is the TPU's: tests/test_chip_compile.py)
+    assert serve["step_pool_sized_copies"] == 0
+    assert serve["step_aliased_bytes"] >= serve["kv_pool_bytes_per_chip"] > 0
     assert serve["hit_tokens"] >= 12          # three full shared blocks
     assert serve["max_chunk_tokens"] <= 32    # the 40/70 prompts chunked
     # float32 on one backend: far inside the bf16 bound, and greedy
@@ -51,6 +57,21 @@ def test_phases_run_at_toy_widths_on_cpu(monkeypatch):
         "no flash kernel in the trainer's step program"], train
     assert train["train_compiles"] == 1
     assert train["losses"][-1] < train["losses"][0]
+
+
+def test_pool_sized_copies_reads_a_relayout():
+    """The two lines a [3072, 16, 16, 64] pool left in the step program
+    (PR 25's tree, compiled for a described v5e), and lines that are no
+    whole-pool copy."""
+    text = """
+  %copy.5 = bf16[3072,16,16,64]{3,2,1,0:T(8,128)(2,1)} copy(%pools_0__0_.1), sharding={replicated}
+  %copy.22 = bf16[3072,16,16,64]{0,3,2,1:T(8,128)(2,1)} copy(%bitcast.4), backend_config={}
+  %copy.7 = bf16[768,1024]{1,0:T(8,128)(2,1)} copy(%fusion.3)
+  %scatter.1 = bf16[49152,2048]{1,0:T(8,128)(2,1)} scatter(%bitcast.9, %slots, %rows)
+"""
+    found = chip_smoke.pool_sized_copies(text, 3072 * 16 * 16 * 64)
+    assert len(found) == 2 and all("copy(" in line for line in found)
+    assert chip_smoke.pool_sized_copies(text, 3072 * 16 * 2048) == []
 
 
 def test_main_refuses_to_run_off_the_chip(capsys):

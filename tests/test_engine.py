@@ -416,7 +416,7 @@ def test_from_saved_model_keeps_compute_dtype(tmp_path):
     kw = dict(max_batch_size=2, block_size=4, num_blocks=32)
     eng = ServeEngine.from_saved_model(path, **kw)
     assert eng.model.dtype == jnp.bfloat16
-    assert eng.cache.pools[0][0].dtype == jnp.bfloat16
+    assert eng.cache.pools[0].dtype == jnp.bfloat16
     # the checkpoint's host arrays were placed on the device once
     assert all(isinstance(x, jax.Array)
                for x in jax.tree.leaves(eng.variables))
@@ -428,7 +428,7 @@ def test_from_saved_model_keeps_compute_dtype(tmp_path):
     sig_path.write_text(json.dumps(sig))
     old = ServeEngine.from_saved_model(path, **kw)
     assert old.model.dtype == jnp.float32
-    assert old.cache.pools[0][0].dtype == jnp.float32
+    assert old.cache.pools[0].dtype == jnp.float32
 
 
 def test_old_manifest_without_serve_block(model_and_vars, tmp_path):
@@ -445,3 +445,206 @@ def test_old_manifest_without_serve_block(model_and_vars, tmp_path):
     assert out[0].shape == (1, 4, VOCAB)
     with pytest.raises(ValueError, match="serve"):
         ServeEngine.from_saved_model(path)
+
+
+# -- the pool's layout and its donation (engine/paged_cache.py owns both) --
+
+# (kv heads, head_dim): a head padded up to 128 lanes, one that fills
+# them exactly (the GPT-2 cells' shape), one padded up to 256
+LAYOUT_SHAPES = [(4, 8), (2, 64), (3, 96)]
+
+
+@pytest.mark.parametrize("hkv,hd", LAYOUT_SHAPES)
+def test_pool_rows_hold_k_and_v_side_by_side(hkv, hd):
+    from paddle_tpu.engine.paged_cache import (head_lanes, pack_kv,
+                                               unpack_kv, write_kv)
+    rng = np.random.default_rng(hkv * hd)
+    k = rng.standard_normal((5, hkv, hd)).astype(np.float32)
+    v = rng.standard_normal((5, hkv, hd)).astype(np.float32)
+    lanes = head_lanes(hd)
+    assert lanes % 128 == 0 and 2 * hd <= lanes < 2 * hd + 128
+    rows = pack_kv(k, v)
+    assert rows.shape == (5, hkv * lanes)
+    heads = rows.reshape(5, hkv, lanes)
+    assert (heads[..., :hd] == k).all() and (heads[..., hd:2 * hd] == v).all()
+    assert not heads[..., 2 * hd:].any()
+    back = unpack_kv(rows, hd)
+    assert (back[0] == k).all() and (back[1] == v).all()
+    # the step's write: whole rows land at their flat slots, nothing else
+    cache = PagedKVCache(num_layers=1, num_blocks=4, block_size=4,
+                         num_kv_heads=hkv, head_dim=hd)
+    assert cache.pools[0].shape == cache.pool_shape() == (4, 4, hkv * lanes)
+    slots = jnp.asarray([6, 3, 15, 8, 9], jnp.int32)
+    pool = np.asarray(write_kv(cache.pools[0], slots, jnp.asarray(k),
+                               jnp.asarray(v))).reshape(16, -1)
+    assert (pool[np.asarray(slots)] == rows).all()
+    assert not np.delete(pool, np.asarray(slots), axis=0).any()
+
+
+def _engine_with_a_known_block(hkv, hd, **kw):
+    """A small engine whose block 3 holds seeded k/v in every layer,
+    written through the cache's own write; returns (engine, per-layer
+    (k, v) of that block)."""
+    from paddle_tpu.engine.paged_cache import write_kv
+    model = CausalLM(vocab=VOCAB, model_dim=hkv * hd, num_heads=hkv,
+                     num_layers=2, ffn_dim=32, dropout=0.0, max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, num_blocks=16, **kw)
+    rng = np.random.default_rng(7)
+    want = []
+    for li, pool in enumerate(eng.cache.pools):
+        k = rng.standard_normal((4, hkv, hd)).astype(np.float32)
+        v = rng.standard_normal((4, hkv, hd)).astype(np.float32)
+        eng.cache.pools[li] = write_kv(pool, jnp.arange(12, 16), k, v)
+        want.append((k, v))
+    return eng, want
+
+
+def _assert_block(eng, block, want):
+    for (k, v), (wk, wv) in zip(eng.cache.read_block(block), want):
+        assert k.shape == wk.shape and (k == wk).all() and (v == wv).all()
+
+
+KEY = (1, 2, 3, 4)
+
+
+def _round_trip_cow(hkv, hd):
+    eng, want = _engine_with_a_known_block(hkv, hd)
+    consumed = list(eng.cache.pools)
+    eng.cache._pending_copies.append((3, 5))
+    assert eng._flush_cow() == 1
+    assert all(p.is_deleted() for p in consumed)    # donated to the copy
+    _assert_block(eng, 5, want)
+    _assert_block(eng, 3, want)
+
+
+def _round_trip_host_tier(hkv, hd):
+    eng, want = _engine_with_a_known_block(hkv, hd, host_tier_bytes=1 << 22)
+    assert eng.cache._demote_block(3, KEY, "evict")
+    eng.cache._pending_host_loads.append((6, eng.host_tier.get(KEY)))
+    assert eng._flush_tier_loads() == 1
+    _assert_block(eng, 6, want)
+
+
+def _round_trip_compress_promote(hkv, hd):
+    from paddle_tpu.quant.int8_compute import (dequantize_block,
+                                               quantize_block)
+    eng, want = _engine_with_a_known_block(hkv, hd, kv_compress_blocks=4)
+    eng.cache._pending_compress.append((3, 1))
+    assert eng._flush_compress() == 1
+    eng.cache._pending_promotes.append((7, 1))
+    assert eng._flush_promote() == 1
+    spilled = eng.cache._slot_qlayers(1)
+    for (k, v), (wk, wv), (kq, ks, vq, vs) in zip(
+            eng.cache.read_block(7), want, spilled):
+        for got, src, q8, scale in ((k, wk, kq, ks), (v, wv, vq, vs)):
+            # k and v each under its own per-block scale, one quant step
+            q, s = quantize_block(jnp.asarray(src))
+            assert (np.asarray(q) == q8).all() and float(s) == scale
+            assert (got == np.asarray(
+                dequantize_block(q, s, jnp.float32))).all()
+            assert np.abs(got - src).max() <= scale / 127 + 1e-7
+
+
+def _round_trip_kvxfer(hkv, hd):
+    from paddle_tpu.engine import prefix_digest
+    from paddle_tpu.serve.kvxfer import decode_entry, encode_tier_blob
+    src, want = _engine_with_a_known_block(hkv, hd, host_tier_bytes=1 << 22)
+    dst, _ = _engine_with_a_known_block(hkv, hd, host_tier_bytes=1 << 22)
+    assert src.cache._demote_block(3, KEY, "finish")
+    payload = encode_tier_blob(src.host_tier, prefix_digest(KEY))
+    key, blobs, nbytes = decode_entry(payload, int8=False)
+    assert key == KEY and dst.host_tier.insert_encoded(key, blobs, nbytes)
+    dst.cache._pending_host_loads.append((9, dst.host_tier.get(KEY)))
+    assert dst._flush_tier_loads() == 1
+    _assert_block(dst, 9, want)
+
+
+@pytest.mark.parametrize("hkv,hd", LAYOUT_SHAPES[:2])
+@pytest.mark.parametrize("path", [
+    _round_trip_cow, _round_trip_host_tier, _round_trip_compress_promote,
+    _round_trip_kvxfer], ids=lambda f: f.__name__[12:])
+def test_block_round_trip(path, hkv, hd):
+    """One block's k/v through every way a block leaves and re-enters
+    the pool: each goes through the cache's view of a block, so none
+    knows the pool's layout."""
+    path(hkv, hd)
+
+
+def _dense_logprob(model, variables, prompt, generated):
+    seq = jnp.asarray([prompt + generated], jnp.int32)
+    logp = jax.nn.log_softmax(
+        model.apply(variables, seq, training=False)[0].astype(jnp.float32))
+    return float(sum(logp[len(prompt) - 1 + i, t]
+                     for i, t in enumerate(generated)))
+
+
+@pytest.mark.parametrize("tier", ["reference", "interpret"])
+def test_step_consumes_the_pools_and_serves_the_same(model_and_vars, tier,
+                                                     monkeypatch):
+    """Every step takes the pools donated: the handles it was given are
+    deleted, the engine holds the ones it returned, and no path (flushes,
+    demotion, revival, snapshots) reads a consumed one. Tokens equal
+    model.generate's and the log-probabilities the dense forward's: the
+    numbers the split [blocks, bs, heads, hd] pools gave (CHANGES.md,
+    PR 26, has the parent's side by side)."""
+    monkeypatch.setenv("PTPU_PAGED_KERNEL", tier)
+    model, variables = model_and_vars
+    eng = _engine(model, variables, num_blocks=12, host_tier_bytes=1 << 22,
+                  kv_compress_blocks=6)
+    reqs = [eng.add_request(p, max_new_tokens=5) for p in PROMPTS]
+    while eng.scheduler.has_work():
+        before = list(eng.cache.pools)
+        assert eng.step()
+        assert all(p.is_deleted() for p in before)
+        assert not any(p.is_deleted() for p in eng.cache.pools)
+        eng.debug_state(), eng.kv_prefix_directory()
+        assert eng.cache.per_chip_pool_bytes() > 0
+    for r, p in zip(reqs, PROMPTS):
+        want = np.asarray(model.generate(
+            variables, jnp.asarray([p], jnp.int32), num_steps=5))[0, -5:]
+        assert r.generated == want.tolist()
+        assert r.logprob_sum == pytest.approx(
+            _dense_logprob(model, variables, p, r.generated), abs=1e-4)
+    # churn the 11-block pool so that blocks demote, compress and revive
+    # between donated steps
+    for i in range(6):
+        eng.generate([[20 + i] * 9 + [1, 2]], max_new_tokens=3)
+    again = eng.generate([PROMPTS[3]], max_new_tokens=5)[0]
+    assert again == reqs[3].generated
+    assert eng._step_fn._cache_size() == 1
+    eng.cache.assert_quiesced()
+
+
+def test_failed_donated_step_leaves_a_serving_engine(model_and_vars):
+    """A step that raises after it consumed the donated pools: the
+    engine rebuilds them, sends what was running back to the queue, and
+    lets the error through; the next calls serve every request, the
+    interrupted ones included, with the tokens an undisturbed engine
+    gives."""
+    model, variables = model_and_vars
+    want = _engine(model, variables).generate(PROMPTS, max_new_tokens=6)
+    eng = _engine(model, variables)
+    reqs = [eng.add_request(p, max_new_tokens=6) for p in PROMPTS]
+    for _ in range(3):
+        eng.step()
+    assert any(r.generated for r in reqs)
+    real = eng._step_fn
+
+    def falls_over(*operands):
+        real(*operands)                 # the pools are consumed ...
+        raise RuntimeError("the device fell over")     # ... then this
+    eng._step_fn = falls_over
+    with pytest.raises(RuntimeError, match="fell over"):
+        eng.step()
+    eng._step_fn = real
+    assert not any(p.is_deleted() for p in eng.cache.pools)
+    assert not eng.scheduler.running and eng.scheduler.queue_depth == 4
+    assert eng.cache.used_blocks == 0 and not eng.cache.prefix_keys()
+    eng.run()
+    assert [eng._generated_of(r) for r in reqs] == want
+    late = eng.generate([[9, 9, 8]], max_new_tokens=4)
+    assert late == _engine(model, variables).generate([[9, 9, 8]],
+                                                      max_new_tokens=4)
+    eng.cache.assert_quiesced()

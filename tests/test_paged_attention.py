@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.engine.paged_cache import head_lanes, pack_kv, unpack_kv
 from paddle_tpu.kernels.attention import reference_attention
 from paddle_tpu.kernels.paged_attention import (
     paged_attention, paged_attention_reference, ragged_paged_attention,
@@ -133,8 +134,9 @@ def _ragged_case(rows, h, hkv, d, bs, tq, seed=0, extra_pad_tiles=1):
     (context_len, q_len): each row's queries are the window
     [ctx - q_len, ctx) of its sequence — q_len=1 is a decode row,
     q_len=ctx a whole prompt, anything between a mid-prompt chunk.
-    Returns the ragged operands plus the dense k/v and per-row dense
-    queries for the oracle."""
+    Returns the ragged operands (the pool in the cache's layout: K and
+    V of a head side by side in one lane-dense row) plus the dense k/v
+    and per-row dense queries for the oracle."""
     b = len(rows)
     tmax = max(ctx for ctx, _ in rows)
     rng = np.random.default_rng(seed)
@@ -163,7 +165,9 @@ def _ragged_case(rows, h, hkv, d, bs, tq, seed=0, extra_pad_tiles=1):
             tile_rows[cursor // tq + t] = i
             tile_offs[cursor // tq + t] = t * tq
         cursor += -(-qlen // tq) * tq
-    args = (jnp.asarray(qflat), k_pool, v_pool, jnp.asarray(bt),
+    kv_pool = pack_kv(k_pool, v_pool)
+    assert kv_pool.shape == (k_pool.shape[0], bs, hkv * head_lanes(d))
+    args = (jnp.asarray(qflat), kv_pool, jnp.asarray(bt),
             jnp.asarray(cl), jnp.asarray(qs), jnp.asarray(tile_rows),
             jnp.asarray(tile_offs))
     return args, k, v, qrows, spans
@@ -192,13 +196,18 @@ RAGGED_MIXED_CASES = [
     ([(7, 3), (11, 1)], 8, 2, 16, 4, 4),              # GQA 4:1
     ([(12, 5), (3, 1)], 4, 1, 8, 8, 4),               # MQA
     ([(16, 16)], 4, 4, 8, 4, 8),                      # block-aligned, tq 8
+    # the serving cells' head shapes: 128 lanes a head, rows of 2,048
+    # and 2,560 lanes; and GQA at 256 lanes a head
+    ([(21, 1), (37, 9)], 16, 16, 64, 16, 8),          # GPT-2 medium
+    ([(33, 17), (5, 1)], 20, 20, 64, 16, 8),          # GPT-2 large
+    ([(19, 3), (40, 1)], 8, 2, 128, 16, 8),           # GQA 4:1 x 128
 ]
 
 
 @pytest.mark.parametrize("rows,h,hkv,d,bs,tq", RAGGED_MIXED_CASES)
 def test_ragged_reference_matches_dense(rows, h, hkv, d, bs, tq):
     args, k, v, qrows, spans = _ragged_case(rows, h, hkv, d, bs, tq)
-    got = ragged_paged_attention_reference(*args)
+    got = ragged_paged_attention_reference(*args, groups=h // hkv)
     for i, (off, qlen) in enumerate(spans):
         want = _ragged_dense_oracle(k, v, qrows, rows)[i]
         np.testing.assert_allclose(got[off:off + qlen], want,
@@ -211,8 +220,9 @@ def test_ragged_kernel_matches_reference(rows, h, hkv, d, bs, tq):
     mixed batches — decode rows, mid-prompt chunks, pad slack and GQA
     head groups in one launch."""
     args, k, v, qrows, spans = _ragged_case(rows, h, hkv, d, bs, tq)
-    got = ragged_paged_attention(*args, use_kernel=True, interpret=True)
-    want = ragged_paged_attention_reference(*args)
+    got = ragged_paged_attention(*args, use_kernel=True, interpret=True,
+                                 groups=h // hkv)
+    want = ragged_paged_attention_reference(*args, groups=h // hkv)
     assert bool(jnp.isfinite(got).all())    # pad queries/tiles stay finite
     for off, qlen in spans:
         np.testing.assert_allclose(got[off:off + qlen],
@@ -226,7 +236,8 @@ def test_ragged_decode_rows_match_decode_kernel():
     paged_attention on the same pools."""
     rows = [(5, 1), (8, 1), (3, 1)]
     args, k, v, qrows, spans = _ragged_case(rows, 4, 4, 8, 4, 4)
-    qflat, k_pool, v_pool, bt, cl, qs, tr, to = args
+    qflat, kv_pool, bt, cl, qs, tr, to = args
+    k_pool, v_pool = unpack_kv(kv_pool, 8)
     got = ragged_paged_attention_reference(*args)
     qb = jnp.stack([qrows[i][0] for i in range(3)])    # [B, H, D]
     want = paged_attention_reference(qb, k_pool, v_pool, bt[:3], cl[:3])
@@ -276,9 +287,9 @@ def _quantize_some_blocks(args, which="odd"):
     bar is that these two produce byte-identical output."""
     from paddle_tpu.quant.int8_compute import dequantize_block, \
         quantize_block
-    (qf, k_pool, v_pool, bt, cl, qs, tr, to) = args
+    (qf, kv_pool, bt, cl, qs, tr, to) = args
+    k_pool, v_pool = unpack_kv(kv_pool, qf.shape[-1])
     bt = np.asarray(bt).copy()
-    nb = k_pool.shape[0]
     # referenced (row, j) entries with full blocks only: quantizing a
     # block that the row writes into would be invalid upstream, but at
     # kernel level any referenced block is fair game — pick by parity.
@@ -317,13 +328,13 @@ def _quantize_some_blocks(args, which="odd"):
             b = int(bt[i, j])
             if b in slot_of:
                 bt_mixed[i, j] = -(slot_of[b] + 1)
-    qkw = dict(kq_pool=jnp.asarray(np.stack(kq)),
-               vq_pool=jnp.asarray(np.stack(vq)),
+    qkw = dict(kvq_pool=jnp.asarray(pack_kv(np.stack(kq), np.stack(vq))),
                k_scales=jnp.asarray(ksc, jnp.float32),
-               v_scales=jnp.asarray(vsc, jnp.float32))
-    mixed = ((qf, k_pool, v_pool, jnp.asarray(bt_mixed), cl, qs, tr, to),
-             qkw)
-    promoted = ((qf, jnp.asarray(k_pro), jnp.asarray(v_pro),
+               v_scales=jnp.asarray(vsc, jnp.float32),
+               groups=qf.shape[1] * head_lanes(qf.shape[-1])
+               // kv_pool.shape[-1])
+    mixed = ((qf, kv_pool, jnp.asarray(bt_mixed), cl, qs, tr, to), qkw)
+    promoted = ((qf, jnp.asarray(pack_kv(k_pro, v_pro)),
                  jnp.asarray(bt), cl, qs, tr, to), qkw)
     return mixed, promoted, len(picks)
 
@@ -337,7 +348,7 @@ def test_ragged_mixed_reference_bit_exact_vs_promote(rows, h, hkv, d,
     args, *_ = _ragged_case(rows, h, hkv, d, bs, tq)
     (margs, qkw), (pargs, _), n = _quantize_some_blocks(args)
     got = ragged_paged_attention_reference(*margs, **qkw)
-    want = ragged_paged_attention_reference(*pargs)
+    want = ragged_paged_attention_reference(*pargs, groups=h // hkv)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -349,7 +360,8 @@ def test_ragged_mixed_kernel_bit_exact_vs_promote(rows, h, hkv, d, bs, tq):
     (margs, qkw), (pargs, _), n = _quantize_some_blocks(args)
     got = ragged_paged_attention(*margs, use_kernel=True, interpret=True,
                                  **qkw)
-    want = ragged_paged_attention(*pargs, use_kernel=True, interpret=True)
+    want = ragged_paged_attention(*pargs, use_kernel=True, interpret=True,
+                                  groups=h // hkv)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -374,9 +386,7 @@ def test_ragged_fp_only_through_mixed_signature_bit_exact():
     batches must not pay a numeric (or recompile) cost."""
     rows = [(7, 1), (10, 6), (4, 4)]
     args, *_ = _ragged_case(rows, 4, 4, 8, 4, 4)
-    nb = args[1].shape[1:]
-    qkw = dict(kq_pool=jnp.zeros((2,) + nb, jnp.int8),
-               vq_pool=jnp.zeros((2,) + nb, jnp.int8),
+    qkw = dict(kvq_pool=jnp.zeros((2,) + args[1].shape[1:], jnp.int8),
                k_scales=jnp.ones((2,), jnp.float32),
                v_scales=jnp.ones((2,), jnp.float32))
     ref_fp = ragged_paged_attention_reference(*args)

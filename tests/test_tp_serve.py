@@ -149,8 +149,10 @@ class TestPoolSharding:
     def test_pool_shape_divides_kv_heads(self):
         c = PagedKVCache(num_blocks=8, block_size=4, num_layers=1,
                          num_kv_heads=4, head_dim=8)
-        assert c.pool_shape() == (8, 4, 4, 8)
-        assert c.pool_shape(2) == (8, 4, 2, 8)
+        # rows of kv_heads x head_lanes(8) = 128 lanes; tp divides the heads
+        assert c.pool_shape() == (8, 4, 4 * 128)
+        assert c.pool_shape(2) == (8, 4, 2 * 128)
+        assert c.pools[0].shape == c.pool_shape()
         with pytest.raises(ValueError):
             c.pool_shape(3)
         with pytest.raises(ValueError):

@@ -2,16 +2,18 @@
 
 Sweeps (block_size x num_blocks) cells and reports, per cell:
 
-- pool_gb:    KV pool footprint = layers * 2 * NB * BS * Hkv * Dh * 2B
-              (bf16 K and V planes per layer), and the fraction of the
+- pool_gb:    KV pool footprint = layers * NB * BS * Hkv * W * 2B, W =
+              engine/paged_cache.py head_lanes(Dh): a head's K and V
+              side by side in one bf16 row, padded to whole 128-lane
+              tiles (2 * Dh at Dh 64 and 128), and the fraction of the
               rig's HBM it claims (--hbm-gb).
 - capacity:   tokens the pool can hold (NB * BS) and the context each
               of --batch concurrent decodes gets at full occupancy.
 - decode bytes/token: a decode step streams every live block of the
               row's context once (the ragged kernel's skip predicate
               elides only past-context blocks, so partial tail blocks
-              still stream whole): layers * 2 * ceil(ctx/BS) * BS *
-              Hkv * Dh * 2B. Arithmetic intensity of paged decode is
+              still stream whole): layers * ceil(ctx/BS) * BS *
+              Hkv * W * 2B. Arithmetic intensity of paged decode is
               ~1 FLOP/byte, far left of the ridge, so the HBM ceiling
               IS the decode ceiling:
 - tok_s_ceiling: --hbm-gbps / bytes_per_token — the best any kernel
@@ -71,18 +73,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.engine.paged_cache import head_lanes, pack_kv, unpack_kv
+
 
 def kv_pool_bytes(layers, num_blocks, block_size, kv_heads, head_dim,
                   dtype_bytes=2):
-    return layers * 2 * num_blocks * block_size * kv_heads * head_dim \
-        * dtype_bytes
+    return layers * num_blocks * block_size * kv_heads \
+        * head_lanes(head_dim) * dtype_bytes
 
 
 def decode_bytes_per_token(layers, ctx, block_size, kv_heads, head_dim,
                            dtype_bytes=2):
     blocks = -(-ctx // block_size)
-    return layers * 2 * blocks * block_size * kv_heads * head_dim \
-        * dtype_bytes
+    return layers * blocks * block_size * kv_heads \
+        * head_lanes(head_dim) * dtype_bytes
 
 
 def expected_emitted(spec_k, accept):
@@ -98,18 +102,17 @@ def expected_emitted(spec_k, accept):
 def _ragged_decode_operands(batch, ctx, block_size, num_blocks, heads,
                             kv_heads, head_dim, tile_q=8, seed=0):
     """Flat-packed pure-decode batch: one tile per row, query at the
-    last written position, distinct blocks per row."""
+    last written position, distinct blocks per row. Returns the
+    kernel's positional operands (the pool in the cache's layout) and
+    its `groups` keyword."""
     rs = np.random.RandomState(seed)
     mb = -(-ctx // block_size)
     assert batch * mb <= num_blocks, "pool too small for the sweep cell"
     t_flat = batch * tile_q
     q = jnp.asarray(rs.randn(t_flat, heads, head_dim), jnp.float32) * 0.3
-    k_pool = jnp.asarray(
-        rs.randn(num_blocks, block_size, kv_heads, head_dim),
-        jnp.float32) * 0.3
-    v_pool = jnp.asarray(
-        rs.randn(num_blocks, block_size, kv_heads, head_dim),
-        jnp.float32) * 0.3
+    kv_pool = pack_kv(*(
+        jnp.asarray(rs.randn(num_blocks, block_size, kv_heads, head_dim),
+                    jnp.float32) * 0.3 for _ in range(2)))
     perm = rs.permutation(num_blocks)
     bt = np.zeros((batch + 1, mb), np.int32)
     for i in range(batch):
@@ -120,8 +123,9 @@ def _ragged_decode_operands(batch, ctx, block_size, num_blocks, heads,
     qs[batch] = 0
     tr = np.arange(batch, dtype=np.int32)       # one tile per row
     to = np.zeros((batch,), np.int32)
-    return (q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(cl),
-            jnp.asarray(qs), jnp.asarray(tr), jnp.asarray(to))
+    return ((q, kv_pool, jnp.asarray(bt), jnp.asarray(cl),
+             jnp.asarray(qs), jnp.asarray(tr), jnp.asarray(to)),
+            {"groups": heads // kv_heads})
 
 
 def _quantize_operand_blocks(ops, int8_frac, seed=1):
@@ -134,7 +138,8 @@ def _quantize_operand_blocks(ops, int8_frac, seed=1):
     from paddle_tpu.quant.int8_compute import dequantize_block, \
         quantize_block
 
-    (q, k_pool, v_pool, bt, cl, qs, tr, to) = ops
+    (q, kv_pool, bt, cl, qs, tr, to) = ops
+    k_pool, v_pool = unpack_kv(kv_pool, q.shape[-1])
     bt = np.asarray(bt).copy()
     stride = max(1, round(1.0 / max(int8_frac, 1e-9)))
     kq, vq, ksc, vsc = [], [], [], []
@@ -164,12 +169,11 @@ def _quantize_operand_blocks(ops, int8_frac, seed=1):
         vq.append(np.zeros(v_pool.shape[1:], np.int8))
         ksc.append(1.0)
         vsc.append(1.0)
-    qkw = dict(kq_pool=jnp.asarray(np.stack(kq)),
-               vq_pool=jnp.asarray(np.stack(vq)),
+    qkw = dict(kvq_pool=jnp.asarray(pack_kv(np.stack(kq), np.stack(vq))),
                k_scales=jnp.asarray(ksc, jnp.float32),
                v_scales=jnp.asarray(vsc, jnp.float32))
-    mixed = (q, k_pool, v_pool, jnp.asarray(bt_mixed), cl, qs, tr, to)
-    promoted = (q, jnp.asarray(k_pro), jnp.asarray(v_pro),
+    mixed = (q, kv_pool, jnp.asarray(bt_mixed), cl, qs, tr, to)
+    promoted = (q, jnp.asarray(pack_kv(k_pro, v_pro)),
                 jnp.asarray(bt), cl, qs, tr, to)
     return mixed, qkw, promoted, len(kq), n_total
 
@@ -181,12 +185,12 @@ def smoke_interpret(direct_int8=False):
     read."""
     from paddle_tpu.kernels import paged_attention as paged
 
-    ops = _ragged_decode_operands(batch=2, ctx=10, block_size=4,
-                                  num_blocks=16, heads=4, kv_heads=2,
-                                  head_dim=8)
-    ref = paged.ragged_paged_attention(*ops, use_kernel=False)
+    ops, gkw = _ragged_decode_operands(batch=2, ctx=10, block_size=4,
+                                       num_blocks=16, heads=4, kv_heads=2,
+                                       head_dim=8)
+    ref = paged.ragged_paged_attention(*ops, use_kernel=False, **gkw)
     out = paged.ragged_paged_attention(*ops, use_kernel=True,
-                                       interpret=True)
+                                       interpret=True, **gkw)
     diff = float(jnp.max(jnp.abs(out - ref)))
     ok = bool(np.isfinite(diff) and diff < 1e-5)
     print(f"interpret smoke: kernel vs reference max|diff| = {diff:.2e} "
@@ -194,12 +198,12 @@ def smoke_interpret(direct_int8=False):
     if not direct_int8:
         return ok
     mixed, qkw, promoted, n8, nt = _quantize_operand_blocks(ops, 0.5)
-    mref = paged.ragged_paged_attention_reference(*mixed, **qkw)
+    mref = paged.ragged_paged_attention_reference(*mixed, **qkw, **gkw)
     mout = paged.ragged_paged_attention(*mixed, use_kernel=True,
-                                        interpret=True, **qkw)
+                                        interpret=True, **qkw, **gkw)
     mdiff = float(jnp.max(jnp.abs(mout - mref)))
     pout = paged.ragged_paged_attention(*promoted, use_kernel=True,
-                                        interpret=True)
+                                        interpret=True, **gkw)
     exact = bool(np.array_equal(np.asarray(mout), np.asarray(pout)))
     mok = bool(np.isfinite(mdiff) and mdiff < 1e-5 and exact)
     print(f"direct-int8 smoke: {n8}/{nt} blocks int8; mixed kernel vs "
@@ -217,11 +221,12 @@ def measure_cell(batch, ctx, block_size, num_blocks, heads, kv_heads,
     from paddle_tpu.benchmark.harness import run_timed
     from paddle_tpu.kernels import paged_attention as paged
 
-    ops = _ragged_decode_operands(batch, ctx, block_size, num_blocks,
-                                  heads, kv_heads, head_dim, tile_q)
-    qkw, n8, nt = {}, 0, batch * -(-ctx // block_size)
+    ops, qkw = _ragged_decode_operands(batch, ctx, block_size, num_blocks,
+                                       heads, kv_heads, head_dim, tile_q)
+    n8, nt = 0, batch * -(-ctx // block_size)
     if int8_frac > 0.0:
-        ops, qkw, _, n8, nt = _quantize_operand_blocks(ops, int8_frac)
+        ops, q8, _, n8, nt = _quantize_operand_blocks(ops, int8_frac)
+        qkw.update(q8)
     q = ops[0]
 
     def step(c):
@@ -243,7 +248,7 @@ def measure_cell(batch, ctx, block_size, num_blocks, heads, kv_heads,
                                               kv_heads, head_dim,
                                               dtype_bytes=4)
     if n8:
-        blk = 2 * block_size * kv_heads * head_dim
+        blk = block_size * kv_heads * head_lanes(head_dim)
         streamed -= n8 * blk * 3            # 4B -> 1B on the int8 share
         streamed += n8 * 2 * 4              # per-plane scales
     return sec * 1e3, streamed / sec / 1e9
