@@ -1,6 +1,7 @@
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py             # one TPU chip: serve phase, train phase
+    python chip_smoke.py             # one TPU chip: train, serve, latent
+    python chip_smoke.py --only latent   # that phase alone: a minute
     python chip_smoke.py --chips 4   # one four-chip host: tp=4 serving
                                      # against tp=1, and the sharded
                                      # training parity gate — nothing else
@@ -13,6 +14,13 @@ medium's published widths and full depth in bf16:
 - serve: export the model, build the replica exactly as `python -m
   paddle_tpu.serve.replica --model-dir ...` does, and send it requests
   over HTTP.
+
+- latent: the latent-attention, routed-expert decoder at the published
+  widths of `benchmarks/configs/glm-4.7-flash.json` cut to two layers
+  (the dense one and one expert layer, all 64 experts, the whole
+  vocabulary), bf16 weights from the benchmark's seed, through
+  `ServeEngine`'s one step and its latent pool; the logits it sampled
+  from are held against the benchmark's plain float32 reference.
 
 Each phase prints one JSON line of what it counted and which of its
 gates failed; the last line of stdout is the result object. Everything
@@ -66,6 +74,12 @@ SERVE = dict(widths=GPT2_MEDIUM, engine=ENGINE,
 # at these widths), while a paging or masking fault moves logits by their
 # own spread, about 25% of the largest.
 LOGIT_TOL = 0.05
+# The latent phase: the cell's own engine but for the pool (256 blocks of
+# 128 rows are 32,768 tokens: two layers' pools take 84 MB). The first
+# prompt is prefilled in three chunks of 1,024, the second hits its
+# first 2,048 tokens in the prefix index.
+LATENT = dict(layers=2, num_blocks=256, prompt_lens=(2500, 2300),
+              shared_prefix=2048, new_tokens=8)
 TRAIN = dict(batch=8, seq=1024, steps=5)
 PEAK_BYTES_LIMIT = 14e9
 SEED = 0
@@ -445,6 +459,129 @@ def train_phase(widths: dict, batch: int, seq: int, steps: int, dtype,
     return line
 
 
+def latent_phase(config: dict, layers: int, num_blocks: int, prompt_lens,
+                 shared_prefix: int, new_tokens: int, seed: int,
+                 cache=None) -> dict:
+    """Serve the configuration's block, cut to `layers`, in process and
+    hold every logits row the engine sampled from against the
+    benchmark's reference, teacher-forced. Returns the phase's JSON
+    line."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.common import build_model
+    from paddle_tpu.engine import engine as engine_mod
+    from paddle_tpu.engine.engine import ServeEngine
+
+    t_start = time.monotonic()
+    config = dict(config, num_hidden_layers=layers)
+    dev = jax.devices()[0]
+    model = build_model(config)
+    params = importlib.import_module(config["weights"]).make_params(
+        config, seed)
+    reference = importlib.import_module(config["reference"])
+    serve = dict(config["serve"], num_blocks=num_blocks)
+    eng = ServeEngine(model, {"params": params}, **serve)
+    del params
+    rng = np.random.default_rng(seed)
+    vocab = config["vocab_size"]
+    shared = rng.integers(0, vocab, shared_prefix).tolist()
+    prompts = [shared + rng.integers(0, vocab, n - shared_prefix).tolist()
+               for n in prompt_lens]
+
+    seen = []
+    sample = engine_mod._sample
+
+    def spy(logits, req, pos):
+        seen.append(np.array(logits, np.float32))
+        return sample(logits, req, pos)
+
+    generated = []
+    with mock.patch.object(engine_mod, "_sample", spy):
+        for prompt in prompts:     # one at a time: the second hits
+            generated.append(eng.generate([prompt],
+                                          max_new_tokens=new_tokens)[0])
+    setup_s = time.monotonic() - t_start
+    stats = eng.stats()
+    failed = []
+    compiles = eng._step_fn._cache_size()
+    if compiles != 1:
+        failed.append(f"the step compiled {compiles} times")
+    if stats["hit_tokens"] < shared_prefix // serve["block_size"] \
+            * serve["block_size"]:
+        failed.append(f"prefix hit of {stats['hit_tokens']} tokens, under "
+                      f"the shared {shared_prefix}")
+    step = _engine_step_compiled(eng)
+    step_text = step.as_text()
+    pallas_in_step = "tpu_custom_call" in step_text
+    if not pallas_in_step:
+        failed.append("no Pallas kernel in the engine's step program")
+    pool_copies = pool_sized_copies(
+        step_text, int(np.prod(eng.cache.pool_shape())))
+    if pool_copies:
+        failed.append(f"{len(pool_copies)} whole-pool copies in the "
+                      f"engine's step program: {pool_copies[0]}")
+    per_expert = eng.expert_tokens.copy()
+    computed = stats["prefill_tokens_computed"] + sum(
+        len(g) - 1 for g in generated)
+    want = computed * config["num_experts_per_tok"] * per_expert.shape[0]
+    if per_expert.sum() != want:
+        failed.append(f"{per_expert.sum()} (token, expert) pairs counted "
+                      f"for {computed} computed tokens: want {want}")
+    eng.cache.assert_quiesced()
+    peak = _device_bytes(dev, "peak_bytes_in_use")
+    del eng
+    gc.collect()
+
+    width = -(-(max(prompt_lens) + new_tokens) // 128) * 128
+    tokens = np.zeros((len(prompts), width), np.int32)
+    rows = np.zeros((len(prompts), new_tokens), np.int32)
+    for i, (p, g) in enumerate(zip(prompts, generated)):
+        tokens[i, :len(p) + len(g)] = p + g
+        rows[i] = len(p) - 1 + np.arange(new_tokens)
+    ref, _ = reference.logits_at(config, seed, jnp.asarray(tokens),
+                                 jnp.asarray(rows))
+    ref = np.asarray(ref, np.float32).reshape(-1, vocab)
+    got = np.stack(seen)
+    logit_err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+    if not (np.isfinite(got).all() and logit_err <= LOGIT_TOL):
+        failed.append(f"the step's logits off the reference by "
+                      f"{logit_err:.4f} of the largest logit")
+    best = ref.max(axis=-1)
+    served = np.concatenate(generated)
+    gaps = best - ref[np.arange(len(served)), served]
+    line = {
+        "phase": "latent", "config": config["name"], "layers": layers,
+        "dtype": config["compute_dtype"], "engine": serve,
+        "setup_and_serve_seconds": round(setup_s, 1),
+        "prompt_tokens": [len(p) for p in prompts],
+        "new_tokens_each": new_tokens, "engine_compiles": compiles,
+        "engine_steps": stats["steps"], "hit_tokens": stats["hit_tokens"],
+        "max_chunk_tokens": stats["max_chunk_tokens"],
+        "pallas_in_step": pallas_in_step,
+        "step_pool_sized_copies": len(pool_copies),
+        "expert_pairs": int(per_expert.sum()),
+        "experts_touched": int((per_expert > 0).sum()),
+        "logit_err_share_of_max": round(logit_err, 5),
+        "logit_tol": LOGIT_TOL,
+        "token_gap_max": float(gaps.max()),
+        "tokens_equal_to_reference": f"{int((gaps == 0).sum())}/{gaps.size}",
+        "peak_bytes_in_use": peak, "failed": failed,
+    }
+    if cache is not None:
+        line["compile_cache"] = cache.take()
+    return line
+
+
+def _latent_config() -> dict:
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "glm-4.7-flash.json")) as f:
+        return json.load(f)
+
+
 def _quiet(fn, *args, **kwargs):
     """Run a phase with everything its callees print (serve events, the
     dry run's summary) sent to stderr: stdout carries the phase lines
@@ -505,7 +642,10 @@ def four_chip_phases(dtype, cache=None) -> bool:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    chips = ap.parse_args(argv).chips
+    ap.add_argument("--only", choices=("latent",), default=None,
+                    help="run that one-chip phase alone")
+    args = ap.parse_args(argv)
+    chips = args.chips
 
     from paddle_tpu.utils.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
@@ -538,6 +678,9 @@ def main(argv=None) -> int:
 
     if chips == 4:
         ok = four_chip_phases(jnp.bfloat16, cache)
+    elif args.only == "latent":
+        ok = _emit(_quiet(latent_phase, _latent_config(), seed=SEED,
+                          cache=cache, **LATENT))
     else:
         # train first: peak_bytes_in_use is the process's high-water
         # mark and cannot be reset, and the serve phase's is the higher
@@ -548,6 +691,9 @@ def main(argv=None) -> int:
         serve, _ = _quiet(serve_phase, dtype=jnp.bfloat16, seed=SEED,
                           cache=cache, **SERVE)
         ok = _emit(serve) and ok
+        gc.collect()
+        ok = _emit(_quiet(latent_phase, _latent_config(), seed=SEED,
+                          cache=cache, **LATENT)) and ok
     print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 

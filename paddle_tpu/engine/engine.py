@@ -37,7 +37,8 @@ masked attention lanes underflow to exact zero, so reusing it is
 bit-identical to recomputing it (tests/test_prefix_cache.py).
 
 Sampling runs on host from the [B, spec_len, V] logits (greedy /
-temperature / top-k). Stochastic sampling derives its rng stream from
+temperature / top-k); the step hands their log-sum-exp over with them,
+so a greedy token is an argmax and a subtraction. Stochastic sampling derives its rng stream from
 (request seed, absolute position), never from batch composition, so
 scheduling decisions can't change a request's output.
 
@@ -71,7 +72,8 @@ import numpy as np
 
 from paddle_tpu.core.module import Context, _CtxCore
 from paddle_tpu.engine.kvtier import HostKVTier, prefix_digest
-from paddle_tpu.engine.paged_cache import PagedKVCache, pack_kv, unpack_kv
+from paddle_tpu.engine.paged_cache import (PagedKVCache, pack_kv,
+                                           refuse_latent, unpack_kv)
 from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
@@ -96,7 +98,11 @@ def serve_metadata(model) -> dict:
     """Introspect a CausalLM into the manifest `serve` block
     (io/inference.py `save_inference_model(..., serve_meta=...)`):
     everything `ServeEngine.from_saved_model` needs to rebuild the
-    module and size its KV pools without touching the checkpoint."""
+    module and size its KV pools without touching the checkpoint. A
+    model of another family describes itself (`model.serve_metadata()`,
+    its own `model_type`)."""
+    if hasattr(model, "serve_metadata"):
+        return model.serve_metadata()
     attn = model.blocks[0].attn
     return {
         "model_type": "causal_lm",
@@ -158,11 +164,18 @@ def compile_steps(model, variables, compress: bool, serve_tp=None):
     def _step_fn(variables, tokens, positions, pools, qpools, qscales,
                  block_tables, context_lens, q_starts, tile_rows,
                  tile_offs, slots, last_idx):
-        return model.ragged_step_paged(
+        # ((logits, their log-sum-exp), pools); a model with expert
+        # layers adds the step's tokens per expert, int32 [expert
+        # layers, experts]. The log-sum-exp is taken here, where the
+        # rows lie, so that scoring a greedy token on the host is one
+        # subtraction and no pass over the vocabulary
+        logits, pools, *rest = model.ragged_step_paged(
             _fresh_cx(variables), tokens, positions, pools,
             block_tables, context_lens, q_starts, tile_rows,
             tile_offs, slots, last_idx, tp=serve_tp,
             qpools=qpools, qscales=qscales)
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        return (logits, lse), pools, *rest
 
     @functools.partial(jax.jit, donate_argnums=(0,), **copy_sh)
     def _copy_blocks(pools, src, dst):
@@ -174,10 +187,12 @@ def compile_steps(model, variables, compress: bool, serve_tp=None):
 
 
 def _sample(logits: np.ndarray, req: Request, pos: int
-            ) -> "tuple[int, float]":
+            ) -> "tuple[int, Optional[float]]":
     """Host-side sampling for one row: (token, log-probability of that
-    token under the sampling distribution — greedy scores against the
-    plain softmax). Deterministic in (req.seed, pos): the same request
+    token under the sampling distribution). Greedy scores against the
+    plain softmax, whose log-sum-exp the step has already taken on the
+    device: its log-probability comes back None and `_pick` fills it
+    in. Deterministic in (req.seed, pos): the same request
     samples the same token at the same position no matter what batch
     it rode in — which is ALSO what makes speculative verification
     exact (a draft is accepted iff it equals this function's output at
@@ -185,10 +200,7 @@ def _sample(logits: np.ndarray, req: Request, pos: int
     a solo run with seed + i). The logprob accumulates into
     Request.logprob_sum, the best_of ranking signal."""
     if req.temperature <= 0.0:
-        tok = int(np.argmax(logits))
-        z = logits.astype(np.float64)
-        z = z - z.max()
-        return tok, float(z[tok] - np.log(np.exp(z).sum()))
+        return int(np.argmax(logits)), None
     z = logits.astype(np.float64) / req.temperature
     if 0 < req.top_k < z.size:
         kth = np.partition(z, -req.top_k)[-req.top_k]
@@ -199,6 +211,18 @@ def _sample(logits: np.ndarray, req: Request, pos: int
     rng = np.random.default_rng([req.seed & 0x7FFFFFFF, pos])
     tok = int(rng.choice(z.size, p=p))
     return tok, float(np.log(p[tok]))
+
+
+def _pick(logits: np.ndarray, lse, req: Request, pos: int
+          ) -> "tuple[int, float]":
+    """`_sample` for one row, a greedy token scored against the row's
+    log-sum-exp as the step took it on the device (float32; a pass
+    over the vocabulary on the host was most of a decode step's host
+    time at 50k logits a row and more)."""
+    tok, lp = _sample(logits, req, pos)
+    if lp is None:
+        lp = float(logits[tok]) - float(lse)
+    return tok, lp
 
 
 class ServeEngine:
@@ -244,6 +268,11 @@ class ServeEngine:
         self.obs = registry if registry is not None else default_registry()
         self.tracer = tracer if tracer is not None else RequestTracer()
         attn = model.blocks[0].attn
+        # what one cached row is, read from the model: kv_heads x
+        # [k | v], or one latent entry a token (paged_cache.py)
+        latent = getattr(attn, "latent_row", None)
+        if latent is not None:
+            refuse_latent(int(tp_size), int(kv_compress_blocks))
         # tensor-parallel serving (ENGINE.md "Tensor-parallel serving"):
         # tp_size > 1 builds a tp mesh over the first tp_size devices,
         # shards the weights (parallel.sharding.serve_tp_rules) and KV
@@ -382,7 +411,7 @@ class ServeEngine:
             host_tier=self.host_tier,
             compress_blocks=kv_compress_blocks,
             promote_hits=kv_promote_hits, tp_size=self.tp_size,
-            mesh=self._mesh)
+            mesh=self._mesh, latent=latent)
         if self.host_tier is not None:
             # prime the eager kernels tier traffic dispatches — the
             # demote gather (pool[block] device_get) and the revival
@@ -418,6 +447,10 @@ class ServeEngine:
         self.finished: Dict[int, Request] = {}
         self.steps = 0
         self._plan_us = 0.0     # `engine.plan`'s opening stamp (step())
+        # tokens per (expert layer, expert) since construction
+        self.expert_tokens = np.zeros(
+            (getattr(model, "expert_layers", 0),
+             getattr(model, "num_experts", 0)), np.int64)
         self.prefill_tokens_computed = 0
         self.peak_occupancy = 0.0
         self.max_chunk_tokens = 0       # largest prefill step actually run
@@ -458,15 +491,22 @@ class ServeEngine:
                 f"{model_dir} has no `serve` metadata in its manifest; "
                 "re-export with save_inference_model(..., "
                 "serve_meta=serve_metadata(model))")
-        model = CausalLM(
-            vocab=meta["vocab"], model_dim=meta["model_dim"],
-            num_heads=meta["num_heads"], num_layers=meta["num_layers"],
-            ffn_dim=meta["ffn_dim"], dropout=0.0, max_len=meta["max_len"],
-            tie_embeddings=meta["tie_embeddings"],
-            fused_qkv=meta["fused_qkv"],
-            num_kv_heads=meta["num_kv_heads"],
-            # exports older than the field were all float32
-            dtype=jnp.dtype(meta.get("dtype", "float32")))
+        if meta.get("model_type") == "latent_moe_lm":
+            from paddle_tpu.models.latent_moe import LatentMoELM
+            model = LatentMoELM(
+                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
+                param_dtype=jnp.dtype(meta["param_dtype"]))
+        else:
+            model = CausalLM(
+                vocab=meta["vocab"], model_dim=meta["model_dim"],
+                num_heads=meta["num_heads"], num_layers=meta["num_layers"],
+                ffn_dim=meta["ffn_dim"], dropout=0.0,
+                max_len=meta["max_len"],
+                tie_embeddings=meta["tie_embeddings"],
+                fused_qkv=meta["fused_qkv"],
+                num_kv_heads=meta["num_kv_heads"],
+                # exports older than the field were all float32
+                dtype=jnp.dtype(meta.get("dtype", "float32")))
         variables = load_checkpoint(os.path.join(model_dir, "params"))
         engine_kwargs.setdefault("max_seq_len", meta["max_len"])
         return cls(model, variables, **engine_kwargs)
@@ -498,6 +538,22 @@ class ServeEngine:
             labelnames=("kind",))        # kind=prefill|cached|generated
         self._m_steps = m.counter(
             "ptpu_engine_steps_total", "Compiled mixed steps executed")
+        self._m_kv_read = m.counter(
+            "ptpu_attn_kv_tokens_read_total",
+            "Context lengths summed over the steps' real rows: the cached "
+            "tokens the attention of a step has to read")
+        self._m_attn_keys = m.counter(
+            "ptpu_attn_keys_attended_total",
+            "Keys attended (position + 1) summed over the steps' real "
+            "query tokens")
+        self._m_moe_assign = m.counter(
+            "ptpu_moe_assignments_total",
+            "Real (row, choice) pairs routed to an expert, summed over "
+            "the expert layers")
+        self._m_moe_active = m.counter(
+            "ptpu_moe_active_experts_total",
+            "(layer, expert) pairs that received at least one token in "
+            "a step")
         self._m_compiles = m.gauge(
             "ptpu_engine_compiles",
             "jit cache size of the unified step (the one-compile "
@@ -695,15 +751,15 @@ class ServeEngine:
                 self.cache.step_now = step
                 if self.cache.compress_enabled:
                     self.cache.compress_cold(_COMPRESS_IDLE_STEPS)
-            chunks, decodes, chunk_tokens, drafted, accepted = \
+            chunks, decodes, chunk_tokens, drafted, accepted, asked = \
                 self._step_mixed(rows)
             with annotate("engine.publish", step=step):
                 self._publish(chunks, decodes, chunk_tokens, drafted,
-                              accepted)
+                              accepted, asked)
             span.set(decode_rows=len(decodes), chunk_rows=len(chunks),
                      chunk_tokens=chunk_tokens,
                      queue_depth=self.scheduler.queue_depth,
-                     used_blocks=self.cache.used_blocks)
+                     used_blocks=self.cache.used_blocks, **asked)
         # "spec" wins over mixed/decode so the speculation-on latency
         # distribution is separable from plain decode's
         kind = ("spec" if drafted
@@ -713,9 +769,17 @@ class ServeEngine:
         return True
 
     def _publish(self, chunks: List[StepRow], decodes: List[StepRow],
-                 computed: int, drafted: int, accepted: int) -> None:
+                 computed: int, drafted: int, accepted: int,
+                 asked: Dict[str, int]) -> None:
         """Per-step telemetry: the step's `serve_event` lines and
-        host-side counter and gauge writes."""
+        host-side counter and gauge writes. `asked` is what the step's
+        attention and experts were asked to do (`_step_mixed`), each
+        entry the addend of the counter of its name."""
+        self._m_kv_read.inc(asked["kv_tokens_read"])
+        self._m_attn_keys.inc(asked["attn_keys"])
+        if "moe_assignments" in asked:
+            self._m_moe_assign.inc(asked["moe_assignments"])
+            self._m_moe_active.inc(asked["moe_active_experts"])
         if chunks:
             # per-event field: a request's prefix-hit tokens are
             # attributed to the step its FIRST chunk runs
@@ -821,7 +885,8 @@ class ServeEngine:
             for li, pool in enumerate(self.cache.pools):
                 rows = np.zeros((_TIER_LANES,) + pool.shape[1:], np.float32)
                 for j, (_, layers) in enumerate(batch):
-                    rows[j] = pack_kv(*map(np.asarray, layers[li]))
+                    rows[j] = self.cache.pack_block(
+                        *map(np.asarray, layers[li]))
                 self.cache.pools[li] = pool.at[blocks].set(
                     jnp.asarray(rows, pool.dtype))
         return len(loads)
@@ -905,9 +970,8 @@ class ServeEngine:
         ids — the same encoding the router's prefix_shard hashes.
         Engine-loop thread only (reads the unlocked prefix index); the
         serve front-end snapshots it between steps for /kvprefixes."""
-        out = [{"len": len(key), "digest": prefix_digest(key),
-                "tier": "device"}
-               for key in self.cache.prefix_keys(limit)]
+        out = [{"len": ln, "digest": digest, "tier": "device"}
+               for ln, digest in self.cache.prefix_rows(limit)]
         if self.cache.compress_enabled:
             out.extend({"len": len(key), "digest": prefix_digest(key),
                         "tier": "device_int8"}
@@ -963,7 +1027,7 @@ class ServeEngine:
         return out
 
     def _step_mixed(self, rows: List[StepRow]
-                    ) -> "tuple[list, list, int, int, int]":
+                    ) -> "tuple[list, list, int, int, int, dict]":
         """Pack the plan's rows — decode rows AND prefill chunks — into
         the flat ragged layout and run ONE compiled step. Row i's token
         window [start, start+length) lands in a tile_q-aligned segment
@@ -1006,7 +1070,7 @@ class ServeEngine:
             tile_rows = np.full((nt,), b, np.int32)  # pad tiles -> null row
             tile_offs = np.zeros((nt,), np.int32)
             last_idx = np.zeros((b, self.spec_len), np.int32)
-            cursor = 0
+            cursor = kv_read = attn_keys = 0
             for i, row in enumerate(rows):
                 r = row.req
                 toks = r.tokens
@@ -1024,6 +1088,9 @@ class ServeEngine:
                 block_tables[i] = self.cache.padded_table(r.req_id, mb)
                 context_lens[i] = row.start + row.length
                 q_starts[i] = row.start
+                kv_read += row.start + row.length
+                attn_keys += (row.length * row.start
+                              + row.length * (row.length + 1) // 2)
                 if row.decode:
                     # verification gathers per-position logits (plain
                     # decode rows have length 1: every column clamps to
@@ -1039,17 +1106,22 @@ class ServeEngine:
                     tile_offs[t0 + k] = k * tq
                 cursor += ntiles * tq
         with annotate("engine.dispatch", step=step):
-            logits, self.cache.pools = self._donating(
+            (logits, lse), self.cache.pools, *per_expert = self._donating(
                 self._step_fn,
-                self.variables, jnp.asarray(tokens), jnp.asarray(positions),
+                self.variables, tokens, positions,
                 self.cache.pools, self.cache.qpools, self.cache.qscales,
-                jnp.asarray(block_tables), jnp.asarray(context_lens),
-                jnp.asarray(q_starts), jnp.asarray(tile_rows),
-                jnp.asarray(tile_offs), jnp.asarray(slots),
-                jnp.asarray(last_idx))
+                block_tables, context_lens, q_starts, tile_rows,
+                tile_offs, slots, last_idx)
         with annotate("engine.fetch", step=step) as span:
-            logits = np.asarray(logits)
-            span.set(bytes=logits.nbytes)
+            logits, lse = np.asarray(logits), np.asarray(lse)
+            span.set(bytes=logits.nbytes + lse.nbytes)
+            asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys}
+            if per_expert:
+                per_expert = np.asarray(per_expert[0])
+                self.expert_tokens += per_expert
+                asked.update(
+                    moe_assignments=int(per_expert.sum()),
+                    moe_active_experts=int((per_expert > 0).sum()))
         with annotate("engine.sample", step=step) as span:
             # the logits reached the host: every first token and finish
             # of this step is stamped with the span's opening reading
@@ -1068,8 +1140,8 @@ class ServeEngine:
                         # logits[i, j] scored window position start+j, i.e.
                         # it predicts the token at cache seq_len (which the
                         # advances below keep in lockstep with j)
-                        tok, lp = _sample(logits[i, j], r,
-                                          self.cache.seq_len(r.req_id))
+                        tok, lp = _pick(logits[i, j], lse[i, j], r,
+                                        self.cache.seq_len(r.req_id))
                         r.logprob_sum += lp
                         self._emit_token(r, tok, ts_us)
                         if r.finish_reason or j >= len(row.draft):
@@ -1103,8 +1175,10 @@ class ServeEngine:
                             # fork BEFORE the primary consumes the logits:
                             # each sibling samples its first token from the
                             # same final-chunk row under its own seed
-                            self._fork_candidates(r, logits[i, 0], ts_us)
-                        tok, lp = _sample(logits[i, 0], r, len(r.prompt))
+                            self._fork_candidates(r, logits[i, 0], lse[i, 0],
+                                                  ts_us)
+                        tok, lp = _pick(logits[i, 0], lse[i, 0], r,
+                                        len(r.prompt))
                         r.logprob_sum += lp
                         if not r.first_token_time:
                             r.first_token_time = ts_us / 1e6
@@ -1112,10 +1186,10 @@ class ServeEngine:
                         self._emit_token(r, tok, ts_us)
             span.set(emitted=int(generated.value - emitted),
                      finished=len(self.finished) - finished)
-        return chunks, decodes, computed, drafted, accepted
+        return chunks, decodes, computed, drafted, accepted, asked
 
     def _fork_candidates(self, primary: Request, logits_row: np.ndarray,
-                         ts_us: float) -> None:
+                         lse, ts_us: float) -> None:
         """Split a finished prefill into n parallel-sampling candidates.
         Each sibling's cache sequence shares EVERY prompt block with the
         primary — fork_sequence only bumps refcounts; COW peels a
@@ -1153,7 +1227,7 @@ class ServeEngine:
                                    prompt=len(sib.prompt))
             self.tracer.on_admit(sib.req_id, ts_us, self.steps,
                                  len(sib.prompt))
-            tok, lp = _sample(logits_row, sib, len(sib.prompt))
+            tok, lp = _pick(logits_row, lse, sib, len(sib.prompt))
             sib.logprob_sum += lp
             sib.first_token_time = ts_us / 1e6
             self.tracer.on_first_token(sib.req_id, ts_us, self.steps)

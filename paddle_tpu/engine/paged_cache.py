@@ -67,6 +67,19 @@ V; the int8 pools take the same rule, their scales stay per block.
 Under tensor parallelism the row shards over its heads:
 P(None, None, "tp").
 
+A LATENT row (`latent=(k_dim, v_dim)`, what a latent-attention model
+caches) is the pool's second layout: one entry a token and no head
+axis, `k_dim` values in `latent_lanes(k_dim)` lanes (576 -> 640), the
+scores contracting all of them and the first `v_dim` lanes doubling as
+the value (the ragged kernel reads it with `value_lanes=(0, v_dim)`).
+Everything above the row — allocator, prefix index, copy-on-write,
+eviction, the host tier's and the transfer plane's whole-block moves
+(`read_block` / `pack_block`, which carry the row as its two parts
+[value lanes | the rest]) — is the same code. Two things cannot work on
+it and refuse at construction: tensor parallelism (a latent has no head
+to divide over the chips) and the int8 tier (its per-block k and v
+scales are laid over a head's [k | v] halves).
+
 Host/device split: this class is the HOST-side allocator + bookkeeping
 (free list, refcounts, per-sequence tables/lengths/tokens, prefix
 index). The device-side pools are jnp arrays held in `self.pools`; the
@@ -129,13 +142,55 @@ def unpack_kv(rows, head_dim: int):
     return heads[..., :head_dim], heads[..., head_dim:2 * head_dim]
 
 
+def _write_rows(pool, slots, rows):
+    nb, bs, lanes = pool.shape
+    return pool.reshape(nb * bs, lanes).at[slots].set(
+        rows.astype(pool.dtype)).reshape(pool.shape)
+
+
 def write_kv(pool, slots, k, v):
     """The step's write: token i's k/v [T, Hkv, hd] land in the pool's
     flat row `slots[i]` (block_id * block_size + offset). One scatter
     of whole rows; on a donated pool it runs in place."""
-    nb, bs, lanes = pool.shape
-    return pool.reshape(nb * bs, lanes).at[slots].set(
-        pack_kv(k, v).astype(pool.dtype)).reshape(pool.shape)
+    return _write_rows(pool, slots, pack_kv(k, v))
+
+
+def latent_lanes(k_dim: int) -> int:
+    """Lanes of a latent row: its k_dim values padded up to whole
+    128-lane tiles."""
+    return -(-k_dim // _LANES) * _LANES
+
+
+def pack_latent(latent, lanes: int):
+    """Latent entries [..., k_dim] as pool rows [..., lanes]."""
+    xp = np if isinstance(latent, np.ndarray) else jnp
+    pad = lanes - latent.shape[-1]
+    if not pad:
+        return latent
+    return xp.concatenate(
+        [latent, xp.zeros(latent.shape[:-1] + (pad,), latent.dtype)],
+        axis=-1)
+
+
+def write_latent(pool, slots, latent):
+    """`write_kv` for a latent pool: token i's entry [T, k_dim] lands in
+    flat row `slots[i]`."""
+    return _write_rows(pool, slots, pack_latent(latent, pool.shape[-1]))
+
+
+def refuse_latent(tp_size: int, compress_blocks: int) -> None:
+    """What a latent pool cannot do, said at construction."""
+    if tp_size > 1:
+        raise ValueError(
+            f"tp_size={tp_size} over a latent KV pool: a latent row is one "
+            "entry a token shared by every head, so there is no kv head "
+            "to divide over the chips (serve it with tp_size=1)")
+    if compress_blocks > 0:
+        raise ValueError(
+            f"kv_compress_blocks={compress_blocks} over a latent KV pool: "
+            "the int8 tier keeps one k scale and one v scale a block, laid "
+            "over each head's [k | v] halves, and a latent row has neither "
+            "(serve it with kv_compress_blocks=0)")
 
 
 class PagedKVCache:
@@ -154,7 +209,14 @@ class PagedKVCache:
                  host_tier: Optional["HostKVTier"] = None,
                  compress_blocks: int = 0,
                  promote_hits: int = 0,
-                 tp_size: int = 1, mesh=None):
+                 tp_size: int = 1, mesh=None,
+                 latent: Optional[Tuple[int, int]] = None):
+        """`latent=(k_dim, v_dim)` selects the latent row (module
+        docstring); `num_kv_heads` and `head_dim` then count for
+        nothing."""
+        if latent is not None:
+            refuse_latent(tp_size, compress_blocks)
+            num_kv_heads, head_dim = 1, latent[0]
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is scratch)")
         if compress_blocks < 0:
@@ -175,6 +237,7 @@ class PagedKVCache:
         self.block_size = block_size
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
+        self.latent = latent
         self.dtype = dtype
         self.tp_size = tp_size
         self.enable_prefix_cache = enable_prefix_cache
@@ -250,6 +313,8 @@ class PagedKVCache:
         # full-prefix token tuple -> block holding that prefix's last block
         self._index: Dict[tuple, int] = {}
         self._key_of: Dict[int, tuple] = {}           # block -> index key
+        # block -> (its index key, the key's advertised digest): prefix_rows
+        self._digest_of: Dict[int, Tuple[tuple, str]] = {}
         self._pending_copies: List[Tuple[int, int]] = []   # (src, dst)
         # optional host-RAM second tier (engine/kvtier.py): blocks the
         # pool is about to destroy are copied out, and alloc_sequence
@@ -309,8 +374,9 @@ class PagedKVCache:
             raise ValueError(
                 f"num_kv_heads={self.num_kv_heads} not divisible by "
                 f"tp_size={tp}")
-        return (self.num_blocks, self.block_size,
-                self.num_kv_heads // tp * head_lanes(self.head_dim))
+        lanes = (latent_lanes(self.latent[0]) if self.latent else
+                 self.num_kv_heads // tp * head_lanes(self.head_dim))
+        return (self.num_blocks, self.block_size, lanes)
 
     def _place(self, pool):
         if self._sharding is None:
@@ -340,9 +406,25 @@ class PagedKVCache:
 
     def _host_kv(self, rows) -> Tuple[np.ndarray, np.ndarray]:
         """Device rows of one block -> its (k, v) on the host, each
-        [block_size, Hkv, hd] and contiguous (the tiers keep them)."""
-        k, v = unpack_kv(np.asarray(rows), self.head_dim)
+        [block_size, Hkv, hd] and contiguous (the tiers keep them). A
+        latent row travels as its two parts: ([bs, 1, v_dim] value
+        lanes, [bs, 1, k_dim - v_dim] the rest)."""
+        rows = np.asarray(rows)
+        if self.latent:
+            k_dim, v_dim = self.latent
+            k, v = rows[:, None, :v_dim], rows[:, None, v_dim:k_dim]
+        else:
+            k, v = unpack_kv(rows, self.head_dim)
         return np.ascontiguousarray(k), np.ascontiguousarray(v)
+
+    def pack_block(self, k, v):
+        """Inverse of `read_block`'s per-layer pair: one block's pool
+        rows from the (k, v) the tiers keep."""
+        if self.latent:
+            xp = np if isinstance(k, np.ndarray) else jnp
+            return pack_latent(xp.concatenate([k, v], axis=-1)[..., 0, :],
+                               latent_lanes(self.latent[0]))
+        return pack_kv(k, v)
 
     def read_block(self, block: int) -> List[Tuple[np.ndarray, np.ndarray]]:
         """One block's per-layer (k, v) on the host: the host tier's
@@ -1005,12 +1087,30 @@ class PagedKVCache:
                              f"> max {max_blocks}")
         return table + [0] * (max_blocks - len(table))
 
-    def prefix_keys(self, limit: int = 512) -> List[tuple]:
-        """Most recently indexed prefix keys (device tier) — the
-        engine's half of the fleet prefix directory advertisement.
-        Engine-loop thread only (reads the unlocked index)."""
-        keys = list(self._index.keys())
-        return keys[-limit:] if limit and len(keys) > limit else keys
+    def prefix_rows(self, limit: int = 512) -> List[Tuple[int, str]]:
+        """(length, digest) of the most recently indexed prefix keys
+        (device tier) — the engine's half of the fleet prefix directory
+        advertisement. Engine-loop thread only (reads the unlocked
+        index). A digest is a pass over the key's tokens, thousands for
+        a long shared document, and the advertisement is refreshed on
+        the engine loop every quarter second: so a key's digest is
+        computed once and kept beside its block for as long as the
+        block carries that very key."""
+        from paddle_tpu.engine.kvtier import prefix_digest
+        items = list(self._index.items())
+        if limit and len(items) > limit:
+            items = items[-limit:]
+        rows = []
+        for key, block in items:
+            kept = self._digest_of.get(block)
+            if kept is None or kept[0] is not key:
+                kept = self._digest_of[block] = (key, prefix_digest(key))
+            rows.append((len(key), kept[1]))
+        if len(self._digest_of) > 2 * len(self._index) + 64:
+            live = set(self._index.values())      # entries of blocks gone
+            self._digest_of = {b: v for b, v in self._digest_of.items()
+                               if b in live}
+        return rows
 
     def compressed_keys(self, limit: int = 512) -> List[tuple]:
         """Most recently touched compressed-tier keys (hottest last) —
@@ -1057,7 +1157,9 @@ class PagedKVCache:
         x block-bytes when the int8 pool is full of fp-evicted content
         — the ~2x-effective-pool headline, sampled into
         ptpu_kv_pool_effective_bytes."""
-        blk = (2 * self.block_size * self.num_kv_heads * self.head_dim
+        values = (self.latent[0] if self.latent else
+                  2 * self.num_kv_heads * self.head_dim)
+        blk = (self.block_size * values
                * np.dtype(self.dtype).itemsize * len(self.pools))
         uniq = sum(1 for k in self._cindex if k not in self._index)
         return (self.num_blocks - 1 + uniq) * blk

@@ -311,6 +311,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 # carries p.v in lanes [D, 2D): no sub-tile lane slicing per block, and
 # a 128-deep contraction where head_dim 64 half-filled it.
 #
+# A LATENT pool (one entry a token, no head axis: `value_lanes=(0, V)`)
+# rides the same grid, index maps and DMA: every one of the H query
+# heads reads the one cached row (groups = H), q is [q~ | q_rope] padded
+# to the row's lanes, the scores contract the whole row and the
+# accumulator keeps lanes [0, V) — a query width and a value width that
+# differ and overlap. With one kv head the (query, head) pairs are
+# flattened to rows outside the kernel, so each cell is two plain 2-D
+# matmuls and no packed operand is reshaped in it.
+#
 # Masking is absolute-position causal AND context-bounded
 # (kv_pos <= q_pos, kv_pos < ctx — the paged_prefill_attention
 # contract), so decode rows, mid-prompt chunks and pad queries all fall
@@ -354,7 +363,8 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
                                      scale: Optional[float] = None,
                                      groups: int = 1,
                                      kvq_pool=None,
-                                     k_scales=None, v_scales=None):
+                                     k_scales=None, v_scales=None,
+                                     value_lanes=None):
     """XLA oracle for the ragged layout: expand tile metadata to
     per-token rows and run the dense gather + masked attention.
     q: [T, H, D] flat-packed; kv_pool: [NB, BS, Hkv * W] (the section
@@ -367,7 +377,11 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
 
     With kvq_pool (+[NQ] per-block k_scales/v_scales) the table
     entries are bias-encoded: id >= 0 reads the fp pool, id < 0 reads
-    int8 slot -id-1 and dequantizes in place."""
+    int8 slot -id-1 and dequantizes in place.
+
+    `value_lanes=(0, V)`: a latent pool [NB, BS, lanes] — one row a
+    token for all H heads, keys its lanes [0, D), values its lanes
+    [0, V); returns [T, H, V]."""
     t, h, d = q.shape
     nb, bs, _ = kv_pool.shape
     hkv = h // groups
@@ -380,6 +394,17 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
     qpos = (jnp.repeat(q_starts[tile_rows] + tile_offs, tq)
             + jnp.tile(jnp.arange(tq, dtype=jnp.int32), nt))  # [T]
     bt = block_tables[row_of]                                # [T, MB]
+    if value_lanes is not None:
+        v_off, v_dim = _latent_lanes(value_lanes, h, groups, kvq_pool)
+        rows = kv_pool[bt].reshape(t, mb * bs, -1)
+        kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
+        mask = ((kv_pos[None, :] <= qpos[:, None])
+                & (kv_pos[None, :] < context_lens[row_of][:, None]))
+        s_ = jnp.einsum("thd,tkd->thk", q.astype(rows.dtype),
+                        rows[..., :d]).astype(jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(mask[:, None], s_, NEG_INF), axis=-1)
+        return jnp.einsum("thk,tkv->thv", p.astype(rows.dtype),
+                          rows[..., v_off:v_off + v_dim]).astype(q.dtype)
     if kvq_pool is None:
         k, v = _split_kv(kv_pool[bt], hkv, d)
     else:
@@ -393,6 +418,22 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
             & (kv_pos[None, :] < ctx[:, None]))[:, None, None, :]
     return reference_attention(q[:, None].astype(k.dtype), k, v, mask=mask,
                                scale=scale)[:, 0].astype(q.dtype)
+
+
+def _latent_lanes(value_lanes, h: int, groups: int, kvq_pool):
+    """(value offset, value width) of a latent pool, or why it cannot
+    be read."""
+    if h != groups:
+        raise ValueError(
+            f"a latent pool holds one row a token for all heads: groups "
+            f"must be the head count {h}, got {groups}")
+    if kvq_pool is not None:
+        raise ValueError("a latent pool has no int8 tier "
+                         "(engine/paged_cache.py refuses it)")
+    v_off, v_dim = value_lanes
+    if v_off != 0:
+        raise ValueError("a latent row's value is its first lanes")
+    return v_off, v_dim
 
 
 def _heads_to_kv_major(x, hkv: int, groups: int):
@@ -432,16 +473,26 @@ def _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr, *,
     shared by the fp-only and mixed-precision ragged kernels. q:
     [TQ, H, W], zero beyond lane D; kv: [Hkv, BS, W], each head's
     [k | v | pad]; scratch rows are flattened TQ*H, the accumulator W
-    lanes wide with p.v in lanes [D, 2D)."""
-    tq, h, _ = q.shape
+    lanes wide with p.v in lanes [D, 2D). Over a latent pool q comes
+    flattened, [TQ*H, W] against the one kv "head" [1, BS, W], and the
+    accumulator keeps the row's leading value lanes only."""
     hkv = kv.shape[0]
-    # batch over kv heads: [Hkv, TQ*G, W] x [Hkv, BS, W]; q's zero
-    # lanes drop v out of the contraction
-    qg = _heads_to_kv_major(q, hkv, groups)
-    s = jax.lax.dot_general(
-        qg, kv, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale  # [Hkv, TQ*G, BS]
-    s = _kv_major_to_rows(s, tq, groups)            # [TQ*H, BS]
+    flat = q.ndim == 2        # one kv head: rows are (query, head) pairs
+    if flat:
+        h = groups
+        tq = q.shape[0] // h
+        s = jax.lax.dot_general(
+            q, kv[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [TQ*H, BS]
+    else:
+        tq, h, _ = q.shape
+        # batch over kv heads: [Hkv, TQ*G, W] x [Hkv, BS, W]; q's zero
+        # lanes drop v out of the contraction
+        qg = _heads_to_kv_major(q, hkv, groups)
+        s = jax.lax.dot_general(
+            qg, kv, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [Hkv, TQ*G, BS]
+        s = _kv_major_to_rows(s, tq, groups)            # [TQ*H, BS]
     qpos = q0 + jax.lax.broadcasted_iota(
         jnp.int32, (tq, h, block_size), 0).reshape(tq * h, block_size)
     kpos = j * block_size + jax.lax.broadcasted_iota(
@@ -454,27 +505,36 @@ def _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr, *,
     p = jnp.exp(s - m_new)                          # [TQ*H, BS]
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    pg = _heads_to_kv_major(p.reshape(tq, h, block_size), hkv, groups)
-    pv = jax.lax.dot_general(
-        pg.astype(kv.dtype), kv, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)         # [Hkv, TQ*G, W]
-    acc_scr[...] = alpha * acc_scr[...] + _kv_major_to_rows(pv, tq, groups)
+    if flat:    # the value lanes lead the row: whole 128-lane tiles
+        pv = jax.lax.dot_general(
+            p.astype(kv.dtype), kv[0][:, :acc_scr.shape[1]],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)     # [TQ*H, V]
+    else:
+        pg = _heads_to_kv_major(p.reshape(tq, h, block_size), hkv, groups)
+        pv = _kv_major_to_rows(jax.lax.dot_general(
+            pg.astype(kv.dtype), kv, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32), tq, groups)  # [TQ*H, W]
+    acc_scr[...] = alpha * acc_scr[...] + pv
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
-def _ragged_finalize(o_ref, l_scr, acc_scr):
+def _ragged_finalize(o_ref, l_scr, acc_scr, v_off=None):
     """Normalize the accumulator's v lanes into the output tile
-    [TQ, H, D]."""
+    [TQ, H, D] (lanes [D, 2D) of a head's [k | v]; over a latent pool
+    lanes [v_off, v_off + V) into [TQ*H, V])."""
     d = o_ref.shape[-1]
+    v_off = d if v_off is None else v_off
     l = l_scr[...][:, :1]
-    o_ref[...] = (acc_scr[...][:, d:2 * d] / jnp.maximum(l, 1e-30)
+    o_ref[...] = (acc_scr[...][:, v_off:v_off + d] / jnp.maximum(l, 1e-30)
                   ).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
                    q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   scale: float, block_size: int, tile_q: int, groups: int):
+                   scale: float, block_size: int, tile_q: int, groups: int,
+                   v_off=None):
     """One (query-tile, kv-block) grid cell. q_ref: [TQ, H, W] — one
     tile of the flat packing, lane-padded; kv_ref: the pool block the
     index map selected, [BS, Hkv * W]. Online-softmax scratch is
@@ -497,14 +557,15 @@ def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
     @pl.when((j * block_size < ctx) & (j * block_size <= q0 + tile_q - 1))
     def _compute():
         q = q_ref[...]
-        _ragged_tile_update(q, _block_heads(kv_ref[...],
-                                            q.shape[1] // groups),
-                            q0, ctx, j, m_scr, l_scr, acc_scr, scale=scale,
-                            block_size=block_size, groups=groups)
+        kv = (kv_ref[...][None] if q.ndim == 2 else
+              _block_heads(kv_ref[...], q.shape[1] // groups))
+        _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr,
+                            scale=scale, block_size=block_size,
+                            groups=groups)
 
     @pl.when(j == nblk - 1)
     def _finalize():
-        _ragged_finalize(o_ref, l_scr, acc_scr)
+        _ragged_finalize(o_ref, l_scr, acc_scr, v_off)
 
 
 def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
@@ -559,7 +620,8 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
 def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
                         q_starts, tile_rows, tile_offs, scale,
                         interpret: bool, groups: int,
-                        kvq_pool=None, k_scales=None, v_scales=None):
+                        kvq_pool=None, k_scales=None, v_scales=None,
+                        value_lanes=None):
     t, h, d = q.shape
     nb, bs, lanes = kv_pool.shape
     mb = block_tables.shape[1]
@@ -570,14 +632,32 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     if h % groups:
         raise ValueError(f"q heads {h} not a multiple of groups {groups}")
     w = lanes // (h // groups)
-    if w < 2 * d or w * (h // groups) != lanes:
-        raise ValueError(
-            f"pool rows of {lanes} lanes do not hold {h // groups} kv "
-            f"heads of [k | v] at head_dim {d}")
+    latent = value_lanes is not None
+    if latent:
+        v_off, out_d = _latent_lanes(value_lanes, h, groups, kvq_pool)
+        acc_w = -(-(v_off + out_d) // LANES) * LANES
+        if w < d or acc_w > w:
+            raise ValueError(
+                f"pool rows of {lanes} lanes do not hold a latent of {d} "
+                f"values whose first {out_d} are the value")
+    else:
+        v_off, out_d, acc_w = None, d, w
+        if w < 2 * d or w * (h // groups) != lanes:
+            raise ValueError(
+                f"pool rows of {lanes} lanes do not hold {h // groups} kv "
+                f"heads of [k | v] at head_dim {d}")
     mixed = kvq_pool is not None
     # q in the head's full lane width: zero lanes meet v in the
     # contraction
     q = jnp.pad(q, ((0, 0), (0, 0), (0, w - d)))
+    if latent:      # rows are (query, head) pairs: a free reshape here
+        q = q.reshape(t * h, w)
+        q_block, o_block = (tq * h, w), (tq * h, out_d)
+        out_shape = (t * h, out_d)
+    else:
+        q_block, o_block = (tq, h, w), (tq, h, d)
+        out_shape = (t, h, d)
+    zeros = (0,) * (len(q_block) - 1)
 
     def _active(ti, j, cl, qs, tr, to):
         # skip predicate shared by every kv index map: inactive cells
@@ -606,34 +686,35 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
 
     if mixed:
         def _q_map(ti, j, bt, cl, qs, tr, to, ksc, vsc):
-            return (ti, 0, 0)
+            return (ti,) + zeros
         # block_tables, ctx_lens, q_starts, tiles x2, k/v scales
         num_prefetch = 7
         in_specs = [
-            pl.BlockSpec((tq, h, w), _q_map),
+            pl.BlockSpec(q_block, _q_map),
             pl.BlockSpec((None, bs, lanes), _kv_fp),
             pl.BlockSpec((None, bs, lanes), _kv_q),
         ]
         kernel_fn = _ragged_kernel_mixed
     else:
         def _q_map(ti, j, bt, cl, qs, tr, to):
-            return (ti, 0, 0)
+            return (ti,) + zeros
         num_prefetch = 5  # block_tables, ctx_lens, q_starts, tiles x2
         in_specs = [
-            pl.BlockSpec((tq, h, w), _q_map),
+            pl.BlockSpec(q_block, _q_map),
             pl.BlockSpec((None, bs, lanes), _kv_block),
         ]
-        kernel_fn = _ragged_kernel
+        kernel_fn = (functools.partial(_ragged_kernel, v_off=v_off)
+                     if latent else _ragged_kernel)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
         grid=(nt, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tq, h, d), _q_map),
+        out_specs=pl.BlockSpec(o_block, _q_map),
         scratch_shapes=[
             _scratch((tq * h, LANES)),
             _scratch((tq * h, LANES)),
-            _scratch((tq * h, w)),
+            _scratch((tq * h, acc_w)),
         ],
     )
     kernel = functools.partial(kernel_fn, scale=scale, block_size=bs,
@@ -641,10 +722,11 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ragged_latent_attention" if latent else None,
     )
     scalars = (block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
                q_starts.astype(jnp.int32), tile_rows.astype(jnp.int32),
@@ -652,7 +734,8 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     if mixed:
         return call(*scalars, k_scales.astype(jnp.float32),
                     v_scales.astype(jnp.float32), q, kv_pool, kvq_pool)
-    return call(*scalars, q, kv_pool)
+    out = call(*scalars, q, kv_pool)
+    return out.reshape(t, h, out_d) if latent else out
 
 
 def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
@@ -661,7 +744,8 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None,
                            groups: int = 1,
-                           kvq_pool=None, k_scales=None, v_scales=None):
+                           kvq_pool=None, k_scales=None, v_scales=None,
+                           value_lanes=None):
     """Mixed prefill+decode attention over the flat ragged packing —
     the engine's single-step entry point. q: [T, H, D]; kv_pool: one
     layer's pool as the cache lays it out, [NB, BS, Hkv * W]; `groups`
@@ -676,7 +760,11 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
     place — dequantized per block inside the gather — instead of being
     promoted to fp first. The signature is shape-stable across fp-only
     / mixed / all-int8 batches so the jit cache stays at one entry
-    (TP004)."""
+    (TP004).
+
+    `value_lanes=(0, V)` reads a LATENT pool [NB, BS, lanes] (the
+    section comment above): q [T, H, D] is each head's absorbed query,
+    `groups` = H, and the result is [T, H, V], the attended latent."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     use_kernel, interpret = _resolve_dispatch(use_kernel, interpret)
@@ -684,12 +772,14 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
         return ragged_paged_attention_reference(
             q, kv_pool, block_tables, context_lens, q_starts,
             tile_rows, tile_offs, scale=scale, groups=groups,
-            kvq_pool=kvq_pool, k_scales=k_scales, v_scales=v_scales)
+            kvq_pool=kvq_pool, k_scales=k_scales, v_scales=v_scales,
+            value_lanes=value_lanes)
     return _ragged_kernel_call(q, kv_pool, block_tables,
                                context_lens, q_starts, tile_rows, tile_offs,
                                scale, interpret, groups,
                                kvq_pool=kvq_pool,
-                               k_scales=k_scales, v_scales=v_scales)
+                               k_scales=k_scales, v_scales=v_scales,
+                               value_lanes=value_lanes)
 
 
 # -- tensor-parallel wrappers (engine tp_size knob, ENGINE.md) ------------

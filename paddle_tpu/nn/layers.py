@@ -420,6 +420,25 @@ class BatchNorm(Module):
         return y.astype(self.dtype or x.dtype)
 
 
+class RMSNorm(Module):
+    """x * rsqrt(mean(x^2) + epsilon) * scale over the last axis, computed
+    in float32: LayerNorm without the mean and the offset."""
+
+    def __init__(self, epsilon: float = 1e-5, dtype=None,
+                 param_dtype=jnp.float32):
+        super().__init__()
+        self.epsilon = epsilon
+        self.dtype = dtype
+        self.param_dtype = param_dtype
+
+    def forward(self, cx: Context, x):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        scale = cx.param("scale", (x.shape[-1],), I.ones, self.param_dtype)
+        y = xf * lax.rsqrt(var + self.epsilon) * scale.astype(jnp.float32)
+        return y.astype(self.dtype or x.dtype)
+
+
 class LayerNorm(Module):
     """Reference fluid.layers.layer_norm (operators/layer_norm_op)."""
 

@@ -261,3 +261,64 @@ def test_gpipe_backward_keeps_its_psum_in_the_tick_loop(topo):
                and "transpose(jvp())/shard_map/while/body" in ln]
     assert in_loop, "the backward psum left the tick loop"
 
+
+
+def test_latent_expert_step_at_its_cell_sizes(one_chip):
+    """The engine's step over a latent pool with routed experts,
+    compiled for the described chip at `glm47f-docs8k`'s own sizes (all
+    seven layers, 64 experts, the whole vocabulary, 2,048 blocks of 128
+    latent rows; shapes only): the latent ragged kernel is accepted,
+    the pools are aliased to the step's output, no whole-pool relayout
+    is in the program, and everything the step holds fits the chip with
+    room for the allocator (under 15 GB of 16)."""
+    import json
+    from unittest import mock
+
+    from paddle_tpu.engine.engine import compile_steps
+    from paddle_tpu.engine.paged_cache import latent_lanes
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.latent_moe import LatentMoELM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm-4.7-flash.json")) as f:
+        cfg = json.load(f)
+    model = LatentMoELM(
+        dtype=jnp.bfloat16,
+        **{k: cfg[v] for k, v in cfg["constructor_args"].items()})
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    s = cfg["serve"]
+    tq, b = s["tile_q"], s["max_batch_size"]
+    # ServeEngine's sizing of its flat step, without an engine: one
+    # would hold 9 GB of weights
+    t = -(-s["max_prefill_tokens"] // tq) * tq + b * tq
+    mb = -(-s["max_seq_len"] // s["block_size"])
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    row = model.blocks[0].attn.latent_row
+    pool = jax.ShapeDtypeStruct(
+        (s["num_blocks"], s["block_size"], latent_lanes(row[0])),
+        jnp.bfloat16, sharding=one_chip)
+    assert pool.shape[-1] == 640
+    pools = [pool] * len(model.blocks)
+    step, _ = compile_steps(model, shapes, False)
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = step.lower(
+            jax.tree.map(on_chip, shapes), i32(t), i32(t), pools, [], [],
+            i32(b + 1, mb), i32(b + 1), i32(b + 1), i32(t // tq),
+            i32(t // tq), i32(t), i32(b, 1)).compile()
+    text = compiled.as_text()
+    assert "ragged_latent_attention" in text and "tpu_custom_call" in text
+    assert _pool_sized_copies(text, pool) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = len(pools) * pool.size * pool.dtype.itemsize
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0.25 * 16e9 < total < 15e9, total

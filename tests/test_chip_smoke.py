@@ -100,3 +100,20 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, placed):
         want = os.path.join(REPO, ".jax_cache")
         assert compile_cache.enable_compile_cache() == want
         assert updates == [("jax_compilation_cache_dir", want)]
+
+
+def test_latent_phase_runs_at_toy_widths_on_cpu():
+    cfg = chip_smoke._latent_config()
+    cfg = {**cfg, **cfg["toy"]}
+    line = chip_smoke.latent_phase(
+        cfg, layers=2, num_blocks=67, prompt_lens=(50, 41), shared_prefix=32,
+        new_tokens=4, seed=0)
+    assert line["failed"] == [
+        "no Pallas kernel in the engine's step program"], line
+    assert line["engine_compiles"] == 1 and line["hit_tokens"] == 32
+    assert line["max_chunk_tokens"] == 32        # the 50 in two chunks
+    assert line["step_pool_sized_copies"] == 0
+    # float32 on one backend: the served tokens are the reference's
+    assert line["logit_err_share_of_max"] < 1e-4
+    assert line["tokens_equal_to_reference"] == "8/8"
+    assert line["expert_pairs"] == (50 + 9 + 2 * 3) * 2

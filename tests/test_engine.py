@@ -617,6 +617,31 @@ def test_step_consumes_the_pools_and_serves_the_same(model_and_vars, tier,
     eng.cache.assert_quiesced()
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_pick_scores_a_greedy_token_against_the_steps_log_sum_exp(
+        temperature):
+    """`_sample` leaves a greedy token's log-probability to `_pick`,
+    which takes it from the row's log-sum-exp as the step computes it
+    (float32): the plain softmax's, within float32 rounding of a
+    float64 pass over the row. A sampled token is scored by `_sample`
+    itself, under its temperature."""
+    from paddle_tpu.engine.engine import _pick, _sample
+    row = (np.random.default_rng(3).standard_normal(50_000)
+           * 2.5).astype(np.float32)
+    lse = np.asarray(jax.nn.logsumexp(jnp.asarray(row)))
+    req = Request(prompt=[1], max_new_tokens=1, temperature=temperature,
+                  seed=11)
+    tok, lp = _pick(row, lse, req, 4)
+    z = row.astype(np.float64) / (temperature or 1.0)
+    want = z - z.max() - np.log(np.exp(z - z.max()).sum())
+    assert lp == pytest.approx(want[tok], abs=2e-6)
+    if temperature:
+        assert _sample(row, req, 4) == (tok, lp)
+    else:
+        assert (tok, _sample(row, req, 4)) == (int(row.argmax()),
+                                               (tok, None))
+
+
 def test_failed_donated_step_leaves_a_serving_engine(model_and_vars):
     """A step that raises after it consumed the donated pools: the
     engine rebuilds them, sends what was running back to the queue, and
@@ -641,7 +666,7 @@ def test_failed_donated_step_leaves_a_serving_engine(model_and_vars):
     eng._step_fn = real
     assert not any(p.is_deleted() for p in eng.cache.pools)
     assert not eng.scheduler.running and eng.scheduler.queue_depth == 4
-    assert eng.cache.used_blocks == 0 and not eng.cache.prefix_keys()
+    assert eng.cache.used_blocks == 0 and not eng.cache.prefix_rows()
     eng.run()
     assert [eng._generated_of(r) for r in reqs] == want
     late = eng.generate([[9, 9, 8]], max_new_tokens=4)

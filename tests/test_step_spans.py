@@ -267,3 +267,48 @@ def test_step_reads_the_clock_at_most_twice_a_span(drained):
     # and the engine has no other clock
     source = inspect.getsource(engine_mod)
     assert "perf_counter" not in source and "time.monotonic" not in source
+
+
+def test_attention_counts_agree_with_the_counters(drained):
+    """`kv_tokens_read` is the sum of the step's rows' contexts and
+    `attn_keys` the keys its query tokens attend; the host knows both,
+    whatever the model."""
+    eng, events = drained
+    steps = _spans(events, "engine.step")
+    assert sum(st["args"]["kv_tokens_read"] for st in steps) == \
+        eng.obs.get("ptpu_attn_kv_tokens_read_total").value > 0
+    assert sum(st["args"]["attn_keys"] for st in steps) == \
+        eng.obs.get("ptpu_attn_keys_attended_total").value
+    # every computed token attends itself and what came before it
+    want = sum((len(p) + 5) * (len(p) + 6) // 2 for p in PROMPTS)
+    assert eng.obs.get("ptpu_attn_keys_attended_total").value == want
+    # a dense model has no expert to count
+    assert "moe_assignments" not in steps[0]["args"]
+    assert eng.obs.get("ptpu_moe_assignments_total").value == 0
+
+
+def test_expert_counts_agree_with_the_counters():
+    """A model with expert layers: `moe_assignments` is real (row,
+    choice) pairs over the expert layers, `moe_active_experts` the
+    (layer, expert) pairs a step touched."""
+    from paddle_tpu.models.latent_moe import LatentMoELM
+    model = LatentMoELM(vocab=VOCAB, model_dim=16, num_heads=2, num_layers=3,
+                        q_rank=8, kv_rank=8, nope_dim=4, rope_dim=4, v_dim=4,
+                        dense_dim=32, expert_dim=8, num_experts=8, top_k=2,
+                        max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=4)
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    steps = _spans(prof.get_events(), "engine.step")
+    computed = sum(len(p) + 5 for p in PROMPTS)
+    assert sum(st["args"]["moe_assignments"] for st in steps) == \
+        eng.obs.get("ptpu_moe_assignments_total").value == computed * 2 * 2
+    active = [st["args"]["moe_active_experts"] for st in steps]
+    assert sum(active) == eng.obs.get("ptpu_moe_active_experts_total").value
+    assert all(0 < a <= min(2 * 8, st["args"]["moe_assignments"])
+               for a, st in zip(active, steps))
+    assert sum(st["args"]["kv_tokens_read"] for st in steps) == \
+        eng.obs.get("ptpu_attn_kv_tokens_read_total").value
+    assert eng.expert_tokens.sum() == computed * 2 * 2
