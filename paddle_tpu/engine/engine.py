@@ -76,6 +76,7 @@ from paddle_tpu.engine.paged_cache import (PagedKVCache, pack_kv,
                                            refuse_latent, unpack_kv)
 from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
+from paddle_tpu.kernels.paged_attention import ragged_span
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
 from paddle_tpu.obs.tracing import RequestTracer
 from paddle_tpu.profiler.profiler import annotate, now_us
@@ -437,6 +438,13 @@ class ServeEngine:
             np.asarray(self.cache.qpools[0][0])   # the host-spill gathers
             float(self.cache.qscales[0][0][0])
         self.max_blocks_per_seq = self.cache.blocks_for(self.max_seq_len)
+        # keys a grid cell of the ragged kernel covers (kernels/
+        # paged_attention.py `ragged_span`, on a chip's own pool rows):
+        # what `attn_cells` counts cells by
+        pool = self.cache.pools[0]
+        self._cell_keys = block_size * ragged_span(
+            block_size, pool.shape[2] // self.tp_size, pool.dtype.itemsize,
+            self.max_blocks_per_seq)
         self.scheduler = Scheduler(
             self.cache, max_batch_size=max_batch_size,
             max_prefill_tokens=max_prefill_tokens,
@@ -546,6 +554,11 @@ class ServeEngine:
             "ptpu_attn_keys_attended_total",
             "Keys attended (position + 1) summed over the steps' real "
             "query tokens")
+        self._m_attn_cells = m.counter(
+            "ptpu_attn_cells_total",
+            "Grid cells with work the ragged kernel runs a layer: "
+            "summed over the steps' query tiles (pad tiles too), the "
+            "spans of pool blocks the tile reaches")
         self._m_moe_assign = m.counter(
             "ptpu_moe_assignments_total",
             "Real (row, choice) pairs routed to an expert, summed over "
@@ -777,6 +790,7 @@ class ServeEngine:
         entry the addend of the counter of its name."""
         self._m_kv_read.inc(asked["kv_tokens_read"])
         self._m_attn_keys.inc(asked["attn_keys"])
+        self._m_attn_cells.inc(asked["attn_cells"])
         if "moe_assignments" in asked:
             self._m_moe_assign.inc(asked["moe_assignments"])
             self._m_moe_active.inc(asked["moe_active_experts"])
@@ -1070,7 +1084,7 @@ class ServeEngine:
             tile_rows = np.full((nt,), b, np.int32)  # pad tiles -> null row
             tile_offs = np.zeros((nt,), np.int32)
             last_idx = np.zeros((b, self.spec_len), np.int32)
-            cursor = kv_read = attn_keys = 0
+            cursor = kv_read = attn_keys = cells = 0
             for i, row in enumerate(rows):
                 r = row.req
                 toks = r.tokens
@@ -1104,7 +1118,12 @@ class ServeEngine:
                 for k in range(ntiles):
                     tile_rows[t0 + k] = i
                     tile_offs[t0 + k] = k * tq
+                    # the tile reaches the row's context, cut at its
+                    # last query's causal edge: the spans up to there
+                    reach = row.start + min(row.length, (k + 1) * tq)
+                    cells += -(-reach // self._cell_keys)
                 cursor += ntiles * tq
+            cells += nt - cursor // tq   # a pad tile: the null row's one
         with annotate("engine.dispatch", step=step):
             (logits, lse), self.cache.pools, *per_expert = self._donating(
                 self._step_fn,
@@ -1115,7 +1134,8 @@ class ServeEngine:
         with annotate("engine.fetch", step=step) as span:
             logits, lse = np.asarray(logits), np.asarray(lse)
             span.set(bytes=logits.nbytes + lse.nbytes)
-            asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys}
+            asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys,
+                     "attn_cells": cells}
             if per_expert:
                 per_expert = np.asarray(per_expert[0])
                 self.expert_tokens += per_expert

@@ -311,8 +311,19 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 # carries p.v in lanes [D, 2D): no sub-tile lane slicing per block, and
 # a 128-deep contraction where head_dim 64 half-filled it.
 #
+# Grid: (query tiles, SPANS of the block table). A cell is one tile
+# against S consecutive table entries — S read off the pool's shape by
+# `ragged_span`, never an option — and does ONE online-softmax update
+# over S * BS keys. The pool never rides a BlockSpec: it stays in HBM
+# (memory_space ANY) and a cell with work copies its span's blocks
+# itself, one DMA a block into a [2, S, BS, lanes] scratch, the next
+# cell's span in flight while this one computes (`_ragged_cell`). A
+# cell costs the scalar core about 0.1 us an index map it evaluates,
+# work or not, so S index maps a cell buy nothing (PERF.md §6, PR 28);
+# a cell without work now costs its grid step and nothing else.
+#
 # A LATENT pool (one entry a token, no head axis: `value_lanes=(0, V)`)
-# rides the same grid, index maps and DMA: every one of the H query
+# rides the same grid, cell and DMA: every one of the H query
 # heads reads the one cached row (groups = H), q is [q~ | q_rope] padded
 # to the row's lanes, the scores contract the whole row and the
 # accumulator keeps lanes [0, V) — a query width and a value width that
@@ -467,42 +478,43 @@ def _block_heads(rows, hkv: int):
     return jnp.transpose(rows.reshape(bs, hkv, lanes // hkv), (1, 0, 2))
 
 
-def _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr, *,
-                        scale: float, block_size: int, groups: int):
-    """Online-softmax update for one (query-tile, kv-block) cell —
-    shared by the fp-only and mixed-precision ragged kernels. q:
-    [TQ, H, W], zero beyond lane D; kv: [Hkv, BS, W], each head's
-    [k | v | pad]; scratch rows are flattened TQ*H, the accumulator W
-    lanes wide with p.v in lanes [D, 2D). Over a latent pool q comes
-    flattened, [TQ*H, W] against the one kv "head" [1, BS, W], and the
-    accumulator keeps the row's leading value lanes only."""
-    hkv = kv.shape[0]
+def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
+                        scale: float, groups: int):
+    """Online-softmax update for one (query-tile, span of kv blocks)
+    cell — shared by the fp-only and mixed-precision ragged kernels. q:
+    [TQ, H, W], zero beyond lane D; kv: [Hkv, K, W], the span's K keys
+    from absolute position k0 on, each head's [k | v | pad]; scratch
+    rows are flattened TQ*H, the accumulator W lanes wide with p.v in
+    lanes [D, 2D). Over a latent pool q comes flattened, [TQ*H, W]
+    against the one kv "head" [1, K, W], and the accumulator keeps the
+    row's leading value lanes only."""
+    hkv, keys, _ = kv.shape
     flat = q.ndim == 2        # one kv head: rows are (query, head) pairs
     if flat:
         h = groups
         tq = q.shape[0] // h
         s = jax.lax.dot_general(
             q, kv[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [TQ*H, BS]
+            preferred_element_type=jnp.float32) * scale   # [TQ*H, K]
     else:
         tq, h, _ = q.shape
-        # batch over kv heads: [Hkv, TQ*G, W] x [Hkv, BS, W]; q's zero
+        # batch over kv heads: [Hkv, TQ*G, W] x [Hkv, K, W]; q's zero
         # lanes drop v out of the contraction
         qg = _heads_to_kv_major(q, hkv, groups)
         s = jax.lax.dot_general(
             qg, kv, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [Hkv, TQ*G, BS]
-        s = _kv_major_to_rows(s, tq, groups)            # [TQ*H, BS]
+            preferred_element_type=jnp.float32) * scale  # [Hkv, TQ*G, K]
+        s = _kv_major_to_rows(s, tq, groups)            # [TQ*H, K]
     qpos = q0 + jax.lax.broadcasted_iota(
-        jnp.int32, (tq, h, block_size), 0).reshape(tq * h, block_size)
-    kpos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (tq, h, block_size), 2).reshape(tq * h, block_size)
+        jnp.int32, (tq, h, keys), 0).reshape(tq * h, keys)
+    kpos = k0 + jax.lax.broadcasted_iota(
+        jnp.int32, (tq, h, keys), 2).reshape(tq * h, keys)
     s = jnp.where((kpos <= qpos) & (kpos < ctx), s, NEG_INF)
 
     m_prev = m_scr[...][:, :1]                      # [TQ*H, 1]
     l_prev = l_scr[...][:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                          # [TQ*H, BS]
+    p = jnp.exp(s - m_new)                          # [TQ*H, K]
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
     if flat:    # the value lanes lead the row: whole 128-lane tiles
@@ -511,7 +523,7 @@ def _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr, *,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)     # [TQ*H, V]
     else:
-        pg = _heads_to_kv_major(p.reshape(tq, h, block_size), hkv, groups)
+        pg = _heads_to_kv_major(p.reshape(tq, h, keys), hkv, groups)
         pv = _kv_major_to_rows(jax.lax.dot_general(
             pg.astype(kv.dtype), kv, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32), tq, groups)  # [TQ*H, W]
@@ -531,20 +543,83 @@ def _ragged_finalize(o_ref, l_scr, acc_scr, v_off=None):
                   ).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
-def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
-                   q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   scale: float, block_size: int, tile_q: int, groups: int,
-                   v_off=None):
-    """One (query-tile, kv-block) grid cell. q_ref: [TQ, H, W] — one
-    tile of the flat packing, lane-padded; kv_ref: the pool block the
-    index map selected, [BS, Hkv * W]. Online-softmax scratch is
-    flattened to (TQ*H, ·) rows and persists across the sequential kv
-    axis."""
+# What a grid cell may hold. A cell with work costs about a microsecond
+# before it has touched a key (PERF.md §6, PR 28), so it takes as many
+# blocks as pay: up to _SPAN_KEYS keys, and no more than _SPAN_BYTES of
+# pool blocks (two spans are in flight, and the cell's own relayout of
+# a span is as large again). The sweep on the chip put the best span
+# at 8 blocks for 16-token blocks of 2,048 and 2,560 lanes (16 is
+# slower) and at 4 for 128-token blocks of 640 lanes.
+_SPAN_KEYS = 512
+_SPAN_BYTES = 768 << 10
+
+
+def ragged_span(block_size: int, lanes: int, itemsize: int,
+                max_blocks: int) -> int:
+    """How many consecutive block-table entries one grid cell of the
+    ragged kernel covers. Read off the pool's shape ([*, block_size,
+    lanes] of `itemsize` bytes — a tensor-parallel shard's own) and the
+    table's width: the largest power of two within _SPAN_KEYS keys,
+    _SPAN_BYTES of blocks, and the table."""
+    fits = min(_SPAN_KEYS // block_size,
+               _SPAN_BYTES // (block_size * lanes * itemsize), max_blocks)
+    return 1 << (max(fits, 1).bit_length() - 1)
+
+
+def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
+                 load_span, o_ref, m_scr, l_scr, acc_scr, bufs, cnt, *,
+                 scale: float, span: int, tile_q: int, groups: int,
+                 v_off=None):
+    """One (query-tile, span) grid cell, shared by both ragged kernels.
+    The pools stay in HBM; a cell with work waits for its span's blocks
+    in one of two VMEM buffers and, before it computes, starts the
+    copies of the NEXT cell with work into the other — the next span of
+    this tile, else the first span of the next tile (every tile reaches
+    a key, so that cell exists and will wait for them). Cells without
+    work touch nothing: no index map rides the kv axis.
+
+    `span_copies(row, j, slot)` lists span j of table row `row` as
+    (place in the span, whether this copy serves the place, the DMA
+    into buffer `slot`); `load_span(row, j, slot)` gives the span's
+    keys as [Hkv, K, W] ([1, K, W] over a latent pool). Online-softmax
+    scratch is flattened to (TQ*H, ·) rows and persists across the
+    sequential kv axis."""
     t, j = pl.program_id(0), pl.program_id(1)
-    nblk = pl.num_programs(1)
-    row = tr_ref[t]
-    ctx = cl_ref[row]
-    q0 = qs_ref[row] + to_ref[t]        # absolute pos of the tile's 1st query
+    bs = bufs[0].shape[2]
+    span_keys = span * bs
+
+    def tile(ti):
+        """(table row, context, first query position, keys reached) of
+        query tile ti. It attends positions below its row's context,
+        cut at the causal edge of its LAST query (q0 + tile_q - 1):
+        blocks at and past that reach have no work for it (the
+        engine's `attn_cells` counts cells by the same rule)."""
+        row = tr_ref[ti]
+        ctx = cl_ref[row]
+        q0 = qs_ref[row] + to_ref[ti]
+        return row, ctx, q0, jnp.minimum(ctx, q0 + tile_q)
+
+    def move(go, ti, sj, slot):
+        """Start (go) or await the copies of tile ti's span sj: its
+        blocks within the tile's reach, no others."""
+        row, _, _, reach = tile(ti)
+        blocks = -(-reach // bs) - sj * span
+        for i, serves, copy in span_copies(row, sj, slot):
+            @pl.when((i < blocks) & serves)
+            def _():
+                copy.start() if go else copy.wait()
+
+    row, ctx, q0, reach = tile(t)
+
+    @pl.when((t == 0) & (j == 0))
+    def _first():
+        # a span's blocks past its tile's reach are never copied: what
+        # the buffers hold there must be finite (masked scores give an
+        # exact 0 weight, and 0 times a stale NaN would not be 0)
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        cnt[0] = 0
+        move(True, 0, 0, 0)
 
     @pl.when(j == 0)
     def _init():
@@ -552,74 +627,120 @@ def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # skip blocks entirely past the row's context OR entirely in the
-    # causal future of the tile's LAST query (position q0 + tile_q - 1)
-    @pl.when((j * block_size < ctx) & (j * block_size <= q0 + tile_q - 1))
+    # a span has work if its FIRST key is within the tile's reach; the
+    # update's mask cuts the span the context or the causal edge ends
+    # in, block boundary or not
+    @pl.when(j * span_keys < reach)
     def _compute():
-        q = q_ref[...]
-        kv = (kv_ref[...][None] if q.ndim == 2 else
-              _block_heads(kv_ref[...], q.shape[1] // groups))
-        _ragged_tile_update(q, kv, q0, ctx, j, m_scr, l_scr, acc_scr,
-                            scale=scale, block_size=block_size,
-                            groups=groups)
+        slot = cnt[0] % 2
+        more = (j + 1) * span_keys < reach
 
-    @pl.when(j == nblk - 1)
+        @pl.when(more)
+        def _next_span():
+            move(True, t, j + 1, 1 - slot)
+
+        @pl.when(jnp.logical_not(more) & (t + 1 < pl.num_programs(0)))
+        def _next_tile():
+            move(True, t + 1, 0, 1 - slot)
+
+        move(False, t, j, slot)
+        _ragged_tile_update(q_ref[...], load_span(row, j, slot), q0, ctx,
+                            j * span_keys, m_scr, l_scr, acc_scr,
+                            scale=scale, groups=groups)
+        cnt[0] += 1
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         _ragged_finalize(o_ref, l_scr, acc_scr, v_off)
 
 
+def _block_copy(pool_ref, block, buf, sem, slot, place):
+    """The DMA of one pool block into its place of buffer `slot`, on
+    the slot's semaphore: built alike to start it and to await it."""
+    return pltpu.make_async_copy(pool_ref.at[block], buf.at[slot, place],
+                                 sem.at[slot])
+
+
+def _span_entries(bt_ref, row, j, span: int):
+    """(place, table entry) of each block of span j of table row `row`;
+    places past the table's width repeat its last entry (no tile
+    reaches them: a context fits its table)."""
+    last = bt_ref.shape[1] - 1
+    return [(i, bt_ref[row, jnp.minimum(j * span + i, last)])
+            for i in range(span)]
+
+
+def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
+                   o_ref, m_scr, l_scr, acc_scr, buf, sem, cnt, *,
+                   span: int, groups: int, **cell):
+    """q_ref: [TQ, H, W] — one tile of the flat packing, lane-padded
+    ([TQ*H, W] over a latent pool); pool_ref: the whole pool, in HBM;
+    buf: [2, span, BS, Hkv * W], the two spans in flight."""
+
+    def span_copies(row, j, slot):
+        return [(i, True, _block_copy(pool_ref, e, buf, sem, slot, i))
+                for i, e in _span_entries(bt_ref, row, j, span)]
+
+    def load_span(row, j, slot):
+        rows = buf[slot].reshape(-1, buf.shape[-1])     # [span * BS, lanes]
+        return (rows[None] if q_ref.ndim == 2 else
+                _block_heads(rows, q_ref.shape[1] // groups))
+
+    _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
+                 load_span, o_ref, m_scr, l_scr, acc_scr, (buf,), cnt,
+                 span=span, groups=groups, **cell)
+
+
 def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
-                         ksc_ref, vsc_ref,
-                         q_ref, kv_ref, kvq_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *,
-                         scale: float, block_size: int, tile_q: int,
-                         groups: int):
-    """Mixed-precision variant: the block table entry is bias-encoded
-    (id >= 0 -> fp pool block id; id < 0 -> int8 pool slot -id-1). Both
-    pools ride their own BlockSpec — each index map degenerates to slot
-    0 for the tier it does NOT serve, so only the selected tier's DMA
-    changes block-to-block — and the kernel dequantizes the int8 block
-    in registers with the per-block k and v scales from scalar
-    prefetch, each over its own lanes of a head. The dequant is
-    bit-identical to quant.dequantize_block, which is what pins
-    direct-read output to the promote path's bytes."""
-    t, j = pl.program_id(0), pl.program_id(1)
-    nblk = pl.num_programs(1)
-    row = tr_ref[t]
-    ctx = cl_ref[row]
-    q0 = qs_ref[row] + to_ref[t]
-    e = bt_ref[row, j]
-    is8 = e < 0
-    slot = jnp.where(is8, -e - 1, 0)
+                         ksc_ref, vsc_ref, q_ref, pool_ref, qpool_ref,
+                         o_ref, m_scr, l_scr, acc_scr, buf, qbuf, sem, cnt,
+                         *, span: int, groups: int, **cell):
+    """Mixed-precision variant: a block table entry is bias-encoded
+    (id >= 0 -> fp pool block id; id < 0 -> int8 pool slot -id-1). A
+    block is copied from the pool of the tier that serves it, into
+    that tier's buffer, and the kernel dequantizes the int8 block in
+    registers with the per-block k and v scales from scalar prefetch,
+    each over its own lanes of a head. The dequant is bit-identical to
+    quant.dequantize_block, which is what pins direct-read output to
+    the promote path's bytes."""
+    hkv, d = q_ref.shape[1] // groups, o_ref.shape[-1]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def span_copies(row, j, slot):
+        copies = []
+        for i, e in _span_entries(bt_ref, row, j, span):
+            copies += [(i, e >= 0, _block_copy(pool_ref, jnp.maximum(e, 0),
+                                               buf, sem, slot, i)),
+                       (i, e < 0, _block_copy(qpool_ref,
+                                              jnp.maximum(-e - 1, 0), qbuf,
+                                              sem, slot, i))]
+        return copies
 
-    @pl.when((j * block_size < ctx) & (j * block_size <= q0 + tile_q - 1))
-    def _compute():
-        q = q_ref[...]
-        hkv, d = q.shape[1] // groups, o_ref.shape[-1]
-        fp = _block_heads(kv_ref[...], hkv)             # [Hkv, BS, W]
-        q8 = _block_heads(kvq_ref[...].astype(jnp.float32), hkv)
-        lane = jax.lax.broadcasted_iota(jnp.int32, q8.shape, 2)
-        sc = jnp.where(lane < d, ksc_ref[slot] * _RQMAX,
-                       vsc_ref[slot] * _RQMAX)
-        kv = jnp.where(is8, (q8 * sc).astype(fp.dtype), fp)
-        _ragged_tile_update(q, kv, q0, ctx, j,
-                            m_scr, l_scr, acc_scr, scale=scale,
-                            block_size=block_size, groups=groups)
+    def load_span(row, j, slot):
+        blocks = []
+        for i, e in _span_entries(bt_ref, row, j, span):
+            is8 = e < 0
+            s8 = jnp.where(is8, -e - 1, 0)
+            fp = _block_heads(buf[slot, i], hkv)            # [Hkv, BS, W]
+            q8 = _block_heads(qbuf[slot, i].astype(jnp.float32), hkv)
+            lane = jax.lax.broadcasted_iota(jnp.int32, q8.shape, 2)
+            sc = jnp.where(lane < d, ksc_ref[s8] * _RQMAX,
+                           vsc_ref[s8] * _RQMAX)
+            blocks.append(jnp.where(is8, (q8 * sc).astype(fp.dtype), fp))
+        return jnp.concatenate(blocks, axis=1)
 
-    @pl.when(j == nblk - 1)
-    def _finalize():
-        _ragged_finalize(o_ref, l_scr, acc_scr)
+    _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
+                 load_span, o_ref, m_scr, l_scr, acc_scr, (buf, qbuf), cnt,
+                 span=span, groups=groups, **cell)
 
 
+# jitted so that a model's layers, which call it at one set of shapes,
+# trace and lower the kernel once between them (set-up time: a span's
+# unrolled copies make the body long to trace)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "groups",
+                                             "span", "value_lanes"))
 def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
                         q_starts, tile_rows, tile_offs, scale,
-                        interpret: bool, groups: int,
+                        interpret: bool, groups: int, span: int,
                         kvq_pool=None, k_scales=None, v_scales=None,
                         value_lanes=None):
     t, h, d = q.shape
@@ -659,72 +780,40 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
         out_shape = (t, h, d)
     zeros = (0,) * (len(q_block) - 1)
 
-    def _active(ti, j, cl, qs, tr, to):
-        # skip predicate shared by every kv index map: inactive cells
-        # re-select block 0, which elides the DMA entirely when the
-        # previous cell already holds it (Pallas skips re-fetch on an
-        # unchanged block index)
-        row = tr[ti]
-        return ((j * bs < cl[row])
-                & (j * bs <= qs[row] + to[ti] + tq - 1))
+    def _q_map(ti, j, *prefetched):
+        return (ti,) + zeros
 
-    def _kv_block(ti, j, bt, cl, qs, tr, to):
-        return (jnp.where(_active(ti, j, cl, qs, tr, to),
-                          bt[tr[ti], j], 0), 0, 0)
-
-    def _kv_fp(ti, j, bt, cl, qs, tr, to, ksc, vsc):
-        # bias-encoded entry: only non-negative ids live in the fp pool
-        e = bt[tr[ti], j]
-        act = _active(ti, j, cl, qs, tr, to) & (e >= 0)
-        return (jnp.where(act, e, 0), 0, 0)
-
-    def _kv_q(ti, j, bt, cl, qs, tr, to, ksc, vsc):
-        # negative ids decode to int8 pool slot -id-1
-        e = bt[tr[ti], j]
-        act = _active(ti, j, cl, qs, tr, to) & (e < 0)
-        return (jnp.where(act, -e - 1, 0), 0, 0)
-
-    if mixed:
-        def _q_map(ti, j, bt, cl, qs, tr, to, ksc, vsc):
-            return (ti,) + zeros
-        # block_tables, ctx_lens, q_starts, tiles x2, k/v scales
-        num_prefetch = 7
-        in_specs = [
-            pl.BlockSpec(q_block, _q_map),
-            pl.BlockSpec((None, bs, lanes), _kv_fp),
-            pl.BlockSpec((None, bs, lanes), _kv_q),
-        ]
-        kernel_fn = _ragged_kernel_mixed
-    else:
-        def _q_map(ti, j, bt, cl, qs, tr, to):
-            return (ti,) + zeros
-        num_prefetch = 5  # block_tables, ctx_lens, q_starts, tiles x2
-        in_specs = [
-            pl.BlockSpec(q_block, _q_map),
-            pl.BlockSpec((None, bs, lanes), _kv_block),
-        ]
-        kernel_fn = (functools.partial(_ragged_kernel, v_off=v_off)
-                     if latent else _ragged_kernel)
+    # the pools stay where they lie; the kernel copies the blocks it
+    # needs itself (`_ragged_cell`), two spans in flight a pool
+    pools = (kv_pool, kvq_pool) if mixed else (kv_pool,)
+    # block_tables, ctx_lens, q_starts, tiles x2 (+ k/v scales)
+    num_prefetch = 7 if mixed else 5
+    kernel_fn = (_ragged_kernel_mixed if mixed else
+                 functools.partial(_ragged_kernel, v_off=v_off))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
-        grid=(nt, mb),
-        in_specs=in_specs,
+        grid=(nt, -(-mb // span)),
+        in_specs=[pl.BlockSpec(q_block, _q_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec(o_block, _q_map),
         scratch_shapes=[
             _scratch((tq * h, LANES)),
             _scratch((tq * h, LANES)),
             _scratch((tq * h, acc_w)),
-        ],
+        ] + [pltpu.VMEM((2, span, bs, lanes), p.dtype) for p in pools]
+        + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)],
     )
-    kernel = functools.partial(kernel_fn, scale=scale, block_size=bs,
+    kernel = functools.partial(kernel_fn, scale=scale, span=span,
                                tile_q=tq, groups=groups)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        # tiles in order too: a tile's last cell starts the next tile's
+        # first copies
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ragged_latent_attention" if latent else None,
     )
@@ -774,9 +863,12 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
             tile_rows, tile_offs, scale=scale, groups=groups,
             kvq_pool=kvq_pool, k_scales=k_scales, v_scales=v_scales,
             value_lanes=value_lanes)
+    _, bs, lanes = kv_pool.shape
+    span = ragged_span(bs, lanes, kv_pool.dtype.itemsize,
+                       block_tables.shape[1])
     return _ragged_kernel_call(q, kv_pool, block_tables,
                                context_lens, q_starts, tile_rows, tile_offs,
-                               scale, interpret, groups,
+                               scale, interpret, groups, span,
                                kvq_pool=kvq_pool,
                                k_scales=k_scales, v_scales=v_scales,
                                value_lanes=value_lanes)

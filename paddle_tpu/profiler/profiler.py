@@ -56,7 +56,7 @@ def record(name: str, ts: float, dur: float, **args) -> None:
     `request` here when it finishes) to the ring, stamps on `now_us`,
     under the calling thread's id."""
     ev = {"name": name, "ts": ts, "dur": dur,
-          "tid": threading.get_ident() & 0xFFFF, "args": args}
+          "tid": threading.get_native_id(), "args": args}
     with _lock:
         _events.append(ev)
 

@@ -63,14 +63,24 @@ def _pool_rows(nb, bs, kv_heads, head_dim, dtype, sharding):
     (4, 4, 64, False),       # its tp=4 per-shard slice
     (4, 4, 64, True),
     (32, 4, 128, False),     # GQA
+    (20, 20, 64, False),     # GPT-2 large: rows of 2,560 lanes
+    (20, 20, 64, True),
 ], ids=["mha16x64-fp", "mha16x64-int8_mixed", "tp4_slice-fp",
-        "tp4_slice-int8_mixed", "gqa32_4x128-fp"])
+        "tp4_slice-int8_mixed", "gqa32_4x128-fp", "mha20x64-fp",
+        "mha20x64-int8_mixed"])
 def test_ragged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, head_dim,
                                         mixed):
-    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+    """The ragged kernel at the span its pool's shape gives (8 blocks a
+    cell at 16 and 20 heads, 32 on a tp=4 slice, 4 at GQA's 1,024
+    lanes): every block of the span an operand of its own."""
+    from paddle_tpu.engine.paged_cache import head_lanes
+    from paddle_tpu.kernels.paged_attention import (ragged_paged_attention,
+                                                    ragged_span)
     # the engine's default step: 512-token chunk budget + 8 decode rows,
     # tile_q 8, block 16, max_len 1024
     t, tq, bs, nb, mb, rows, nq = 576, 8, 16, 2048, 64, 9, 256
+    assert ragged_span(bs, kv_heads * head_lanes(head_dim), 2, mb) == {
+        16: 8, 20: 8, 4: 32, 32: 16}[heads]
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -215,6 +225,29 @@ def test_tp4_step_updates_its_pool_shards_in_place(topo, heads, blocks,
     assert _pool_sized_copies(text, pools[0]) == []
     assert (compiled.memory_analysis().alias_size_in_bytes
             >= 2 * shard.size * 2)
+
+
+def test_latent_ragged_kernel_compiles_for_v5e(one_chip):
+    """The latent row at `glm47f-docs8k`'s shapes alone (the whole step
+    is below): 20 heads over one 640-lane row a token, blocks of 128,
+    a table of 72, so four blocks and 512 keys a cell."""
+    from paddle_tpu.kernels.paged_attention import (ragged_paged_attention,
+                                                    ragged_span)
+    t, tq, heads, bs, nb, mb, rows = 1152, 8, 20, 128, 2048, 72, 17
+    assert ragged_span(bs, 640, 2, mb) == 4
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(*args):
+        return ragged_paged_attention(
+            *args, use_kernel=True, interpret=False, groups=heads,
+            value_lanes=(0, 512), scale=0.1)
+    text = jax.jit(fn).lower(
+        s((t, heads, 576), jnp.bfloat16), s((nb, bs, 640), jnp.bfloat16),
+        s((rows, mb)), s((rows,)), s((rows,)), s((t // tq,)), s((t // tq,))
+    ).compile().as_text()
+    assert "ragged_latent_attention" in text and "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

@@ -412,3 +412,88 @@ def test_env_override_dispatch_covers_mixed(monkeypatch):
     monkeypatch.setenv("PTPU_PAGED_KERNEL", "reference")
     got = ragged_paged_attention(*margs, **qkw)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
+
+
+# -- a span of pool blocks a grid cell -------------------------------------
+
+@pytest.mark.parametrize("bs,lanes,itemsize,mb,want", [
+    (16, 2048, 2, 64, 8),     # gpt2m-chat: 128 keys, 512 KB a cell
+    (16, 2560, 2, 64, 8),     # gpt2l-docs: 640 KB
+    (128, 640, 2, 72, 4),     # glm47f-docs8k's latent pool: 512 keys
+    (16, 512, 2, 64, 32),     # medium's tp=4 shard: 512 keys, 512 KB
+    (16, 640, 2, 64, 32),     # large's tp=4 shard
+    (512, 2048, 2, 16, 1),    # a block that is a cell's keys already
+    (128, 4096, 2, 16, 1),    # ... or a cell's bytes
+    (16, 2048, 2, 3, 2),      # never past the table
+    (4, 128, 4, 10, 8),       # the tests' shapes: the table bounds it
+], ids=["gpt2m", "gpt2l", "latent128", "gpt2m_tp4", "gpt2l_tp4",
+        "keys_full", "bytes_full", "short_table", "tiny"])
+def test_span_is_read_off_the_pool_shape(bs, lanes, itemsize, mb, want):
+    from paddle_tpu.kernels.paged_attention import ragged_span
+    assert ragged_span(bs, lanes, itemsize, mb) == want
+
+
+# Rows that a span makes new, at 4-token blocks and 4-query tiles; with
+# a span of 4 blocks (16 keys) they are: a context that ends in a
+# span's first block (18), in its last (31), exactly on a span's edge
+# (16, 32), a chunk whose tiles' causal edges fall inside a span (38/10:
+# queries 28..37), a whole prompt of one span, and the pad tile; the
+# table is 10 wide, no multiple of 2 or 4.
+SPAN_ROWS = [(18, 1), (31, 1), (32, 1), (38, 10), (16, 16)]
+
+
+def _span_case(kind, span, monkeypatch):
+    from paddle_tpu.engine.paged_cache import latent_lanes, pack_latent
+    from paddle_tpu.kernels import paged_attention as pa
+    bs, tq = 4, 4
+    h, hkv, d = {"mha": (4, 4, 8), "gqa": (8, 2, 16), "mixed": (4, 4, 8),
+                 "latent": (4, 1, 20)}[kind]
+    args, *_, spans = _ragged_case(SPAN_ROWS, h, hkv, d, bs, tq)
+    kw = dict(groups=h // hkv)
+    if kind == "latent":      # one row a token, its first 16 the value
+        k_pool, _ = unpack_kv(args[1], d)
+        args = (args[0], jnp.asarray(pack_latent(np.asarray(k_pool[:, :, 0]),
+                                                 latent_lanes(d))), *args[2:])
+        kw.update(value_lanes=(0, 16), scale=0.3)
+    # S is read off the pool's shape: steered here, in the test, by the
+    # keys a cell may hold
+    monkeypatch.setattr(pa, "_SPAN_KEYS", span * bs)
+    assert args[2].shape[1] == 10
+    assert pa.ragged_span(bs, args[1].shape[2], 4, 10) == span
+    return args, kw, spans
+
+
+@pytest.mark.parametrize("span", [1, 2, 4, 8])
+@pytest.mark.parametrize("kind", ["mha", "gqa", "latent"])
+def test_ragged_kernel_over_spans_matches_reference(kind, span, monkeypatch):
+    """The kernel in interpret mode against the XLA oracle where a cell
+    covers `span` blocks: 1 (a block already large), two that leave the
+    table's tenth entry in a span of its own or in a short last span,
+    and 8 (one short span and a second one mostly past the table)."""
+    args, kw, spans = _span_case(kind, span, monkeypatch)
+    got = ragged_paged_attention(*args, use_kernel=True, interpret=True, **kw)
+    want = ragged_paged_attention_reference(*args, **{"scale": None, **kw})
+    assert bool(jnp.isfinite(got).all())    # pad queries/tiles stay finite
+    for off, qlen in spans:
+        np.testing.assert_allclose(got[off:off + qlen], want[off:off + qlen],
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("span", [1, 2, 4])
+def test_ragged_mixed_kernel_over_spans(span, monkeypatch):
+    """The int8 tier through the same spans: each block of a span is
+    dequantized by its own entry's scales (odd table entries are int8
+    here, so every span mixes tiers), at the oracle's tolerance and
+    bit for bit against promoting the blocks first."""
+    args, kw, spans = _span_case("mixed", span, monkeypatch)
+    (margs, qkw), (pargs, _), n = _quantize_some_blocks(args)
+    assert n > 0
+    got = ragged_paged_attention(*margs, use_kernel=True, interpret=True,
+                                 **qkw)
+    want = ragged_paged_attention_reference(*margs, **qkw)
+    for off, qlen in spans:
+        np.testing.assert_allclose(got[off:off + qlen], want[off:off + qlen],
+                                   atol=1e-5, rtol=1e-5)
+    promoted = ragged_paged_attention(*pargs, use_kernel=True,
+                                      interpret=True, **kw)
+    assert np.array_equal(np.asarray(got), np.asarray(promoted))

@@ -287,6 +287,42 @@ def test_attention_counts_agree_with_the_counters(drained):
     assert eng.obs.get("ptpu_moe_assignments_total").value == 0
 
 
+def test_attn_cells_is_the_ragged_grid_s_cells_with_work(model_and_vars,
+                                                         monkeypatch):
+    """`attn_cells`: over the step's query tiles, the spans of pool
+    blocks the tile reaches (its row's context, cut at the tile's last
+    query's causal edge), a pad tile one. Held to the counter, and to a
+    count by hand for a step of one decode row, one two-tile chunk and
+    three pad tiles, where a cell is two 4-token blocks."""
+    from paddle_tpu.kernels import paged_attention
+    # the span is read off the pool's shape; steered here by a cell's keys
+    monkeypatch.setattr(paged_attention, "_SPAN_KEYS", 8)
+    eng = _engine(*model_and_vars, max_prefill_tokens=16)
+    assert (eng.num_tiles, eng.tile_q, eng._cell_keys) == (6, 8, 8)
+    prof.reset_profiler()
+    before = eng.obs.get("ptpu_attn_cells_total").value
+    first = eng.add_request(list(range(1, 6)), max_new_tokens=12)
+    for _ in range(3):
+        eng.step()
+    eng.add_request(list(range(30, 58)), max_new_tokens=2)
+    while eng.step():
+        pass
+    steps = _spans(prof.get_events(), "engine.step")
+    assert sum(st["args"]["attn_cells"] for st in steps) == \
+        eng.obs.get("ptpu_attn_cells_total").value - before
+    # the prompt alone: one tile reaching 5 keys, five pad tiles
+    assert steps[0]["args"]["attn_cells"] == 1 + 5
+    mixed = [st["args"] for st in steps
+             if st["args"]["decode_rows"] == 1 and st["args"]["chunk_rows"]]
+    assert [a["chunk_tokens"] for a in mixed] == [16, 12]
+    ctx = mixed[1]["kv_tokens_read"] - 28     # the decode row's context
+    assert 5 < ctx <= 5 + len(first.generated)
+    # the chunk [16, 28): tiles reaching 24 and 28 keys, 3 and 4 cells
+    assert mixed[1]["attn_cells"] == -(-ctx // 8) + 3 + 4 + 3
+    # the chunk [0, 16): its first tile stops at its own causal edge
+    assert mixed[0]["attn_cells"] == -(-(ctx - 1) // 8) + 1 + 2 + 3
+
+
 def test_expert_counts_agree_with_the_counters():
     """A model with expert layers: `moe_assignments` is real (row,
     choice) pairs over the expert layers, `moe_active_experts` the
