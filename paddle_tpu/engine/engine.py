@@ -72,11 +72,11 @@ import numpy as np
 
 from paddle_tpu.core.module import Context, _CtxCore
 from paddle_tpu.engine.kvtier import HostKVTier, prefix_digest
-from paddle_tpu.engine.paged_cache import (PagedKVCache, pack_kv,
-                                           refuse_latent, unpack_kv)
+from paddle_tpu.engine.paged_cache import PagedKVCache, refuse_latent
 from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
-from paddle_tpu.kernels.paged_attention import ragged_span
+from paddle_tpu.kernels.paged_attention import (pack_kv, ragged_span,
+                                                unpack_kv)
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
 from paddle_tpu.obs.tracing import RequestTracer
 from paddle_tpu.profiler.profiler import annotate, now_us
@@ -270,7 +270,8 @@ class ServeEngine:
         self.tracer = tracer if tracer is not None else RequestTracer()
         attn = model.blocks[0].attn
         # what one cached row is, read from the model: kv_heads x
-        # [k | v], or one latent entry a token (paged_cache.py)
+        # [k | v], or one latent entry a token ("The pool's row",
+        # kernels/paged_attention.py)
         latent = getattr(attn, "latent_row", None)
         if latent is not None:
             refuse_latent(int(tp_size), int(kv_compress_blocks))

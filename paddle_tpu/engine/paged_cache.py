@@ -51,34 +51,19 @@ quantize/dequantize run as the engine's fixed-lane eager scatters
 compressed entry ships its int8 payload + scales straight into the
 host tier without a second quantization.
 
-Pool layout (this module owns it; nothing else states a pool's shape):
-one array per layer, [num_blocks, block_size, Hkv * head_lanes(hd)].
-A token's row holds each kv head's K in lanes [0, hd) of the head's
-`head_lanes` and its V in lanes [hd, 2*hd), zero-padded up to a
-multiple of the TPU's 128 lanes (hd 64 -> 128 lanes a head, hd 128 ->
-256). With a lane-dense minor dimension the TPU's default device layout
-of that shape is plain row-major, which is what the step's flat scatter
-(`write_kv`) and the ragged kernel's per-block DMA both use: the step
-updates a donated pool IN PLACE. (A [blocks, bs, Hkv, 64] pool's default
-layout puts the blocks in the lanes, and every step transposed each
-pool into a padded row-major temporary and back.) `pack_kv` /
-`unpack_kv` translate between that row and per-head [.., Hkv, hd] K and
-V; the int8 pools take the same rule, their scales stay per block.
-Under tensor parallelism the row shards over its heads:
-P(None, None, "tp").
-
-A LATENT row (`latent=(k_dim, v_dim)`, what a latent-attention model
-caches) is the pool's second layout: one entry a token and no head
-axis, `k_dim` values in `latent_lanes(k_dim)` lanes (576 -> 640), the
-scores contracting all of them and the first `v_dim` lanes doubling as
-the value (the ragged kernel reads it with `value_lanes=(0, v_dim)`).
-Everything above the row — allocator, prefix index, copy-on-write,
-eviction, the host tier's and the transfer plane's whole-block moves
-(`read_block` / `pack_block`, which carry the row as its two parts
-[value lanes | the rest]) — is the same code. Two things cannot work on
-it and refuse at construction: tensor parallelism (a latent has no head
-to divide over the chips) and the int8 tier (its per-block k and v
-scales are laid over a head's [k | v] halves).
+Pool layout: one array per layer, [num_blocks, block_size, lanes], a
+token's row either each kv head's [k | v | pad] or one latent entry
+(`latent=(k_dim, v_dim)`, what a latent-attention model caches). The
+row's format and its functions live beside the kernel that reads it
+(kernels/paged_attention.py, "The pool's row"); this module asks it for
+the lanes (`pool_shape`) and keeps what is policy. Everything above the
+row — allocator, prefix index, copy-on-write, eviction, the host tier's
+and the transfer plane's whole-block moves (`read_block` / `pack_block`,
+which carry a latent row as its two parts [value lanes | the rest]) — is
+the same code for both rows. Two things cannot work on a latent row and
+refuse at construction (`refuse_latent`): tensor parallelism (a latent
+has no head to divide over the chips) and the int8 tier (its per-block k
+and v scales are laid over a head's [k | v] halves).
 
 Host/device split: this class is the HOST-side allocator + bookkeeping
 (free list, refcounts, per-sequence tables/lengths/tokens, prefix
@@ -103,6 +88,7 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Set, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
 
 if TYPE_CHECKING:
@@ -111,71 +97,6 @@ if TYPE_CHECKING:
 
 class CacheExhausted(Exception):
     """No free blocks; the scheduler must evict (preempt) a sequence."""
-
-
-_LANES = 128    # the TPU's lane count: a pool row is a multiple of it
-
-
-def head_lanes(head_dim: int) -> int:
-    """Lanes one kv head takes in a pool row: K then V side by side,
-    padded up to whole 128-lane tiles."""
-    return -(-2 * head_dim // _LANES) * _LANES
-
-
-def pack_kv(k, v):
-    """Per-head k and v, each [..., Hkv, hd], as pool rows
-    [..., Hkv * head_lanes(hd)] (numpy in, numpy out; jax in, jax
-    out)."""
-    xp = np if isinstance(k, np.ndarray) else jnp
-    hd = k.shape[-1]
-    pad = head_lanes(hd) - 2 * hd
-    parts = [k, v] + ([xp.zeros(k.shape[:-1] + (pad,), k.dtype)]
-                      if pad else [])
-    rows = xp.concatenate(parts, axis=-1)
-    return rows.reshape(k.shape[:-2] + (-1,))
-
-
-def unpack_kv(rows, head_dim: int):
-    """Inverse of pack_kv: pool rows [..., Hkv * head_lanes(hd)] ->
-    (k, v), each [..., Hkv, hd]."""
-    heads = rows.reshape(rows.shape[:-1] + (-1, head_lanes(head_dim)))
-    return heads[..., :head_dim], heads[..., head_dim:2 * head_dim]
-
-
-def _write_rows(pool, slots, rows):
-    nb, bs, lanes = pool.shape
-    return pool.reshape(nb * bs, lanes).at[slots].set(
-        rows.astype(pool.dtype)).reshape(pool.shape)
-
-
-def write_kv(pool, slots, k, v):
-    """The step's write: token i's k/v [T, Hkv, hd] land in the pool's
-    flat row `slots[i]` (block_id * block_size + offset). One scatter
-    of whole rows; on a donated pool it runs in place."""
-    return _write_rows(pool, slots, pack_kv(k, v))
-
-
-def latent_lanes(k_dim: int) -> int:
-    """Lanes of a latent row: its k_dim values padded up to whole
-    128-lane tiles."""
-    return -(-k_dim // _LANES) * _LANES
-
-
-def pack_latent(latent, lanes: int):
-    """Latent entries [..., k_dim] as pool rows [..., lanes]."""
-    xp = np if isinstance(latent, np.ndarray) else jnp
-    pad = lanes - latent.shape[-1]
-    if not pad:
-        return latent
-    return xp.concatenate(
-        [latent, xp.zeros(latent.shape[:-1] + (pad,), latent.dtype)],
-        axis=-1)
-
-
-def write_latent(pool, slots, latent):
-    """`write_kv` for a latent pool: token i's entry [T, k_dim] lands in
-    flat row `slots[i]`."""
-    return _write_rows(pool, slots, pack_latent(latent, pool.shape[-1]))
 
 
 def refuse_latent(tp_size: int, compress_blocks: int) -> None:
@@ -374,8 +295,8 @@ class PagedKVCache:
             raise ValueError(
                 f"num_kv_heads={self.num_kv_heads} not divisible by "
                 f"tp_size={tp}")
-        lanes = (latent_lanes(self.latent[0]) if self.latent else
-                 self.num_kv_heads // tp * head_lanes(self.head_dim))
+        lanes = (paged.latent_lanes(self.latent[0]) if self.latent else
+                 self.num_kv_heads // tp * paged.head_lanes(self.head_dim))
         return (self.num_blocks, self.block_size, lanes)
 
     def _place(self, pool):
@@ -414,7 +335,7 @@ class PagedKVCache:
             k_dim, v_dim = self.latent
             k, v = rows[:, None, :v_dim], rows[:, None, v_dim:k_dim]
         else:
-            k, v = unpack_kv(rows, self.head_dim)
+            k, v = paged.unpack_kv(rows, self.head_dim)
         return np.ascontiguousarray(k), np.ascontiguousarray(v)
 
     def pack_block(self, k, v):
@@ -422,9 +343,10 @@ class PagedKVCache:
         rows from the (k, v) the tiers keep."""
         if self.latent:
             xp = np if isinstance(k, np.ndarray) else jnp
-            return pack_latent(xp.concatenate([k, v], axis=-1)[..., 0, :],
-                               latent_lanes(self.latent[0]))
-        return pack_kv(k, v)
+            return paged.pack_latent(
+                xp.concatenate([k, v], axis=-1)[..., 0, :],
+                paged.latent_lanes(self.latent[0]))
+        return paged.pack_kv(k, v)
 
     def read_block(self, block: int) -> List[Tuple[np.ndarray, np.ndarray]]:
         """One block's per-layer (k, v) on the host: the host tier's

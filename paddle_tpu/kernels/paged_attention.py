@@ -1,37 +1,29 @@
-"""Paged decode attention: K/V gathered through per-sequence block tables.
+"""Ragged paged attention: the serving step's one attention launch, its
+XLA reference, and the pool row both read.
 
-The serving-side sibling of kernels/flash.py. Online inference
-(engine/) stores each sequence's KV history as a list of fixed-size
-token blocks inside one shared pool per layer, so admission/eviction
-never copies KV state and a ragged batch wastes at most block_size-1
-slots per sequence ("Ragged Paged Attention", arxiv 2604.15464).
-Decode attention then has to gather K/V through the block table
-instead of slicing a dense [B, Tmax] cache.
+Online inference (engine/) keeps each sequence's KV history as a list
+of fixed-size token blocks inside one shared pool per layer, so
+admission and eviction never copy KV state and a ragged batch wastes at
+most block_size-1 slots a sequence ("Ragged Paged Attention", arxiv
+2604.15464). Attention then gathers K/V through the block table instead
+of slicing a dense [B, Tmax] cache. Three things live here, and nothing
+else reads a pool:
 
-Two implementations with one contract (mirroring attention.py's
-flash/reference split):
-
-- `paged_attention_reference` — pure-XLA gather + dense attention.
-  Runs anywhere, is the numerics oracle for tests, and is what the
-  dispatcher uses off-TPU.
-- a Pallas kernel — grid (B, blocks_per_seq); the block table rides
-  scalar prefetch (pltpu.PrefetchScalarGridSpec) so the *index map*
-  picks which pool block to DMA into VMEM: the gather IS the block
-  fetch, no [B, T, Hkv, Dh] contiguous K/V ever materializes. The kv
-  axis is sequential ("arbitrary") with online-softmax scratch, and
-  blocks past a sequence's context length are skipped entirely, so a
-  ragged batch costs ~sum(ceil(len_i/bs)) block reads, not B*max_len.
-  Runs in interpret mode on CPU so tests validate it without TPU
-  hardware (same policy as kernels/flash.py).
-
-Layout: q is [B, H, Dh] (one query token per sequence — decode);
-the decode kernel and the chunked-prefill gather take per-head pools
-[NB, BS, Hkv, Dh] (views of the engine's pool: paged_cache.unpack_kv;
-no serving path runs them any more); block_tables [B, MB] int32
-pool-block ids; context_lens [B] int32 valid-token counts. GQA/MQA:
-Hkv may divide H; the grouped einsum reads each kv head once. The
-ragged kernel below — the engine's one step — reads the engine's pool
-as it lies, [NB, BS, Hkv * W] (its section comment has the row).
+- the pool's ROW ("The pool's row" below): how a token's K and V, or its
+  one latent, lie in a pool's lanes, with the functions that pack,
+  unpack and scatter rows. The models' steps write through `write_kv` /
+  `write_latent`; the kernel DMAs the same rows as they lie;
+  engine/paged_cache.py allocates pools of that row and keeps the
+  policy above it.
+- `ragged_paged_attention` — the entry point. Decode rows (one query)
+  and prefill chunks (a window of queries) of a step ride ONE flat
+  packing and one Pallas launch; `ragged_paged_attention_tp` is the same
+  launch as a shard_map island over a "tp" mesh axis. Pallas kernel on
+  the TPU, the reference elsewhere; `PTPU_PAGED_KERNEL` forces a tier
+  (`_resolve_dispatch`), which is how the CPU tests run the whole engine
+  through the interpreted kernel.
+- `ragged_paged_attention_reference` — pure-XLA gather + dense masked
+  attention: the numerics oracle, and the tier off the TPU.
 """
 
 from __future__ import annotations
@@ -49,7 +41,9 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.kernels.attention import reference_attention
 
 NEG_INF = -1e9
-LANES = 128   # online-softmax m/l scratch is lane-broadcast, as in flash.py
+# the TPU's lane count: a pool row is a multiple of it, and the
+# online-softmax m/l scratch is lane-broadcast over it, as in flash.py
+LANES = 128
 # Mirror of quant.int8_compute's QMAX reciprocal (importing it would pull
 # nn.layers into the kernel module). The in-place dequant below must stay
 # bit-identical to dequantize_block: x = (q_int8 -> f32) * (scale * RQMAX),
@@ -72,10 +66,11 @@ def _device_platform() -> str:
 
 def _resolve_dispatch(use_kernel: Optional[bool],
                       interpret: Optional[bool]) -> tuple:
-    """Shared kernel/reference/interpret tier selection for the paged
-    dispatchers. Explicit caller arguments win; with use_kernel=None the
-    PTPU_PAGED_KERNEL env var can force a tier (so the FULL engine path
-    can run through the kernel in interpret mode, not just unit tests):
+    """Kernel/reference/interpret tier selection for
+    `ragged_paged_attention`. Explicit caller arguments win; with
+    use_kernel=None the PTPU_PAGED_KERNEL env var can force a tier (so
+    the FULL engine path can run through the kernel in interpret mode,
+    not just unit tests):
 
     - "kernel":    Pallas kernel, interpret off-TPU
     - "interpret": Pallas kernel in interpret mode everywhere
@@ -103,182 +98,102 @@ def _resolve_dispatch(use_kernel: Optional[bool],
     return True, interpret
 
 
-def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
-                              scale: Optional[float] = None):
-    """Oracle path: gather blocks dense, mask past context_len, run
-    reference_attention. q: [B, H, D]; pools: [NB, BS, Hkv, D];
-    block_tables: [B, MB] int32; context_lens: [B] int32 -> [B, H, D]."""
-    b, h, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
-    mb = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(b, mb * bs, hkv, d)
-    v = v_pool[block_tables].reshape(b, mb * bs, hkv, d)
-    mask = (jnp.arange(mb * bs)[None, :]
-            < context_lens[:, None])[:, None, None, :]
-    return reference_attention(q[:, None].astype(k.dtype), k, v, mask=mask,
-                               scale=scale)[:, 0].astype(q.dtype)
+# ---------------------------------------------------------------------------
+# The pool's row. One pool a layer, [num_blocks, block_size, lanes]; a
+# token's row is one of two layouts, and this section is the only place
+# that states either:
+#
+# - K/V: each kv head's K in lanes [0, hd) of the head's `head_lanes(hd)`
+#   lanes and its V in lanes [hd, 2*hd), zero-padded up to whole
+#   128-lane tiles (hd 64 -> 128 lanes a head, hd 128 -> 256); heads side
+#   by side, lanes = Hkv * head_lanes(hd). Under tensor parallelism the
+#   row shards over its heads, P(None, None, "tp"). The int8 pools take
+#   the same rule; their scales stay per block.
+# - latent (what a latent-attention model caches): one entry a token and
+#   no head axis, k_dim values in `latent_lanes(k_dim)` lanes (576 ->
+#   640), the scores contracting all of them and the first v_dim lanes
+#   doubling as the value (read with `value_lanes=(0, v_dim)`).
+#
+# With a lane-dense minor dimension the TPU's default device layout of a
+# pool is plain row-major, which is what the step's flat scatter
+# (`write_kv` / `write_latent`) and the ragged kernel's per-block DMA
+# both use: the step updates a donated pool IN PLACE. (A
+# [blocks, bs, Hkv, 64] pool's default layout puts the blocks in the
+# lanes, and every step transposed each pool into a padded row-major
+# temporary and back.)
+# ---------------------------------------------------------------------------
 
 
-def paged_prefill_attention(q, k_pool, v_pool, block_tables, context_lens,
-                            q_positions, scale: Optional[float] = None):
-    """Chunked-prefill attention: a CHUNK of queries per sequence
-    attends through the block table — over the prefix KV already in the
-    pool AND the chunk's own KV (the caller scatters the chunk's k/v
-    into the pool first), causally.
+def head_lanes(head_dim: int) -> int:
+    """Lanes one kv head takes in a pool row: K then V side by side,
+    padded up to whole 128-lane tiles."""
+    return -(-2 * head_dim // LANES) * LANES
 
-    q: [B, C, H, D] chunk queries; q_positions: [B, C] int32 absolute
-    position of each query (start offset + within-chunk index — rows of
-    a batch may start at different depths, and pad rows sit at
-    position 0); pools [NB, BS, Hkv, D]; block_tables [B, MB];
-    context_lens [B] int32 = each row's chunk-end position (or 1 for
-    pad rows). Returns [B, C, H, D].
 
-    A gathered slot's logical position IS its index in block-table
-    order, so causality is `kv_pos <= q_pos` — which also masks the
-    scratch-block garbage gathered for padded table entries (their
-    kv_pos exceeds every real query position). Masked scores sit at
-    NEG_INF and underflow to exact 0 after the softmax's max-shift, so
-    widening the gather never perturbs the attended sum — the property
-    the engine's exact batching-invariance tests lean on.
+def _row_heads(rows, head_dim: int):
+    """Pool rows [..., Hkv * head_lanes(hd)] -> [..., Hkv, head_lanes(hd)],
+    each head's [k | v | pad]: the lanes split at whole 128-lane tiles."""
+    return rows.reshape(rows.shape[:-1] + (-1, head_lanes(head_dim)))
 
-    XLA-only for now: chunk prefill is compute-bound (unlike decode,
-    whose gather the Pallas kernel exists to keep HBM-shaped), and the
-    dense gather is the same oracle path `paged_attention_reference`
-    uses. A Pallas ragged-prefill kernel (PAPERS.md "Ragged Paged
-    Attention") is the TPU-rig follow-up tracked in ROADMAP.md.
-    """
-    b, c, h, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
-    mb = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(b, mb * bs, hkv, d)
-    v = v_pool[block_tables].reshape(b, mb * bs, hkv, d)
-    kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
-    mask = ((kv_pos[None, None, :] <= q_positions[:, :, None])
-            & (kv_pos[None, None, :] < context_lens[:, None, None]))
-    return reference_attention(q.astype(k.dtype), k, v,
-                               mask=mask[:, None], scale=scale
-                               ).astype(q.dtype)
+
+def pack_kv(k, v):
+    """Per-head k and v, each [..., Hkv, hd], as pool rows
+    [..., Hkv * head_lanes(hd)] (numpy in, numpy out; jax in, jax
+    out)."""
+    xp = np if isinstance(k, np.ndarray) else jnp
+    hd = k.shape[-1]
+    pad = head_lanes(hd) - 2 * hd
+    parts = [k, v] + ([xp.zeros(k.shape[:-1] + (pad,), k.dtype)]
+                      if pad else [])
+    rows = xp.concatenate(parts, axis=-1)
+    return rows.reshape(k.shape[:-2] + (-1,))
+
+
+def unpack_kv(rows, head_dim: int):
+    """Inverse of pack_kv: pool rows [..., Hkv * head_lanes(hd)] ->
+    (k, v), each [..., Hkv, hd]."""
+    heads = _row_heads(rows, head_dim)
+    return heads[..., :head_dim], heads[..., head_dim:2 * head_dim]
+
+
+def _write_rows(pool, slots, rows):
+    nb, bs, lanes = pool.shape
+    return pool.reshape(nb * bs, lanes).at[slots].set(
+        rows.astype(pool.dtype)).reshape(pool.shape)
+
+
+def write_kv(pool, slots, k, v):
+    """The step's write: token i's k/v [T, Hkv, hd] land in the pool's
+    flat row `slots[i]` (block_id * block_size + offset). One scatter
+    of whole rows; on a donated pool it runs in place."""
+    return _write_rows(pool, slots, pack_kv(k, v))
+
+
+def latent_lanes(k_dim: int) -> int:
+    """Lanes of a latent row: its k_dim values padded up to whole
+    128-lane tiles."""
+    return -(-k_dim // LANES) * LANES
+
+
+def pack_latent(latent, lanes: int):
+    """Latent entries [..., k_dim] as pool rows [..., lanes]."""
+    xp = np if isinstance(latent, np.ndarray) else jnp
+    pad = lanes - latent.shape[-1]
+    if not pad:
+        return latent
+    return xp.concatenate(
+        [latent, xp.zeros(latent.shape[:-1] + (pad,), latent.dtype)],
+        axis=-1)
+
+
+def write_latent(pool, slots, latent):
+    """`write_kv` for a latent pool: token i's entry [T, k_dim] lands in
+    flat row `slots[i]`."""
+    return _write_rows(pool, slots, pack_latent(latent, pool.shape[-1]))
 
 
 def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
-
-
-def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, block_size: int,
-                  groups: int):
-    """One (sequence, kv-block) grid cell. q_ref: [H, D]; k/v_ref: the
-    pool block the index map selected via the prefetched block table,
-    [BS, Hkv, D]. Scratch persists across the sequential kv axis."""
-    b, j = pl.program_id(0), pl.program_id(1)
-    nblk = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    ctx = cl_ref[b]
-
-    @pl.when(j * block_size < ctx)
-    def _compute():
-        q = q_ref[...]                                  # [H, D]
-        k = k_ref[...]                                  # [BS, Hkv, D]
-        v = v_ref[...]
-        h, d = q.shape
-        hkv = k.shape[1]
-        qg = q.reshape(hkv, groups, d)
-        kt = jnp.transpose(k, (1, 0, 2))                # [Hkv, BS, D]
-        # batched over kv heads: [Hkv, G, D] x [Hkv, BS, D] -> [Hkv, G, BS]
-        s = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        s = s.reshape(h, block_size)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (h, block_size), 1)
-        s = jnp.where(pos < ctx, s, NEG_INF)
-
-        m_prev = m_scr[...][:, :1]                      # [H, 1]
-        l_prev = l_scr[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                          # [H, BS]
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pg = p.reshape(hkv, groups, block_size)
-        vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, BS, D]
-        pv = jax.lax.dot_general(
-            pg.astype(v.dtype), vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # [Hkv, G, D]
-        acc_scr[...] = alpha * acc_scr[...] + pv.reshape(h, d)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == nblk - 1)
-    def _finalize():
-        l = l_scr[...][:, :1]
-        o_ref[...] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(
-            o_ref.dtype)
-
-
-def _paged_kernel_call(q, k_pool, v_pool, block_tables, context_lens, scale,
-                       interpret: bool):
-    b, h, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
-    mb = block_tables.shape[1]
-    if h % hkv:
-        raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_tables, context_lens
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((None, h, d), lambda b, j, bt, cl: (b, 0, 0)),
-            # the paged gather: the index map dereferences the block table
-            pl.BlockSpec((None, bs, hkv, d),
-                         lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((None, bs, hkv, d),
-                         lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, h, d), lambda b, j, bt, cl: (b, 0, 0)),
-        scratch_shapes=[
-            _scratch((h, LANES)),
-            _scratch((h, LANES)),
-            _scratch((h, d)),
-        ],
-    )
-    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                               groups=h // hkv)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pool, v_pool)
-
-
-def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                    scale: Optional[float] = None,
-                    use_kernel: Optional[bool] = None,
-                    interpret: Optional[bool] = None):
-    """Dispatching entry point (the mha() of the paged path).
-
-    use_kernel=None: Pallas kernel on TPU, XLA reference elsewhere —
-    the engine and model code call with defaults and get the right tier
-    (PTPU_PAGED_KERNEL overrides; see _resolve_dispatch). Tests pass
-    use_kernel=True, interpret=True to validate the kernel's numerics
-    on CPU.
-    """
-    d = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    use_kernel, interpret = _resolve_dispatch(use_kernel, interpret)
-    if not use_kernel:
-        return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                         context_lens, scale=scale)
-    return _paged_kernel_call(q, k_pool, v_pool, block_tables, context_lens,
-                              scale, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -302,11 +217,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 #   row), and first-query positions. A decode row is simply q_len=1:
 #   q_start = ctx - 1.
 #
-# Pool operand: the engine's pool as it lies in HBM (engine/paged_cache.py
-# owns the layout): [NB, BS, Hkv * W], a token's row holding each kv
-# head's K in lanes [0, D) of the head's W lanes and its V in lanes
-# [D, 2D), W a multiple of 128. The kernel reads W off the shape
-# (lanes / (H / groups)); one DMA fetches a block's K and V together.
+# Pool operand: the engine's pool as it lies in HBM, in the row of the
+# section above: [NB, BS, Hkv * W], W = head_lanes(D) lanes a head
+# holding [k | v | pad]; one DMA fetches a block's K and V together.
 # With q zero-padded to W lanes, q'.[k|v]^T = q.k^T, and p.[k|v]
 # carries p.v in lanes [D, 2D): no sub-tile lane slicing per block, and
 # a 128-deep contraction where head_dim 64 half-filled it.
@@ -332,20 +245,17 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 # matmuls and no packed operand is reshaped in it.
 #
 # Masking is absolute-position causal AND context-bounded
-# (kv_pos <= q_pos, kv_pos < ctx — the paged_prefill_attention
-# contract), so decode rows, mid-prompt chunks and pad queries all fall
-# out of one rule: pad queries attend a finite prefix (never sampled),
-# and kv position 0 is always visible, so no softmax row is ever empty.
+# (kv_pos <= q_pos, kv_pos < ctx; a gathered slot's logical position
+# IS its index in block-table order, and a masked score sits at NEG_INF
+# and underflows to an exact 0 after the softmax's max-shift, which is
+# what the engine's exact batching-invariance tests lean on), so decode
+# rows, mid-prompt chunks and pad queries all fall out of one rule: pad
+# queries attend a finite prefix (never sampled), and kv position 0 is
+# always visible, so no softmax row is ever empty.
 # ---------------------------------------------------------------------------
 
 
-def _split_kv(rows, hkv: int, d: int):
-    """Pool rows [..., Hkv * W] -> (k, v), each [..., Hkv, D]."""
-    heads = rows.reshape(rows.shape[:-1] + (hkv, rows.shape[-1] // hkv))
-    return heads[..., :d], heads[..., d:2 * d]
-
-
-def _gather_mixed(pool, q_pool, k_scales, v_scales, ids, hkv: int, d: int):
+def _gather_mixed(pool, q_pool, k_scales, v_scales, ids, d: int):
     """Dense mixed-tier gather for the reference oracle: fp pool rows
     where the (bias-decoded) table entry is non-negative, per-block
     dequantized int8 rows where it is negative. ids: [...] raw table
@@ -359,8 +269,8 @@ def _gather_mixed(pool, q_pool, k_scales, v_scales, ids, hkv: int, d: int):
     sel = neg[..., None, None, None]
     out = []
     for dense, q8, scales in zip(
-            _split_kv(pool[fp_ids], hkv, d),
-            _split_kv(q_pool[q_ids], hkv, d), (k_scales, v_scales)):
+            unpack_kv(pool[fp_ids], d), unpack_kv(q_pool[q_ids], d),
+            (k_scales, v_scales)):
         deq = (q8.astype(jnp.float32)
                * (scales[q_ids] * _RQMAX)[..., None, None, None]
                ).astype(pool.dtype)
@@ -381,10 +291,10 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
     q: [T, H, D] flat-packed; kv_pool: [NB, BS, Hkv * W] (the section
     comment above); returns [T, H, D].
 
-    Gathers [T, MB*BS, Hkv, D] — heavier than the per-row [B, ...]
-    gathers above (every token re-gathers its row's blocks), but it is
-    the off-TPU dispatch tier where T stays small (CPU smoke + tests),
-    and XLA's masked softmax keeps it exactly batch-invariant.
+    Gathers [T, MB*BS, Hkv, D] — every token re-gathers its row's
+    blocks — but it is the off-TPU dispatch tier where T stays small
+    (CPU smoke + tests), and XLA's masked softmax keeps it exactly
+    batch-invariant.
 
     With kvq_pool (+[NQ] per-block k_scales/v_scales) the table
     entries are bias-encoded: id >= 0 reads the fp pool, id < 0 reads
@@ -406,7 +316,7 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
             + jnp.tile(jnp.arange(tq, dtype=jnp.int32), nt))  # [T]
     bt = block_tables[row_of]                                # [T, MB]
     if value_lanes is not None:
-        v_off, v_dim = _latent_lanes(value_lanes, h, groups, kvq_pool)
+        v_off, v_dim = _latent_value(value_lanes, h, groups, kvq_pool)
         rows = kv_pool[bt].reshape(t, mb * bs, -1)
         kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
         mask = ((kv_pos[None, :] <= qpos[:, None])
@@ -417,10 +327,9 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
         return jnp.einsum("thk,tkv->thv", p.astype(rows.dtype),
                           rows[..., v_off:v_off + v_dim]).astype(q.dtype)
     if kvq_pool is None:
-        k, v = _split_kv(kv_pool[bt], hkv, d)
+        k, v = unpack_kv(kv_pool[bt], d)
     else:
-        k, v = _gather_mixed(kv_pool, kvq_pool, k_scales, v_scales, bt,
-                             hkv, d)
+        k, v = _gather_mixed(kv_pool, kvq_pool, k_scales, v_scales, bt, d)
     k = k.reshape(t, mb * bs, hkv, d)
     v = v.reshape(t, mb * bs, hkv, d)
     kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
@@ -431,7 +340,7 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
                                scale=scale)[:, 0].astype(q.dtype)
 
 
-def _latent_lanes(value_lanes, h: int, groups: int, kvq_pool):
+def _latent_value(value_lanes, h: int, groups: int, kvq_pool):
     """(value offset, value width) of a latent pool, or why it cannot
     be read."""
     if h != groups:
@@ -470,12 +379,11 @@ def _kv_major_to_rows(x, tq: int, groups: int):
             .reshape(tq * hkv * groups, last)
 
 
-def _block_heads(rows, hkv: int):
-    """One pool block [BS, Hkv * W] -> [Hkv, BS, W]: the lanes split
-    at whole 128-lane tiles, then kv heads lead (the batched matmuls'
+def _block_heads(rows, d: int):
+    """Pool rows [K, Hkv * W] at head_dim d -> [Hkv, K, W]: the row's
+    heads (`_row_heads`), then kv heads lead (the batched matmuls'
     operand layout)."""
-    bs, lanes = rows.shape
-    return jnp.transpose(rows.reshape(bs, hkv, lanes // hkv), (1, 0, 2))
+    return jnp.transpose(_row_heads(rows, d), (1, 0, 2))
 
 
 def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
@@ -558,9 +466,10 @@ def ragged_span(block_size: int, lanes: int, itemsize: int,
                 max_blocks: int) -> int:
     """How many consecutive block-table entries one grid cell of the
     ragged kernel covers. Read off the pool's shape ([*, block_size,
-    lanes] of `itemsize` bytes — a tensor-parallel shard's own) and the
-    table's width: the largest power of two within _SPAN_KEYS keys,
-    _SPAN_BYTES of blocks, and the table."""
+    lanes] of `itemsize` bytes, `lanes` whatever "The pool's row" makes
+    them — a tensor-parallel shard's own) and the table's width: the
+    largest power of two within _SPAN_KEYS keys, _SPAN_BYTES of blocks,
+    and the table."""
     fits = min(_SPAN_KEYS // block_size,
                _SPAN_BYTES // (block_size * lanes * itemsize), max_blocks)
     return 1 << (max(fits, 1).bit_length() - 1)
@@ -684,7 +593,7 @@ def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
     def load_span(row, j, slot):
         rows = buf[slot].reshape(-1, buf.shape[-1])     # [span * BS, lanes]
         return (rows[None] if q_ref.ndim == 2 else
-                _block_heads(rows, q_ref.shape[1] // groups))
+                _block_heads(rows, o_ref.shape[-1]))
 
     _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
                  load_span, o_ref, m_scr, l_scr, acc_scr, (buf,), cnt,
@@ -703,7 +612,7 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
     each over its own lanes of a head. The dequant is bit-identical to
     quant.dequantize_block, which is what pins direct-read output to
     the promote path's bytes."""
-    hkv, d = q_ref.shape[1] // groups, o_ref.shape[-1]
+    d = o_ref.shape[-1]
 
     def span_copies(row, j, slot):
         copies = []
@@ -720,8 +629,8 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
         for i, e in _span_entries(bt_ref, row, j, span):
             is8 = e < 0
             s8 = jnp.where(is8, -e - 1, 0)
-            fp = _block_heads(buf[slot, i], hkv)            # [Hkv, BS, W]
-            q8 = _block_heads(qbuf[slot, i].astype(jnp.float32), hkv)
+            fp = _block_heads(buf[slot, i], d)              # [Hkv, BS, W]
+            q8 = _block_heads(qbuf[slot, i].astype(jnp.float32), d)
             lane = jax.lax.broadcasted_iota(jnp.int32, q8.shape, 2)
             sc = jnp.where(lane < d, ksc_ref[s8] * _RQMAX,
                            vsc_ref[s8] * _RQMAX)
@@ -752,18 +661,18 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     tq = t // nt
     if h % groups:
         raise ValueError(f"q heads {h} not a multiple of groups {groups}")
-    w = lanes // (h // groups)
     latent = value_lanes is not None
     if latent:
-        v_off, out_d = _latent_lanes(value_lanes, h, groups, kvq_pool)
-        acc_w = -(-(v_off + out_d) // LANES) * LANES
-        if w < d or acc_w > w:
+        v_off, out_d = _latent_value(value_lanes, h, groups, kvq_pool)
+        w, acc_w = latent_lanes(d), latent_lanes(out_d)
+        if lanes != w or out_d > d:
             raise ValueError(
                 f"pool rows of {lanes} lanes do not hold a latent of {d} "
                 f"values whose first {out_d} are the value")
     else:
-        v_off, out_d, acc_w = None, d, w
-        if w < 2 * d or w * (h // groups) != lanes:
+        v_off, out_d = None, d
+        w = acc_w = head_lanes(d)
+        if w * (h // groups) != lanes:
             raise ValueError(
                 f"pool rows of {lanes} lanes do not hold {h // groups} kv "
                 f"heads of [k | v] at head_dim {d}")
@@ -839,8 +748,8 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
     the engine's single-step entry point. q: [T, H, D]; kv_pool: one
     layer's pool as the cache lays it out, [NB, BS, Hkv * W]; `groups`
     is H / Hkv (the same on every tensor-parallel shard). Dispatch
-    tiers mirror paged_attention: Pallas kernel on TPU, XLA reference
-    elsewhere, PTPU_PAGED_KERNEL / explicit flags override.
+    tiers: Pallas kernel on TPU, XLA reference elsewhere,
+    PTPU_PAGED_KERNEL / explicit flags override (`_resolve_dispatch`).
 
     When the engine's compressed tier is live it passes the int8 pool
     (kvq_pool [NQ, BS, Hkv * W]) and per-block scales ([NQ] f32 each
@@ -923,25 +832,3 @@ def ragged_paged_attention_tp(mesh, q, kv_pool, block_tables,
                   out_specs=heads, check_vma=False)
     return f(q, kv_pool, block_tables, context_lens, q_starts,
              tile_rows, tile_offs, *quant)
-
-
-def paged_prefill_attention_tp(mesh, q, k_pool, v_pool, block_tables,
-                               context_lens, q_positions,
-                               scale: Optional[float] = None):
-    """`paged_prefill_attention` sharded the same way: q [B, C, H, D]
-    on H, pools on Hkv, int32 metadata replicated, output sharded on
-    H."""
-    from jax.sharding import PartitionSpec as P
-
-    from jax import shard_map
-
-    def body(q_, kp, vp, bt, cl, qp):
-        return paged_prefill_attention(q_, kp, vp, bt, cl, qp, scale=scale)
-
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P(None, None, "tp", None),
-                            P(None, None, "tp", None),
-                            P(None, None, "tp", None),
-                            P(), P(), P()),
-                  out_specs=P(None, None, "tp", None), check_vma=False)
-    return f(q, k_pool, v_pool, block_tables, context_lens, q_positions)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.module import Context, Module
+from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, Linear, RMSNorm
 
@@ -62,9 +63,9 @@ def _weight(cx: Context, linear: Linear, in_features: int):
 
 class LatentAttention(Module):
     """Multi-head latent attention. `latent_row` = (k_dim, v_dim) tells
-    the engine what one cached row is (engine/paged_cache.py owns the
-    layout): k_dim = kv_rank + rope_dim values of which the first v_dim
-    = kv_rank are also the value."""
+    the engine what one cached row is (kernels/paged_attention.py, "The
+    pool's row", has the layout): k_dim = kv_rank + rope_dim values of
+    which the first v_dim = kv_rank are also the value."""
 
     def __init__(self, model_dim: int, num_heads: int, q_rank: int,
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
@@ -139,12 +140,10 @@ class LatentAttention(Module):
         ragged kernel serves every row against the pool as it lies.
         Returns (out [T, d], new pool)."""
         cx = cx.scope(self._name or type(self).__name__)
-        from paddle_tpu.engine.paged_cache import write_latent
-        from paddle_tpu.kernels import paged_attention as paged
         with jax.named_scope("mla_attention"):
             q_nope, q_rope, c_kv, k_rope = self._project(cx, x, positions)
             wk, wv = self._kv_b(cx)
-            kv_pool = write_latent(
+            kv_pool = paged.write_latent(
                 kv_pool, slots, jnp.concatenate([c_kv, k_rope], axis=-1))
             q = jnp.concatenate(
                 [jnp.einsum("thn,chn->thc", q_nope, wk), q_rope], axis=-1)

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.module import Context, Module, PARAMS
+from paddle_tpu.kernels import attention as attn_kernel
+from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Dropout, Embedding, LayerNorm, Linear
 from paddle_tpu.ops import functional as F
@@ -172,7 +174,6 @@ class MultiHeadAttention(Module):
             if not prefill:
                 kh, vh = k_all, v_all
 
-        from paddle_tpu.kernels import attention as attn_kernel
         out = attn_kernel.mha(qh, kh, vh, mask=mask, causal=causal,
                               segment_ids=segment_ids,
                               dropout_rng=(cx.rng() if cx.training and
@@ -184,84 +185,6 @@ class MultiHeadAttention(Module):
         out = self.out_proj(cx, out)
         return (out, cache) if cache is not None else (out, None)
 
-    def decode_paged(self, cx: Context, x, kv_pool, block_tables,
-                     context_lens, slots):
-        """Single-token decode through a PAGED KV cache (engine/ serving
-        path). x: [B, 1, D]; kv_pool: this layer's shared block pool in
-        the cache's layout (engine/paged_cache.py owns it: write_kv
-        scatters into it, unpack_kv views it per head); block_tables:
-        [B, MB] int32; context_lens: [B] int32 valid
-        tokens per sequence INCLUDING this one; slots: [B] int32 flat pool
-        slot (block_id * BS + offset) where this token's k/v lands.
-        Returns (out [B, 1, D], new_kv_pool). Unlike the
-        dense `cache=` path, every sequence in the batch may sit at a
-        DIFFERENT position — the whole point of continuous batching."""
-        # self-scope like Embedding.attend: this is not routed through
-        # __call__, so the child scope must be entered by hand
-        cx = cx.scope(self._name or type(self).__name__)
-        if self.fused_qkv:
-            b = x.shape[0]
-            p = self.qkv(cx, x).reshape(       # head-major: [H, 3, hd]
-                b, 1, self.num_heads, 3, self.head_dim)
-            qh, kh, vh = p[..., 0, :], p[..., 1, :], p[..., 2, :]
-        else:
-            qh = self._split(self.q_proj(cx, x))
-            kh = self._split_kv(self.k_proj(cx, x))
-            vh = self._split_kv(self.v_proj(cx, x))
-        from paddle_tpu.engine.paged_cache import unpack_kv, write_kv
-        from paddle_tpu.kernels import paged_attention as paged
-        kv_pool = write_kv(kv_pool, slots, kh[:, 0], vh[:, 0])
-        out = paged.paged_attention(
-            qh[:, 0], *unpack_kv(kv_pool, self.head_dim), block_tables,
-            context_lens)                                # [B, H, hd]
-        out = self.out_proj(cx, out.reshape(x.shape[0], 1, self.model_dim))
-        return out, kv_pool
-
-    def prefill_chunk_paged(self, cx: Context, x, q_positions, kv_pool,
-                            block_tables, context_lens, slots, tp=None):
-        """CHUNKED prefill through a paged KV cache (the serving path's
-        suffix-only prefill). x: [B, C, D] — a window of each prompt,
-        not necessarily starting at position 0 (prefix-cache hits skip
-        the cached head; long prompts arrive one budget-bounded chunk
-        per step); q_positions: [B, C] absolute positions; slots:
-        [B*C] flat pool slots receiving this chunk's k/v. The chunk
-        k/v is scattered into the pool FIRST, then every chunk query
-        attends causally through the block table — over the cached
-        prefix and the chunk itself in one go. Returns
-        (out [B, C, D], new_kv_pool).
-
-        `tp` (parallel.serve_collective.ServeTP or None) routes the
-        attention through an explicit shard_map island over the mesh's
-        "tp" axis — heads/kv-heads device-local, metadata replicated;
-        the projections around it stay GSPMD ops at global shapes."""
-        cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        if self.fused_qkv:
-            b, t = x.shape[:2]
-            p = self.qkv(cx, x).reshape(       # head-major: [H, 3, hd]
-                b, t, self.num_heads, 3, self.head_dim)
-            qh, kh, vh = p[..., 0, :], p[..., 1, :], p[..., 2, :]
-        else:
-            qh = self._split(self.q_proj(cx, x))
-            kh = self._split_kv(self.k_proj(cx, x))
-            vh = self._split_kv(self.v_proj(cx, x))
-        from paddle_tpu.engine.paged_cache import unpack_kv, write_kv
-        from paddle_tpu.kernels import paged_attention as paged
-        kv_pool = write_kv(kv_pool, slots,
-                           kh.reshape((-1,) + kh.shape[2:]),
-                           vh.reshape((-1,) + vh.shape[2:]))
-        k_pool, v_pool = unpack_kv(kv_pool, self.head_dim)
-        if tp is not None:
-            out = paged.paged_prefill_attention_tp(
-                tp.mesh, qh, k_pool, v_pool, block_tables, context_lens,
-                q_positions)                               # [B, C, H, hd]
-        else:
-            out = paged.paged_prefill_attention(
-                qh, k_pool, v_pool, block_tables, context_lens,
-                q_positions)                               # [B, C, H, hd]
-        b, c = x.shape[:2]
-        out = self.out_proj(cx, out.reshape(b, c, self.model_dim))
-        return out, kv_pool
-
     def ragged_step_paged(self, cx: Context, x, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
                           tile_offs, slots, tp=None, qpool=None):
@@ -272,8 +195,11 @@ class MultiHeadAttention(Module):
         pool at `slots` [T] first (pad positions land in scratch
         block 0; in place when the caller donates the pool), then one
         attention launch reads the pool as it lies and serves every
-        row. Returns (out [T, D], new_kv_pool). `tp` routes attention
-        through the sharded island (see prefill_chunk_paged).
+        row. Returns (out [T, D], new_kv_pool). `tp`
+        (parallel.serve_collective.ServeTP or None) routes the attention
+        through an explicit shard_map island over the mesh's "tp" axis
+        — heads/kv-heads device-local, metadata replicated; the
+        projections around it stay GSPMD ops at global shapes.
         `qpool` = (kvq, k_scales, v_scales) threads this layer's
         int8 compressed tier into the launch: bias-encoded (negative)
         block-table entries read it in place. Writes always target the
@@ -291,9 +217,7 @@ class MultiHeadAttention(Module):
                                             self.head_dim)
             vh = self.v_proj(cx, x).reshape(t, self.num_kv_heads,
                                             self.head_dim)
-        from paddle_tpu.engine.paged_cache import write_kv
-        from paddle_tpu.kernels import paged_attention as paged
-        kv_pool = write_kv(kv_pool, slots, kh, vh)
+        kv_pool = paged.write_kv(kv_pool, slots, kh, vh)
         kvq, ksc, vsc = qpool if qpool is not None else (None,) * 3
         attend = (paged.ragged_paged_attention if tp is None else
                   functools.partial(paged.ragged_paged_attention_tp,
@@ -391,21 +315,6 @@ class DecoderLayer(Module):
         x = x + self.drop(cx, self.ffn(cx, self.ln3(cx, x)))
         return x, new_cache
 
-    def decode_paged(self, cx: Context, x, memory, kv_pool,
-                     block_tables, context_lens, slots, cross_mask=None):
-        """Paged self-attention decode step + dense cross-attention over
-        `memory` (encoder states stay dense — they are written once at
-        admission and never grow)."""
-        cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        h, pools = self.self_attn.decode_paged(
-            cx, self.ln1(cx, x), kv_pool, block_tables, context_lens, slots)
-        x = x + self.drop(cx, h)
-        h, _ = self.cross_attn(cx, self.ln2(cx, x), kv=memory,
-                               mask=cross_mask)
-        x = x + self.drop(cx, h)
-        x = x + self.drop(cx, self.ffn(cx, self.ln3(cx, x)))
-        return x, pools
-
 
 class Transformer(Module):
     """Encoder-decoder Transformer-base (d=512, h=8, L=6, ffn=2048)."""
@@ -500,26 +409,6 @@ class Transformer(Module):
         logits = self.head(cx, self.dec_ln(cx, x))
         return logits[:, 0], new_caches
 
-    def decode_step_paged(self, cx: Context, token, positions, memory,
-                          pools, block_tables, context_lens, slots,
-                          src_mask=None):
-        """Continuous-batching decode for the encoder-decoder stack:
-        paged self-attention KV (one pool per layer in `pools`),
-        per-sequence `positions` [B] int32, dense cross-attention over
-        `memory`. Returns (logits [B, V], new pools). The Transformer
-        analog of CausalLM.decode_step_paged."""
-        x = self.trg_embed(cx, token[:, None]) * math.sqrt(self.model_dim)
-        pe = sinusoid_position_encoding(self.max_len, self.model_dim)
-        x = x + pe[positions.astype(jnp.int32)].astype(x.dtype)[:, None]
-        new_pools = []
-        for layer, kv_pool in zip(self.dec_layers, pools):
-            x, np_ = layer.decode_paged(cx, x, memory, kv_pool,
-                                        block_tables, context_lens, slots,
-                                        cross_mask=src_mask)
-            new_pools.append(np_)
-        logits = self.head(cx, self.dec_ln(cx, x))
-        return logits[:, 0], new_pools
-
 
 class CausalBlock(Module):
     """Pre-LN causal self-attention + FFN block (decoder-only stack —
@@ -547,28 +436,6 @@ class CausalBlock(Module):
         x = x + self.drop(cx, h)
         x = x + self.drop(cx, self.ffn(cx, self.ln2(cx, x)))
         return x, nc
-
-    def decode_paged(self, cx: Context, x, kv_pool, block_tables,
-                     context_lens, slots):
-        cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        h, pools = self.attn.decode_paged(cx, self.ln1(cx, x), kv_pool,
-                                          block_tables, context_lens,
-                                          slots)
-        x = x + self.drop(cx, h)
-        x = x + self.drop(cx, self.ffn(cx, self.ln2(cx, x)))
-        return x, pools
-
-    def prefill_chunk_paged(self, cx: Context, x, q_positions, kv_pool,
-                            block_tables, context_lens, slots, tp=None):
-        cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        h, pools = self.attn.prefill_chunk_paged(
-            cx, self.ln1(cx, x), q_positions, kv_pool,
-            block_tables, context_lens, slots, tp=tp)
-        x = x + self.drop(cx, h)
-        f = (self.ffn.forward_serve_tp(cx, self.ln2(cx, x), tp)
-             if tp is not None else self.ffn(cx, self.ln2(cx, x)))
-        x = x + self.drop(cx, f)
-        return x, pools
 
     def ragged_step_paged(self, cx: Context, x, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
@@ -690,67 +557,6 @@ class CausalLM(Module):
             new_caches.append(nc)
         return self._head(cx, self.ln_f(cx, x[:, -1:]))[:, 0], new_caches
 
-    def prefill_paged(self, cx: Context, tokens, last_pos):
-        """Paged-serving prefill: tokens [B, Tpad] (right-padded prompts,
-        padding ignored by causal attention for real positions), last_pos
-        [B] int32 index of each prompt's final real token. Returns
-        (logits [B, V] at last_pos, per-layer (k, v) [B, Tpad, Hkv, hd])
-        — the engine scatters the k/v into its block pools (only real
-        positions get slots) and samples the first generated token from
-        the logits. Differs from `prefill` in that the last REAL position
-        is per-sequence, so ragged prompt batches share one padded call."""
-        b, t0 = tokens.shape
-        x = self.embed(cx, tokens) * math.sqrt(self.model_dim)
-        pe = sinusoid_position_encoding(self.max_len, self.model_dim)[:t0]
-        x = x + pe.astype(x.dtype)[None]
-        kvs = []
-        for blk, cache in zip(self.blocks, init_kv_caches(self.blocks, b,
-                                                          t0)):
-            # prefill=True writes THIS call's k/v over the whole cache
-            # (decode_pos=0, full-length update), so nc IS the prompt k/v
-            x, nc = blk(cx, x, cache=cache, decode_pos=0, prefill=True)
-            kvs.append((nc["k"], nc["v"]))
-        hidden = self.ln_f(cx, x)
-        idx = last_pos.astype(jnp.int32)[:, None, None]
-        last_h = jnp.take_along_axis(
-            hidden, jnp.broadcast_to(idx, (b, 1, hidden.shape[-1])), axis=1)
-        return self._head(cx, last_h)[:, 0], kvs
-
-    def prefill_chunk_paged(self, cx: Context, tokens, start_pos, pools,
-                            block_tables, context_lens, slots, last_idx,
-                            tp=None):
-        """Chunked/suffix-only prefill for paged serving: tokens [B, C]
-        is ONE WINDOW of each prompt (right-padded; pad positions
-        scatter to scratch slot 0), start_pos [B] int32 the absolute
-        position of each row's first chunk token — a prefix-cache hit
-        starts the window mid-prompt, and a long prompt arrives one
-        budget-bounded chunk per step. Attention runs causally through
-        the block pool (cached prefix + this chunk), so positional
-        encodings are offset by start_pos. Returns (logits [B, V] at
-        each row's `last_idx` within-chunk position, new pools) — only
-        a prompt's FINAL chunk's logits are sampled (the first
-        generated token); earlier chunks exist to populate KV.
-        Subsumes whole-prompt prefill: start_pos=0 with the chunk
-        budget covering the prompt is the monolithic case."""
-        b, c = tokens.shape
-        x = self.embed(cx, tokens) * math.sqrt(self.model_dim)
-        pe = sinusoid_position_encoding(self.max_len, self.model_dim)
-        pos = start_pos.astype(jnp.int32)[:, None] \
-            + jnp.arange(c, dtype=jnp.int32)[None, :]          # [B, C]
-        pos_safe = jnp.clip(pos, 0, self.max_len - 1)
-        x = x + pe[pos_safe].astype(x.dtype)
-        new_pools = []
-        for blk, kv_pool in zip(self.blocks, pools):
-            x, np_ = blk.prefill_chunk_paged(cx, x, pos, kv_pool,
-                                             block_tables, context_lens,
-                                             slots, tp=tp)
-            new_pools.append(np_)
-        hidden = self.ln_f(cx, x)
-        idx = last_idx.astype(jnp.int32)[:, None, None]
-        last_h = jnp.take_along_axis(
-            hidden, jnp.broadcast_to(idx, (b, 1, hidden.shape[-1])), axis=1)
-        return self._head(cx, last_h)[:, 0], new_pools
-
     def ragged_step_paged(self, cx: Context, tokens, positions, pools,
                           block_tables, context_lens, q_starts, tile_rows,
                           tile_offs, slots, last_idx, tp=None,
@@ -791,23 +597,6 @@ class CausalLM(Module):
         last_h = jnp.take(hidden, idx.reshape(-1), axis=0)
         logits = self._head(cx, last_h)
         return logits.reshape(idx.shape + (logits.shape[-1],)), new_pools
-
-    def decode_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, slots):
-        """Continuous-batching decode step: tokens [B] ids, positions [B]
-        int32 (PER-SEQUENCE positions — rows decode at different depths),
-        pools: one block pool per layer, block_tables
-        [B, MB], context_lens [B] (= positions + 1), slots [B] flat pool
-        slots for this token's k/v. Returns (logits [B, V], new pools)."""
-        x = self.embed(cx, tokens[:, None]) * math.sqrt(self.model_dim)
-        pe = sinusoid_position_encoding(self.max_len, self.model_dim)
-        x = x + pe[positions.astype(jnp.int32)].astype(x.dtype)[:, None]
-        new_pools = []
-        for blk, kv_pool in zip(self.blocks, pools):
-            x, np_ = blk.decode_paged(cx, x, kv_pool, block_tables,
-                                      context_lens, slots)
-            new_pools.append(np_)
-        return self._head(cx, self.ln_f(cx, x))[:, 0], new_pools
 
     def decode_step(self, cx: Context, token, pos, caches):
         """One step: token [B] ids at position `pos` -> (logits [B, V],

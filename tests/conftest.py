@@ -15,6 +15,17 @@ if not os.environ.get("PTPU_TEST_REAL_DEVICE"):
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
+    # XLA:CPU sizes its thread pools to the machine's cores (or to
+    # PJRT_NPROC), and a virtual device's blocking collective holds one
+    # of the intra-op pool's threads. On an 8-core machine the 8 virtual
+    # devices' all-reduces could take every thread while a participant
+    # still waited for one: a deadlock that XLA ends by aborting the
+    # process after 40 s ("Termination timeout ... Expected 4 threads to
+    # join the rendezvous, but only 3 of them arrived"), which is what
+    # took down a worker in test_pipeline.py's 60 pipelined train steps,
+    # alone and unloaded in 1 run of 22 (0 of 120 with this). Two threads
+    # a virtual device leaves room.
+    os.environ.setdefault("PJRT_NPROC", str(max(16, os.cpu_count() or 1)))
 
 # The suite does not turn on jax's persistent compilation cache for its
 # own process, but the replicas and examples it starts as subprocesses

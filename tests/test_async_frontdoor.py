@@ -171,16 +171,20 @@ class TestSlowClient:
             assert sock.recv(256).startswith(b"HTTP/1.0 200")
             t = threading.Thread(target=well_behaved)
             t.start()
+            # the eviction is ~100 token frames away (what it takes to
+            # overrun the kernel's smallest buffers) plus the deadline:
+            # 6.5 s of engine steps alone, 20 s beside eight busy test
+            # workers, so the waits get room a loaded machine needs
             try:
                 assert _wait_until(lambda: _counter_value(
                     eng.obs, "ptpu_serve_slow_client_evictions_total")
-                    == 1.0), "slow client never evicted"
+                    == 1.0, timeout=120.0), "slow client never evicted"
             finally:
-                t.join(timeout=30)
+                t.join(timeout=120)
             assert not t.is_alive()
             # eviction cancelled the request: every block back
             assert _wait_until(
-                lambda: eng.cache.occupancy() == baseline), \
+                lambda: eng.cache.occupancy() == baseline, timeout=60.0), \
                 "evicted stream leaked KV blocks"
             eng.cache.assert_quiesced()
             # the neighbour never noticed

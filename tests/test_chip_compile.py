@@ -52,7 +52,7 @@ def _compiles_with_kernel(fn, *args) -> bool:
 
 def _pool_rows(nb, bs, kv_heads, head_dim, dtype, sharding):
     """One layer's pool as the cache lays it out, as a shape."""
-    from paddle_tpu.engine.paged_cache import head_lanes
+    from paddle_tpu.kernels.paged_attention import head_lanes
     return jax.ShapeDtypeStruct((nb, bs, kv_heads * head_lanes(head_dim)),
                                 dtype, sharding=sharding)
 
@@ -73,8 +73,8 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, head_dim,
     """The ragged kernel at the span its pool's shape gives (8 blocks a
     cell at 16 and 20 heads, 32 on a tp=4 slice, 4 at GQA's 1,024
     lanes): every block of the span an operand of its own."""
-    from paddle_tpu.engine.paged_cache import head_lanes
-    from paddle_tpu.kernels.paged_attention import (ragged_paged_attention,
+    from paddle_tpu.kernels.paged_attention import (head_lanes,
+                                                    ragged_paged_attention,
                                                     ragged_span)
     # the engine's default step: 512-token chunk budget + 8 decode rows,
     # tile_q 8, block 16, max_len 1024
@@ -308,7 +308,6 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
     from unittest import mock
 
     from paddle_tpu.engine.engine import compile_steps
-    from paddle_tpu.engine.paged_cache import latent_lanes
     from paddle_tpu.kernels import paged_attention
     from paddle_tpu.models.latent_moe import LatentMoELM
 
@@ -335,7 +334,8 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     row = model.blocks[0].attn.latent_row
     pool = jax.ShapeDtypeStruct(
-        (s["num_blocks"], s["block_size"], latent_lanes(row[0])),
+        (s["num_blocks"], s["block_size"],
+         paged_attention.latent_lanes(row[0])),
         jnp.bfloat16, sharding=one_chip)
     assert pool.shape[-1] == 640
     pools = [pool] * len(model.blocks)

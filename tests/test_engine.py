@@ -447,7 +447,7 @@ def test_old_manifest_without_serve_block(model_and_vars, tmp_path):
         ServeEngine.from_saved_model(path)
 
 
-# -- the pool's layout and its donation (engine/paged_cache.py owns both) --
+# -- the pool's row (kernels/paged_attention.py) and the pool's donation --
 
 # (kv heads, head_dim): a head padded up to 128 lanes, one that fills
 # them exactly (the GPT-2 cells' shape), one padded up to 256
@@ -456,8 +456,8 @@ LAYOUT_SHAPES = [(4, 8), (2, 64), (3, 96)]
 
 @pytest.mark.parametrize("hkv,hd", LAYOUT_SHAPES)
 def test_pool_rows_hold_k_and_v_side_by_side(hkv, hd):
-    from paddle_tpu.engine.paged_cache import (head_lanes, pack_kv,
-                                               unpack_kv, write_kv)
+    from paddle_tpu.kernels.paged_attention import (head_lanes, pack_kv,
+                                                    unpack_kv, write_kv)
     rng = np.random.default_rng(hkv * hd)
     k = rng.standard_normal((5, hkv, hd)).astype(np.float32)
     v = rng.standard_normal((5, hkv, hd)).astype(np.float32)
@@ -485,7 +485,7 @@ def _engine_with_a_known_block(hkv, hd, **kw):
     """A small engine whose block 3 holds seeded k/v in every layer,
     written through the cache's own write; returns (engine, per-layer
     (k, v) of that block)."""
-    from paddle_tpu.engine.paged_cache import write_kv
+    from paddle_tpu.kernels.paged_attention import write_kv
     model = CausalLM(vocab=VOCAB, model_dim=hkv * hd, num_heads=hkv,
                      num_layers=2, ffn_dim=32, dropout=0.0, max_len=64)
     variables = model.init(jax.random.PRNGKey(0),

@@ -172,8 +172,9 @@ def test_absorbed_is_the_published_form(cfg, model, params, sampled):
                                        (jnp.bfloat16, 3e-2)],
                          ids=["f32", "bf16"])
 def test_latent_kernel_interpreted_is_the_gather_reference(dtype, tol):
-    from paddle_tpu.engine.paged_cache import latent_lanes, pack_latent
-    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+    from paddle_tpu.kernels.paged_attention import (latent_lanes,
+                                                    pack_latent,
+                                                    ragged_paged_attention)
     rng = np.random.default_rng(0)
     heads, k_dim, v_dim, bs, nb, mb, tq = 4, 20, 16, 4, 32, 8, 8
     pool = pack_latent(rng.normal(size=(nb, bs, k_dim)).astype(np.float32),

@@ -1,4 +1,4 @@
-"""Paged decode attention vs the dense oracle.
+"""Ragged paged attention vs the dense oracle.
 
 Reference bar: the block-table gather (kernels/paged_attention.py) must
 be numerically indistinguishable from dense attention over the same
@@ -8,16 +8,14 @@ Ragged shapes are the point: single-token sequences, lengths landing
 exactly on block boundaries, and mixed depths in one batch.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.engine.paged_cache import head_lanes, pack_kv, unpack_kv
 from paddle_tpu.kernels.attention import reference_attention
 from paddle_tpu.kernels.paged_attention import (
-    paged_attention, paged_attention_reference, ragged_paged_attention,
-    ragged_paged_attention_reference)
+    head_lanes, latent_lanes, pack_kv, pack_latent, ragged_paged_attention,
+    ragged_paged_attention_reference, ragged_span, unpack_kv)
 
 pytestmark = pytest.mark.serve
 
@@ -44,101 +42,21 @@ def _pools_from_dense(k, v, block_size, num_blocks=None, seed=3):
     return jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(tables)
 
 
-def _dense_oracle(q, k, v, context_lens, scale=None):
-    """Per-sequence masked dense attention on the SAME tokens."""
-    t = k.shape[1]
-    mask = (jnp.arange(t)[None, :] < context_lens[:, None])[:, None, None, :]
-    return reference_attention(q[:, None], k, v, mask=mask,
-                               scale=scale)[:, 0]
-
-
-def _case(b, t, h, hkv, d, context_lens, block_size, seed=0):
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, t, hkv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, t, hkv, d)), jnp.float32)
-    cl = jnp.asarray(context_lens, jnp.int32)
-    k_pool, v_pool, tables = _pools_from_dense(k, v, block_size)
-    return q, k, v, cl, k_pool, v_pool, tables
-
-
-RAGGED_CASES = [
-    # (B, T, H, Hkv, D, context_lens, block_size)
-    (3, 16, 4, 4, 8, [1, 1, 1], 4),          # all single-token
-    (3, 16, 4, 4, 8, [4, 8, 16], 4),         # exact block boundaries
-    (4, 13, 4, 4, 8, [1, 4, 7, 13], 4),      # mixed depths, odd T
-    (2, 9, 8, 2, 16, [3, 9], 4),             # GQA 4:1
-    (2, 12, 4, 1, 8, [5, 12], 8),            # MQA
-]
-
-
-@pytest.mark.parametrize("b,t,h,hkv,d,lens,bs", RAGGED_CASES)
-def test_reference_matches_dense(b, t, h, hkv, d, lens, bs):
-    q, k, v, cl, k_pool, v_pool, tables = _case(b, t, h, hkv, d, lens, bs)
-    got = paged_attention_reference(q, k_pool, v_pool, tables, cl)
-    want = _dense_oracle(q, k, v, cl)
-    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
-
-
-@pytest.mark.parametrize("b,t,h,hkv,d,lens,bs", RAGGED_CASES)
-def test_kernel_matches_reference(b, t, h, hkv, d, lens, bs):
-    """The Pallas kernel in interpret mode (CPU) against the oracle —
-    the acceptance bar from the paged-serving design: <= 1e-5 in fp32."""
-    q, k, v, cl, k_pool, v_pool, tables = _case(b, t, h, hkv, d, lens, bs)
-    got = paged_attention(q, k_pool, v_pool, tables, cl,
-                          use_kernel=True, interpret=True)
-    want = _dense_oracle(q, k, v, cl)
-    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
-
-
-def test_dispatcher_reference_on_cpu():
-    """Defaults off-TPU must take the XLA reference path (no interpret
-    overhead in production CPU serving)."""
-    q, k, v, cl, k_pool, v_pool, tables = _case(2, 8, 4, 4, 8, [3, 8], 4)
-    got = paged_attention(q, k_pool, v_pool, tables, cl)
-    want = paged_attention_reference(q, k_pool, v_pool, tables, cl)
-    np.testing.assert_allclose(got, want, atol=0, rtol=0)
-
-
-def test_scratch_block_rows_are_inert():
-    """A padded batch row (all-zero table, context_len 1) must produce
-    finite output and not disturb real rows — the engine's fixed-shape
-    decode relies on this."""
-    q, k, v, cl, k_pool, v_pool, tables = _case(2, 8, 4, 4, 8, [3, 8], 4)
-    # row 2: dummy pointing at scratch block 0
-    q3 = jnp.concatenate([q, q[:1]], axis=0)
-    tables3 = jnp.concatenate(
-        [tables, jnp.zeros((1, tables.shape[1]), jnp.int32)], axis=0)
-    cl3 = jnp.concatenate([cl, jnp.ones((1,), jnp.int32)], axis=0)
-    got = paged_attention(q3, k_pool, v_pool, tables3, cl3,
-                          use_kernel=True, interpret=True)
-    assert bool(jnp.isfinite(got).all())
-    want = paged_attention(q, k_pool, v_pool, tables, cl,
-                           use_kernel=True, interpret=True)
-    np.testing.assert_allclose(got[:2], want, atol=0, rtol=0)
-
-
-def test_kernel_grad_free_path_jits():
-    """The kernel must be jit-compatible (the engine decode step wraps it)."""
-    q, k, v, cl, k_pool, v_pool, tables = _case(2, 8, 4, 4, 8, [3, 8], 4)
-    f = jax.jit(lambda *a: paged_attention(*a, use_kernel=False))
-    got = f(q, k_pool, v_pool, tables, cl)
-    want = _dense_oracle(q, k, v, cl)
-    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
-
-
 # -- ragged mixed prefill+decode ------------------------------------------
 
-def _ragged_case(rows, h, hkv, d, bs, tq, seed=0, extra_pad_tiles=1):
+def _ragged_case(rows, h, hkv, d, bs, tq, tmax=None, seed=0,
+                 extra_pad_tiles=1):
     """Build a flat-packed mixed batch. `rows` is a list of
     (context_len, q_len): each row's queries are the window
     [ctx - q_len, ctx) of its sequence — q_len=1 is a decode row,
     q_len=ctx a whole prompt, anything between a mid-prompt chunk.
-    Returns the ragged operands (the pool in the cache's layout: K and
-    V of a head side by side in one lane-dense row) plus the dense k/v
-    and per-row dense queries for the oracle."""
+    `tmax` (default: the longest context) is how many tokens the block
+    tables are wide enough for. Returns the ragged operands (the pool
+    in the cache's layout: K and V of a head side by side in one
+    lane-dense row) plus the dense k/v and per-row dense queries for
+    the oracle."""
     b = len(rows)
-    tmax = max(ctx for ctx, _ in rows)
+    tmax = tmax or max(ctx for ctx, _ in rows)
     rng = np.random.default_rng(seed)
     k = jnp.asarray(rng.standard_normal((b, tmax, hkv, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, tmax, hkv, d)), jnp.float32)
@@ -204,45 +122,62 @@ RAGGED_MIXED_CASES = [
 ]
 
 
-@pytest.mark.parametrize("rows,h,hkv,d,bs,tq", RAGGED_MIXED_CASES)
-def test_ragged_reference_matches_dense(rows, h, hkv, d, bs, tq):
-    args, k, v, qrows, spans = _ragged_case(rows, h, hkv, d, bs, tq)
+def _decode_case(tmax, h, hkv, d, context_lens, bs):
+    """A batch of single-token sequences — every row a decode row
+    `(len, 1)`, its one query at position len - 1 — under block tables
+    wide enough for `tmax` tokens."""
+    return ([(n, 1) for n in context_lens], h, hkv, d, bs, 4, tmax)
+
+
+# every step of a decoding batch is this shape
+DECODE_CASES = [
+    _decode_case(16, 4, 4, 8, [1, 1, 1], 4),         # all single-token
+    _decode_case(16, 4, 4, 8, [4, 8, 16], 4),        # exact block boundaries
+    _decode_case(13, 4, 4, 8, [1, 4, 7, 13], 4),     # mixed depths, odd T
+    _decode_case(9, 8, 2, 16, [3, 9], 4),            # GQA 4:1
+    _decode_case(12, 4, 1, 8, [5, 12], 8),           # MQA
+]
+RAGGED_CASES = [c + (None,) for c in RAGGED_MIXED_CASES] + DECODE_CASES
+
+
+@pytest.mark.parametrize("rows,h,hkv,d,bs,tq,tmax", RAGGED_CASES)
+def test_ragged_reference_matches_dense(rows, h, hkv, d, bs, tq, tmax):
+    args, k, v, qrows, spans = _ragged_case(rows, h, hkv, d, bs, tq, tmax)
     got = ragged_paged_attention_reference(*args, groups=h // hkv)
+    want = _ragged_dense_oracle(k, v, qrows, rows)
     for i, (off, qlen) in enumerate(spans):
-        want = _ragged_dense_oracle(k, v, qrows, rows)[i]
-        np.testing.assert_allclose(got[off:off + qlen], want,
+        np.testing.assert_allclose(got[off:off + qlen], want[i],
                                    atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("rows,h,hkv,d,bs,tq", RAGGED_MIXED_CASES)
-def test_ragged_kernel_matches_reference(rows, h, hkv, d, bs, tq):
-    """The ragged Pallas kernel in interpret mode vs the XLA oracle on
-    mixed batches — decode rows, mid-prompt chunks, pad slack and GQA
-    head groups in one launch."""
-    args, k, v, qrows, spans = _ragged_case(rows, h, hkv, d, bs, tq)
+@pytest.mark.parametrize("rows,h,hkv,d,bs,tq,tmax", RAGGED_CASES)
+def test_ragged_kernel_matches_reference(rows, h, hkv, d, bs, tq, tmax):
+    """The ragged Pallas kernel in interpret mode vs the XLA oracle and
+    the dense one on mixed batches — decode rows, mid-prompt chunks,
+    pad slack and GQA head groups in one launch."""
+    args, k, v, qrows, spans = _ragged_case(rows, h, hkv, d, bs, tq, tmax)
     got = ragged_paged_attention(*args, use_kernel=True, interpret=True,
                                  groups=h // hkv)
     want = ragged_paged_attention_reference(*args, groups=h // hkv)
+    dense = _ragged_dense_oracle(k, v, qrows, rows)
     assert bool(jnp.isfinite(got).all())    # pad queries/tiles stay finite
-    for off, qlen in spans:
+    for i, (off, qlen) in enumerate(spans):
         np.testing.assert_allclose(got[off:off + qlen],
                                    want[off:off + qlen],
                                    atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(got[off:off + qlen], dense[i],
+                                   atol=1e-5, rtol=1e-5)
 
 
-def test_ragged_decode_rows_match_decode_kernel():
-    """A decode row in the ragged layout is EXACTLY the old decode
-    kernel's contract (q_start = ctx - 1): outputs must agree with
-    paged_attention on the same pools."""
-    rows = [(5, 1), (8, 1), (3, 1)]
-    args, k, v, qrows, spans = _ragged_case(rows, 4, 4, 8, 4, 4)
-    qflat, kv_pool, bt, cl, qs, tr, to = args
-    k_pool, v_pool = unpack_kv(kv_pool, 8)
-    got = ragged_paged_attention_reference(*args)
-    qb = jnp.stack([qrows[i][0] for i in range(3)])    # [B, H, D]
-    want = paged_attention_reference(qb, k_pool, v_pool, bt[:3], cl[:3])
-    for i, (off, _) in enumerate(spans):
-        np.testing.assert_allclose(got[off], want[i], atol=1e-6, rtol=1e-6)
+def test_ragged_dispatcher_reference_on_cpu(monkeypatch):
+    """With defaults and no TPU the entry point IS the XLA reference,
+    bit for bit (no interpret overhead in CPU serving)."""
+    monkeypatch.delenv("PTPU_PAGED_KERNEL", raising=False)
+    args, *_ = _ragged_case([(7, 1), (10, 6), (4, 4)], 8, 2, 16, 4, 4)
+    got = ragged_paged_attention(*args, groups=4)
+    want = ragged_paged_attention_reference(*args, scale=16 ** -0.5,
+                                            groups=4)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_ragged_pad_rows_are_inert():
@@ -429,7 +364,6 @@ def test_env_override_dispatch_covers_mixed(monkeypatch):
 ], ids=["gpt2m", "gpt2l", "latent128", "gpt2m_tp4", "gpt2l_tp4",
         "keys_full", "bytes_full", "short_table", "tiny"])
 def test_span_is_read_off_the_pool_shape(bs, lanes, itemsize, mb, want):
-    from paddle_tpu.kernels.paged_attention import ragged_span
     assert ragged_span(bs, lanes, itemsize, mb) == want
 
 
@@ -443,7 +377,6 @@ SPAN_ROWS = [(18, 1), (31, 1), (32, 1), (38, 10), (16, 16)]
 
 
 def _span_case(kind, span, monkeypatch):
-    from paddle_tpu.engine.paged_cache import latent_lanes, pack_latent
     from paddle_tpu.kernels import paged_attention as pa
     bs, tq = 4, 4
     h, hkv, d = {"mha": (4, 4, 8), "gqa": (8, 2, 16), "mixed": (4, 4, 8),

@@ -350,7 +350,11 @@ class TestRouter:
         finally:
             router.stop()
             for fe in fes:
-                fe._teardown()
+                # stop(), not _teardown(): the replica that was not
+                # drained still has its engine loop, which would write
+                # `frontdoor.wait` spans into the process-wide ring for
+                # the rest of this worker's life
+                fe.stop()
 
 
 # -- lock-discipline regressions -------------------------------------------

@@ -3,7 +3,7 @@
 Sweeps (block_size x num_blocks) cells and reports, per cell:
 
 - pool_gb:    KV pool footprint = layers * NB * BS * Hkv * W * 2B, W =
-              engine/paged_cache.py head_lanes(Dh): a head's K and V
+              kernels/paged_attention.py head_lanes(Dh): a head's K and V
               side by side in one bf16 row, padded to whole 128-lane
               tiles (2 * Dh at Dh 64 and 128), and the fraction of the
               rig's HBM it claims (--hbm-gb).
@@ -73,7 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.engine.paged_cache import head_lanes, pack_kv, unpack_kv
+from paddle_tpu.kernels.paged_attention import head_lanes, pack_kv, unpack_kv
 
 
 def kv_pool_bytes(layers, num_blocks, block_size, kv_heads, head_dim,
