@@ -29,10 +29,12 @@ import jax
 
 from paddle_tpu.utils.log import vlog
 
-# 90 s of the busiest serving cell (about 650 steps of 12 spans, 700
-# request records) fit twice over; at 50 idle `frontdoor.wait` spans a
-# second the ring still reaches back five minutes
-RING_SPANS = 16384
+# 90 s of the busiest serving cell fit nearly twice over: a step leaves
+# 10 spans and two steps in three a request record, 390 entries a second
+# at 36 steps a second (PERF.md, PR 30: at 16,384 the ring no longer
+# reached back over a 40 s window and its tail); at 50 idle
+# `frontdoor.wait` spans a second it reaches back twenty minutes
+RING_SPANS = 65536
 _lock = threading.Lock()
 # completed spans: name/ts/dur/tid (us) and args
 _events: Deque[dict] = deque(maxlen=RING_SPANS)   # guarded-by: _lock

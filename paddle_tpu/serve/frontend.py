@@ -55,8 +55,12 @@ host-side allocator bookkeeping). All engine mutation happens on ONE
 loop thread. The connection side is an asyncio event loop on ONE
 acceptor thread (serve/aio.py): each connection is a coroutine that
 only enqueues work (submissions, cancellations) onto thread-safe
-queues and parks on its stream's event, woken from the engine thread
-via `loop.call_soon_threadsafe`. Thousands of idle SSE streams cost
+queues and parks on its stream's event. The engine thread enqueues a
+frame the moment it has one and wakes the consumers ONCE A STEP: one
+`loop.call_soon_threadsafe` hands the event loop every stream that got
+a frame in this iteration of the engine loop (`_HandOver`), so the row
+loop of a step never gives the interpreter away between two of its
+tokens. Thousands of idle SSE streams cost
 coroutines, not OS threads — `ptpu_serve_conn_threads` stays flat
 while `ptpu_serve_open_connections` climbs. Disconnects come from the
 transport (a parked read resolves on peer close); writes are
@@ -115,6 +119,52 @@ from paddle_tpu.utils.log import serve_event
 _DIR_INTERVAL_S = 0.25   # default /kvprefixes + /debug refresh cadence
 
 
+def _set_events(events) -> None:
+    for ev in events:
+        ev.set()
+
+
+def _wake(loop: asyncio.AbstractEventLoop, events) -> None:
+    """One cross-thread wake-up for all of `events` (a no-op once the
+    loop is closed: teardown). `call_soon_threadsafe` writes a byte to
+    the loop's self-pipe — a system call, which gives the interpreter
+    to whoever waits for it — so it is made once for many events."""
+    try:
+        loop.call_soon_threadsafe(_set_events, events)
+    except RuntimeError:
+        pass
+
+
+class _HandOver:
+    """The streams that got a frame since the engine loop last woke
+    their consumers. `_Stream.push` on the engine loop's thread only
+    notes the stream here; the loop calls `flush()` once an iteration
+    (inside `frontdoor.finish`, after the step's tokens and done
+    frames are all enqueued), again for what an iteration pushed
+    outside a step, and on every exit, so nothing stays parked.
+    `tid` is the engine loop's thread; `pending` belongs to it."""
+
+    __slots__ = ("tid", "pending")
+
+    def __init__(self):
+        self.tid: Optional[int] = None
+        self.pending: set = set()
+
+    def flush(self) -> int:
+        """Wake every noted stream's consumer, one
+        `call_soon_threadsafe` per event loop; returns the streams
+        woken."""
+        if not self.pending:
+            return 0
+        pending, self.pending = self.pending, set()
+        by_loop: Dict[asyncio.AbstractEventLoop, list] = {}
+        for s in pending:
+            by_loop.setdefault(s.loop, []).append(s.ev)
+        for loop, events in by_loop.items():
+            _wake(loop, events)
+        return len(pending)
+
+
 class _Stream:
     """Plumbing for one in-flight completion GROUP (1 primary +
     n - 1 forked candidates share one HTTP response): the engine
@@ -125,13 +175,15 @@ class _Stream:
 
     The queue stays a thread-safe `queue.Queue` (warmup drains it
     BLOCKING before any event loop exists); `attach()` bridges it to
-    the connection coroutine — after that every push also wakes the
-    stream's asyncio.Event via `loop.call_soon_threadsafe`, so a
-    parked consumer resumes without polling. `gone` is flipped in-loop
-    by the transport disconnect watcher."""
+    the connection coroutine, which parks on the stream's
+    asyncio.Event and resumes without polling. A push enqueues at
+    once; the wake-up of a push from the engine loop's thread is the
+    loop's one hand-over an iteration (`_HandOver`), that of a push
+    from any other thread is made on the spot. `gone` is flipped
+    in-loop by the transport disconnect watcher."""
 
     __slots__ = ("params", "arrival_us", "q", "req", "streamed",
-                 "cand_pos", "loop", "ev", "gone")
+                 "cand_pos", "loop", "ev", "handover", "gone")
 
     def __init__(self, params: dict, arrival_us: Optional[float] = None):
         self.params = params
@@ -144,25 +196,30 @@ class _Stream:
         self.cand_pos: Dict[int, int] = {}   # candidate -> tokens sent
         self.loop: Optional[asyncio.AbstractEventLoop] = None
         self.ev: Optional[asyncio.Event] = None
+        self.handover: Optional[_HandOver] = None
         self.gone = False
 
-    def attach(self, loop: asyncio.AbstractEventLoop,
-               ev: asyncio.Event) -> None:
-        """Bind the consumer side; call BEFORE submitting to the
-        engine so no push can miss the wake-up."""
+    def attach(self, loop: asyncio.AbstractEventLoop, ev: asyncio.Event,
+               handover: _HandOver) -> None:
+        """Bind the consumer side (and the engine loop's hand-over,
+        which wakes it); call BEFORE submitting to the engine so no
+        push can miss the wake-up."""
         self.ev = ev
+        self.handover = handover
         self.loop = loop
 
     def push(self, item: tuple) -> None:
-        """Engine-thread producer: enqueue + wake the parked
-        coroutine (a no-op wake before attach/after loop teardown)."""
+        """Producer: enqueue now; the wake-up waits for the engine
+        loop's hand-over when this IS the engine loop's thread (a
+        no-op wake before attach/after loop teardown)."""
         self.q.put(item)
-        loop, ev = self.loop, self.ev
-        if loop is not None and ev is not None:
-            try:
-                loop.call_soon_threadsafe(ev.set)
-            except RuntimeError:
-                pass                    # loop already closed (teardown)
+        loop, ev, handover = self.loop, self.ev, self.handover
+        if loop is None or ev is None:
+            return
+        if threading.get_ident() == handover.tid:
+            handover.pending.add(self)
+        else:
+            _wake(loop, (ev,))
 
 
 class ServeFrontend:
@@ -260,6 +317,8 @@ class ServeFrontend:
         self._engine_thread: Optional[threading.Thread] = None
         self._work = threading.Event()       # engine loop wake-up
         self._stopped = threading.Event()    # engine loop exited
+        # the engine loop's one wake-up a step (its thread only)
+        self._handover = _HandOver()
         self._submit: "deque[_Stream]" = deque()
         self._cancel: "deque[_Stream]" = deque()
         self._lock = threading.Lock()
@@ -327,6 +386,10 @@ class ServeFrontend:
             "ptpu_serve_slow_client_evictions_total",
             "Streams cancelled at the per-connection write deadline "
             "(stalled readers; their KV blocks are freed)")
+        self._m_wakeups = m.counter(
+            "ptpu_frontdoor_wakeups_total",
+            "Hand-overs of the engine loop that woke at least one "
+            "stream (generated tokens over this: tokens a wake-up)")
         self._m_token_write = m.histogram(
             "ptpu_serve_token_write_seconds",
             "Per-token SSE write+drain latency")
@@ -511,6 +574,7 @@ class ServeFrontend:
     # -- engine loop ------------------------------------------------------
     def _engine_loop(self) -> None:
         eng = self.engine
+        self._handover.tid = threading.get_ident()
         try:
             while True:
                 self._drain_control_queues()
@@ -538,6 +602,9 @@ class ServeFrontend:
                 elif self._stop_requested:
                     self._abort_active("shutdown")
                     break
+                # what this iteration pushed outside a step: a refused
+                # submission's error, the drain deadline's aborts
+                self._hand_over()
                 if not progressed:
                     with annotate("frontdoor.wait"):
                         self._work.wait(0.02)
@@ -550,6 +617,8 @@ class ServeFrontend:
             serve_event("serve_engine_crash", error=repr(e))
             raise
         finally:
+            # no exit leaves a consumer parked on a frame it was sent
+            self._hand_over()
             # spill the host tier LAST, with no traffic left to mutate
             # it: the successor process warm-starts from exactly the
             # state the drain left behind
@@ -702,9 +771,20 @@ class ServeFrontend:
         best = max(infos, key=lambda c: c["logprob"])
         return best["index"], infos
 
+    def _hand_over(self) -> int:
+        """Wake the consumers of every stream that got a frame since
+        the last hand-over (engine-loop thread only); returns their
+        number."""
+        woken = self._handover.flush()
+        if woken:
+            self._m_wakeups.inc()
+        return woken
+
     def _flush_finished(self) -> None:
         """Push done frames for request GROUPS the last step finished
-        (for n > 1 the frame waits until every candidate is done)."""
+        (for n > 1 the frame waits until every candidate is done),
+        then hand the step's frames over: a request's done frame
+        leaves in the same wake-up as its last token."""
         with annotate("frontdoor.finish") as span:
             with self._lock:
                 done = [(rid, s) for rid, s in self._active.items()
@@ -724,7 +804,7 @@ class ServeFrontend:
                              # silent best_of-only candidates stay
                              # server-side; the wire sees n candidates
                              "candidates": cands[:n_stream]}))
-            span.set(closed=len(done))
+            span.set(closed=len(done), woken=self._hand_over())
 
     def _drain_finished(self) -> bool:
         """True once every in-flight stream completed (or the deadline
@@ -1017,7 +1097,8 @@ class ServeFrontend:
                 None, self._maybe_pull_kv, req, params["prompt"])
         stream = _Stream(params, arrival_us)
         # bind the wake-up bridge BEFORE the engine can see the stream
-        stream.attach(asyncio.get_running_loop(), asyncio.Event())
+        stream.attach(asyncio.get_running_loop(), asyncio.Event(),
+                      self._handover)
         with self._lock:
             self._open_streams += 1
         try:

@@ -204,6 +204,26 @@ def test_request_record_is_monotone_and_names_its_steps(served):
                for c in _spans(events, "frontdoor.control"))
 
 
+def test_finish_span_says_how_many_streams_it_woke(served):
+    """`frontdoor.finish` closes a step's streams and hands its frames
+    to the event loop in one wake-up: `woken` counts the streams of
+    that hand-over. One stream was served, so every step that emitted
+    a token woke one stream, and the wake-ups are the counter's."""
+    fe, out, events = served
+    finishes = _spans(events, "frontdoor.finish")
+    assert all({"closed", "woken"} <= set(e["args"]) for e in finishes)
+    emitting = [e for e in _spans(events, "engine.sample")
+                if e["args"]["emitted"]]
+    woke = [e for e in finishes if e["args"]["woken"]]
+    # the warm-up's two tokens went to a stream with no loop to wake
+    assert len(woke) == len(out["tokens"]) == len(emitting) - 2
+    assert sum(e["args"]["woken"] for e in finishes) == len(woke)
+    assert fe.obs.get("ptpu_frontdoor_wakeups_total").value == len(woke)
+    # the done frame left with its last token's hand-over
+    assert [e["args"] for e in finishes if e["args"]["closed"]][-1] == \
+        {"closed": 1, "woken": 1}
+
+
 def test_queued_starts_at_the_arrival(served):
     fe, out, events = served
     (rec,) = [e["args"] for e in _spans(events, "request")
