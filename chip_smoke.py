@@ -1,7 +1,9 @@
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py             # one TPU chip: train, serve, latent
+    python chip_smoke.py             # one TPU chip: train, serve, latent,
+                                     # hybrid
     python chip_smoke.py --only latent   # that phase alone: a minute
+    python chip_smoke.py --only hybrid   # likewise
     python chip_smoke.py --chips 4   # one four-chip host: tp=4 serving
                                      # against tp=1, and the sharded
                                      # training parity gate — nothing else
@@ -21,6 +23,13 @@ medium's published widths and full depth in bf16:
   vocabulary), bf16 weights from the benchmark's seed, through
   `ServeEngine`'s one step and its latent pool; the logits it sampled
   from are held against the benchmark's plain float32 reference.
+
+- hybrid: the decoder of five layer kinds at the published widths of
+  `benchmarks/configs/phi-4-mini-flash.json` cut to six layers (one of
+  each kind: state-space, window, the memory layer, full, gated memory
+  unit, cross), through `ServeEngine`'s paged pool, window rings and
+  state slots; the largest difference of the logits it sampled from
+  against the benchmark's plain float32 reference is printed.
 
 Each phase prints one JSON line of what it counted and which of its
 gates failed; the last line of stdout is the result object. Everything
@@ -80,6 +89,16 @@ LOGIT_TOL = 0.05
 # first 2,048 tokens in the prefix index.
 LATENT = dict(layers=2, num_blocks=256, prompt_lens=(2500, 2300),
               shared_prefix=2048, new_tokens=8)
+# The hybrid phase: one layer of each of the five kinds at the published
+# widths (the memory layer doubles as the state-space one), the cell's
+# own engine but for 64 paged blocks and four slots. The first prompt is
+# prefilled in six chunks of 256 and decoded past 1,400 positions, so
+# every ring has turned over; the second takes the slot a finished
+# sequence left.
+HYBRID = dict(layer_kinds=("mamba", "window", "mamba", "full", "gmu",
+                           "cross"),
+              num_blocks=64, max_batch_size=4, prompt_lens=(1400, 300),
+              new_tokens=8)
 TRAIN = dict(batch=8, seq=1024, steps=5)
 PEAK_BYTES_LIMIT = 14e9
 SEED = 0
@@ -459,6 +478,54 @@ def train_phase(widths: dict, batch: int, seq: int, steps: int, dtype,
     return line
 
 
+def _spied_generate(eng, prompts, new_tokens: int):
+    """Serve `prompts` one at a time; returns (generated, every logits
+    row the engine sampled from, in order)."""
+    from paddle_tpu.engine import engine as engine_mod
+    seen = []
+    sample = engine_mod._sample
+
+    def spy(logits, req, pos):
+        seen.append(np.array(logits, np.float32))
+        return sample(logits, req, pos)
+
+    with mock.patch.object(engine_mod, "_sample", spy):
+        generated = [eng.generate([prompt], max_new_tokens=new_tokens)[0]
+                     for prompt in prompts]
+    return generated, seen
+
+
+def _teacher_forced(prompts, generated, new_tokens: int):
+    """(tokens, rows) of the reference's pass over each prompt with its
+    served tokens: the positions the engine sampled from."""
+    width = -(-(max(map(len, prompts)) + new_tokens) // 128) * 128
+    tokens = np.zeros((len(prompts), width), np.int32)
+    rows = np.zeros((len(prompts), new_tokens), np.int32)
+    for i, (p, g) in enumerate(zip(prompts, generated)):
+        tokens[i, :len(p) + len(g)] = p + g
+        rows[i] = len(p) - 1 + np.arange(new_tokens)
+    return tokens, rows
+
+
+def _against_reference(ref, seen, generated, failed: list) -> dict:
+    """The sampled logits against the reference's [rows, vocab]: the
+    line's fields, and the gate on the largest difference."""
+    got = np.stack(seen)
+    logit_err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+    if not (np.isfinite(got).all() and logit_err <= LOGIT_TOL):
+        failed.append(f"the step's logits off the reference by "
+                      f"{logit_err:.4f} of the largest logit")
+    served = np.concatenate(generated)
+    gaps = ref.max(axis=-1) - ref[np.arange(len(served)), served]
+    return {
+        "logit_err_share_of_max": round(logit_err, 5),
+        "logit_tol": LOGIT_TOL,
+        "largest_logit_difference": float(np.max(np.abs(got - ref))),
+        "token_gap_max": float(gaps.max()),
+        "tokens_equal_to_reference": f"{int((gaps == 0).sum())}/{gaps.size}",
+    }
+
+
 def latent_phase(config: dict, layers: int, num_blocks: int, prompt_lens,
                  shared_prefix: int, new_tokens: int, seed: int,
                  cache=None) -> dict:
@@ -472,7 +539,6 @@ def latent_phase(config: dict, layers: int, num_blocks: int, prompt_lens,
     import jax.numpy as jnp
 
     from benchmarks.common import build_model
-    from paddle_tpu.engine import engine as engine_mod
     from paddle_tpu.engine.engine import ServeEngine
 
     t_start = time.monotonic()
@@ -491,18 +557,8 @@ def latent_phase(config: dict, layers: int, num_blocks: int, prompt_lens,
     prompts = [shared + rng.integers(0, vocab, n - shared_prefix).tolist()
                for n in prompt_lens]
 
-    seen = []
-    sample = engine_mod._sample
-
-    def spy(logits, req, pos):
-        seen.append(np.array(logits, np.float32))
-        return sample(logits, req, pos)
-
-    generated = []
-    with mock.patch.object(engine_mod, "_sample", spy):
-        for prompt in prompts:     # one at a time: the second hits
-            generated.append(eng.generate([prompt],
-                                          max_new_tokens=new_tokens)[0])
+    # one at a time: the second hits
+    generated, seen = _spied_generate(eng, prompts, new_tokens)
     setup_s = time.monotonic() - t_start
     stats = eng.stats()
     failed = []
@@ -535,23 +591,12 @@ def latent_phase(config: dict, layers: int, num_blocks: int, prompt_lens,
     del eng
     gc.collect()
 
-    width = -(-(max(prompt_lens) + new_tokens) // 128) * 128
-    tokens = np.zeros((len(prompts), width), np.int32)
-    rows = np.zeros((len(prompts), new_tokens), np.int32)
-    for i, (p, g) in enumerate(zip(prompts, generated)):
-        tokens[i, :len(p) + len(g)] = p + g
-        rows[i] = len(p) - 1 + np.arange(new_tokens)
+    tokens, rows = _teacher_forced(prompts, generated, new_tokens)
     ref, _ = reference.logits_at(config, seed, jnp.asarray(tokens),
                                  jnp.asarray(rows))
-    ref = np.asarray(ref, np.float32).reshape(-1, vocab)
-    got = np.stack(seen)
-    logit_err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
-    if not (np.isfinite(got).all() and logit_err <= LOGIT_TOL):
-        failed.append(f"the step's logits off the reference by "
-                      f"{logit_err:.4f} of the largest logit")
-    best = ref.max(axis=-1)
-    served = np.concatenate(generated)
-    gaps = best - ref[np.arange(len(served)), served]
+    compared = _against_reference(
+        np.asarray(ref, np.float32).reshape(-1, vocab), seen, generated,
+        failed)
     line = {
         "phase": "latent", "config": config["name"], "layers": layers,
         "dtype": config["compute_dtype"], "engine": serve,
@@ -563,16 +608,105 @@ def latent_phase(config: dict, layers: int, num_blocks: int, prompt_lens,
         "pallas_in_step": pallas_in_step,
         "step_pool_sized_copies": len(pool_copies),
         "expert_pairs": int(per_expert.sum()),
-        "experts_touched": int((per_expert > 0).sum()),
-        "logit_err_share_of_max": round(logit_err, 5),
-        "logit_tol": LOGIT_TOL,
-        "token_gap_max": float(gaps.max()),
-        "tokens_equal_to_reference": f"{int((gaps == 0).sum())}/{gaps.size}",
+        "experts_touched": int((per_expert > 0).sum()), **compared,
         "peak_bytes_in_use": peak, "failed": failed,
     }
     if cache is not None:
         line["compile_cache"] = cache.take()
     return line
+
+
+def hybrid_phase(config: dict, layer_kinds, num_blocks: int,
+                 max_batch_size: int, prompt_lens, new_tokens: int,
+                 seed: int, cache=None) -> dict:
+    """Serve the configuration's block cut to `layer_kinds` in process,
+    through the paged pool, the window rings and the state slots, and
+    hold every logits row the engine sampled from against the
+    benchmark's reference, teacher-forced. Returns the phase's JSON
+    line."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.common import build_model
+    from paddle_tpu.engine.engine import ServeEngine
+
+    t_start = time.monotonic()
+    config = dict(config, layer_kinds=list(layer_kinds),
+                  num_hidden_layers=len(layer_kinds))
+    dev = jax.devices()[0]
+    model = build_model(config)
+    params = importlib.import_module(config["weights"]).make_params(
+        config, seed)
+    reference = importlib.import_module(config["reference"])
+    serve = dict(config["serve"], num_blocks=num_blocks,
+                 max_batch_size=max_batch_size)
+    eng = ServeEngine(model, {"params": params}, **serve)
+    del params
+    rng = np.random.default_rng(seed)
+    vocab = config["vocab_size"]
+    prompts = [rng.integers(0, vocab, n).tolist() for n in prompt_lens]
+
+    # one at a time: the slot is handed on
+    generated, seen = _spied_generate(eng, prompts, new_tokens)
+    setup_s = time.monotonic() - t_start
+    stats = eng.stats()
+    failed = []
+    compiles = eng._step_fn._cache_size()
+    if compiles != 1:
+        failed.append(f"the step compiled {compiles} times")
+    step_text = _engine_step_compiled(eng).as_text()
+    pallas_in_step = "tpu_custom_call" in step_text
+    if not pallas_in_step:
+        failed.append("no Pallas kernel in the engine's step program")
+    # the gate is of the chip's program: off it the scan's XLA tier
+    # carries the state through a loop, and the compiler copies it
+    copies = [c for size in sorted({int(p.size)
+                                    for p in eng.cache.pools[:-1]})
+              for c in pool_sized_copies(step_text, size)
+              ] if pallas_in_step else []
+    if copies:
+        failed.append(f"{len(copies)} copies of a pool's or a state's size "
+                      f"in the engine's step program: {copies[0]}")
+    released = eng.cache.window_blocks_released
+    if max(prompt_lens) > config["sliding_window"] and not released:
+        failed.append("no ring block was given back")
+    eng.cache.assert_quiesced()
+    peak = _device_bytes(dev, "peak_bytes_in_use")
+    del eng
+    gc.collect()
+
+    tokens, rows = _teacher_forced(prompts, generated, new_tokens)
+    ref = reference.logits_at(config, seed, jnp.asarray(tokens),
+                              jnp.asarray(rows))
+    compared = _against_reference(
+        np.asarray(ref, np.float32).reshape(-1, vocab), seen, generated,
+        failed)
+    line = {
+        "phase": "hybrid", "config": config["name"],
+        "layer_kinds": list(layer_kinds),
+        "dtype": config["compute_dtype"], "engine": serve,
+        "setup_and_serve_seconds": round(setup_s, 1),
+        "prompt_tokens": [len(p) for p in prompts],
+        "new_tokens_each": new_tokens, "engine_compiles": compiles,
+        "engine_steps": stats["steps"],
+        "max_chunk_tokens": stats["max_chunk_tokens"],
+        "pallas_in_step": pallas_in_step,
+        "step_pool_or_state_sized_copies": len(copies),
+        "window_blocks_released": released, **compared,
+        "peak_bytes_in_use": peak, "failed": failed,
+    }
+    if cache is not None:
+        line["compile_cache"] = cache.take()
+    return line
+
+
+def _hybrid_config() -> dict:
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "phi-4-mini-flash.json")) as f:
+        return json.load(f)
 
 
 def _latent_config() -> dict:
@@ -642,7 +776,7 @@ def four_chip_phases(dtype, cache=None) -> bool:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--only", choices=("latent",), default=None,
+    ap.add_argument("--only", choices=("latent", "hybrid"), default=None,
                     help="run that one-chip phase alone")
     args = ap.parse_args(argv)
     chips = args.chips
@@ -681,6 +815,9 @@ def main(argv=None) -> int:
     elif args.only == "latent":
         ok = _emit(_quiet(latent_phase, _latent_config(), seed=SEED,
                           cache=cache, **LATENT))
+    elif args.only == "hybrid":
+        ok = _emit(_quiet(hybrid_phase, _hybrid_config(), seed=SEED,
+                          cache=cache, **HYBRID))
     else:
         # train first: peak_bytes_in_use is the process's high-water
         # mark and cannot be reset, and the serve phase's is the higher
@@ -694,6 +831,9 @@ def main(argv=None) -> int:
         gc.collect()
         ok = _emit(_quiet(latent_phase, _latent_config(), seed=SEED,
                           cache=cache, **LATENT)) and ok
+        gc.collect()
+        ok = _emit(_quiet(hybrid_phase, _hybrid_config(), seed=SEED,
+                          cache=cache, **HYBRID)) and ok
     print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 

@@ -72,7 +72,8 @@ import numpy as np
 
 from paddle_tpu.core.module import Context, _CtxCore
 from paddle_tpu.engine.kvtier import HostKVTier, prefix_digest
-from paddle_tpu.engine.paged_cache import PagedKVCache, refuse_latent
+from paddle_tpu.engine.paged_cache import (CacheLayout, PagedKVCache,
+                                            refuse_latent, refuse_slots)
 from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
 from paddle_tpu.kernels.paged_attention import (pack_kv, ragged_span,
@@ -124,14 +125,18 @@ def serve_metadata(model) -> dict:
     }
 
 
-def compile_steps(model, variables, compress: bool, serve_tp=None):
+def compile_steps(model, variables, compress: bool, serve_tp=None,
+                  kinds=None):
     """The engine's two compiled entry points, `(step, copy_blocks)`:
     the ONE ragged step for all traffic and the fixed-width COW replay.
     Both take the pools DONATED: the call writes the buffers it was
     handed and returns them, so a step holds one pool, not two, and the
     handles passed in are dead afterwards (the engine assigns the
     returned ones back before anything else runs). `variables` may be
-    shapes; `compress` says whether the int8 pools ride along.
+    shapes; `compress` says whether the int8 pools ride along; `kinds`
+    names the entries of `pools` where a model's cache layout gives
+    them several (`PagedKVCache.kinds`): the block copy moves blocks of
+    the paged ones only.
 
     Under tensor parallelism (`serve_tp`) the operand shardings are
     pinned so every call reuses the same executable (TP004 / the
@@ -182,7 +187,10 @@ def compile_steps(model, variables, compress: bool, serve_tp=None):
     def _copy_blocks(pools, src, dst):
         # COW replay: dst blocks take src blocks' contents, every
         # layer; padding lanes are (0, 0) — scratch onto itself
-        return [pool.at[dst].set(pool[src]) for pool in pools]
+        if kinds is None:
+            return [pool.at[dst].set(pool[src]) for pool in pools]
+        return [pool.at[dst].set(pool[src]) if kind == "paged" else pool
+                for kind, pool in zip(kinds, pools)]
 
     return _step_fn, _copy_blocks
 
@@ -250,7 +258,7 @@ class ServeEngine:
                  max_seq_len: Optional[int] = None,
                  max_prefill_tokens: int = 512,
                  tile_q: int = 8,
-                 enable_prefix_cache: bool = True,
+                 enable_prefix_cache: Optional[bool] = None,
                  spec_k: int = 0,
                  drafter=None,
                  registry: Optional[MetricsRegistry] = None,
@@ -268,13 +276,33 @@ class ServeEngine:
         # engine so its A/B cells don't pollute each other.
         self.obs = registry if registry is not None else default_registry()
         self.tracer = tracer if tracer is not None else RequestTracer()
-        attn = model.blocks[0].attn
-        # what one cached row is, read from the model: kv_heads x
-        # [k | v], or one latent entry a token ("The pool's row",
-        # kernels/paged_attention.py)
-        latent = getattr(attn, "latent_row", None)
-        if latent is not None:
-            refuse_latent(int(tp_size), int(kv_compress_blocks))
+        # what each layer keeps between steps, read from the model: a
+        # layout a layer (`CacheLayout`, ENGINE.md "Cache kinds"), or
+        # nothing said and a paged pool in every layer
+        layout = getattr(model, "cache_layout", None)
+        if layout is not None:
+            layout = CacheLayout(layout, block_size, max_batch_size,
+                                 min(max_prefill_tokens,
+                                     max_seq_len or model.max_len))
+            if layout.has_slots:
+                refuse_slots(enable_prefix_cache,
+                             max(spec_k, drafter.k if drafter else 0),
+                             host_tier_bytes, kv_compress_blocks, tp_size,
+                             demote_finished)
+                enable_prefix_cache = False
+            kv_heads, head_dim = model.kv_row
+            latent = None
+        else:
+            attn = model.blocks[0].attn
+            kv_heads, head_dim = attn.num_kv_heads, attn.head_dim
+            # what one cached row is, read from the model: kv_heads x
+            # [k | v], or one latent entry a token ("The pool's row",
+            # kernels/paged_attention.py)
+            latent = getattr(attn, "latent_row", None)
+            if latent is not None:
+                refuse_latent(int(tp_size), int(kv_compress_blocks))
+        if enable_prefix_cache is None:     # on wherever the cache can
+            enable_prefix_cache = True
         # tensor-parallel serving (ENGINE.md "Tensor-parallel serving"):
         # tp_size > 1 builds a tp mesh over the first tp_size devices,
         # shards the weights (parallel.sharding.serve_tp_rules) and KV
@@ -407,13 +435,13 @@ class ServeEngine:
         # PR-19 behavior; N > 1 = warm-up threshold).
         self.cache = PagedKVCache(
             num_layers=len(model.blocks), num_blocks=num_blocks,
-            block_size=block_size, num_kv_heads=attn.num_kv_heads,
-            head_dim=attn.head_dim, dtype=model.dtype,
+            block_size=block_size, num_kv_heads=kv_heads,
+            head_dim=head_dim, dtype=model.dtype,
             enable_prefix_cache=enable_prefix_cache, registry=self.obs,
             host_tier=self.host_tier,
             compress_blocks=kv_compress_blocks,
             promote_hits=kv_promote_hits, tp_size=self.tp_size,
-            mesh=self._mesh, latent=latent)
+            mesh=self._mesh, latent=latent, layout=layout)
         if self.host_tier is not None:
             # prime the eager kernels tier traffic dispatches — the
             # demote gather (pool[block] device_get) and the revival
@@ -442,7 +470,7 @@ class ServeEngine:
         # keys a grid cell of the ragged kernel covers (kernels/
         # paged_attention.py `ragged_span`, on a chip's own pool rows):
         # what `attn_cells` counts cells by
-        pool = self.cache.pools[0]
+        pool = self.cache.pools[self.cache.kinds.index("paged")]
         self._cell_keys = block_size * ragged_span(
             block_size, pool.shape[2] // self.tp_size, pool.dtype.itemsize,
             self.max_blocks_per_seq)
@@ -479,7 +507,7 @@ class ServeEngine:
 
         self._step_fn, self._copy_blocks = compile_steps(
             model, self.variables, self.cache.compress_enabled,
-            self._serve_tp)
+            self._serve_tp, None if layout is None else self.cache.kinds)
 
     # -- construction from an exported artifact ---------------------------
     @classmethod
@@ -503,6 +531,11 @@ class ServeEngine:
         if meta.get("model_type") == "latent_moe_lm":
             from paddle_tpu.models.latent_moe import LatentMoELM
             model = LatentMoELM(
+                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
+                param_dtype=jnp.dtype(meta["param_dtype"]))
+        elif meta.get("model_type") == "hybrid_lm":
+            from paddle_tpu.models.hybrid_lm import HybridLM
+            model = HybridLM(
                 **meta["config"], dtype=jnp.dtype(meta["dtype"]),
                 param_dtype=jnp.dtype(meta["param_dtype"]))
         else:
@@ -560,6 +593,26 @@ class ServeEngine:
             "Grid cells with work the ragged kernel runs a layer: "
             "summed over the steps' query tiles (pad tiles too), the "
             "spans of pool blocks the tile reaches")
+        self._m_ssm_tokens = m.counter(
+            "ptpu_ssm_tokens_scanned_total",
+            "Real tokens through the selective scan, a state-space layer")
+        self._m_kv_rows = m.counter(
+            "ptpu_attn_kv_rows_total",
+            "Cached rows a layer's attention has to read, by the kind of "
+            "pool: the whole context (full) or clipped to the window",
+            labelnames=("kind",))        # kind=full|window
+        self._m_keys_kind = m.counter(
+            "ptpu_attn_keys_total",
+            "Keys attended by the steps' real query tokens, a layer, by "
+            "the kind of pool", labelnames=("kind",))
+        self._m_ring_released = m.counter(
+            "ptpu_kv_window_blocks_released_total",
+            "Blocks that fell wholly behind the window and were given "
+            "back while their sequence lived, a window layer")
+        self._m_state_slots = m.gauge(
+            "ptpu_state_slots_in_use",
+            "State slots (recurrent state and window ring) held by "
+            "running sequences")
         self._m_moe_assign = m.counter(
             "ptpu_moe_assignments_total",
             "Real (row, choice) pairs routed to an expert, summed over "
@@ -680,6 +733,12 @@ class ServeEngine:
             raise ValueError(
                 f"n {n} not in [1, max_batch_size={self.max_batch_size}]: "
                 "every candidate needs a batch slot to decode")
+        if n > 1 and self.cache.layout is not None \
+                and self.cache.layout.has_slots:
+            raise ValueError(
+                f"n={n} over recurrent state or a window ring: the "
+                "candidates would fork one prefill, and a slot's state and "
+                "ring have no copy to hand a sibling (serve it with n=1)")
         if len(prompt) + 1 > self.max_seq_len:
             raise ValueError(f"prompt len {len(prompt)} leaves no room to "
                              f"generate under max_seq_len {self.max_seq_len}")
@@ -795,6 +854,15 @@ class ServeEngine:
         if "moe_assignments" in asked:
             self._m_moe_assign.inc(asked["moe_assignments"])
             self._m_moe_active.inc(asked["moe_active_experts"])
+        if "ssm_tokens" in asked:
+            self._m_ssm_tokens.inc(asked["ssm_tokens"])
+            for kind in ("full", "window"):
+                self._m_kv_rows.labels(kind=kind).inc(
+                    asked["kv_rows_" + kind])
+                self._m_keys_kind.labels(kind=kind).inc(
+                    asked["attn_keys_" + kind])
+            self._m_ring_released.inc(asked["window_blocks_released"])
+            self._m_state_slots.set(self.cache.slots_in_use)
         if chunks:
             # per-event field: a request's prefix-hit tokens are
             # attributed to the step its FIRST chunk runs
@@ -1086,6 +1154,10 @@ class ServeEngine:
             tile_offs = np.zeros((nt,), np.int32)
             last_idx = np.zeros((b, self.spec_len), np.int32)
             cursor = kv_read = attn_keys = cells = 0
+            slotted = (self.cache.layout is not None
+                       and self.cache.layout.has_slots)
+            win = self.cache.layout.window if slotted else 0
+            ssm_tokens = win_rows = win_keys = released = 0
             for i, row in enumerate(rows):
                 r = row.req
                 toks = r.tokens
@@ -1106,6 +1178,20 @@ class ServeEngine:
                 kv_read += row.start + row.length
                 attn_keys += (row.length * row.start
                               + row.length * (row.length + 1) // 2)
+                if slotted:
+                    ssm_tokens += row.length
+                    end = row.start + row.length
+                    if win:
+                        # the rows read reach back win - 1 from the
+                        # first query; a query at p sees min(p + 1, win)
+                        # keys: p + 1 below the window's width, win past
+                        win_rows += end - max(0, row.start - (win - 1))
+                        ramp = min(end, win) - min(row.start, win)
+                        win_keys += (
+                            ramp * (min(row.start, win) + min(end, win) + 1)
+                            // 2 + (row.length - ramp) * win)
+                    released += self.cache.release_behind_window(
+                        r.req_id, end)
                 if row.decode:
                     # verification gathers per-position logits (plain
                     # decode rows have length 1: every column clamps to
@@ -1125,6 +1211,11 @@ class ServeEngine:
                     cells += -(-reach // self._cell_keys)
                 cursor += ntiles * tq
             cells += nt - cursor // tq   # a pad tile: the null row's one
+            if slotted:
+                # the rows table rides with the pools: this step's rows'
+                # slots and rings
+                self.cache.pools[-1] = jnp.asarray(self.cache.bind_rows(
+                    [row.req.req_id for row in rows]))
         with annotate("engine.dispatch", step=step):
             (logits, lse), self.cache.pools, *per_expert = self._donating(
                 self._step_fn,
@@ -1137,6 +1228,12 @@ class ServeEngine:
             span.set(bytes=logits.nbytes + lse.nbytes)
             asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys,
                      "attn_cells": cells}
+            if slotted:
+                asked.update(
+                    ssm_tokens=ssm_tokens, state_slots=len(rows),
+                    kv_rows_full=kv_read, attn_keys_full=attn_keys,
+                    kv_rows_window=win_rows, attn_keys_window=win_keys,
+                    window_blocks_released=released)
             if per_expert:
                 per_expert = np.asarray(per_expert[0])
                 self.expert_tokens += per_expert

@@ -114,13 +114,133 @@ def refuse_latent(tp_size: int, compress_blocks: int) -> None:
             "(serve it with kv_compress_blocks=0)")
 
 
+def refuse_slots(prefix_cache, spec_k: int, host_tier_bytes: int,
+                 compress_blocks: int, tp_size: int,
+                 demote_finished: bool) -> None:
+    """What a cache with per-sequence SLOTS (recurrent state, window
+    rings: `CacheLayout`) cannot do yet, said at construction."""
+    def no(what: str, why: str, how: str):
+        raise ValueError(f"{what} over recurrent state or a window ring: "
+                         f"{why} (serve it with {how})")
+    if prefix_cache:
+        no("enable_prefix_cache=True",
+           "a prefix hit skips the cached tokens, and the scan's state and "
+           "the ring behind them exist only as the slot's newest value, not "
+           "as a snapshot at the block boundary",
+           "enable_prefix_cache=False, or leave it unset")
+    if spec_k > 0:
+        no(f"spec_k={spec_k}",
+           "a rejected draft rolls the paged rows back by not advancing, "
+           "and a state that has walked the draft cannot be walked back",
+           "spec_k=0")
+    if host_tier_bytes > 0:
+        no(f"host_tier_bytes={host_tier_bytes}",
+           "the host tier demotes and revives whole paged blocks by their "
+           "prefix, and a revived prefix has no state to resume from",
+           "host_tier_bytes=0")
+    if compress_blocks > 0:
+        no(f"kv_compress_blocks={compress_blocks}",
+           "the int8 tier stands in for prefix blocks, which this cache "
+           "never shares", "kv_compress_blocks=0")
+    if tp_size > 1:
+        no(f"tp_size={tp_size}",
+           "a slot's state and ring are one chip's arrays: nothing divides "
+           "the scan's channels or the ring's heads over a mesh yet",
+           "tp_size=1")
+    if demote_finished:
+        no("demote_finished=True (the prefill phase of kvxfer)",
+           "a decode replica that pulls the paged blocks of a prefix would "
+           "still lack the state and the ring behind it",
+           "the mixed phase")
+
+
+class CacheLayout:
+    """What each layer of a model keeps between steps, as the model
+    declares it (`model.cache_layout`, one dict a layer), sized for one
+    engine. Five kinds:
+
+    - {"kind": "paged"}: a block pool under the block tables and the
+      free list (the only kind a model without a layout has);
+    - {"kind": "window", "window": W}: a pool of RINGS, one a slot, of
+      `ring_blocks` blocks each: logical block b of a sequence lives in
+      ring place b mod ring_blocks, so a sequence holds at most
+      `ring_blocks` blocks whatever its context, and a block that falls
+      wholly behind the window is given back by being written over;
+    - {"kind": "state", "arrays": ((name, shape, dtype), ...)}: arrays
+      of one entry a slot (the scan's state, the convolution's tail);
+    - {"kind": "reads", "layer": k}: the layer reads layer k's paged
+      pool and owns nothing;
+    - {"kind": "none"}.
+
+    A SLOT is what one running sequence holds of the window and state
+    kinds: slot 0 is the null slot of padding, slots 1..`slots` are
+    handed out at admission and taken back when the sequence is freed
+    (finished, cancelled or preempted: the scheduler recomputes).
+    Nothing zeroes a slot: a sequence's first step starts at position
+    0, and the step starts such a row from zeros whatever the slot
+    holds (kernels/selective_scan.py `tile_meta`).
+    """
+
+    def __init__(self, layers, block_size: int, slots: int,
+                 chunk_tokens: int):
+        self.layers = [dict(layer) for layer in layers]
+        self.slots = int(slots)
+        for i, layer in enumerate(self.layers):
+            if layer["kind"] not in ("paged", "window", "state", "reads",
+                                     "none"):
+                raise ValueError(f"layer {i}: unknown cache kind "
+                                 f"{layer['kind']!r}")
+            if layer["kind"] == "reads" and \
+                    self.layers[layer["layer"]]["kind"] != "paged":
+                raise ValueError(f"layer {i} reads layer {layer['layer']}, "
+                                 "which keeps no paged pool")
+        windows = [layer["window"] for layer in self.layers
+                   if layer["kind"] == "window"]
+        # a step's chunk of C tokens starting at p reads back to
+        # p - (W - 1) and writes up to p + C - 1: that many blocks must
+        # be live at once, and one more for the block a boundary splits
+        self.window = max(windows, default=0)
+        self.ring_blocks = (
+            -(-(self.window - 1 + chunk_tokens) // block_size) + 1
+            if windows else 0)
+        self.has_slots = bool(windows) or any(
+            layer["kind"] == "state" for layer in self.layers)
+
+    def arrays(self, pool_shape, dtype):
+        """[(kind, shape, dtype)] of the arrays the step is handed, in
+        layer order, then the rows table."""
+        out = []
+        _, bs, lanes = pool_shape
+        for layer in self.layers:
+            if layer["kind"] == "paged":
+                out.append(("paged", pool_shape, dtype))
+            elif layer["kind"] == "window":
+                out.append(("window", (1 + self.slots * self.ring_blocks,
+                                       bs, lanes), dtype))
+            elif layer["kind"] == "state":
+                out += [("state", (self.slots + 1,) + tuple(shape), dt)
+                        for _, shape, dt in layer["arrays"]]
+        out.append(("rows", (self.slots + 1, 1 + self.ring_blocks),
+                    jnp.int32))
+        return out
+
+    def ring(self, slot: int) -> List[int]:
+        """The pool blocks of `slot`'s ring (block 0 is scratch)."""
+        first = 1 + (slot - 1) * self.ring_blocks
+        return list(range(first, first + self.ring_blocks))
+
+
 class PagedKVCache:
     """Refcounted block-pool KV cache shared by all layers of one model.
 
-    All layers allocate in lockstep (a token occupies the same slot in
-    every layer's pool), so ONE free list / block table set serves the
-    whole stack; `pools` holds one array per layer in the layout
-    `pool_shape` gives (module docstring, "Pool layout").
+    All paged layers allocate in lockstep (a token occupies the same
+    slot in every layer's pool), so ONE free list / block table set
+    serves the whole stack; `pools` holds one array per layer in the
+    layout `pool_shape` gives (module docstring, "Pool layout"). With a
+    `layout` (`CacheLayout`) `pools` is the list its `arrays` describe:
+    paged pools, window pools, state arrays, and last the ROWS table the
+    step reads its rows' slots and rings from (`bind_rows`); `kinds`
+    names each entry.
     """
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
@@ -131,10 +251,17 @@ class PagedKVCache:
                  compress_blocks: int = 0,
                  promote_hits: int = 0,
                  tp_size: int = 1, mesh=None,
-                 latent: Optional[Tuple[int, int]] = None):
+                 latent: Optional[Tuple[int, int]] = None,
+                 layout: Optional[CacheLayout] = None):
         """`latent=(k_dim, v_dim)` selects the latent row (module
         docstring); `num_kv_heads` and `head_dim` then count for
-        nothing."""
+        nothing. `layout` gives each layer its kind (`CacheLayout`);
+        without one every layer keeps a paged pool."""
+        if layout is not None and layout.has_slots:
+            refuse_slots(enable_prefix_cache, 0,
+                         host_tier.byte_budget if host_tier else 0,
+                         compress_blocks, tp_size, False)
+        self.layout = layout
         if latent is not None:
             refuse_latent(tp_size, compress_blocks)
             num_kv_heads, head_dim = 1, latent[0]
@@ -170,6 +297,21 @@ class PagedKVCache:
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._sharding = NamedSharding(mesh, P(None, None, "tp"))
         self.pools: List[jnp.ndarray] = self.fresh_pools()
+        # what each entry of `pools` is: "paged", "window", "state" or
+        # "rows". Whole-block movers (the COW replay, the tiers) touch
+        # the paged ones only
+        self.kinds: List[str] = (
+            ["paged"] * num_layers if layout is None else
+            [kind for kind, _, _ in
+             layout.arrays(self.pool_shape(1), self.dtype)])
+        # slots of the window and state kinds: free list, owner map,
+        # and how many ring blocks each sequence has given back
+        self._free_slots = deque(range(1, layout.slots + 1)
+                                 if layout is not None and layout.has_slots
+                                 else ())
+        self._slot: Dict[int, int] = {}
+        self._ring_released: Dict[int, int] = {}
+        self.window_blocks_released = 0
         # optional in-device compressed tier: a parallel int8 block pool
         # (+ per-block k/v scales) cold prefix content quantizes into at
         # ~half the bytes. Slot 0 is scratch (the fixed-lane flushes pad
@@ -309,8 +451,55 @@ class PagedKVCache:
         """Zeroed pools of this cache's shape and placement: what the
         constructor holds, and what the engine rebuilds after a step
         that failed with the pools already donated."""
+        if self.layout is not None:
+            return [jnp.zeros(shape, dtype) for _, shape, dtype in
+                    self.layout.arrays(self.pool_shape(1), self.dtype)]
         return [self._place(jnp.zeros(self.pool_shape(1), self.dtype))
                 for _ in range(self._num_layers)]
+
+    # -- slots (window rings, recurrent state) ----------------------------
+    @property
+    def slots_in_use(self) -> int:
+        return len(self._slot)
+
+    def slot(self, seq_id: int) -> int:
+        return self._slot[seq_id]
+
+    def bind_rows(self, seq_ids: Sequence[int]):
+        """The ROWS table of a step whose row i is sequence
+        `seq_ids[i]`: int32 [slots + 1, 1 + ring_blocks], a row's slot
+        then its ring's pool blocks; the rows past the step's, the null
+        row among them, keep slot 0 and the scratch block. The engine
+        puts it in `pools`' last place before the step."""
+        lay = self.layout
+        table = np.zeros((lay.slots + 1, 1 + lay.ring_blocks), np.int32)
+        for i, seq_id in enumerate(seq_ids):
+            slot = self._slot[seq_id]
+            table[i, 0] = slot
+            table[i, 1:] = lay.ring(slot)
+        return table
+
+    def ring_blocks_held(self, seq_id: int, next_pos: int) -> int:
+        """Blocks of its ring a sequence holds live once its next query
+        stands at `next_pos`: those not wholly behind the window."""
+        lay, bs = self.layout, self.block_size
+        behind = max(0, next_pos - (lay.window - 1)) // bs
+        return -(-next_pos // bs) - behind
+
+    def release_behind_window(self, seq_id: int, next_pos: int) -> int:
+        """A step has moved `seq_id`'s next query to `next_pos`: the
+        logical blocks now wholly behind every window are given back
+        (their ring places are the ones the sequence writes next).
+        Returns how many this call released."""
+        lay = self.layout
+        if lay is None or not lay.ring_blocks:
+            return 0
+        behind = max(0, next_pos - (lay.window - 1)) // self.block_size
+        newly = behind - self._ring_released.get(seq_id, 0)
+        if newly > 0:
+            self._ring_released[seq_id] = behind
+            self.window_blocks_released += newly
+        return max(newly, 0)
 
     def reset_pools(self) -> None:
         """The fp pools' content is lost (a step failed after it had
@@ -592,6 +781,9 @@ class PagedKVCache:
         """Admission check. `tokens` may be a token list (prefix-aware:
         matched blocks cost nothing beyond their own revival) or a bare
         count (conservative)."""
+        if self.layout is not None and self.layout.has_slots \
+                and not self._free_slots:
+            return False        # every slot holds a running sequence
         if isinstance(tokens, int):
             return self.blocks_for(tokens) <= len(self._free)
         matched = self._match_prefix(tokens)
@@ -616,6 +808,9 @@ class PagedKVCache:
         and would otherwise inflate hit_rate."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already allocated")
+        if self.layout is not None and self.layout.has_slots \
+                and not self._free_slots:
+            raise CacheExhausted("no free state slot")
         n = len(tokens)
         bs = self.block_size
         matched = self._match_prefix(tokens)
@@ -713,6 +908,8 @@ class PagedKVCache:
             self._refs[b] = 1
             self._last_hit[b] = self.step_now
         self._tables[seq_id] = matched + mid_blocks + host_blocks + fresh
+        if self._free_slots:
+            self._slot[seq_id] = self._free_slots.popleft()
         self._lens[seq_id] = n
         self._tokens[seq_id] = list(tokens)
         cached = min((len(matched) + len(chits) + len(host_blocks))
@@ -889,6 +1086,12 @@ class PagedKVCache:
         blocks just drop one reference."""
         if dst_id in self._tables:
             raise ValueError(f"sequence {dst_id} already allocated")
+        if self.layout is not None and self.layout.has_slots:
+            raise ValueError(
+                "a fork over recurrent state or a window ring: the paged "
+                "blocks share by refcount and copy on write, and a slot's "
+                "state and ring have no copy to hand the sibling (serve it "
+                "with n=1)")
         table = self._tables[src_id]
         for b in table:
             if b < 0:       # shared direct-read slot: bump its pin too
@@ -921,6 +1124,10 @@ class PagedKVCache:
         later would clobber the new owner's KV. Returns how many blocks
         went back to the free list (shared ones live on)."""
         blocks = self._tables.pop(seq_id, [])
+        slot = self._slot.pop(seq_id, None)
+        if slot is not None:    # state and ring go with the sequence
+            self._free_slots.append(slot)
+            self._ring_released.pop(seq_id, None)
         self._lens.pop(seq_id, None)
         self._tokens.pop(seq_id, None)
         self._committed.pop(seq_id, None)
@@ -1132,6 +1339,8 @@ class PagedKVCache:
         design); an indexed block NOT on the free list is a leak."""
         if self._tables:
             raise RuntimeError(f"live sequences: {list(self._tables)}")
+        if self._slot:
+            raise RuntimeError(f"leaked state slots: {self._slot}")
         if self._refs:
             raise RuntimeError(f"leaked refcounts: {self._refs}")
         if self._pending_host_loads:

@@ -285,7 +285,8 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
                                      groups: int = 1,
                                      kvq_pool=None,
                                      k_scales=None, v_scales=None,
-                                     value_lanes=None):
+                                     value_lanes=None,
+                                     window: Optional[int] = None):
     """XLA oracle for the ragged layout: expand tile metadata to
     per-token rows and run the dense gather + masked attention.
     q: [T, H, D] flat-packed; kv_pool: [NB, BS, Hkv * W] (the section
@@ -302,7 +303,10 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
 
     `value_lanes=(0, V)`: a latent pool [NB, BS, lanes] — one row a
     token for all H heads, keys its lanes [0, D), values its lanes
-    [0, V); returns [T, H, V]."""
+    [0, V); returns [T, H, V].
+
+    `window`: a query sees the `window` newest positions up to its own
+    (its own counts), whatever the table holds behind them."""
     t, h, d = q.shape
     nb, bs, _ = kv_pool.shape
     hkv = h // groups
@@ -335,7 +339,10 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
     kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
     ctx = context_lens[row_of]
     mask = ((kv_pos[None, :] <= qpos[:, None])
-            & (kv_pos[None, :] < ctx[:, None]))[:, None, None, :]
+            & (kv_pos[None, :] < ctx[:, None]))
+    if window is not None:
+        mask = mask & (kv_pos[None, :] > qpos[:, None] - window)
+    mask = mask[:, None, None, :]
     return reference_attention(q[:, None].astype(k.dtype), k, v, mask=mask,
                                scale=scale)[:, 0].astype(q.dtype)
 
@@ -387,7 +394,7 @@ def _block_heads(rows, d: int):
 
 
 def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
-                        scale: float, groups: int):
+                        scale: float, groups: int, window=None):
     """Online-softmax update for one (query-tile, span of kv blocks)
     cell — shared by the fp-only and mixed-precision ragged kernels. q:
     [TQ, H, W], zero beyond lane D; kv: [Hkv, K, W], the span's K keys
@@ -417,7 +424,14 @@ def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
         jnp.int32, (tq, h, keys), 0).reshape(tq * h, keys)
     kpos = k0 + jax.lax.broadcasted_iota(
         jnp.int32, (tq, h, keys), 2).reshape(tq * h, keys)
-    s = jnp.where((kpos <= qpos) & (kpos < ctx), s, NEG_INF)
+    seen = (kpos <= qpos) & (kpos < ctx)
+    if window is not None:
+        # a query whose keys all lie in later spans takes weights of 1
+        # on this one; its next span's max-shift multiplies them by an
+        # exact 0 (every query reaches its own position or the context's
+        # last, `window` >= the tile's width)
+        seen = seen & (kpos > qpos - window)
+    s = jnp.where(seen, s, NEG_INF)
 
     m_prev = m_scr[...][:, :1]                      # [TQ*H, 1]
     l_prev = l_scr[...][:, :1]
@@ -478,7 +492,7 @@ def ragged_span(block_size: int, lanes: int, itemsize: int,
 def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
                  load_span, o_ref, m_scr, l_scr, acc_scr, bufs, cnt, *,
                  scale: float, span: int, tile_q: int, groups: int,
-                 v_off=None):
+                 v_off=None, window=None):
     """One (query-tile, span) grid cell, shared by both ragged kernels.
     The pools stay in HBM; a cell with work waits for its span's blocks
     in one of two VMEM buffers and, before it computes, starts the
@@ -492,33 +506,49 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
     into buffer `slot`); `load_span(row, j, slot)` gives the span's
     keys as [Hkv, K, W] ([1, K, W] over a latent pool). Online-softmax
     scratch is flattened to (TQ*H, ·) rows and persists across the
-    sequential kv axis."""
+    sequential kv axis.
+
+    With a `window` (a query sees its `window` newest positions, its
+    own among them) a tile's work starts at the span that holds the
+    oldest key its FIRST query sees: the spans wholly behind it are
+    cells without work, and within the first span the blocks wholly
+    behind it are not copied."""
     t, j = pl.program_id(0), pl.program_id(1)
     bs = bufs[0].shape[2]
     span_keys = span * bs
 
     def tile(ti):
-        """(table row, context, first query position, keys reached) of
-        query tile ti. It attends positions below its row's context,
-        cut at the causal edge of its LAST query (q0 + tile_q - 1):
-        blocks at and past that reach have no work for it (the
-        engine's `attn_cells` counts cells by the same rule)."""
+        """(table row, context, first query position, keys reached,
+        oldest key seen) of query tile ti. It attends positions below
+        its row's context, cut at the causal edge of its LAST query
+        (q0 + tile_q - 1): blocks at and past that reach have no work
+        for it (the engine's `attn_cells` counts cells by the same
+        rule)."""
         row = tr_ref[ti]
         ctx = cl_ref[row]
         q0 = qs_ref[row] + to_ref[ti]
-        return row, ctx, q0, jnp.minimum(ctx, q0 + tile_q)
+        oldest = 0 if window is None else jnp.maximum(q0 - (window - 1), 0)
+        return row, ctx, q0, jnp.minimum(ctx, q0 + tile_q), oldest
+
+    def first_span(ti):
+        return 0 if window is None else tile(ti)[4] // span_keys
 
     def move(go, ti, sj, slot):
         """Start (go) or await the copies of tile ti's span sj: its
-        blocks within the tile's reach, no others."""
-        row, _, _, reach = tile(ti)
+        blocks within the tile's reach and not wholly behind its
+        window, no others."""
+        row, _, _, reach, oldest = tile(ti)
         blocks = -(-reach // bs) - sj * span
         for i, serves, copy in span_copies(row, sj, slot):
-            @pl.when((i < blocks) & serves)
+            wanted = (i < blocks) & serves
+            if window is not None:
+                wanted = wanted & (i >= oldest // bs - sj * span)
+
+            @pl.when(wanted)
             def _():
                 copy.start() if go else copy.wait()
 
-    row, ctx, q0, reach = tile(t)
+    row, ctx, q0, reach, oldest = tile(t)
 
     @pl.when((t == 0) & (j == 0))
     def _first():
@@ -528,7 +558,7 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
         cnt[0] = 0
-        move(True, 0, 0, 0)
+        move(True, 0, first_span(0), 0)
 
     @pl.when(j == 0)
     def _init():
@@ -539,7 +569,11 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
     # a span has work if its FIRST key is within the tile's reach; the
     # update's mask cuts the span the context or the causal edge ends
     # in, block boundary or not
-    @pl.when(j * span_keys < reach)
+    work = j * span_keys < reach
+    if window is not None:
+        work = work & ((j + 1) * span_keys > oldest)
+
+    @pl.when(work)
     def _compute():
         slot = cnt[0] % 2
         more = (j + 1) * span_keys < reach
@@ -550,12 +584,12 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
 
         @pl.when(jnp.logical_not(more) & (t + 1 < pl.num_programs(0)))
         def _next_tile():
-            move(True, t + 1, 0, 1 - slot)
+            move(True, t + 1, first_span(t + 1), 1 - slot)
 
         move(False, t, j, slot)
         _ragged_tile_update(q_ref[...], load_span(row, j, slot), q0, ctx,
                             j * span_keys, m_scr, l_scr, acc_scr,
-                            scale=scale, groups=groups)
+                            scale=scale, groups=groups, window=window)
         cnt[0] += 1
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -646,12 +680,13 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
 # trace and lower the kernel once between them (set-up time: a span's
 # unrolled copies make the body long to trace)
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "groups",
-                                             "span", "value_lanes"))
+                                             "span", "value_lanes",
+                                             "window", "name"))
 def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
                         q_starts, tile_rows, tile_offs, scale,
                         interpret: bool, groups: int, span: int,
                         kvq_pool=None, k_scales=None, v_scales=None,
-                        value_lanes=None):
+                        value_lanes=None, window=None, name=None):
     t, h, d = q.shape
     nb, bs, lanes = kv_pool.shape
     mb = block_tables.shape[1]
@@ -661,6 +696,10 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     tq = t // nt
     if h % groups:
         raise ValueError(f"q heads {h} not a multiple of groups {groups}")
+    if window is not None and window < tq:
+        raise ValueError(
+            f"window {window} narrower than the query tile {tq}: a pad "
+            "query behind its context would see no key")
     latent = value_lanes is not None
     if latent:
         v_off, out_d = _latent_value(value_lanes, h, groups, kvq_pool)
@@ -714,7 +753,9 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
         + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)],
     )
     kernel = functools.partial(kernel_fn, scale=scale, span=span,
-                               tile_q=tq, groups=groups)
+                               tile_q=tq, groups=groups,
+                               **({} if window is None else
+                                  {"window": window}))
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -724,7 +765,7 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="ragged_latent_attention" if latent else None,
+        name=name or ("ragged_latent_attention" if latent else None),
     )
     scalars = (block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
                q_starts.astype(jnp.int32), tile_rows.astype(jnp.int32),
@@ -743,7 +784,8 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
                            interpret: Optional[bool] = None,
                            groups: int = 1,
                            kvq_pool=None, k_scales=None, v_scales=None,
-                           value_lanes=None):
+                           value_lanes=None, window: Optional[int] = None,
+                           name: Optional[str] = None):
     """Mixed prefill+decode attention over the flat ragged packing —
     the engine's single-step entry point. q: [T, H, D]; kv_pool: one
     layer's pool as the cache lays it out, [NB, BS, Hkv * W]; `groups`
@@ -762,16 +804,30 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
 
     `value_lanes=(0, V)` reads a LATENT pool [NB, BS, lanes] (the
     section comment above): q [T, H, D] is each head's absorbed query,
-    `groups` = H, and the result is [T, H, V], the attended latent."""
+    `groups` = H, and the result is [T, H, V], the attended latent.
+
+    `window=W` is sliding-window attention: a query sees positions
+    (p - W, p]. The table is still indexed by logical block, so the
+    cache may give the blocks behind the window back while the sequence
+    lives: the kernel neither copies them nor lets them in, whatever
+    their entries hold. A differential-attention layer arrives here as
+    head_dim = 2 x its key width: a pair of key heads side by side is
+    one key "head", the pair's two value heads its value, and each
+    query head of the pair zero on the other's lanes. `name` is the
+    name the Pallas call carries into a device trace."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    if window is not None and (value_lanes is not None
+                               or kvq_pool is not None):
+        raise ValueError("a window is read over a K/V pool with no int8 "
+                         "tier beside it")
     use_kernel, interpret = _resolve_dispatch(use_kernel, interpret)
     if not use_kernel:
         return ragged_paged_attention_reference(
             q, kv_pool, block_tables, context_lens, q_starts,
             tile_rows, tile_offs, scale=scale, groups=groups,
             kvq_pool=kvq_pool, k_scales=k_scales, v_scales=v_scales,
-            value_lanes=value_lanes)
+            value_lanes=value_lanes, window=window)
     _, bs, lanes = kv_pool.shape
     span = ragged_span(bs, lanes, kv_pool.dtype.itemsize,
                        block_tables.shape[1])
@@ -780,7 +836,8 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
                                scale, interpret, groups, span,
                                kvq_pool=kvq_pool,
                                k_scales=k_scales, v_scales=v_scales,
-                               value_lanes=value_lanes)
+                               value_lanes=value_lanes, window=window,
+                               name=name)
 
 
 # -- tensor-parallel wrappers (engine tp_size knob, ENGINE.md) ------------

@@ -175,7 +175,7 @@ def build_frontend(a: argparse.Namespace):
             a.model_dir, max_batch_size=a.max_batch_size,
             block_size=a.block_size, num_blocks=a.num_blocks,
             max_prefill_tokens=a.max_prefill_tokens, tile_q=a.tile_q,
-            enable_prefix_cache=not a.no_prefix_cache,
+            enable_prefix_cache=False if a.no_prefix_cache else None,
             spec_k=a.spec_k, registry=registry,
             host_tier_bytes=a.host_tier_bytes,
             kv_tier_int8=a.kv_tier_int8,
@@ -197,7 +197,7 @@ def build_frontend(a: argparse.Namespace):
             model, variables, max_batch_size=a.max_batch_size,
             block_size=a.block_size, num_blocks=a.num_blocks,
             max_prefill_tokens=a.max_prefill_tokens, tile_q=a.tile_q,
-            enable_prefix_cache=not a.no_prefix_cache,
+            enable_prefix_cache=False if a.no_prefix_cache else None,
             spec_k=a.spec_k, registry=registry,
             host_tier_bytes=a.host_tier_bytes,
             kv_tier_int8=a.kv_tier_int8,

@@ -355,3 +355,127 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0.25 * 16e9 < total < 15e9, total
+
+
+def test_scan_kernel_compiles_for_v5e(one_chip):
+    """The ragged selective scan at `phi4mf-reason`'s step: 512 flat
+    positions in 64 tiles, 5,120 channels of 16 states, 33 slots, the
+    state donated and aliased to the kernel's output."""
+    from paddle_tpu.kernels.selective_scan import ragged_selective_scan
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    t, dn, n, nt, slots = 512, 5120, 16, 64, 33
+    args = [s((t, dn), jnp.bfloat16), s((t, dn), jnp.float32),
+            s((n, dn), jnp.float32), s((t, n), jnp.float32),
+            s((t, n), jnp.float32), s((dn,), jnp.float32),
+            s((slots, n, dn), jnp.float32), s((nt,), jnp.int32),
+            s((nt,), jnp.int32), s((nt,), jnp.int32)]
+
+    def fn(*a):
+        return ragged_selective_scan(*a, use_kernel=True, interpret=False)
+    compiled = jax.jit(fn, donate_argnums=(6,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged_selective_scan" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        slots * n * dn * 4
+
+
+@pytest.mark.parametrize("window,blocks", [(512, 225), (None, 1024)],
+                         ids=["window_ring", "whole_context"])
+def test_pair_row_ragged_kernel_compiles_for_v5e(one_chip, window, blocks):
+    """The ragged kernel as a differential-attention layer calls it: 40
+    query heads over 10 key pairs of 128 + 128 (rows of 2,560 lanes,
+    groups 4, one block of 128 rows a cell), with the 512 window over a
+    ring pool and without over the paged one."""
+    from paddle_tpu.kernels.paged_attention import (ragged_paged_attention,
+                                                    ragged_span)
+    t, tq, bs, mb, rows = 512, 8, 128, 27, 33
+    assert ragged_span(bs, 2560, 2, mb) == 1
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = [s((t, 40, 128), jnp.bfloat16),
+            _pool_rows(blocks, bs, 10, 128, jnp.bfloat16, one_chip),
+            s((rows, mb), jnp.int32), s((rows,), jnp.int32),
+            s((rows,), jnp.int32), s((t // tq,), jnp.int32),
+            s((t // tq,), jnp.int32)]
+
+    def fn(q, kv, bt, cl, qs, tr, to):
+        return ragged_paged_attention(
+            q, kv, bt, cl, qs, tr, to, use_kernel=True, interpret=False,
+            groups=4, scale=0.125, window=window,
+            name="ragged_diff_attention")
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "ragged_diff_attention" in text
+
+
+def test_hybrid_step_at_its_cell_sizes(one_chip):
+    """The engine's step over the three kinds of cache, compiled for the
+    described chip at `phi4mf-reason`'s own sizes (all 32 layers, the
+    whole vocabulary, 1,024 paged blocks, 32 rings of 7 blocks in each
+    of eight window pools, 33 slots of state; shapes only): both
+    kernels are accepted, every pool and state array is aliased to the
+    step's output, no copy of a pool's or a state's size is in the
+    program, the three scopes are in its text, and everything the step
+    holds fits the chip (about 9.8 GB of 16)."""
+    import json
+    from unittest import mock
+
+    from paddle_tpu.engine.engine import compile_steps
+    from paddle_tpu.engine.paged_cache import CacheLayout
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.hybrid_lm import HybridLM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "phi-4-mini-flash.json")) as f:
+        cfg = json.load(f)
+    model = HybridLM(
+        dtype=jnp.bfloat16,
+        **{k: cfg[v] for k, v in cfg["constructor_args"].items()})
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    s = cfg["serve"]
+    tq, b = s["tile_q"], s["max_batch_size"]
+    t = -(-s["max_prefill_tokens"] // tq) * tq + b * tq
+    mb = -(-s["max_seq_len"] // s["block_size"])
+    layout = CacheLayout(model.cache_layout, s["block_size"], b,
+                         s["max_prefill_tokens"])
+    assert layout.ring_blocks == 7
+    heads, head_dim = model.kv_row
+    arrays = layout.arrays(
+        (s["num_blocks"], s["block_size"],
+         heads * paged_attention.head_lanes(head_dim)), jnp.bfloat16)
+    kinds = [kind for kind, _, _ in arrays]
+    assert (kinds.count("paged"), kinds.count("window"),
+            kinds.count("state")) == (1, 8, 18)
+    pools = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for _, shape, dtype in arrays]
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    step, _ = compile_steps(model, shapes, False, None, kinds)
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = step.lower(
+            jax.tree.map(on_chip, shapes), i32(t), i32(t), pools, [], [],
+            i32(b + 1, mb), i32(b + 1), i32(b + 1), i32(t // tq),
+            i32(t // tq), i32(t), i32(b, 1)).compile()
+    text = compiled.as_text()
+    for name in ("tpu_custom_call", "ragged_selective_scan",
+                 "ragged_diff_attention", "ssm_scan", "gated_memory",
+                 "diff_attention"):
+        assert name in text, name
+    for size in sorted({p.size for p in pools[:-1]}):
+        assert _pool_sized_copies(text, jax.ShapeDtypeStruct(
+            (size,), jnp.int8)) == [], size
+    mem = compiled.memory_analysis()
+    held = sum(p.size * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= held > 1.9e9
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0.25 * 16e9 < total < 15e9, total

@@ -117,3 +117,22 @@ def test_latent_phase_runs_at_toy_widths_on_cpu():
     assert line["logit_err_share_of_max"] < 1e-4
     assert line["tokens_equal_to_reference"] == "8/8"
     assert line["expert_pairs"] == (50 + 9 + 2 * 3) * 2
+
+
+def test_hybrid_phase_runs_at_toy_widths_on_cpu():
+    cfg = chip_smoke._hybrid_config()
+    cfg = {**cfg, **cfg["toy"]}
+    line = chip_smoke.hybrid_phase(
+        cfg, layer_kinds=chip_smoke.HYBRID["layer_kinds"], num_blocks=67,
+        max_batch_size=2, prompt_lens=(60, 20), new_tokens=4, seed=0)
+    assert line["failed"] == [
+        "no Pallas kernel in the engine's step program"], line
+    assert line["engine_compiles"] == 1
+    assert line["max_chunk_tokens"] == 32        # the 60 in two chunks
+    assert line["step_pool_or_state_sized_copies"] == 0
+    # 60 + 3 positions computed at window 8, block 4: the next query at
+    # 63 has 14 blocks behind its window, the 20-token prompt's at 23 has 4
+    assert line["window_blocks_released"] == (63 - 7) // 4 + (23 - 7) // 4
+    # float32 on one backend: the served tokens are the reference's
+    assert line["logit_err_share_of_max"] < 1e-4
+    assert line["tokens_equal_to_reference"] == "8/8"

@@ -55,3 +55,37 @@ def test_nothing_below_the_engine_imports_it(package):
 
 def test_kernels_import_no_model():
     assert _reaching("paddle_tpu/kernels", "paddle_tpu.models") == []
+
+
+MODEL_NAMES = ("hybrid_lm", "HybridLM", "latent_moe", "LatentMoELM", "phi4",
+               "phi-4", "glm")
+
+
+@pytest.mark.parametrize("path", [
+    "paddle_tpu/kernels/paged_attention.py",
+    "paddle_tpu/kernels/selective_scan.py",
+    "paddle_tpu/engine/paged_cache.py",
+    "paddle_tpu/engine/scheduler.py"])
+def test_cache_manager_and_kernels_name_no_model(path):
+    """What a model keeps between steps reaches the manager as a layout
+    the model declares, and the kernels as shapes: neither names a
+    model or its module."""
+    with open(os.path.join(ROOT, path)) as f:
+        text = f.read()
+    assert [name for name in MODEL_NAMES if name in text] == []
+
+
+def test_the_engine_names_models_only_to_rebuild_an_export():
+    """`engine.py` reads the cache layout, the row and the expert
+    layers off the model it is handed; the one place it names a model's
+    class is `from_saved_model`, which rebuilds one from a manifest."""
+    with open(os.path.join(ROOT, "paddle_tpu/engine/engine.py")) as f:
+        tree = ast.parse(f.read())
+    named = set()
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            for inner in ast.walk(node):
+                if isinstance(inner, ast.ImportFrom) and inner.module and \
+                        inner.module.startswith("paddle_tpu.models"):
+                    named.add(node.name)
+    assert named <= {"ServeEngine", "from_saved_model"}

@@ -368,3 +368,54 @@ def test_expert_counts_agree_with_the_counters():
     assert sum(st["args"]["kv_tokens_read"] for st in steps) == \
         eng.obs.get("ptpu_attn_kv_tokens_read_total").value
     assert eng.expert_tokens.sum() == computed * 2 * 2
+
+
+def test_slot_counts_agree_with_the_counters():
+    """A model whose layers keep three kinds of state: `ssm_tokens` is
+    the real tokens through the scan a state-space layer, `state_slots`
+    the rows' slots, `kv_rows_*` and `attn_keys_*` the cached rows read
+    and the keys attended a layer of each kind of pool (the window's
+    clipped to it), `window_blocks_released` the ring blocks given back;
+    each span field sums to the counter of its name."""
+    from paddle_tpu.models.hybrid_lm import HybridLM
+    model = HybridLM(
+        vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2, ffn_dim=32,
+        layer_kinds=["mamba", "window", "mamba", "full", "gmu", "cross"],
+        window=8, d_inner=32, d_state=4, d_conv=4, dt_rank=2, max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=8)
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    steps = _spans(prof.get_events(), "engine.step")
+
+    def total(field):
+        return sum(st["args"][field] for st in steps)
+    computed = sum(len(p) + 5 for p in PROMPTS)
+    assert total("ssm_tokens") == computed == eng.obs.get(
+        "ptpu_ssm_tokens_scanned_total").value
+    assert total("state_slots") == sum(
+        st["args"]["decode_rows"] + st["args"]["chunk_rows"] for st in steps)
+    for kind in ("full", "window"):
+        assert total("kv_rows_" + kind) == eng.obs.get(
+            "ptpu_attn_kv_rows_total").labels(kind=kind).value
+        assert total("attn_keys_" + kind) == eng.obs.get(
+            "ptpu_attn_keys_total").labels(kind=kind).value
+    # every query token at position p: p + 1 keys, min(p + 1, 8) in the
+    # window
+    assert total("attn_keys_full") == total("attn_keys") == sum(
+        p + 1 for prompt in PROMPTS for p in range(len(prompt) + 5))
+    assert total("attn_keys_window") == sum(
+        min(p + 1, 8) for prompt in PROMPTS for p in range(len(prompt) + 5))
+    assert total("kv_rows_full") == total("kv_tokens_read")
+    assert 0 < total("kv_rows_window") < total("kv_rows_full")
+    assert total("window_blocks_released") == eng.obs.get(
+        "ptpu_kv_window_blocks_released_total").value \
+        == eng.cache.window_blocks_released > 0
+    # the gauge reads the slots held as a step ends
+    assert eng.obs.get("ptpu_state_slots_in_use").value == 0
+    eng.add_request(PROMPTS[0], max_new_tokens=4)
+    eng.step()
+    assert eng.obs.get("ptpu_state_slots_in_use").value == 1
+    eng.run()
+    assert eng._step_fn._cache_size() == 1
