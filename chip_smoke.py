@@ -349,7 +349,8 @@ def serve_phase(widths: dict, engine: dict, prompt_lens, shared_prefix: int,
         seen.append(np.array(logits, np.float32))
         return sample(logits, req, pos)
 
-    with mock.patch.object(engine_mod, "_sample", spy):
+    with mock.patch.multiple(engine_mod, _sample=spy,
+                             _needs_logits=lambda req: True):
         eng.generate([logit_prompt], max_new_tokens=1)
     ragged = seen[-1]
     one_dev = jax.device_put(eng.variables, dev)
@@ -489,7 +490,8 @@ def _spied_generate(eng, prompts, new_tokens: int):
         seen.append(np.array(logits, np.float32))
         return sample(logits, req, pos)
 
-    with mock.patch.object(engine_mod, "_sample", spy):
+    with mock.patch.multiple(engine_mod, _sample=spy,
+                             _needs_logits=lambda req: True):
         generated = [eng.generate([prompt], max_new_tokens=new_tokens)[0]
                      for prompt in prompts]
     return generated, seen

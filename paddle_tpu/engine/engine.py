@@ -3,8 +3,9 @@
 Ties the subsystem together (ENGINE.md): a refcounted `PagedKVCache`
 holds KV state in block pools (prefix-shared, copy-on-write), a
 `Scheduler` plans one MIXED batch per step (decode rows + prefill
-chunks), and this engine compiles + executes the steps, samples tokens
-host-side, streams them to per-request callbacks, and emits structured
+chunks), and this engine compiles + executes the steps (which pick
+their own greedy tokens), samples the rows at a temperature host-side,
+streams tokens to per-request callbacks, and emits structured
 `serve_event` JSON (utils/log.py) for observability.
 
 Shape discipline — the one-compilation rule: continuous batching
@@ -36,9 +37,12 @@ tokens at the same positions by the same compiled chunk step, and
 masked attention lanes underflow to exact zero, so reusing it is
 bit-identical to recomputing it (tests/test_prefix_cache.py).
 
-Sampling runs on host from the [B, spec_len, V] logits (greedy /
-temperature / top-k); the step hands their log-sum-exp over with them,
-so a greedy token is an argmax and a subtraction. Stochastic sampling derives its rng stream from
+A greedy row is picked by the step itself: beside the [B, spec_len, V]
+logits it returns each row's best id, that id's logit and the row's
+log-sum-exp, and only those three numbers a row come to the host; the
+logits stay on the chip. A step in which a row samples at a temperature
+(`_needs_logits`) downloads the logits too, and that row is sampled on
+the host (temperature / top-k). Stochastic sampling derives its rng stream from
 (request seed, absolute position), never from batch composition, so
 scheduling decisions can't change a request's output.
 
@@ -170,18 +174,22 @@ def compile_steps(model, variables, compress: bool, serve_tp=None,
     def _step_fn(variables, tokens, positions, pools, qpools, qscales,
                  block_tables, context_lens, q_starts, tile_rows,
                  tile_offs, slots, last_idx):
-        # ((logits, their log-sum-exp), pools); a model with expert
-        # layers adds the step's tokens per expert, int32 [expert
-        # layers, experts]. The log-sum-exp is taken here, where the
-        # rows lie, so that scoring a greedy token on the host is one
-        # subtraction and no pass over the vocabulary
+        # ((logits, their log-sum-exp, the greedy pick, its logit),
+        # pools); a model with expert layers adds the step's tokens per
+        # expert, int32 [expert layers, experts]. The three numbers a
+        # row are taken here, where the rows lie, so that a greedy
+        # token costs the host one subtraction and the logits need not
+        # leave the device (`_pick`): the first best id, as np.argmax,
+        # and the maximum in the logits' own dtype (widened exactly)
         logits, pools, *rest = model.ragged_step_paged(
             _fresh_cx(variables), tokens, positions, pools,
             block_tables, context_lens, q_starts, tile_rows,
             tile_offs, slots, last_idx, tp=serve_tp,
             qpools=qpools, qscales=qscales)
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        return (logits, lse), pools, *rest
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        top = jnp.max(logits, axis=-1).astype(jnp.float32)
+        return (logits, lse, ids, top), pools, *rest
 
     @functools.partial(jax.jit, donate_argnums=(0,), **copy_sh)
     def _copy_blocks(pools, src, dst):
@@ -222,12 +230,24 @@ def _sample(logits: np.ndarray, req: Request, pos: int
     return tok, float(np.log(p[tok]))
 
 
-def _pick(logits: np.ndarray, lse, req: Request, pos: int
-          ) -> "tuple[int, float]":
-    """`_sample` for one row, a greedy token scored against the row's
-    log-sum-exp as the step took it on the device (float32; a pass
-    over the vocabulary on the host was most of a decode step's host
-    time at 50k logits a row and more)."""
+def _needs_logits(req: Request) -> bool:
+    """Whether a row of `req` that samples in a step needs its logits
+    on the host. A greedy row does not: the step picked its token. A
+    fork's siblings sample as their primary does, so a final chunk with
+    `n_candidates > 1` is covered by its own temperature."""
+    return req.temperature > 0.0
+
+
+def _pick(logits: Optional[np.ndarray], best, top, lse, req: Request,
+          pos: int) -> "tuple[int, float]":
+    """One row's (token, log-probability). `logits` is None where the
+    row's logits stayed on the device: the token is the step's own
+    pick `best`, scored as its logit `top` less the row's log-sum-exp
+    `lse`, both as the step took them (the subtraction the host made on
+    the downloaded row before, on the same values). With the row's
+    logits it is `_sample`'s, a greedy token scored the same way."""
+    if logits is None:
+        return int(best), float(top) - float(lse)
     tok, lp = _sample(logits, req, pos)
     if lp is None:
         lp = float(logits[tok]) - float(lse)
@@ -580,6 +600,11 @@ class ServeEngine:
             labelnames=("kind",))        # kind=prefill|cached|generated
         self._m_steps = m.counter(
             "ptpu_engine_steps_total", "Compiled mixed steps executed")
+        self._m_logit_downloads = m.counter(
+            "ptpu_engine_logit_downloads_total",
+            "Steps that downloaded their logits: a row that samples at "
+            "a temperature was among those that sampled. A greedy "
+            "step moves each row's pick, its logit and its log-sum-exp")
         self._m_kv_read = m.counter(
             "ptpu_attn_kv_tokens_read_total",
             "Context lengths summed over the steps' real rows: the cached "
@@ -1217,15 +1242,22 @@ class ServeEngine:
                 self.cache.pools[-1] = jnp.asarray(self.cache.bind_rows(
                     [row.req.req_id for row in rows]))
         with annotate("engine.dispatch", step=step):
-            (logits, lse), self.cache.pools, *per_expert = self._donating(
+            (logits, *picks), self.cache.pools, *per_expert = self._donating(
                 self._step_fn,
                 self.variables, tokens, positions,
                 self.cache.pools, self.cache.qpools, self.cache.qscales,
                 block_tables, context_lens, q_starts, tile_rows,
                 tile_offs, slots, last_idx)
         with annotate("engine.fetch", step=step) as span:
-            logits, lse = np.asarray(logits), np.asarray(lse)
-            span.set(bytes=logits.nbytes + lse.nbytes)
+            # three numbers a row come down; the logits stay on the
+            # device unless a row samples from its own on the host
+            wants = [row.samples and _needs_logits(row.req) for row in rows]
+            fetched = jax.device_get(
+                (*picks, per_expert, logits if any(wants) else None))
+            lse, ids, top, per_expert, logits = fetched
+            span.set(bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)))
+            if logits is not None:
+                self._m_logit_downloads.inc()
             asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys,
                      "attn_cells": cells}
             if slotted:
@@ -1235,13 +1267,13 @@ class ServeEngine:
                     kv_rows_window=win_rows, attn_keys_window=win_keys,
                     window_blocks_released=released)
             if per_expert:
-                per_expert = np.asarray(per_expert[0])
+                per_expert = per_expert[0]
                 self.expert_tokens += per_expert
                 asked.update(
                     moe_assignments=int(per_expert.sum()),
                     moe_active_experts=int((per_expert > 0).sum()))
         with annotate("engine.sample", step=step) as span:
-            # the logits reached the host: every first token and finish
+            # the picks reached the host: every first token and finish
             # of this step is stamped with the span's opening reading
             ts_us = span.ts
             generated = self._m_tokens.labels(kind="generated")
@@ -1249,6 +1281,8 @@ class ServeEngine:
             drafted = accepted = 0
             for i, row in enumerate(rows):
                 r = row.req
+                # the row's own logits where it samples from them
+                mine = logits[i] if wants[i] else None
                 if row.decode:
                     # the step wrote r.generated[-1]'s k/v at the reserved
                     # slot
@@ -1258,8 +1292,10 @@ class ServeEngine:
                         # logits[i, j] scored window position start+j, i.e.
                         # it predicts the token at cache seq_len (which the
                         # advances below keep in lockstep with j)
-                        tok, lp = _pick(logits[i, j], lse[i, j], r,
-                                        self.cache.seq_len(r.req_id))
+                        tok, lp = _pick(
+                            None if mine is None else mine[j],
+                            ids[i, j], top[i, j], lse[i, j], r,
+                            self.cache.seq_len(r.req_id))
                         r.logprob_sum += lp
                         self._emit_token(r, tok, ts_us)
                         if r.finish_reason or j >= len(row.draft):
@@ -1288,26 +1324,27 @@ class ServeEngine:
                     self.cache.commit_prefill(r.req_id, row.start + row.length)
                     self.tracer.on_chunk(r.req_id, row.start, row.length,
                                          ts_us, step)
-                    if row.start + row.length == len(r.prompt):  # final chunk
+                    if row.samples:     # the prompt's final chunk
+                        picked = (None if mine is None else mine[0],
+                                  ids[i, 0], top[i, 0], lse[i, 0])
                         if r.n_candidates > 1 and not r.forks:
                             # fork BEFORE the primary consumes the logits:
                             # each sibling samples its first token from the
                             # same final-chunk row under its own seed
-                            self._fork_candidates(r, logits[i, 0], lse[i, 0],
-                                                  ts_us)
-                        tok, lp = _pick(logits[i, 0], lse[i, 0], r,
-                                        len(r.prompt))
+                            self._fork_candidates(r, picked, ts_us)
+                        tok, lp = _pick(*picked, r, len(r.prompt))
                         r.logprob_sum += lp
                         if not r.first_token_time:
                             r.first_token_time = ts_us / 1e6
                         self.tracer.on_first_token(r.req_id, ts_us, step)
                         self._emit_token(r, tok, ts_us)
             span.set(emitted=int(generated.value - emitted),
-                     finished=len(self.finished) - finished)
+                     finished=len(self.finished) - finished,
+                     host_rows=sum(wants))
         return chunks, decodes, computed, drafted, accepted, asked
 
-    def _fork_candidates(self, primary: Request, logits_row: np.ndarray,
-                         lse, ts_us: float) -> None:
+    def _fork_candidates(self, primary: Request, picked: tuple,
+                         ts_us: float) -> None:
         """Split a finished prefill into n parallel-sampling candidates.
         Each sibling's cache sequence shares EVERY prompt block with the
         primary — fork_sequence only bumps refcounts; COW peels a
@@ -1315,7 +1352,8 @@ class ServeEngine:
         block — so the prompt is prefilled once and held once no matter
         how large n is. Siblings enter the running set decode-ready
         (prefill_pos == len(prompt)) and sample their FIRST token from
-        the same final-chunk logits row under seed + i: because
+        the same final-chunk row (`picked`: what `_pick` takes of it;
+        a greedy fork's is the step's own pick) under seed + i: because
         _sample is deterministic in (seed, position) and the ragged
         step's rows are batch-invariant, candidate i's whole stream is
         bit-identical to a solo run submitted with that seed."""
@@ -1345,7 +1383,7 @@ class ServeEngine:
                                    prompt=len(sib.prompt))
             self.tracer.on_admit(sib.req_id, ts_us, self.steps,
                                  len(sib.prompt))
-            tok, lp = _pick(logits_row, lse, sib, len(sib.prompt))
+            tok, lp = _pick(*picked, sib, len(sib.prompt))
             sib.logprob_sum += lp
             sib.first_token_time = ts_us / 1e6
             self.tracer.on_first_token(sib.req_id, ts_us, self.steps)

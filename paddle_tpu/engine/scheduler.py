@@ -131,6 +131,13 @@ class StepRow:
     decode: bool = False
     draft: List[int] = field(default_factory=list)
 
+    @property
+    def samples(self) -> bool:
+        """Whether the step yields a token for this row: a decode row
+        does, and the chunk that ends its prompt."""
+        return (self.decode
+                or self.start + self.length == len(self.req.prompt))
+
 
 # back-compat alias: a prefill chunk is a StepRow with decode=False
 PrefillChunk = StepRow

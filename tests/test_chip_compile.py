@@ -112,6 +112,20 @@ def _pool_sized_copies(text: str, pool) -> list:
     return chip_smoke.pool_sized_copies(text, pool.size)
 
 
+def _holds_the_picks(compiled, batch, vocab, dtype, sharding=None):
+    """The step's first output is (logits, log-sum-exp, best id, its
+    logit) at [batch, spec_len 1] rows: the logits in the head's own
+    dtype, the three numbers a row in 4 bytes each (under tensor
+    parallelism every one of them whole on every chip)."""
+    picks, *_ = compiled.out_info
+    assert [(x.shape, x.dtype) for x in picks] == [
+        ((batch, 1, vocab), dtype), ((batch, 1), jnp.float32),
+        ((batch, 1), jnp.int32), ((batch, 1), jnp.float32)]
+    if sharding is not None:
+        assert all(x.sharding.is_equivalent_to(sharding, x.ndim)
+                   for x in picks)
+
+
 @pytest.mark.parametrize("heads,kv_heads,head_dim,blocks,batch,compress", [
     (16, 16, 64, 3072, 32, 0),    # gpt2m-chat's pool
     (20, 20, 64, 1280, 16, 0),    # gpt2l-docs's pool
@@ -174,6 +188,7 @@ def test_engine_step_updates_the_pool_in_place(one_chip, heads, kv_heads,
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert _pool_sized_copies(text, pools[0]) == []
+    _holds_the_picks(compiled, batch, 512, jnp.bfloat16)
     pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
 
@@ -219,6 +234,8 @@ def test_tp4_step_updates_its_pool_shards_in_place(topo, heads, blocks,
             i32(batch, 1)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    _holds_the_picks(compiled, batch, 512, jnp.bfloat16,
+                     NamedSharding(mesh, P()))
     shard = jax.ShapeDtypeStruct((blocks, 16, pools[0].shape[2] // 4),
                                  jnp.bfloat16)
     assert _pool_sized_copies(text, shard) == []
@@ -349,6 +366,8 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
     text = compiled.as_text()
     assert "ragged_latent_attention" in text and "tpu_custom_call" in text
     assert _pool_sized_copies(text, pool) == []
+    # an untied float32 head: 9.9 MB of logits that stay put
+    _holds_the_picks(compiled, b, cfg["vocab_size"], jnp.float32)
     mem = compiled.memory_analysis()
     pool_bytes = len(pools) * pool.size * pool.dtype.itemsize
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -473,6 +492,8 @@ def test_hybrid_step_at_its_cell_sizes(one_chip):
     for size in sorted({p.size for p in pools[:-1]}):
         assert _pool_sized_copies(text, jax.ShapeDtypeStruct(
             (size,), jnp.int8)) == [], size
+    # the tied head's logits are float32 here: 25.6 MB that stay put
+    _holds_the_picks(compiled, b, cfg["vocab_size"], jnp.float32)
     mem = compiled.memory_analysis()
     held = sum(p.size * p.dtype.itemsize for p in pools)
     assert mem.alias_size_in_bytes >= held > 1.9e9

@@ -631,15 +631,17 @@ def test_pick_scores_a_greedy_token_against_the_steps_log_sum_exp(
     lse = np.asarray(jax.nn.logsumexp(jnp.asarray(row)))
     req = Request(prompt=[1], max_new_tokens=1, temperature=temperature,
                   seed=11)
-    tok, lp = _pick(row, lse, req, 4)
+    best, top = int(row.argmax()), row.max()
+    tok, lp = _pick(row, best, top, lse, req, 4)
     z = row.astype(np.float64) / (temperature or 1.0)
     want = z - z.max() - np.log(np.exp(z - z.max()).sum())
     assert lp == pytest.approx(want[tok], abs=2e-6)
     if temperature:
         assert _sample(row, req, 4) == (tok, lp)
     else:
-        assert (tok, _sample(row, req, 4)) == (int(row.argmax()),
-                                               (tok, None))
+        assert (tok, _sample(row, req, 4)) == (best, (tok, None))
+        # without the row, the step's own pick: the same pair, bit for bit
+        assert _pick(None, best, top, lse, req, 4) == (tok, lp)
 
 
 def test_failed_donated_step_leaves_a_serving_engine(model_and_vars):

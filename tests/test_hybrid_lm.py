@@ -154,7 +154,8 @@ def _reference_rows(cfg, prompt, generated):
 
 def _served_against_reference(cfg, eng, prompts, new_tokens):
     spy = Spy()
-    with mock.patch.object(engine_mod, "_sample", spy):
+    with mock.patch.multiple(engine_mod, _sample=spy,
+                             _needs_logits=lambda req: True):
         reqs = [eng.add_request(p, max_new_tokens=new_tokens)
                 for p in prompts]
         eng.run()
@@ -210,7 +211,8 @@ def test_preemption_drops_the_state_and_recomputes_it(toy):
     prompt = _tokens(cfg, np.random.default_rng(5), 19)[0]
     eng = _engine(model, variables)
     spy = Spy()
-    with mock.patch.object(engine_mod, "_sample", spy):
+    with mock.patch.multiple(engine_mod, _sample=spy,
+                             _needs_logits=lambda req: True):
         req = eng.add_request(prompt, max_new_tokens=14)
         while req.num_generated < 6:
             eng.step()

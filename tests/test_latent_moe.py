@@ -86,6 +86,8 @@ def sampled(monkeypatch):
         rows.append(np.array(logits))
         return plain(logits, req, pos)
     monkeypatch.setattr(engine_mod, "_sample", spy)
+    # a greedy row's logits stay on the device: ask for them
+    monkeypatch.setattr(engine_mod, "_needs_logits", lambda req: True)
     return rows
 
 
