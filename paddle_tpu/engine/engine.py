@@ -197,10 +197,47 @@ def compile_steps(model, variables, compress: bool, serve_tp=None,
         # layer; padding lanes are (0, 0) — scratch onto itself
         if kinds is None:
             return [pool.at[dst].set(pool[src]) for pool in pools]
-        return [pool.at[dst].set(pool[src]) if kind == "paged" else pool
+        # an index pool's rows go with their block
+        return [pool.at[dst].set(pool[src])
+                if kind in ("paged", "index") else pool
                 for kind, pool in zip(kinds, pools)]
 
     return _step_fn, _copy_blocks
+
+
+def compile_snapshot_moves(places, kinds, ring_blocks: int):
+    """The cache's two state-snapshot copies, `(take, restore)`, each
+    `_COPY_LANES` (slot, snapshot place) pairs wide (padding lanes move
+    the null slot onto the scratch place, and back): `take(pools, snaps,
+    slots, places)` copies the slots' state arrays and window rings into
+    the snapshot pool, donated; `restore(pools, snaps, places, slots)`
+    copies them back into the pools, donated. `places[i]` is where
+    `snaps[i]`'s array lies in `pools`."""
+    def ring(ids):     # a slot's (a place's) ring blocks; 0: scratch
+        first = 1 + (ids - 1) * ring_blocks
+        return jnp.where(
+            ids[:, None] > 0,
+            first[:, None] + jnp.arange(ring_blocks, dtype=jnp.int32), 0
+        ).reshape(-1)
+
+    def move(dst, src, dst_ids, src_ids, kind):
+        if kind == "window":
+            dst_ids, src_ids = ring(dst_ids), ring(src_ids)
+        return dst.at[dst_ids].set(src[src_ids])
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def take(pools, snaps, slots, ids):
+        return [move(snap, pools[at], ids, slots, kinds[at])
+                for at, snap in zip(places, snaps)]
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def restore(pools, snaps, ids, slots):
+        pools = list(pools)
+        for at, snap in zip(places, snaps):
+            pools[at] = move(pools[at], snap, slots, ids, kinds[at])
+        return pools
+
+    return take, restore
 
 
 def _sample(logits: np.ndarray, req: Request, pos: int
@@ -289,7 +326,9 @@ class ServeEngine:
                  kv_compress_blocks: int = 0,
                  kv_promote_hits: int = 0,
                  tp_size: int = 1,
-                 demote_finished: bool = False):
+                 demote_finished: bool = False,
+                 snapshot_tokens: Optional[int] = None,
+                 snapshot_slots: Optional[int] = None):
         self.model = model
         # telemetry (OBSERVABILITY.md): None -> the process registry /
         # a fresh tracer. serve_bench passes a private registry per
@@ -301,15 +340,25 @@ class ServeEngine:
         # nothing said and a paged pool in every layer
         layout = getattr(model, "cache_layout", None)
         if layout is not None:
+            # prefix reuse over slots goes by state snapshots: how far
+            # apart and how many is the engine's to say, else the
+            # model's (`snapshot_tokens`, `snapshot_slots`), else the
+            # layout's default where the prefix cache is asked for
+            if snapshot_tokens is None:
+                snapshot_tokens = getattr(model, "snapshot_tokens", 0)
+            if snapshot_slots is None:
+                snapshot_slots = getattr(model, "snapshot_slots", 0)
             layout = CacheLayout(layout, block_size, max_batch_size,
                                  min(max_prefill_tokens,
-                                     max_seq_len or model.max_len))
+                                     max_seq_len or model.max_len),
+                                 snapshot_tokens or None,
+                                 snapshot_slots or None)
             if layout.has_slots:
-                refuse_slots(enable_prefix_cache,
-                             max(spec_k, drafter.k if drafter else 0),
+                refuse_slots(max(spec_k, drafter.k if drafter else 0),
                              host_tier_bytes, kv_compress_blocks, tp_size,
                              demote_finished)
-                enable_prefix_cache = False
+                if enable_prefix_cache is None:
+                    enable_prefix_cache = bool(snapshot_tokens)
             kv_heads, head_dim = model.kv_row
             latent = None
         else:
@@ -528,6 +577,17 @@ class ServeEngine:
         self._step_fn, self._copy_blocks = compile_steps(
             model, self.variables, self.cache.compress_enabled,
             self._serve_tp, None if layout is None else self.cache.kinds)
+        # what the model counts of its own sparse attention a row
+        self._sparse_counts = getattr(model, "sparse_counts", None)
+        self._snapshots_seen = (0, 0, 0)
+        if self.cache.snapshot_every:
+            self._snapshot_take, self._snapshot_restore = \
+                compile_snapshot_moves(self.cache.snap_places,
+                                       self.cache.kinds, layout.ring_blocks)
+            # both compiled before the first request: the null slot
+            # onto the scratch place, and back
+            self._move_snapshots([(0, 0)], take=True)
+            self._move_snapshots([(0, 0)], take=False)
 
     # -- construction from an exported artifact ---------------------------
     @classmethod
@@ -551,6 +611,11 @@ class ServeEngine:
         if meta.get("model_type") == "latent_moe_lm":
             from paddle_tpu.models.latent_moe import LatentMoELM
             model = LatentMoELM(
+                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
+                param_dtype=jnp.dtype(meta["param_dtype"]))
+        elif meta.get("model_type") == "sparse_linear_lm":
+            from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
+            model = SparseLinearLM(
                 **meta["config"], dtype=jnp.dtype(meta["dtype"]),
                 param_dtype=jnp.dtype(meta["param_dtype"]))
         elif meta.get("model_type") == "hybrid_lm":
@@ -630,6 +695,17 @@ class ServeEngine:
             "ptpu_attn_keys_total",
             "Keys attended by the steps' real query tokens, a layer, by "
             "the kind of pool", labelnames=("kind",))
+        self._m_index_rows = m.counter(
+            "ptpu_attn_index_rows_total",
+            "Compressed-key rows of the index pool a sparse layer's "
+            "selection scores, a kv head")
+        self._m_blocks_selected = m.counter(
+            "ptpu_attn_blocks_selected_total",
+            "Blocks kept by the selection, summed over the steps' real "
+            "query tokens past the dense length, a sparse layer and kv head")
+        self._m_la_tokens = m.counter(
+            "ptpu_la_tokens_total",
+            "Real tokens through lightning attention, a layer")
         self._m_ring_released = m.counter(
             "ptpu_kv_window_blocks_released_total",
             "Blocks that fell wholly behind the window and were given "
@@ -887,6 +963,14 @@ class ServeEngine:
                 self._m_keys_kind.labels(kind=kind).inc(
                     asked["attn_keys_" + kind])
             self._m_ring_released.inc(asked["window_blocks_released"])
+        if "sparse_keys" in asked:
+            self._m_kv_rows.labels(kind="sparse").inc(
+                asked["sparse_rows_read"])
+            self._m_keys_kind.labels(kind="sparse").inc(asked["sparse_keys"])
+            self._m_index_rows.inc(asked["index_rows_read"])
+            self._m_blocks_selected.inc(asked["blocks_selected"])
+            self._m_la_tokens.inc(asked["la_tokens"])
+        if "state_slots" in asked:
             self._m_state_slots.set(self.cache.slots_in_use)
         if chunks:
             # per-event field: a request's prefix-hit tokens are
@@ -955,6 +1039,25 @@ class ServeEngine:
                 self._copy_blocks, self.cache.pools, jnp.asarray(src),
                 jnp.asarray(dst))
         return len(copies)
+
+    def _move_snapshots(self, pairs, take: bool) -> int:
+        """Make the cache's staged state-snapshot copies, `_COPY_LANES`
+        a compiled call: (slot, place) pairs into the snapshot pool
+        (`take`), or (place, slot) pairs back into the pools."""
+        for i in range(0, len(pairs), _COPY_LANES):
+            a = np.zeros((_COPY_LANES,), np.int32)
+            b = np.zeros((_COPY_LANES,), np.int32)
+            for j, (x, y) in enumerate(pairs[i:i + _COPY_LANES]):
+                a[j], b[j] = x, y
+            if take:    # (slot, place)
+                self.cache.snaps = self._snapshot_take(
+                    self.cache.pools, self.cache.snaps, jnp.asarray(a),
+                    jnp.asarray(b))
+            else:       # (place, slot)
+                self.cache.pools = self._donating(
+                    self._snapshot_restore, self.cache.pools,
+                    self.cache.snaps, jnp.asarray(a), jnp.asarray(b))
+        return len(pairs)
 
     def _donating(self, compiled, *operands):
         """Call a compiled function that takes the pools donated. A
@@ -1159,7 +1262,9 @@ class ServeEngine:
             span.set(compress=self._flush_compress(),
                      promote=self._flush_promote(),
                      loads=self._flush_tier_loads(),
-                     cow=self._flush_cow())
+                     cow=self._flush_cow(),
+                     restores=self._move_snapshots(
+                         self.cache.drain_snapshot_restores(), take=False))
         with annotate("engine.pack", step=step):
             chunks = [w for w in rows if not w.decode]
             decodes = [w for w in rows if w.decode]
@@ -1183,14 +1288,22 @@ class ServeEngine:
                        and self.cache.layout.has_slots)
             win = self.cache.layout.window if slotted else 0
             ssm_tokens = win_rows = win_keys = released = 0
+            sparse = dict.fromkeys(
+                ("sparse_rows_read", "sparse_keys", "blocks_selected",
+                 "index_rows_read"), 0)
             for i, row in enumerate(rows):
                 r = row.req
-                toks = r.tokens
+                # the row's window of prompt + generated, without
+                # building that list (a long prompt a row a step)
+                split = len(r.prompt)
+                own = r.prompt[row.start:row.start + row.length] \
+                    + r.generated[max(row.start - split, 0):
+                                  max(row.start + row.length - split, 0)]
                 if row.draft:
                     # draft tokens live only in the plan, not in req.tokens
-                    window = [toks[row.start]] + row.draft
+                    window = own[:1] + row.draft
                 else:
-                    window = toks[row.start:row.start + row.length]
+                    window = own
                 tokens[cursor:cursor + row.length] = window
                 positions[cursor:cursor + row.length] = np.arange(
                     row.start, row.start + row.length, dtype=np.int32)
@@ -1203,6 +1316,10 @@ class ServeEngine:
                 kv_read += row.start + row.length
                 attn_keys += (row.length * row.start
                               + row.length * (row.length + 1) // 2)
+                if self._sparse_counts is not None:
+                    for name, n in self._sparse_counts(
+                            row.start, row.length).items():
+                        sparse[name] += n
                 if slotted:
                     ssm_tokens += row.length
                     end = row.start + row.length
@@ -1260,12 +1377,28 @@ class ServeEngine:
                 self._m_logit_downloads.inc()
             asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys,
                      "attn_cells": cells}
-            if slotted:
+            if self._sparse_counts is not None:
+                # what ONE sparse layer and kv head reads after
+                # selection; real tokens through a lightning layer
+                asked.update(sparse, la_tokens=ssm_tokens,
+                             state_slots=len(rows))
+            elif slotted:
                 asked.update(
                     ssm_tokens=ssm_tokens, state_slots=len(rows),
                     kv_rows_full=kv_read, attn_keys_full=attn_keys,
                     kv_rows_window=win_rows, attn_keys_window=win_keys,
                     window_blocks_released=released)
+            if self.cache.snapshot_every:
+                # restored at this plan's admissions; taken by the step
+                # before (its copies were made when it ended)
+                now = (self.cache.snapshots_taken,
+                       self.cache.snapshots_restored,
+                       self.cache.snapshot_tokens_skipped)
+                asked.update(zip(
+                    ("snapshots_taken", "snapshots_restored",
+                     "snapshot_tokens_skipped"),
+                    (a - b for a, b in zip(now, self._snapshots_seen))))
+                self._snapshots_seen = now
             if per_expert:
                 per_expert = per_expert[0]
                 self.expert_tokens += per_expert
@@ -1321,7 +1454,11 @@ class ServeEngine:
                         self._m_spec_ratio.observe(
                             row_accepted / len(row.draft))
                 else:
-                    self.cache.commit_prefill(r.req_id, row.start + row.length)
+                    end = row.start + row.length
+                    self.cache.commit_prefill(r.req_id, end)
+                    # a chunk that ended on a snapshot boundary of the
+                    # prompt: the slot's state is that prefix's
+                    self.cache.take_snapshot(r.req_id, end)
                     self.tracer.on_chunk(r.req_id, row.start, row.length,
                                          ts_us, step)
                     if row.samples:     # the prompt's final chunk
@@ -1341,6 +1478,8 @@ class ServeEngine:
             span.set(emitted=int(generated.value - emitted),
                      finished=len(self.finished) - finished,
                      host_rows=sum(wants))
+        # before anything else walks the slots
+        self._move_snapshots(self.cache.drain_snapshot_takes(), take=True)
         return chunks, decodes, computed, drafted, accepted, asked
 
     def _fork_candidates(self, primary: Request, picked: tuple,
@@ -1400,7 +1539,8 @@ class ServeEngine:
         if req.callback is not None:
             req.callback(tok)
         hit_eos = req.eos_id is not None and tok == req.eos_id
-        out_of_room = (len(req.tokens) >= self.max_seq_len - 1)
+        out_of_room = (len(req.prompt) + len(req.generated)
+                       >= self.max_seq_len - 1)
         if hit_eos or req.num_generated >= req.max_new_tokens or out_of_room:
             self._finish(req, "eos" if hit_eos else "length", ts_us)
 
@@ -1460,6 +1600,7 @@ class ServeEngine:
         survive) and the request tracer — the post-warmup baseline
         serve_bench measures from."""
         self.cache.reset_stats()
+        self._snapshots_seen = (0, 0, 0)
         self.prefill_tokens_computed = 0
         self.peak_occupancy = 0.0
         self.max_chunk_tokens = 0

@@ -114,20 +114,15 @@ def refuse_latent(tp_size: int, compress_blocks: int) -> None:
             "(serve it with kv_compress_blocks=0)")
 
 
-def refuse_slots(prefix_cache, spec_k: int, host_tier_bytes: int,
-                 compress_blocks: int, tp_size: int,
-                 demote_finished: bool) -> None:
+def refuse_slots(spec_k: int, host_tier_bytes: int, compress_blocks: int,
+                 tp_size: int, demote_finished: bool) -> None:
     """What a cache with per-sequence SLOTS (recurrent state, window
-    rings: `CacheLayout`) cannot do yet, said at construction."""
+    rings: `CacheLayout`) cannot do yet, said at construction. Prefix
+    reuse is no longer among them: a slot's arrays and rings are
+    snapshot at block boundaries (`CacheLayout`, "State snapshots")."""
     def no(what: str, why: str, how: str):
         raise ValueError(f"{what} over recurrent state or a window ring: "
                          f"{why} (serve it with {how})")
-    if prefix_cache:
-        no("enable_prefix_cache=True",
-           "a prefix hit skips the cached tokens, and the scan's state and "
-           "the ring behind them exist only as the slot's newest value, not "
-           "as a snapshot at the block boundary",
-           "enable_prefix_cache=False, or leave it unset")
     if spec_k > 0:
         no(f"spec_k={spec_k}",
            "a rejected draft rolls the paged rows back by not advancing, "
@@ -136,12 +131,14 @@ def refuse_slots(prefix_cache, spec_k: int, host_tier_bytes: int,
     if host_tier_bytes > 0:
         no(f"host_tier_bytes={host_tier_bytes}",
            "the host tier demotes and revives whole paged blocks by their "
-           "prefix, and a revived prefix has no state to resume from",
+           "prefix, and a state snapshot lives on the device only: a "
+           "revived prefix has no state to resume from",
            "host_tier_bytes=0")
     if compress_blocks > 0:
         no(f"kv_compress_blocks={compress_blocks}",
-           "the int8 tier stands in for prefix blocks, which this cache "
-           "never shares", "kv_compress_blocks=0")
+           "the int8 tier stands in for prefix blocks one at a time, and a "
+           "hit over state lands on a snapshot's boundary with every block "
+           "before it in the fp pool", "kv_compress_blocks=0")
     if tp_size > 1:
         no(f"tp_size={tp_size}",
            "a slot's state and ring are one chip's arrays: nothing divides "
@@ -160,7 +157,13 @@ class CacheLayout:
     engine. Five kinds:
 
     - {"kind": "paged"}: a block pool under the block tables and the
-      free list (the only kind a model without a layout has);
+      free list (the only kind a model without a layout has).
+      `"pools": n` gives the layer n such pools (a model whose kv heads
+      are read through tables of their own keeps a pool a head);
+      `"index": {"stride": s, "lanes": l}` adds an INDEX pool under the
+      same tables, [blocks, block_size / s, l]: one row a `s` tokens
+      (a sparse layer's compressed keys), shared and evicted with its
+      block;
     - {"kind": "window", "window": W}: a pool of RINGS, one a slot, of
       `ring_blocks` blocks each: logical block b of a sequence lives in
       ring place b mod ring_blocks, so a sequence holds at most
@@ -179,12 +182,31 @@ class CacheLayout:
     Nothing zeroes a slot: a sequence's first step starts at position
     0, and the step starts such a row from zeros whatever the slot
     holds (kernels/selective_scan.py `tile_meta`).
+
+    STATE SNAPSHOTS are how a prefix hit works over slots. A slot's
+    arrays and rings are copied, every `snapshot_tokens` positions of a
+    prompt (a whole number of blocks), into one of `snapshot_slots`
+    snapshot places (`snapshot_arrays`: the same arrays with the
+    snapshot places where the slots were; place 0 is scratch). The
+    manager keeps them by the prefix they end, least recently used
+    first out; a hit reaches the deepest boundary that still has one,
+    and admission copies it into the request's slot.
     """
 
     def __init__(self, layers, block_size: int, slots: int,
-                 chunk_tokens: int):
+                 chunk_tokens: int, snapshot_tokens: Optional[int] = None,
+                 snapshot_slots: Optional[int] = None):
         self.layers = [dict(layer) for layer in layers]
         self.slots = int(slots)
+        # where prefix reuse is on: 16 blocks apart and two places a
+        # slot unless the engine or the model says otherwise
+        self.snapshot_tokens = int(snapshot_tokens or 16 * block_size)
+        self.snapshot_slots = int(snapshot_slots or 2 * self.slots)
+        if self.snapshot_tokens % block_size:
+            raise ValueError(
+                f"snapshot_tokens={self.snapshot_tokens} is not a whole "
+                f"number of blocks of {block_size}: a hit ends on a block "
+                "boundary")
         for i, layer in enumerate(self.layers):
             if layer["kind"] not in ("paged", "window", "state", "reads",
                                      "none"):
@@ -194,6 +216,11 @@ class CacheLayout:
                     self.layers[layer["layer"]]["kind"] != "paged":
                 raise ValueError(f"layer {i} reads layer {layer['layer']}, "
                                  "which keeps no paged pool")
+            stride = (layer.get("index") or {}).get("stride")
+            if stride and block_size % stride:
+                raise ValueError(
+                    f"layer {i}: an index row a {stride} tokens does not "
+                    f"divide a block of {block_size}")
         windows = [layer["window"] for layer in self.layers
                    if layer["kind"] == "window"]
         # a step's chunk of C tokens starting at p reads back to
@@ -210,10 +237,14 @@ class CacheLayout:
         """[(kind, shape, dtype)] of the arrays the step is handed, in
         layer order, then the rows table."""
         out = []
-        _, bs, lanes = pool_shape
+        nb, bs, lanes = pool_shape
         for layer in self.layers:
             if layer["kind"] == "paged":
-                out.append(("paged", pool_shape, dtype))
+                out += [("paged", pool_shape, dtype)] * layer.get("pools", 1)
+                if layer.get("index"):
+                    out.append(("index",
+                                (nb, bs // layer["index"]["stride"],
+                                 layer["index"]["lanes"]), dtype))
             elif layer["kind"] == "window":
                 out.append(("window", (1 + self.slots * self.ring_blocks,
                                        bs, lanes), dtype))
@@ -222,6 +253,20 @@ class CacheLayout:
                         for _, shape, dt in layer["arrays"]]
         out.append(("rows", (self.slots + 1, 1 + self.ring_blocks),
                     jnp.int32))
+        return out
+
+    def snapshot_arrays(self, arrays) -> list:
+        """[(place in `arrays`, shape, dtype)] of the snapshot pool: one
+        array a window pool and a state array, with `snapshot_slots`
+        places where the slots were."""
+        out = []
+        for i, (kind, shape, dtype) in enumerate(arrays):
+            if kind == "window":
+                out.append((i, (1 + self.snapshot_slots * self.ring_blocks,)
+                            + tuple(shape[1:]), dtype))
+            elif kind == "state":
+                out.append((i, (self.snapshot_slots + 1,) + tuple(shape[1:]),
+                            dtype))
         return out
 
     def ring(self, slot: int) -> List[int]:
@@ -258,8 +303,7 @@ class PagedKVCache:
         nothing. `layout` gives each layer its kind (`CacheLayout`);
         without one every layer keeps a paged pool."""
         if layout is not None and layout.has_slots:
-            refuse_slots(enable_prefix_cache, 0,
-                         host_tier.byte_budget if host_tier else 0,
+            refuse_slots(0, host_tier.byte_budget if host_tier else 0,
                          compress_blocks, tp_size, False)
         self.layout = layout
         if latent is not None:
@@ -312,6 +356,38 @@ class PagedKVCache:
         self._slot: Dict[int, int] = {}
         self._ring_released: Dict[int, int] = {}
         self.window_blocks_released = 0
+        # state snapshots (`CacheLayout`): prefix reuse over slots. The
+        # snapshot pool `snaps` (one array a window pool and a state
+        # array, `snap_places` its place in `pools`); the snapshots held,
+        # by the prefix each ends, least recently used first:
+        # key -> (place, the blocks under the prefix, the key of the
+        # boundary before it); how many held snapshots stand one
+        # boundary deeper than a key; which snapshots lean on a block;
+        # the copies staged for the engine
+        self.snapshot_every = (
+            layout.snapshot_tokens
+            if layout is not None and layout.has_slots
+            and enable_prefix_cache else 0)
+        self.snap_places: List[int] = []
+        self.snaps: List[jnp.ndarray] = []
+        if self.snapshot_every:
+            described = layout.snapshot_arrays(
+                layout.arrays(self.pool_shape(1), self.dtype))
+            self.snap_places = [i for i, _, _ in described]
+            self.snaps = [jnp.zeros(shape, dtype)
+                          for _, shape, dtype in described]
+        self._snap_free = deque(range(1, layout.snapshot_slots + 1)
+                                if self.snapshot_every else ())
+        self._snap_index: "OrderedDict[tuple, Tuple[int, tuple, tuple]]" \
+            = OrderedDict()
+        self._snap_deeper: Dict[tuple, int] = {}
+        self._block_snaps: Dict[int, Set[tuple]] = {}
+        self._pending_restores: List[Tuple[int, int]] = []  # (place, slot)
+        self._pending_takes: List[Tuple[int, int]] = []     # (slot, place)
+        self.snapshots_taken = 0
+        self.snapshots_restored = 0
+        self.snapshots_evicted = 0
+        self.snapshot_tokens_skipped = 0
         # optional in-device compressed tier: a parallel int8 block pool
         # (+ per-block k/v scales) cold prefix content quantizes into at
         # ~half the bytes. Slot 0 is scratch (the fixed-lane flushes pad
@@ -423,6 +499,13 @@ class PagedKVCache:
         self._c_direct_toks = reg.counter(
             "ptpu_kv_direct_int8_tokens_total",
             "Prompt tokens served by direct int8 reads")
+        self._c_snapshots = reg.counter(
+            "ptpu_state_snapshots_total",
+            "State snapshots taken at a prompt's block boundary, restored "
+            "into an admitted request's slot, evicted",
+            labelnames=("event",))
+        self._g_snapshots = reg.gauge(
+            "ptpu_state_snapshots_held", "State snapshots in the pool")
 
     # -- capacity ---------------------------------------------------------
     def pool_shape(self, tp_size: Optional[int] = None) -> Tuple[int, ...]:
@@ -513,6 +596,10 @@ class PagedKVCache:
         self._key_of.clear()
         self._last_hit.clear()
         self._committed = dict.fromkeys(self._committed, 0)
+        for snap in list(self._snap_index):   # their blocks hold nothing
+            self._drop_snapshot(snap)
+        self._pending_restores.clear()
+        self._pending_takes.clear()
 
     def _host_kv(self, rows) -> Tuple[np.ndarray, np.ndarray]:
         """Device rows of one block -> its (k, v) on the host, each
@@ -592,6 +679,8 @@ class PagedKVCache:
         host tier attached the content is demoted before the entry
         dies — eviction becomes a tier transition, not a loss."""
         block = self._free.popleft()
+        for snap in list(self._block_snaps.pop(block, ())):
+            self._drop_snapshot(snap)    # its prefix loses a block
         key = self._key_of.pop(block, None)
         if key is not None and self._index.get(key) == block:
             self._demote_block(block, key, "evict")
@@ -768,6 +857,9 @@ class PagedKVCache:
         (read-only: no refs taken)."""
         if not self.enable_prefix_cache:
             return []
+        if self.snapshot_every:     # over slots a hit goes by snapshots
+            found = self._match_snapshot(tokens)
+            return list(found[1][1]) if found else []
         matched: List[int] = []
         bs = self.block_size
         for end in range(bs, len(tokens) + 1, bs):
@@ -776,6 +868,98 @@ class PagedKVCache:
                 break
             matched.append(block)
         return matched
+
+    # -- state snapshots ----------------------------------------------------
+    def _match_snapshot(self, tokens: Sequence[int]):
+        """The deepest snapshot boundary under `tokens` that leaves a
+        token to compute: (its key, (place, blocks)) or None. One key
+        a boundary, deepest first (a key is the prefix itself: building
+        and hashing one is a pass over it, so the walk is over the
+        boundaries, not over the blocks)."""
+        every = self.snapshot_every
+        if not every or not self._snap_index:
+            return None
+        for end in range((len(tokens) - 1) // every * every, 0, -every):
+            key = tuple(tokens[:end])
+            held = self._snap_index.get(key)
+            if held is not None:
+                return key, held
+        return None
+
+    def snapshot_depth(self, tokens: Sequence[int]) -> int:
+        """How many of `tokens` a hit over the snapshots held would
+        skip."""
+        found = self._match_snapshot(tokens)
+        return len(found[1][1]) * self.block_size if found else 0
+
+    def take_snapshot(self, seq_id: int, upto: int) -> bool:
+        """A chunk of `seq_id`'s prompt has ended at the boundary
+        `upto`: keep its slot's state as the snapshot of that prefix,
+        unless one is held already. The copy is staged for the engine
+        (`drain_snapshot_takes`), which makes it before anything else
+        touches the slot. The least recently used snapshot gives way
+        when the pool is full: first among those that a deeper
+        snapshot of the same prompt stands behind (a prompt that runs on
+        past both hits the deeper one), else of all: a long document's
+        last boundary outlives its sixteen earlier ones."""
+        every = self.snapshot_every
+        if not every or upto % every or upto <= 0:
+            return False
+        key = tuple(self._tokens[seq_id][:upto])
+        if key in self._snap_index:
+            self._snap_index.move_to_end(key)
+            return False
+        if not self._snap_free:
+            self._drop_snapshot(next(
+                (k for k in self._snap_index if self._snap_deeper.get(k)),
+                next(iter(self._snap_index))))
+        place = self._snap_free.popleft()
+        blocks = tuple(self._tables[seq_id][:upto // self.block_size])
+        before = key[:upto - every]
+        self._snap_index[key] = (place, blocks, before)
+        self._snap_deeper[before] = self._snap_deeper.get(before, 0) + 1
+        for b in blocks:
+            self._block_snaps.setdefault(b, set()).add(key)
+        self._pending_takes.append((self._slot[seq_id], place))
+        self.snapshots_taken += 1
+        self._c_snapshots.labels(event="taken").inc()
+        self._g_snapshots.set(len(self._snap_index))
+        return True
+
+    def _drop_snapshot(self, key: tuple) -> None:
+        place, blocks, before = self._snap_index.pop(key)
+        self._snap_free.append(place)
+        self._snap_deeper[before] -= 1
+        if not self._snap_deeper[before]:
+            del self._snap_deeper[before]
+        for b in blocks:
+            leaning = self._block_snaps.get(b)
+            if leaning is not None:
+                leaning.discard(key)
+                if not leaning:
+                    del self._block_snaps[b]
+        # a copy into the place that has not been made yet is void
+        self._pending_takes = [(s, p) for s, p in self._pending_takes
+                               if p != place]
+        self.snapshots_evicted += 1
+        self._c_snapshots.labels(event="evicted").inc()
+        self._g_snapshots.set(len(self._snap_index))
+
+    @property
+    def snapshots_held(self) -> int:
+        return len(self._snap_index)
+
+    def drain_snapshot_takes(self) -> List[Tuple[int, int]]:
+        """Staged (slot, snapshot place) copies: the engine makes them
+        right after the step that reached the boundary."""
+        out, self._pending_takes = self._pending_takes, []
+        return out
+
+    def drain_snapshot_restores(self) -> List[Tuple[int, int]]:
+        """Staged (snapshot place, slot) copies of this plan's hits:
+        the engine makes them before the step reads the slots."""
+        out, self._pending_restores = self._pending_restores, []
+        return out
 
     def can_allocate(self, tokens) -> bool:
         """Admission check. `tokens` may be a token list (prefix-aware:
@@ -813,7 +997,9 @@ class PagedKVCache:
             raise CacheExhausted("no free state slot")
         n = len(tokens)
         bs = self.block_size
-        matched = self._match_prefix(tokens)
+        snapshot = self._match_snapshot(tokens)
+        matched = (list(snapshot[1][1]) if snapshot else
+                   [] if self.snapshot_every else self._match_prefix(tokens))
         # walk PAST the device-fp match into the compressed tier. Each
         # hit is served IN PLACE by default: the table entry carries the
         # bias-encoded slot (-(slot+1)) and the ragged step dequantizes
@@ -910,6 +1096,13 @@ class PagedKVCache:
         self._tables[seq_id] = matched + mid_blocks + host_blocks + fresh
         if self._free_slots:
             self._slot[seq_id] = self._free_slots.popleft()
+        if snapshot:    # the slot starts from the boundary's state
+            self._snap_index.move_to_end(snapshot[0])
+            self._pending_restores.append(
+                (snapshot[1][0], self._slot[seq_id]))
+            self.snapshots_restored += 1
+            self.snapshot_tokens_skipped += len(matched) * bs
+            self._c_snapshots.labels(event="restored").inc()
         self._lens[seq_id] = n
         self._tokens[seq_id] = list(tokens)
         cached = min((len(matched) + len(chits) + len(host_blocks))
@@ -1009,7 +1202,10 @@ class PagedKVCache:
         return self._committed.get(seq_id, 0)
 
     def _register_full_blocks(self, seq_id: int) -> None:
-        if not self.enable_prefix_cache:
+        # over slots a hit goes by the snapshots' own record of their
+        # blocks: no block is indexed (a key is the prefix itself, and
+        # hashing one a block is a pass over a long document a block)
+        if not self.enable_prefix_cache or self.snapshot_every:
             return
         bs = self.block_size
         table = self._tables[seq_id]
@@ -1128,6 +1324,8 @@ class PagedKVCache:
         if slot is not None:    # state and ring go with the sequence
             self._free_slots.append(slot)
             self._ring_released.pop(seq_id, None)
+            self._pending_restores = [(p, s) for p, s in
+                                      self._pending_restores if s != slot]
         self._lens.pop(seq_id, None)
         self._tokens.pop(seq_id, None)
         self._committed.pop(seq_id, None)
@@ -1226,7 +1424,10 @@ class PagedKVCache:
         computed once and kept beside its block for as long as the
         block carries that very key."""
         from paddle_tpu.engine.kvtier import prefix_digest
-        items = list(self._index.items())
+        # over slots what can be hit is a snapshot's prefix; its first
+        # last block stands where an indexed prefix's block does
+        items = ([(key, held[1][-1]) for key, held in self._snap_index.items()]
+                 if self.snapshot_every else list(self._index.items()))
         if limit and len(items) > limit:
             items = items[-limit:]
         rows = []
@@ -1235,8 +1436,8 @@ class PagedKVCache:
             if kept is None or kept[0] is not key:
                 kept = self._digest_of[block] = (key, prefix_digest(key))
             rows.append((len(key), kept[1]))
-        if len(self._digest_of) > 2 * len(self._index) + 64:
-            live = set(self._index.values())      # entries of blocks gone
+        if len(self._digest_of) > 2 * len(items) + 64:
+            live = {block for _, block in items}  # entries of blocks gone
             self._digest_of = {b: v for b, v in self._digest_of.items()
                                if b in live}
         return rows
@@ -1310,6 +1511,12 @@ class PagedKVCache:
             "used_blocks": self.used_blocks,
             "occupancy": round(self.occupancy(), 4),
         }
+        if self.snapshot_every:
+            out["snapshots_held"] = len(self._snap_index)
+            out["snapshots_taken"] = self.snapshots_taken
+            out["snapshots_restored"] = self.snapshots_restored
+            out["snapshots_evicted"] = self.snapshots_evicted
+            out["snapshot_tokens_skipped"] = self.snapshot_tokens_skipped
         if self._compress_on:
             out["compressed_blocks"] = len(self._cindex)
             out["compress_total"] = self.compressed_total
@@ -1331,6 +1538,8 @@ class PagedKVCache:
         self.compressed_total = self.promoted_total = 0
         self.compress_spills = self.compress_hit_tokens = 0
         self.direct_reads = self.direct_read_tokens = 0
+        self.snapshots_taken = self.snapshots_restored = 0
+        self.snapshots_evicted = self.snapshot_tokens_skipped = 0
 
     def assert_quiesced(self) -> None:
         """Leak check: with no live sequences every refcount must be
@@ -1341,6 +1550,16 @@ class PagedKVCache:
             raise RuntimeError(f"live sequences: {list(self._tables)}")
         if self._slot:
             raise RuntimeError(f"leaked state slots: {self._slot}")
+        if self._pending_restores or self._pending_takes:
+            raise RuntimeError(
+                f"{len(self._pending_restores)} snapshot restores and "
+                f"{len(self._pending_takes)} takes never made")
+        if self.snapshot_every and len(self._snap_free) \
+                + len(self._snap_index) != self.layout.snapshot_slots:
+            raise RuntimeError(
+                f"snapshot place leak: {len(self._snap_free)} free + "
+                f"{len(self._snap_index)} held != "
+                f"{self.layout.snapshot_slots}")
         if self._refs:
             raise RuntimeError(f"leaked refcounts: {self._refs}")
         if self._pending_host_loads:

@@ -213,6 +213,11 @@ class Scheduler:
                     continue
                 take = min(len(req.prompt) - req.prefill_pos, budget)
                 start = req.prefill_pos
+                every = self.cache.snapshot_every
+                if every:
+                    # a chunk ends on the next snapshot boundary, so the
+                    # slot's state there can be kept for a later hit
+                    take = min(take, every - start % every)
                 # COW (a chunk writing into a shared block) may need a
                 # free block; a dry pool preempts from the tail
                 self._ensure_writable_or_preempt(req, start, start + take)
@@ -277,14 +282,18 @@ class Scheduler:
 
     def _try_admit(self) -> List[Request]:
         admitted: List[Request] = []
-        while self.waiting:
-            req = self.waiting[0]
+        at = 0      # the first request that does not wait of its own accord
+        while at < len(self.waiting):
+            req = self.waiting[at]
+            if self._awaits_a_prefill(req, admitted):
+                at += 1     # those behind it may pass: it loses nothing
+                continue
             slots = (sum(self._slots_of(r) for r in self.running)
                      + sum(self._slots_of(r) for r in admitted))
             if (slots + self._slots_of(req) > self.max_batch_size
                     or not self.cache.can_allocate(req.tokens)):
-                break       # FIFO: don't skip ahead of the head request
-            self.waiting.popleft()
+                break       # FIFO: don't skip ahead of a request held up
+            del self.waiting[at]
             # re-admissions re-hit their own committed blocks; don't let
             # that inflate the prefix-cache hit rate
             cached = self.cache.alloc_sequence(
@@ -298,6 +307,30 @@ class Scheduler:
             for req in admitted:
                 self.on_admit(req)
         return admitted
+
+    def _awaits_a_prefill(self, req: Request, admitted) -> bool:
+        """Over state snapshots (`CacheLayout`): whether a running
+        request is still prefilling towards a snapshot boundary that
+        `req` could hit and cannot yet. Blocks are shared as they
+        commit, but a slot's state only where a snapshot was taken, so
+        a request admitted beside its prefix's first owner would compute
+        the whole prefix again; it waits in the queue instead, is
+        admitted onto the owner's snapshots, and meanwhile lets the
+        requests behind it pass (`_try_admit`). It waits for
+        a request that is running and moving only: an owner that was
+        preempted stands before it in the queue, and one past the
+        boundary whose snapshot is gone is not waited for."""
+        every = self.cache.snapshot_every
+        if not every:
+            return False
+        toks = req.tokens
+        want = self.cache.snapshot_depth(toks) + every
+        if want >= len(toks):
+            return False
+        head = toks[:want]
+        return any(r.prefilling and r.prefill_pos < want <= len(r.prompt)
+                   and r.prompt[:want] == head
+                   for r in self.running + admitted)
 
     def _ensure_writable_or_preempt(self, req: Request, start: int,
                                     end: int) -> None:

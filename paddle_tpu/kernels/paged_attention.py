@@ -286,7 +286,8 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
                                      kvq_pool=None,
                                      k_scales=None, v_scales=None,
                                      value_lanes=None,
-                                     window: Optional[int] = None):
+                                     window: Optional[int] = None,
+                                     block_mask=None):
     """XLA oracle for the ragged layout: expand tile metadata to
     per-token rows and run the dense gather + masked attention.
     q: [T, H, D] flat-packed; kv_pool: [NB, BS, Hkv * W] (the section
@@ -306,7 +307,10 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
     [0, V); returns [T, H, V].
 
     `window`: a query sees the `window` newest positions up to its own
-    (its own counts), whatever the table holds behind them."""
+    (its own counts), whatever the table holds behind them.
+
+    `block_mask` [T, MB] bool: the table entries each query may see
+    (block-sparse attention: the keys of the others are masked out)."""
     t, h, d = q.shape
     nb, bs, _ = kv_pool.shape
     hkv = h // groups
@@ -342,6 +346,8 @@ def ragged_paged_attention_reference(q, kv_pool, block_tables,
             & (kv_pos[None, :] < ctx[:, None]))
     if window is not None:
         mask = mask & (kv_pos[None, :] > qpos[:, None] - window)
+    if block_mask is not None:
+        mask = mask & jnp.repeat(block_mask, bs, axis=1)
     mask = mask[:, None, None, :]
     return reference_attention(q[:, None].astype(k.dtype), k, v, mask=mask,
                                scale=scale)[:, 0].astype(q.dtype)
@@ -394,7 +400,8 @@ def _block_heads(rows, d: int):
 
 
 def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
-                        scale: float, groups: int, window=None):
+                        scale: float, groups: int, window=None,
+                        block_sel=None):
     """Online-softmax update for one (query-tile, span of kv blocks)
     cell — shared by the fp-only and mixed-precision ragged kernels. q:
     [TQ, H, W], zero beyond lane D; kv: [Hkv, K, W], the span's K keys
@@ -431,6 +438,24 @@ def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
         # exact 0 (every query reaches its own position or the context's
         # last, `window` >= the tile's width)
         seen = seen & (kpos > qpos - window)
+    if block_sel is not None:
+        # block-sparse: block_sel [TQ, S], 1 where the query may see
+        # the span's block. Spread to (query, head) rows and to keys by
+        # two products with 0/1 matrices (no relayout): a query with no
+        # block in this span takes weights of 1 on it, as under a
+        # window, and its first kept key multiplies them by an exact 0
+        # (the first block is always kept)
+        blocks = block_sel.shape[1]
+        rows = (jax.lax.broadcasted_iota(jnp.int32, (tq * h, tq), 0) // h
+                == jax.lax.broadcasted_iota(jnp.int32, (tq * h, tq), 1))
+        cols = (jax.lax.broadcasted_iota(jnp.int32, (blocks, keys), 1)
+                // (keys // blocks)
+                == jax.lax.broadcasted_iota(jnp.int32, (blocks, keys), 0))
+        kept = jnp.dot(jnp.dot(rows.astype(jnp.float32), block_sel,
+                               preferred_element_type=jnp.float32),
+                       cols.astype(jnp.float32),
+                       preferred_element_type=jnp.float32)
+        seen = seen & (kept > 0.5)
     s = jnp.where(seen, s, NEG_INF)
 
     m_prev = m_scr[...][:, :1]                      # [TQ*H, 1]
@@ -492,7 +517,7 @@ def ragged_span(block_size: int, lanes: int, itemsize: int,
 def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
                  load_span, o_ref, m_scr, l_scr, acc_scr, bufs, cnt, *,
                  scale: float, span: int, tile_q: int, groups: int,
-                 v_off=None, window=None):
+                 v_off=None, window=None, sel_ref=None):
     """One (query-tile, span) grid cell, shared by both ragged kernels.
     The pools stay in HBM; a cell with work waits for its span's blocks
     in one of two VMEM buffers and, before it computes, starts the
@@ -589,7 +614,9 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
         move(False, t, j, slot)
         _ragged_tile_update(q_ref[...], load_span(row, j, slot), q0, ctx,
                             j * span_keys, m_scr, l_scr, acc_scr,
-                            scale=scale, groups=groups, window=window)
+                            scale=scale, groups=groups, window=window,
+                            block_sel=(None if sel_ref is None
+                                       else sel_ref[0, j]))
         cnt[0] += 1
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -632,6 +659,14 @@ def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
     _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
                  load_span, o_ref, m_scr, l_scr, acc_scr, (buf,), cnt,
                  span=span, groups=groups, **cell)
+
+
+def _ragged_kernel_selected(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref,
+                            sel_ref, pool_ref, *rest, **cell):
+    """`_ragged_kernel` with one more operand: sel_ref [1, spans, TQ,
+    span], the tile's block selection a span."""
+    _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
+                   *rest, sel_ref=sel_ref, **cell)
 
 
 def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
@@ -686,7 +721,8 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
                         q_starts, tile_rows, tile_offs, scale,
                         interpret: bool, groups: int, span: int,
                         kvq_pool=None, k_scales=None, v_scales=None,
-                        value_lanes=None, window=None, name=None):
+                        value_lanes=None, window=None, name=None,
+                        block_mask=None):
     t, h, d = q.shape
     nb, bs, lanes = kv_pool.shape
     mb = block_tables.shape[1]
@@ -716,6 +752,18 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
                 f"pool rows of {lanes} lanes do not hold {h // groups} kv "
                 f"heads of [k | v] at head_dim {d}")
     mixed = kvq_pool is not None
+    spans = -(-mb // span)
+    selected = block_mask is not None
+    if selected:
+        if mixed or latent or window is not None or h != groups:
+            raise ValueError(
+                "a block selection is read over one kv head's K/V pool, "
+                "with no window, int8 tier or latent row")
+        # [T, MB] -> a tile's [spans, TQ, span]: a cell takes its span's
+        # selection by a leading index
+        sel = jnp.pad(block_mask.astype(jnp.float32),
+                      ((0, 0), (0, spans * span - mb)))
+        sel = sel.reshape(nt, tq, spans, span).transpose(0, 2, 1, 3)
     # q in the head's full lane width: zero lanes meet v in the
     # contraction
     q = jnp.pad(q, ((0, 0), (0, 0), (0, w - d)))
@@ -737,12 +785,16 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     # block_tables, ctx_lens, q_starts, tiles x2 (+ k/v scales)
     num_prefetch = 7 if mixed else 5
     kernel_fn = (_ragged_kernel_mixed if mixed else
+                 _ragged_kernel_selected if selected else
                  functools.partial(_ragged_kernel, v_off=v_off))
+    sel_specs = ([pl.BlockSpec((1, spans, tq, span),
+                               lambda ti, j, *prefetched: (ti, 0, 0, 0))]
+                 if selected else [])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
-        grid=(nt, -(-mb // span)),
-        in_specs=[pl.BlockSpec(q_block, _q_map)]
+        grid=(nt, spans),
+        in_specs=[pl.BlockSpec(q_block, _q_map)] + sel_specs
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec(o_block, _q_map),
         scratch_shapes=[
@@ -773,6 +825,8 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
     if mixed:
         return call(*scalars, k_scales.astype(jnp.float32),
                     v_scales.astype(jnp.float32), q, kv_pool, kvq_pool)
+    if selected:
+        return call(*scalars, q, sel, kv_pool)
     out = call(*scalars, q, kv_pool)
     return out.reshape(t, h, out_d) if latent else out
 
@@ -785,7 +839,7 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
                            groups: int = 1,
                            kvq_pool=None, k_scales=None, v_scales=None,
                            value_lanes=None, window: Optional[int] = None,
-                           name: Optional[str] = None):
+                           name: Optional[str] = None, block_mask=None):
     """Mixed prefill+decode attention over the flat ragged packing —
     the engine's single-step entry point. q: [T, H, D]; kv_pool: one
     layer's pool as the cache lays it out, [NB, BS, Hkv * W]; `groups`
@@ -814,7 +868,18 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
     head_dim = 2 x its key width: a pair of key heads side by side is
     one key "head", the pair's two value heads its value, and each
     query head of the pair zero on the other's lanes. `name` is the
-    name the Pallas call carries into a device trace."""
+    name the Pallas call carries into a device trace.
+
+    `block_mask` [T, MB] bool is BLOCK-SPARSE attention: query i sees
+    table entry b of its row only where block_mask[i, b] (and the first
+    entry always, the caller's promise: no softmax row is empty). The
+    pool holds one kv head (groups = H); a caller whose kv heads select
+    apart keeps a pool a head. A row whose every query keeps the same
+    few blocks is better served by a COMPACTED table (the kept entries
+    in order, the context shortened to match: without positions the
+    kernel cannot tell), which costs the kernel nothing; the mask is for
+    rows whose queries differ, and its cells still copy their spans
+    whole."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     if window is not None and (value_lanes is not None
@@ -827,7 +892,7 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
             q, kv_pool, block_tables, context_lens, q_starts,
             tile_rows, tile_offs, scale=scale, groups=groups,
             kvq_pool=kvq_pool, k_scales=k_scales, v_scales=v_scales,
-            value_lanes=value_lanes, window=window)
+            value_lanes=value_lanes, window=window, block_mask=block_mask)
     _, bs, lanes = kv_pool.shape
     span = ragged_span(bs, lanes, kv_pool.dtype.itemsize,
                        block_tables.shape[1])
@@ -837,7 +902,7 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
                                kvq_pool=kvq_pool,
                                k_scales=k_scales, v_scales=v_scales,
                                value_lanes=value_lanes, window=window,
-                               name=name)
+                               name=name, block_mask=block_mask)
 
 
 # -- tensor-parallel wrappers (engine tp_size knob, ENGINE.md) ------------

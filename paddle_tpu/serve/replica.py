@@ -47,6 +47,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-prefill-tokens", type=int, default=64)
     p.add_argument("--tile-q", type=int, default=8)
     p.add_argument("--no-prefix-cache", action="store_true")
+    p.add_argument("--snapshot-tokens", type=int, default=None,
+                   help="prefix reuse over recurrent state: snapshot a "
+                        "slot's state every this many prompt tokens (a whole "
+                        "number of blocks; default: what the model asks for)")
+    p.add_argument("--snapshot-slots", type=int, default=None,
+                   help="snapshots the pool holds, least recently used out")
     p.add_argument("--spec-k", type=int, default=0,
                    help="speculative draft length (0 disables; > 0 "
                         "turns on the n-gram self-drafter)")
@@ -181,7 +187,9 @@ def build_frontend(a: argparse.Namespace):
             kv_tier_int8=a.kv_tier_int8,
             kv_compress_blocks=a.kv_compress_blocks,
             tier_spill_dir=a.tier_spill_dir, tp_size=a.tp_size,
-            demote_finished=(a.phase == "prefill"))
+            demote_finished=(a.phase == "prefill"),
+            snapshot_tokens=a.snapshot_tokens,
+            snapshot_slots=a.snapshot_slots)
     else:
         import jax
         import jax.numpy as jnp
@@ -203,7 +211,9 @@ def build_frontend(a: argparse.Namespace):
             kv_tier_int8=a.kv_tier_int8,
             kv_compress_blocks=a.kv_compress_blocks,
             tier_spill_dir=a.tier_spill_dir, tp_size=a.tp_size,
-            demote_finished=(a.phase == "prefill"))
+            demote_finished=(a.phase == "prefill"),
+            snapshot_tokens=a.snapshot_tokens,
+            snapshot_slots=a.snapshot_slots)
     slo = SLOMonitor(
         registry,
         objectives=default_objectives(

@@ -500,3 +500,115 @@ def test_hybrid_step_at_its_cell_sizes(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0.25 * 16e9 < total < 15e9, total
+
+
+def test_lightning_kernel_compiles_for_v5e(one_chip):
+    """Ragged lightning attention at `sala-docs32k`'s step: 512 flat
+    positions in 64 tiles, 32 heads of 128 side by side in the lanes, 33
+    slots of [32, 128, 128] float32, the state donated and aliased to
+    the kernel's output."""
+    from paddle_tpu.kernels.lightning_attention import \
+        ragged_lightning_attention
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    t, h, d, nt, slots = 512, 32, 128, 64, 33
+    args = [s((t, h, d), jnp.float32)] * 3 + [
+        s((h,), jnp.float32), s((slots, h, d, d), jnp.float32)] \
+        + [s((nt,), jnp.int32)] * 4
+
+    def fn(*a):
+        return ragged_lightning_attention(*a, use_kernel=True,
+                                          interpret=False)
+    compiled = jax.jit(fn, donate_argnums=(4,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged_lightning_attention" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        slots * h * d * d * 4
+
+
+def test_block_mask_ragged_kernel_compiles_for_v5e(one_chip):
+    """The ragged kernel as a block-sparse layer calls it, a kv head a
+    call: 16 query heads over one kv head of 128 (rows of 256 lanes,
+    blocks of 64, eight a cell), a table of 520 entries a row in SMEM
+    and a block mask a query."""
+    from paddle_tpu.kernels.paged_attention import (ragged_paged_attention,
+                                                    ragged_span)
+    t, tq, bs, mb, rows, nb = 512, 8, 64, 520, 33, 6144
+    assert ragged_span(bs, 256, 2, mb) == 8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = [s((t, 16, 128), jnp.bfloat16),
+            _pool_rows(nb, bs, 1, 128, jnp.bfloat16, one_chip),
+            s((rows, mb), jnp.int32), s((rows,), jnp.int32),
+            s((rows,), jnp.int32), s((t // tq,), jnp.int32),
+            s((t // tq,), jnp.int32), s((t, mb), jnp.bool_)]
+
+    def fn(q, kv, bt, cl, qs, tr, to, mask):
+        return ragged_paged_attention(
+            q, kv, bt, cl, qs, tr, to, use_kernel=True, interpret=False,
+            groups=16, block_mask=mask, name="ragged_sparse_attention")
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "ragged_sparse_attention" in text
+
+
+def test_sparse_linear_step_keeps_pools_and_state_in_place(one_chip):
+    """The engine's step over paged pools a kv head, index pools and
+    lightning state, compiled for the described chip at
+    `sala-docs32k`'s widths and pool sizes with two layers (one of each
+    kind; shapes only): both kernels are in it, every pool and state
+    array is aliased to the step's output, and no copy of a pool's, an
+    index pool's or a state's size is in the program (a gather of part
+    of a row once laid each paged pool out anew, 1.6 GB a step)."""
+    import json
+    from unittest import mock
+
+    from paddle_tpu.engine.engine import compile_steps
+    from paddle_tpu.engine.paged_cache import CacheLayout
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "minicpm-sala.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, mixer_types=["lightning-attn", "minicpm4"],
+               num_hidden_layers=2, vocab_size=4096)
+    model = SparseLinearLM(
+        dtype=jnp.bfloat16,
+        **{k: cfg[v] for k, v in cfg["constructor_args"].items()})
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    s = cfg["serve"]
+    tq, b, bs = s["tile_q"], s["max_batch_size"], s["block_size"]
+    t = -(-s["max_prefill_tokens"] // tq) * tq + b * tq
+    mb = -(-s["max_seq_len"] // bs)
+    layout = CacheLayout(model.cache_layout, bs, b, s["max_prefill_tokens"])
+    described = layout.arrays(
+        (s["num_blocks"], bs, paged_attention.head_lanes(cfg["head_dim"])),
+        jnp.bfloat16)
+    kinds = [kind for kind, _, _ in described]
+    assert kinds == ["state", "paged", "paged", "index", "rows"]
+    pools = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for _, shape, dtype in described]
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    step, _ = compile_steps(model, shapes, False, None, kinds)
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = step.lower(
+            jax.tree.map(on_chip, shapes), i32(t), i32(t), pools, [], [],
+            i32(b + 1, mb), i32(b + 1), i32(b + 1), i32(t // tq),
+            i32(t // tq), i32(t), i32(b, 1)).compile()
+    text = compiled.as_text()
+    assert "ragged_sparse_attention" in text
+    assert "ragged_lightning_attention" in text
+    for pool in pools[:-1]:
+        assert _pool_sized_copies(text, pool) == [], pool.shape
+    held = sum(p.size * p.dtype.itemsize for p in pools[:-1])
+    assert compiled.memory_analysis().alias_size_in_bytes >= held

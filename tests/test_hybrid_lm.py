@@ -343,19 +343,107 @@ def test_block_copies_leave_slots_alone(toy):
 
 
 @pytest.mark.parametrize("kwargs,words", [
-    ({"enable_prefix_cache": True}, "enable_prefix_cache=True over"),
     ({"spec_k": 2}, "spec_k=2 over"),
     ({"host_tier_bytes": 1 << 20}, "host_tier_bytes=1048576 over"),
     ({"kv_compress_blocks": 8}, "kv_compress_blocks=8 over"),
     ({"tp_size": 2}, "tp_size=2 over"),
     ({"demote_finished": True}, "kvxfer"),
-], ids=["prefix", "speculation", "host_tier", "int8_tier", "tp", "kvxfer"])
+], ids=["speculation", "host_tier", "int8_tier", "tp", "kvxfer"])
 def test_what_slots_cannot_do_refuses_at_construction(toy, kwargs, words):
     cfg, model, variables = toy
     with pytest.raises(ValueError, match=words) as e:
         _engine(model, variables, **kwargs)
     assert "recurrent state or a window ring" in str(e.value)
     assert "serve it with" in str(e.value)
+
+
+@pytest.mark.parametrize("kwargs,words", [
+    ({"spec_k": 2}, "spec_k=2 over"),
+    ({"host_tier_bytes": 1 << 20}, "host_tier_bytes=1048576 over"),
+    ({"kv_compress_blocks": 8}, "kv_compress_blocks=8 over"),
+], ids=["speculation", "host_tier", "int8_tier"])
+def test_the_prefix_cache_leaves_the_other_refusals_standing(toy, kwargs,
+                                                              words):
+    cfg, model, variables = toy
+    with pytest.raises(ValueError, match=words):
+        _engine(model, variables, enable_prefix_cache=True, **kwargs)
+
+
+# -- prefix reuse over the scan's state, the tail and the rings --------------
+
+def _rows_of(eng, prompts, new_tokens):
+    spy = Spy()
+    with mock.patch.multiple(engine_mod, _sample=spy,
+                             _needs_logits=lambda req: True):
+        reqs = [eng.add_request(p, max_new_tokens=new_tokens)
+                for p in prompts]
+        eng.run()
+    return reqs, [np.stack([spy.rows[(r.req_id, len(p) + j)]
+                            for j in range(new_tokens)])
+                  for r, p in zip(reqs, prompts)]
+
+
+def test_the_prefix_cache_works_over_state_by_snapshots(toy):
+    """`enable_prefix_cache=True` no longer refuses: every 16 tokens of
+    a prompt (four blocks of 4) the slot's scan state, convolution tail
+    and window rings are snapshot. A second prompt that shares 43 tokens
+    of the first's 50 hits 32 deep, its slot restored, and is served
+    bit for bit as by an engine without the cache; a third that shares
+    all of them hits 48 deep. The unset default stays off."""
+    cfg, model, variables = toy
+    first = _tokens(cfg, np.random.default_rng(21), 50)[0]
+    second = first[:43] + _tokens(cfg, np.random.default_rng(22), 7)[0]
+    third = first + [3, 1, 4]
+    eng = _engine(model, variables, enable_prefix_cache=True,
+                  snapshot_tokens=16, snapshot_slots=4)
+    assert eng.cache.snapshot_every == 16
+    assert len(eng.cache.snaps) == eng.cache.kinds.count("window") \
+        + eng.cache.kinds.count("state")
+    _rows_of(eng, [first], 3)
+    assert eng.cache.snapshots_held == 3           # at 16, 32 and 48
+    reqs, hit = _rows_of(eng, [second, third], 8)
+    assert [r.cached_tokens for r in reqs] == [32, 48]
+    plain = _engine(model, variables)
+    assert plain.cache.enable_prefix_cache is False
+    assert plain.cache.snapshot_every == 0 and not plain.cache.snaps
+    reqs2, cold = _rows_of(plain, [second, third], 8)
+    assert [r.cached_tokens for r in reqs2] == [0, 0]
+    for a, b in zip(hit, cold):
+        np.testing.assert_array_equal(a, b)
+    want = _reference_rows(cfg, second, ServeEngine._generated_of(reqs[0]))
+    np.testing.assert_allclose(hit[0], want, atol=TOL, rtol=0)
+    assert eng._step_fn._cache_size() == 1
+    eng.cache.assert_quiesced()
+
+
+def test_a_snapshot_holds_rings_tail_and_state(toy):
+    """What a snapshot place holds after a prompt's boundary is what the
+    slot held there: every state array's entry and every ring block,
+    place for place."""
+    cfg, model, variables = toy
+    eng = _engine(model, variables, enable_prefix_cache=True,
+                  snapshot_tokens=16, snapshot_slots=2, max_batch_size=1)
+    prompt = _tokens(cfg, np.random.default_rng(23), 16)[0] + [5]
+    req = eng.add_request(prompt, max_new_tokens=1)
+    eng.step()                                     # the chunk [0, 16)
+    assert req.prefill_pos == 16 and eng.cache.snapshots_held == 1
+    place, blocks, _ = eng.cache._snap_index[tuple(prompt[:16])]
+    slot = eng.cache.slot(req.req_id)
+    lay = eng.cache.layout
+    assert len(blocks) == 4
+    for at, snap in zip(eng.cache.snap_places, eng.cache.snaps):
+        pool, kind = eng.cache.pools[at], eng.cache.kinds[at]
+        if kind == "window":
+            first = 1 + (place - 1) * lay.ring_blocks
+            np.testing.assert_array_equal(
+                np.asarray(snap[first:first + lay.ring_blocks]),
+                np.asarray(pool[jnp.asarray(lay.ring(slot))]))
+        else:
+            np.testing.assert_array_equal(np.asarray(snap[place]),
+                                          np.asarray(pool[slot]))
+            assert float(jnp.abs(snap[place]).max()) > 0
+    eng.run()
+    eng.cache.assert_quiesced()
 
 
 def test_forks_refuse(toy):
@@ -367,7 +455,8 @@ def test_forks_refuse(toy):
     with pytest.raises(ValueError, match="fork over recurrent state"):
         eng.cache.fork_sequence(7, 8)
     eng.cache.free_sequence(7)
-    # and unset, the prefix cache is off rather than refused
+    # and unset, the prefix cache is off: this model asks for no
+    # snapshots of its own
     assert eng.cache.enable_prefix_cache is False
 
 

@@ -12,6 +12,7 @@ import importlib
 import inspect
 import json
 import threading
+import time
 from unittest import mock
 
 import jax
@@ -178,7 +179,19 @@ def served(model_and_vars):
     out = collect_stream(fe.url, {"prompt": PROMPTS[2],
                                   "max_new_tokens": 16})
     assert out["done"] and len(out["tokens"]) == 16
-    yield fe, out, prof.get_events()
+    # the client has its last frame as soon as the hand-over wakes the
+    # handler; the engine loop's thread closes the spans of that very
+    # step a moment later (it may wait for the interpreter meanwhile):
+    # the ring is read once it has stopped growing
+    events = prof.get_events()
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline:
+        time.sleep(0.1)
+        later = prof.get_events()
+        if len(later) == len(events):
+            break
+        events = later
+    yield fe, out, events
     fe.stop()
 
 
@@ -419,3 +432,67 @@ def test_slot_counts_agree_with_the_counters():
     assert eng.obs.get("ptpu_state_slots_in_use").value == 1
     eng.run()
     assert eng._step_fn._cache_size() == 1
+
+
+def test_sparse_and_snapshot_counts_agree_with_the_counters():
+    """A model of block-sparse and lightning layers, served with state
+    snapshots: `sparse_rows_read`, `sparse_keys`, `index_rows_read` and
+    `blocks_selected` are what ONE sparse layer and kv head reads after
+    selection, `la_tokens` the real tokens through a lightning layer,
+    `snapshots_taken` / `snapshots_restored` / `snapshot_tokens_skipped`
+    the cache's snapshot traffic of the step; each span field sums to
+    the counter that goes with it."""
+    from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
+    sel = dict(dense_len=16, kernel=4, stride=2, block=4, init_blocks=1,
+               local=8, topk=1)
+    model = SparseLinearLM(
+        vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2, head_dim=4,
+        ffn_dim=32, mixer_types=["lightning-attn", "minicpm4"], la_heads=2,
+        la_head_dim=8, sparse=sel, max_len=64, snapshot_tokens=8,
+        snapshot_slots=4)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=8)
+    assert eng.cache.snapshot_every == 8
+    shared = list(range(1, 27))
+    prof.reset_profiler()
+    eng.generate([shared + [30, 31]], max_new_tokens=6)
+    eng.generate([shared + [40]], max_new_tokens=6)     # hits 24 deep
+    steps = _spans(prof.get_events(), "engine.step")
+
+    def total(field):
+        return sum(st["args"][field] for st in steps)
+    rows = [(28, 6), (27, 6)]
+    computed = (28 + 5) + (27 - 24 + 5)
+    assert total("la_tokens") == computed == eng.obs.get(
+        "ptpu_la_tokens_total").value
+    assert total("state_slots") == sum(
+        st["args"]["decode_rows"] + st["args"]["chunk_rows"] for st in steps)
+    assert total("sparse_rows_read") == eng.obs.get(
+        "ptpu_attn_kv_rows_total").labels(kind="sparse").value
+    assert total("sparse_keys") == eng.obs.get(
+        "ptpu_attn_keys_total").labels(kind="sparse").value
+    assert total("index_rows_read") == eng.obs.get(
+        "ptpu_attn_index_rows_total").value > 0
+    assert total("blocks_selected") == eng.obs.get(
+        "ptpu_attn_blocks_selected_total").value > 0
+    # a query past dense_len keeps the first block, the best one and the
+    # blocks of its 8 newest positions: fewer keys than its context
+    dense = sum(p + 1 for n, new in rows for p in range(n + new - 1)) \
+        - sum(p + 1 for p in range(24))
+    assert 0 < total("sparse_keys") < dense == total("attn_keys")
+    snaps = eng.obs.get("ptpu_state_snapshots_total")
+    assert total("snapshots_restored") == 1 \
+        == snaps.labels(event="restored").value
+    assert total("snapshot_tokens_skipped") == 24 \
+        == eng.cache.snapshot_tokens_skipped
+    # taken at 8, 16 and 24 of the first prompt; a take is counted by
+    # the step after it, so the last drain's spans may lack none here
+    assert snaps.labels(event="taken").value == 3 \
+        == eng.cache.snapshots_taken
+    assert total("snapshots_taken") == 3
+    assert eng.obs.get("ptpu_state_snapshots_held").value == 3
+    flushes = _spans(prof.get_events(), "engine.flush")
+    assert sum(f["args"]["restores"] for f in flushes) == 1
+    assert eng._step_fn._cache_size() == 1
+    eng.cache.assert_quiesced()
