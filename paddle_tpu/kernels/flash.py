@@ -6,16 +6,25 @@ but attention's softmax-rescaling loop is the canonical case where a custom
 kernel beats the compiler by keeping the [Tq, Tk] score matrix out of HBM.
 
 Design (TPU-idiomatic, layout [BH, T, D]):
-- Forward: grid (bh, q_blocks, k_blocks); the k dimension is sequential
-  ("arbitrary" semantics) and K/V stream through VMEM one block at a time —
-  VMEM holds O(block_q*D + block_k*D), never the full K/V. Online-softmax
-  state (running max m, denom l, accumulator) lives in VMEM scratch that
-  persists across the sequential k steps. Also emits the log-sum-exp
-  residual (lane-broadcast, the standard TPU layout) for the backward pass.
-- Backward: two recompute kernels wired through jax.custom_vjp (pallas_call
-  has no autodiff rule). dq streams K/V blocks per q block; dk/dv streams
-  Q/dO blocks per k block. Both recompute p = exp(s - lse) from the saved
-  lse instead of storing the [Tq, Tk] probability matrix.
+- Forward (`flash_fwd`): grid (bh, q_blocks, k_blocks); the k dimension is
+  sequential ("arbitrary" semantics) and K/V stream through VMEM one block
+  at a time — VMEM holds O(block_q*D + block_k*D), never the full K/V.
+  Online-softmax state (running max m, denom l, accumulator) lives in VMEM
+  scratch that persists across the sequential k steps. Also emits the
+  log-sum-exp residual (lane-broadcast, the standard TPU layout) for the
+  backward pass.
+- Backward, wired through jax.custom_vjp (pallas_call has no autodiff
+  rule), recomputing p = exp(s - lse) from the saved lse instead of storing
+  the [Tq, Tk] probability matrix. One kernel body, two schedules, chosen
+  from the shapes at trace time (`_bwd_impl`): ONE PASS (`flash_bwd`) where
+  a head's dq fits in VMEM — s, p, dp computed once a tile feed dq, dk and
+  dv; and TWO KERNELS (`flash_dq` streams K/V blocks per q block,
+  `flash_dkv` streams Q/dO blocks per k block) for longer sequences.
+- Inside a fetched block every kernel computes strips that stop at the
+  diagonal (`_plan`): at (1024, 1024) blocks and T = 1,024 the forward
+  computes 56% of a head's T^2 and the one pass 62.5%, where whole
+  (512, 512) blocks computed 75%; the causal iota/compare/select runs on
+  the crossed part of a strip alone.
 
 Structured masking (all handled block-wise, never as a dense [Tq, Tk]
 tensor):
@@ -27,8 +36,8 @@ tensor):
   ~sum(len_i^2), not T^2.
 - `dropout_rate` — in-kernel attention dropout via a stateless integer
   hash (murmur3 finalizer) on (seed, batch*head, q_pos, k_pos). Using
-  global positions makes the keep-mask identical in the forward and both
-  backward kernels regardless of block shape, with no [Tq, Tk] mask
+  global positions makes the keep-mask identical in the forward and every
+  backward schedule regardless of block and strip shape, with no [Tq, Tk] mask
   materialized. The softmax denominator uses UNdropped probabilities
   (dropout applies after normalization, matching the XLA reference path's
   bernoulli-on-probs semantics); only the accumulator sees dropped ones.
@@ -55,11 +64,7 @@ NEG_INF = -1e30
 LANES = 128     # f32 lane width: m/l/lse scratch is lane-broadcast
 SUBLANES = 8    # kv segment ids ride the sublane dim: [B, SUBLANES, Tk]
 
-# Defaults are resolved adaptively in flash_attention() (None = choose by
-# sequence length). Measured on v5e (bf16, causal, fwd+bwd): large square
-# blocks win at moderate T ((512,512): 3.5x over (128,128) at T=1024,
-# 4.8x over XLA dense); (256,512) wins at T>=4096. Small blocks
-# under-fill the MXU and pay per-iteration scratch/loop overhead.
+# Defaults are resolved in flash_attention() (None = `_default_blocks`).
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 
@@ -84,16 +89,27 @@ def normalize_segment_ids(segment_ids, b: int, t_q: int, t_k: int):
 
 
 def _default_blocks(t_q: int, t_k: int):
-    # v5e-measured: (512,512) best at T<=2048 (2.91 ms @1024/bs16);
-    # (1024,1024) best at long T — the round-5 roofline sweep
-    # (tools/flash_roofline.py, ceiling-relative): fwd 85.9% of the
-    # same-day sustained-matmul rate at 16k vs 70.7% for the previous
-    # (512,1024) default (arithmetic intensity 334 vs 204 FLOP/B —
-    # comfortably compute-bound either way; the win is fewer grid steps
-    # amortizing per-block scratch/loop overhead).
-    if t_k > 2048:
-        return 1024, 1024
-    return 512, 512
+    # One block a head where the sequence allows: a block is computed in
+    # strips that stop at the diagonal (`_plan`), so its size sets the
+    # grid steps and the DMAs, not the computed share. Longer sequences
+    # take the largest block that divides them, so that nothing is padded.
+    # v5e, bf16, 16 heads of 64, a layer, forward + backward in ms (my chip
+    # runs, PR 34, tools/flash_roofline.py on [BH, T, D] operands):
+    #   bs 8 x 1,024 causal (the training cell): 0.58-0.66 + 0.84 at
+    #     (1024, 1024), 0.96 + 1.10-1.27 at (512, 512); parent 1.08 + 1.70
+    #   bs 4 x 2,048 causal: 1.09 + 1.39 at (1024, 1024), 1.38 + 1.80 at
+    #     (512, 512); parent 1.69 + 2.84
+    #   bs 8 x 1,024 not causal, documents of 384, 128, 256, 256 packed a
+    #     row: 1.05 + 1.37 at (1024, 1024), 1.01 + 1.18 at (512, 512),
+    #     which skips documents by 512 rows; parent 1.11 + 1.78
+    # At T > 2,048 (1024, 1024) was the round-5 sweep's choice too (not
+    # re-measured since the kernels walk strips).
+    def side(t):
+        if t <= 1024:
+            return 1024         # the caller caps a block at the sequence
+        return next((b for b in (1024, 512, 256) if t % b == 0), 1024)
+
+    return side(t_q), side(t_k)
 
 
 def _scratch(shape):
@@ -112,8 +128,8 @@ def _smem_spec():
 # --------------------------------------------------------------------------
 # Stateless in-kernel dropout: murmur3-finalizer hash of
 # (seed, bh, q_pos, k_pos). Global positions => the keep-mask is identical
-# across the forward and both backward kernels by construction, independent
-# of block shape.
+# across the forward and the backward by construction, independent of block
+# and strip shape.
 # --------------------------------------------------------------------------
 
 def _mix32(x):
@@ -143,6 +159,8 @@ def _block_mask(s, q_start, k_start, *, causal: bool, limit: Optional[int],
     """Apply causal / length-bound / segment masking to a [BQ, BK] block.
 
     q_seg: [BQ, 1] int32; kv_seg: [1, BK] int32 (or both None)."""
+    if not causal and limit is None and q_seg is None:
+        return s
     bq, bk = s.shape
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     if causal:
@@ -158,12 +176,12 @@ def _block_mask(s, q_start, k_start, *, causal: bool, limit: Optional[int],
     return s
 
 
-def _seg_block(qseg_ref, kseg_ref):
-    """[BQ, 1] and [1, BK] segment-id slices from the lane/sublane-broadcast
-    block refs (or (None, None))."""
+def _seg_block(qseg_ref, kseg_ref, rows=slice(None), cols=slice(None)):
+    """[R, 1] and [1, C] segment-id slices from the lane/sublane-broadcast
+    block refs (or (None, None)); the whole block unless rows/cols say."""
     if qseg_ref is None:
         return None, None
-    return qseg_ref[...][:, :1], kseg_ref[...][:1, :]
+    return qseg_ref[rows, :][:, :1], kseg_ref[:, cols][:1, :]
 
 
 def _contributes(causal, q_start, k_start, block_q, q_seg, kv_seg):
@@ -181,12 +199,130 @@ def _contributes(causal, q_start, k_start, block_q, q_seg, kv_seg):
 
 
 # --------------------------------------------------------------------------
+# The schedule inside a fetched block. A grid step fetches a large block
+# (it amortises the step and its DMAs) and computes it in strips, each
+# one tile: a strip of query rows against every key of the block that one
+# of them may see (forward and dq: the softmax state and dq are per row),
+# or a strip of key columns against every query that may see one of them
+# (one-pass backward and dk/dv: those are per column). A strip ends
+# at the diagonal, to the other side's grain, so the computed share of a
+# head's T^2 follows the lower triangle, and only the part of a tile that
+# the diagonal crosses pays for the causal iota, compare and select.
+# --------------------------------------------------------------------------
+
+# (sub_q, sub_k) of strips of rows ("q"), then of strips of keys ("k"). A
+# strip of 128 query rows ends at the diagonal to 128 keys; a strip of
+# keys is 256 wide and starts at the diagonal to 128 rows. The launchers
+# take the pair as `strips` (static; None is this), so a test can give a
+# small block strips of its own.
+STRIPS = ((128, 128), (128, 256))
+
+
+def _strip_sizes(block_q: int, block_k: int, by: str, strips=None):
+    """The strips' (sub_q, sub_k) for `by`, each side halved down to the
+    lane width until it divides the block's, else the block's own side
+    (small test blocks)."""
+    def fit(sub, side):
+        while side % sub and sub > LANES:
+            sub //= 2
+        return sub if side % sub == 0 else side
+
+    sub_q, sub_k = (strips or STRIPS)[by == "k"]
+    return fit(sub_q, block_q), fit(sub_k, block_k)
+
+
+def _plan(block_q, block_k, sub_q, sub_k, kind, by):
+    """Static list of a block's tiles, one a strip: (rows, cols, crossed),
+    slices within the block, `crossed` the part of the tile's long side
+    that needs the causal mask (a slice within the tile) or None.
+
+    by "q": strips of sub_q rows, long side the keys; by "k": strips of
+    sub_k columns, long side the queries. kind "all": no causal mask (not
+    causal, or the block lies wholly under the diagonal); "diag": the
+    block's corner is on the diagonal (q_start == k_start): a strip
+    stops where the diagonal leaves it; "any": a causal block at an
+    unknown offset: every strip whole, masked."""
+    tiles = []
+    if by == "q":
+        for lo in range(0, block_q, sub_q):
+            hi = lo + sub_q
+            seen, clear = block_k, 0 if kind == "any" else block_k
+            if kind == "diag":
+                # keys < hi are seen by some row, keys <= lo by every row
+                seen = min(block_k, -(-hi // sub_k) * sub_k)
+                clear = (lo + 1) // sub_k * sub_k
+            tiles.append((slice(lo, hi), slice(0, seen),
+                          slice(clear, seen) if clear < seen else None))
+    else:
+        for lo in range(0, block_k, sub_k):
+            hi = lo + sub_k
+            first, clear = 0, block_q if kind == "any" else 0
+            if kind == "diag":
+                # rows >= lo see some key, rows >= hi - 1 see every key
+                first = lo // sub_q * sub_q
+                clear = min(block_q, -(-(hi - 1) // sub_q) * sub_q)
+            tiles.append((slice(first, block_q), slice(lo, hi),
+                          slice(0, clear - first) if clear > first
+                          else None))
+    return tiles
+
+
+def _walk(causal, q_start, k_start, block_q, block_k, by, strips, body):
+    """Emit body(tiles) for the block at (q_start, k_start). A causal
+    block that `_contributes` let through is either wholly under the
+    diagonal or crossed by it; with square blocks a crossed block's
+    corner is on the diagonal (q_start == k_start), which makes its
+    lower triangle a static list."""
+    plan = functools.partial(
+        _plan, block_q, block_k,
+        *_strip_sizes(block_q, block_k, by, strips), by=by)
+    if not causal:
+        body(plan(kind="all"))
+        return
+    under = k_start + block_k - 1 <= q_start
+    pl.when(under)(lambda: body(plan(kind="all")))
+    pl.when(jnp.logical_not(under))(lambda: body(
+        plan(kind="diag" if block_q == block_k else "any")))
+
+
+def _tile_mask(s, q0, k0, crossed, by, *, limit, q_seg, kv_seg):
+    """Mask a tile whose first element is (q0, k0): the length and
+    segment masks over all of it (their rule: every tile when they are
+    given), the causal mask over `crossed` alone."""
+    s = _block_mask(s, q0, k0, causal=False, limit=limit, q_seg=q_seg,
+                    kv_seg=kv_seg)
+    if crossed is None:
+        return s
+    axis = 1 if by == "q" else 0
+    lo, hi, n = crossed.start, crossed.stop, s.shape[axis]
+    part = functools.partial(jax.lax.slice_in_dim, s, axis=axis)
+    mid = _block_mask(part(lo, hi), q0 + (0 if axis else lo),
+                      k0 + (lo if axis else 0), causal=True, limit=None)
+    parts = [mid]
+    if lo > 0:
+        parts.insert(0, part(0, lo))
+    if hi < n:
+        parts.append(part(hi, n))
+    return mid if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
+
+def _sub_rows(x, lanes_col):
+    """x [R, C] minus a per-row scalar held lane-broadcast as [R, LANES]:
+    whole vregs where C is a multiple of the lane width."""
+    c = x.shape[1]
+    if c % LANES == 0:
+        return x - (lanes_col if c == LANES
+                    else jnp.tile(lanes_col, (1, c // LANES)))
+    return x - lanes_col[:, :1]
+
+
+# --------------------------------------------------------------------------
 # Forward
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
                 block_k: int, limit: Optional[int], want_lse: bool,
-                has_segs: bool, dropout_rate: float):
+                has_segs: bool, dropout_rate: float, strips):
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     qseg_ref = next(it) if has_segs else None
@@ -207,40 +343,48 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_seg, kv_seg = _seg_block(qseg_ref, kseg_ref)
+    def body(tiles):
+        # one tile a strip of rows: its state is read and written once
+        for rows, cols, crossed in tiles:
+            q0, k0 = q_start + rows.start, k_start + cols.start
+            # Matmul inputs stay in the storage dtype (bf16 on the
+            # training path) so the MXU runs at bf16 rate; accumulation
+            # and all softmax state are fp32 via preferred_element_type.
+            # Casting q/k/v to fp32 here ran the dots at fp32 rate — 4x
+            # slower on v5e (round-3 fix).
+            q = q_ref[rows, :]                               # [SQ, D]
+            k = k_ref[cols, :]                               # [C, D]
+            v = v_ref[cols, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [SQ, C] f32
+            q_seg, kv_seg = _seg_block(qseg_ref, kseg_ref, rows, cols)
+            s = _tile_mask(s, q0, k0, crossed, "q", limit=limit,
+                           q_seg=q_seg, kv_seg=kv_seg)
+            m_prev = m_scr[rows, :][:, :1]                   # [SQ, 1]
+            l_prev = l_scr[rows, :][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            # l (the softmax denominator) accumulates UNdropped p:
+            # dropout applies to normalized probabilities, after the
+            # softmax.
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            if dropout_rate > 0.0:
+                keep = _dropout_keep(seed_ref[0, 0], bh, q0, k0, p.shape,
+                                     dropout_rate)
+                p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+            acc_scr[rows, :] = alpha * acc_scr[rows, :] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (q.shape[0], LANES))
+            l_scr[rows, :] = jnp.broadcast_to(l_new, (q.shape[0], LANES))
 
-    @pl.when(_contributes(causal, q_start, k_start, block_q, q_seg, kv_seg))
+    @pl.when(_contributes(causal, q_start, k_start, block_q,
+                          *_seg_block(qseg_ref, kseg_ref)))
     def _compute():
-        # Matmul inputs stay in the storage dtype (bf16 on the training
-        # path) so the MXU runs at bf16 rate; accumulation and all softmax
-        # state are fp32 via preferred_element_type. Casting q/k/v to fp32
-        # here ran the dots at fp32 rate — 4x slower on v5e (round-3 fix).
-        q = q_ref[...]                                   # [BQ, D]
-        k = k_ref[...]                                   # [BK, D]
-        v = v_ref[...]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [BQ, BK] f32
-        s = _block_mask(s, q_start, k_start, causal=causal, limit=limit,
-                        q_seg=q_seg, kv_seg=kv_seg)
-
-        m_prev = m_scr[...][:, :1]                       # [BQ, 1]
-        l_prev = l_scr[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        # l (the softmax denominator) accumulates UNdropped p: dropout
-        # applies to normalized probabilities, after the softmax.
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0, 0], bh, q_start, k_start,
-                                 p.shape, dropout_rate)
-            p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        _walk(causal, q_start, k_start, block_q, block_k, "q", strips,
+              body)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -268,7 +412,8 @@ def _seg_specs(heads: int, block_q: int, block_k: int, *, q_axis, k_axis):
     """BlockSpecs for the expanded segment-id arrays. Segment ids are per
     BATCH element while the grid's axis 0 is the flattened batch*heads, so
     the index maps divide by `heads`. q_axis/k_axis pick which grid axis
-    (1 or 2) indexes q blocks vs k blocks (the dkv kernel swaps them)."""
+    (1 or 2) indexes q blocks vs k blocks (the backward's k-major grids
+    swap them)."""
     def qmap(b, i, j):
         g = (b, i, j)
         return (b // heads, g[q_axis], 0)
@@ -281,8 +426,16 @@ def _seg_specs(heads: int, block_q: int, block_k: int, *, q_axis, k_axis):
             pl.BlockSpec((None, SUBLANES, block_k), kmap))
 
 
+# A model calls these once a layer with the same shapes: under jit the
+# kernel body (a strip a tile, unrolled) is traced and lowered once a
+# program, not once a layer.
+_STATIC = ("scale", "causal", "kv_len", "block_q", "block_k", "interpret",
+           "dropout_rate", "heads", "strips")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("want_lse",))
 def _fwd(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len, block_q,
-         block_k, interpret, want_lse, dropout_rate, heads):
+         block_k, interpret, want_lse, dropout_rate, heads, strips=None):
     """q/k/v: [BH, T, D], T a multiple of the block size (flash_attention
     pads) -> (o [BH, Tq, D], lse [BH, Tq, LANES] f32 | None).
 
@@ -296,7 +449,7 @@ def _fwd(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len, block_q,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, limit=kv_len, want_lse=want_lse,
-        has_segs=has_segs, dropout_rate=dropout_rate)
+        has_segs=has_segs, dropout_rate=dropout_rate, strips=strips)
     o_spec = pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0))
     o_shape = jax.ShapeDtypeStruct((bh, t_q, d), q.dtype)
     in_specs = [
@@ -333,199 +486,248 @@ def _fwd(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len, block_q,
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_fwd",
     )(*inputs)
     return (out[0], out[1]) if want_lse else (out[0], None)
 
 
 # --------------------------------------------------------------------------
-# Backward: dq kernel (stream K/V per q block), dk/dv kernel (stream Q/dO
-# per k block). Standard flash recompute: p = exp(q·kᵀ·scale − lse).
-# With dropout, ds_ij = p_ij (keep_ij·dp_ij/(1-r) − delta_i) and dv uses
+# Backward. Standard flash recompute: p = exp(q·kᵀ·scale − lse), and with
+# dropout ds_ij = p_ij (keep_ij·dp_ij/(1-r) − delta_i), dv from
 # g_ij = keep_ij·p_ij/(1-r) — the delta_i = Σ do·o trick still holds
 # because o already includes the dropout.
+#
+# One kernel body, three schedules (`outputs`):
+# - "all", the one-pass backward (`flash_bwd`): grid (bh, k blocks,
+#   q blocks). s, p and dp of a tile are computed once and feed dq, dk
+#   and dv: five products and one exponent pass. dk/dv accumulate in f32
+#   scratch over the q blocks of a k block; dq accumulates in an f32
+#   scratch that holds the whole head's [t_q, d] and is written once, on
+#   the last k block; delta is computed once a q block, on the first.
+# - "dq" (`flash_dq`: grid (bh, q blocks, k blocks)) and "dkv"
+#   (`flash_dkv`: grid (bh, k blocks, q blocks)): the two-kernel path for
+#   sequences whose dq does not fit in VMEM; each recomputes s, p, dp.
 # --------------------------------------------------------------------------
 
-def _dq_kernel(*refs, scale: float, causal: bool, block_q: int,
-               block_k: int, limit: Optional[int], has_segs: bool,
-               dropout_rate: float):
-    it = iter(refs)
-    q_ref, k_ref, v_ref = next(it), next(it), next(it)
-    do_ref, o_ref, lse_ref = next(it), next(it), next(it)
-    qseg_ref = next(it) if has_segs else None
-    kseg_ref = next(it) if has_segs else None
-    seed_ref = next(it) if dropout_rate > 0.0 else None
-    dq_ref = next(it)
-    dq_scr = next(it)
-
-    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_start, k_start = qi * block_q, ki * block_k
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    q_seg, kv_seg = _seg_block(qseg_ref, kseg_ref)
-
-    @pl.when(_contributes(causal, q_start, k_start, block_q, q_seg, kv_seg))
-    def _compute():
-        # bf16 matmul inputs + fp32 accumulation (see _fwd_kernel note)
-        q = q_ref[...]
-        k = k_ref[...]
-        v = v_ref[...]
-        do = do_ref[...]
-        lse = jnp.max(lse_ref[...], axis=1, keepdims=True)  # lanes equal
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _block_mask(s, q_start, k_start, causal=causal, limit=limit,
-                        q_seg=q_seg, kv_seg=kv_seg)
-        p = jnp.exp(s - lse)                                # [BQ, BK] f32
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [BQ, BK]
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0, 0], bh, q_start, k_start,
-                                 p.shape, dropout_rate)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        do_f = do.astype(jnp.float32)
-        o = o_ref[...].astype(jnp.float32)
-        delta = jnp.sum(do_f * o, axis=1, keepdims=True)    # [BQ, 1]
-        ds = p * (dp - delta)
-        dq_scr[...] += scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+# What the one-pass backward keeps in VMEM for a whole head beside its
+# blocks: dq's f32 scratch and delta, each t_q x 128 lanes x 4 B (a d of
+# 64 pads to a vreg's 128 lanes), and dq's output block in the storage
+# dtype, double-buffered: t_q x 128 x (4 + 4 + 2 x 2) B = 1.5 KiB a
+# query row at bf16: 1.5 MiB at t_q 1,024, 6 MiB at 4,096, 24 MiB at
+# 16,384. Beside it at (1024, 1024) blocks, bf16, d <= 128: q, k, v, do
+# and o double-buffered 2.5 MiB, lse 1, dk and dv out 1, their f32
+# scratch 1, and a [1024, 256] strip's s, p and dp in f32 3: 8.5 MiB.
+# The compiler gives a kernel 16 MiB of VMEM unasked, so the head's
+# share may be 6 MiB (14.5 in all). The boundary compiles for a v5e:
+# t_q 4,096 at d 64 and 128 in bf16 (with segments and dropout too),
+# 3,072 at d 128 in f32, 2,048 at d 256 in bf16
+# (tests/test_chip_compile.py holds the first).
+ONE_PASS_VMEM_BYTES = 6 * 1024 * 1024
 
 
-def _dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
+def _one_pass_fits(t_q: int, d: int, itemsize: int) -> bool:
+    lanes = -(-d // LANES) * LANES
+    return t_q * lanes * (4 + 4 + 2 * itemsize) <= ONE_PASS_VMEM_BYTES
+
+
+def _bwd_kernel(*refs, scale: float, causal: bool, block_q: int,
                 block_k: int, limit: Optional[int], has_segs: bool,
-                dropout_rate: float):
+                dropout_rate: float, outputs: str, strips):
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     do_ref, o_ref, lse_ref = next(it), next(it), next(it)
     qseg_ref = next(it) if has_segs else None
     kseg_ref = next(it) if has_segs else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
-    dk_ref, dv_ref = next(it), next(it)
-    dk_scr, dv_scr = next(it), next(it)
+    want_dq, want_dkv = outputs != "dkv", outputs != "dq"
+    dq_ref = next(it) if want_dq else None
+    dk_ref, dv_ref = (next(it), next(it)) if want_dkv else (None, None)
+    dq_scr = next(it) if want_dq else None
+    dk_scr, dv_scr = (next(it), next(it)) if want_dkv else (None, None)
+    delta_scr = next(it)
 
-    bh, ki, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    if outputs == "dq":           # q-major grid
+        bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        nk = pl.num_programs(2)
+    else:                         # k-major grid
+        bh, ki, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        nk, nq = pl.num_programs(1), pl.num_programs(2)
     q_start, k_start = qi * block_q, ki * block_k
+    # the one-pass scratches hold the whole head: this block's rows in them
+    head_rows = pl.multiple_of(q_start, block_q) if outputs == "all" else 0
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    def _delta():
+        delta = jnp.sum(do_ref[...].astype(jnp.float32)
+                        * o_ref[...].astype(jnp.float32),
+                        axis=1, keepdims=True)              # [BQ, 1]
+        delta_scr[pl.ds(head_rows, block_q), :] = jnp.broadcast_to(
+            delta, (block_q, LANES))
 
-    q_seg, kv_seg = _seg_block(qseg_ref, kseg_ref)
+    if want_dq:
+        @pl.when(ki == 0)
+        def _init_q():
+            dq_scr[pl.ds(head_rows, block_q), :] = jnp.zeros(
+                (block_q, dq_scr.shape[1]), jnp.float32)
+            _delta()                                # once a q block
 
-    @pl.when(_contributes(causal, q_start, k_start, block_q, q_seg, kv_seg))
-    def _compute():
-        # bf16 matmul inputs + fp32 accumulation (see _fwd_kernel note)
-        q = q_ref[...]
-        k = k_ref[...]
-        v = v_ref[...]
-        do = do_ref[...]
-        lse = jnp.max(lse_ref[...], axis=1, keepdims=True)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [BQ, BK]
-        s = _block_mask(s, q_start, k_start, causal=causal, limit=limit,
-                        q_seg=q_seg, kv_seg=kv_seg)
-        p = jnp.exp(s - lse)
-        keep = None
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0, 0], bh, q_start, k_start,
-                                 p.shape, dropout_rate)
-            g = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-        else:
+    if want_dkv:
+        @pl.when(qi == 0)
+        def _init_k():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    by = "q" if outputs == "dq" else "k"
+
+    def body(tiles):
+        # s, p, dp and ds of a tile once; the products that need them. A
+        # strip of keys is one tile, so its dk and dv come from one
+        # product each; so does a strip of rows' dq on the q-major grid.
+        # The one pass adds a tile's dq into the head's scratch.
+        for rows, cols, crossed in tiles:
+            q0, k0 = q_start + rows.start, k_start + cols.start
+            # bf16 matmul inputs + fp32 accumulation (see _fwd_kernel note)
+            q = q_ref[rows, :]
+            k = k_ref[cols, :]
+            v = v_ref[cols, :]
+            do = do_ref[rows, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # [R, C]
+            q_seg, kv_seg = _seg_block(qseg_ref, kseg_ref, rows, cols)
+            s = _tile_mask(s, q0, k0, crossed, by, limit=limit,
+                           q_seg=q_seg, kv_seg=kv_seg)
+            p = jnp.exp(_sub_rows(s, lse_ref[rows, :]))         # lanes equal
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [R, C]
             g = p
-        g_lo = g.astype(do.dtype)
-        dv_scr[...] += jax.lax.dot_general(
-            g_lo, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [BK, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [BQ, BK]
-        if keep is not None:
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        do_f = do.astype(jnp.float32)
-        o = o_ref[...].astype(jnp.float32)
-        delta = jnp.sum(do_f * o, axis=1, keepdims=True)
-        ds = p * (dp - delta)
-        dk_scr[...] += scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [BK, D]
+            if dropout_rate > 0.0:
+                keep = _dropout_keep(seed_ref[0, 0], bh, q0, k0, p.shape,
+                                     dropout_rate)
+                g = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+                dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
+            head = pl.ds(head_rows + rows.start, rows.stop - rows.start)
+            ds = (p * _sub_rows(dp, delta_scr[head, :])).astype(q.dtype)
+            if want_dkv:
+                dv_scr[cols, :] += jax.lax.dot_general(
+                    g.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # [C, D]
+                dk_scr[cols, :] += jax.lax.dot_general(
+                    ds, q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # [C, D]
+            if want_dq:
+                dq_scr[head, :] += jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # [R, D]
 
-    @pl.when(qi == nq - 1)
-    def _finalize():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+    @pl.when(_contributes(causal, q_start, k_start, block_q,
+                          *_seg_block(qseg_ref, kseg_ref)))
+    def _compute():
+        if outputs == "dkv":
+            _delta()      # q blocks are the inner axis: once a grid cell
+        _walk(causal, q_start, k_start, block_q, block_k, by, strips,
+              body)
+
+    if want_dq:
+        @pl.when(ki == nk - 1)
+        def _finalize_q():
+            rows = pl.ds(head_rows, block_q)
+            dq_ref[rows, :] = (scale * dq_scr[rows, :]).astype(dq_ref.dtype)
+
+    if want_dkv:
+        @pl.when(qi == nq - 1)
+        def _finalize_k():
+            dk_ref[...] = (scale * dk_scr[...]).astype(dk_ref.dtype)
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd_impl(q, k, v, o, lse, do, q_seg, kv_seg, seed, scale, causal,
-              kv_len, block_q, block_k, interpret, dropout_rate, heads):
+@functools.partial(jax.jit, static_argnames=("outputs",) + _STATIC)
+def _bwd_call(outputs, q, k, v, o, lse, do, q_seg, kv_seg, seed, scale,
+              causal, kv_len, block_q, block_k, interpret, dropout_rate,
+              heads, strips=None):
+    """One backward Pallas call; `outputs` as `_bwd_kernel` has it."""
     bh, t_q, d = q.shape
     t_k = k.shape[1]
     has_segs = q_seg is not None
-    common = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, limit=kv_len, has_segs=has_segs,
-                  dropout_rate=dropout_rate)
-    seg_inputs = []
-    if has_segs:
-        seg_inputs = list(_expand_segs(q_seg, kv_seg))
-    seed_inputs = [seed] if dropout_rate > 0.0 else []
+    nq, nk = pl.cdiv(t_q, block_q), pl.cdiv(t_k, block_k)
+    # grid axes 1 and 2: which of them walks q blocks, which k blocks
+    q_axis, k_axis = (1, 2) if outputs == "dq" else (2, 1)
 
-    q_spec = pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0))
-    lse_spec = pl.BlockSpec((None, block_q, LANES), lambda b, i, j: (b, i, 0))
-    kj_spec = pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0))
-    dq_in_specs = [q_spec, kj_spec, kj_spec, q_spec, q_spec, lse_spec]
-    if has_segs:
-        qspec, kspec = _seg_specs(heads, block_q, block_k, q_axis=1,
-                                  k_axis=2)
-        dq_in_specs += [qspec, kspec]
-    if dropout_rate > 0.0:
-        dq_in_specs.append(_smem_spec())
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        grid=(bh, pl.cdiv(t_q, block_q), pl.cdiv(t_k, block_k)),
-        in_specs=dq_in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[_scratch((block_q, d))],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-    )(q, k, v, do, o, lse, *seg_inputs, *seed_inputs)
+    def block_of(axis):
+        return lambda *g: (g[0], g[axis], 0)
 
-    qj_spec = pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, j, 0))
-    lsej_spec = pl.BlockSpec((None, block_q, LANES),
-                             lambda b, i, j: (b, j, 0))
-    ki_spec = pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, i, 0))
-    dkv_in_specs = [qj_spec, ki_spec, ki_spec, qj_spec, qj_spec, lsej_spec]
+    q_spec = pl.BlockSpec((None, block_q, d), block_of(q_axis))
+    k_spec = pl.BlockSpec((None, block_k, d), block_of(k_axis))
+    lse_spec = pl.BlockSpec((None, block_q, LANES), block_of(q_axis))
+    in_specs = [q_spec, k_spec, k_spec, q_spec, q_spec, lse_spec]
+    inputs = [q, k, v, do, o, lse]
     if has_segs:
-        # dkv grid is (bh, k_blocks, q_blocks): q blocks ride grid axis 2
-        qspec, kspec = _seg_specs(heads, block_q, block_k, q_axis=2,
-                                  k_axis=1)
-        dkv_in_specs += [qspec, kspec]
+        in_specs += _seg_specs(heads, block_q, block_k, q_axis=q_axis,
+                               k_axis=k_axis)
+        inputs += _expand_segs(q_seg, kv_seg)
     if dropout_rate > 0.0:
-        dkv_in_specs.append(_smem_spec())
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common),
-        grid=(bh, pl.cdiv(t_k, block_k), pl.cdiv(t_q, block_q)),
-        in_specs=dkv_in_specs,
-        out_specs=[ki_spec, ki_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+        in_specs.append(_smem_spec())
+        inputs.append(seed)
+
+    # dq's accumulator and delta cover the rows dq stays in VMEM for: the
+    # whole head in the one pass (its output block too), else a q block
+    held = t_q if outputs == "all" else block_q
+    out_specs, out_shape, scratch = [], [], []
+    if outputs != "dkv":
+        out_specs.append(
+            pl.BlockSpec((None, t_q, d), lambda b, i, j: (b, 0, 0))
+            if outputs == "all" else q_spec)
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch.append(_scratch((held, d)))
+    if outputs != "dq":
+        out_specs += [k_spec, k_spec]
+        out_shape += [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                      jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        scratch += [_scratch((block_k, d)), _scratch((block_k, d))]
+    scratch.append(_scratch((held, LANES)))
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, limit=kv_len, has_segs=has_segs,
+            dropout_rate=dropout_rate, outputs=outputs, strips=strips),
+        grid=(bh, nq, nk) if outputs == "dq" else (bh, nk, nq),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(
+            "parallel", "arbitrary" if outputs == "all" else "parallel",
+            "arbitrary"),
         interpret=interpret,
-    )(q, k, v, do, o, lse, *seg_inputs, *seed_inputs)
+        name={"all": "flash_bwd", "dq": "flash_dq",
+              "dkv": "flash_dkv"}[outputs],
+    )(*inputs)
+
+
+def _bwd_one_pass(*args):
+    """dq, dk, dv from one Pallas call (`flash_bwd`); the arguments are
+    `_bwd_impl`'s."""
+    return tuple(_bwd_call("all", *args))
+
+
+def _bwd_two_kernels(*args):
+    """dq, dk, dv from two Pallas calls (`flash_dq`, `flash_dkv`), each
+    of which recomputes the scores: for a head whose dq the one pass
+    cannot hold in VMEM."""
+    (dq,) = _bwd_call("dq", *args)
+    dk, dv = _bwd_call("dkv", *args)
     return dq, dk, dv
+
+
+def _bwd_impl(q, k, v, o, lse, do, q_seg, kv_seg, seed, scale, causal,
+              kv_len, block_q, block_k, interpret, dropout_rate, heads,
+              strips=None):
+    """The backward for [BH, T, D] operands; which schedule runs follows
+    from the shapes alone, at trace time."""
+    one_pass = _one_pass_fits(q.shape[1], q.shape[2], q.dtype.itemsize)
+    return (_bwd_one_pass if one_pass else _bwd_two_kernels)(
+        q, k, v, o, lse, do, q_seg, kv_seg, seed, scale, causal, kv_len,
+        block_q, block_k, interpret, dropout_rate, heads, strips)
 
 
 # --------------------------------------------------------------------------
@@ -534,29 +736,31 @@ def _bwd_impl(q, k, v, o, lse, do, q_seg, kv_seg, seed, scale, causal,
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14))
 def _flash_core(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len,
-                block_q, block_k, interpret, dropout_rate, heads):
+                block_q, block_k, interpret, dropout_rate, heads,
+                strips=None):
     o, _ = _fwd(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len,
                 block_q, block_k, interpret, want_lse=False,
-                dropout_rate=dropout_rate, heads=heads)
+                dropout_rate=dropout_rate, heads=heads, strips=strips)
     return o
 
 
 def _flash_core_fwd(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len,
-                    block_q, block_k, interpret, dropout_rate, heads):
+                    block_q, block_k, interpret, dropout_rate, heads,
+                    strips):
     o, lse = _fwd(q, k, v, q_seg, kv_seg, seed, scale, causal, kv_len,
                   block_q, block_k, interpret, want_lse=True,
-                  dropout_rate=dropout_rate, heads=heads)
+                  dropout_rate=dropout_rate, heads=heads, strips=strips)
     return o, (q, k, v, o, lse, q_seg, kv_seg, seed)
 
 
 def _flash_core_bwd(scale, causal, kv_len, block_q, block_k, interpret,
-                    dropout_rate, heads, res, do):
+                    dropout_rate, heads, strips, res, do):
     q, k, v, o, lse, q_seg, kv_seg, seed = res
     dq, dk, dv = _bwd_impl(q, k, v, o, lse, do, q_seg, kv_seg, seed, scale,
                            causal, kv_len, block_q, block_k, interpret,
-                           dropout_rate, heads)
+                           dropout_rate, heads, strips)
     return dq, dk, dv, None, None, None
 
 
@@ -631,8 +835,11 @@ def flash_attention(q, k, v, mask=None, scale: Optional[float] = None,
     # Segment ids pad with -1: real ids are >= 0 so real rows never attend
     # the pad tail, while pad q rows match pad kv columns (keeps their
     # denominators non-degenerate; those rows are sliced off below).
-    block_q = min(block_q, t_q)
-    block_k = min(block_k, t_k)
+    # A block is no longer than its sequence rounded up to the lane width:
+    # a length such as 1,000 pads to one aligned 1,024 block under kv_len,
+    # so the strips apply and no tile is [1000, 1000].
+    block_q = min(block_q, -(-t_q // LANES) * LANES)
+    block_k = min(block_k, -(-t_k // LANES) * LANES)
     pad_q = -t_q % block_q
     pad_k = -t_k % block_k
     if pad_k and kv_len is None and kv_seg is None:

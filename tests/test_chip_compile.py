@@ -267,11 +267,25 @@ def test_latent_ragged_kernel_compiles_for_v5e(one_chip):
     assert "ragged_latent_attention" in text and "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_flash_kernel_compiles_for_v5e(one_chip, grad):
+@pytest.mark.parametrize("shape,grad,kernels", [
+    ((1, 1024, 16, 64), False, ("flash_fwd",)),
+    ((1, 1024, 16, 64), True, ("flash_fwd", "flash_bwd")),
+    # gpt2m-train-1k's own shape: the backward is the one pass
+    ((8, 1024, 16, 64), True, ("flash_fwd", "flash_bwd")),
+    # the longest head the one pass takes (ONE_PASS_VMEM_BYTES)
+    ((1, 4096, 8, 64), True, ("flash_fwd", "flash_bwd")),
+    # a length no block divides: one 1,024 block, padded, in strips
+    ((1, 1000, 8, 64), True, ("flash_fwd", "flash_bwd")),
+    # lm_longctx's: a head's dq does not fit in VMEM, so two kernels
+    ((1, 16384, 8, 64), True, ("flash_fwd", "flash_dq", "flash_dkv")),
+], ids=["fwd", "grad", "grad-train_cell", "grad-4k", "grad-1000",
+        "grad-16k"])
+def test_flash_kernel_compiles_for_v5e(one_chip, shape, grad, kernels):
+    """The flash kernels at real widths, and the rule that picks the
+    backward's schedule from the shapes alone: the compiled text names
+    the Pallas calls that ran."""
     from paddle_tpu.kernels.flash import flash_attention
-    q = jax.ShapeDtypeStruct((1, 1024, 16, 64), jnp.bfloat16,
-                             sharding=one_chip)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False)
@@ -279,7 +293,10 @@ def test_flash_kernel_compiles_for_v5e(one_chip, grad):
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    assert _compiles_with_kernel(fn, q, q, q)
+    text = jax.jit(fn).lower(q, q, q).compile().as_text()
+    assert "tpu_custom_call" in text
+    for name in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv"):
+        assert (name in text) == (name in kernels), name
 
 
 def test_gpipe_backward_keeps_its_psum_in_the_tick_loop(topo):
