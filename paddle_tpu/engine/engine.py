@@ -84,7 +84,8 @@ from paddle_tpu.kernels.paged_attention import (pack_kv, ragged_span,
                                                 unpack_kv)
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
 from paddle_tpu.obs.tracing import RequestTracer
-from paddle_tpu.profiler.profiler import annotate, now_us
+from paddle_tpu.profiler.profiler import annotate, gc_total_us, now_us, \
+    watch_gc
 from paddle_tpu.quant.int8_compute import dequantize_block, quantize_block
 from paddle_tpu.utils.log import serve_event
 
@@ -553,6 +554,10 @@ class ServeEngine:
         self.finished: Dict[int, Request] = {}
         self.steps = 0
         self._plan_us = 0.0     # `engine.plan`'s opening stamp (step())
+        # the collector's stamps (the profiler's; one callback a
+        # process), and what of their sum the last step had seen
+        watch_gc()
+        self._gc_seen_us = gc_total_us()
         # tokens per (expert layer, expert) since construction
         self.expert_tokens = np.zeros(
             (getattr(model, "expert_layers", 0),
@@ -930,10 +935,14 @@ class ServeEngine:
             with annotate("engine.publish", step=step):
                 self._publish(chunks, decodes, chunk_tokens, drafted,
                               accepted, asked)
+            # every collection since the last step closed, on whichever
+            # thread: each held the interpreter, so each stopped the loop
+            gc_seen, self._gc_seen_us = self._gc_seen_us, gc_total_us()
             span.set(decode_rows=len(decodes), chunk_rows=len(chunks),
                      chunk_tokens=chunk_tokens,
                      queue_depth=self.scheduler.queue_depth,
-                     used_blocks=self.cache.used_blocks, **asked)
+                     used_blocks=self.cache.used_blocks,
+                     gc_us=self._gc_seen_us - gc_seen, **asked)
         # "spec" wins over mixed/decode so the speculation-on latency
         # distribution is separable from plain decode's
         kind = ("spec" if drafted
@@ -1369,8 +1378,16 @@ class ServeEngine:
             # three numbers a row come down; the logits stay on the
             # device unless a row samples from its own on the host
             wants = [row.samples and _needs_logits(row.req) for row in rows]
-            fetched = jax.device_get(
-                (*picks, per_expert, logits if any(wants) else None))
+            down = (*picks, per_expert, logits if any(wants) else None)
+            # the copies are asked for first, as `device_get` asks: they
+            # follow the step on the device's queue and cost no round
+            # trip of their own once the wait has returned
+            for leaf in jax.tree.leaves(down):
+                leaf.copy_to_host_async()
+            # the wait for the device's step apart from what follows it
+            with annotate("engine.wait", step=step):
+                jax.block_until_ready(picks)
+            fetched = jax.device_get(down)
             lse, ids, top, per_expert, logits = fetched
             span.set(bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)))
             if logits is not None:

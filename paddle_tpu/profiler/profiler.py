@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import gc
 import json
 import os
 import threading
@@ -29,14 +30,19 @@ import jax
 
 from paddle_tpu.utils.log import vlog
 
-# 90 s of the busiest serving cell fit nearly twice over: a step leaves
-# 10 spans and two steps in three a request record, 390 entries a second
-# at 36 steps a second (PERF.md, PR 30: at 16,384 the ring no longer
-# reached back over a 40 s window and its tail); at 50 idle
-# `frontdoor.wait` spans a second it reaches back twenty minutes
-RING_SPANS = 65536
-_lock = threading.Lock()
-# completed spans: name/ts/dur/tid (us) and args
+# The busiest serving cell's window and its tail (some 50 s) fit nearly
+# three times over: a step leaves 12 spans (`engine.step`, its seven
+# children, `engine.wait`, `frontdoor.control`, `.finish`, `.deliver`)
+# and two steps in three a request record, 570 entries a second at the
+# 45 steps a second of a 22.2 ms cycle (PERF.md, PR 32), so the ring
+# reaches back 143 s (at 65,536 it was 115; PR 30: at 16,384 it no
+# longer reached back over a 40 s window and its tail); at 50 idle
+# `frontdoor.wait` spans a second it reaches back 27 minutes
+RING_SPANS = 81920
+# re-entrant: a collection can start on a thread that holds the lock
+# (`get_events` builds a list), and its callback files a record
+_lock = threading.RLock()
+# completed spans: name/ts/dur/cpu/tid (us) and args
 _events: Deque[dict] = deque(maxlen=RING_SPANS)   # guarded-by: _lock
 _enabled = False
 _trace_dir: Optional[str] = None
@@ -53,14 +59,71 @@ def now_us() -> float:
     return (_EPOCH_NS + time.perf_counter_ns()) / 1e3
 
 
-def record(name: str, ts: float, dur: float, **args) -> None:
+# The thread's CPU clock is a system call (0.24 us here, 6 us on the
+# machine with the chip, where it also ticks only every 10 ms: PERF.md,
+# PR 35), and a span's opening usually follows the closing of the one
+# before within a few microseconds: a reading under CPU_REUSE_US old is
+# given again, so a step's thirteen spans read the clock some dozen
+# times, not twenty-six, and a span's `cpu` is off by that much at most
+CPU_REUSE_US = 20.0
+_cpu_read = threading.local()   # .last: (now_us, ns) of the thread's reading
+
+
+def _thread_cpu_ns(at_us: float) -> int:
+    last = getattr(_cpu_read, "last", None)
+    if last is not None and at_us - last[0] < CPU_REUSE_US:
+        return last[1]
+    ns = time.thread_time_ns()
+    _cpu_read.last = (at_us, ns)
+    return ns
+
+
+def record(name: str, ts: float, dur: float, cpu: Optional[float] = None,
+           **args) -> None:
     """Append one finished span (or record: `obs/tracing.py` files a
     `request` here when it finishes) to the ring, stamps on `now_us`,
-    under the calling thread's id."""
-    ev = {"name": name, "ts": ts, "dur": dur,
+    under the calling thread's id. `cpu` is the microseconds of CPU
+    time the span's thread got between the two stamps (None for a
+    record that is no stretch of one thread): `dur - cpu` is how long
+    the thread was off the CPU, blocked in a call that waits or
+    waiting for the interpreter."""
+    ev = {"name": name, "ts": ts, "dur": dur, "cpu": cpu,
           "tid": threading.get_native_id(), "args": args}
     with _lock:
         _events.append(ev)
+
+
+# a collection stops every thread, whichever one ran it: its
+# microseconds go to ONE sum, which only grows (collections never
+# overlap, so the callback is its one writer) and of which each engine
+# takes what is new as its step closes (`gc_us`)
+GC_RECORD_US = 200.0
+_gc_start_ns = 0
+_gc_total_us = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_start_ns, _gc_total_us
+    if phase == "start":
+        _gc_start_ns = time.perf_counter_ns()
+        return
+    dur = (time.perf_counter_ns() - _gc_start_ns) / 1e3
+    _gc_total_us += dur
+    if dur >= GC_RECORD_US:
+        record("runtime.gc", (_EPOCH_NS + _gc_start_ns) / 1e3, dur,
+               generation=info["generation"], collected=info["collected"])
+
+
+def watch_gc() -> None:
+    """Put the collector's stamps on the ring (`ServeEngine` calls this
+    when it is built; once a process, however often it is called)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_total_us() -> float:
+    """Microseconds of every collection since `watch_gc`."""
+    return _gc_total_us
 
 
 class RecordEvent:
@@ -109,17 +172,19 @@ class annotate:
     trace (`jax.profiler.TraceAnnotation(name, **args)`: on the
     timeline of the device's operations whenever a `jax.profiler`
     session is on, a flag test when none is), and ALWAYS to the ring,
-    on `now_us`: two clock readings, `ts` when it opens and `dur` when
-    it closes, both readable afterwards. `args` are what is known when
+    on `now_us`: two readings of the clock and of the thread's CPU
+    time (`_thread_cpu_ns`), `ts` when it opens, `dur` and `cpu` when
+    it closes, all readable afterwards. `args` are what is known when
     it opens; `set()` adds the counts known only when it closes (those
     reach the ring's entry alone). `discard()` keeps a span that turned
     out to bracket nothing (an idle engine step) out of the ring."""
 
-    __slots__ = ("name", "args", "ts", "dur", "_trace", "_keep")
+    __slots__ = ("name", "args", "ts", "dur", "cpu", "_cpu_ns", "_trace",
+                 "_keep")
 
     def __init__(self, name: str, **args):
         self.name, self.args = name, args
-        self.ts = self.dur = 0.0
+        self.ts = self.dur = self.cpu = 0.0
         self._keep = True
         self._trace = jax.profiler.TraceAnnotation(name, **args)
 
@@ -132,13 +197,16 @@ class annotate:
     def __enter__(self) -> "annotate":
         self._trace.__enter__()
         self.ts = now_us()
+        self._cpu_ns = _thread_cpu_ns(self.ts)
         return self
 
     def __exit__(self, *exc) -> bool:
-        self.dur = now_us() - self.ts
+        end = now_us()
+        self.dur = end - self.ts
         self._trace.__exit__(*exc)
-        if self._keep:
-            record(self.name, self.ts, self.dur, **self.args)
+        if self._keep:      # a discarded span's thread clock is not read
+            self.cpu = (_thread_cpu_ns(end) - self._cpu_ns) / 1e3
+            record(self.name, self.ts, self.dur, self.cpu, **self.args)
         return False
 
 

@@ -60,7 +60,9 @@ frame the moment it has one and wakes the consumers ONCE A STEP: one
 `loop.call_soon_threadsafe` hands the event loop every stream that got
 a frame in this iteration of the engine loop (`_HandOver`), so the row
 loop of a step never gives the interpreter away between two of its
-tokens. Thousands of idle SSE streams cost
+tokens; the event loop's thread files one `frontdoor.deliver` record a
+hand-over (when it woke, how long until the woken handlers had written
+their frames, how many). Thousands of idle SSE streams cost
 coroutines, not OS threads — `ptpu_serve_conn_threads` stays flat
 while `ptpu_serve_open_connections` climbs. Disconnects come from the
 transport (a parked read resolves on peer close); writes are
@@ -105,7 +107,7 @@ from paddle_tpu.engine.scheduler import Request
 from paddle_tpu.obs.flightrec import FlightRecorder
 from paddle_tpu.obs.http import json_route, obs_response
 from paddle_tpu.obs.slo import SLOMonitor
-from paddle_tpu.profiler.profiler import annotate, now_us
+from paddle_tpu.profiler.profiler import annotate, now_us, record
 from paddle_tpu.resilience.errors import PREEMPT_EXIT_CODE
 from paddle_tpu.resilience.supervisor import RunSupervisor
 from paddle_tpu.serve.aio import AioConnection, AioRequest, \
@@ -119,18 +121,39 @@ from paddle_tpu.utils.log import serve_event
 _DIR_INTERVAL_S = 0.25   # default /kvprefixes + /debug refresh cadence
 
 
-def _set_events(events) -> None:
+def _set_events(events, note=None) -> None:
+    """On the event loop's thread: wake the consumers parked on
+    `events`. With the `note` of an engine-loop hand-over, also open
+    its `frontdoor.deliver`: `_delivered` is queued behind the woken
+    consumers, each of which writes every frame it was handed in its
+    one turn (a write yields only on a transport that is paused)."""
+    opened = note and (now_us(), time.thread_time_ns(), note[0].written)
     for ev in events:
         ev.set()
+    if opened:
+        asyncio.get_running_loop().call_soon(
+            _delivered, note, *opened, len(events))
 
 
-def _wake(loop: asyncio.AbstractEventLoop, events) -> None:
+def _delivered(note, ts: float, cpu_ns: int, written: int,
+               streams: int) -> None:
+    """Close a hand-over's `frontdoor.deliver` (OBSERVABILITY.md "Host
+    spans"): from `_set_events` to here on the event loop's thread,
+    under the engine step that caused it."""
+    handover, step, flushed_us = note
+    record("frontdoor.deliver", ts, now_us() - ts,
+           (time.thread_time_ns() - cpu_ns) / 1e3, step=step,
+           streams=streams, frames=handover.written - written,
+           wake_us=ts - flushed_us)
+
+
+def _wake(loop: asyncio.AbstractEventLoop, events, note=None) -> None:
     """One cross-thread wake-up for all of `events` (a no-op once the
     loop is closed: teardown). `call_soon_threadsafe` writes a byte to
     the loop's self-pipe — a system call, which gives the interpreter
     to whoever waits for it — so it is made once for many events."""
     try:
-        loop.call_soon_threadsafe(_set_events, events)
+        loop.call_soon_threadsafe(_set_events, events, note)
     except RuntimeError:
         pass
 
@@ -142,26 +165,32 @@ class _HandOver:
     (inside `frontdoor.finish`, after the step's tokens and done
     frames are all enqueued), again for what an iteration pushed
     outside a step, and on every exit, so nothing stays parked.
-    `tid` is the engine loop's thread; `pending` belongs to it."""
+    `tid` is the engine loop's thread; `pending` belongs to it;
+    `written`, the frames the handlers have written to their sockets,
+    belongs to the event loop's thread."""
 
-    __slots__ = ("tid", "pending")
+    __slots__ = ("tid", "pending", "written")
 
     def __init__(self):
         self.tid: Optional[int] = None
         self.pending: set = set()
+        self.written = 0
 
-    def flush(self) -> int:
+    def flush(self, step: Optional[int] = None) -> int:
         """Wake every noted stream's consumer, one
-        `call_soon_threadsafe` per event loop; returns the streams
-        woken."""
+        `call_soon_threadsafe` per event loop, each with this
+        hand-over's note (the engine step that caused it, if one did,
+        and the stamp of its leaving: `frontdoor.deliver`); returns the
+        streams woken."""
         if not self.pending:
             return 0
         pending, self.pending = self.pending, set()
         by_loop: Dict[asyncio.AbstractEventLoop, list] = {}
         for s in pending:
             by_loop.setdefault(s.loop, []).append(s.ev)
+        note = (self, step, now_us())
         for loop, events in by_loop.items():
-            _wake(loop, events)
+            _wake(loop, events, note)
         return len(pending)
 
 
@@ -581,7 +610,7 @@ class ServeFrontend:
                 progressed = False
                 if eng.scheduler.has_work():
                     progressed = self._step_once()
-                    self._flush_finished()
+                    self._flush_finished(eng.steps if progressed else None)
                 now = time.monotonic()
                 if now >= self._dir_next:
                     self._dir_next = now + self.dir_interval_s
@@ -771,20 +800,22 @@ class ServeFrontend:
         best = max(infos, key=lambda c: c["logprob"])
         return best["index"], infos
 
-    def _hand_over(self) -> int:
+    def _hand_over(self, step: Optional[int] = None) -> int:
         """Wake the consumers of every stream that got a frame since
-        the last hand-over (engine-loop thread only); returns their
+        the last hand-over (engine-loop thread only), on behalf of
+        engine step `step` where one made the frames; returns their
         number."""
-        woken = self._handover.flush()
+        woken = self._handover.flush(step)
         if woken:
             self._m_wakeups.inc()
         return woken
 
-    def _flush_finished(self) -> None:
+    def _flush_finished(self, step: Optional[int] = None) -> None:
         """Push done frames for request GROUPS the last step finished
         (for n > 1 the frame waits until every candidate is done),
         then hand the step's frames over: a request's done frame
-        leaves in the same wake-up as its last token."""
+        leaves in the same wake-up as its last token. `step` is the
+        engine step that just ran, if one did."""
         with annotate("frontdoor.finish") as span:
             with self._lock:
                 done = [(rid, s) for rid, s in self._active.items()
@@ -804,7 +835,7 @@ class ServeFrontend:
                              # silent best_of-only candidates stay
                              # server-side; the wire sees n candidates
                              "candidates": cands[:n_stream]}))
-            span.set(closed=len(done), woken=self._hand_over())
+            span.set(closed=len(done), woken=self._hand_over(step))
 
     def _drain_finished(self) -> bool:
         """True once every in-flight stream completed (or the deadline
@@ -1180,6 +1211,7 @@ class ServeFrontend:
                             stream.req.req_id, t1)
                     stream.cand_pos[cand] = pos + 1
                     stream.streamed += 1
+                    self._handover.written += 1
                 elif item[0] == "done":
                     _, reason, tokens, extra = item
                     frame = {"done": True, "reason": reason,
@@ -1191,11 +1223,13 @@ class ServeFrontend:
                         frame.update(extra)
                     await conn.write(sse_event(frame)
                                      + sse_event(DONE_SENTINEL))
+                    self._handover.written += 1
                     return
                 else:                              # ("error", msg)
                     await conn.write(sse_event(
                         {"error": item[1], "done": True,
                          "reason": "error"}) + sse_event(DONE_SENTINEL))
+                    self._handover.written += 1
                     return
         except SlowClientError:
             # the peer stopped draining: its transport is already

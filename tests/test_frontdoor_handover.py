@@ -239,10 +239,10 @@ def loop_log(monkeypatch):
         log.append("control")
         control(self)
 
-    def logged_flush(self):
+    def logged_flush(self, step=None):
         if self.pending:
             log.append(("flush", len(self.pending)))
-        return flush(self)
+        return flush(self, step)
     monkeypatch.setattr(ServeFrontend, "_drain_control_queues",
                         logged_control)
     monkeypatch.setattr(_HandOver, "flush", logged_flush)

@@ -135,7 +135,9 @@ def test_ring_is_bounded_and_outlives_engine_and_front_end(model_and_vars):
         prof.record("filler", 0.0, 0.0)
     events = prof.get_events()
     assert len(events) == prof.RING_SPANS
-    assert {e["name"] for e in events} == {"filler"}
+    # (a collection that ran meanwhile may have filed its own record)
+    assert {"filler"} <= {e["name"] for e in events} <= \
+        {"filler", "runtime.gc"}
     prof.reset_profiler()
 
 
@@ -291,7 +293,8 @@ def test_step_reads_the_clock_at_most_twice_a_span(drained):
         while eng.step():
             steps += 1
     spans = [e for e in prof.get_events() if e["name"].startswith("engine.")]
-    assert steps >= 4 and len(spans) == 8 * steps
+    # `engine.step`, its seven children and `engine.wait`
+    assert steps >= 4 and len(spans) == 9 * steps
     assert _spans(prof.get_events(), "request")
     # the idle call that ended the loop opened two spans it discarded;
     # another test's front end may be waiting on its own thread
@@ -300,6 +303,209 @@ def test_step_reads_the_clock_at_most_twice_a_span(drained):
     # and the engine has no other clock
     source = inspect.getsource(engine_mod)
     assert "perf_counter" not in source and "time.monotonic" not in source
+
+
+def test_every_span_says_how_long_its_thread_ran(drained):
+    """`cpu` is the opening thread's CPU time between the span's two
+    stamps: never more than `dur` (but for the two clocks' grain);
+    a record that is no stretch of one thread has None."""
+    _, events = drained
+    assert all({"name", "ts", "dur", "cpu", "tid", "args"} == set(e)
+               for e in events)
+    spans = [e for e in events if e["name"] not in ("request", "runtime.gc")]
+    assert len(spans) >= 9 * 6
+    for e in spans:
+        assert 0.0 <= e["cpu"] <= e["dur"] + 50.0, e
+    assert [e["cpu"] for e in _spans(events, "request")] == \
+        [None] * len(PROMPTS)
+    # a parent ran at least as long as its children did
+    for st in _spans(events, "engine.step"):
+        kids = [e for e in events if e["name"] in CHILDREN
+                and e["args"]["step"] == st["args"]["step"]]
+        assert sum(k["cpu"] for k in kids) <= st["cpu"] + 50.0
+
+
+def test_a_span_that_sleeps_was_off_the_cpu():
+    prof.reset_profiler()
+    with prof.annotate("test.sleeps") as span:
+        time.sleep(0.05)
+    (ev,) = _spans(prof.get_events(), "test.sleeps")
+    assert ev["dur"] >= 50e3 and ev["cpu"] == span.cpu < 0.1 * ev["dur"]
+    # and one that computes was on it
+    with prof.annotate("test.computes"):
+        deadline = time.thread_time() + 0.02
+        while time.thread_time() < deadline:
+            pass
+    (ev,) = _spans(prof.get_events(), "test.computes")
+    assert 20e3 <= ev["cpu"] <= ev["dur"] + 50.0
+
+
+def test_a_reading_of_the_thread_clock_serves_the_boundaries_beside_it():
+    """The thread's CPU clock is a system call (6 us on the machine with
+    the chip): a reading under `CPU_REUSE_US` old, by `now_us`, is given
+    again, so a parent's and its first child's openings share one, and
+    the last child's and the parent's closings."""
+    real, reads = time.thread_time_ns, []
+
+    def nested(stamps):
+        clock = iter(stamps)
+        del reads[:]
+        prof._cpu_read.__dict__.clear()
+        with mock.patch.object(prof, "now_us", lambda: next(clock)), \
+                mock.patch.object(prof.time, "thread_time_ns",
+                                  lambda: reads.append(1) or real()):
+            with prof.annotate("test.outer"):
+                with prof.annotate("test.inner"):
+                    pass
+        return len(reads)
+    near = prof.CPU_REUSE_US / 4
+    assert nested([0.0, near, 1000.0, 1000.0 + near]) == 2
+    far = prof.CPU_REUSE_US * 2
+    assert nested([0.0, far, 1000.0, 1000.0 + far]) == 4
+    # a reading is another thread's business never
+    seen = []
+    t = threading.Thread(target=lambda: seen.append(
+        prof._thread_cpu_ns(prof.now_us())))
+    mine = prof._thread_cpu_ns(prof.now_us())
+    t.start()
+    t.join(10)
+    assert seen and seen[0] != mine
+
+
+def test_wait_lies_inside_its_fetch_and_leaves_the_old_children_alone(
+        drained):
+    """`engine.wait` brackets `block_until_ready` on the step's picks,
+    first thing inside `engine.fetch`, under the same `step`. It is no
+    child of `engine.step`: the seven still tile the step (the test
+    above all), so `benchmarks/span_reduce.py`, which knows the seven,
+    reads the step's self time and dispatch-to-fetch as before."""
+    _, events = drained
+    steps = _spans(events, "engine.step")
+    waits = {e["args"]["step"]: e for e in _spans(events, "engine.wait")}
+    fetches = {e["args"]["step"]: e for e in _spans(events, "engine.fetch")}
+    assert sorted(waits) == sorted(fetches) == \
+        [st["args"]["step"] for st in steps]
+    for step, wait in waits.items():
+        fetch = fetches[step]
+        assert wait["tid"] == fetch["tid"] and set(wait["args"]) == {"step"}
+        assert fetch["ts"] <= wait["ts"] and _end(wait) <= _end(fetch)
+    assert "engine.wait" not in CHILDREN
+    for st in steps:
+        mine = [e["name"] for e in sorted(events, key=lambda e: e["ts"])
+                if e["name"].startswith("engine.") and e["name"] != "engine.step"
+                and e["args"]["step"] == st["args"]["step"]]
+        assert mine == list(CHILDREN[:5]) + ["engine.wait"] + \
+            list(CHILDREN[5:])
+
+
+def test_one_deliver_a_hand_over_on_the_event_loops_thread(served):
+    """Every hand-over that woke a stream leaves one `frontdoor.deliver`,
+    filed by the event loop's thread, under the engine step whose
+    frames it carried."""
+    _, out, events = served
+    woke = [e for e in _spans(events, "frontdoor.finish")
+            if e["args"]["woken"]]
+    delivers = _spans(events, "frontdoor.deliver")
+    assert len(delivers) == len(woke) == len(out["tokens"])
+    loop_tid = _spans(events, "engine.step")[-1]["tid"]
+    assert {e["tid"] for e in delivers} != {loop_tid}
+    assert len({e["tid"] for e in delivers}) == 1
+    assert all(set(e["args"]) == {"step", "streams", "frames", "wake_us"}
+               for e in delivers)
+    assert all(e["args"]["streams"] == 1 for e in delivers)
+    # its step is a step of the ring, the one that had just sampled
+    samples = {e["args"]["step"]: e for e in _spans(events, "engine.sample")
+               if e["ts"] > woke[0]["ts"] - 1e6}
+    for d, f in zip(delivers, woke):
+        sample = samples[d["args"]["step"]]
+        assert sample["args"]["emitted"] == 1
+        assert _end(sample) <= f["ts"] <= _end(f)
+        assert sample["ts"] < d["ts"] + d["dur"]
+        assert 0.0 <= d["cpu"] <= d["dur"] + 50.0
+
+
+def test_delivers_count_the_frames_the_client_received(served):
+    """`frames` is what the handlers wrote between a hand-over's
+    `_set_events` and its closer: over a drained run every token frame
+    and the done frame."""
+    _, out, events = served
+    delivers = _spans(events, "frontdoor.deliver")
+    assert sum(e["args"]["frames"] for e in delivers) == \
+        len(out["tokens"]) + 1
+    # the done frame left with the last token's hand-over
+    assert [e["args"]["frames"] for e in delivers] == \
+        [1] * (len(delivers) - 1) + [2]
+
+
+def test_a_wake_up_takes_no_negative_time(served):
+    """`wake_us`: from the engine loop's `flush` to `_set_events` on the
+    event loop's thread, both on `now_us`; the flush lies inside its
+    `frontdoor.finish`."""
+    _, _, events = served
+    finishes = [e for e in _spans(events, "frontdoor.finish")
+                if e["args"]["woken"]]
+    for d, f in zip(_spans(events, "frontdoor.deliver"), finishes):
+        assert d["args"]["wake_us"] >= 0.0
+        flushed = d["ts"] - d["args"]["wake_us"]
+        assert f["ts"] <= flushed <= _end(f)
+
+
+def test_a_collection_between_two_steps_shows_in_the_next_step(
+        model_and_vars):
+    """The collector's stamps: every collection's microseconds go to one
+    sum of which a closing `engine.step` takes what is new (`gc_us`),
+    and one of 0.2 ms or more is a `runtime.gc` record on the ring."""
+    eng = _engine(*model_and_vars)
+    eng.add_request(PROMPTS[0], max_new_tokens=6)
+    prof.reset_profiler()
+    assert eng.step() and eng.step()
+    assert gc.collect() >= 0        # a full collection: milliseconds
+    assert eng.step()
+    eng.run()
+    steps = _spans(prof.get_events(), "engine.step")
+    forced = [e for e in _spans(prof.get_events(), "runtime.gc")
+              if e["args"]["generation"] == 2
+              and _end(steps[1]) <= e["ts"] <= steps[2]["ts"]]
+    assert len(forced) == 1
+    (rec,) = forced
+    assert rec["dur"] >= prof.GC_RECORD_US and rec["cpu"] is None
+    assert set(rec["args"]) == {"generation", "collected"}
+    assert rec["tid"] == steps[2]["tid"]
+    assert all("gc_us" in st["args"] for st in steps)
+    # (a difference of two readings of the sum: to the microsecond)
+    assert steps[2]["args"]["gc_us"] >= rec["dur"] - 1.0
+    # the sum only grows, and the steps took all of it
+    assert sum(st["args"]["gc_us"] for st in steps) <= prof.gc_total_us()
+
+
+def test_a_second_engine_installs_no_second_callback(model_and_vars):
+    _engine(*model_and_vars)
+    _engine(*model_and_vars)
+    assert gc.callbacks.count(prof._on_gc) == 1
+    prof.watch_gc()
+    assert gc.callbacks.count(prof._on_gc) == 1
+
+
+def test_ring_reaches_back_130_s_at_chats_rate(served):
+    """The arithmetic of `RING_SPANS`'s comment: a step that streams
+    leaves twelve entries (`engine.step`, its seven children,
+    `engine.wait`, `frontdoor.control` when it drained something,
+    `frontdoor.finish`, `frontdoor.deliver`), two steps in three a
+    `request` record; `gpt2m-chat` steps 45 times a second (a 22.2 ms
+    cycle, PERF.md section 5)."""
+    _, _, events = served
+    per_step = {"engine.step", *CHILDREN, "engine.wait", "frontdoor.control",
+                "frontdoor.finish", "frontdoor.deliver"}
+    assert len(per_step) == 12
+    steps = _spans(events, "engine.step")
+    # nothing else recurs with the steps: the loop's other spans
+    # (`frontdoor.snapshot` every 0.25 s, `.wait` when idle) do not
+    others = {e["name"] for e in events} - per_step
+    assert others <= {"request", "frontdoor.snapshot", "frontdoor.wait",
+                      "obs.scrape", "runtime.gc"}
+    assert sum(e["name"] in per_step for e in events) <= 12 * len(steps)
+    per_second = 45.1 * (12 + 2 / 3)
+    assert prof.RING_SPANS / per_second >= 130.0
 
 
 def test_attention_counts_agree_with_the_counters(drained):
