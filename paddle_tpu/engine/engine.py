@@ -68,6 +68,7 @@ Two features ride that determinism with zero new compiled paths:
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -204,6 +205,51 @@ def compile_steps(model, variables, compress: bool, serve_tp=None,
                 for kind, pool in zip(kinds, pools)]
 
     return _step_fn, _copy_blocks
+
+
+def compile_merge(serve_tp=None):
+    """The small compiled call beside the step that makes its `tokens`
+    operand: the packed host tokens, with the positions whose token is a
+    pick of the step before taken from that step's `ids` on the device.
+    `src` maps a flat position to the row of the step before, or -1 for
+    a token the host packed. It runs before EVERY step, a step that
+    takes nothing too (a map of -1), so the step always sees the same
+    kind of operand and keeps its one cache entry."""
+    sh = {}
+    if serve_tp is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        rep = NamedSharding(serve_tp.mesh, P())
+        sh = dict(in_shardings=(rep, rep, rep), out_shardings=rep)
+
+    @functools.partial(jax.jit, **sh)
+    def _merge_picks(tokens, ids, src):
+        return jnp.where(src >= 0, ids[jnp.maximum(src, 0), 0], tokens)
+
+    return _merge_picks
+
+
+@dataclass
+class _Flight:
+    """A step that was launched and is not yet collected: its plan, what
+    it asked of the device, and the handles of what it brings back."""
+    step: int
+    rows: List[StepRow]
+    chunks: List[StepRow]
+    decodes: List[StepRow]
+    computed: int               # prefill tokens of the chunks
+    asked: Dict[str, int]       # `_publish`'s addends
+    wants: List[bool]           # rows that sample from their own logits
+    # what `engine.fetch` brings to the host, on the device: each row's
+    # (lse, ids, top), an expert model's tokens per expert, the logits
+    # or None
+    down: tuple
+    row_of: Dict[int, int]      # req_id -> row, for the next step's merge
+    overlapped: bool            # launched while the step before was out
+    void: bool = False          # the pools were lost: no row counts
+    collected: bool = False
+    discarded: int = 0          # rows whose request had ended meanwhile
+    drafted: int = 0
+    accepted: int = 0
 
 
 def compile_snapshot_moves(places, kinds, ring_blocks: int):
@@ -551,8 +597,14 @@ class ServeEngine:
             drafter=self.drafter)
         self.scheduler.on_preempt = self._on_preempt
         self.scheduler.on_admit = self._on_admit
+        self.scheduler.on_starved = self._make_room
         self.finished: Dict[int, Request] = {}
+        # `steps` numbers the step whose tokens were last emitted,
+        # `_launched` the one last sent to the device; they differ while
+        # `_flight`, a step launched and not yet collected, is out
         self.steps = 0
+        self._launched = 0
+        self._flight: Optional[_Flight] = None
         self._plan_us = 0.0     # `engine.plan`'s opening stamp (step())
         # the collector's stamps (the profiler's; one callback a
         # process), and what of their sum the last step had seen
@@ -582,6 +634,11 @@ class ServeEngine:
         self._step_fn, self._copy_blocks = compile_steps(
             model, self.variables, self.cache.compress_enabled,
             self._serve_tp, None if layout is None else self.cache.kinds)
+        self._merge = compile_merge(self._serve_tp)
+        # the picks of the step before, on the device: `_merge`'s second
+        # operand (zeros before the first step, whose map takes none)
+        self._last_ids = jnp.zeros((max_batch_size, self.spec_len),
+                                   jnp.int32)
         # what the model counts of its own sparse attention a row
         self._sparse_counts = getattr(model, "sparse_counts", None)
         self._snapshots_seen = (0, 0, 0)
@@ -670,6 +727,16 @@ class ServeEngine:
             labelnames=("kind",))        # kind=prefill|cached|generated
         self._m_steps = m.counter(
             "ptpu_engine_steps_total", "Compiled mixed steps executed")
+        self._m_overlapped = m.counter(
+            "ptpu_engine_steps_overlapped_total",
+            "Steps launched while the step before them was still "
+            "uncollected (counted, like ptpu_engine_steps_total, when "
+            "the step is collected)")
+        self._m_discarded = m.counter(
+            "ptpu_engine_rows_discarded_total",
+            "Rows of a collected step thrown away because their request "
+            "had ended (end-of-sequence, cancel) after the step was "
+            "launched")
         self._m_logit_downloads = m.counter(
             "ptpu_engine_logit_downloads_total",
             "Steps that downloaded their logits: a row that samples at "
@@ -795,7 +862,7 @@ class ServeEngine:
         if req.admit_time == 0.0:
             self._m_queue_wait.observe((now - req.enqueue_time) * 1e3)
         req.admit_time = now
-        self.tracer.on_admit(req.req_id, self._plan_us, self.steps + 1,
+        self.tracer.on_admit(req.req_id, self._plan_us, self._launched + 1,
                              req.cached_tokens)
         self._set_sched_gauges()
 
@@ -877,8 +944,9 @@ class ServeEngine:
         its KV blocks (shared prefix blocks drop one refcount), counts
         it under requests{reason=...}, and closes its trace. Returns
         False when it already finished. Engine-thread only, between
-        steps — the HTTP front-end marshals disconnects through the
-        serve loop (serve/frontend.py)."""
+        calls of `step()` — the HTTP front-end marshals disconnects
+        through the serve loop (serve/frontend.py). A row it has in the
+        step in flight is thrown away when that step is collected."""
         if not self.scheduler.cancel(req):
             return False
         ts_us = now_us()
@@ -907,57 +975,84 @@ class ServeEngine:
 
     # -- serve loop --------------------------------------------------------
     def step(self) -> bool:
-        """Advance one scheduler plan (one mixed batch through the
-        single compiled step). Returns False when idle. `engine.step`
-        and its seven children (OBSERVABILITY.md "Host spans") bracket
-        every phase; an idle call leaves no span."""
-        step = self.steps + 1
-        with annotate("engine.step", step=step) as span:
-            with annotate("engine.plan", step=step) as plan:
-                # admissions and preemptions inside the plan are
-                # stamped with its opening reading (_on_admit)
-                self._plan_us = plan.ts
-                rows = self.scheduler.next_batch()
-                if rows is None:
-                    plan.discard()
+        """Emit one step's tokens. Returns False when idle. In the
+        steady state the call plans and launches step N+1 while step N
+        runs on the device, then collects N (ENGINE.md "A second step in
+        flight"); `engine.step` carries N, each of its seven children
+        (OBSERVABILITY.md "Host spans") the step it works for; an idle
+        call leaves no span."""
+        return self._step(ahead=True)
+
+    def _step(self, ahead: bool) -> bool:
+        flight = self._flight
+        with annotate("engine.step", step=flight.step if flight
+                      else self._launched + 1) as span:
+            if flight is None:
+                flight = self._launch()
+                if flight is None:
                     span.discard()
                     return False
-                self.steps = step
-                # publish the coldness clock, then sweep: blocks the
-                # plan just admitted are hot (touched at step_now), so
-                # only genuinely idle prefix content stages quantize
-                # lanes for this step's _flush_compress
-                self.cache.step_now = step
-                if self.cache.compress_enabled:
-                    self.cache.compress_cold(_COMPRESS_IDLE_STEPS)
-            chunks, decodes, chunk_tokens, drafted, accepted, asked = \
-                self._step_mixed(rows)
-            with annotate("engine.publish", step=step):
-                self._publish(chunks, decodes, chunk_tokens, drafted,
-                              accepted, asked)
+            if ahead and self._runs_ahead(flight):
+                # the next step behind it, if there is one to plan. The
+                # plan or its flush may collect `flight` themselves
+                # first (`_make_room`, `_launch`)
+                self._launch()
+            if not flight.collected:
+                self._collect(flight)
             # every collection since the last step closed, on whichever
             # thread: each held the interpreter, so each stopped the loop
             gc_seen, self._gc_seen_us = self._gc_seen_us, gc_total_us()
-            span.set(decode_rows=len(decodes), chunk_rows=len(chunks),
-                     chunk_tokens=chunk_tokens,
+            span.set(decode_rows=len(flight.decodes),
+                     chunk_rows=len(flight.chunks),
+                     chunk_tokens=flight.computed,
                      queue_depth=self.scheduler.queue_depth,
                      used_blocks=self.cache.used_blocks,
-                     gc_us=self._gc_seen_us - gc_seen, **asked)
+                     gc_us=self._gc_seen_us - gc_seen,
+                     overlapped=int(flight.overlapped),
+                     discarded_rows=flight.discarded, **flight.asked)
         # "spec" wins over mixed/decode so the speculation-on latency
         # distribution is separable from plain decode's
-        kind = ("spec" if drafted
-                else "mixed" if chunks and decodes
-                else "prefill" if chunks else "decode")
+        kind = ("spec" if flight.drafted
+                else "mixed" if flight.chunks and flight.decodes
+                else "prefill" if flight.chunks else "decode")
         self._m_step.labels(kind=kind).observe(span.dur / 1e3)
         return True
 
-    def _publish(self, chunks: List[StepRow], decodes: List[StepRow],
-                 computed: int, drafted: int, accepted: int,
-                 asked: Dict[str, int]) -> None:
-        """Per-step telemetry: the step's `serve_event` lines and
-        host-side counter and gauge writes. `asked` is what the step's
-        attention and experts were asked to do (`_step_mixed`), each
-        entry the addend of the counter of its name."""
+    def _runs_ahead(self, flight: _Flight) -> bool:
+        """Whether the step after `flight` may be planned and launched
+        before `flight` is collected: its greedy rows' next input tokens
+        are on the device already. Not where the host has to see this
+        step's results first: a row that samples from its own logits, a
+        drafter (it proposes from the tokens so far, and acceptance
+        decides the length), a request that forks when its prompt ends."""
+        if self.drafter is not None:
+            return False
+        for row, want in zip(flight.rows, flight.wants):
+            r = row.req
+            if want or (row.samples and r.n_candidates > 1 and not r.forks):
+                return False
+        return True
+
+    def _make_room(self) -> bool:
+        """Scheduler hook (`on_starved`): the plan is about to preempt.
+        A step still in flight is collected first: a victim's `prompt +
+        generated` has to be whole, and a request that ends with these
+        tokens gives its blocks back. True if one was."""
+        flight = self._flight
+        if flight is None:
+            return False
+        self._collect(flight)
+        return True
+
+    def _publish(self, flight: _Flight) -> None:
+        """Per-step telemetry of a collected step: its `serve_event`
+        lines and host-side counter and gauge writes. `asked` is what
+        the step's attention and experts were asked to do (`_launch`),
+        each entry the addend of the counter of its name."""
+        chunks, decodes, computed = (flight.chunks, flight.decodes,
+                                     flight.computed)
+        drafted, accepted, asked = (flight.drafted, flight.accepted,
+                                    flight.asked)
         self._m_kv_read.inc(asked["kv_tokens_read"])
         self._m_attn_keys.inc(asked["attn_keys"])
         self._m_attn_cells.inc(asked["attn_cells"])
@@ -1011,6 +1106,10 @@ class ServeEngine:
         self.peak_occupancy = max(self.peak_occupancy,
                                   self.cache.occupancy())
         self._m_steps.inc()
+        if flight.overlapped:
+            self._m_overlapped.inc()
+        if flight.discarded:
+            self._m_discarded.inc(flight.discarded)
         self._m_compiles.set(self._step_fn._cache_size())
         self._m_occ.set(self.cache.occupancy())
         self._m_hit.set(self.cache.hit_rate())
@@ -1026,7 +1125,8 @@ class ServeEngine:
                 computed / self.scheduler.max_prefill_tokens)
 
     def run(self) -> Dict[int, List[int]]:
-        """Drain the queue; returns {req_id: generated token ids}."""
+        """Drain the queue, the step in flight with it; returns
+        {req_id: generated token ids}."""
         while self.step():
             pass
         return {rid: self._generated_of(r)
@@ -1080,6 +1180,13 @@ class ServeEngine:
         except Exception:
             if any(pool.is_deleted() for pool in self.cache.pools):
                 self.cache.reset_pools()
+                # a step still in flight counts for nothing: its
+                # requests re-prefill and pick those tokens again
+                flight = self._flight
+                if flight is not None:
+                    flight.void = True
+                    for row in flight.rows:
+                        row.req.in_flight = 0
                 for req in reversed(list(self.scheduler.running)):
                     self.scheduler.preempt(req)
                 serve_event("serve_pools_rebuilt", step=self.steps,
@@ -1246,27 +1353,68 @@ class ServeEngine:
             out["host_tier"] = self.host_tier.stats()
         return out
 
-    def _step_mixed(self, rows: List[StepRow]
-                    ) -> "tuple[list, list, int, int, int, dict]":
-        """Pack the plan's rows — decode rows AND prefill chunks — into
-        the flat ragged layout and run ONE compiled step. Row i's token
-        window [start, start+length) lands in a tile_q-aligned segment
-        of the [T] arrays; per-row metadata (block table, chunk-end
-        context, start position) sits at index i, and the null row at
-        index max_batch_size backs pad tiles (ctx 1, scratch table).
-        For a plain decode row the window is [seq_len, seq_len+1) of
-        req.tokens — exactly the last generated token at its next-token
-        position, which is what the old decode step fed. A SPECULATIVE
-        row widens that window to [seq_len, seq_len+1+k): the base
-        token followed by k drafted tokens (scheduler StepRow.draft) —
-        the same multi-token shape a prefill chunk uses, so the ragged
-        kernel scores all k+1 positions in the one launch (each window
-        position scatters its own k/v before attention reads it,
-        exactly as chunk rows already do). last_idx is [B, spec_len]:
-        speculative rows gather one hidden state per window position
-        for verification; every other row repeats its single real
-        index across the columns."""
-        step = self.steps
+    def _launch(self) -> Optional[_Flight]:
+        """Plan the next step and send it to the device: `engine.plan`,
+        `engine.flush`, `engine.pack`, `engine.dispatch`. Returns the
+        step in flight, or None where there is nothing to plan.
+
+        The plan's rows — decode rows AND prefill chunks — are packed
+        into the flat ragged layout and run as ONE compiled step. Row
+        i's token window [start, start+length) lands in a tile_q-aligned
+        segment of the [T] arrays; per-row metadata (block table,
+        chunk-end context, start position) sits at index i, and the null
+        row at index max_batch_size backs pad tiles (ctx 1, scratch
+        table). For a plain decode row the window is [seq_len,
+        seq_len+1) of req.tokens — exactly the last generated token at
+        its next-token position, which is what the old decode step fed;
+        where that token is the pick of the step still in flight the
+        host does not have it, and `_merge` takes it from that step's
+        `ids` on the device. A SPECULATIVE row widens that window to
+        [seq_len, seq_len+1+k): the base token followed by k drafted
+        tokens (scheduler StepRow.draft) — the same multi-token shape a
+        prefill chunk uses, so the ragged kernel scores all k+1
+        positions in the one launch (each window position scatters its
+        own k/v before attention reads it, exactly as chunk rows already
+        do). last_idx is [B, spec_len]: speculative rows gather one
+        hidden state per window position for verification; every other
+        row repeats its single real index across the columns.
+
+        What has to run ahead of the picks' values does so here, behind
+        the dispatch: a decode row's length (the next plan reserves the
+        slot behind it), a chunk's commit, and a boundary's state
+        snapshot, whose copy has to stand on the device's queue before
+        the next step moves the slot on."""
+        step = self._launched + 1
+        with annotate("engine.plan", step=step) as plan:
+            # admissions and preemptions inside the plan are
+            # stamped with its opening reading (_on_admit)
+            self._plan_us = plan.ts
+            rows = self.scheduler.next_batch()
+            if rows is None:
+                plan.discard()
+                return None
+            # publish the coldness clock, then sweep: blocks the
+            # plan just admitted are hot (touched at step_now), so
+            # only genuinely idle prefix content stages quantize
+            # lanes for this step's _flush_compress
+            self.cache.step_now = step
+            if self.cache.compress_enabled:
+                self.cache.compress_cold(_COMPRESS_IDLE_STEPS)
+        # the step before, unless the plan had to collect it (_make_room)
+        before = self._flight
+        if before is not None and self.cache.tier_flush_pending:
+            # tier loads, compress and promote lanes go out behind a
+            # collected step. Whether one is pending is known only now,
+            # so the plan was made with `before` still out: a request
+            # that ends with its tokens (an end-of-sequence token) has
+            # given its table back and loses its row, as it does where
+            # the plan itself collected (`Scheduler.next_batch`)
+            self._collect(before)
+            before = None
+            rows = [w for w in rows if w.req.state == RUNNING]
+            if not rows:
+                return None     # the flush waits for the next plan
+        self._launched = step
         with annotate("engine.flush", step=step) as span:
             span.set(compress=self._flush_compress(),
                      promote=self._flush_promote(),
@@ -1282,6 +1430,8 @@ class ServeEngine:
             b = self.max_batch_size
             mb = self.max_blocks_per_seq
             tokens = np.zeros((t_flat,), np.int32)
+            # a position that takes the pick of `before`'s row: which
+            src = np.full((t_flat,), -1, np.int32)
             positions = np.zeros((t_flat,), np.int32)
             # pad positions scatter into scratch block 0 (slot < bs)
             slots = np.zeros((t_flat,), np.int32)
@@ -1313,7 +1463,11 @@ class ServeEngine:
                     window = own[:1] + row.draft
                 else:
                     window = own
-                tokens[cursor:cursor + row.length] = window
+                if window:
+                    tokens[cursor:cursor + row.length] = window
+                else:
+                    # the token is the pick of the step in flight
+                    src[cursor] = before.row_of[r.req_id]
                 positions[cursor:cursor + row.length] = np.arange(
                     row.start, row.start + row.length, dtype=np.int32)
                 for p in range(row.length):
@@ -1367,31 +1521,6 @@ class ServeEngine:
                 # slots and rings
                 self.cache.pools[-1] = jnp.asarray(self.cache.bind_rows(
                     [row.req.req_id for row in rows]))
-        with annotate("engine.dispatch", step=step):
-            (logits, *picks), self.cache.pools, *per_expert = self._donating(
-                self._step_fn,
-                self.variables, tokens, positions,
-                self.cache.pools, self.cache.qpools, self.cache.qscales,
-                block_tables, context_lens, q_starts, tile_rows,
-                tile_offs, slots, last_idx)
-        with annotate("engine.fetch", step=step) as span:
-            # three numbers a row come down; the logits stay on the
-            # device unless a row samples from its own on the host
-            wants = [row.samples and _needs_logits(row.req) for row in rows]
-            down = (*picks, per_expert, logits if any(wants) else None)
-            # the copies are asked for first, as `device_get` asks: they
-            # follow the step on the device's queue and cost no round
-            # trip of their own once the wait has returned
-            for leaf in jax.tree.leaves(down):
-                leaf.copy_to_host_async()
-            # the wait for the device's step apart from what follows it
-            with annotate("engine.wait", step=step):
-                jax.block_until_ready(picks)
-            fetched = jax.device_get(down)
-            lse, ids, top, per_expert, logits = fetched
-            span.set(bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)))
-            if logits is not None:
-                self._m_logit_downloads.inc()
             asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys,
                      "attn_cells": cells}
             if self._sparse_counts is not None:
@@ -1405,9 +1534,48 @@ class ServeEngine:
                     kv_rows_full=kv_read, attn_keys_full=attn_keys,
                     kv_rows_window=win_rows, attn_keys_window=win_keys,
                     window_blocks_released=released)
+        with annotate("engine.dispatch", step=step):
+            (logits, *picks), self.cache.pools, *per_expert = self._donating(
+                self._step_fn,
+                self.variables, self._merge(tokens, self._last_ids, src),
+                positions,
+                self.cache.pools, self.cache.qpools, self.cache.qscales,
+                block_tables, context_lens, q_starts, tile_rows,
+                tile_offs, slots, last_idx)
+            self._last_ids = picks[1]
+            # three numbers a row come down; the logits stay on the
+            # device unless a row samples from its own on the host
+            wants = [row.samples and _needs_logits(row.req) for row in rows]
+            down = (*picks, per_expert, logits if any(wants) else None)
+            # the copies are asked for right away, as `device_get` asks:
+            # they follow their own step on the device's queue, not the
+            # next one, and cost no round trip of their own once the
+            # wait has returned
+            for leaf in jax.tree.leaves(down):
+                leaf.copy_to_host_async()
+            for row in rows:
+                r = row.req
+                if row.decode:
+                    # the step writes its input token's k/v at the
+                    # reserved slot; a pick still in flight is supplied
+                    # when it lands (`_emit_token`)
+                    known = row.start - len(r.prompt) < len(r.generated)
+                    self.cache.advance(r.req_id,
+                                       r.generated[-1] if known else None)
+                else:
+                    end = row.start + row.length
+                    self.cache.commit_prefill(r.req_id, end)
+                    # a chunk that ends on a snapshot boundary of the
+                    # prompt: the slot's state is that prefix's
+                    self.cache.take_snapshot(r.req_id, end)
+                if row.samples:
+                    r.in_flight += 1
+            # the copies go right behind the step that made the state,
+            # before the next step or anything else walks the slots
+            self._move_snapshots(self.cache.drain_snapshot_takes(), take=True)
             if self.cache.snapshot_every:
-                # restored at this plan's admissions; taken by the step
-                # before (its copies were made when it ended)
+                # restored at this plan's admissions, taken behind this
+                # step
                 now = (self.cache.snapshots_taken,
                        self.cache.snapshots_restored,
                        self.cache.snapshot_tokens_skipped)
@@ -1416,6 +1584,34 @@ class ServeEngine:
                      "snapshot_tokens_skipped"),
                     (a - b for a, b in zip(now, self._snapshots_seen))))
                 self._snapshots_seen = now
+            self._flight = _Flight(
+                step=step, rows=rows, chunks=chunks, decodes=decodes,
+                computed=computed, asked=asked, wants=wants, down=down,
+                row_of={row.req.req_id: i
+                                   for i, row in enumerate(rows)},
+                overlapped=before is not None)
+            self.scheduler.steps_in_flight += 1
+        return self._flight
+
+    def _collect(self, flight: _Flight) -> None:
+        """Bring a launched step's picks to the host and emit its
+        tokens: `engine.fetch` (with `engine.wait`), `engine.sample`,
+        `engine.publish`. `steps` is this step's number from here on."""
+        step = self.steps = flight.step
+        flight.collected = True
+        if self._flight is flight:
+            self._flight = None
+        self.scheduler.steps_in_flight -= 1
+        rows, wants, asked = flight.rows, flight.wants, flight.asked
+        with annotate("engine.fetch", step=step) as span:
+            # the wait for the device's step apart from what follows it
+            with annotate("engine.wait", step=step):
+                jax.block_until_ready(flight.down[:3])
+            fetched = jax.device_get(flight.down)
+            lse, ids, top, per_expert, logits = fetched
+            span.set(bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)))
+            if logits is not None:
+                self._m_logit_downloads.inc()
             if per_expert:
                 per_expert = per_expert[0]
                 self.expert_tokens += per_expert
@@ -1428,24 +1624,32 @@ class ServeEngine:
             ts_us = span.ts
             generated = self._m_tokens.labels(kind="generated")
             emitted, finished = generated.value, len(self.finished)
-            drafted = accepted = 0
             for i, row in enumerate(rows):
                 r = row.req
+                if flight.void or r.state != RUNNING:
+                    # the request ended after this step was launched (an
+                    # end-of-sequence token in the step before, a cancel
+                    # from the front door), or the pools were lost:
+                    # nothing is emitted, no callback runs, no length is
+                    # kept. The row's write landed past the sequence's
+                    # last kept token, in a block no index entry covers,
+                    # and any later owner of that block or slot writes
+                    # after it on the device's queue
+                    flight.discarded += 1
+                    continue
+                r.in_flight -= row.samples
                 # the row's own logits where it samples from them
                 mine = logits[i] if wants[i] else None
                 if row.decode:
-                    # the step wrote r.generated[-1]'s k/v at the reserved
-                    # slot
-                    self.cache.advance(r.req_id, r.generated[-1])
                     row_accepted = 0
                     for j in range(len(row.draft) + 1):
                         # logits[i, j] scored window position start+j, i.e.
-                        # it predicts the token at cache seq_len (which the
-                        # advances below keep in lockstep with j)
+                        # it predicts the token at start+j+1 (where the
+                        # advances below keep the cache in lockstep with j)
                         tok, lp = _pick(
                             None if mine is None else mine[j],
                             ids[i, j], top[i, j], lse[i, j], r,
-                            self.cache.seq_len(r.req_id))
+                            row.start + j + 1)
                         r.logprob_sum += lp
                         self._emit_token(r, tok, ts_us)
                         if r.finish_reason or j >= len(row.draft):
@@ -1462,8 +1666,8 @@ class ServeEngine:
                         self.cache.advance(r.req_id, tok)
                         row_accepted += 1
                     if row.draft:
-                        drafted += len(row.draft)
-                        accepted += row_accepted
+                        flight.drafted += len(row.draft)
+                        flight.accepted += row_accepted
                         self._m_spec_drafted.inc(len(row.draft))
                         self._m_spec_accepted.inc(row_accepted)
                         self._m_spec_rejected.inc(
@@ -1471,11 +1675,6 @@ class ServeEngine:
                         self._m_spec_ratio.observe(
                             row_accepted / len(row.draft))
                 else:
-                    end = row.start + row.length
-                    self.cache.commit_prefill(r.req_id, end)
-                    # a chunk that ended on a snapshot boundary of the
-                    # prompt: the slot's state is that prefix's
-                    self.cache.take_snapshot(r.req_id, end)
                     self.tracer.on_chunk(r.req_id, row.start, row.length,
                                          ts_us, step)
                     if row.samples:     # the prompt's final chunk
@@ -1495,9 +1694,8 @@ class ServeEngine:
             span.set(emitted=int(generated.value - emitted),
                      finished=len(self.finished) - finished,
                      host_rows=sum(wants))
-        # before anything else walks the slots
-        self._move_snapshots(self.cache.drain_snapshot_takes(), take=True)
-        return chunks, decodes, computed, drafted, accepted, asked
+        with annotate("engine.publish", step=step):
+            self._publish(flight)
 
     def _fork_candidates(self, primary: Request, picked: tuple,
                          ts_us: float) -> None:
@@ -1556,10 +1754,13 @@ class ServeEngine:
         if req.callback is not None:
             req.callback(tok)
         hit_eos = req.eos_id is not None and tok == req.eos_id
-        out_of_room = (len(req.prompt) + len(req.generated)
-                       >= self.max_seq_len - 1)
-        if hit_eos or req.num_generated >= req.max_new_tokens or out_of_room:
+        if (hit_eos or req.num_generated >= req.max_new_tokens
+                or self.scheduler.out_of_room(req)):
             self._finish(req, "eos" if hit_eos else "length", ts_us)
+        elif self.cache.trails(req.req_id):
+            # the next step is out already with this pick as its input:
+            # the cache's record of the sequence gets the value now
+            self.cache.supply(req.req_id, tok)
 
     def _finish(self, req: Request, reason: str, ts_us: float) -> None:
         req.finish_time = ts_us / 1e6
@@ -1615,13 +1816,16 @@ class ServeEngine:
         touching compiled steps or live state. Also zeroes this
         engine's metrics registry IN PLACE (families and child handles
         survive) and the request tracer — the post-warmup baseline
-        serve_bench measures from."""
+        serve_bench measures from. A step still in flight belongs to
+        what came before: it is collected first."""
+        if self._flight is not None:
+            self._step(ahead=False)
         self.cache.reset_stats()
         self._snapshots_seen = (0, 0, 0)
         self.prefill_tokens_computed = 0
         self.peak_occupancy = 0.0
         self.max_chunk_tokens = 0
-        self.steps = 0
+        self.steps = self._launched = 0
         self.obs.reset()
         self.tracer.reset()
         # static-config series survive the zeroing: the tp degree and

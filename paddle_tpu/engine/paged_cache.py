@@ -829,7 +829,7 @@ class PagedKVCache:
         toks = self._tokens[seq_id]
         bs = self.block_size
         count = 0
-        for bi in range(self._committed.get(seq_id, 0) // bs):
+        for bi in range(self._known(seq_id) // bs):
             b = table[bi]
             if b < 0:
                 # bias-encoded direct-read entry: the content already
@@ -1183,6 +1183,13 @@ class PagedKVCache:
         out, self._pending_compress = self._pending_compress, []
         return out
 
+    @property
+    def tier_flush_pending(self) -> bool:
+        """Whether a compress, promote or host-tier load is staged for
+        the next step's flush."""
+        return bool(self._pending_compress or self._pending_promotes
+                    or self._pending_host_loads)
+
     def drain_promotes(self) -> List[Tuple[int, int]]:
         """Staged (fp block, int8 slot) dequantize promotions, flushed
         AFTER compressions (a promo may read a slot the same plan just
@@ -1201,6 +1208,13 @@ class PagedKVCache:
     def committed_len(self, seq_id: int) -> int:
         return self._committed.get(seq_id, 0)
 
+    def _known(self, seq_id: int) -> int:
+        """Positions that are in the pool AND whose tokens the host
+        knows: what a prefix key may cover. A sequence's length runs one
+        ahead of its tokens while the step that picks the token at its
+        tail is still in flight (`advance` without a value)."""
+        return min(self._committed.get(seq_id, 0), len(self._tokens[seq_id]))
+
     def _register_full_blocks(self, seq_id: int) -> None:
         # over slots a hit goes by the snapshots' own record of their
         # blocks: no block is indexed (a key is the prefix itself, and
@@ -1210,7 +1224,7 @@ class PagedKVCache:
         bs = self.block_size
         table = self._tables[seq_id]
         toks = self._tokens[seq_id]
-        for bi in range(self._committed[seq_id] // bs):
+        for bi in range(self._known(seq_id) // bs):
             block = table[bi]
             if block < 0:
                 continue    # int8-resident: indexed by _cindex, not here
@@ -1299,14 +1313,29 @@ class PagedKVCache:
         self._tokens[dst_id] = list(self._tokens[src_id])
         self._committed[dst_id] = self._committed[src_id]
 
-    def advance(self, seq_id: int, token: int) -> None:
-        """The decode step wrote `token`'s k/v at the reserved slot:
-        extend the sequence and index the tail block if it just
-        filled (generated continuations are shareable too)."""
-        self._tokens[seq_id].append(token)
+    def advance(self, seq_id: int, token: Optional[int] = None) -> None:
+        """A decode step was launched that writes `token`'s k/v at the
+        reserved slot: extend the sequence, so that the next plan
+        reserves the slot behind it, and index the tail block if it just
+        filled (generated continuations are shareable too). `token` is
+        None where the value is still on the device, the pick of a step
+        in flight: the length then runs one ahead of the tokens until
+        `supply` brings the value, and no block is indexed before all
+        its tokens are known."""
         self._lens[seq_id] += 1
         self._committed[seq_id] = self._lens[seq_id]
-        if self._lens[seq_id] % self.block_size == 0:
+        if token is not None:
+            self.supply(seq_id, token)
+
+    def trails(self, seq_id: int) -> bool:
+        """Whether the sequence's tokens are one short of its length."""
+        return len(self._tokens[seq_id]) < self._lens[seq_id]
+
+    def supply(self, seq_id: int, token: int) -> None:
+        """The value of the position the length had run ahead by."""
+        toks = self._tokens[seq_id]
+        toks.append(token)
+        if len(toks) % self.block_size == 0:
             self._register_full_blocks(seq_id)
 
     def free_sequence(self, seq_id: int) -> int:

@@ -56,9 +56,12 @@ WAITING, RUNNING, FINISHED = "waiting", "running", "finished"
 _req_ids = itertools.count()
 
 
-@dataclass
+@dataclass(eq=False)
 class Request:
-    """One inference request; `prompt` grows on preemption (recompute)."""
+    """One inference request; `prompt` grows on preemption (recompute).
+    A request is itself and equals no other (`eq=False`): the plan asks
+    `req in self.running` some 1,500 times a step of 32 rows, and a
+    comparison field by field cost 2 ms of every plan."""
     prompt: List[int]
     max_new_tokens: int = 32
     temperature: float = 0.0          # 0 => greedy
@@ -98,6 +101,10 @@ class Request:
     first_token_time: float = 0.0
     finish_time: float = 0.0
     finish_reason: str = ""
+    # tokens that steps launched and not yet collected will yield (0 or
+    # 1 whenever a plan is made): `generated` gets a token only when
+    # its step is collected, and the plan made meanwhile counts it in
+    in_flight: int = 0
 
     @property
     def tokens(self) -> List[int]:
@@ -171,6 +178,13 @@ class Scheduler:
         # queue-wait histograms and request-lifecycle spans)
         self.on_preempt: Optional[Callable[[Request], None]] = None
         self.on_admit: Optional[Callable[[Request], None]] = None
+        # steps the engine has launched and not yet collected: their
+        # rows' tokens, finishes and freed blocks are still to come
+        self.steps_in_flight = 0
+        # engine hook, called before a victim is chosen: True if it gave
+        # blocks back or made tokens known (it collected the step in
+        # flight), and the reservation is tried again first
+        self.on_starved: Optional[Callable[[], bool]] = None
 
     # -- intake -----------------------------------------------------------
     def add(self, req: Request) -> None:
@@ -185,7 +199,9 @@ class Scheduler:
         return len(self.waiting)
 
     def has_work(self) -> bool:
-        return bool(self.waiting or self.running)
+        """A step in flight is work too: its rows' requests may all have
+        been cancelled since, and it still has to be collected."""
+        return bool(self.waiting or self.running or self.steps_in_flight)
 
     # -- planning ---------------------------------------------------------
     def next_batch(self) -> Optional[Plan]:
@@ -198,12 +214,22 @@ class Scheduler:
         its next-token block reserved before it enters the plan,
         preempting from the tail if the pool runs dry. The chunk token
         budget goes to the head request first, so earlier prompts
-        reach their first token sooner."""
+        reach their first token sooner.
+
+        The engine may plan while the step before is still in flight
+        (ENGINE.md "A second step in flight"): a sequence's length then
+        stands one past its known tokens, the row's input token is that
+        step's pick, and `Request.in_flight` counts the token to come.
+        A request whose token in flight is its last, by
+        `max_new_tokens` or by `max_seq_len`, gets no row. A plan that
+        would have to preempt asks `on_starved` first: a victim's
+        `prompt + generated` has to be whole."""
         self._try_admit()
         if not self.running:
             self._check_liveness()
             return None
         rows: List[StepRow] = []
+        ending = False      # a request waits for its last token, in flight
         budget = self.max_prefill_tokens
         for req in list(self.running):
             if req not in self.running:     # preempted by an earlier row
@@ -224,6 +250,8 @@ class Scheduler:
                 req.prefill_pos += take
                 budget -= take
                 rows.append(StepRow(req, start, take, decode=False))
+            elif self._ends_in_flight(req):
+                ending = True   # no row that would be thrown away
             else:
                 draft = self._propose_draft(req)
                 if draft:
@@ -252,10 +280,30 @@ class Scheduler:
         rows = [w for w in rows if w.req in self.running]
         if rows:
             return rows
+        if ending:
+            return None     # nothing to plan until that step is collected
         if self.running:
             return self.next_batch()    # everything preempted; replan
         self._check_liveness()
         return None
+
+    def _ends_in_flight(self, req: Request) -> bool:
+        """Whether the token a step in flight will yield is `req`'s
+        last, by its count or by the sequence-length ceiling: the host
+        knows both without the token's value."""
+        # (the lengths, not `req.tokens`: that builds the list, 33k
+        # tokens a running request a call in the longest cell)
+        return req.in_flight > 0 and (
+            req.num_generated + req.in_flight >= req.max_new_tokens
+            or self.out_of_room(req, req.in_flight))
+
+    def out_of_room(self, req: Request, coming: int = 0) -> bool:
+        """Whether `req` stands at the sequence-length ceiling once
+        `coming` more tokens have landed: the engine ends a request by
+        it when a token is emitted, and the plan made while that token
+        is in flight foresees it by the same bound."""
+        return (len(req.prompt) + len(req.generated) + coming
+                >= self.max_seq_len)
 
     def _propose_draft(self, req: Request) -> List[int]:
         """Draft tokens for one decode-ready row, capped so the whole
@@ -277,7 +325,8 @@ class Scheduler:
         chunk. Admission counts the whole group up front so the forks'
         decode rows are guaranteed batch room the moment they exist."""
         if not req.prefilling:
-            return 1
+            # one that ends with the token in flight has no row any more
+            return 0 if self._ends_in_flight(req) else 1
         return 1 + max(0, req.n_candidates - 1 - len(req.forks))
 
     def _try_admit(self) -> List[Request]:
@@ -341,6 +390,8 @@ class Scheduler:
                 self.cache.ensure_writable(req.req_id, start, end)
                 return
             except CacheExhausted:
+                if self.on_starved is not None and self.on_starved():
+                    continue
                 victim = self._pick_victim(req)
                 if victim is None:
                     raise
@@ -355,6 +406,8 @@ class Scheduler:
                 self.cache.append_token(req.req_id)
                 return True
             except CacheExhausted:
+                if self.on_starved is not None and self.on_starved():
+                    continue
                 victim = self._pick_victim(req)
                 if victim is None:
                     raise CacheExhausted(
@@ -475,10 +528,11 @@ class Scheduler:
         held) or the running set (frees its blocks; shared prefix
         blocks just drop one refcount and queued COW copies to freed
         blocks are cancelled by free_sequence). Returns False when the
-        request already finished. Engine-thread only, BETWEEN steps: a
-        cancelled row must never reach an in-flight plan (the serve
-        front-end marshals client disconnects through the engine loop,
-        serve/frontend.py)."""
+        request already finished. Engine-thread only, BETWEEN calls of
+        `step()` (the serve front-end marshals client disconnects
+        through the engine loop, serve/frontend.py): a row the request
+        has in a step in flight is thrown away when that step is
+        collected."""
         if req in self.running:
             self.cache.free_sequence(req.req_id)
             self.running.remove(req)

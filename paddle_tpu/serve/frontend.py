@@ -62,7 +62,12 @@ a frame in this iteration of the engine loop (`_HandOver`), so the row
 loop of a step never gives the interpreter away between two of its
 tokens; the event loop's thread files one `frontdoor.deliver` record a
 hand-over (when it woke, how long until the woken handlers had written
-their frames, how many). Thousands of idle SSE streams cost
+their frames, how many). The engine loop then WAITS, off the
+interpreter, until those frames are written (`_hand_over`): since the
+engine keeps a second step in flight its loop no longer parks on the
+device while the handlers write, and the two threads share one
+interpreter; the device has the next step to run meanwhile.
+Thousands of idle SSE streams cost
 coroutines, not OS threads — `ptpu_serve_conn_threads` stays flat
 while `ptpu_serve_open_connections` climbs. Disconnects come from the
 transport (a parked read resolves on peer close); writes are
@@ -119,6 +124,9 @@ from paddle_tpu.serve.tokenizer import ByteTokenizer
 from paddle_tpu.utils.log import serve_event
 
 _DIR_INTERVAL_S = 0.25   # default /kvprefixes + /debug refresh cadence
+# the longest the engine loop waits for a hand-over's frames to be
+# written (`ServeFrontend._hand_over`)
+_DELIVER_WAIT_S = 0.05
 
 
 def _set_events(events, note=None) -> None:
@@ -145,6 +153,7 @@ def _delivered(note, ts: float, cpu_ns: int, written: int,
            (time.thread_time_ns() - cpu_ns) / 1e3, step=step,
            streams=streams, frames=handover.written - written,
            wake_us=ts - flushed_us)
+    handover.delivered.set()
 
 
 def _wake(loop: asyncio.AbstractEventLoop, events, note=None) -> None:
@@ -155,7 +164,8 @@ def _wake(loop: asyncio.AbstractEventLoop, events, note=None) -> None:
     try:
         loop.call_soon_threadsafe(_set_events, events, note)
     except RuntimeError:
-        pass
+        if note:    # nobody is left to deliver it
+            note[0].delivered.set()
 
 
 class _HandOver:
@@ -167,14 +177,18 @@ class _HandOver:
     outside a step, and on every exit, so nothing stays parked.
     `tid` is the engine loop's thread; `pending` belongs to it;
     `written`, the frames the handlers have written to their sockets,
-    belongs to the event loop's thread."""
+    belongs to the event loop's thread. `delivered` is clear while a
+    hand-over is out: from its `flush()` until the woken consumers
+    have written its frames (`_delivered`)."""
 
-    __slots__ = ("tid", "pending", "written")
+    __slots__ = ("tid", "pending", "written", "delivered")
 
     def __init__(self):
         self.tid: Optional[int] = None
         self.pending: set = set()
         self.written = 0
+        self.delivered = threading.Event()
+        self.delivered.set()
 
     def flush(self, step: Optional[int] = None) -> int:
         """Wake every noted stream's consumer, one
@@ -185,6 +199,7 @@ class _HandOver:
         if not self.pending:
             return 0
         pending, self.pending = self.pending, set()
+        self.delivered.clear()
         by_loop: Dict[asyncio.AbstractEventLoop, list] = {}
         for s in pending:
             by_loop.setdefault(s.loop, []).append(s.ev)
@@ -804,10 +819,20 @@ class ServeFrontend:
         """Wake the consumers of every stream that got a frame since
         the last hand-over (engine-loop thread only), on behalf of
         engine step `step` where one made the frames; returns their
-        number."""
+        number. The loop then waits, off the interpreter, until the
+        handlers have written those frames (bounded; the device has the
+        next step to run meanwhile). The engine launches a step before
+        it collects the one before, so its loop no longer parks on the
+        device while the handlers write: planning and launching beside
+        them, both on one interpreter, cost each nearly its double, the
+        event loop was in a delivery three quarters of the time, and a
+        new request's first token came 40 ms later than before; a loop
+        that outran them altogether handed over two steps' frames at
+        once (PERF.md section 6, PR 36)."""
         woken = self._handover.flush(step)
         if woken:
             self._m_wakeups.inc()
+            self._handover.delivered.wait(_DELIVER_WAIT_S)
         return woken
 
     def _flush_finished(self, step: Optional[int] = None) -> None:

@@ -11,6 +11,8 @@ guarantee; `test_engine_matches_model_generate` pins the engine to the
 repo's reference decode path.
 """
 
+import functools
+import importlib
 import json
 
 import jax
@@ -21,6 +23,10 @@ import pytest
 from paddle_tpu.engine import (CacheExhausted, PagedKVCache, Request,
                                Scheduler, ServeEngine)
 from paddle_tpu.models.transformer import CausalLM
+from paddle_tpu.obs.metrics import MetricsRegistry
+
+# the package re-exports a function named `profiler` over the submodule
+prof = importlib.import_module("paddle_tpu.profiler.profiler")
 
 pytestmark = pytest.mark.serve
 
@@ -595,9 +601,11 @@ def test_step_consumes_the_pools_and_serves_the_same(model_and_vars, tier,
                   kv_compress_blocks=6)
     reqs = [eng.add_request(p, max_new_tokens=5) for p in PROMPTS]
     while eng.scheduler.has_work():
-        before = list(eng.cache.pools)
+        before, launched = list(eng.cache.pools), eng._launched
         assert eng.step()
-        assert all(p.is_deleted() for p in before)
+        # (a call that only collects the last step launches nothing)
+        assert all(p.is_deleted() for p in before) \
+            or eng._launched == launched
         assert not any(p.is_deleted() for p in eng.cache.pools)
         eng.debug_state(), eng.kv_prefix_directory()
         assert eng.cache.per_chip_pool_bytes() > 0
@@ -675,3 +683,404 @@ def test_failed_donated_step_leaves_a_serving_engine(model_and_vars):
     assert late == _engine(model, variables).generate([[9, 9, 8]],
                                                       max_new_tokens=4)
     eng.cache.assert_quiesced()
+
+
+# -- a second step in flight ------------------------------------------------
+#
+# ENGINE.md "A second step in flight": `step()` launches step N+1 before it
+# collects step N wherever N's rows are greedy. The loop held synchronous
+# (`_runs_ahead` says no: the same launch and collect, back to back) is what
+# every property is held against, over each kind of cache the engine keeps.
+
+LAYOUTS = ("dense", "latent", "hybrid", "snapshot")
+LONG = list(range(1, 27))       # three 8-token snapshot boundaries and a tail
+MIXED = [LONG + [30, 31], [5, 9, 2, 7, 1, 3], [4, 4, 8], LONG + [40]]
+
+
+@functools.lru_cache(maxsize=None)
+def _layout(kind):
+    """(model, variables, engine options) of a toy of each cache kind:
+    dense paged, latent paged, state slots with a window ring, and
+    block-sparse + lightning layers served with state snapshots."""
+    if kind == "dense":
+        model = CausalLM(vocab=VOCAB, model_dim=16, num_heads=4,
+                         num_layers=2, ffn_dim=32, dropout=0.0, max_len=64)
+    elif kind == "latent":
+        from paddle_tpu.models.latent_moe import LatentMoELM
+        model = LatentMoELM(
+            vocab=VOCAB, model_dim=16, num_heads=2, num_layers=3, q_rank=8,
+            kv_rank=8, nope_dim=4, rope_dim=4, v_dim=4, dense_dim=32,
+            expert_dim=8, num_experts=8, top_k=2, max_len=64)
+    elif kind == "hybrid":
+        from paddle_tpu.models.hybrid_lm import HybridLM
+        model = HybridLM(
+            vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2,
+            ffn_dim=32, window=8, d_inner=32, d_state=4, d_conv=4, dt_rank=2,
+            layer_kinds=["mamba", "window", "mamba", "full", "gmu", "cross"],
+            max_len=64)
+    else:
+        from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
+        model = SparseLinearLM(
+            vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2,
+            head_dim=4, ffn_dim=32, la_heads=2, la_head_dim=8, max_len=64,
+            mixer_types=["lightning-attn", "minicpm4"], snapshot_tokens=8,
+            snapshot_slots=4, sparse=dict(
+                dense_len=16, kernel=4, stride=2, block=4, init_blocks=1,
+                local=8, topk=1))
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    return model, variables, {"max_prefill_tokens": 8}
+
+
+def _pair(kind, **kw):
+    """Two engines of one kind: the loop as it runs, and held
+    synchronous."""
+    model, variables, options = _layout(kind)
+    ahead = _engine(model, variables, registry=MetricsRegistry(),
+                    **{**options, **kw})
+    held = _engine(model, variables, registry=MetricsRegistry(),
+                   **{**options, **kw})
+    held._runs_ahead = lambda flight: False
+    return ahead, held
+
+
+def _count(eng, name):
+    return eng.obs.get(name).value
+
+
+def _served(eng, requests):
+    """Serve `requests` (add_request's keywords) to the end; what each
+    got: tokens, reason, the float sum, and the callback's calls."""
+    calls = [[] for _ in requests]
+    reqs = [eng.add_request(callback=calls[i].append, **kw)
+            for i, kw in enumerate(requests)]
+    eng.run()
+    assert all(c == ServeEngine._generated_of(r)
+               for c, r in zip(calls, reqs))
+    return [(ServeEngine._generated_of(r), r.finish_reason, r.logprob_sum,
+             len(c)) for r, c in zip(reqs, calls)]
+
+
+@pytest.mark.parametrize("kind", LAYOUTS)
+def test_a_second_step_in_flight_serves_the_synchronous_tokens(kind):
+    """Same prompts, same weights: tokens, finish reasons, `logprob_sum`
+    and the number of callbacks are those of the loop held synchronous,
+    request by request. No request computes a row past its count
+    (`discarded_rows` 0, and the generated tokens are the tokens asked
+    for), nearly every step was launched behind an uncollected one, and
+    the step compiled once on both sides."""
+    ahead, held = _pair(kind)
+    requests = [{"prompt": p, "max_new_tokens": n}
+                for p, n in zip(MIXED, (6, 9, 1, 5))]
+    got, want = _served(ahead, requests), _served(held, requests)
+    assert got == want
+    assert [g[1] for g in got] == ["length"] * 4
+    asked = sum(r["max_new_tokens"] for r in requests)
+    for eng in (ahead, held):
+        assert eng.obs.get("ptpu_serve_tokens_total").labels(
+            kind="generated").value == asked
+        assert _count(eng, "ptpu_engine_rows_discarded_total") == 0
+        assert eng._step_fn._cache_size() == 1
+        assert eng._flight is None and not eng.scheduler.has_work()
+        eng.cache.assert_quiesced()
+    assert _count(held, "ptpu_engine_steps_overlapped_total") == 0
+    steps = _count(ahead, "ptpu_engine_steps_total")
+    assert steps == ahead.steps == held.steps
+    # all but the first launch of the burst
+    assert _count(ahead, "ptpu_engine_steps_overlapped_total") == steps - 1
+
+
+@pytest.mark.parametrize("kind", ["dense", "hybrid"])
+def test_no_row_is_planned_past_the_sequence_ceiling(kind):
+    """A request that ends by `max_seq_len` is foreseen by the plan made
+    while its last token is in flight, by the bound `_emit_token` ends it
+    with (`Scheduler.out_of_room`): no row is computed and thrown away,
+    and the tokens are the synchronous loop's."""
+    ahead, held = _pair(kind, max_seq_len=24)
+    requests = [{"prompt": MIXED[1], "max_new_tokens": 40},
+                {"prompt": MIXED[2], "max_new_tokens": 12}]
+    got, want = _served(ahead, requests), _served(held, requests)
+    assert got == want
+    # 6 prompt tokens + 17 generated stand at the ceiling of 24 - 1
+    assert [(len(g[0]), g[1]) for g in got] == [(17, "length"),
+                                                (12, "length")]
+    for eng in (ahead, held):
+        assert _count(eng, "ptpu_engine_rows_discarded_total") == 0
+        assert eng.obs.get("ptpu_serve_tokens_total").labels(
+            kind="generated").value == 29
+        eng.cache.assert_quiesced()
+    steps = _count(ahead, "ptpu_engine_steps_total")
+    assert steps == held.steps
+    assert _count(ahead, "ptpu_engine_steps_overlapped_total") == steps - 1
+
+
+def _free_run(kind, prompt, n):
+    model, variables, options = _layout(kind)
+    return _engine(model, variables, **options).generate(
+        [prompt], max_new_tokens=n)[0]
+
+
+@pytest.mark.parametrize("how", ["eos", "cancel", "cancel_group"])
+@pytest.mark.parametrize("kind", LAYOUTS)
+def test_a_request_that_ends_with_a_row_in_flight(kind, how):
+    """An end-of-sequence token, and a cancel from outside, while the
+    request has a row in the step in flight: that row is thrown away
+    when its step is collected. Nothing is emitted after the end, the
+    neighbour's tokens are the synchronous loop's, and every block and
+    slot comes back."""
+    prompt, other = MIXED[1], MIXED[0]
+    free = _free_run(kind, prompt, 12)
+    ahead, held = _pair(kind)
+    if how == "eos":
+        cut = free.index(free[2])   # its first occurrence ends the request
+        requests = [{"prompt": prompt, "max_new_tokens": 12,
+                     "eos_id": free[cut]},
+                    {"prompt": other, "max_new_tokens": 8}]
+        got, want = _served(ahead, requests), _served(held, requests)
+        assert got == want
+        assert got[0][:2] == (free[:cut + 1], "eos") and got[0][3] == cut + 1
+    else:
+        outputs = []
+        for eng in (ahead, held):
+            seen = []
+            req = eng.add_request(prompt, max_new_tokens=12,
+                                  callback=seen.append)
+            keep = eng.add_request(other, max_new_tokens=8)
+            for _ in range(4):
+                assert eng.step()
+            assert (eng._flight is not None) == (eng is ahead)
+            had = list(seen)
+            if how == "cancel":
+                assert eng.cancel(req)
+            else:
+                assert eng.cancel_group(req) == 1
+            eng.run()
+            assert seen == had == req.generated
+            assert req.finish_reason == "cancelled"
+            outputs.append((had, keep.generated, keep.finish_reason))
+        assert outputs[0] == outputs[1]
+    # the row that was out when the request ended, and no other
+    assert _count(ahead, "ptpu_engine_rows_discarded_total") == 1
+    assert _count(held, "ptpu_engine_rows_discarded_total") == 0
+    for eng in (ahead, held):
+        assert eng._step_fn._cache_size() == 1
+        assert eng.cache.occupancy() == 0.0
+        eng.cache.assert_quiesced()
+
+
+@pytest.mark.parametrize("tier", ["promote", "host"])
+def test_an_end_of_sequence_behind_a_pending_tier_flush(model_and_vars, tier):
+    """A promote lane or a host-tier load staged by the plan of step N+1
+    has step N collected before the flush, after the plan was made. A
+    request that ends by `eos_id` in that collect has given its table
+    back: its row of the plan is dropped, the neighbours' tokens are the
+    synchronous loop's, and every block comes back."""
+    model, variables = model_and_vars
+    options = ({"num_blocks": 16, "kv_compress_blocks": 24,
+                "kv_promote_hits": 1} if tier == "promote"
+               else {"num_blocks": 12, "host_tier_bytes": 1 << 20})
+    system = [7, 3, 7, 3, 11, 2, 5, 9, 1, 1, 4, 8]
+    prompt = PROMPTS[1]
+    free = _engine(model, variables).generate([prompt], max_new_tokens=12)[0]
+    cut = free.index(free[2])
+    outputs = []
+    for hold in (False, True):
+        eng = _engine(model, variables, registry=MetricsRegistry(), **options)
+        if hold:
+            eng._runs_ahead = lambda flight: False
+        # the system prompt's blocks leave the pool for the lower rung
+        eng.generate([system + [6, 2]], max_new_tokens=6)
+        eng.generate([[50] * 8], max_new_tokens=8)
+        for i in range(3):
+            eng.generate([[30 + i] * 16], max_new_tokens=12)
+        assert tuple(system[:4]) not in eng.cache._index
+        seen = []
+        ends = eng.add_request(prompt, max_new_tokens=12, eos_id=free[cut],
+                               callback=seen.append)
+        for _ in range(cut):
+            assert eng.step()
+        # the pick that ends it is on the device, not yet on the host
+        assert seen == free[:cut] and (eng._flight is None) == hold
+        flushes = []
+        flush = eng._flush_promote if tier == "promote" \
+            else eng._flush_tier_loads
+        staged = lambda: flushes.append(flush()) or flushes[-1]
+        if tier == "promote":
+            eng._flush_promote = staged
+        else:
+            eng._flush_tier_loads = staged
+        # its admission stages the lanes, in the plan of the next step
+        late = eng.add_request(system + [6, 2], max_new_tokens=6)
+        eng.run()
+        assert max(flushes) > 0
+        assert seen == free[:cut + 1] and ends.finish_reason == "eos"
+        assert late.finish_reason == "length" and late.cached_tokens > 0
+        outputs.append((seen, late.generated))
+        assert _count(eng, "ptpu_engine_rows_discarded_total") == 0
+        assert eng._step_fn._cache_size() == 1
+        eng.cache.assert_quiesced()
+    assert outputs[0] == outputs[1]
+
+
+def _overlapped(eng):
+    """`overlapped` of the ring's `engine.step` spans, by step."""
+    return {e["args"]["step"]: e["args"]["overlapped"]
+            for e in prof.get_events() if e["name"] == "engine.step"}
+
+
+@pytest.mark.parametrize("what", ["preempt", "draft", "fork", "temperature"])
+def test_a_step_the_host_has_to_see_is_collected_first(model_and_vars, what):
+    """A plan that must preempt, a drafted row, a request that forks and
+    a row at a temperature: the step is collected before the next is
+    planned, and the outputs are the synchronous loop's."""
+    model, variables = model_and_vars
+    options, requests = {}, [{"prompt": p, "max_new_tokens": 10}
+                             for p in PROMPTS[:3]]
+    if what == "preempt":
+        options = {"max_batch_size": 3, "num_blocks": 9}
+        requests = [{"prompt": p, "max_new_tokens": 12}
+                    for p in ([5, 9, 2, 4], [7, 1, 1, 3], [4, 4, 2, 9])]
+    elif what == "draft":
+        options = {"spec_k": 3}
+        requests.append({"prompt": [1, 2, 3, 4, 5] * 2 + [1, 2],
+                         "max_new_tokens": 10})
+    elif what == "fork":
+        requests.append({"prompt": PROMPTS[3], "max_new_tokens": 6, "n": 3})
+    else:
+        requests.append({"prompt": PROMPTS[3], "max_new_tokens": 6,
+                         "temperature": 0.7, "top_k": 20, "seed": 11})
+    ahead = _engine(model, variables, registry=MetricsRegistry(), **options)
+    held = _engine(model, variables, registry=MetricsRegistry(), **options)
+    held._runs_ahead = lambda flight: False
+    whole = []
+    preempt = ahead.scheduler.preempt
+    ahead.scheduler.preempt = lambda req: (
+        whole.append(ahead._flight is None and not req.in_flight),
+        preempt(req))
+    prof.reset_profiler()
+    got = _served(ahead, requests)
+    flags = _overlapped(ahead)
+    assert got == _served(held, requests)
+    if what == "fork":
+        forks = [ServeEngine._generated_of(f) for eng in (ahead, held)
+                 for r in eng.finished.values() for f in r.forks]
+        assert len(forks) == 4 and forks[:2] == forks[2:]
+    steps = _count(ahead, "ptpu_engine_steps_total")
+    ran_ahead = _count(ahead, "ptpu_engine_steps_overlapped_total")
+    assert ran_ahead == sum(flags.values())
+    if what == "preempt":
+        # every victim's `prompt + generated` was whole
+        assert whole and all(whole)
+        assert sum(r.preemptions for r in ahead.finished.values()) > 0
+        assert 0 < ran_ahead < steps
+    elif what == "draft":
+        assert ran_ahead == 0 and ahead._m_spec_accepted.value > 0
+    elif what == "fork":
+        # the step behind the prompt's final chunk waited for the fork
+        first = min(r.req_id for r in ahead.finished.values()
+                    if r.n_candidates > 1)
+        forked = next(e["args"]["first_token_step"]
+                      for e in prof.get_events() if e["name"] == "request"
+                      and e["args"]["req"] == first)
+        assert flags[forked + 1] == 0 and 0 < ran_ahead < steps
+    else:
+        # the steps in which the sampled request drew a token, and the
+        # ones behind them: none ran ahead; the rest did
+        downloads = _count(ahead, "ptpu_engine_logit_downloads_total")
+        assert downloads == 6 and ran_ahead == steps - downloads - 1
+    assert ahead._step_fn._cache_size() == held._step_fn._cache_size() == 1
+    ahead.cache.assert_quiesced()
+
+
+def test_the_step_compiles_once_whatever_the_order(model_and_vars):
+    """`_step_fn` sees one kind of `tokens` operand: after the warm-up,
+    a synchronous step, steps launched behind one in flight and a drain
+    its cache holds one entry, and the merge beside it one."""
+    model, variables = model_and_vars
+    eng = _engine(model, variables, max_prefill_tokens=8)
+    eng.generate([[3, 1, 4]], max_new_tokens=2)     # the warm-up
+    sizes = [(eng._step_fn._cache_size(), eng._merge._cache_size())]
+    for p in PROMPTS:
+        eng.add_request(p, max_new_tokens=6)
+    ahead = eng._runs_ahead
+    eng._runs_ahead = lambda flight: False
+    assert eng.step() and eng._flight is None       # synchronous
+    sizes.append((eng._step_fn._cache_size(), eng._merge._cache_size()))
+    eng._runs_ahead = ahead
+    assert eng.step() and eng._flight is not None   # a second one out
+    assert eng.step() and eng._flight.overlapped
+    sizes.append((eng._step_fn._cache_size(), eng._merge._cache_size()))
+    eng.run()                                       # the drain
+    sizes.append((eng._step_fn._cache_size(), eng._merge._cache_size()))
+    assert sizes == [(1, 1)] * 4
+    assert eng._copy_blocks._cache_size() <= 1
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_a_block_of_generated_tokens_is_indexed_once_its_values_are_known(
+        kind):
+    """A sequence's length runs one token ahead of its values while a
+    step is in flight: a block filled by generated tokens enters the
+    prefix index only when its last value has landed, under the key of
+    the tokens it holds, and a later prompt hits it as it does after the
+    synchronous loop."""
+    ahead, held = _pair(kind)
+    prompt = [5, 9, 2]
+    hits = []
+    for eng in (ahead, held):
+        req = eng.add_request(prompt, max_new_tokens=11)
+        while eng.step():
+            known = req.prompt + req.generated
+            for key, block in eng.cache._index.items():
+                # never a key over a token the host has not seen
+                assert len(key) % 4 == 0 and list(key) == known[:len(key)]
+        text = prompt + req.generated
+        # positions 0..12 are in the pool: three full blocks of 4
+        assert sorted(len(k) for k in eng.cache._index) == [4, 8, 12]
+        again = eng.add_request(text[:13] + [7], max_new_tokens=3)
+        eng.run()
+        hits.append((again.cached_tokens, again.generated))
+    assert hits[0] == hits[1] and hits[0][0] == 12
+    assert _count(ahead, "ptpu_engine_steps_overlapped_total") > 0
+
+
+@pytest.mark.parametrize("kind", ["hybrid", "snapshot"])
+def test_a_snapshot_holds_its_boundary_though_the_next_step_was_out(kind):
+    """The chunk that ends on a snapshot boundary is followed on the
+    device's queue by the copy of its slot's state, before the next
+    step, launched ahead of the collect, moves the slot on: the
+    snapshot places hold what the synchronous loop's hold, and a prompt
+    that restores one is served the same tokens."""
+    model, variables, options = _layout(kind)
+    if kind == "hybrid":
+        options = dict(options, enable_prefix_cache=True, snapshot_tokens=8,
+                       snapshot_slots=4)
+    engines = [_engine(model, variables, registry=MetricsRegistry(),
+                       **options) for _ in range(2)]
+    ahead, held = engines
+    held._runs_ahead = lambda flight: False
+    kept = []
+    for eng in engines:
+        first = eng.generate([MIXED[0]], max_new_tokens=4)
+        assert eng.cache.snapshots_held == 3        # at 8, 16 and 24
+        places = {len(k): v[0] for k, v in eng.cache._snap_index.items()}
+        state = [np.asarray(snap[places[n]]) for n in (8, 16, 24)
+                 for snap, at in zip(eng.cache.snaps, eng.cache.snap_places)
+                 if eng.cache.kinds[at] != "window"]
+        req = eng.add_request(MIXED[3], max_new_tokens=6)
+        eng.run()
+        assert req.cached_tokens == 24
+        kept.append((first, state, req.generated))
+        eng.cache.assert_quiesced()
+    assert kept[0][0] == kept[1][0] and kept[0][2] == kept[1][2]
+    assert len(kept[0][1]) >= 3
+    for a, b in zip(kept[0][1], kept[1][1]):
+        np.testing.assert_array_equal(a, b)
+        assert np.abs(a).max() > 0
+    # the boundary chunks' successors were out before their collect
+    assert _count(ahead, "ptpu_engine_steps_overlapped_total") >= 3
+    assert _count(held, "ptpu_engine_steps_overlapped_total") == 0
+    # and the hit is what a cold engine serves
+    cold = _engine(model, variables, **dict(options, enable_prefix_cache=False)
+                   ).generate([MIXED[3]], max_new_tokens=6)[0]
+    assert kept[0][2] == cold

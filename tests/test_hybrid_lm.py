@@ -425,7 +425,10 @@ def test_a_snapshot_holds_rings_tail_and_state(toy):
                   snapshot_tokens=16, snapshot_slots=2, max_batch_size=1)
     prompt = _tokens(cfg, np.random.default_rng(23), 16)[0] + [5]
     req = eng.add_request(prompt, max_new_tokens=1)
-    eng.step()                                     # the chunk [0, 16)
+    # the chunk [0, 16), collected with nothing launched behind it: the
+    # pool is read as the boundary's chunk left it (tests/test_engine.py
+    # holds a snapshot to its boundary with the next step out)
+    eng._step(ahead=False)
     assert req.prefill_pos == 16 and eng.cache.snapshots_held == 1
     place, blocks, _ = eng.cache._snap_index[tuple(prompt[:16])]
     slot = eng.cache.slot(req.req_id)

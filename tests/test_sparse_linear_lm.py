@@ -367,11 +367,17 @@ def test_chunks_end_on_snapshot_boundaries(toy):
     req = eng.add_request(_tokens(cfg, np.random.default_rng(17), 75)[0],
                           max_new_tokens=1)
     ends = []
-    while req.prefilling or not ends:
-        eng.step()
-        ends.append(req.prefill_pos)
-    assert ends == [24, 32, 56, 64, 75]
+    plan = eng.scheduler.next_batch
+
+    def noted():
+        # (a call of `step()` plans the step behind the one it collects)
+        rows = plan()
+        ends.extend(w.start + w.length for w in rows or () if not w.decode)
+        return rows
+
+    eng.scheduler.next_batch = noted
     eng.run()
+    assert ends == [24, 32, 56, 64, 75] and req.finish_reason == "length"
 
 
 # -- the kernels -------------------------------------------------------------

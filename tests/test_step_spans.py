@@ -11,6 +11,7 @@ import glob
 import importlib
 import inspect
 import json
+import statistics
 import threading
 import time
 from unittest import mock
@@ -55,16 +56,23 @@ def _engine(model, variables, **kw):
     return ServeEngine(model, variables, **kw)
 
 
-@pytest.fixture
-def drained(model_and_vars):
+def _drain(model_and_vars, held=False):
     """A fresh engine that served PROMPTS (a prefill budget of 4 tokens:
-    several chunk steps a prompt), over an emptied ring."""
+    several chunk steps a prompt), over an emptied ring; `held`
+    synchronous, or with a second step in flight as it ships."""
     eng = _engine(*model_and_vars, max_prefill_tokens=4)
+    if held:
+        eng._runs_ahead = lambda flight: False
     eng.generate([[1, 2]], max_new_tokens=2)      # the one compilation
     eng.reset_stats()
     prof.reset_profiler()
     eng.generate(PROMPTS, max_new_tokens=6)
     return eng, prof.get_events()
+
+
+@pytest.fixture
+def drained(model_and_vars):
+    return _drain(model_and_vars)
 
 
 def _spans(events, name):
@@ -75,21 +83,65 @@ def _end(ev):
     return ev["ts"] + ev["dur"]
 
 
-def test_children_nest_in_their_step_and_cover_it(drained):
-    _, events = drained
+def _inside(events, outer, names=CHILDREN):
+    """The spans named `names` that lie inside `outer`, by start: how
+    `benchmarks/span_reduce.py` finds a step's children. A child's own
+    `step` is the step it works for, which is the next one's for the
+    launch half of a call that keeps a second step in flight."""
+    return sorted((e for e in events if e["name"] in names
+                   and e["tid"] == outer["tid"]
+                   and outer["ts"] <= e["ts"] and _end(e) <= _end(outer)),
+                  key=lambda e: e["ts"])
+
+
+LAUNCH, COLLECT = CHILDREN[:4], CHILDREN[4:]
+
+
+@pytest.mark.parametrize("held", [False, True], ids=["ahead", "held"])
+def test_children_nest_in_their_step_and_cover_it(model_and_vars, held):
+    """Every child lies inside one `engine.step`. A call's children, in
+    time: whole launches (plan, flush, pack, dispatch) of the steps it
+    sent out, then one collect (fetch, sample, publish) of the step
+    whose number the `engine.step` span carries. Held synchronous, a
+    call is its own step's seven and nothing else."""
+    _, events = _drain(model_and_vars, held)
     steps = _spans(events, "engine.step")
     assert len(steps) >= 6
+    homed = 0
     for st in steps:
-        kids = sorted((e for e in events if e["name"] in CHILDREN
-                       and e["args"]["step"] == st["args"]["step"]),
-                      key=lambda e: e["ts"])
-        assert [k["name"] for k in kids] == list(CHILDREN)
-        assert all(k["tid"] == st["tid"] for k in kids)
-        assert kids[0]["ts"] >= st["ts"] and _end(kids[-1]) <= _end(st)
+        kids = _inside(events, st)
+        homed += len(kids)
         for a, b in zip(kids, kids[1:]):
             assert _end(a) <= b["ts"], (a["name"], b["name"])
-    covered = sum(e["dur"] for e in events if e["name"] in CHILDREN)
-    assert covered >= 0.95 * sum(st["dur"] for st in steps)
+        names = [k["name"] for k in kids]
+        assert tuple(names[-3:]) == COLLECT
+        assert {k["args"]["step"] for k in kids[-3:]} == {st["args"]["step"]}
+        launches = kids[:-3]
+        assert len(launches) % 4 == 0 and len(launches) <= 8
+        if held:
+            assert [k["args"]["step"] for k in launches] == \
+                [st["args"]["step"]] * 4
+        for i in range(0, len(launches), 4):
+            group = launches[i:i + 4]
+            assert tuple(k["name"] for k in group) == LAUNCH
+            assert len({k["args"]["step"] for k in group}) == 1
+            assert group[0]["args"]["step"] >= st["args"]["step"]
+    assert homed == sum(e["name"] in CHILDREN for e in events)
+    # (with a second step in flight a call has a launch's and a
+    # collect's lines between its children, on a toy step of a
+    # millisecond: the synchronous call keeps the bound it had. The
+    # median call, since one that lost its core between two children,
+    # beside five busy test workers, read as low as 0.07)
+    covered = statistics.median(
+        sum(k["dur"] for k in _inside(events, st)) / st["dur"]
+        for st in steps)
+    assert covered >= (0.95 if held else 0.9)
+    # by its own number every step still has the seven, in their order
+    for st in steps:
+        mine = sorted((e for e in events if e["name"] in CHILDREN
+                       and e["args"]["step"] == st["args"]["step"]),
+                      key=lambda e: e["ts"])
+        assert [k["name"] for k in mine] == list(CHILDREN)
 
 
 def test_spans_carry_the_step_and_agree_with_the_counters(drained):
@@ -111,12 +163,63 @@ def test_spans_carry_the_step_and_agree_with_the_counters(drained):
                for e in _spans(events, "engine.fetch"))
     assert {"cow", "loads", "compress", "promote"} <= \
         set(_spans(events, "engine.flush")[0]["args"])
-    assert {"decode_rows", "chunk_rows", "queue_depth", "used_blocks"} <= \
-        set(steps[0]["args"])
+    assert {"decode_rows", "chunk_rows", "queue_depth", "used_blocks",
+            "overlapped", "discarded_rows"} <= set(steps[0]["args"])
+    # a step launched while the one before it was uncollected says so,
+    # and greedy traffic that ends by its count throws no row away
+    assert sum(st["args"]["overlapped"] for st in steps) == \
+        eng.obs.get("ptpu_engine_steps_overlapped_total").value > 0.5 * n
+    assert sum(st["args"]["discarded_rows"] for st in steps) == \
+        eng.obs.get("ptpu_engine_rows_discarded_total").value == 0
     # the histogram is fed from the span: one observation a step, and
     # the idle call that ends `run()` left neither
     assert sum(c.count for c in eng.obs.get(
         "ptpu_serve_step_ms").children().values()) == n
+
+
+def test_overlapped_and_discarded_rows_are_their_counters_deltas(
+        model_and_vars):
+    """`overlapped` (the step was launched while the one before it was
+    uncollected) and `discarded_rows` (rows whose request had ended
+    since the launch) on `engine.step` sum to the deltas of
+    `ptpu_engine_steps_overlapped_total` and
+    `ptpu_engine_rows_discarded_total`, with a request that ends on an
+    end-of-sequence token, one cancelled with a row out, and one at a
+    temperature (its steps wait for the host) in the traffic."""
+    eng = _engine(*model_and_vars)
+    free = eng.generate([PROMPTS[0]], max_new_tokens=8)[0]
+    names = ("ptpu_engine_steps_total", "ptpu_engine_steps_overlapped_total",
+             "ptpu_engine_rows_discarded_total")
+    before = [eng.obs.get(n).value for n in names]
+    prof.reset_profiler()
+    assert free.index(free[5]) == 5     # the sixth token ends it
+    eng.add_request(PROMPTS[0], max_new_tokens=8, eos_id=free[5])
+    drop = eng.add_request(PROMPTS[1], max_new_tokens=12)
+    eng.add_request(PROMPTS[2], max_new_tokens=1, temperature=0.7, seed=3)
+    for _ in range(6):
+        assert eng.step()
+    assert eng.cancel(drop)
+    eng.run()
+    events = prof.get_events()
+    steps = _spans(events, "engine.step")
+    n, ahead, dropped = (eng.obs.get(name).value - was
+                         for name, was in zip(names, before))
+    assert len(steps) == n
+    assert sum(st["args"]["overlapped"] for st in steps) == ahead
+    assert sum(st["args"]["discarded_rows"] for st in steps) == dropped
+    assert 0 < ahead < n and dropped == 2
+    assert all(st["args"]["overlapped"] in (0, 1) for st in steps)
+    # the emitted step's number on the span, children inside it
+    assert [st["args"]["step"] for st in steps] == \
+        list(range(steps[0]["args"]["step"], steps[0]["args"]["step"] + int(n)))
+    for st in steps:
+        kids = _inside(events, st)
+        assert {k["args"]["step"] for k in kids if k["name"] in COLLECT} == \
+            {st["args"]["step"]}
+        assert all(k["args"]["step"] == st["args"]["step"] + 1
+                   for k in kids if k["name"] in LAUNCH) \
+            or not st["args"]["overlapped"]
+    eng.cache.assert_quiesced()
 
 
 def test_ring_is_bounded_and_outlives_engine_and_front_end(model_and_vars):
@@ -299,7 +402,9 @@ def test_step_reads_the_clock_at_most_twice_a_span(drained):
     # the idle call that ended the loop opened two spans it discarded;
     # another test's front end may be waiting on its own thread
     mine = reads.count(threading.get_ident())
-    assert 0 < mine <= 2 * len(spans) + 4
+    # (a call that found nothing to launch behind its step discarded a
+    # plan span too: one reading each)
+    assert 0 < mine <= 2 * len(spans) + 4 + steps
     # and the engine has no other clock
     source = inspect.getsource(engine_mod)
     assert "perf_counter" not in source and "time.monotonic" not in source
@@ -314,15 +419,33 @@ def test_every_span_says_how_long_its_thread_ran(drained):
                for e in events)
     spans = [e for e in events if e["name"] not in ("request", "runtime.gc")]
     assert len(spans) >= 9 * 6
+    # the thread's clock may tick far more coarsely than `now_us` (every
+    # 10 ms on one machine, PERF.md section 6): a reading is then up to a
+    # tick behind, so one span's `cpu` may pass its `dur` by a tick, and
+    # the steps' sum the stretch they lie in by no more. The grain is
+    # measured here, not assumed
+    grain = _thread_clock_grain_us()
     for e in spans:
-        assert 0.0 <= e["cpu"] <= e["dur"] + 50.0, e
+        assert 0.0 <= e["cpu"] <= e["dur"] + grain + 50.0, e
+    steps = _spans(events, "engine.step")
+    assert sum(st["cpu"] for st in steps) <= \
+        _end(steps[-1]) - steps[0]["ts"] + grain + 50.0
     assert [e["cpu"] for e in _spans(events, "request")] == \
         [None] * len(PROMPTS)
-    # a parent ran at least as long as its children did
-    for st in _spans(events, "engine.step"):
-        kids = [e for e in events if e["name"] in CHILDREN
-                and e["args"]["step"] == st["args"]["step"]]
-        assert sum(k["cpu"] for k in kids) <= st["cpu"] + 50.0
+    # a parent ran at least as long as the children inside it did: their
+    # readings lie between its two, whatever the grain
+    for st in steps:
+        assert sum(k["cpu"] for k in _inside(events, st)) <= st["cpu"] + 50.0
+
+
+def _thread_clock_grain_us() -> float:
+    """The smallest step `time.thread_time_ns` shows while this thread
+    spins, in microseconds: the thread clock's grain on this machine."""
+    seen, deadline = set(), time.perf_counter() + 0.05
+    while time.perf_counter() < deadline or len(seen) < 2:
+        seen.add(time.thread_time_ns())
+    ticks = sorted(seen)
+    return min(b - a for a, b in zip(ticks, ticks[1:])) / 1e3
 
 
 def test_a_span_that_sleeps_was_off_the_cpu():
@@ -337,7 +460,7 @@ def test_a_span_that_sleeps_was_off_the_cpu():
         while time.thread_time() < deadline:
             pass
     (ev,) = _spans(prof.get_events(), "test.computes")
-    assert 20e3 <= ev["cpu"] <= ev["dur"] + 50.0
+    assert 20e3 <= ev["cpu"] <= ev["dur"] + _thread_clock_grain_us() + 50.0
 
 
 def test_a_reading_of_the_thread_clock_serves_the_boundaries_beside_it():
@@ -413,6 +536,7 @@ def test_one_deliver_a_hand_over_on_the_event_loops_thread(served):
     assert all(set(e["args"]) == {"step", "streams", "frames", "wake_us"}
                for e in delivers)
     assert all(e["args"]["streams"] == 1 for e in delivers)
+    grain = _thread_clock_grain_us()
     # its step is a step of the ring, the one that had just sampled
     samples = {e["args"]["step"]: e for e in _spans(events, "engine.sample")
                if e["ts"] > woke[0]["ts"] - 1e6}
@@ -421,7 +545,7 @@ def test_one_deliver_a_hand_over_on_the_event_loops_thread(served):
         assert sample["args"]["emitted"] == 1
         assert _end(sample) <= f["ts"] <= _end(f)
         assert sample["ts"] < d["ts"] + d["dur"]
-        assert 0.0 <= d["cpu"] <= d["dur"] + 50.0
+        assert 0.0 <= d["cpu"] <= d["dur"] + grain + 50.0
 
 
 def test_delivers_count_the_frames_the_client_received(served):
