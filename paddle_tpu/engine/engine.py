@@ -680,6 +680,12 @@ class ServeEngine:
             model = SparseLinearLM(
                 **meta["config"], dtype=jnp.dtype(meta["dtype"]),
                 param_dtype=jnp.dtype(meta["param_dtype"]))
+        elif meta.get("model_type") == "parallel_hybrid_lm":
+            from paddle_tpu.models.parallel_hybrid_lm import \
+                ParallelHybridLM
+            model = ParallelHybridLM(
+                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
+                param_dtype=jnp.dtype(meta["param_dtype"]))
         elif meta.get("model_type") == "hybrid_lm":
             from paddle_tpu.models.hybrid_lm import HybridLM
             model = HybridLM(

@@ -163,7 +163,10 @@ class CacheLayout:
       `"index": {"stride": s, "lanes": l}` adds an INDEX pool under the
       same tables, [blocks, block_size / s, l]: one row a `s` tokens
       (a sparse layer's compressed keys), shared and evicted with its
-      block;
+      block; `"arrays"` as the state kind's gives the layer state
+      arrays besides, in slots (a layer whose attention and recurrence
+      run side by side keeps both): the step is handed its pools, then
+      those arrays, and its sequences hold blocks and a slot;
     - {"kind": "window", "window": W}: a pool of RINGS, one a slot, of
       `ring_blocks` blocks each: logical block b of a sequence lives in
       ring place b mod ring_blocks, so a sequence holds at most
@@ -212,6 +215,10 @@ class CacheLayout:
                                      "none"):
                 raise ValueError(f"layer {i}: unknown cache kind "
                                  f"{layer['kind']!r}")
+            if layer.get("arrays") and layer["kind"] not in ("paged",
+                                                             "state"):
+                raise ValueError(f"layer {i}: a {layer['kind']} layer keeps "
+                                 "no state arrays")
             if layer["kind"] == "reads" and \
                     self.layers[layer["layer"]]["kind"] != "paged":
                 raise ValueError(f"layer {i} reads layer {layer['layer']}, "
@@ -231,7 +238,8 @@ class CacheLayout:
             -(-(self.window - 1 + chunk_tokens) // block_size) + 1
             if windows else 0)
         self.has_slots = bool(windows) or any(
-            layer["kind"] == "state" for layer in self.layers)
+            layer["kind"] == "state" or layer.get("arrays")
+            for layer in self.layers)
 
     def arrays(self, pool_shape, dtype):
         """[(kind, shape, dtype)] of the arrays the step is handed, in
@@ -245,12 +253,12 @@ class CacheLayout:
                     out.append(("index",
                                 (nb, bs // layer["index"]["stride"],
                                  layer["index"]["lanes"]), dtype))
+            if layer["kind"] in ("paged", "state"):
+                out += [("state", (self.slots + 1,) + tuple(shape), dt)
+                        for _, shape, dt in layer.get("arrays", ())]
             elif layer["kind"] == "window":
                 out.append(("window", (1 + self.slots * self.ring_blocks,
                                        bs, lanes), dtype))
-            elif layer["kind"] == "state":
-                out += [("state", (self.slots + 1,) + tuple(shape), dt)
-                        for _, shape, dt in layer["arrays"]]
         out.append(("rows", (self.slots + 1, 1 + self.ring_blocks),
                     jnp.int32))
         return out

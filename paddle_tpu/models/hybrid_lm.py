@@ -299,15 +299,22 @@ class DiffAttention(Module):
 
 
 class GatedFFN(Module):
-    def __init__(self, model_dim, ffn_dim, dtype, param_dtype):
+    """W2 (up . silu(gate_scale . gate)), [gate | up] = W1 y."""
+
+    def __init__(self, model_dim, ffn_dim, dtype, param_dtype,
+                 gate_scale: float = 1.0):
         super().__init__()
         self.model_dim, self.ffn_dim = model_dim, ffn_dim
         self.dtype, self.param_dtype = dtype, param_dtype
+        self.gate_scale = gate_scale
 
     def forward(self, cx: Context, y):
         gu = _dense(cx, "w1", y, 2 * self.ffn_dim, self.dtype,
                     self.param_dtype)
-        h = gu[..., self.ffn_dim:] * jax.nn.silu(gu[..., :self.ffn_dim])
+        gate = gu[..., :self.ffn_dim]
+        if self.gate_scale != 1.0:
+            gate = gate * self.gate_scale
+        h = gu[..., self.ffn_dim:] * jax.nn.silu(gate)
         return _dense(cx, "w2", h, self.model_dim, self.dtype,
                       self.param_dtype)
 

@@ -629,3 +629,100 @@ def test_sparse_linear_step_keeps_pools_and_state_in_place(one_chip):
         assert _pool_sized_copies(text, pool) == [], pool.shape
     held = sum(p.size * p.dtype.itemsize for p in pools[:-1])
     assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+def test_ssd_kernel_compiles_for_v5e(one_chip):
+    """The ragged SSD scan at `falconh1-reason`'s step: 512 flat
+    positions in 64 tiles, 32 heads of 128 in two groups of keys and
+    queries of 256, 33 slots of [32, 256, 128] float32, two grid cells a
+    tile (a group's 16 heads each), the state donated and aliased."""
+    from paddle_tpu.kernels.lightning_attention import ragged_ssd
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    t, h, p, g, n, nt, slots = 512, 32, 128, 2, 256, 64, 33
+    args = [s((t, h, p), jnp.float32), s((t, h), jnp.float32),
+            s((h,), jnp.float32), s((t, g, n), jnp.float32),
+            s((t, g, n), jnp.float32), s((h,), jnp.float32),
+            s((slots, h, n, p), jnp.float32)] + [s((nt,), jnp.int32)] * 4
+
+    def fn(*a):
+        return ragged_ssd(*a, use_kernel=True, interpret=False)
+    compiled = jax.jit(fn, donate_argnums=(6,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged_ssd" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        slots * h * n * p * 4
+
+
+def test_parallel_hybrid_step_at_its_cell_sizes(one_chip):
+    """The engine's step over layers that each keep a paged pool AND
+    state slots, compiled for the described chip at `falconh1-reason`'s
+    own sizes (all 6 layers, the whole vocabulary of 261,120, 1,024
+    blocks of 128, 33 slots; shapes only): the ragged paged kernel and
+    the SSD kernel are in it with both named scopes, every pool and
+    state array is aliased to the step's output, no copy of a pool's or
+    a state's size is in the program, and everything the step holds
+    fits the chip."""
+    import json
+    from unittest import mock
+
+    from paddle_tpu.engine.engine import compile_steps
+    from paddle_tpu.engine.paged_cache import CacheLayout
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.parallel_hybrid_lm import ParallelHybridLM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "falcon-h1-34b.json")) as f:
+        cfg = json.load(f)
+    model = ParallelHybridLM(
+        dtype=jnp.bfloat16,
+        **{k: cfg[v] for k, v in cfg["constructor_args"].items()})
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    s = cfg["serve"]
+    tq, b = s["tile_q"], s["max_batch_size"]
+    t = -(-s["max_prefill_tokens"] // tq) * tq + b * tq
+    mb = -(-s["max_seq_len"] // s["block_size"])
+    layout = CacheLayout(model.cache_layout, s["block_size"], b,
+                         s["max_prefill_tokens"])
+    heads, head_dim = model.kv_row
+    arrays = layout.arrays(
+        (s["num_blocks"], s["block_size"],
+         heads * paged_attention.head_lanes(head_dim)), jnp.bfloat16)
+    kinds = [kind for kind, _, _ in arrays]
+    assert kinds == ["paged", "state", "state"] * 6 + ["rows"]
+    pools = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for _, shape, dtype in arrays]
+    assert [p.shape for p in pools[:3]] == [
+        (1024, 128, 1024), (33, 32, 256, 128), (33, 3 * 5120)]
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    step, _ = compile_steps(model, shapes, False, None, kinds)
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = step.lower(
+            jax.tree.map(on_chip, shapes), i32(t), i32(t), pools, [], [],
+            i32(b + 1, mb), i32(b + 1), i32(b + 1), i32(t // tq),
+            i32(t // tq), i32(t), i32(b, 1)).compile()
+    text = compiled.as_text()
+    for name in ("tpu_custom_call", "ragged_ssd", "ragged_paged_attention",
+                 "ssd_scan", "parallel_mixer"):
+        assert name in text, name
+    for size in sorted({p.size for p in pools[:-1]}):
+        assert _pool_sized_copies(text, jax.ShapeDtypeStruct(
+            (size,), jnp.int8)) == [], size
+    _holds_the_picks(compiled, b, cfg["vocab_size"], jnp.float32)
+    mem = compiled.memory_analysis()
+    held = sum(p.size * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= held > 2.4e9
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"falconh1-reason step: {total} bytes compiled, "
+          f"{mem.temp_size_in_bytes} of them temporaries")
+    assert 0.25 * 16e9 < total < 15.5e9, total

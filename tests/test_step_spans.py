@@ -764,6 +764,39 @@ def test_slot_counts_agree_with_the_counters():
     assert eng._step_fn._cache_size() == 1
 
 
+def test_a_layer_of_two_kinds_counts_its_scan_and_its_rows():
+    """A model whose every layer keeps a paged pool AND a state slot
+    (attention and a Mamba-2 mixer side by side): `ssm_tokens` is the
+    real tokens through one layer's scan, `state_slots` the rows'
+    slots, `kv_rows_full` and `attn_keys_full` one layer's cached rows
+    read and keys attended; each span field sums to its counter."""
+    from paddle_tpu.models.parallel_hybrid_lm import ParallelHybridLM
+    model = ParallelHybridLM(
+        vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2, head_dim=8,
+        ffn_dim=32, num_layers=2, ssm_heads=4, ssm_head_dim=4, ssm_state=8,
+        ssm_groups=2, max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=8)
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    steps = _spans(prof.get_events(), "engine.step")
+
+    def total(field):
+        return sum(st["args"][field] for st in steps)
+    computed = sum(len(p) + 5 for p in PROMPTS)
+    assert total("ssm_tokens") == computed == eng.obs.get(
+        "ptpu_ssm_tokens_scanned_total").value
+    assert total("state_slots") == sum(
+        st["args"]["decode_rows"] + st["args"]["chunk_rows"] for st in steps)
+    assert total("kv_rows_full") == total("kv_tokens_read") == eng.obs.get(
+        "ptpu_attn_kv_rows_total").labels(kind="full").value
+    assert total("attn_keys_full") == total("attn_keys") == sum(
+        p + 1 for prompt in PROMPTS for p in range(len(prompt) + 5))
+    assert total("kv_rows_window") == total("window_blocks_released") == 0
+    assert eng._step_fn._cache_size() == 1
+
+
 def test_sparse_and_snapshot_counts_agree_with_the_counters():
     """A model of block-sparse and lightning layers, served with state
     snapshots: `sparse_rows_read`, `sparse_keys`, `index_rows_read` and
