@@ -583,13 +583,16 @@ class ServeEngine:
             np.asarray(self.cache.qpools[0][0])   # the host-spill gathers
             float(self.cache.qscales[0][0][0])
         self.max_blocks_per_seq = self.cache.blocks_for(self.max_seq_len)
-        # keys a grid cell of the ragged kernel covers (kernels/
+        # keys a span of the ragged kernel covers (kernels/
         # paged_attention.py `ragged_span`, on a chip's own pool rows):
-        # what `attn_cells` counts cells by
+        # what `attn_cells` counts spans by; a call's tiles x its table's
+        # spans, what a grid of spans would have stepped
         pool = self.cache.pools[self.cache.kinds.index("paged")]
-        self._cell_keys = block_size * ragged_span(
-            block_size, pool.shape[2] // self.tp_size, pool.dtype.itemsize,
-            self.max_blocks_per_seq)
+        span = ragged_span(block_size, pool.shape[2] // self.tp_size,
+                           pool.dtype.itemsize, self.max_blocks_per_seq)
+        self._cell_keys = block_size * span
+        self._grid_cells = self.num_tiles * -(-self.max_blocks_per_seq
+                                               // span)
         self.scheduler = Scheduler(
             self.cache, max_batch_size=max_batch_size,
             max_prefill_tokens=max_prefill_tokens,
@@ -758,9 +761,13 @@ class ServeEngine:
             "query tokens")
         self._m_attn_cells = m.counter(
             "ptpu_attn_cells_total",
-            "Grid cells with work the ragged kernel runs a layer: "
-            "summed over the steps' query tiles (pad tiles too), the "
-            "spans of pool blocks the tile reaches")
+            "Spans of pool blocks the ragged kernel walks a paged layer: "
+            "summed over the steps' query tiles, the spans the tile "
+            "reaches (a pad tile none)")
+        self._m_attn_skipped = m.counter(
+            "ptpu_attn_cells_skipped_total",
+            "Spans a paged layer's ragged kernel does not walk: the "
+            "steps' query tiles x their tables' spans, less the walked")
         self._m_ssm_tokens = m.counter(
             "ptpu_ssm_tokens_scanned_total",
             "Real tokens through the selective scan, a state-space layer")
@@ -1062,6 +1069,7 @@ class ServeEngine:
         self._m_kv_read.inc(asked["kv_tokens_read"])
         self._m_attn_keys.inc(asked["attn_keys"])
         self._m_attn_cells.inc(asked["attn_cells"])
+        self._m_attn_skipped.inc(asked["attn_cells_skipped"])
         if "moe_assignments" in asked:
             self._m_moe_assign.inc(asked["moe_assignments"])
             self._m_moe_active.inc(asked["moe_active_experts"])
@@ -1442,8 +1450,8 @@ class ServeEngine:
             # pad positions scatter into scratch block 0 (slot < bs)
             slots = np.zeros((t_flat,), np.int32)
             block_tables = np.zeros((b + 1, mb), np.int32)
-            # null/pad rows: scratch
-            context_lens = np.ones((b + 1,), np.int32)
+            # null/pad rows: scratch, and no key: a pad tile walks nothing
+            context_lens = np.zeros((b + 1,), np.int32)
             q_starts = np.zeros((b + 1,), np.int32)
             tile_rows = np.full((nt,), b, np.int32)  # pad tiles -> null row
             tile_offs = np.zeros((nt,), np.int32)
@@ -1486,8 +1494,8 @@ class ServeEngine:
                 attn_keys += (row.length * row.start
                               + row.length * (row.length + 1) // 2)
                 if self._sparse_counts is not None:
-                    for name, n in self._sparse_counts(
-                            row.start, row.length).items():
+                    row_sparse = self._sparse_counts(row.start, row.length)
+                    for name, n in row_sparse.items():
                         sparse[name] += n
                 if slotted:
                     ssm_tokens += row.length
@@ -1517,18 +1525,23 @@ class ServeEngine:
                     tile_rows[t0 + k] = i
                     tile_offs[t0 + k] = k * tq
                     # the tile reaches the row's context, cut at its
-                    # last query's causal edge: the spans up to there
-                    reach = row.start + min(row.length, (k + 1) * tq)
+                    # last query's causal edge: the spans up to there.
+                    # A sparse model's decode row reads its kept blocks
+                    # through a compacted table: its keys are its reach
+                    reach = (row_sparse["sparse_keys"]
+                             if self._sparse_counts is not None
+                             and row.length == 1 else
+                             row.start + min(row.length, (k + 1) * tq))
                     cells += -(-reach // self._cell_keys)
                 cursor += ntiles * tq
-            cells += nt - cursor // tq   # a pad tile: the null row's one
             if slotted:
                 # the rows table rides with the pools: this step's rows'
                 # slots and rings
                 self.cache.pools[-1] = jnp.asarray(self.cache.bind_rows(
                     [row.req.req_id for row in rows]))
             asked = {"kv_tokens_read": kv_read, "attn_keys": attn_keys,
-                     "attn_cells": cells}
+                     "attn_cells": cells,
+                     "attn_cells_skipped": self._grid_cells - cells}
             if self._sparse_counts is not None:
                 # what ONE sparse layer and kv head reads after
                 # selection; real tokens through a lightning layer

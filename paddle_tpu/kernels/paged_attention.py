@@ -207,8 +207,9 @@ def _scratch(shape):
 # packing back to sequences:
 #
 # - tile_rows [NT] int32: which metadata row each query tile belongs to
-#   (pad tiles point at a "null row" whose context_len is 1 and whose
-#   block table is all scratch block 0).
+#   (the engine points pad tiles at a "null row" whose context_len is 0
+#   and whose block table is all scratch block 0: such a tile reaches
+#   no key, walks no span and writes zeros).
 # - tile_offs [NT] int32: the tile's token offset WITHIN its row's
 #   segment, so a query's absolute position is
 #   q_starts[row] + tile_off + (index inside the tile).
@@ -224,24 +225,27 @@ def _scratch(shape):
 # carries p.v in lanes [D, 2D): no sub-tile lane slicing per block, and
 # a 128-deep contraction where head_dim 64 half-filled it.
 #
-# Grid: (query tiles, SPANS of the block table). A cell is one tile
-# against S consecutive table entries — S read off the pool's shape by
-# `ragged_span`, never an option — and does ONE online-softmax update
-# over S * BS keys. The pool never rides a BlockSpec: it stays in HBM
-# (memory_space ANY) and a cell with work copies its span's blocks
-# itself, one DMA a block into a [2, S, BS, lanes] scratch, the next
-# cell's span in flight while this one computes (`_ragged_cell`). A
-# cell costs the scalar core about 0.1 us an index map it evaluates,
-# work or not, so S index maps a cell buy nothing (PERF.md §6, PR 28);
-# a cell without work now costs its grid step and nothing else.
+# Grid: one cell a query tile. A SPAN is S consecutive table entries —
+# S read off the pool's shape by `ragged_span`, never an option — and a
+# tile walks the spans it has work in, in a loop, ONE online-softmax
+# update over S * BS keys each. Which spans those are is reckoned in
+# XLA before the call (`_tile_walk`: the tile's reach, its window) and
+# prefetched with the metadata, so a tile steps over no span and a tile
+# that reaches no key (a pad tile) walks none. The pool never rides a
+# BlockSpec: it stays in HBM (memory_space ANY) and a walked span
+# copies its blocks itself, one DMA a block into a [2, S, BS, lanes]
+# scratch, the next walked span's in flight while this one computes
+# (`_ragged_cell`). A grid cell costs the scalar core about 0.1 us an
+# index map it evaluates (PERF.md §6, PR 28): a grid of tiles x spans
+# paid it for every span a step's tiles had no work in.
 #
 # A LATENT pool (one entry a token, no head axis: `value_lanes=(0, V)`)
-# rides the same grid, cell and DMA: every one of the H query
+# rides the same grid, walk and DMA: every one of the H query
 # heads reads the one cached row (groups = H), q is [q~ | q_rope] padded
 # to the row's lanes, the scores contract the whole row and the
 # accumulator keeps lanes [0, V) — a query width and a value width that
 # differ and overlap. With one kv head the (query, head) pairs are
-# flattened to rows outside the kernel, so each cell is two plain 2-D
+# flattened to rows outside the kernel, so each span is two plain 2-D
 # matmuls and no packed operand is reshaped in it.
 #
 # Masking is absolute-position causal AND context-bounded
@@ -249,9 +253,10 @@ def _scratch(shape):
 # IS its index in block-table order, and a masked score sits at NEG_INF
 # and underflows to an exact 0 after the softmax's max-shift, which is
 # what the engine's exact batching-invariance tests lean on), so decode
-# rows, mid-prompt chunks and pad queries all fall out of one rule: pad
-# queries attend a finite prefix (never sampled), and kv position 0 is
-# always visible, so no softmax row is ever empty.
+# rows, mid-prompt chunks and pad queries all fall out of one rule: a
+# pad query in a row's last tile attends a finite prefix (never
+# sampled), kv position 0 is visible to every query of a tile that
+# reaches a key, and a tile that reaches none reads zeros.
 # ---------------------------------------------------------------------------
 
 
@@ -402,8 +407,8 @@ def _block_heads(rows, d: int):
 def _ragged_tile_update(q, kv, q0, ctx, k0, m_scr, l_scr, acc_scr, *,
                         scale: float, groups: int, window=None,
                         block_sel=None):
-    """Online-softmax update for one (query-tile, span of kv blocks)
-    cell — shared by the fp-only and mixed-precision ragged kernels. q:
+    """Online-softmax update for one query tile against one span of kv
+    blocks — shared by the fp-only and mixed-precision ragged kernels. q:
     [TQ, H, W], zero beyond lane D; kv: [Hkv, K, W], the span's K keys
     from absolute position k0 on, each head's [k | v | pad]; scratch
     rows are flattened TQ*H, the accumulator W lanes wide with p.v in
@@ -490,11 +495,11 @@ def _ragged_finalize(o_ref, l_scr, acc_scr, v_off=None):
                   ).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
-# What a grid cell may hold. A cell with work costs about a microsecond
-# before it has touched a key (PERF.md §6, PR 28), so it takes as many
-# blocks as pay: up to _SPAN_KEYS keys, and no more than _SPAN_BYTES of
-# pool blocks (two spans are in flight, and the cell's own relayout of
-# a span is as large again). The sweep on the chip put the best span
+# What a span may hold. A walked span costs about a microsecond before
+# it has touched a key (PERF.md §6, PR 28), so it takes as many blocks
+# as pay: up to _SPAN_KEYS keys, and no more than _SPAN_BYTES of pool
+# blocks (two spans are in flight, and the update's own relayout of a
+# span is as large again). The sweep on the chip put the best span
 # at 8 blocks for 16-token blocks of 2,048 and 2,560 lanes (16 is
 # slower) and at 4 for 128-token blocks of 640 lanes.
 _SPAN_KEYS = 512
@@ -503,42 +508,43 @@ _SPAN_BYTES = 768 << 10
 
 def ragged_span(block_size: int, lanes: int, itemsize: int,
                 max_blocks: int) -> int:
-    """How many consecutive block-table entries one grid cell of the
-    ragged kernel covers. Read off the pool's shape ([*, block_size,
-    lanes] of `itemsize` bytes, `lanes` whatever "The pool's row" makes
-    them — a tensor-parallel shard's own) and the table's width: the
-    largest power of two within _SPAN_KEYS keys, _SPAN_BYTES of blocks,
-    and the table."""
+    """How many consecutive block-table entries one span of the ragged
+    kernel covers: a tile walks its work a span at a time. Read off the
+    pool's shape ([*, block_size, lanes] of `itemsize` bytes, `lanes`
+    whatever "The pool's row" makes them — a tensor-parallel shard's
+    own) and the table's width: the largest power of two within
+    _SPAN_KEYS keys, _SPAN_BYTES of blocks, and the table."""
     fits = min(_SPAN_KEYS // block_size,
                _SPAN_BYTES // (block_size * lanes * itemsize), max_blocks)
     return 1 << (max(fits, 1).bit_length() - 1)
 
 
-def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
-                 load_span, o_ref, m_scr, l_scr, acc_scr, bufs, cnt, *,
-                 scale: float, span: int, tile_q: int, groups: int,
+def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, walk_ref, q_ref,
+                 span_copies, load_span, o_ref, m_scr, l_scr, acc_scr, bufs,
+                 cnt, *, scale: float, span: int, tile_q: int, groups: int,
                  v_off=None, window=None, sel_ref=None):
-    """One (query-tile, span) grid cell, shared by both ragged kernels.
-    The pools stay in HBM; a cell with work waits for its span's blocks
-    in one of two VMEM buffers and, before it computes, starts the
-    copies of the NEXT cell with work into the other — the next span of
-    this tile, else the first span of the next tile (every tile reaches
-    a key, so that cell exists and will wait for them). Cells without
-    work touch nothing: no index map rides the kv axis.
+    """One query tile, the grid's one cell a tile, shared by every
+    ragged kernel. It walks the spans it has work in, walk_ref[0, t] up
+    to walk_ref[1, t] (`_tile_walk`), in order, one online-softmax
+    update each; a tile with none walks nothing and writes zeros. The
+    pools stay in HBM: a walked span waits for its blocks in one of two
+    VMEM buffers and, before it computes, starts the copies of the NEXT
+    walked span into the other — the tile's next span, else the first
+    span of the next tile that has work, walk_ref[2, t + 1] (a pad tile
+    between them walks nothing, so that tile is found by index, not as
+    t + 1).
 
     `span_copies(row, j, slot)` lists span j of table row `row` as
     (place in the span, whether this copy serves the place, the DMA
     into buffer `slot`); `load_span(row, j, slot)` gives the span's
     keys as [Hkv, K, W] ([1, K, W] over a latent pool). Online-softmax
-    scratch is flattened to (TQ*H, ·) rows and persists across the
-    sequential kv axis.
+    scratch is flattened to (TQ*H, ·) rows.
 
     With a `window` (a query sees its `window` newest positions, its
-    own among them) a tile's work starts at the span that holds the
-    oldest key its FIRST query sees: the spans wholly behind it are
-    cells without work, and within the first span the blocks wholly
-    behind it are not copied."""
-    t, j = pl.program_id(0), pl.program_id(1)
+    own among them) a tile's walk starts at the span that holds the
+    oldest key its FIRST query sees, and within that span the blocks
+    wholly behind it are not copied."""
+    t, nt = pl.program_id(0), pl.num_programs(0)
     bs = bufs[0].shape[2]
     span_keys = span * bs
 
@@ -547,16 +553,13 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
         oldest key seen) of query tile ti. It attends positions below
         its row's context, cut at the causal edge of its LAST query
         (q0 + tile_q - 1): blocks at and past that reach have no work
-        for it (the engine's `attn_cells` counts cells by the same
-        rule)."""
+        for it (`_tile_walk` and the engine's `attn_cells` count spans
+        by the same rule)."""
         row = tr_ref[ti]
         ctx = cl_ref[row]
         q0 = qs_ref[row] + to_ref[ti]
         oldest = 0 if window is None else jnp.maximum(q0 - (window - 1), 0)
         return row, ctx, q0, jnp.minimum(ctx, q0 + tile_q), oldest
-
-    def first_span(ti):
-        return 0 if window is None else tile(ti)[4] // span_keys
 
     def move(go, ti, sj, slot):
         """Start (go) or await the copies of tile ti's span sj: its
@@ -573,9 +576,14 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
             def _():
                 copy.start() if go else copy.wait()
 
-    row, ctx, q0, reach, oldest = tile(t)
+    def start_tile(ti, slot):
+        """Start the copies of tile ti's first span, if ti is a tile
+        (nt: no tile with work is left)."""
+        @pl.when(ti < nt)
+        def _():
+            move(True, ti, walk_ref[0, ti], slot)
 
-    @pl.when((t == 0) & (j == 0))
+    @pl.when(t == 0)
     def _first():
         # a span's blocks past its tile's reach are never copied: what
         # the buffers hold there must be finite (masked scores give an
@@ -583,33 +591,26 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
         cnt[0] = 0
-        move(True, 0, first_span(0), 0)
+        start_tile(walk_ref[2, 0], 0)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    row, ctx, q0, _, _ = tile(t)
+    last = walk_ref[1, t] - 1
 
-    # a span has work if its FIRST key is within the tile's reach; the
-    # update's mask cuts the span the context or the causal edge ends
-    # in, block boundary or not
-    work = j * span_keys < reach
-    if window is not None:
-        work = work & ((j + 1) * span_keys > oldest)
-
-    @pl.when(work)
-    def _compute():
+    def walk(j, carry):
+        # the update's mask cuts the span the context or the causal
+        # edge ends in, block boundary or not
         slot = cnt[0] % 2
-        more = (j + 1) * span_keys < reach
 
-        @pl.when(more)
+        @pl.when(j < last)
         def _next_span():
             move(True, t, j + 1, 1 - slot)
 
-        @pl.when(jnp.logical_not(more) & (t + 1 < pl.num_programs(0)))
+        @pl.when(j == last)
         def _next_tile():
-            move(True, t + 1, first_span(t + 1), 1 - slot)
+            start_tile(walk_ref[2, t + 1], 1 - slot)
 
         move(False, t, j, slot)
         _ragged_tile_update(q_ref[...], load_span(row, j, slot), q0, ctx,
@@ -618,10 +619,32 @@ def _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
                             block_sel=(None if sel_ref is None
                                        else sel_ref[0, j]))
         cnt[0] += 1
+        return carry
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        _ragged_finalize(o_ref, l_scr, acc_scr, v_off)
+    jax.lax.fori_loop(walk_ref[0, t], last + 1, walk, 0)
+    _ragged_finalize(o_ref, l_scr, acc_scr, v_off)
+
+
+def _tile_walk(context_lens, q_starts, tile_rows, tile_offs, *,
+               tile_q: int, span_keys: int, window=None):
+    """What each query tile walks, in XLA from the call's metadata:
+    int32 [3, NT + 1], at column t the tile's first span with work and
+    the span past its last (a span has work if its first key is within
+    the tile's reach and, under a window, its last is not behind the
+    oldest key the tile's first query sees), and at column i the first
+    tile at or after i that walks any span (NT where none does; column
+    NT is NT)."""
+    nt = tile_rows.shape[0]
+    ctx = context_lens[tile_rows]
+    q0 = q_starts[tile_rows] + tile_offs
+    end = -(-jnp.minimum(ctx, q0 + tile_q) // span_keys)
+    first = (jnp.zeros_like(end) if window is None else
+             jnp.minimum(jnp.maximum(q0 - (window - 1), 0) // span_keys,
+                         end))
+    works = jnp.where(end > first, jnp.arange(nt), nt)
+    after = jax.lax.cummin(jnp.append(works, nt), reverse=True)
+    return jnp.stack([jnp.append(first, 0), jnp.append(end, 0),
+                      after]).astype(jnp.int32)
 
 
 def _block_copy(pool_ref, block, buf, sem, slot, place):
@@ -640,8 +663,8 @@ def _span_entries(bt_ref, row, j, span: int):
             for i in range(span)]
 
 
-def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
-                   o_ref, m_scr, l_scr, acc_scr, buf, sem, cnt, *,
+def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, walk_ref, q_ref,
+                   pool_ref, o_ref, m_scr, l_scr, acc_scr, buf, sem, cnt, *,
                    span: int, groups: int, **cell):
     """q_ref: [TQ, H, W] — one tile of the flat packing, lane-padded
     ([TQ*H, W] over a latent pool); pool_ref: the whole pool, in HBM;
@@ -656,20 +679,21 @@ def _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
         return (rows[None] if q_ref.ndim == 2 else
                 _block_heads(rows, o_ref.shape[-1]))
 
-    _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
-                 load_span, o_ref, m_scr, l_scr, acc_scr, (buf,), cnt,
-                 span=span, groups=groups, **cell)
+    _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, walk_ref, q_ref,
+                 span_copies, load_span, o_ref, m_scr, l_scr, acc_scr,
+                 (buf,), cnt, span=span, groups=groups, **cell)
 
 
-def _ragged_kernel_selected(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref,
-                            sel_ref, pool_ref, *rest, **cell):
+def _ragged_kernel_selected(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
+                            walk_ref, q_ref, sel_ref, pool_ref, *rest,
+                            **cell):
     """`_ragged_kernel` with one more operand: sel_ref [1, spans, TQ,
     span], the tile's block selection a span."""
-    _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, q_ref, pool_ref,
-                   *rest, sel_ref=sel_ref, **cell)
+    _ragged_kernel(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, walk_ref, q_ref,
+                   pool_ref, *rest, sel_ref=sel_ref, **cell)
 
 
-def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
+def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref, walk_ref,
                          ksc_ref, vsc_ref, q_ref, pool_ref, qpool_ref,
                          o_ref, m_scr, l_scr, acc_scr, buf, qbuf, sem, cnt,
                          *, span: int, groups: int, **cell):
@@ -706,9 +730,9 @@ def _ragged_kernel_mixed(bt_ref, cl_ref, qs_ref, tr_ref, to_ref,
             blocks.append(jnp.where(is8, (q8 * sc).astype(fp.dtype), fp))
         return jnp.concatenate(blocks, axis=1)
 
-    _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, q_ref, span_copies,
-                 load_span, o_ref, m_scr, l_scr, acc_scr, (buf, qbuf), cnt,
-                 span=span, groups=groups, **cell)
+    _ragged_cell(cl_ref, qs_ref, tr_ref, to_ref, walk_ref, q_ref,
+                 span_copies, load_span, o_ref, m_scr, l_scr, acc_scr,
+                 (buf, qbuf), cnt, span=span, groups=groups, **cell)
 
 
 # jitted so that a model's layers, which call it at one set of shapes,
@@ -759,7 +783,7 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
             raise ValueError(
                 "a block selection is read over one kv head's K/V pool, "
                 "with no window, int8 tier or latent row")
-        # [T, MB] -> a tile's [spans, TQ, span]: a cell takes its span's
+        # [T, MB] -> a tile's [spans, TQ, span]: a walked span takes its
         # selection by a leading index
         sel = jnp.pad(block_mask.astype(jnp.float32),
                       ((0, 0), (0, spans * span - mb)))
@@ -776,24 +800,24 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
         out_shape = (t, h, d)
     zeros = (0,) * (len(q_block) - 1)
 
-    def _q_map(ti, j, *prefetched):
+    def _q_map(ti, *prefetched):
         return (ti,) + zeros
 
     # the pools stay where they lie; the kernel copies the blocks it
     # needs itself (`_ragged_cell`), two spans in flight a pool
     pools = (kv_pool, kvq_pool) if mixed else (kv_pool,)
-    # block_tables, ctx_lens, q_starts, tiles x2 (+ k/v scales)
-    num_prefetch = 7 if mixed else 5
+    # block_tables, ctx_lens, q_starts, tiles x2, the walk (+ k/v scales)
+    num_prefetch = 8 if mixed else 6
     kernel_fn = (_ragged_kernel_mixed if mixed else
                  _ragged_kernel_selected if selected else
                  functools.partial(_ragged_kernel, v_off=v_off))
     sel_specs = ([pl.BlockSpec((1, spans, tq, span),
-                               lambda ti, j, *prefetched: (ti, 0, 0, 0))]
+                               lambda ti, *prefetched: (ti, 0, 0, 0))]
                  if selected else [])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
-        grid=(nt, spans),
+        grid=(nt,),
         in_specs=[pl.BlockSpec(q_block, _q_map)] + sel_specs
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec(o_block, _q_map),
@@ -812,16 +836,18 @@ def _ragged_kernel_call(q, kv_pool, block_tables, context_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-        # tiles in order too: a tile's last cell starts the next tile's
-        # first copies
+        # tiles in order: a tile's last span starts the copies of the
+        # next tile with work
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name or ("ragged_latent_attention" if latent else None),
     )
     scalars = (block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
                q_starts.astype(jnp.int32), tile_rows.astype(jnp.int32),
                tile_offs.astype(jnp.int32))
+    scalars += (_tile_walk(*scalars[1:], tile_q=tq, span_keys=span * bs,
+                           window=window),)
     if mixed:
         return call(*scalars, k_scales.astype(jnp.float32),
                     v_scales.astype(jnp.float32), q, kv_pool, kvq_pool)
@@ -878,7 +904,7 @@ def ragged_paged_attention(q, kv_pool, block_tables, context_lens,
     few blocks is better served by a COMPACTED table (the kept entries
     in order, the context shortened to match: without positions the
     kernel cannot tell), which costs the kernel nothing; the mask is for
-    rows whose queries differ, and its cells still copy their spans
+    rows whose queries differ, and its tiles still copy their spans
     whole."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)

@@ -371,54 +371,131 @@ def test_span_is_read_off_the_pool_shape(bs, lanes, itemsize, mb, want):
 # a span of 4 blocks (16 keys) they are: a context that ends in a
 # span's first block (18), in its last (31), exactly on a span's edge
 # (16, 32), a chunk whose tiles' causal edges fall inside a span (38/10:
-# queries 28..37), a whole prompt of one span, and the pad tile; the
-# table is 10 wide, no multiple of 2 or 4.
+# queries 28..37), a whole prompt of one span; the table is 10 wide, no
+# multiple of 2 or 4. Pad tiles lie as the engine lays them, on the null
+# row, which holds no key (context 0): two before the first row's tiles
+# and, after each row's in turn, 0, 3, 1, 0 and 2, so a walked span's
+# next copies jump over several to the next tile with work.
 SPAN_ROWS = [(18, 1), (31, 1), (32, 1), (38, 10), (16, 16)]
+PADS_AFTER = (0, 3, 1, 0, 2)
+
+
+def _pads_between(args, spans, tq):
+    """The flat packing of `_ragged_case` laid out anew with pad tiles
+    between and after its rows' (PADS_AFTER; no row: pads alone).
+    Returns (args, spans, the pad tiles)."""
+    q, pool, bt, cl, qs, tr, to = args
+    null = bt.shape[0] - 1
+    order, moved = [None, None], []       # an old tile a new one; None a pad
+    for (off, qlen), after in zip(spans, PADS_AFTER):
+        moved.append((len(order) * tq, qlen))
+        order += list(range(off // tq, off // tq + -(-qlen // tq)))
+        order += [None] * after
+    tiles = np.asarray(q).reshape(-1, tq, *q.shape[1:])
+    q = np.concatenate([np.zeros_like(tiles[0]) if k is None else tiles[k]
+                        for k in order])
+    tr = [null if k is None else int(tr[k]) for k in order]
+    to = [0 if k is None else int(to[k]) for k in order]
+    cl = np.asarray(cl).copy()
+    cl[null] = 0
+    pads = [i for i, k in enumerate(order) if k is None]
+    return ((jnp.asarray(q), pool, bt, jnp.asarray(cl), qs,
+             jnp.asarray(tr, jnp.int32), jnp.asarray(to, jnp.int32)),
+            moved, pads)
+
+
+def _compacted(args, spans, bs, seed=5):
+    """Block-sparse operands as `SparseAttention.ragged_step` builds
+    them: a decode row reads a random few of its blocks (the first and
+    its own always) through a compacted table, its context shortened to
+    match and its mask all true; a chunk's queries each keep a random
+    few blocks of the whole table (the first always). Returns (args,
+    block_mask [T, MB])."""
+    q, pool, bt, cl, qs, tr, to = args
+    rng = np.random.default_rng(seed)
+    bt, cl, qs = (np.asarray(a).copy() for a in (bt, cl, qs))
+    tr = np.asarray(tr)
+    tq = q.shape[0] // tr.shape[0]
+    mask = rng.random((q.shape[0], bt.shape[1])) < 0.5
+    mask[:, 0] = True
+    for off, qlen in spans:
+        if qlen != 1:
+            continue
+        i = int(tr[off // tq])
+        ctx, n = int(cl[i]), -(-int(cl[i]) // bs)
+        kept = sorted({0, n - 1} | set(np.flatnonzero(rng.random(n) < 0.4)))
+        bt[i] = 0
+        bt[i, :len(kept)] = np.asarray(args[2])[i, kept]
+        cl[i] = (len(kept) - 1) * bs + ctx - (ctx - 1) // bs * bs
+        qs[i] = cl[i] - 1
+        mask[off:off + tq] = True
+    return ((q, pool, jnp.asarray(bt), jnp.asarray(cl), jnp.asarray(qs),
+             jnp.asarray(tr), to), jnp.asarray(mask))
 
 
 def _span_case(kind, span, monkeypatch):
     from paddle_tpu.kernels import paged_attention as pa
     bs, tq = 4, 4
     h, hkv, d = {"mha": (4, 4, 8), "gqa": (8, 2, 16), "mixed": (4, 4, 8),
-                 "latent": (4, 1, 20)}[kind]
+                 "latent": (4, 1, 20), "window": (4, 4, 8),
+                 "sparse": (4, 1, 8), "pads": (4, 4, 8)}[kind]
     args, *_, spans = _ragged_case(SPAN_ROWS, h, hkv, d, bs, tq)
+    args, spans, pads = _pads_between(
+        args, [] if kind == "pads" else spans, tq)
     kw = dict(groups=h // hkv)
     if kind == "latent":      # one row a token, its first 16 the value
         k_pool, _ = unpack_kv(args[1], d)
         args = (args[0], jnp.asarray(pack_latent(np.asarray(k_pool[:, :, 0]),
                                                  latent_lanes(d))), *args[2:])
         kw.update(value_lanes=(0, 16), scale=0.3)
+    if kind == "window":      # the 38-token row's last tile sees 26 on
+        kw.update(window=11)
+    if kind == "sparse":
+        args, mask = _compacted(args, spans, bs)
+        kw.update(block_mask=mask)
     # S is read off the pool's shape: steered here, in the test, by the
-    # keys a cell may hold
+    # keys a span may hold
     monkeypatch.setattr(pa, "_SPAN_KEYS", span * bs)
     assert args[2].shape[1] == 10
     assert pa.ragged_span(bs, args[1].shape[2], 4, 10) == span
-    return args, kw, spans
+    return args, kw, spans, pads
+
+
+def _pad_rows(got, pads, tq):
+    """The outputs of the pad tiles, which walk nothing."""
+    return np.asarray(got).reshape(-1, tq, *got.shape[1:])[pads]
 
 
 @pytest.mark.parametrize("span", [1, 2, 4, 8])
-@pytest.mark.parametrize("kind", ["mha", "gqa", "latent"])
+@pytest.mark.parametrize("kind", ["mha", "gqa", "latent", "window", "sparse",
+                                  "pads"])
 def test_ragged_kernel_over_spans_matches_reference(kind, span, monkeypatch):
-    """The kernel in interpret mode against the XLA oracle where a cell
+    """The kernel in interpret mode against the XLA oracle where a span
     covers `span` blocks: 1 (a block already large), two that leave the
     table's tenth entry in a span of its own or in a short last span,
-    and 8 (one short span and a second one mostly past the table)."""
-    args, kw, spans = _span_case(kind, span, monkeypatch)
+    and 8 (one short span and a second one mostly past the table). Pad
+    tiles lie between and after the rows' (all of them under "pads")
+    and write zeros; under a window a tile's walk starts at the span
+    its first query's oldest key lies in (the 7th of 10 at a span of one
+    block); under a block mask decode rows read compacted tables beside
+    a chunk's masked full ones."""
+    args, kw, spans, pads = _span_case(kind, span, monkeypatch)
     got = ragged_paged_attention(*args, use_kernel=True, interpret=True, **kw)
     want = ragged_paged_attention_reference(*args, **{"scale": None, **kw})
     assert bool(jnp.isfinite(got).all())    # pad queries/tiles stay finite
+    assert pads and not _pad_rows(got, pads, 4).any()
     for off, qlen in spans:
         np.testing.assert_allclose(got[off:off + qlen], want[off:off + qlen],
                                    atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("span", [1, 2, 4])
+@pytest.mark.parametrize("span", [1, 2, 4, 8])
 def test_ragged_mixed_kernel_over_spans(span, monkeypatch):
     """The int8 tier through the same spans: each block of a span is
     dequantized by its own entry's scales (odd table entries are int8
     here, so every span mixes tiers), at the oracle's tolerance and
     bit for bit against promoting the blocks first."""
-    args, kw, spans = _span_case("mixed", span, monkeypatch)
+    args, kw, spans, pads = _span_case("mixed", span, monkeypatch)
     (margs, qkw), (pargs, _), n = _quantize_some_blocks(args)
     assert n > 0
     got = ragged_paged_attention(*margs, use_kernel=True, interpret=True,
@@ -430,3 +507,4 @@ def test_ragged_mixed_kernel_over_spans(span, monkeypatch):
     promoted = ragged_paged_attention(*pargs, use_kernel=True,
                                       interpret=True, **kw)
     assert np.array_equal(np.asarray(got), np.asarray(promoted))
+    assert not _pad_rows(got, pads, 4).any()

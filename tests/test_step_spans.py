@@ -653,17 +653,21 @@ def test_attention_counts_agree_with_the_counters(drained):
 def test_attn_cells_is_the_ragged_grid_s_cells_with_work(model_and_vars,
                                                          monkeypatch):
     """`attn_cells`: over the step's query tiles, the spans of pool
-    blocks the tile reaches (its row's context, cut at the tile's last
-    query's causal edge), a pad tile one. Held to the counter, and to a
-    count by hand for a step of one decode row, one two-tile chunk and
-    three pad tiles, where a cell is two 4-token blocks."""
+    blocks the kernel walks for the tile (its row's context, cut at the
+    tile's last query's causal edge), a pad tile none; and
+    `attn_cells_skipped` the rest of the step's tiles x its table's
+    spans. Held to their counters, and to a count by hand for a step of
+    one decode row, one two-tile chunk and three pad tiles, where a span
+    is two 4-token blocks."""
     from paddle_tpu.kernels import paged_attention
-    # the span is read off the pool's shape; steered here by a cell's keys
+    # the span is read off the pool's shape; steered here by a span's keys
     monkeypatch.setattr(paged_attention, "_SPAN_KEYS", 8)
     eng = _engine(*model_and_vars, max_prefill_tokens=16)
     assert (eng.num_tiles, eng.tile_q, eng._cell_keys) == (6, 8, 8)
+    assert eng._grid_cells == 6 * -(-eng.max_blocks_per_seq // 2)
     prof.reset_profiler()
-    before = eng.obs.get("ptpu_attn_cells_total").value
+    before = {name: eng.obs.get(f"ptpu_{name}_total").value
+              for name in ("attn_cells", "attn_cells_skipped")}
     first = eng.add_request(list(range(1, 6)), max_new_tokens=12)
     for _ in range(3):
         eng.step()
@@ -671,19 +675,23 @@ def test_attn_cells_is_the_ragged_grid_s_cells_with_work(model_and_vars,
     while eng.step():
         pass
     steps = _spans(prof.get_events(), "engine.step")
-    assert sum(st["args"]["attn_cells"] for st in steps) == \
-        eng.obs.get("ptpu_attn_cells_total").value - before
-    # the prompt alone: one tile reaching 5 keys, five pad tiles
-    assert steps[0]["args"]["attn_cells"] == 1 + 5
+    for name, was in before.items():
+        assert sum(st["args"][name] for st in steps) == \
+            eng.obs.get(f"ptpu_{name}_total").value - was
+    assert all(st["args"]["attn_cells"] + st["args"]["attn_cells_skipped"]
+               == eng._grid_cells for st in steps)
+    # the prompt alone: one tile reaching 5 keys; the five pad tiles walk
+    # nothing
+    assert steps[0]["args"]["attn_cells"] == 1
     mixed = [st["args"] for st in steps
              if st["args"]["decode_rows"] == 1 and st["args"]["chunk_rows"]]
     assert [a["chunk_tokens"] for a in mixed] == [16, 12]
     ctx = mixed[1]["kv_tokens_read"] - 28     # the decode row's context
     assert 5 < ctx <= 5 + len(first.generated)
-    # the chunk [16, 28): tiles reaching 24 and 28 keys, 3 and 4 cells
-    assert mixed[1]["attn_cells"] == -(-ctx // 8) + 3 + 4 + 3
+    # the chunk [16, 28): tiles reaching 24 and 28 keys, 3 and 4 spans
+    assert mixed[1]["attn_cells"] == -(-ctx // 8) + 3 + 4
     # the chunk [0, 16): its first tile stops at its own causal edge
-    assert mixed[0]["attn_cells"] == -(-(ctx - 1) // 8) + 1 + 2 + 3
+    assert mixed[0]["attn_cells"] == -(-(ctx - 1) // 8) + 1 + 2
 
 
 def test_expert_counts_agree_with_the_counters():
@@ -797,15 +805,19 @@ def test_a_layer_of_two_kinds_counts_its_scan_and_its_rows():
     assert eng._step_fn._cache_size() == 1
 
 
-def test_sparse_and_snapshot_counts_agree_with_the_counters():
+def test_sparse_and_snapshot_counts_agree_with_the_counters(monkeypatch):
     """A model of block-sparse and lightning layers, served with state
     snapshots: `sparse_rows_read`, `sparse_keys`, `index_rows_read` and
     `blocks_selected` are what ONE sparse layer and kv head reads after
     selection, `la_tokens` the real tokens through a lightning layer,
     `snapshots_taken` / `snapshots_restored` / `snapshot_tokens_skipped`
     the cache's snapshot traffic of the step; each span field sums to
-    the counter that goes with it."""
+    the counter that goes with it. A decode row's `attn_cells` are the
+    spans of its compacted table."""
+    from paddle_tpu.kernels import paged_attention
     from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
+    # a span of one 4-token block
+    monkeypatch.setattr(paged_attention, "_SPAN_KEYS", 4)
     sel = dict(dense_len=16, kernel=4, stride=2, block=4, init_blocks=1,
                local=8, topk=1)
     model = SparseLinearLM(
@@ -844,6 +856,14 @@ def test_sparse_and_snapshot_counts_agree_with_the_counters():
     dense = sum(p + 1 for n, new in rows for p in range(n + new - 1)) \
         - sum(p + 1 for p in range(24))
     assert 0 < total("sparse_keys") < dense == total("attn_keys")
+    # a decode row past dense_len walks the spans of its kept keys,
+    # fewer than its context's
+    assert eng._cell_keys == 4
+    alone = [st["args"] for st in steps
+             if st["args"]["decode_rows"] == 1 and not st["args"]["chunk_rows"]]
+    assert alone and all(a["attn_cells"] == -(-a["sparse_keys"] // 4)
+                         for a in alone)
+    assert all(a["attn_cells"] < -(-a["kv_tokens_read"] // 4) for a in alone)
     snaps = eng.obs.get("ptpu_state_snapshots_total")
     assert total("snapshots_restored") == 1 \
         == snaps.labels(event="restored").value
