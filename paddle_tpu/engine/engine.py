@@ -689,6 +689,11 @@ class ServeEngine:
             model = ParallelHybridLM(
                 **meta["config"], dtype=jnp.dtype(meta["dtype"]),
                 param_dtype=jnp.dtype(meta["param_dtype"]))
+        elif meta.get("model_type") == "conv_moe_lm":
+            from paddle_tpu.models.conv_moe_lm import ConvMoELM
+            model = ConvMoELM(
+                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
+                param_dtype=jnp.dtype(meta["param_dtype"]))
         elif meta.get("model_type") == "hybrid_lm":
             from paddle_tpu.models.hybrid_lm import HybridLM
             model = HybridLM(

@@ -86,8 +86,8 @@ def ragged_causal_conv(x, tails, weight, bias, slots, real, fresh, last,
     """Depthwise causal convolution of width K over the flat packing.
     x [T, D]; tails [S, (K-1) * D], a slot's last K-1 inputs, oldest
     first, flat (whole rows gather and scatter in place); weight [K, D]
-    (weight[K-1] meets the token itself), bias [D]. Returns
-    (x conv w + bias [T, D], float32; new tails).
+    (weight[K-1] meets the token itself), bias [D] or None (no bias).
+    Returns (x conv w + bias [T, D], float32; new tails).
 
     A tile's tokens are preceded by the K-1 inputs before them: the
     previous tile's last ones (a row's tiles are consecutive) or, in
@@ -106,8 +106,9 @@ def ragged_causal_conv(x, tails, weight, bias, slots, real, fresh, last,
     first = (tile_offs == 0)[:, None, None]
     seq = jnp.concatenate([jnp.where(first, held, before), xt], axis=1)
     w = weight.astype(jnp.float32)
-    out = bias.astype(jnp.float32) + sum(
-        w[j] * seq[:, j:j + tq] for j in range(k))
+    out = sum(w[j] * seq[:, j:j + tq] for j in range(k))
+    if bias is not None:
+        out = bias.astype(jnp.float32) + out
     # the K-1 inputs ending at the tile's last real token: seq[real:]
     at = real[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
     new = jnp.take_along_axis(seq, at[:, :, None], axis=1)

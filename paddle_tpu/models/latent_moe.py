@@ -183,20 +183,23 @@ def grouped_matmul(x, w, group_sizes):
 class RoutedExperts(Module):
     """`num_experts` routed gated-SiLU experts of which each token takes
     `top_k`, plus `num_shared` always-on ones (one FFN of their summed
-    width). Router: scores = sigmoid(W_g x) in float32; the chosen are
-    the top_k of scores + bias; their weights the scores (without the
-    bias) over their sum, times `scaling`."""
+    width; none at 0). Router: scores = sigmoid(W_g x) in float32; the
+    chosen are the top_k of scores + bias; their weights the scores
+    (without the bias) over their sum + `eps`, times `scaling`."""
 
     def __init__(self, model_dim: int, expert_dim: int, num_experts: int,
                  top_k: int, num_shared: int = 1, scaling: float = 1.0,
-                 dtype=jnp.float32, param_dtype=jnp.float32):
+                 dtype=jnp.float32, param_dtype=jnp.float32,
+                 eps: float = 1e-20):
         super().__init__()
         self.model_dim, self.expert_dim = model_dim, expert_dim
         self.num_experts, self.top_k = num_experts, top_k
-        self.scaling = scaling
+        self.scaling, self.eps = scaling, eps
         self.dtype, self.param_dtype = dtype, param_dtype
-        self.shared = GatedFFN(model_dim, expert_dim * num_shared, dtype,
-                               param_dtype)
+        self.num_shared = num_shared
+        if num_shared:
+            self.shared = GatedFFN(model_dim, expert_dim * num_shared, dtype,
+                                   param_dtype)
 
     def _route(self, cx: Context, x):
         """x [T, d] -> (chosen [T, k] int32, weights [T, k] float32)."""
@@ -210,7 +213,7 @@ class RoutedExperts(Module):
             precision=jax.lax.Precision.HIGHEST))
         _, chosen = jax.lax.top_k(scores + b.astype(jnp.float32), self.top_k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = (picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = (picked / (picked.sum(axis=-1, keepdims=True) + self.eps)
                    * self.scaling)
         return chosen.astype(jnp.int32), weights
 
@@ -218,8 +221,8 @@ class RoutedExperts(Module):
         """x [T, d] -> (y [T, d], tokens per expert [E] int32, the
         router's choices [T, k]). `real` [T] bool marks the rows that
         are tokens; the others are routed to no expert, counted nowhere,
-        and come out as the shared expert's output alone (nobody reads
-        them)."""
+        and come out as the shared expert's output alone, or zeros
+        (nobody reads them)."""
         t, d = x.shape
         e, k, f = self.num_experts, self.top_k, self.expert_dim
         routed, weights = self._route(cx, x)
@@ -243,7 +246,10 @@ class RoutedExperts(Module):
                 jnp.arange(t * k, dtype=order.dtype))
             pairs = jnp.take(ys, inverse, axis=0).reshape(t, k, d)
             y = jnp.einsum("tkd,tk->td", pairs.astype(jnp.float32), weights)
-        return y.astype(self.dtype) + self.shared(cx, x), counts, routed
+        y = y.astype(self.dtype)
+        if self.num_shared:
+            y = y + self.shared(cx, x)
+        return y, counts, routed
 
 
 class LatentMoEBlock(Module):

@@ -56,11 +56,13 @@ from paddle_tpu.nn.layers import Embedding, RMSNorm
 
 
 class Attention(Module):
-    """GQA with rotary over the whole head and a key multiplier.
-    `kv_row` is what one pool's row holds."""
+    """GQA with rotary over the whole head and a key multiplier; with
+    `qk_norm_eps`, q and k each through an RMSNorm over the head (a
+    learned scale of head_dim) before the rotary. `kv_row` is what one
+    pool's row holds."""
 
     def __init__(self, model_dim, num_heads, num_kv_heads, head_dim, theta,
-                 key_multiplier, dtype, param_dtype):
+                 key_multiplier, dtype, param_dtype, qk_norm_eps=None):
         super().__init__()
         self.model_dim, self.num_heads = model_dim, num_heads
         self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
@@ -69,6 +71,12 @@ class Attention(Module):
         self.dtype, self.param_dtype = dtype, param_dtype
         self.scale = 1.0 / math.sqrt(head_dim)
         self.kv_row = (num_kv_heads, head_dim)
+        self.qk_norm = qk_norm_eps is not None
+        if self.qk_norm:
+            self.q_norm = RMSNorm(qk_norm_eps, dtype=jnp.float32,
+                                  param_dtype=param_dtype)
+            self.k_norm = RMSNorm(qk_norm_eps, dtype=jnp.float32,
+                                  param_dtype=param_dtype)
 
     def _project(self, cx: Context, y, positions):
         """y [..., T, d] -> q [..., T, H, hd], k, v [..., T, Hkv, hd]."""
@@ -79,6 +87,8 @@ class Attention(Module):
         q = qkv[..., :h * hd].reshape(lead + (h, hd))
         k = qkv[..., h * hd:(h + kvh) * hd].reshape(lead + (kvh, hd))
         v = qkv[..., (h + kvh) * hd:].reshape(lead + (kvh, hd))
+        if self.qk_norm:
+            q, k = self.q_norm(cx, q), self.k_norm(cx, k)
         q = rotate(q.astype(jnp.float32), positions, self.theta)
         k = rotate(k.astype(jnp.float32) * self.key_multiplier, positions,
                    self.theta)

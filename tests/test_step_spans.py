@@ -721,6 +721,43 @@ def test_expert_counts_agree_with_the_counters():
     assert eng.expert_tokens.sum() == computed * 2 * 2
 
 
+def test_expert_counts_over_a_slotted_layout_agree_with_the_counters():
+    """A model whose layers keep a paged pool or a state slot AND whose
+    step returns tokens per expert: `moe_assignments` and
+    `moe_active_experts` are what they are over a paged-only layout,
+    each summing to its counter, beside the slots' own fields."""
+    from paddle_tpu.models.conv_moe_lm import ConvMoELM
+    model = ConvMoELM(
+        vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2, ffn_dim=32,
+        expert_dim=8, num_experts=8, top_k=2,
+        layer_types=["conv", "full_attention", "conv"], num_dense_layers=1,
+        max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=8)
+    assert eng.cache.kinds == ["state", "paged", "state", "rows"]
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    steps = _spans(prof.get_events(), "engine.step")
+
+    def total(field):
+        return sum(st["args"][field] for st in steps)
+    computed = sum(len(p) + 5 for p in PROMPTS)
+    # two expert layers, two choices a token
+    assert total("moe_assignments") == eng.obs.get(
+        "ptpu_moe_assignments_total").value == computed * 2 * 2 \
+        == eng.expert_tokens.sum()
+    assert total("moe_active_experts") == eng.obs.get(
+        "ptpu_moe_active_experts_total").value
+    assert all(0 < st["args"]["moe_active_experts"]
+               <= min(2 * 8, st["args"]["moe_assignments"]) for st in steps)
+    assert total("ssm_tokens") == computed == eng.obs.get(
+        "ptpu_ssm_tokens_scanned_total").value
+    assert total("state_slots") == sum(
+        st["args"]["decode_rows"] + st["args"]["chunk_rows"] for st in steps)
+    assert eng._step_fn._cache_size() == 1
+
+
 def test_slot_counts_agree_with_the_counters():
     """A model whose layers keep three kinds of state: `ssm_tokens` is
     the real tokens through the scan a state-space layer, `state_slots`
