@@ -19,9 +19,11 @@ values per head, nothing cached); the two agree to rounding
 (tests/test_latent_moe.py).
 
 The expert layer is sorted and dropless: the step's (row, choice) pairs
-are ordered by expert, one grouped matrix product per weight runs over
-the expert groups, the results are un-sorted, weighted and summed, and
-the shared expert is added. No capacity, so no token is dropped whatever
+are ordered by expert, the grouped products run over the expert groups
+(`kernels/grouped_product.py`: on the TPU one Pallas call for the gate
+and up, one for the down, each reading a touched expert's weights
+once), the results are un-sorted, weighted and summed, and the shared
+expert is added. No capacity, so no token is dropped whatever
 the imbalance; rows that are padding of the flat packing are routed
 nowhere and counted nowhere. (`parallel/moe.py` holds the training-side
 capacity-buffer experts; nothing of it is used here.)
@@ -35,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.module import Context, Module
+from paddle_tpu.kernels import grouped_product as grouped
 from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, Linear, RMSNorm
@@ -171,15 +174,6 @@ class GatedFFN(Module):
         return self.down(cx, jax.nn.silu(self.gate(cx, x)) * self.up(cx, x))
 
 
-def grouped_matmul(x, w, group_sizes):
-    """x [M, in] sorted by group, w [G, in, out], group_sizes [G] int32
-    -> [M, out]: rows of group g times w[g]. Rows past the groups' sum
-    come out as zeros."""
-    y = jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
-    rows = jnp.arange(x.shape[0], dtype=jnp.int32)
-    return jnp.where((rows < group_sizes.sum())[:, None], y, 0)
-
-
 class RoutedExperts(Module):
     """`num_experts` routed gated-SiLU experts of which each token takes
     `top_k`, plus `num_shared` always-on ones (one FFN of their summed
@@ -237,10 +231,9 @@ class RoutedExperts(Module):
             order = jnp.argsort(flat, stable=True)
             counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
             xs = jnp.take(x.astype(self.dtype), order // k, axis=0)
-            h = (jax.nn.silu(grouped_matmul(xs, gate.astype(self.dtype),
-                                            counts))
-                 * grouped_matmul(xs, up.astype(self.dtype), counts))
-            ys = grouped_matmul(h, down.astype(self.dtype), counts)
+            h = grouped.gated_grouped_product(
+                xs, gate.astype(self.dtype), up.astype(self.dtype), counts)
+            ys = grouped.grouped_product(h, down.astype(self.dtype), counts)
             # un-sort: pair (row, choice) sits at inverse[row * k + choice]
             inverse = jnp.zeros_like(order).at[order].set(
                 jnp.arange(t * k, dtype=order.dtype))

@@ -267,6 +267,46 @@ def test_latent_ragged_kernel_compiles_for_v5e(one_chip):
     assert "ragged_latent_attention" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("rows,d,f,experts", [
+    (2048, 2048, 1792, 32),     # lfm2-reason: 512 flat rows x 4
+    (4608, 2048, 1536, 64),     # glm47f-docs8k: 1,152 flat rows x 4
+], ids=["lfm2_reason", "glm47f_docs8k"])
+def test_grouped_product_compiles_for_v5e(one_chip, rows, d, f, experts):
+    """The experts' two grouped-product calls at the expert cells'
+    shapes, under the `moe_experts` scope as `RoutedExperts` makes
+    them: each call's instruction is named and lies under the scope
+    (`benchmarks/scope_reduce.py`, which `moe_roofline_pct` reads), and
+    the program holds no grouped product of the compiler's own."""
+    import sys
+    from unittest import mock
+
+    from paddle_tpu.kernels import grouped_product, paged_attention
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks.scope_reduce import scopes_of
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(x, gate, up, down, counts):
+        with jax.named_scope("moe_experts"):
+            h = grouped_product.gated_grouped_product(x, gate, up, counts)
+            return grouped_product.grouped_product(h, down, counts)
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        text = jax.jit(fn).lower(
+            s((rows, d)), s((experts, d, f)), s((experts, d, f)),
+            s((experts, f, d)), s((experts,), jnp.int32)).compile().as_text()
+    calls = [ln.split("=")[0].split()[-1].lstrip("%")
+             for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "grouped_gate_up", "grouped_product"]
+    under = scopes_of(text, ["moe_experts"])
+    assert all(under.get(c) == "moe_experts" for c in calls), calls
+    assert "ragged-dot" not in text
+
+
 @pytest.mark.parametrize("shape,grad,kernels", [
     ((1, 1024, 16, 64), False, ("flash_fwd",)),
     ((1, 1024, 16, 64), True, ("flash_fwd", "flash_bwd")),
@@ -330,6 +370,20 @@ def test_gpipe_backward_keeps_its_psum_in_the_tick_loop(topo):
 
 
 
+def _experts_are_the_kernel(text, compiled, cfg):
+    """The expert layers' products are the two grouped-product calls of
+    `kernels/grouped_product.py`, not the compiler's grouped product,
+    and the step's temporaries hold no buffer of an expert matrix's
+    size: no expert's weights are copied (concatenated, cast or laid
+    out anew) on their way to the kernel."""
+    assert "grouped_gate_up" in text and "grouped_product" in text
+    assert "ragged-dot" not in text
+    args = cfg["constructor_args"]
+    matrix = (cfg[args["num_experts"]] * cfg[args["model_dim"]]
+              * cfg[args["expert_dim"]] * 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < matrix
+
+
 def test_latent_expert_step_at_its_cell_sizes(one_chip):
     """The engine's step over a latent pool with routed experts,
     compiled for the described chip at `glm47f-docs8k`'s own sizes (all
@@ -382,6 +436,7 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
             i32(t // tq), i32(t), i32(b, 1)).compile()
     text = compiled.as_text()
     assert "ragged_latent_attention" in text and "tpu_custom_call" in text
+    _experts_are_the_kernel(text, compiled, cfg)
     assert _pool_sized_copies(text, pool) == []
     # an untied float32 head: 9.9 MB of logits that stay put
     _holds_the_picks(compiled, b, cfg["vocab_size"], jnp.float32)
@@ -390,6 +445,8 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
     assert mem.alias_size_in_bytes >= pool_bytes
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"glm47f-docs8k step: {total} bytes compiled, "
+          f"{mem.temp_size_in_bytes} of them temporaries")
     assert 0.25 * 16e9 < total < 15e9, total
 
 
@@ -789,6 +846,7 @@ def test_conv_moe_step_at_its_cell_sizes(one_chip):
     for name in ("tpu_custom_call", "ragged_paged_attention", "short_conv",
                  "moe_experts"):
         assert name in text, name
+    _experts_are_the_kernel(text, compiled, cfg)
     for size in sorted({p.size for p in pools[:-1]}):
         assert _pool_sized_copies(text, jax.ShapeDtypeStruct(
             (size,), jnp.int8)) == [], size
