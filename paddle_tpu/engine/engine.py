@@ -23,6 +23,11 @@ compile every step. Instead every device call runs at a FIXED shape:
   compile, ever — no more pow2 chunk buckets and no separate decode
   step. Pad positions scatter to the reserved scratch block 0
   (context_len 1, slot 0) so they can never touch a live sequence.
+  Only the tile kernels see those T rows: every product, norm and
+  elementwise chain runs on the step's tokens alone, at the compact
+  width `product_rows` = round_up(chunk_budget, tile_q) +
+  max_batch_size * spec_len (models/step_rows.py, ENGINE.md "Two
+  widths").
 - COW block copies run through one fixed-width compiled
   gather/scatter (`_copy_blocks`); unused lanes copy scratch block 0
   onto itself.
@@ -512,6 +517,11 @@ class ServeEngine:
             -(-max_prefill_tokens // tile_q) * tile_q
             + max_batch_size * (-(-self.spec_len // tile_q) * tile_q))
         self.num_tiles = self.flat_tokens // tile_q
+        # what the step's products run on: its tokens alone, in as many
+        # rows as a step can hold, the chunk budget and a whole window a
+        # row (models/step_rows.py reads the same from the step's shapes)
+        self.product_rows = (-(-max_prefill_tokens // tile_q) * tile_q
+                             + max_batch_size * self.spec_len)
         # host-RAM KV tier (engine/kvtier.py): a byte budget > 0 hangs
         # a second tier behind the pool — cached-free evictions and
         # preemptions demote block KV to host (int8-quantized when
@@ -741,6 +751,11 @@ class ServeEngine:
             labelnames=("kind",))        # kind=prefill|cached|generated
         self._m_steps = m.counter(
             "ptpu_engine_steps_total", "Compiled mixed steps executed")
+        self._m_product_rows = m.counter(
+            "ptpu_engine_product_rows_total",
+            "Rows the steps' products ran on: the steps' real tokens "
+            "(real), and the rest of each step's compact width (pad)",
+            labelnames=("kind",))        # kind=real|pad
         self._m_overlapped = m.counter(
             "ptpu_engine_steps_overlapped_total",
             "Steps launched while the step before them was still "
@@ -1023,6 +1038,7 @@ class ServeEngine:
             span.set(decode_rows=len(flight.decodes),
                      chunk_rows=len(flight.chunks),
                      chunk_tokens=flight.computed,
+                     product_rows=self.product_rows,
                      queue_depth=self.scheduler.queue_depth,
                      used_blocks=self.cache.used_blocks,
                      gc_us=self._gc_seen_us - gc_seen,
@@ -1125,6 +1141,9 @@ class ServeEngine:
         self.peak_occupancy = max(self.peak_occupancy,
                                   self.cache.occupancy())
         self._m_steps.inc()
+        real = sum(row.length for row in flight.rows)
+        self._m_product_rows.labels(kind="real").inc(real)
+        self._m_product_rows.labels(kind="pad").inc(self.product_rows - real)
         if flight.overlapped:
             self._m_overlapped.inc()
         if flight.discarded:
@@ -1539,6 +1558,13 @@ class ServeEngine:
                              row.start + min(row.length, (k + 1) * tq))
                     cells += -(-reach // self._cell_keys)
                 cursor += ntiles * tq
+            # the step's tokens fit the products' compact width
+            real = sum(row.length for row in rows)
+            if real > self.product_rows:
+                raise RuntimeError(
+                    f"a step of {real} tokens over its {self.product_rows} "
+                    "product rows: the plan broke the chunk budget or the "
+                    "batch")
             if slotted:
                 # the rows table rides with the pools: this step's rows'
                 # slots and rings

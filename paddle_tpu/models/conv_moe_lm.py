@@ -40,6 +40,7 @@ from paddle_tpu.kernels import selective_scan as scan
 from paddle_tpu.models.hybrid_lm import _dense
 from paddle_tpu.models.latent_moe import GatedFFN, RoutedExperts
 from paddle_tpu.models.parallel_hybrid_lm import Attention
+from paddle_tpu.models.step_rows import step_rows
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, RMSNorm
 
@@ -81,15 +82,17 @@ class ShortConv(Module):
         return self._out(cx, c, sum(w[j] * padded[:, j:j + t]
                                     for j in range(k)))
 
-    def ragged_step(self, cx: Context, y, tails, meta, tile_offs):
-        """y [T, d] over the flat packing. Returns (output, new tails)."""
+    def ragged_step(self, cx: Context, y, tails, meta, tile_offs, packing):
+        """y [T_c, d], the step's tokens (`packing`,
+        `models/step_rows.py`); the convolution runs over the flat
+        packing. Returns (output, new tails)."""
         slots, real, fresh, last = meta
         with jax.named_scope("short_conv"):
             c, u = self._gate(cx, y)
             v, tails = scan.ragged_causal_conv(
-                u, tails, self._weight(cx), None, slots, real, fresh, last,
-                tile_offs)
-            return self._out(cx, c, v), tails
+                packing.expand(u), tails, self._weight(cx), None, slots, real,
+                fresh, last, tile_offs)
+            return self._out(cx, c, packing.compact(v)), tails
 
 
 class ConvMoEBlock(Module):
@@ -225,9 +228,10 @@ class ConvMoELM(Module):
         `cache_layout`: an attention layer's paged pool, a conv layer's
         tails; last the ROWS table (a step row's state slot). Returns
         (logits, the same list updated, tokens per expert int32
-        [expert layers, E]). A flat position is a real token where it
-        lies among its tile's real positions (`scan.tile_meta`): the
-        others are routed to no expert."""
+        [expert layers, E]). Everything but the kernels runs on the
+        step's tokens alone, at the compact width
+        (`models/step_rows.py`); the rows past them are routed to no
+        expert."""
         if tp is not None or qpools:
             raise ValueError("recurrent state is served on one chip with no "
                              "int8 tier (engine/paged_cache.py)")
@@ -237,24 +241,28 @@ class ConvMoELM(Module):
         positions = positions.astype(jnp.int32)
         meta = scan.tile_meta(rows[:, 0], context_lens, q_starts, tile_rows,
                               tile_offs, tq)
-        real = (jnp.arange(t, dtype=jnp.int32) % tq) < jnp.repeat(meta[1], tq)
+        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                            last_idx, t)
+        tokens, positions, slots = map(packing.compact,
+                                       (tokens, positions, slots))
         out_pools, counts = [], []
-        x = self.embed(cx, tokens)                               # [T, D]
+        x = self.embed(cx, tokens)                               # [T_c, D]
         for blk, held in zip(self.blocks, arrays):
             c = cx.scope(blk._name)
             y = blk.ln1(c, x)
             if blk.kind == "conv":
                 mixed, held = blk.conv.ragged_step(
-                    c.scope("conv"), y, held, meta, tile_offs)
+                    c.scope("conv"), y, held, meta, tile_offs, packing)
             else:
                 mixed, held = blk.attn.ragged_step(
                     c.scope("attn"), y, held, positions, block_tables,
-                    context_lens, q_starts, tile_rows, tile_offs, slots)
+                    context_lens, q_starts, tile_rows, tile_offs, slots,
+                    packing)
             out_pools.append(held)
-            x, n = blk._feed(c, x + mixed, real)
+            x, n = blk._feed(c, x + mixed, packing.real)
             if n is not None:
                 counts.append(n)
-        idx = last_idx.astype(jnp.int32)
+        idx = packing.last
         logits = self._logits(cx, jnp.take(x, idx.reshape(-1), axis=0))
         return (logits.reshape(idx.shape + (logits.shape[-1],)),
                 out_pools + [rows],

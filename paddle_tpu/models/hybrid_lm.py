@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.module import Context, Module
 from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.kernels import selective_scan as scan
+from paddle_tpu.models.step_rows import step_rows
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, LayerNorm
 
@@ -112,6 +113,10 @@ class Mamba(Module):
         """From the convolution's output (float32): u' in the compute
         dtype, delta, B, C in float32."""
         u = jax.nn.silu(conv).astype(self.dtype)
+        return (u,) + self._selection(cx, u)
+
+    def _selection(self, cx: Context, u):
+        """delta, B, C (float32) from u'."""
         dbc = _dense(cx, "x_proj", u, self.dt_rank + 2 * self.d_state,
                      self.dtype, self.param_dtype, out=jnp.float32)
         dt = dbc[..., :self.dt_rank]
@@ -120,7 +125,7 @@ class Mamba(Module):
         delta = jax.nn.softplus(_dense(
             cx, "dt_proj", dt, self.d_inner, self.dtype, self.param_dtype,
             bias=True, out=jnp.float32))
-        return u, delta, b, c
+        return delta, b, c
 
     def _post(self, cx: Context, m, z):
         return _dense(cx, "out_proj", m * jax.nn.silu(z), self.model_dim,
@@ -154,20 +159,27 @@ class Mamba(Module):
         m = jnp.swapaxes(m, 0, 1).astype(self.dtype)
         return self._post(cx, m, z), m
 
-    def ragged_step(self, cx: Context, y, ssm, tails, meta, tile_offs):
-        """y [T, d] over the flat packing. Returns (output, memory m,
-        new scan state, new tails)."""
+    def ragged_step(self, cx: Context, y, ssm, tails, meta, tile_offs,
+                    packing):
+        """y [T_c, d], the step's tokens (`packing`,
+        `models/step_rows.py`); the convolution and the scan run over
+        the flat packing. Returns (output, memory m, new scan state, new
+        tails)."""
         p = self._params(cx)
         slots, real, fresh, last = meta
         u, z = self._pre(cx, y)
         with jax.named_scope("ssm_scan"):
             conv, tails = scan.ragged_causal_conv(
-                u, tails, p["conv_w"], p["conv_b"], slots, real, fresh,
-                last, tile_offs)
-            u, delta, b, c = self._ssm_inputs(cx, conv)
+                packing.expand(u), tails, p["conv_w"], p["conv_b"], slots,
+                real, fresh, last, tile_offs)
+            # u' where the scan reads it, and compact for its products
+            u = jax.nn.silu(conv).astype(self.dtype)
+            delta, b, c = map(packing.expand,
+                              self._selection(cx, packing.compact(u)))
             a = -jnp.exp(p["a_log"].astype(jnp.float32)).T
             m, ssm = scan.ragged_selective_scan(
                 u, delta, a, b, c, p["d"], ssm, slots, real, fresh)
+            m = packing.compact(m)
         return self._post(cx, m, z), m, ssm, tails
 
 
@@ -273,11 +285,12 @@ class DiffAttention(Module):
         return self._combine(cx, att), (k, v)
 
     def ragged_step(self, cx: Context, y, pool, table, slots, context_lens,
-                    q_starts, tile_rows, tile_offs):
-        """y [T, d] over the flat packing, `pool` this layer's own or
-        (cross) its full layer's, `table` the pool's block tables, `slots`
-        the flat rows its step's tokens are written to (None: nothing
-        to write). Returns (output, pool)."""
+                    q_starts, tile_rows, tile_offs, packing):
+        """y [T_c, d], the step's tokens (`packing`,
+        `models/step_rows.py`), `pool` this layer's own or (cross) its
+        full layer's, `table` the pool's block tables, `slots` the pool
+        rows the tokens are written to (None: nothing to write); the
+        kernel runs over the flat packing. Returns (output, pool)."""
         with jax.named_scope("diff_attention"):
             q, k, v = self._project(cx, y)
             t, hd = y.shape[0], self.head_dim
@@ -286,6 +299,7 @@ class DiffAttention(Module):
                 pool = paged.write_kv(pool, slots, k.reshape(t, kvp, width),
                                       v.reshape(t, kvp, width))
             # query head 2p sees the pair's first key, 2p+1 its second
+            q = packing.expand(q)
             first = jnp.arange(self.num_heads)[:, None] % 2 == 0
             wide = jnp.concatenate([jnp.where(first, q, 0),
                                     jnp.where(first, 0, q)], axis=-1)
@@ -294,7 +308,7 @@ class DiffAttention(Module):
                 tile_offs, scale=self.scale, groups=self.groups,
                 window=self.window,
                 name="ragged_diff_attention")            # [T, H, 2 hd]
-            out = self._combine(cx, att)
+            out = self._combine(cx, packing.compact(att))
         return out, pool
 
 
@@ -465,7 +479,9 @@ class HybridLM(Module):
         them), then the manager's ROWS table, int32 [rows, 1 + ring]:
         a step row's state slot, and the pool blocks of its window ring
         (logical block b of a sequence lives in ring place b mod ring).
-        Returns (logits, the same list updated)."""
+        Returns (logits, the same list updated). Everything but the
+        kernels runs on the step's tokens alone, at the compact width
+        (`models/step_rows.py`)."""
         if tp is not None or qpools:
             raise ValueError("recurrent state is served on one chip with no "
                              "int8 tier (engine/paged_cache.py)")
@@ -476,17 +492,17 @@ class HybridLM(Module):
         row_slots, ring = rows[:, 0], rows[:, 1:]
         meta = scan.tile_meta(row_slots, context_lens, q_starts, tile_rows,
                               tile_offs, tq)
+        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                            last_idx, t)
         # the window pools' block table by logical block, and the flat
         # pool row of each position (padding: scratch block 0)
         mb = block_tables.shape[1]
         places = jnp.arange(mb, dtype=jnp.int32) % ring.shape[1]
         window_table = ring[:, places]
         row_of = jnp.repeat(tile_rows, tq)
-        real = (jnp.tile(jnp.arange(tq, dtype=jnp.int32), nt)
-                < jnp.repeat(meta[1], tq))
         positions = positions.astype(jnp.int32)
         out_pools, memories, full = [], {}, {}
-        x = self.embed(cx, tokens)                               # [T, D]
+        x = self.embed(cx, packing.compact(tokens))              # [T_c, D]
         for i, blk in enumerate(self.blocks):
             c = cx.scope(blk._name)
             y = blk.ln1(c, x)
@@ -494,14 +510,14 @@ class HybridLM(Module):
             if blk.kind == "mamba":
                 ssm, tails = next(arrays), next(arrays)
                 mixed, memories[i], ssm, tails = blk.mixer.ragged_step(
-                    m, y, ssm, tails, meta, tile_offs)
+                    m, y, ssm, tails, meta, tile_offs, packing)
                 out_pools += [ssm, tails]
             elif blk.kind == "gmu":
                 mixed = blk.mixer.forward(m, y, memories[self.source[i]])
             elif blk.kind == "cross":
                 mixed, _ = blk.mixer.ragged_step(
                     m, y, out_pools[full[self.source[i]]], block_tables, None,
-                    context_lens, q_starts, tile_rows, tile_offs)
+                    context_lens, q_starts, tile_rows, tile_offs, packing)
             else:
                 pool = next(arrays)
                 if blk.kind == "window":
@@ -510,18 +526,19 @@ class HybridLM(Module):
                         window_table[row_of], (positions // bs)[:, None],
                         axis=1)[:, 0]
                     table = window_table
-                    rows_at = jnp.where(real, block * bs + positions % bs, 0)
+                    rows_at = jnp.where(packing.flat_real,
+                                        block * bs + positions % bs, 0)
                 else:
                     table, rows_at = block_tables, slots
                 mixed, pool = blk.mixer.ragged_step(
-                    m, y, pool, table, rows_at, context_lens, q_starts,
-                    tile_rows, tile_offs)
+                    m, y, pool, table, packing.compact(rows_at),
+                    context_lens, q_starts, tile_rows, tile_offs, packing)
                 if blk.kind == "full":
                     full[i] = len(out_pools)
                 out_pools.append(pool)
             x = blk.finish(c, x, mixed)
         hidden = self.norm_f(cx, x)
-        idx = last_idx.astype(jnp.int32)
+        idx = packing.last
         logits = self._logits(cx, jnp.take(hidden, idx.reshape(-1), axis=0))
         return (logits.reshape(idx.shape + (logits.shape[-1],)),
                 out_pools + [rows])

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.module import Context, Module
 from paddle_tpu.kernels import grouped_product as grouped
 from paddle_tpu.kernels import paged_attention as paged
+from paddle_tpu.models.step_rows import step_rows
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, Linear, RMSNorm
 
@@ -136,12 +137,13 @@ class LatentAttention(Module):
 
     def ragged_step_paged(self, cx: Context, x, positions, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots):
-        """The absorbed form over the flat ragged packing: x [T, d].
-        The step's latent rows are written into the pool at `slots`
-        first (in place on a donated pool), then one launch of the
-        ragged kernel serves every row against the pool as it lies.
-        Returns (out [T, d], new pool)."""
+                          tile_offs, slots, packing):
+        """The absorbed form over the step's tokens at the compact width
+        (`packing`, `models/step_rows.py`): x [T_c, d]. The step's latent
+        rows are written into the pool at `slots` first (in place on a
+        donated pool), then one launch of the ragged kernel serves every
+        row of the flat packing against the pool as it lies. Returns
+        (out [T_c, d], new pool)."""
         cx = cx.scope(self._name or type(self).__name__)
         with jax.named_scope("mla_attention"):
             q_nope, q_rope, c_kv, k_rope = self._project(cx, x, positions)
@@ -151,10 +153,11 @@ class LatentAttention(Module):
             q = jnp.concatenate(
                 [jnp.einsum("thn,chn->thc", q_nope, wk), q_rope], axis=-1)
             latent = paged.ragged_paged_attention(
-                q, kv_pool, block_tables, context_lens, q_starts, tile_rows,
-                tile_offs, scale=self.scale, groups=self.num_heads,
+                packing.expand(q), kv_pool, block_tables, context_lens,
+                q_starts, tile_rows, tile_offs, scale=self.scale,
+                groups=self.num_heads,
                 value_lanes=(0, self.kv_rank))          # [T, H, kv_rank]
-            o = jnp.einsum("thc,chv->thv", latent, wv)
+            o = jnp.einsum("thc,chv->thv", packing.compact(latent), wv)
         out = self.o(cx, o.reshape(x.shape[0], self.num_heads * self.v_dim))
         return out, kv_pool
 
@@ -275,15 +278,15 @@ class LatentMoEBlock(Module):
             chosen = chosen.reshape(b, t, -1)
         return x + y.reshape(b, t, d), chosen
 
-    def ragged_step_paged(self, cx: Context, x, positions, real, kv_pool,
+    def ragged_step_paged(self, cx: Context, x, positions, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots):
+                          tile_offs, slots, packing):
         cx = cx.scope(self._name or type(self).__name__)
         h, kv_pool = self.attn.ragged_step_paged(
             cx, self.ln1(cx, x), positions, kv_pool, block_tables,
-            context_lens, q_starts, tile_rows, tile_offs, slots)
+            context_lens, q_starts, tile_rows, tile_offs, slots, packing)
         x = x + h
-        y, counts, _ = self._feed(cx, self.ln2(cx, x), real)
+        y, counts, _ = self._feed(cx, self.ln2(cx, x), packing.real)
         return x + y, kv_pool, counts
 
 
@@ -370,31 +373,30 @@ class LatentMoELM(Module):
                           qpools=None, qscales=None):
         """The engine's one step (`CausalLM.ragged_step_paged` has the
         contract) over latent pools. Returns (logits, new pools, tokens
-        per expert int32 [expert layers, E]). A flat position is a real
-        token when its tile belongs to a row (not the null row, the
-        last of the metadata) and it lies inside the row's window."""
+        per expert int32 [expert layers, E]). Everything but the latent
+        kernel runs on the step's tokens alone, at the compact width
+        (`models/step_rows.py`); the rows past them are routed to no
+        expert."""
         if tp is not None or qpools:
             raise ValueError("a latent pool is served on one chip with no "
                              "int8 tier (engine/paged_cache.py)")
-        t, nt = tokens.shape[0], tile_rows.shape[0]
-        row_of = jnp.repeat(tile_rows, t // nt)
-        pos_of = (jnp.repeat(q_starts[tile_rows] + tile_offs, t // nt)
-                  + jnp.tile(jnp.arange(t // nt, dtype=jnp.int32), nt))
-        real = ((row_of < block_tables.shape[0] - 1)
-                & (pos_of < context_lens[row_of]))
-        positions = positions.astype(jnp.int32)
-        x = self.embed(cx, tokens)                               # [T, D]
+        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                            last_idx, tokens.shape[0])
+        tokens, slots = packing.compact(tokens), packing.compact(slots)
+        positions = packing.compact(positions.astype(jnp.int32))
+        x = self.embed(cx, tokens)                               # [T_c, D]
         new_pools, counts = [], []
         for blk, kv_pool in zip(self.blocks, pools):
             x, kv_pool, n = blk.ragged_step_paged(
-                cx, x, positions, real, kv_pool, block_tables, context_lens,
-                q_starts, tile_rows, tile_offs, slots)
+                cx, x, positions, kv_pool, block_tables, context_lens,
+                q_starts, tile_rows, tile_offs, slots, packing)
             new_pools.append(kv_pool)
             if n is not None:
                 counts.append(n)
         hidden = self.norm_f(cx, x)
-        idx = last_idx.astype(jnp.int32)
-        logits = self._logits(cx, jnp.take(hidden, idx.reshape(-1), axis=0))
-        return (logits.reshape(idx.shape + (logits.shape[-1],)), new_pools,
+        logits = self._logits(
+            cx, jnp.take(hidden, packing.last.reshape(-1), axis=0))
+        return (logits.reshape(packing.last.shape + (logits.shape[-1],)),
+                new_pools,
                 jnp.stack(counts) if counts else
                 jnp.zeros((0, self.num_experts), jnp.int32))

@@ -51,6 +51,7 @@ from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.kernels import selective_scan as scan
 from paddle_tpu.models.hybrid_lm import GatedFFN, _dense
 from paddle_tpu.models.sparse_linear_lm import rotate
+from paddle_tpu.models.step_rows import step_rows
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, RMSNorm
 
@@ -115,14 +116,17 @@ class Attention(Module):
                                             self.head_dim))
 
     def ragged_step(self, cx: Context, y, pool, positions, block_tables,
-                    context_lens, q_starts, tile_rows, tile_offs, slots):
-        """y [T, d] over the flat packing. Returns (output, pool)."""
+                    context_lens, q_starts, tile_rows, tile_offs, slots,
+                    packing):
+        """y [T_c, d], positions and slots [T_c]: the step's tokens
+        (`packing`, `models/step_rows.py`); the kernel runs over the flat
+        packing. Returns (output, pool)."""
         q, k, v = self._project(cx, y, positions)
         pool = paged.write_kv(pool, slots, k, v)
         att = paged.ragged_paged_attention(
-            q, pool, block_tables, context_lens, q_starts, tile_rows,
-            tile_offs, scale=self.scale, groups=self.groups)
-        return self._finish(cx, att), pool
+            packing.expand(q), pool, block_tables, context_lens, q_starts,
+            tile_rows, tile_offs, scale=self.scale, groups=self.groups)
+        return self._finish(cx, packing.compact(att)), pool
 
 
 class Mamba2(Module):
@@ -225,20 +229,23 @@ class Mamba2(Module):
             + p["d"].astype(jnp.float32)[:, None] * x
         return self._post(cx, y, z)
 
-    def ragged_step(self, cx: Context, u, ssm, tails, meta, tile_offs):
-        """u [T, d] over the flat packing. Returns (output, new scan
-        state, new tails)."""
+    def ragged_step(self, cx: Context, u, ssm, tails, meta, tile_offs,
+                    packing):
+        """u [T_c, d], the step's tokens (`packing`,
+        `models/step_rows.py`); the convolution and the scan run over
+        the flat packing. Returns (output, new scan state, new tails)."""
         p = self._params(cx)
         slots, real, fresh, last = meta
         z, xbc, dt = self._pre(cx, u)
         with jax.named_scope("ssd_scan"):
             conv, tails = scan.ragged_causal_conv(
-                xbc, tails, p["conv_w"], p["conv_b"], slots, real, fresh,
-                last, tile_offs)
-            x, b, c, delta, a = self._scan_inputs(p, conv, dt)
+                packing.expand(xbc), tails, p["conv_w"], p["conv_b"], slots,
+                real, fresh, last, tile_offs)
+            x, b, c, delta, a = self._scan_inputs(p, conv,
+                                                  packing.expand(dt))
             y, ssm = recurrence.ragged_ssd(x, delta, a, b, c, p["d"], ssm,
                                            slots, real, fresh, tile_offs)
-        return self._post(cx, y, z), ssm, tails
+        return self._post(cx, packing.compact(y), z), ssm, tails
 
 
 class ParallelBlock(Module):
@@ -368,7 +375,9 @@ class ParallelHybridLM(Module):
         contract). `pools` is the cache manager's list for this model's
         `cache_layout`: each layer's paged pool, then its scan state and
         its tails; last the ROWS table (a step row's state slot).
-        Returns (logits, the same list updated)."""
+        Returns (logits, the same list updated). Everything but the
+        kernels runs on the step's tokens alone, at the compact width
+        (`models/step_rows.py`)."""
         if tp is not None or qpools:
             raise ValueError("recurrent state is served on one chip with no "
                              "int8 tier (engine/paged_cache.py)")
@@ -378,8 +387,12 @@ class ParallelHybridLM(Module):
         positions = positions.astype(jnp.int32)
         meta = scan.tile_meta(rows[:, 0], context_lens, q_starts, tile_rows,
                               tile_offs, t // nt)
+        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                            last_idx, t)
+        tokens, positions, slots = map(packing.compact,
+                                       (tokens, positions, slots))
         out_pools = []
-        x = self.embed(cx, tokens) * self.embedding_multiplier   # [T, D]
+        x = self.embed(cx, tokens) * self.embedding_multiplier   # [T_c, D]
         for blk in self.blocks:
             c = cx.scope(blk._name)
             y = blk.ln1(c, x)
@@ -388,13 +401,13 @@ class ParallelHybridLM(Module):
                 attended, pool = blk.attn.ragged_step(
                     c.scope("attn"), y * self.attn_in, pool, positions,
                     block_tables, context_lens, q_starts, tile_rows,
-                    tile_offs, slots)
+                    tile_offs, slots, packing)
                 scanned, ssm, tails = blk.ssm.ragged_step(
                     c.scope("ssm"), y * self.ssm_in, ssm, tails, meta,
-                    tile_offs)
+                    tile_offs, packing)
             out_pools += [pool, ssm, tails]
             x = self._finish(c, blk, x, attended, scanned)
-        idx = last_idx.astype(jnp.int32)
+        idx = packing.last
         logits = self._logits(cx, jnp.take(x, idx.reshape(-1), axis=0))
         return (logits.reshape(idx.shape + (logits.shape[-1],)),
                 out_pools + [rows])

@@ -54,6 +54,7 @@ from paddle_tpu.kernels import lightning_attention as lightning
 from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.kernels import selective_scan as scan
 from paddle_tpu.models.hybrid_lm import GatedFFN, _dense
+from paddle_tpu.models.step_rows import step_rows
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, RMSNorm
 
@@ -196,18 +197,20 @@ class SparseAttention(Module):
             att = jnp.einsum("bkgqj,bjkd->bqkgd", a.astype(v.dtype), v)
             return self._finish(cx, att, gate)
 
-    def ragged_step(self, cx: Context, y, pools, index, pk):
-        """y [T, d] over the flat packing; `pools` this layer's paged
-        pools, one a kv head; `index` its index pool; `pk` the step's
-        packing (`SparseLinearLM._packing`). Returns (output, pools,
-        index)."""
+    def ragged_step(self, cx: Context, y, pools, index, pk, packing):
+        """y [T_c, d], the step's tokens (`packing`,
+        `models/step_rows.py`); `pools` this layer's paged pools, one a
+        kv head; `index` its index pool; `pk` the step's packing
+        (`SparseLinearLM._packing`), over which the selection and the
+        kernel run. Returns (output, pools, index)."""
         sel, hd, g = self.sel, self.head_dim, self.groups
         q, k, v, gate = self._project(cx, y)
-        t = y.shape[0]
         nt, tq = pk["tile_rows"].shape[0], pk["tq"]
-        pools = [paged.write_kv(pool, pk["slots"], k[:, i:i + 1],
-                                v[:, i:i + 1])
+        t = nt * tq
+        slots = packing.compact(pk["slots"])
+        pools = [paged.write_kv(pool, slots, k[:, i:i + 1], v[:, i:i + 1])
                  for i, pool in enumerate(pools)]
+        q = packing.expand(q)
         with jax.named_scope("sparse_select"):
             # the windows this step completes: their keys' mean, from the
             # pool (the chunk's own rows are in it now), a kv head. A
@@ -256,7 +259,7 @@ class SparseAttention(Module):
                     groups=g, block_mask=mask,
                     name="ragged_sparse_attention"))
             att = jnp.concatenate(outs, axis=1)            # [T, H, hd]
-            out = self._finish(cx, att, gate)
+            out = self._finish(cx, packing.compact(att), gate)
         return out, pools, index
 
 
@@ -320,15 +323,19 @@ class LightningAttention(Module):
                 jnp.swapaxes(x, 0, 1) for x in (q, k, v)))
             return self._finish(cx, jnp.swapaxes(o, 0, 1), gate)
 
-    def ragged_step(self, cx: Context, y, state, positions, meta, tile_offs):
-        """y [T, d] over the flat packing. Returns (output, new state)."""
+    def ragged_step(self, cx: Context, y, state, positions, meta, tile_offs,
+                    packing):
+        """y [T_c, d] and positions [T_c], the step's tokens (`packing`,
+        `models/step_rows.py`); the kernel runs over the flat packing.
+        Returns (output, new state)."""
         with jax.named_scope("lightning_attention"):
             q, k, v, gate = self._project(cx, y, positions)
             slots, real, fresh, _ = meta
             o, state = lightning.ragged_lightning_attention(
-                q, k, v, jnp.asarray(self.log_decay, jnp.float32), state,
+                *map(packing.expand, (q, k, v)),
+                jnp.asarray(self.log_decay, jnp.float32), state,
                 slots, real, fresh, tile_offs)
-            return self._finish(cx, o, gate), state
+            return self._finish(cx, packing.compact(o), gate), state
 
 
 class SparseLinearBlock(Module):
@@ -550,7 +557,9 @@ class SparseLinearLM(Module):
         `cache_layout`: a sparse layer's paged pools, one a kv head,
         then its index pool; a lightning layer's state; last the ROWS
         table (a step row's state slot). Returns (logits, the same list
-        updated)."""
+        updated). Everything but the kernels and the selection runs on
+        the step's tokens alone, at the compact width
+        (`models/step_rows.py`)."""
         if tp is not None or qpools:
             raise ValueError("recurrent state is served on one chip with no "
                              "int8 tier (engine/paged_cache.py)")
@@ -561,13 +570,14 @@ class SparseLinearLM(Module):
         positions = positions.astype(jnp.int32)
         meta = scan.tile_meta(rows[:, 0], context_lens, q_starts, tile_rows,
                               tile_offs, tq)
-        real = (jnp.tile(jnp.arange(tq, dtype=jnp.int32), nt)
-                < jnp.repeat(meta[1], tq))
+        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                            last_idx, t)
         pk = self._packing(positions, block_tables, context_lens, q_starts,
-                           tile_rows, tile_offs, slots, nt, tq, real) \
-            if self.sparse_layers else None
+                           tile_rows, tile_offs, slots, nt, tq,
+                           packing.flat_real) if self.sparse_layers else None
         out_pools = []
-        x = self.embed(cx, tokens) * self.scale_emb              # [T, D]
+        positions_c = packing.compact(positions)
+        x = self.embed(cx, packing.compact(tokens)) * self.scale_emb
         for blk in self.blocks:
             c = cx.scope(blk._name)
             m = c.scope("mixer")
@@ -575,14 +585,15 @@ class SparseLinearLM(Module):
             if blk.kind == "minicpm4":
                 mine = [next(arrays) for _ in range(blk.mixer.num_kv_heads)]
                 mixed, mine, index = blk.mixer.ragged_step(
-                    m, y, mine, next(arrays), pk)
+                    m, y, mine, next(arrays), pk, packing)
                 out_pools += mine + [index]
             else:
                 mixed, state = blk.mixer.ragged_step(
-                    m, y, next(arrays), positions, meta, tile_offs)
+                    m, y, next(arrays), positions_c, meta, tile_offs,
+                    packing)
                 out_pools.append(state)
             x = self._mix(c, blk, x, mixed)
-        idx = last_idx.astype(jnp.int32)
+        idx = packing.last
         logits = self._logits(cx, jnp.take(x, idx.reshape(-1), axis=0))
         return (logits.reshape(idx.shape + (logits.shape[-1],)),
                 out_pools + [rows])

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.module import Context, Module, PARAMS
 from paddle_tpu.kernels import attention as attn_kernel
 from paddle_tpu.kernels import paged_attention as paged
+from paddle_tpu.models.step_rows import step_rows
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Dropout, Embedding, LayerNorm, Linear
 from paddle_tpu.ops import functional as F
@@ -187,15 +188,16 @@ class MultiHeadAttention(Module):
 
     def ragged_step_paged(self, cx: Context, x, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, tp=None, qpool=None):
+                          tile_offs, slots, packing, tp=None, qpool=None):
         """Mixed prefill+decode step over the FLAT ragged packing
-        (kernels/paged_attention.py ragged_paged_attention): x: [T, D]
-        — decode rows and prefill chunks packed into tile-aligned
-        segments, no batch axis. The step's k/v is scattered into the
-        pool at `slots` [T] first (pad positions land in scratch
-        block 0; in place when the caller donates the pool), then one
-        attention launch reads the pool as it lies and serves every
-        row. Returns (out [T, D], new_kv_pool). `tp`
+        (kernels/paged_attention.py ragged_paged_attention): x: [T_c, D]
+        — the step's tokens at the compact width (`packing`, a
+        `models.step_rows.StepRows`), no batch axis. The step's k/v is
+        scattered into the pool at `slots` [T_c] first (past the tokens
+        into scratch block 0; in place when the caller donates the
+        pool), then the queries are laid out in the flat packing's
+        tiles and one attention launch reads the pool as it lies and
+        serves every row. Returns (out [T_c, D], new_kv_pool). `tp`
         (parallel.serve_collective.ServeTP or None) routes the attention
         through an explicit shard_map island over the mesh's "tp" axis
         — heads/kv-heads device-local, metadata replicated; the
@@ -222,12 +224,13 @@ class MultiHeadAttention(Module):
         attend = (paged.ragged_paged_attention if tp is None else
                   functools.partial(paged.ragged_paged_attention_tp,
                                     tp.mesh))
-        out = attend(qh, kv_pool, block_tables, context_lens, q_starts,
-                     tile_rows, tile_offs,
+        out = attend(packing.expand(qh), kv_pool, block_tables,
+                     context_lens, q_starts, tile_rows, tile_offs,
                      groups=self.num_heads // self.num_kv_heads,
                      kvq_pool=kvq, k_scales=ksc,
                      v_scales=vsc)                         # [T, H, hd]
-        out = self.out_proj(cx, out.reshape(t, self.model_dim))
+        out = self.out_proj(cx, packing.compact(out).reshape(
+            t, self.model_dim))
         return out, kv_pool
 
 
@@ -439,12 +442,12 @@ class CausalBlock(Module):
 
     def ragged_step_paged(self, cx: Context, x, kv_pool,
                           block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, tp=None, qpool=None):
+                          tile_offs, slots, packing, tp=None, qpool=None):
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
         h, pools = self.attn.ragged_step_paged(
             cx, self.ln1(cx, x), kv_pool, block_tables,
-            context_lens, q_starts, tile_rows, tile_offs, slots, tp=tp,
-            qpool=qpool)
+            context_lens, q_starts, tile_rows, tile_offs, slots, packing,
+            tp=tp, qpool=qpool)
         x = x + self.drop(cx, h)
         f = (self.ffn.forward_serve_tp(cx, self.ln2(cx, x), tp)
              if tp is not None else self.ffn(cx, self.ln2(cx, x)))
@@ -579,8 +582,15 @@ class CausalLM(Module):
         decoded a token. With qpools/qscales (the engine's in-device
         compressed tier; empty lists when compression is off) each
         layer's int8 pool + per-block scales join its attention launch,
-        and bias-encoded block-table entries read them in place."""
-        x = self.embed(cx, tokens) * math.sqrt(self.model_dim)   # [T, D]
+        and bias-encoded block-table entries read them in place.
+
+        Everything but the attention launch runs on the step's tokens
+        alone, at the compact width (`models/step_rows.py`)."""
+        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                            last_idx, tokens.shape[0])
+        tokens, positions, slots = map(packing.compact,
+                                       (tokens, positions, slots))
+        x = self.embed(cx, tokens) * math.sqrt(self.model_dim)   # [T_c, D]
         pe = sinusoid_position_encoding(self.max_len, self.model_dim)
         pos_safe = jnp.clip(positions.astype(jnp.int32), 0, self.max_len - 1)
         x = x + pe[pos_safe].astype(x.dtype)
@@ -590,13 +600,14 @@ class CausalLM(Module):
             x, np_ = blk.ragged_step_paged(cx, x, kv_pool,
                                            block_tables, context_lens,
                                            q_starts, tile_rows, tile_offs,
-                                           slots, tp=tp, qpool=qpool)
+                                           slots, packing, tp=tp,
+                                           qpool=qpool)
             new_pools.append(np_)
-        hidden = self.ln_f(cx, x)                                # [T, D]
-        idx = last_idx.astype(jnp.int32)
-        last_h = jnp.take(hidden, idx.reshape(-1), axis=0)
+        hidden = self.ln_f(cx, x)                                # [T_c, D]
+        last_h = jnp.take(hidden, packing.last.reshape(-1), axis=0)
         logits = self._head(cx, last_h)
-        return logits.reshape(idx.shape + (logits.shape[-1],)), new_pools
+        return (logits.reshape(packing.last.shape + (logits.shape[-1],)),
+                new_pools)
 
     def decode_step(self, cx: Context, token, pos, caches):
         """One step: token [B] ids at position `pos` -> (logits [B, V],

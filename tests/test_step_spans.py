@@ -758,6 +758,40 @@ def test_expert_counts_over_a_slotted_layout_agree_with_the_counters():
     assert eng._step_fn._cache_size() == 1
 
 
+@pytest.mark.parametrize("layout", ["paged", "slots"])
+def test_product_rows_agree_with_the_counters(model_and_vars, layout):
+    """`product_rows` is the compact width every product of the step ran
+    on (the chunk budget rounded to whole tiles, and a window a batch
+    row: 8 + 4 of the flat packing's 8 + 4 x 8 here); the counter's
+    `real` kind sums the steps' tokens, its `pad` kind the rest of each
+    step's width. Over a paged layout and over one with state slots."""
+    if layout == "paged":
+        model, variables = model_and_vars
+    else:
+        from paddle_tpu.models.conv_moe_lm import ConvMoELM
+        model = ConvMoELM(
+            vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2,
+            ffn_dim=32, expert_dim=8, num_experts=8, top_k=2,
+            layer_types=["conv", "full_attention", "conv"], max_len=64)
+        variables = model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=4)
+    assert (eng.product_rows, eng.flat_tokens) == (8 + 4, 8 + 4 * 8)
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    steps = _spans(prof.get_events(), "engine.step")
+    assert all(st["args"]["product_rows"] == eng.product_rows
+               for st in steps)
+    real = sum(st["args"]["chunk_tokens"] + st["args"]["decode_rows"]
+               for st in steps)
+    assert real == sum(len(p) + 5 for p in PROMPTS)
+    rows = eng.obs.get("ptpu_engine_product_rows_total")
+    assert rows.labels(kind="real").value == real
+    assert rows.labels(kind="pad").value == sum(
+        st["args"]["product_rows"] for st in steps) - real > 0
+    assert eng._step_fn._cache_size() == 1
+
+
 def test_slot_counts_agree_with_the_counters():
     """A model whose layers keep three kinds of state: `ssm_tokens` is
     the real tokens through the scan a state-space layer, `state_slots`
