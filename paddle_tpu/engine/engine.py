@@ -88,6 +88,7 @@ from paddle_tpu.engine.scheduler import (RUNNING, Request, Scheduler,
                                          StepRow)
 from paddle_tpu.kernels.paged_attention import (pack_kv, ragged_span,
                                                 unpack_kv)
+from paddle_tpu.models.step_rows import serve_step
 from paddle_tpu.obs.metrics import MetricsRegistry, default_registry
 from paddle_tpu.obs.tracing import RequestTracer
 from paddle_tpu.profiler.profiler import annotate, gc_total_us, now_us, \
@@ -107,47 +108,18 @@ def _fresh_cx(variables) -> Context:
                             rng=None, rng_count=0, training=False))
 
 
-def serve_metadata(model) -> dict:
-    """Introspect a CausalLM into the manifest `serve` block
-    (io/inference.py `save_inference_model(..., serve_meta=...)`):
-    everything `ServeEngine.from_saved_model` needs to rebuild the
-    module and size its KV pools without touching the checkpoint. A
-    model of another family describes itself (`model.serve_metadata()`,
-    its own `model_type`)."""
-    if hasattr(model, "serve_metadata"):
-        return model.serve_metadata()
-    attn = model.blocks[0].attn
-    return {
-        "model_type": "causal_lm",
-        "vocab": model.vocab,
-        "model_dim": model.model_dim,
-        "num_heads": attn.num_heads,
-        "num_kv_heads": attn.num_kv_heads,
-        "head_dim": attn.head_dim,
-        "num_layers": len(model.blocks),
-        "ffn_dim": model.blocks[0].ffn.fc1.features,
-        "max_len": model.max_len,
-        "tie_embeddings": model.tie_embeddings,
-        "fused_qkv": attn.fused_qkv,
-        # compute dtype: the rebuilt model's activations AND the KV
-        # pool's element type (a bf16 export must not come back float32
-        # with a pool twice the size)
-        "dtype": jnp.dtype(model.dtype).name,
-    }
-
-
-def compile_steps(model, variables, compress: bool, serve_tp=None,
-                  kinds=None):
+def compile_steps(model, variables, compress: bool, serve_tp, kinds):
     """The engine's two compiled entry points, `(step, copy_blocks)`:
-    the ONE ragged step for all traffic and the fixed-width COW replay.
-    Both take the pools DONATED: the call writes the buffers it was
-    handed and returns them, so a step holds one pool, not two, and the
-    handles passed in are dead afterwards (the engine assigns the
-    returned ones back before anything else runs). `variables` may be
-    shapes; `compress` says whether the int8 pools ride along; `kinds`
-    names the entries of `pools` where a model's cache layout gives
-    them several (`PagedKVCache.kinds`): the block copy moves blocks of
-    the paged ones only.
+    the ONE ragged step for all traffic (`models/step_rows.py`
+    `serve_step` over the model's `trunk` and `logits`) and the
+    fixed-width COW replay. Both take the pools DONATED: the call writes
+    the buffers it was handed and returns them, so a step holds one
+    pool, not two, and the handles passed in are dead afterwards (the
+    engine assigns the returned ones back before anything else runs).
+    `variables` may be shapes; `compress` says whether the int8 pools
+    ride along; `kinds` names each entry of `pools`
+    (`PagedKVCache.kinds`): the block copy moves blocks of the paged and
+    index ones only.
 
     Under tensor parallelism (`serve_tp`) the operand shardings are
     pinned so every call reuses the same executable (TP004 / the
@@ -163,7 +135,7 @@ def compile_steps(model, variables, compress: bool, serve_tp=None,
         from paddle_tpu.parallel.sharding import serve_tp_rules
         mesh = serve_tp.mesh
         rep = NamedSharding(mesh, P())
-        nl = len(model.blocks)
+        nl = len(model.cache_layout)
         pools_sh = [NamedSharding(mesh, P(None, None, "tp"))] * nl
         # int8 pools shard like the fp pools; the per-block scales are
         # head-independent scalars, replicated. Compression off -> empty
@@ -188,11 +160,10 @@ def compile_steps(model, variables, compress: bool, serve_tp=None,
         # token costs the host one subtraction and the logits need not
         # leave the device (`_pick`): the first best id, as np.argmax,
         # and the maximum in the logits' own dtype (widened exactly)
-        logits, pools, *rest = model.ragged_step_paged(
-            _fresh_cx(variables), tokens, positions, pools,
-            block_tables, context_lens, q_starts, tile_rows,
-            tile_offs, slots, last_idx, tp=serve_tp,
-            qpools=qpools, qscales=qscales)
+        logits, pools, *rest = serve_step(
+            model, _fresh_cx(variables), tokens, positions, pools, qpools,
+            qscales, block_tables, context_lens, q_starts, tile_rows,
+            tile_offs, slots, last_idx, tp=serve_tp)
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         top = jnp.max(logits, axis=-1).astype(jnp.float32)
@@ -201,10 +172,8 @@ def compile_steps(model, variables, compress: bool, serve_tp=None,
     @functools.partial(jax.jit, donate_argnums=(0,), **copy_sh)
     def _copy_blocks(pools, src, dst):
         # COW replay: dst blocks take src blocks' contents, every
-        # layer; padding lanes are (0, 0) — scratch onto itself
-        if kinds is None:
-            return [pool.at[dst].set(pool[src]) for pool in pools]
-        # an index pool's rows go with their block
+        # layer; padding lanes are (0, 0) — scratch onto itself. An
+        # index pool's rows go with their block
         return [pool.at[dst].set(pool[src])
                 if kind in ("paged", "index") else pool
                 for kind, pool in zip(kinds, pools)]
@@ -344,7 +313,8 @@ def _pick(logits: Optional[np.ndarray], best, top, lse, req: Request,
 
 
 class ServeEngine:
-    """Continuous-batching serve loop over a CausalLM.
+    """Continuous-batching serve loop over a served model (one of
+    `models/`, declared by `models/step_rows.py` `ServedModel`).
 
     add_request() enqueues; step() advances the world by one scheduler
     plan — ONE mixed batch of decode rows and prefill chunks through a
@@ -387,43 +357,33 @@ class ServeEngine:
         # engine so its A/B cells don't pollute each other.
         self.obs = registry if registry is not None else default_registry()
         self.tracer = tracer if tracer is not None else RequestTracer()
-        # what each layer keeps between steps, read from the model: a
-        # layout a layer (`CacheLayout`, ENGINE.md "Cache kinds"), or
-        # nothing said and a paged pool in every layer
-        layout = getattr(model, "cache_layout", None)
-        if layout is not None:
-            # prefix reuse over slots goes by state snapshots: how far
-            # apart and how many is the engine's to say, else the
-            # model's (`snapshot_tokens`, `snapshot_slots`), else the
-            # layout's default where the prefix cache is asked for
-            if snapshot_tokens is None:
-                snapshot_tokens = getattr(model, "snapshot_tokens", 0)
-            if snapshot_slots is None:
-                snapshot_slots = getattr(model, "snapshot_slots", 0)
-            layout = CacheLayout(layout, block_size, max_batch_size,
-                                 min(max_prefill_tokens,
-                                     max_seq_len or model.max_len),
-                                 snapshot_tokens or None,
-                                 snapshot_slots or None)
-            if layout.has_slots:
-                refuse_slots(max(spec_k, drafter.k if drafter else 0),
-                             host_tier_bytes, kv_compress_blocks, tp_size,
-                             demote_finished)
-                if enable_prefix_cache is None:
-                    enable_prefix_cache = bool(snapshot_tokens)
-            kv_heads, head_dim = model.kv_row
-            latent = None
-        else:
-            attn = model.blocks[0].attn
-            kv_heads, head_dim = attn.num_kv_heads, attn.head_dim
-            # what one cached row is, read from the model: kv_heads x
-            # [k | v], or one latent entry a token ("The pool's row",
-            # kernels/paged_attention.py)
-            latent = getattr(attn, "latent_row", None)
-            if latent is not None:
-                refuse_latent(int(tp_size), int(kv_compress_blocks))
+        # what each layer keeps between steps, as the model declares it
+        # (`CacheLayout`, ENGINE.md "Cache kinds"). Prefix reuse over
+        # slots goes by state snapshots: how far apart and how many is
+        # the engine's to say, else the model's, else the layout's
+        # default where the prefix cache is asked for
+        if snapshot_tokens is None:
+            snapshot_tokens = model.snapshot_tokens
+        if snapshot_slots is None:
+            snapshot_slots = model.snapshot_slots
+        layout = CacheLayout(model.cache_layout, block_size, max_batch_size,
+                             min(max_prefill_tokens,
+                                 max_seq_len or model.max_len),
+                             snapshot_tokens or None, snapshot_slots or None)
+        # what one cached row is: kv_heads x [k | v], or one latent
+        # entry a token ("The pool's row", kernels/paged_attention.py)
+        latent = model.latent_row
+        kv_heads, head_dim = model.kv_row or (1, latent[0])
+        # what the cache cannot hold, said once, here
+        if layout.has_slots:
+            refuse_slots(max(spec_k, drafter.k if drafter else 0),
+                         host_tier_bytes, kv_compress_blocks, tp_size,
+                         demote_finished)
+        if latent is not None:
+            refuse_latent(int(tp_size), int(kv_compress_blocks))
         if enable_prefix_cache is None:     # on wherever the cache can
-            enable_prefix_cache = True
+            enable_prefix_cache = (bool(snapshot_tokens) if layout.has_slots
+                                   else True)
         # tensor-parallel serving (ENGINE.md "Tensor-parallel serving"):
         # tp_size > 1 builds a tp mesh over the first tp_size devices,
         # shards the weights (parallel.sharding.serve_tp_rules) and KV
@@ -447,20 +407,14 @@ class ServeEngine:
                     "--xla_force_host_platform_device_count=<n> before "
                     "jax initializes (serve/replica.py --tp-size does "
                     "this for you)")
-            if attn.num_heads % self.tp_size:
-                raise ValueError(
-                    f"num_heads={attn.num_heads} not divisible by "
-                    f"tp_size={self.tp_size}")
-            if attn.num_kv_heads % self.tp_size:
-                raise ValueError(
-                    f"num_kv_heads={attn.num_kv_heads} not divisible by "
-                    f"tp_size={self.tp_size}: KV pools shard over "
-                    "kv-heads so GQA groups stay device-local")
-            ffn_dim = model.blocks[0].ffn.fc1.features
-            if ffn_dim % self.tp_size:
-                raise ValueError(
-                    f"ffn_dim={ffn_dim} not divisible by "
-                    f"tp_size={self.tp_size}")
+            # the heads and the FFN width the model declares: KV pools
+            # shard over kv-heads so GQA groups stay device-local
+            meta = model.serve_metadata()
+            for name in ("num_heads", "num_kv_heads", "ffn_dim"):
+                if meta.get(name) is None or meta[name] % self.tp_size:
+                    raise ValueError(
+                        f"{name}={meta.get(name)} not divisible by "
+                        f"tp_size={self.tp_size}")
             self._mesh = make_mesh(MeshConfig(tp=self.tp_size),
                                    devices=devs[:self.tp_size])
             self._serve_tp = ServeTP(self._mesh, self.tp_size,
@@ -560,7 +514,7 @@ class ServeEngine:
         # kv_promote_hits opts back into fp promotion (1 = always, the
         # PR-19 behavior; N > 1 = warm-up threshold).
         self.cache = PagedKVCache(
-            num_layers=len(model.blocks), num_blocks=num_blocks,
+            num_layers=len(model.cache_layout), num_blocks=num_blocks,
             block_size=block_size, num_kv_heads=kv_heads,
             head_dim=head_dim, dtype=model.dtype,
             enable_prefix_cache=enable_prefix_cache, registry=self.obs,
@@ -625,8 +579,7 @@ class ServeEngine:
         self._gc_seen_us = gc_total_us()
         # tokens per (expert layer, expert) since construction
         self.expert_tokens = np.zeros(
-            (getattr(model, "expert_layers", 0),
-             getattr(model, "num_experts", 0)), np.int64)
+            (model.expert_layers, model.num_experts), np.int64)
         self.prefill_tokens_computed = 0
         self.peak_occupancy = 0.0
         self.max_chunk_tokens = 0       # largest prefill step actually run
@@ -646,14 +599,14 @@ class ServeEngine:
 
         self._step_fn, self._copy_blocks = compile_steps(
             model, self.variables, self.cache.compress_enabled,
-            self._serve_tp, None if layout is None else self.cache.kinds)
+            self._serve_tp, self.cache.kinds)
         self._merge = compile_merge(self._serve_tp)
         # the picks of the step before, on the device: `_merge`'s second
         # operand (zeros before the first step, whose map takes none)
         self._last_ids = jnp.zeros((max_batch_size, self.spec_len),
                                    jnp.int32)
         # what the model counts of its own sparse attention a row
-        self._sparse_counts = getattr(model, "sparse_counts", None)
+        self._sparse_counts = model.sparse_counts
         self._snapshots_seen = (0, 0, 0)
         if self.cache.snapshot_every:
             self._snapshot_take, self._snapshot_restore = \
@@ -668,11 +621,18 @@ class ServeEngine:
     @classmethod
     def from_saved_model(cls, model_dir: str, **engine_kwargs):
         """Build model + engine from a save_inference_model() directory
-        whose manifest carries the `serve` block (serve_metadata)."""
+        whose manifest carries the `serve` block (the model's
+        `serve_metadata()`): the class its `model_type` names rebuilds
+        itself from it (a manifest older than the field is a CausalLM's)."""
         import json
         import os
 
         from paddle_tpu.io.checkpoint import load_checkpoint
+        from paddle_tpu.models.conv_moe_lm import ConvMoELM
+        from paddle_tpu.models.hybrid_lm import HybridLM
+        from paddle_tpu.models.latent_moe import LatentMoELM
+        from paddle_tpu.models.parallel_hybrid_lm import ParallelHybridLM
+        from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
         from paddle_tpu.models.transformer import CausalLM
 
         with open(os.path.join(model_dir, "signature.json")) as f:
@@ -682,44 +642,12 @@ class ServeEngine:
             raise ValueError(
                 f"{model_dir} has no `serve` metadata in its manifest; "
                 "re-export with save_inference_model(..., "
-                "serve_meta=serve_metadata(model))")
-        if meta.get("model_type") == "latent_moe_lm":
-            from paddle_tpu.models.latent_moe import LatentMoELM
-            model = LatentMoELM(
-                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
-                param_dtype=jnp.dtype(meta["param_dtype"]))
-        elif meta.get("model_type") == "sparse_linear_lm":
-            from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
-            model = SparseLinearLM(
-                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
-                param_dtype=jnp.dtype(meta["param_dtype"]))
-        elif meta.get("model_type") == "parallel_hybrid_lm":
-            from paddle_tpu.models.parallel_hybrid_lm import \
-                ParallelHybridLM
-            model = ParallelHybridLM(
-                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
-                param_dtype=jnp.dtype(meta["param_dtype"]))
-        elif meta.get("model_type") == "conv_moe_lm":
-            from paddle_tpu.models.conv_moe_lm import ConvMoELM
-            model = ConvMoELM(
-                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
-                param_dtype=jnp.dtype(meta["param_dtype"]))
-        elif meta.get("model_type") == "hybrid_lm":
-            from paddle_tpu.models.hybrid_lm import HybridLM
-            model = HybridLM(
-                **meta["config"], dtype=jnp.dtype(meta["dtype"]),
-                param_dtype=jnp.dtype(meta["param_dtype"]))
-        else:
-            model = CausalLM(
-                vocab=meta["vocab"], model_dim=meta["model_dim"],
-                num_heads=meta["num_heads"], num_layers=meta["num_layers"],
-                ffn_dim=meta["ffn_dim"], dropout=0.0,
-                max_len=meta["max_len"],
-                tie_embeddings=meta["tie_embeddings"],
-                fused_qkv=meta["fused_qkv"],
-                num_kv_heads=meta["num_kv_heads"],
-                # exports older than the field were all float32
-                dtype=jnp.dtype(meta.get("dtype", "float32")))
+                "serve_meta=model.serve_metadata())")
+        served = {c.model_type: c for c in (
+            CausalLM, LatentMoELM, HybridLM, SparseLinearLM,
+            ParallelHybridLM, ConvMoELM)}
+        model = served[meta.get("model_type", CausalLM.model_type)
+                       ].from_serve_metadata(meta)
         variables = load_checkpoint(os.path.join(model_dir, "params"))
         engine_kwargs.setdefault("max_seq_len", meta["max_len"])
         return cls(model, variables, **engine_kwargs)
@@ -939,8 +867,7 @@ class ServeEngine:
             raise ValueError(
                 f"n {n} not in [1, max_batch_size={self.max_batch_size}]: "
                 "every candidate needs a batch slot to decode")
-        if n > 1 and self.cache.layout is not None \
-                and self.cache.layout.has_slots:
+        if n > 1 and self.cache.layout.has_slots:
             raise ValueError(
                 f"n={n} over recurrent state or a window ring: the "
                 "candidates would fork one prefill, and a slot's state and "
@@ -1481,9 +1408,8 @@ class ServeEngine:
             tile_offs = np.zeros((nt,), np.int32)
             last_idx = np.zeros((b, self.spec_len), np.int32)
             cursor = kv_read = attn_keys = cells = 0
-            slotted = (self.cache.layout is not None
-                       and self.cache.layout.has_slots)
-            win = self.cache.layout.window if slotted else 0
+            slotted = self.cache.layout.has_slots
+            win = self.cache.layout.window
             ssm_tokens = win_rows = win_keys = released = 0
             sparse = dict.fromkeys(
                 ("sparse_rows_read", "sparse_keys", "blocks_selected",

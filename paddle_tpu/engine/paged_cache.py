@@ -60,10 +60,11 @@ the lanes (`pool_shape`) and keeps what is policy. Everything above the
 row — allocator, prefix index, copy-on-write, eviction, the host tier's
 and the transfer plane's whole-block moves (`read_block` / `pack_block`,
 which carry a latent row as its two parts [value lanes | the rest]) — is
-the same code for both rows. Two things cannot work on a latent row and
-refuse at construction (`refuse_latent`): tensor parallelism (a latent
-has no head to divide over the chips) and the int8 tier (its per-block k
-and v scales are laid over a head's [k | v] halves).
+the same code for both rows. Two things cannot work on a latent row, and
+the engine refuses them at its construction (`refuse_latent`): tensor
+parallelism (a latent has no head to divide over the chips) and the int8
+tier (its per-block k and v scales are laid over a head's [k | v]
+halves).
 
 Host/device split: this class is the HOST-side allocator + bookkeeping
 (free list, refcounts, per-sequence tables/lengths/tokens, prefix
@@ -100,7 +101,7 @@ class CacheExhausted(Exception):
 
 
 def refuse_latent(tp_size: int, compress_blocks: int) -> None:
-    """What a latent pool cannot do, said at construction."""
+    """What a latent pool cannot do, said at the engine's construction."""
     if tp_size > 1:
         raise ValueError(
             f"tp_size={tp_size} over a latent KV pool: a latent row is one "
@@ -117,7 +118,8 @@ def refuse_latent(tp_size: int, compress_blocks: int) -> None:
 def refuse_slots(spec_k: int, host_tier_bytes: int, compress_blocks: int,
                  tp_size: int, demote_finished: bool) -> None:
     """What a cache with per-sequence SLOTS (recurrent state, window
-    rings: `CacheLayout`) cannot do yet, said at construction. Prefix
+    rings: `CacheLayout`) cannot do yet, said at the engine's
+    construction. Prefix
     reuse is no longer among them: a slot's arrays and rings are
     snapshot at block boundaries (`CacheLayout`, "State snapshots")."""
     def no(what: str, why: str, how: str):
@@ -157,7 +159,7 @@ class CacheLayout:
     engine. Five kinds:
 
     - {"kind": "paged"}: a block pool under the block tables and the
-      free list (the only kind a model without a layout has).
+      free list (every layer of a model with paged pools only).
       `"pools": n` gives the layer n such pools (a model whose kv heads
       are read through tables of their own keeps a pool a head);
       `"index": {"stride": s, "lanes": l}` adds an INDEX pool under the
@@ -243,7 +245,8 @@ class CacheLayout:
 
     def arrays(self, pool_shape, dtype):
         """[(kind, shape, dtype)] of the arrays the step is handed, in
-        layer order, then the rows table."""
+        layer order, then, where the layout has slots, the rows
+        table."""
         out = []
         nb, bs, lanes = pool_shape
         for layer in self.layers:
@@ -259,8 +262,9 @@ class CacheLayout:
             elif layer["kind"] == "window":
                 out.append(("window", (1 + self.slots * self.ring_blocks,
                                        bs, lanes), dtype))
-        out.append(("rows", (self.slots + 1, 1 + self.ring_blocks),
-                    jnp.int32))
+        if self.has_slots:
+            out.append(("rows", (self.slots + 1, 1 + self.ring_blocks),
+                        jnp.int32))
         return out
 
     def snapshot_arrays(self, arrays) -> list:
@@ -288,12 +292,12 @@ class PagedKVCache:
 
     All paged layers allocate in lockstep (a token occupies the same
     slot in every layer's pool), so ONE free list / block table set
-    serves the whole stack; `pools` holds one array per layer in the
-    layout `pool_shape` gives (module docstring, "Pool layout"). With a
-    `layout` (`CacheLayout`) `pools` is the list its `arrays` describe:
-    paged pools, window pools, state arrays, and last the ROWS table the
-    step reads its rows' slots and rings from (`bind_rows`); `kinds`
-    names each entry.
+    serves the whole stack. `pools` is the list the `layout`
+    (`CacheLayout`) describes: paged pools in the layout `pool_shape`
+    gives (module docstring, "Pool layout"), window pools, state arrays,
+    and last, where the layout has slots, the ROWS table the step reads
+    its rows' slots and rings from (`bind_rows`); `kinds` names each
+    entry.
     """
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
@@ -307,16 +311,12 @@ class PagedKVCache:
                  latent: Optional[Tuple[int, int]] = None,
                  layout: Optional[CacheLayout] = None):
         """`latent=(k_dim, v_dim)` selects the latent row (module
-        docstring); `num_kv_heads` and `head_dim` then count for
-        nothing. `layout` gives each layer its kind (`CacheLayout`);
-        without one every layer keeps a paged pool."""
-        if layout is not None and layout.has_slots:
-            refuse_slots(0, host_tier.byte_budget if host_tier else 0,
-                         compress_blocks, tp_size, False)
-        self.layout = layout
-        if latent is not None:
-            refuse_latent(tp_size, compress_blocks)
-            num_kv_heads, head_dim = 1, latent[0]
+        docstring), with one kv head of `head_dim` = k_dim. `layout`
+        gives each layer its
+        kind (`CacheLayout`); without one each of the `num_layers`
+        keeps a paged pool."""
+        self.layout = layout or CacheLayout([{"kind": "paged"}] * num_layers,
+                                            block_size, 0, 0)
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is scratch)")
         if compress_blocks < 0:
@@ -343,24 +343,21 @@ class PagedKVCache:
         self.enable_prefix_cache = enable_prefix_cache
         # pools are allocated at the GLOBAL shape; under tp the mesh
         # shards the row's heads so each chip HOLDS pool_shape() bytes
-        self._num_layers = num_layers
         self._sharding = None
         if mesh is not None and tp_size > 1:
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._sharding = NamedSharding(mesh, P(None, None, "tp"))
         self.pools: List[jnp.ndarray] = self.fresh_pools()
-        # what each entry of `pools` is: "paged", "window", "state" or
-        # "rows". Whole-block movers (the COW replay, the tiers) touch
-        # the paged ones only
-        self.kinds: List[str] = (
-            ["paged"] * num_layers if layout is None else
-            [kind for kind, _, _ in
-             layout.arrays(self.pool_shape(1), self.dtype)])
+        # what each entry of `pools` is: "paged", "index", "window",
+        # "state" or "rows". Whole-block movers (the COW replay, the
+        # tiers) touch the paged ones only
+        self.kinds: List[str] = [kind for kind, _, _ in self.layout.arrays(
+            self.pool_shape(1), self.dtype)]
+        layout = self.layout
         # slots of the window and state kinds: free list, owner map,
         # and how many ring blocks each sequence has given back
         self._free_slots = deque(range(1, layout.slots + 1)
-                                 if layout is not None and layout.has_slots
-                                 else ())
+                                 if layout.has_slots else ())
         self._slot: Dict[int, int] = {}
         self._ring_released: Dict[int, int] = {}
         self.window_blocks_released = 0
@@ -372,10 +369,9 @@ class PagedKVCache:
         # boundary before it); how many held snapshots stand one
         # boundary deeper than a key; which snapshots lean on a block;
         # the copies staged for the engine
-        self.snapshot_every = (
-            layout.snapshot_tokens
-            if layout is not None and layout.has_slots
-            and enable_prefix_cache else 0)
+        self.snapshot_every = (layout.snapshot_tokens
+                               if layout.has_slots and enable_prefix_cache
+                               else 0)
         self.snap_places: List[int] = []
         self.snaps: List[jnp.ndarray] = []
         if self.snapshot_every:
@@ -542,11 +538,8 @@ class PagedKVCache:
         """Zeroed pools of this cache's shape and placement: what the
         constructor holds, and what the engine rebuilds after a step
         that failed with the pools already donated."""
-        if self.layout is not None:
-            return [jnp.zeros(shape, dtype) for _, shape, dtype in
-                    self.layout.arrays(self.pool_shape(1), self.dtype)]
-        return [self._place(jnp.zeros(self.pool_shape(1), self.dtype))
-                for _ in range(self._num_layers)]
+        return [self._place(jnp.zeros(shape, dtype)) for _, shape, dtype
+                in self.layout.arrays(self.pool_shape(1), self.dtype)]
 
     # -- slots (window rings, recurrent state) ----------------------------
     @property
@@ -583,7 +576,7 @@ class PagedKVCache:
         (their ring places are the ones the sequence writes next).
         Returns how many this call released."""
         lay = self.layout
-        if lay is None or not lay.ring_blocks:
+        if not lay.ring_blocks:
             return 0
         behind = max(0, next_pos - (lay.window - 1)) // self.block_size
         newly = behind - self._ring_released.get(seq_id, 0)
@@ -973,8 +966,7 @@ class PagedKVCache:
         """Admission check. `tokens` may be a token list (prefix-aware:
         matched blocks cost nothing beyond their own revival) or a bare
         count (conservative)."""
-        if self.layout is not None and self.layout.has_slots \
-                and not self._free_slots:
+        if self.layout.has_slots and not self._free_slots:
             return False        # every slot holds a running sequence
         if isinstance(tokens, int):
             return self.blocks_for(tokens) <= len(self._free)
@@ -1000,8 +992,7 @@ class PagedKVCache:
         and would otherwise inflate hit_rate."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already allocated")
-        if self.layout is not None and self.layout.has_slots \
-                and not self._free_slots:
+        if self.layout.has_slots and not self._free_slots:
             raise CacheExhausted("no free state slot")
         n = len(tokens)
         bs = self.block_size
@@ -1304,7 +1295,7 @@ class PagedKVCache:
         blocks just drop one reference."""
         if dst_id in self._tables:
             raise ValueError(f"sequence {dst_id} already allocated")
-        if self.layout is not None and self.layout.has_slots:
+        if self.layout.has_slots:
             raise ValueError(
                 "a fork over recurrent state or a window ring: the paged "
                 "blocks share by refcount and copy on write, and a slot's "

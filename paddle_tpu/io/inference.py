@@ -60,7 +60,7 @@ def save_inference_model(path: str, module_or_fn, variables: Variables,
     with the same structure.
 
     serve_meta: optional dict recorded as the manifest's `serve` block
-    (engine.serve_metadata(model) for a CausalLM: max seq length, KV
+    (model.serve_metadata() of a served model: max seq length, KV
     head count/dim, vocab size, layer config) so
     `ServeEngine.from_saved_model` can rebuild the module and size its
     KV pools without re-deriving shapes from the checkpoint. Manifests

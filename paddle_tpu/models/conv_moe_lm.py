@@ -14,12 +14,12 @@ RMSNorm with a learned scale; no biases. The mixer of layer i is
   blocks of d, in that order); u = B . x~; v_t = sum_j w_j . u_{t-K+1+j}
   for j = 0 .. K-1, depthwise and causal (K = `conv_width`, zeros before
   position 0); out = W_out (C . v).
-- "full_attention": GQA (`parallel_hybrid_lm.Attention`), q and k each
+- "full_attention": GQA (`shared_layers.Attention`), q and k each
   through an RMSNorm over the head, then rotary over the whole head
   (rotate-half) at `rope_theta`, causal softmax at 1 / sqrt(hd), W_o.
 
 F_i is a gated SiLU FFN of `ffn_dim` in the first `num_dense_layers`
-layers and the routed experts after them (`latent_moe.RoutedExperts`: a
+layers and the routed experts after them (`shared_layers.RoutedExperts`: a
 float32 sigmoid router with a selection bias, the top_k of
 `num_experts`, the chosen scores over their sum + 1e-6, times
 `scaling`; no shared expert).
@@ -37,10 +37,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.module import Context, Module
 from paddle_tpu.kernels import selective_scan as scan
-from paddle_tpu.models.hybrid_lm import _dense
-from paddle_tpu.models.latent_moe import GatedFFN, RoutedExperts
-from paddle_tpu.models.parallel_hybrid_lm import Attention
-from paddle_tpu.models.step_rows import step_rows
+from paddle_tpu.models.shared_layers import (Attention, GatedFFN,
+                                             RoutedExperts, dense)
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, RMSNorm
 
@@ -61,7 +60,7 @@ class ShortConv(Module):
     def _gate(self, cx: Context, y):
         """y [..., d] -> (C, u = B . x~), in the compute dtype."""
         d = self.model_dim
-        bcx = _dense(cx, "in_proj", y, 3 * d, self.dtype, self.param_dtype)
+        bcx = dense(cx, "in_proj", y, 3 * d, self.dtype, self.param_dtype)
         return bcx[..., d:2 * d], bcx[..., :d] * bcx[..., 2 * d:]
 
     def _weight(self, cx: Context):
@@ -70,8 +69,8 @@ class ShortConv(Module):
 
     def _out(self, cx: Context, c, v):
         g = (c.astype(jnp.float32) * v).astype(self.dtype)
-        return _dense(cx, "out_proj", g, self.model_dim, self.dtype,
-                      self.param_dtype)
+        return dense(cx, "out_proj", g, self.model_dim, self.dtype,
+                     self.param_dtype)
 
     def forward(self, cx: Context, y):
         """y [B, T, d], whole sequences from position 0."""
@@ -82,17 +81,18 @@ class ShortConv(Module):
         return self._out(cx, c, sum(w[j] * padded[:, j:j + t]
                                     for j in range(k)))
 
-    def ragged_step(self, cx: Context, y, tails, meta, tile_offs, packing):
-        """y [T_c, d], the step's tokens (`packing`,
-        `models/step_rows.py`); the convolution runs over the flat
-        packing. Returns (output, new tails)."""
+    def ragged_step(self, cx: Context, y, tails, meta, batch):
+        """y [T_c, d], the step's tokens (`batch`, a
+        `models.step_rows.StepBatch`; `meta` its `tile_meta`); the
+        convolution runs over the flat packing. Returns (output, new
+        tails)."""
         slots, real, fresh, last = meta
         with jax.named_scope("short_conv"):
             c, u = self._gate(cx, y)
             v, tails = scan.ragged_causal_conv(
-                packing.expand(u), tails, self._weight(cx), None, slots, real,
-                fresh, last, tile_offs)
-            return self._out(cx, c, packing.compact(v)), tails
+                batch.packing.expand(u), tails, self._weight(cx), None,
+                slots, real, fresh, last, batch.tile_offs)
+            return self._out(cx, c, batch.packing.compact(v)), tails
 
 
 class ConvMoEBlock(Module):
@@ -133,12 +133,13 @@ class ConvMoEBlock(Module):
         return out.reshape(b, t, d)
 
 
-class ConvMoELM(Module):
+class ConvMoELM(ServedModel):
     """Decoder-only LM of `ConvMoEBlock`s, a mixer kind a layer
     (`layer_types`), the first `num_dense_layers` with a dense FFN and
     the rest with routed experts; a tied head with float32 logits.
     `max_len` bounds the positions served (the rotary angles are
     computed, so it costs nothing)."""
+    model_type = "conv_moe_lm"
 
     def __init__(self, vocab: int, model_dim: int, num_heads: int,
                  num_kv_heads: int, ffn_dim: int, expert_dim: int,
@@ -194,13 +195,7 @@ class ConvMoELM(Module):
             {"kind": "state", "arrays": b.conv.state_shapes}
             if b.kind == "conv" else {"kind": "paged"} for b in blocks]
 
-    def serve_metadata(self) -> dict:
-        return {"model_type": "conv_moe_lm", "config": dict(self.config),
-                "max_len": self.max_len,
-                "dtype": jnp.dtype(self.dtype).name,
-                "param_dtype": self.param_dtype.name}
-
-    def _logits(self, cx: Context, x):
+    def logits(self, cx: Context, x):
         h = self.norm_f(cx, x)
         table = cx.scope("embed").param(
             "weight", (self.vocab, self.model_dim), I.normal(0.0, 0.02),
@@ -217,54 +212,29 @@ class ConvMoELM(Module):
         x = self.embed(cx, tokens)
         for blk in self.blocks:
             x = blk(cx, x)
-        return self._logits(cx, x)
+        return self.logits(cx, x)
 
-    def ragged_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, last_idx, tp=None,
-                          qpools=None, qscales=None):
-        """The engine's one step (`CausalLM.ragged_step_paged` has the
-        contract). `pools` is the cache manager's list for this model's
+    def trunk(self, cx: Context, batch, pools):
+        """The step's layers (`models/step_rows.py` `serve_step`).
+        `pools` is the cache manager's list for this model's
         `cache_layout`: an attention layer's paged pool, a conv layer's
-        tails; last the ROWS table (a step row's state slot). Returns
-        (logits, the same list updated, tokens per expert int32
-        [expert layers, E]). Everything but the kernels runs on the
-        step's tokens alone, at the compact width
-        (`models/step_rows.py`); the rows past them are routed to no
-        expert."""
-        if tp is not None or qpools:
-            raise ValueError("recurrent state is served on one chip with no "
-                             "int8 tier (engine/paged_cache.py)")
+        tails; last the ROWS table (a step row's state slot). The rows
+        past the step's tokens are routed to no expert."""
         *arrays, rows = pools
-        t, nt = tokens.shape[0], tile_rows.shape[0]
-        tq = t // nt
-        positions = positions.astype(jnp.int32)
-        meta = scan.tile_meta(rows[:, 0], context_lens, q_starts, tile_rows,
-                              tile_offs, tq)
-        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
-                            last_idx, t)
-        tokens, positions, slots = map(packing.compact,
-                                       (tokens, positions, slots))
+        meta = batch.tile_meta(rows[:, 0])
         out_pools, counts = [], []
-        x = self.embed(cx, tokens)                               # [T_c, D]
+        x = self.embed(cx, batch.tokens)                         # [T_c, D]
         for blk, held in zip(self.blocks, arrays):
             c = cx.scope(blk._name)
             y = blk.ln1(c, x)
             if blk.kind == "conv":
-                mixed, held = blk.conv.ragged_step(
-                    c.scope("conv"), y, held, meta, tile_offs, packing)
+                mixed, held = blk.conv.ragged_step(c.scope("conv"), y, held,
+                                                   meta, batch)
             else:
-                mixed, held = blk.attn.ragged_step(
-                    c.scope("attn"), y, held, positions, block_tables,
-                    context_lens, q_starts, tile_rows, tile_offs, slots,
-                    packing)
+                mixed, held = blk.attn.ragged_step(c.scope("attn"), y, held,
+                                                   batch)
             out_pools.append(held)
-            x, n = blk._feed(c, x + mixed, packing.real)
+            x, n = blk._feed(c, x + mixed, batch.packing.real)
             if n is not None:
                 counts.append(n)
-        idx = packing.last
-        logits = self._logits(cx, jnp.take(x, idx.reshape(-1), axis=0))
-        return (logits.reshape(idx.shape + (logits.shape[-1],)),
-                out_pools + [rows],
-                jnp.stack(counts) if counts else
-                jnp.zeros((0, self.num_experts), jnp.int32))
+        return x, out_pools + [rows], counts

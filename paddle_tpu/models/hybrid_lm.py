@@ -53,7 +53,8 @@ import jax.numpy as jnp
 from paddle_tpu.core.module import Context, Module
 from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.kernels import selective_scan as scan
-from paddle_tpu.models.step_rows import step_rows
+from paddle_tpu.models.shared_layers import PackedGatedFFN, dense
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, LayerNorm
 
@@ -62,21 +63,6 @@ KINDS = ("mamba", "window", "full", "gmu", "cross")
 
 def lambda_init(layer: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * layer)
-
-
-def _dense(cx: Context, name: str, x, features: int, dtype, param_dtype,
-           bias: bool = False, out=None):
-    """x @ W (+ b) under the scope `name`; `out` is the product's
-    element type (float32 keeps the accumulator)."""
-    c = cx.scope(name)
-    w = c.param("weight", (x.shape[-1], features), I.glorot_uniform,
-                param_dtype)
-    y = jnp.matmul(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=out or dtype)
-    if bias:
-        y = y + c.param("bias", (features,), I.normal(0.0, 0.02),
-                        param_dtype).astype(y.dtype)
-    return y
 
 
 class Mamba(Module):
@@ -105,8 +91,8 @@ class Mamba(Module):
 
     def _pre(self, cx: Context, y):
         """[u | z] = W_in y, y [..., d]."""
-        uz = _dense(cx, "in_proj", y, 2 * self.d_inner, self.dtype,
-                    self.param_dtype)
+        uz = dense(cx, "in_proj", y, 2 * self.d_inner, self.dtype,
+                   self.param_dtype)
         return uz[..., :self.d_inner], uz[..., self.d_inner:]
 
     def _ssm_inputs(self, cx: Context, conv):
@@ -117,19 +103,19 @@ class Mamba(Module):
 
     def _selection(self, cx: Context, u):
         """delta, B, C (float32) from u'."""
-        dbc = _dense(cx, "x_proj", u, self.dt_rank + 2 * self.d_state,
-                     self.dtype, self.param_dtype, out=jnp.float32)
+        dbc = dense(cx, "x_proj", u, self.dt_rank + 2 * self.d_state,
+                    self.dtype, self.param_dtype, out=jnp.float32)
         dt = dbc[..., :self.dt_rank]
         b = dbc[..., self.dt_rank:self.dt_rank + self.d_state]
         c = dbc[..., self.dt_rank + self.d_state:]
-        delta = jax.nn.softplus(_dense(
+        delta = jax.nn.softplus(dense(
             cx, "dt_proj", dt, self.d_inner, self.dtype, self.param_dtype,
             bias=True, out=jnp.float32))
         return delta, b, c
 
     def _post(self, cx: Context, m, z):
-        return _dense(cx, "out_proj", m * jax.nn.silu(z), self.model_dim,
-                      self.dtype, self.param_dtype)
+        return dense(cx, "out_proj", m * jax.nn.silu(z), self.model_dim,
+                     self.dtype, self.param_dtype)
 
     def forward(self, cx: Context, y):
         """y [B, T, d], whole sequences from position 0. Returns (the
@@ -159,19 +145,19 @@ class Mamba(Module):
         m = jnp.swapaxes(m, 0, 1).astype(self.dtype)
         return self._post(cx, m, z), m
 
-    def ragged_step(self, cx: Context, y, ssm, tails, meta, tile_offs,
-                    packing):
-        """y [T_c, d], the step's tokens (`packing`,
-        `models/step_rows.py`); the convolution and the scan run over
-        the flat packing. Returns (output, memory m, new scan state, new
-        tails)."""
+    def ragged_step(self, cx: Context, y, ssm, tails, meta, batch):
+        """y [T_c, d], the step's tokens (`batch`, a
+        `models.step_rows.StepBatch`; `meta` its `tile_meta`); the
+        convolution and the scan run over the flat packing. Returns
+        (output, memory m, new scan state, new tails)."""
         p = self._params(cx)
+        packing = batch.packing
         slots, real, fresh, last = meta
         u, z = self._pre(cx, y)
         with jax.named_scope("ssm_scan"):
             conv, tails = scan.ragged_causal_conv(
                 packing.expand(u), tails, p["conv_w"], p["conv_b"], slots,
-                real, fresh, last, tile_offs)
+                real, fresh, last, batch.tile_offs)
             # u' where the scan reads it, and compact for its products
             u = jax.nn.silu(conv).astype(self.dtype)
             delta, b, c = map(packing.expand,
@@ -191,10 +177,10 @@ class GatedMemory(Module):
 
     def forward(self, cx: Context, y, memory):
         with jax.named_scope("gated_memory"):
-            g = _dense(cx, "w1", y, self.d_inner, self.dtype,
-                       self.param_dtype)
-            return _dense(cx, "w2", memory * jax.nn.silu(g), self.model_dim,
-                          self.dtype, self.param_dtype)
+            g = dense(cx, "w1", y, self.d_inner, self.dtype,
+                      self.param_dtype)
+            return dense(cx, "w2", memory * jax.nn.silu(g), self.model_dim,
+                         self.dtype, self.param_dtype)
 
 
 class DiffAttention(Module):
@@ -224,11 +210,11 @@ class DiffAttention(Module):
         """y [..., d] -> (q [..., H, hd], k, v [..., Hkv, hd] or None)."""
         hd, kvd = self.head_dim, self.num_kv_heads * self.head_dim
         if self.cross:
-            q = _dense(cx, "q", y, self.model_dim, self.dtype,
-                       self.param_dtype, bias=True)
+            q = dense(cx, "q", y, self.model_dim, self.dtype,
+                      self.param_dtype, bias=True)
             return q.reshape(y.shape[:-1] + (self.num_heads, hd)), None, None
-        qkv = _dense(cx, "qkv", y, self.model_dim + 2 * kvd, self.dtype,
-                     self.param_dtype, bias=True)
+        qkv = dense(cx, "qkv", y, self.model_dim + 2 * kvd, self.dtype,
+                    self.param_dtype, bias=True)
         lead = y.shape[:-1]
         q = qkv[..., :self.model_dim].reshape(lead + (self.num_heads, hd))
         k = qkv[..., self.model_dim:self.model_dim + kvd].reshape(
@@ -255,8 +241,8 @@ class DiffAttention(Module):
             "scale", (2 * self.head_dim,), I.ones, pd).astype(jnp.float32)
         o = (o * jax.lax.rsqrt(var + self.eps) * scale
              * (1.0 - self.lambda_init))
-        return _dense(cx, "o", o.reshape(lead + (self.model_dim,)),
-                      self.model_dim, self.dtype, self.param_dtype, bias=True)
+        return dense(cx, "o", o.reshape(lead + (self.model_dim,)),
+                     self.model_dim, self.dtype, self.param_dtype, bias=True)
 
     def forward(self, cx: Context, y, kv=None):
         """Whole sequences y [B, T, d], the published two-call form.
@@ -284,13 +270,14 @@ class DiffAttention(Module):
         att = att.reshape(b, t, self.num_heads, 2 * hd)
         return self._combine(cx, att), (k, v)
 
-    def ragged_step(self, cx: Context, y, pool, table, slots, context_lens,
-                    q_starts, tile_rows, tile_offs, packing):
-        """y [T_c, d], the step's tokens (`packing`,
-        `models/step_rows.py`), `pool` this layer's own or (cross) its
-        full layer's, `table` the pool's block tables, `slots` the pool
-        rows the tokens are written to (None: nothing to write); the
-        kernel runs over the flat packing. Returns (output, pool)."""
+    def ragged_step(self, cx: Context, y, pool, table, slots, batch):
+        """y [T_c, d], the step's tokens (`batch`, a
+        `models.step_rows.StepBatch`), `pool` this layer's own or
+        (cross) its full layer's, `table` the pool's block tables,
+        `slots` the pool rows the tokens are written to (None: nothing
+        to write); the kernel runs over the flat packing. Returns
+        (output, pool)."""
+        packing = batch.packing
         with jax.named_scope("diff_attention"):
             q, k, v = self._project(cx, y)
             t, hd = y.shape[0], self.head_dim
@@ -304,37 +291,17 @@ class DiffAttention(Module):
             wide = jnp.concatenate([jnp.where(first, q, 0),
                                     jnp.where(first, 0, q)], axis=-1)
             att = paged.ragged_paged_attention(
-                wide, pool, table, context_lens, q_starts, tile_rows,
-                tile_offs, scale=self.scale, groups=self.groups,
+                wide, pool, table, batch.context_lens, batch.q_starts,
+                batch.tile_rows, batch.tile_offs, scale=self.scale,
+                groups=self.groups,
                 window=self.window,
                 name="ragged_diff_attention")            # [T, H, 2 hd]
             out = self._combine(cx, packing.compact(att))
         return out, pool
 
 
-class GatedFFN(Module):
-    """W2 (up . silu(gate_scale . gate)), [gate | up] = W1 y."""
-
-    def __init__(self, model_dim, ffn_dim, dtype, param_dtype,
-                 gate_scale: float = 1.0):
-        super().__init__()
-        self.model_dim, self.ffn_dim = model_dim, ffn_dim
-        self.dtype, self.param_dtype = dtype, param_dtype
-        self.gate_scale = gate_scale
-
-    def forward(self, cx: Context, y):
-        gu = _dense(cx, "w1", y, 2 * self.ffn_dim, self.dtype,
-                    self.param_dtype)
-        gate = gu[..., :self.ffn_dim]
-        if self.gate_scale != 1.0:
-            gate = gate * self.gate_scale
-        h = gu[..., self.ffn_dim:] * jax.nn.silu(gate)
-        return _dense(cx, "w2", h, self.model_dim, self.dtype,
-                      self.param_dtype)
-
-
 class HybridBlock(Module):
-    def __init__(self, kind: str, mixer: Module, ffn: GatedFFN, eps,
+    def __init__(self, kind: str, mixer: Module, ffn: PackedGatedFFN, eps,
                  param_dtype):
         super().__init__()
         self.kind = kind
@@ -348,11 +315,12 @@ class HybridBlock(Module):
         return h + self.ffn(cx, self.ln2(cx, h))
 
 
-class HybridLM(Module):
+class HybridLM(ServedModel):
     """Decoder-only LM of `HybridBlock`s, one a name of `layer_kinds`.
     A "gmu" needs a "mamba" below it and a "cross" a "full"; `window` is
     the window layers' width; d_inner, d_state, d_conv and dt_rank the
     state-space layers'. Tied head, float32 logits."""
+    model_type = "hybrid_lm"
 
     def __init__(self, vocab: int, model_dim: int, num_heads: int,
                  num_kv_heads: int, ffn_dim: int, layer_kinds, window: int,
@@ -409,7 +377,8 @@ class HybridLM(Module):
                 elif kind == "cross":
                     self.source[i] = full
             blocks.append(HybridBlock(
-                kind, mixer, GatedFFN(model_dim, ffn_dim, dtype, param_dtype),
+                kind, mixer,
+                PackedGatedFFN(model_dim, ffn_dim, dtype, param_dtype),
                 eps, param_dtype))
         self.blocks = blocks
         self.norm_f = LayerNorm(eps, param_dtype=param_dtype)
@@ -432,13 +401,8 @@ class HybridLM(Module):
             return {"kind": "reads", "layer": self.source[i]}
         return {"kind": "none"}
 
-    def serve_metadata(self) -> dict:
-        return {"model_type": "hybrid_lm", "config": dict(self.config),
-                "max_len": self.max_len,
-                "dtype": jnp.dtype(self.dtype).name,
-                "param_dtype": self.param_dtype.name}
-
-    def _logits(self, cx: Context, h):
+    def logits(self, cx: Context, x):
+        h = self.norm_f(cx, x)
         table = cx.scope("embed").param(
             "weight", (self.vocab, self.model_dim), I.normal(0.0, 1.0),
             self.param_dtype)
@@ -466,43 +430,30 @@ class HybridLM(Module):
                 if blk.kind == "full":
                     kvs[i] = kv
             x = blk.finish(c, x, mixed)
-        return self._logits(cx, self.norm_f(cx, x))
+        return self.logits(cx, x)
 
-    def ragged_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, last_idx, tp=None,
-                          qpools=None, qscales=None):
-        """The engine's one step (`CausalLM.ragged_step_paged` has the
-        contract). `pools` is the cache manager's list for this model's
+    def trunk(self, cx: Context, batch, pools):
+        """The step's layers (`models/step_rows.py` `serve_step`).
+        `pools` is the cache manager's list for this model's
         `cache_layout`: each layer's arrays in layer order (a paged or a
         window pool; a state layer's arrays in the order it declared
-        them), then the manager's ROWS table, int32 [rows, 1 + ring]:
-        a step row's state slot, and the pool blocks of its window ring
-        (logical block b of a sequence lives in ring place b mod ring).
-        Returns (logits, the same list updated). Everything but the
-        kernels runs on the step's tokens alone, at the compact width
-        (`models/step_rows.py`)."""
-        if tp is not None or qpools:
-            raise ValueError("recurrent state is served on one chip with no "
-                             "int8 tier (engine/paged_cache.py)")
+        them), then the manager's ROWS table, int32 [rows, 1 + ring]: a
+        step row's state slot, and the pool blocks of its window ring
+        (logical block b of a sequence lives in ring place b mod
+        ring)."""
         *arrays, rows = pools
         arrays = iter(arrays)
-        t, nt = tokens.shape[0], tile_rows.shape[0]
-        tq = t // nt
-        row_slots, ring = rows[:, 0], rows[:, 1:]
-        meta = scan.tile_meta(row_slots, context_lens, q_starts, tile_rows,
-                              tile_offs, tq)
-        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
-                            last_idx, t)
+        ring = rows[:, 1:]
+        meta = batch.tile_meta(rows[:, 0])
+        packing, positions = batch.packing, batch.flat_positions
         # the window pools' block table by logical block, and the flat
         # pool row of each position (padding: scratch block 0)
-        mb = block_tables.shape[1]
+        mb = batch.block_tables.shape[1]
         places = jnp.arange(mb, dtype=jnp.int32) % ring.shape[1]
         window_table = ring[:, places]
-        row_of = jnp.repeat(tile_rows, tq)
-        positions = positions.astype(jnp.int32)
+        row_of = jnp.repeat(batch.tile_rows, batch.tq)
         out_pools, memories, full = [], {}, {}
-        x = self.embed(cx, packing.compact(tokens))              # [T_c, D]
+        x = self.embed(cx, batch.tokens)                         # [T_c, D]
         for i, blk in enumerate(self.blocks):
             c = cx.scope(blk._name)
             y = blk.ln1(c, x)
@@ -510,14 +461,14 @@ class HybridLM(Module):
             if blk.kind == "mamba":
                 ssm, tails = next(arrays), next(arrays)
                 mixed, memories[i], ssm, tails = blk.mixer.ragged_step(
-                    m, y, ssm, tails, meta, tile_offs, packing)
+                    m, y, ssm, tails, meta, batch)
                 out_pools += [ssm, tails]
             elif blk.kind == "gmu":
                 mixed = blk.mixer.forward(m, y, memories[self.source[i]])
             elif blk.kind == "cross":
                 mixed, _ = blk.mixer.ragged_step(
-                    m, y, out_pools[full[self.source[i]]], block_tables, None,
-                    context_lens, q_starts, tile_rows, tile_offs, packing)
+                    m, y, out_pools[full[self.source[i]]],
+                    batch.block_tables, None, batch)
             else:
                 pool = next(arrays)
                 if blk.kind == "window":
@@ -526,19 +477,14 @@ class HybridLM(Module):
                         window_table[row_of], (positions // bs)[:, None],
                         axis=1)[:, 0]
                     table = window_table
-                    rows_at = jnp.where(packing.flat_real,
-                                        block * bs + positions % bs, 0)
+                    rows_at = packing.compact(jnp.where(
+                        packing.flat_real, block * bs + positions % bs, 0))
                 else:
-                    table, rows_at = block_tables, slots
-                mixed, pool = blk.mixer.ragged_step(
-                    m, y, pool, table, packing.compact(rows_at),
-                    context_lens, q_starts, tile_rows, tile_offs, packing)
+                    table, rows_at = batch.block_tables, batch.slots
+                mixed, pool = blk.mixer.ragged_step(m, y, pool, table,
+                                                    rows_at, batch)
                 if blk.kind == "full":
                     full[i] = len(out_pools)
                 out_pools.append(pool)
             x = blk.finish(c, x, mixed)
-        hidden = self.norm_f(cx, x)
-        idx = packing.last
-        logits = self._logits(cx, jnp.take(hidden, idx.reshape(-1), axis=0))
-        return (logits.reshape(idx.shape + (logits.shape[-1],)),
-                out_pools + [rows])
+        return x, out_pools + [rows], None

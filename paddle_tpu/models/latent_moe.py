@@ -7,8 +7,8 @@ shared ones), an untied head, parameters resident in `param_dtype`.
 
     x <- x + MLA(RMSNorm(x));  x <- x + F(RMSNorm(x))
 
-Served through the engine's one ragged step (`ragged_step_paged`, the
-`CausalLM` contract plus per-expert token counts), the attention runs
+Served through the engine's one ragged step (`models/step_rows.py`
+`serve_step`, with per-expert token counts), the attention runs
 ABSORBED over a latent block pool: a token's cached row is
 [RMSNorm(c_kv) | RoPE(k_r)] (kv_rank + rope values, no head axis), the
 query of head h is [W_kvb^K,h^T q_nope_h | q_rope_h], the scores contract
@@ -18,14 +18,9 @@ kernel. `forward` computes the published un-absorbed form (full keys and
 values per head, nothing cached); the two agree to rounding
 (tests/test_latent_moe.py).
 
-The expert layer is sorted and dropless: the step's (row, choice) pairs
-are ordered by expert, the grouped products run over the expert groups
-(`kernels/grouped_product.py`: on the TPU one Pallas call for the gate
-and up, one for the down, each reading a touched expert's weights
-once), the results are un-sorted, weighted and summed, and the shared
-expert is added. No capacity, so no token is dropped whatever
-the imbalance; rows that are padding of the flat packing are routed
-nowhere and counted nowhere. (`parallel/moe.py` holds the training-side
+The expert layer is sorted and dropless (`shared_layers.RoutedExperts`);
+rows that are padding of the flat packing are routed nowhere and
+counted nowhere. (`parallel/moe.py` holds the training-side
 capacity-buffer experts; nothing of it is used here.)
 """
 
@@ -37,9 +32,9 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.module import Context, Module
-from paddle_tpu.kernels import grouped_product as grouped
 from paddle_tpu.kernels import paged_attention as paged
-from paddle_tpu.models.step_rows import step_rows
+from paddle_tpu.models.shared_layers import GatedFFN, RoutedExperts
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, Linear, RMSNorm
 
@@ -135,117 +130,31 @@ class LatentAttention(Module):
         o = jnp.einsum("bhqk,bkhv->bqhv", a.astype(v.dtype), seqs(v))
         return self.o(cx, o.reshape(b, t, self.num_heads * self.v_dim))
 
-    def ragged_step_paged(self, cx: Context, x, positions, kv_pool,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, packing):
+    def ragged_step(self, cx: Context, x, kv_pool, batch):
         """The absorbed form over the step's tokens at the compact width
-        (`packing`, `models/step_rows.py`): x [T_c, d]. The step's latent
-        rows are written into the pool at `slots` first (in place on a
-        donated pool), then one launch of the ragged kernel serves every
-        row of the flat packing against the pool as it lies. Returns
-        (out [T_c, d], new pool)."""
+        (`batch`, a `models.step_rows.StepBatch`): x [T_c, d]. The
+        step's latent rows are written into the pool at its slots first
+        (in place on a donated pool), then one launch of the ragged
+        kernel serves every row of the flat packing against the pool as
+        it lies. Returns (out [T_c, d], new pool)."""
         cx = cx.scope(self._name or type(self).__name__)
         with jax.named_scope("mla_attention"):
-            q_nope, q_rope, c_kv, k_rope = self._project(cx, x, positions)
+            q_nope, q_rope, c_kv, k_rope = self._project(cx, x,
+                                                         batch.positions)
             wk, wv = self._kv_b(cx)
             kv_pool = paged.write_latent(
-                kv_pool, slots, jnp.concatenate([c_kv, k_rope], axis=-1))
+                kv_pool, batch.slots,
+                jnp.concatenate([c_kv, k_rope], axis=-1))
             q = jnp.concatenate(
                 [jnp.einsum("thn,chn->thc", q_nope, wk), q_rope], axis=-1)
             latent = paged.ragged_paged_attention(
-                packing.expand(q), kv_pool, block_tables, context_lens,
-                q_starts, tile_rows, tile_offs, scale=self.scale,
-                groups=self.num_heads,
+                batch.packing.expand(q), kv_pool, batch.block_tables,
+                batch.context_lens, batch.q_starts, batch.tile_rows,
+                batch.tile_offs, scale=self.scale, groups=self.num_heads,
                 value_lanes=(0, self.kv_rank))          # [T, H, kv_rank]
-            o = jnp.einsum("thc,chv->thv", packing.compact(latent), wv)
+            o = jnp.einsum("thc,chv->thv", batch.packing.compact(latent), wv)
         out = self.o(cx, o.reshape(x.shape[0], self.num_heads * self.v_dim))
         return out, kv_pool
-
-
-class GatedFFN(Module):
-    """down(silu(gate x) * up x), no biases."""
-
-    def __init__(self, model_dim: int, hidden_dim: int, dtype=jnp.float32,
-                 param_dtype=jnp.float32):
-        super().__init__()
-        kw = dict(use_bias=False, dtype=dtype, param_dtype=param_dtype)
-        self.gate = Linear(hidden_dim, **kw)
-        self.up = Linear(hidden_dim, **kw)
-        self.down = Linear(model_dim, **kw)
-
-    def forward(self, cx: Context, x):
-        return self.down(cx, jax.nn.silu(self.gate(cx, x)) * self.up(cx, x))
-
-
-class RoutedExperts(Module):
-    """`num_experts` routed gated-SiLU experts of which each token takes
-    `top_k`, plus `num_shared` always-on ones (one FFN of their summed
-    width; none at 0). Router: scores = sigmoid(W_g x) in float32; the
-    chosen are the top_k of scores + bias; their weights the scores
-    (without the bias) over their sum + `eps`, times `scaling`."""
-
-    def __init__(self, model_dim: int, expert_dim: int, num_experts: int,
-                 top_k: int, num_shared: int = 1, scaling: float = 1.0,
-                 dtype=jnp.float32, param_dtype=jnp.float32,
-                 eps: float = 1e-20):
-        super().__init__()
-        self.model_dim, self.expert_dim = model_dim, expert_dim
-        self.num_experts, self.top_k = num_experts, top_k
-        self.scaling, self.eps = scaling, eps
-        self.dtype, self.param_dtype = dtype, param_dtype
-        self.num_shared = num_shared
-        if num_shared:
-            self.shared = GatedFFN(model_dim, expert_dim * num_shared, dtype,
-                                   param_dtype)
-
-    def _route(self, cx: Context, x):
-        """x [T, d] -> (chosen [T, k] int32, weights [T, k] float32)."""
-        c = cx.scope("router")
-        w = c.param("weight", (self.model_dim, self.num_experts),
-                    I.glorot_uniform, self.param_dtype)
-        b = c.param("bias", (self.num_experts,), I.normal(0.0, 0.02),
-                    self.param_dtype)
-        scores = jax.nn.sigmoid(jnp.matmul(
-            x.astype(jnp.float32), w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        _, chosen = jax.lax.top_k(scores + b.astype(jnp.float32), self.top_k)
-        picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = (picked / (picked.sum(axis=-1, keepdims=True) + self.eps)
-                   * self.scaling)
-        return chosen.astype(jnp.int32), weights
-
-    def forward(self, cx: Context, x, real=None):
-        """x [T, d] -> (y [T, d], tokens per expert [E] int32, the
-        router's choices [T, k]). `real` [T] bool marks the rows that
-        are tokens; the others are routed to no expert, counted nowhere,
-        and come out as the shared expert's output alone, or zeros
-        (nobody reads them)."""
-        t, d = x.shape
-        e, k, f = self.num_experts, self.top_k, self.expert_dim
-        routed, weights = self._route(cx, x)
-        c = cx.scope("experts")
-        gate = c.param("gate", (e, d, f), I.glorot_uniform, self.param_dtype)
-        up = c.param("up", (e, d, f), I.glorot_uniform, self.param_dtype)
-        down = c.param("down", (e, f, d), I.glorot_uniform, self.param_dtype)
-        with jax.named_scope("moe_experts"):
-            # padding takes expert id E, which sorts behind every expert
-            flat = (routed if real is None else
-                    jnp.where(real[:, None], routed, e)).reshape(-1)  # [T*k]
-            order = jnp.argsort(flat, stable=True)
-            counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
-            xs = jnp.take(x.astype(self.dtype), order // k, axis=0)
-            h = grouped.gated_grouped_product(
-                xs, gate.astype(self.dtype), up.astype(self.dtype), counts)
-            ys = grouped.grouped_product(h, down.astype(self.dtype), counts)
-            # un-sort: pair (row, choice) sits at inverse[row * k + choice]
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(t * k, dtype=order.dtype))
-            pairs = jnp.take(ys, inverse, axis=0).reshape(t, k, d)
-            y = jnp.einsum("tkd,tk->td", pairs.astype(jnp.float32), weights)
-        y = y.astype(self.dtype)
-        if self.num_shared:
-            y = y + self.shared(cx, x)
-        return y, counts, routed
 
 
 class LatentMoEBlock(Module):
@@ -278,24 +187,22 @@ class LatentMoEBlock(Module):
             chosen = chosen.reshape(b, t, -1)
         return x + y.reshape(b, t, d), chosen
 
-    def ragged_step_paged(self, cx: Context, x, positions, kv_pool,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, packing):
+    def ragged_step(self, cx: Context, x, kv_pool, batch):
         cx = cx.scope(self._name or type(self).__name__)
-        h, kv_pool = self.attn.ragged_step_paged(
-            cx, self.ln1(cx, x), positions, kv_pool, block_tables,
-            context_lens, q_starts, tile_rows, tile_offs, slots, packing)
+        h, kv_pool = self.attn.ragged_step(cx, self.ln1(cx, x), kv_pool,
+                                           batch)
         x = x + h
-        y, counts, _ = self._feed(cx, self.ln2(cx, x), packing.real)
+        y, counts, _ = self._feed(cx, self.ln2(cx, x), batch.packing.real)
         return x + y, kv_pool, counts
 
 
-class LatentMoELM(Module):
+class LatentMoELM(ServedModel):
     """Decoder-only LM of `LatentMoEBlock`s: the first `first_dense`
     layers carry a dense gated FFN of width `dense_dim`, the rest the
     routed experts. No position table, no embedding scale, untied head
     with float32 logits. `max_len` bounds the positions served (the
     rotary angles are computed, so it costs nothing)."""
+    model_type = "latent_moe_lm"
 
     def __init__(self, vocab: int, model_dim: int, num_heads: int,
                  num_layers: int, q_rank: int, kv_rank: int, nope_dim: int,
@@ -338,17 +245,13 @@ class LatentMoELM(Module):
         self.norm_f = RMSNorm(eps, param_dtype=param_dtype)
         self.head = Linear(vocab, use_bias=False, dtype=dtype,
                            param_dtype=param_dtype)
+        # a latent pool in every layer: one (k_dim, v_dim) row a token
+        self.cache_layout = [{"kind": "paged"}] * num_layers
+        self.latent_row = blocks[0].attn.latent_row
 
-    def serve_metadata(self) -> dict:
-        """The manifest's `serve` block (engine.serve_metadata)."""
-        return {"model_type": "latent_moe_lm", "config": dict(self.config),
-                "max_len": self.max_len,
-                "dtype": jnp.dtype(self.dtype).name,
-                "param_dtype": self.param_dtype.name}
-
-    def _logits(self, cx: Context, h):
+    def logits(self, cx: Context, h):
         w = _weight(cx, self.head, self.model_dim)
-        return jnp.matmul(h.astype(self.dtype), w,
+        return jnp.matmul(self.norm_f(cx, h).astype(self.dtype), w,
                           preferred_element_type=jnp.float32)
 
     def forward(self, cx: Context, tokens, return_routing: bool = False):
@@ -364,39 +267,18 @@ class LatentMoELM(Module):
             x, chosen = blk(cx, x)
             if chosen is not None:
                 routing.append(chosen)
-        logits = self._logits(cx, self.norm_f(cx, x))
+        logits = self.logits(cx, x)
         return (logits, jnp.stack(routing)) if return_routing else logits
 
-    def ragged_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, last_idx, tp=None,
-                          qpools=None, qscales=None):
-        """The engine's one step (`CausalLM.ragged_step_paged` has the
-        contract) over latent pools. Returns (logits, new pools, tokens
-        per expert int32 [expert layers, E]). Everything but the latent
-        kernel runs on the step's tokens alone, at the compact width
-        (`models/step_rows.py`); the rows past them are routed to no
+    def trunk(self, cx: Context, batch, pools):
+        """The step's layers over latent pools (`models/step_rows.py`
+        `serve_step`); the rows past the step's tokens are routed to no
         expert."""
-        if tp is not None or qpools:
-            raise ValueError("a latent pool is served on one chip with no "
-                             "int8 tier (engine/paged_cache.py)")
-        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
-                            last_idx, tokens.shape[0])
-        tokens, slots = packing.compact(tokens), packing.compact(slots)
-        positions = packing.compact(positions.astype(jnp.int32))
-        x = self.embed(cx, tokens)                               # [T_c, D]
+        x = self.embed(cx, batch.tokens)                         # [T_c, D]
         new_pools, counts = [], []
         for blk, kv_pool in zip(self.blocks, pools):
-            x, kv_pool, n = blk.ragged_step_paged(
-                cx, x, positions, kv_pool, block_tables, context_lens,
-                q_starts, tile_rows, tile_offs, slots, packing)
+            x, kv_pool, n = blk.ragged_step(cx, x, kv_pool, batch)
             new_pools.append(kv_pool)
             if n is not None:
                 counts.append(n)
-        hidden = self.norm_f(cx, x)
-        logits = self._logits(
-            cx, jnp.take(hidden, packing.last.reshape(-1), axis=0))
-        return (logits.reshape(packing.last.shape + (logits.shape[-1],)),
-                new_pools,
-                jnp.stack(counts) if counts else
-                jnp.zeros((0, self.num_experts), jnp.int32))
+        return x, new_pools, counts

@@ -39,94 +39,18 @@ blocks and one slot.
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.core.module import Context, Module
 from paddle_tpu.kernels import lightning_attention as recurrence
-from paddle_tpu.kernels import paged_attention as paged
 from paddle_tpu.kernels import selective_scan as scan
-from paddle_tpu.models.hybrid_lm import GatedFFN, _dense
-from paddle_tpu.models.sparse_linear_lm import rotate
-from paddle_tpu.models.step_rows import step_rows
+from paddle_tpu.models.shared_layers import (Attention, PackedGatedFFN,
+                                             dense)
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, RMSNorm
-
-
-class Attention(Module):
-    """GQA with rotary over the whole head and a key multiplier; with
-    `qk_norm_eps`, q and k each through an RMSNorm over the head (a
-    learned scale of head_dim) before the rotary. `kv_row` is what one
-    pool's row holds."""
-
-    def __init__(self, model_dim, num_heads, num_kv_heads, head_dim, theta,
-                 key_multiplier, dtype, param_dtype, qk_norm_eps=None):
-        super().__init__()
-        self.model_dim, self.num_heads = model_dim, num_heads
-        self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
-        self.groups = num_heads // num_kv_heads
-        self.theta, self.key_multiplier = float(theta), float(key_multiplier)
-        self.dtype, self.param_dtype = dtype, param_dtype
-        self.scale = 1.0 / math.sqrt(head_dim)
-        self.kv_row = (num_kv_heads, head_dim)
-        self.qk_norm = qk_norm_eps is not None
-        if self.qk_norm:
-            self.q_norm = RMSNorm(qk_norm_eps, dtype=jnp.float32,
-                                  param_dtype=param_dtype)
-            self.k_norm = RMSNorm(qk_norm_eps, dtype=jnp.float32,
-                                  param_dtype=param_dtype)
-
-    def _project(self, cx: Context, y, positions):
-        """y [..., T, d] -> q [..., T, H, hd], k, v [..., T, Hkv, hd]."""
-        h, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        lead = y.shape[:-1]
-        qkv = _dense(cx, "qkv", y, (h + 2 * kvh) * hd, self.dtype,
-                     self.param_dtype)
-        q = qkv[..., :h * hd].reshape(lead + (h, hd))
-        k = qkv[..., h * hd:(h + kvh) * hd].reshape(lead + (kvh, hd))
-        v = qkv[..., (h + kvh) * hd:].reshape(lead + (kvh, hd))
-        if self.qk_norm:
-            q, k = self.q_norm(cx, q), self.k_norm(cx, k)
-        q = rotate(q.astype(jnp.float32), positions, self.theta)
-        k = rotate(k.astype(jnp.float32) * self.key_multiplier, positions,
-                   self.theta)
-        return q.astype(self.dtype), k.astype(self.dtype), v
-
-    def _finish(self, cx: Context, att):
-        att = att.reshape(att.shape[:-2] + (-1,)).astype(self.dtype)
-        return _dense(cx, "o", att, self.model_dim, self.dtype,
-                      self.param_dtype)
-
-    def forward(self, cx: Context, y):
-        """Whole sequences y [B, T, d] from position 0."""
-        b, t = y.shape[:2]
-        q, k, v = self._project(cx, y, jnp.broadcast_to(jnp.arange(t),
-                                                        (b, t)))
-        qg = q.reshape(b, t, self.num_kv_heads, self.groups, self.head_dim)
-        s = jnp.einsum("bqkgd,bjkd->bkgqj", qg, k,
-                       preferred_element_type=jnp.float32) * self.scale
-        pos = jnp.arange(t)
-        a = jax.nn.softmax(jnp.where(pos[None, :] <= pos[:, None], s,
-                                     -jnp.inf), axis=-1)
-        att = jnp.einsum("bkgqj,bjkd->bqkgd", a.astype(v.dtype), v)
-        return self._finish(cx, att.reshape(b, t, self.num_heads,
-                                            self.head_dim))
-
-    def ragged_step(self, cx: Context, y, pool, positions, block_tables,
-                    context_lens, q_starts, tile_rows, tile_offs, slots,
-                    packing):
-        """y [T_c, d], positions and slots [T_c]: the step's tokens
-        (`packing`, `models/step_rows.py`); the kernel runs over the flat
-        packing. Returns (output, pool)."""
-        q, k, v = self._project(cx, y, positions)
-        pool = paged.write_kv(pool, slots, k, v)
-        att = paged.ragged_paged_attention(
-            packing.expand(q), pool, block_tables, context_lens, q_starts,
-            tile_rows, tile_offs, scale=self.scale, groups=self.groups)
-        return self._finish(cx, packing.compact(att)), pool
 
 
 class Mamba2(Module):
@@ -166,8 +90,8 @@ class Mamba2(Module):
     def _pre(self, cx: Context, u):
         """u [..., d] -> z (float32), [x | B | C] in the compute dtype,
         dt (float32): the input projection, its blocks scaled."""
-        zxbcdt = _dense(cx, "in_proj", u, self.multipliers.size, self.dtype,
-                        self.param_dtype).astype(jnp.float32) \
+        zxbcdt = dense(cx, "in_proj", u, self.multipliers.size, self.dtype,
+                       self.param_dtype).astype(jnp.float32) \
             * self.multipliers
         ds = self.d_ssm
         return (zxbcdt[..., :ds],
@@ -199,8 +123,8 @@ class Mamba2(Module):
                                        self.param_dtype)
         g = (gg * jax.lax.rsqrt(var + self.eps)).reshape(g.shape) \
             * scale.astype(jnp.float32)
-        return _dense(cx, "out_proj", g.astype(self.dtype), self.model_dim,
-                      self.dtype, self.param_dtype)
+        return dense(cx, "out_proj", g.astype(self.dtype), self.model_dim,
+                     self.dtype, self.param_dtype)
 
     def forward(self, cx: Context, u):
         """u [B, T, d], whole sequences from position 0."""
@@ -229,12 +153,13 @@ class Mamba2(Module):
             + p["d"].astype(jnp.float32)[:, None] * x
         return self._post(cx, y, z)
 
-    def ragged_step(self, cx: Context, u, ssm, tails, meta, tile_offs,
-                    packing):
-        """u [T_c, d], the step's tokens (`packing`,
-        `models/step_rows.py`); the convolution and the scan run over
-        the flat packing. Returns (output, new scan state, new tails)."""
+    def ragged_step(self, cx: Context, u, ssm, tails, meta, batch):
+        """u [T_c, d], the step's tokens (`batch`, a
+        `models.step_rows.StepBatch`; `meta` its `tile_meta`); the
+        convolution and the scan run over the flat packing. Returns
+        (output, new scan state, new tails)."""
         p = self._params(cx)
+        packing, tile_offs = batch.packing, batch.tile_offs
         slots, real, fresh, last = meta
         z, xbc, dt = self._pre(cx, u)
         with jax.named_scope("ssd_scan"):
@@ -249,8 +174,8 @@ class Mamba2(Module):
 
 
 class ParallelBlock(Module):
-    def __init__(self, attn: Attention, ssm: Mamba2, ffn: GatedFFN, eps,
-                 dtype, param_dtype):
+    def __init__(self, attn: Attention, ssm: Mamba2, ffn: PackedGatedFFN,
+                 eps, dtype, param_dtype):
         super().__init__()
         self.attn = attn
         self.ssm = ssm
@@ -259,11 +184,12 @@ class ParallelBlock(Module):
         self.ln2 = RMSNorm(eps, dtype=dtype, param_dtype=param_dtype)
 
 
-class ParallelHybridLM(Module):
+class ParallelHybridLM(ServedModel):
     """Decoder-only LM of `ParallelBlock`s: attention and a Mamba-2
     mixer in every layer. The multipliers are the configuration's
     `*_multiplier(s)`; `ssm_multipliers` scales the input projection's
     blocks z, x, B, C, dt."""
+    model_type = "parallel_hybrid_lm"
 
     def __init__(self, vocab: int, model_dim: int, num_heads: int,
                  num_kv_heads: int, head_dim: int, ffn_dim: int,
@@ -323,8 +249,8 @@ class ParallelHybridLM(Module):
                       rope_theta, key_multiplier, dtype, param_dtype),
             Mamba2(model_dim, ssm_heads, ssm_head_dim, ssm_state, ssm_groups,
                    conv, ssm_multipliers, eps, dtype, param_dtype),
-            GatedFFN(model_dim, ffn_dim, dtype, param_dtype,
-                     gate_scale=float(mlp_multipliers[0])),
+            PackedGatedFFN(model_dim, ffn_dim, dtype, param_dtype,
+                           gate_scale=float(mlp_multipliers[0])),
             eps, dtype, param_dtype) for _ in range(num_layers)]
         self.norm_f = RMSNorm(eps, dtype=jnp.float32, param_dtype=param_dtype)
         # what one pool's row is: every kv head's [k | v]
@@ -333,21 +259,15 @@ class ParallelHybridLM(Module):
         self.cache_layout = [{"kind": "paged", "arrays": b.ssm.state_shapes}
                              for b in self.blocks]
 
-    def serve_metadata(self) -> dict:
-        return {"model_type": "parallel_hybrid_lm",
-                "config": dict(self.config), "max_len": self.max_len,
-                "dtype": jnp.dtype(self.dtype).name,
-                "param_dtype": self.param_dtype.name}
-
     def _finish(self, cx: Context, blk, x, attended, scanned):
         h = x + (self.attn_out * attended.astype(jnp.float32)
                  + self.ssm_out * scanned.astype(jnp.float32)).astype(x.dtype)
         return h + (self.mlp_out * blk.ffn(cx, blk.ln2(cx, h))).astype(
             x.dtype)
 
-    def _logits(self, cx: Context, x):
+    def logits(self, cx: Context, x):
         y = self.norm_f(cx, x)
-        return self.lm_head_multiplier * _dense(
+        return self.lm_head_multiplier * dense(
             cx, "head", y.astype(self.dtype), self.vocab, self.dtype,
             self.param_dtype, out=jnp.float32)
 
@@ -365,49 +285,27 @@ class ParallelHybridLM(Module):
                 attended = blk.attn.forward(c.scope("attn"), y * self.attn_in)
                 scanned = blk.ssm.forward(c.scope("ssm"), y * self.ssm_in)
             x = self._finish(c, blk, x, attended, scanned)
-        return self._logits(cx, x)
+        return self.logits(cx, x)
 
-    def ragged_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, last_idx, tp=None,
-                          qpools=None, qscales=None):
-        """The engine's one step (`CausalLM.ragged_step_paged` has the
-        contract). `pools` is the cache manager's list for this model's
+    def trunk(self, cx: Context, batch, pools):
+        """The step's layers (`models/step_rows.py` `serve_step`).
+        `pools` is the cache manager's list for this model's
         `cache_layout`: each layer's paged pool, then its scan state and
-        its tails; last the ROWS table (a step row's state slot).
-        Returns (logits, the same list updated). Everything but the
-        kernels runs on the step's tokens alone, at the compact width
-        (`models/step_rows.py`)."""
-        if tp is not None or qpools:
-            raise ValueError("recurrent state is served on one chip with no "
-                             "int8 tier (engine/paged_cache.py)")
+        its tails; last the ROWS table (a step row's state slot)."""
         *arrays, rows = pools
         arrays = iter(arrays)
-        t, nt = tokens.shape[0], tile_rows.shape[0]
-        positions = positions.astype(jnp.int32)
-        meta = scan.tile_meta(rows[:, 0], context_lens, q_starts, tile_rows,
-                              tile_offs, t // nt)
-        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
-                            last_idx, t)
-        tokens, positions, slots = map(packing.compact,
-                                       (tokens, positions, slots))
+        meta = batch.tile_meta(rows[:, 0])
         out_pools = []
-        x = self.embed(cx, tokens) * self.embedding_multiplier   # [T_c, D]
+        x = self.embed(cx, batch.tokens) * self.embedding_multiplier
         for blk in self.blocks:
             c = cx.scope(blk._name)
             y = blk.ln1(c, x)
             pool, ssm, tails = next(arrays), next(arrays), next(arrays)
             with jax.named_scope("parallel_mixer"):
                 attended, pool = blk.attn.ragged_step(
-                    c.scope("attn"), y * self.attn_in, pool, positions,
-                    block_tables, context_lens, q_starts, tile_rows,
-                    tile_offs, slots, packing)
+                    c.scope("attn"), y * self.attn_in, pool, batch)
                 scanned, ssm, tails = blk.ssm.ragged_step(
-                    c.scope("ssm"), y * self.ssm_in, ssm, tails, meta,
-                    tile_offs, packing)
+                    c.scope("ssm"), y * self.ssm_in, ssm, tails, meta, batch)
             out_pools += [pool, ssm, tails]
             x = self._finish(c, blk, x, attended, scanned)
-        idx = packing.last
-        logits = self._logits(cx, jnp.take(x, idx.reshape(-1), axis=0))
-        return (logits.reshape(idx.shape + (logits.shape[-1],)),
-                out_pools + [rows])
+        return x, out_pools + [rows], None

@@ -52,26 +52,13 @@ import jax.numpy as jnp
 from paddle_tpu.core.module import Context, Module
 from paddle_tpu.kernels import lightning_attention as lightning
 from paddle_tpu.kernels import paged_attention as paged
-from paddle_tpu.kernels import selective_scan as scan
-from paddle_tpu.models.hybrid_lm import GatedFFN, _dense
-from paddle_tpu.models.step_rows import step_rows
+from paddle_tpu.models.shared_layers import PackedGatedFFN, dense, rotate
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Embedding, RMSNorm
 
 KINDS = ("minicpm4", "lightning-attn")
 NEG = -1e30
-
-
-def rotate(x, positions, theta: float):
-    """Rotary embedding over the whole last axis, rotate-half: x
-    [..., T, H, D] float32, positions [..., T]."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[..., None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
 
 
 def block_scores(p, rows: int):
@@ -135,18 +122,18 @@ class SparseAttention(Module):
         normed), the gate [..., H hd]."""
         hd, h, kvh = self.head_dim, self.num_heads, self.num_kv_heads
         lead = y.shape[:-1]
-        q = _dense(cx, "q", y, h * hd, self.dtype, self.param_dtype)
-        kv = _dense(cx, "kv", y, 2 * kvh * hd, self.dtype, self.param_dtype)
+        q = dense(cx, "q", y, h * hd, self.dtype, self.param_dtype)
+        kv = dense(cx, "kv", y, 2 * kvh * hd, self.dtype, self.param_dtype)
         q = self.q_norm(cx, q.reshape(lead + (h, hd)))
         k = self.k_norm(cx, kv[..., :kvh * hd].reshape(lead + (kvh, hd)))
         v = kv[..., kvh * hd:].reshape(lead + (kvh, hd))
-        gate = _dense(cx, "gate", y, h * hd, self.dtype, self.param_dtype)
+        gate = dense(cx, "gate", y, h * hd, self.dtype, self.param_dtype)
         return q, k, v, gate
 
     def _finish(self, cx: Context, att, gate):
         o = att.reshape(gate.shape).astype(self.dtype) * jax.nn.sigmoid(gate)
-        return _dense(cx, "o", o, self.model_dim, self.dtype,
-                      self.param_dtype)
+        return dense(cx, "o", o, self.model_dim, self.dtype,
+                     self.param_dtype)
 
     def _selection(self, q, kbar, t, limit):
         """q [N, TQ, H, hd]; kbar [N, J', Hkv, hd], a tile's compressed
@@ -197,20 +184,20 @@ class SparseAttention(Module):
             att = jnp.einsum("bkgqj,bjkd->bqkgd", a.astype(v.dtype), v)
             return self._finish(cx, att, gate)
 
-    def ragged_step(self, cx: Context, y, pools, index, pk, packing):
-        """y [T_c, d], the step's tokens (`packing`,
-        `models/step_rows.py`); `pools` this layer's paged pools, one a
-        kv head; `index` its index pool; `pk` the step's packing
-        (`SparseLinearLM._packing`), over which the selection and the
-        kernel run. Returns (output, pools, index)."""
+    def ragged_step(self, cx: Context, y, pools, index, pk, batch):
+        """y [T_c, d], the step's tokens (`batch`, a
+        `models.step_rows.StepBatch`); `pools` this layer's paged pools,
+        one a kv head; `index` its index pool; `pk` what the selection
+        needs of the packing (`SparseLinearLM._packing`), over which the
+        selection and the kernel run. Returns (output, pools, index)."""
         sel, hd, g = self.sel, self.head_dim, self.groups
         q, k, v, gate = self._project(cx, y)
-        nt, tq = pk["tile_rows"].shape[0], pk["tq"]
+        nt, tq = batch.tile_rows.shape[0], batch.tq
         t = nt * tq
-        slots = packing.compact(pk["slots"])
-        pools = [paged.write_kv(pool, slots, k[:, i:i + 1], v[:, i:i + 1])
+        pools = [paged.write_kv(pool, batch.slots, k[:, i:i + 1],
+                                v[:, i:i + 1])
                  for i, pool in enumerate(pools)]
-        q = packing.expand(q)
+        q = batch.packing.expand(q)
         with jax.named_scope("sparse_select"):
             # the windows this step completes: their keys' mean, from the
             # pool (the chunk's own rows are in it now), a kv head. A
@@ -243,23 +230,23 @@ class SparseAttention(Module):
                 count = first.sum(axis=-1).astype(jnp.int32)
                 packed = jnp.where(
                     jnp.arange(first.shape[-1])[None, :] < count[:, None],
-                    jnp.take_along_axis(pk["block_tables"], order, axis=-1),
+                    jnp.take_along_axis(batch.block_tables, order, axis=-1),
                     0)
                 short = pk["compact"]
-                ctx = pk["context_lens"]
+                ctx = batch.context_lens
                 held = (count - 1) * sel["block"] \
                     + ctx - (ctx - 1) // sel["block"] * sel["block"]
-                table = jnp.where(short[:, None], packed, pk["block_tables"])
+                table = jnp.where(short[:, None], packed, batch.block_tables)
                 ctx_i = jnp.where(short, held, ctx)
-                starts = jnp.where(short, held - 1, pk["q_starts"])
+                starts = jnp.where(short, held - 1, batch.q_starts)
                 mask = mine.reshape(t, -1) | short[pk["row_of"]][:, None]
                 outs.append(paged.ragged_paged_attention(
                     q[:, i * g:(i + 1) * g], pool, table, ctx_i, starts,
-                    pk["tile_rows"], pk["tile_offs"], scale=self.scale,
+                    batch.tile_rows, batch.tile_offs, scale=self.scale,
                     groups=g, block_mask=mask,
                     name="ragged_sparse_attention"))
             att = jnp.concatenate(outs, axis=1)            # [T, H, hd]
-            out = self._finish(cx, packing.compact(att), gate)
+            out = self._finish(cx, batch.packing.compact(att), gate)
         return out, pools, index
 
 
@@ -290,18 +277,18 @@ class LightningAttention(Module):
         the gate."""
         h, hd = self.num_heads, self.head_dim
         lead = y.shape[:-1]
-        qkv = _dense(cx, "qkv", y, 3 * h * hd, self.dtype, self.param_dtype)
+        qkv = dense(cx, "qkv", y, 3 * h * hd, self.dtype, self.param_dtype)
         q, k, v = (qkv[..., i * h * hd:(i + 1) * h * hd].reshape(
             lead + (h, hd)) for i in range(3))
         q = rotate(self.q_norm(cx, q), positions, self.theta) * self.scale
         k = rotate(self.k_norm(cx, k), positions, self.theta)
-        gate = _dense(cx, "gate", y, h * hd, self.dtype, self.param_dtype)
+        gate = dense(cx, "gate", y, h * hd, self.dtype, self.param_dtype)
         return q, k, v.astype(jnp.float32), gate
 
     def _finish(self, cx: Context, o, gate):
         o = self.out_norm(cx, o.reshape(gate.shape))
-        return _dense(cx, "o", o * jax.nn.sigmoid(gate), self.model_dim,
-                      self.dtype, self.param_dtype)
+        return dense(cx, "o", o * jax.nn.sigmoid(gate), self.model_dim,
+                     self.dtype, self.param_dtype)
 
     def forward(self, cx: Context, y):
         """Whole sequences y [B, T, d] from position 0."""
@@ -323,24 +310,24 @@ class LightningAttention(Module):
                 jnp.swapaxes(x, 0, 1) for x in (q, k, v)))
             return self._finish(cx, jnp.swapaxes(o, 0, 1), gate)
 
-    def ragged_step(self, cx: Context, y, state, positions, meta, tile_offs,
-                    packing):
-        """y [T_c, d] and positions [T_c], the step's tokens (`packing`,
-        `models/step_rows.py`); the kernel runs over the flat packing.
-        Returns (output, new state)."""
+    def ragged_step(self, cx: Context, y, state, meta, batch):
+        """y [T_c, d], the step's tokens (`batch`, a
+        `models.step_rows.StepBatch`; `meta` its `tile_meta`); the
+        kernel runs over the flat packing. Returns (output, new
+        state)."""
         with jax.named_scope("lightning_attention"):
-            q, k, v, gate = self._project(cx, y, positions)
+            q, k, v, gate = self._project(cx, y, batch.positions)
             slots, real, fresh, _ = meta
             o, state = lightning.ragged_lightning_attention(
-                *map(packing.expand, (q, k, v)),
+                *map(batch.packing.expand, (q, k, v)),
                 jnp.asarray(self.log_decay, jnp.float32), state,
-                slots, real, fresh, tile_offs)
-            return self._finish(cx, packing.compact(o), gate), state
+                slots, real, fresh, batch.tile_offs)
+            return self._finish(cx, batch.packing.compact(o), gate), state
 
 
 class SparseLinearBlock(Module):
-    def __init__(self, kind: str, mixer: Module, ffn: GatedFFN, eps, dtype,
-                 param_dtype):
+    def __init__(self, kind: str, mixer: Module, ffn: PackedGatedFFN, eps,
+                 dtype, param_dtype):
         super().__init__()
         self.kind = kind
         self.mixer = mixer
@@ -349,7 +336,7 @@ class SparseLinearBlock(Module):
         self.ln2 = RMSNorm(eps, dtype=dtype, param_dtype=param_dtype)
 
 
-class SparseLinearLM(Module):
+class SparseLinearLM(ServedModel):
     """Decoder-only LM of `SparseLinearBlock`s, one a name of
     `mixer_types`. `first_layer` is the published index of layer 0 and
     `depth_base` the published depth (both enter the residual's scale
@@ -359,6 +346,7 @@ class SparseLinearLM(Module):
     `snapshot_tokens` / `snapshot_slots` are what the model asks of the
     cache manager for prefix reuse over its state (ENGINE.md "State
     snapshots"); an engine's own arguments override them."""
+    model_type = "sparse_linear_lm"
 
     def __init__(self, vocab: int, model_dim: int, num_heads: int,
                  num_kv_heads: int, head_dim: int, ffn_dim: int, mixer_types,
@@ -417,7 +405,8 @@ class SparseLinearLM(Module):
                     model_dim, la_heads, la_head_dim, first_layer + i,
                     depth_base, rope_theta, eps, dtype, param_dtype)
             blocks.append(SparseLinearBlock(
-                kind, mixer, GatedFFN(model_dim, ffn_dim, dtype, param_dtype),
+                kind, mixer,
+                PackedGatedFFN(model_dim, ffn_dim, dtype, param_dtype),
                 eps, dtype, param_dtype))
         self.blocks = blocks
         self.norm_f = RMSNorm(eps, dtype=jnp.float32, param_dtype=param_dtype)
@@ -433,12 +422,6 @@ class SparseLinearLM(Module):
                               "lanes": blk.mixer.num_kv_heads
                               * blk.mixer.head_dim}}
         return {"kind": "state", "arrays": blk.mixer.state_shapes}
-
-    def serve_metadata(self) -> dict:
-        return {"model_type": "sparse_linear_lm", "config": dict(self.config),
-                "max_len": self.max_len,
-                "dtype": jnp.dtype(self.dtype).name,
-                "param_dtype": self.param_dtype.name}
 
     def sparse_counts(self, start: int, length: int) -> dict:
         """What a step's row [start, start + length) asks of ONE sparse
@@ -471,10 +454,10 @@ class SparseLinearLM(Module):
         return h + (self.residual * blk.ffn(cx, blk.ln2(cx, h))
                     ).astype(x.dtype)
 
-    def _logits(self, cx: Context, x):
+    def logits(self, cx: Context, x):
         y = self.norm_f(cx, x) / self.head_div
-        return _dense(cx, "head", y.astype(self.dtype), self.vocab,
-                      self.dtype, self.param_dtype, out=jnp.float32)
+        return dense(cx, "head", y.astype(self.dtype), self.vocab,
+                     self.dtype, self.param_dtype, out=jnp.float32)
 
     def forward(self, cx: Context, tokens):
         """tokens [B, T] -> float32 logits [B, T, V]; whole sequences,
@@ -487,26 +470,28 @@ class SparseLinearLM(Module):
             c = cx.scope(blk._name)
             x = self._mix(c, blk, x, blk.mixer.forward(c.scope("mixer"),
                                                        blk.ln1(c, x)))
-        return self._logits(cx, x)
+        return self.logits(cx, x)
 
-    def _packing(self, positions, block_tables, context_lens, q_starts,
-                 tile_rows, tile_offs, slots, nt: int, tq: int, real):
+    def _packing(self, batch):
         """What every sparse layer of a step needs of the packing, built
         once: a tile's table, positions and window limit; the windows
         the step completes (the pool blocks their keys lie in and each
-        row's weight in the mean, where the mean goes in an index pool); which rows are decode rows
-        past dense_len."""
+        row's weight in the mean, where the mean goes in an index
+        pool); which rows are decode rows past dense_len."""
         sel = self.sparse
         blk, stride, kern = sel["block"], sel["stride"], sel["kernel"]
         rows_a = blk // stride
-        t = positions.shape[0]
+        positions, slots = batch.flat_positions, batch.flat_slots
+        block_tables, tile_rows = batch.block_tables, batch.tile_rows
+        tile_offs, tq = batch.tile_offs, batch.tq
+        nt, t = tile_rows.shape[0], positions.shape[0]
         r = block_tables.shape[0]
         row_of = jnp.repeat(tile_rows, tq)
-        tile_pos = (q_starts[tile_rows] + tile_offs)[:, None] \
+        tile_pos = (batch.q_starts[tile_rows] + tile_offs)[:, None] \
             + jnp.arange(tq, dtype=jnp.int32)[None, :]
-        ctx = context_lens
+        ctx = batch.context_lens
         # windows complete at the step's own real tokens
-        done = real & ((positions + 1) % stride == 0) \
+        done = batch.packing.flat_real & ((positions + 1) % stride == 0) \
             & (positions + 1 >= kern)
         width = t // stride + r
         at = jnp.nonzero(done, size=width, fill_value=t)[0]
@@ -534,10 +519,7 @@ class SparseLinearLM(Module):
         first_tile = jnp.zeros((r,), jnp.int32).at[tile_rows].max(
             jnp.where(tile_offs == 0, jnp.arange(nt, dtype=jnp.int32), 0))
         return {
-            "tq": tq, "slots": slots, "row_of": row_of,
-            "block_tables": block_tables, "context_lens": ctx,
-            "q_starts": q_starts, "tile_rows": tile_rows,
-            "tile_offs": tile_offs, "tile_pos": tile_pos,
+            "row_of": row_of, "tile_pos": tile_pos,
             "tile_tables": block_tables[tile_rows],
             # this step's own windows are in the index pool when a query
             # scores it; none lies past its row's context
@@ -545,39 +527,23 @@ class SparseLinearLM(Module):
             "win_blocks": win_blocks, "win_weights": win_weights,
             "win_dest": jnp.where(live, dest, 0),
             "first_tile": first_tile,
-            "compact": (ctx - q_starts == 1) & (ctx - 1 >= sel["dense_len"]),
+            "compact": (ctx - batch.q_starts == 1)
+            & (ctx - 1 >= sel["dense_len"]),
         }
 
-    def ragged_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, last_idx, tp=None,
-                          qpools=None, qscales=None):
-        """The engine's one step (`CausalLM.ragged_step_paged` has the
-        contract). `pools` is the cache manager's list for this model's
+    def trunk(self, cx: Context, batch, pools):
+        """The step's layers (`models/step_rows.py` `serve_step`).
+        `pools` is the cache manager's list for this model's
         `cache_layout`: a sparse layer's paged pools, one a kv head,
         then its index pool; a lightning layer's state; last the ROWS
-        table (a step row's state slot). Returns (logits, the same list
-        updated). Everything but the kernels and the selection runs on
-        the step's tokens alone, at the compact width
-        (`models/step_rows.py`)."""
-        if tp is not None or qpools:
-            raise ValueError("recurrent state is served on one chip with no "
-                             "int8 tier (engine/paged_cache.py)")
+        table (a step row's state slot). The selection runs over the
+        flat packing."""
         *arrays, rows = pools
         arrays = iter(arrays)
-        t, nt = tokens.shape[0], tile_rows.shape[0]
-        tq = t // nt
-        positions = positions.astype(jnp.int32)
-        meta = scan.tile_meta(rows[:, 0], context_lens, q_starts, tile_rows,
-                              tile_offs, tq)
-        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
-                            last_idx, t)
-        pk = self._packing(positions, block_tables, context_lens, q_starts,
-                           tile_rows, tile_offs, slots, nt, tq,
-                           packing.flat_real) if self.sparse_layers else None
+        meta = batch.tile_meta(rows[:, 0])
+        pk = self._packing(batch) if self.sparse_layers else None
         out_pools = []
-        positions_c = packing.compact(positions)
-        x = self.embed(cx, packing.compact(tokens)) * self.scale_emb
+        x = self.embed(cx, batch.tokens) * self.scale_emb
         for blk in self.blocks:
             c = cx.scope(blk._name)
             m = c.scope("mixer")
@@ -585,15 +551,11 @@ class SparseLinearLM(Module):
             if blk.kind == "minicpm4":
                 mine = [next(arrays) for _ in range(blk.mixer.num_kv_heads)]
                 mixed, mine, index = blk.mixer.ragged_step(
-                    m, y, mine, next(arrays), pk, packing)
+                    m, y, mine, next(arrays), pk, batch)
                 out_pools += mine + [index]
             else:
-                mixed, state = blk.mixer.ragged_step(
-                    m, y, next(arrays), positions_c, meta, tile_offs,
-                    packing)
+                mixed, state = blk.mixer.ragged_step(m, y, next(arrays),
+                                                     meta, batch)
                 out_pools.append(state)
             x = self._mix(c, blk, x, mixed)
-        idx = packing.last
-        logits = self._logits(cx, jnp.take(x, idx.reshape(-1), axis=0))
-        return (logits.reshape(idx.shape + (logits.shape[-1],)),
-                out_pools + [rows])
+        return x, out_pools + [rows], None

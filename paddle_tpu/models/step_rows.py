@@ -1,4 +1,14 @@
-"""The serving step's two row widths, and the moves between them.
+"""The serving step's contract between the engine and a served model,
+its two row widths, and the moves between them.
+
+The engine's compiled step calls `serve_step` with the operands it
+packed. That builds the step's `StepBatch` once, hands it to the
+model's `trunk` (its own loop over its layers: x [T_c, D], the pools
+updated, and the tokens each expert took or None), gathers the rows the
+engine samples, and hands them to the model's `logits` (the final norm
+and the head). Everything else the engine knows of a model is what it
+declares (`ServedModel`): its cache layout, its pool row, its expert
+layers, its snapshot spacing and its manifest block.
 
 The engine packs a step into T flat rows (kernels/paged_attention.py,
 `ragged_paged_attention`): the chunk budget rounded up to whole query
@@ -33,10 +43,13 @@ identity.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Any, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from paddle_tpu.core.module import Context, Module
+from paddle_tpu.kernels import selective_scan as scan
 
 
 class StepRows(NamedTuple):
@@ -92,3 +105,107 @@ def step_rows(tile_rows, tile_offs, q_starts, context_lens, last_idx,
     return StepRows(flat_of.astype(jnp.int32),
                     jnp.where(flat_real, rank, t_c), flat_of < width,
                     flat_real, jnp.maximum(rank[idx], 0), width)
+
+
+class StepBatch(NamedTuple):
+    """One step as a model's `trunk` reads it. The engine's flat
+    operands as it packs them: `flat_positions` and `flat_slots` [T],
+    `block_tables`, `context_lens` and `q_starts` a row,
+    `tile_rows` and `tile_offs` a query tile of `tq` rows. The map
+    between the widths (`packing`), and the step's tokens at the
+    compact width: `tokens`, `positions`, `slots` [T_c]. Only
+    `CausalLM` reads `tp` (parallel.serve_collective.ServeTP or None)
+    and `qpools` / `qscales`, the in-device int8 tier's pools and
+    scales a layer (empty lists when it is off)."""
+    flat_positions: jax.Array
+    flat_slots: jax.Array
+    block_tables: jax.Array
+    context_lens: jax.Array
+    q_starts: jax.Array
+    tile_rows: jax.Array
+    tile_offs: jax.Array
+    packing: StepRows
+    tokens: jax.Array
+    positions: jax.Array
+    slots: jax.Array
+    tq: int
+    tp: Any
+    qpools: List
+    qscales: List
+
+    def tile_meta(self, row_slots):
+        """(slot, real, fresh, last) a flat position for the recurrent
+        kernels (kernels/selective_scan.py `tile_meta`), `row_slots`
+        [R] a step row's state slot."""
+        return scan.tile_meta(row_slots, self.context_lens, self.q_starts,
+                              self.tile_rows, self.tile_offs, self.tq)
+
+
+def serve_step(model, cx: Context, tokens, positions, pools, qpools,
+               qscales, block_tables, context_lens, q_starts, tile_rows,
+               tile_offs, slots, last_idx, tp=None):
+    """ONE mixed prefill+decode step over the flat ragged packing (the
+    engine's `_step_fn`, kernels/paged_attention.py
+    `ragged_paged_attention` for the operands). Returns (logits
+    [*last_idx.shape, V], the pools updated) and, for a model that
+    counts its experts, the step's tokens per expert int32
+    [expert layers, E]. `last_idx` [B] or [B, S] gathers the rows the
+    engine samples by flat index."""
+    packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
+                        last_idx, tokens.shape[0])
+    positions = positions.astype(jnp.int32)
+    batch = StepBatch(
+        positions, slots, block_tables, context_lens, q_starts, tile_rows,
+        tile_offs, packing, *map(packing.compact, (tokens, positions, slots)),
+        tokens.shape[0] // tile_rows.shape[0], tp, qpools, qscales)
+    x, pools, counts = model.trunk(cx, batch, pools)
+    logits = model.logits(cx, jnp.take(x, packing.last.reshape(-1), axis=0))
+    logits = logits.reshape(packing.last.shape + (logits.shape[-1],))
+    if counts is None:
+        return logits, pools
+    return logits, pools, (jnp.stack(counts) if counts else
+                           jnp.zeros((0, model.num_experts), jnp.int32))
+
+
+class ServedModel(Module):
+    """What the engine reads off a model it serves, declared, with the
+    defaults of a model that has no such thing. A served model sets
+    `model_type` (its manifest's name), `cache_layout` (what each layer
+    keeps between steps, engine/paged_cache.py `CacheLayout`) and its
+    pool's row: `kv_row` = (kv heads, head width) of a [k | v] row, or
+    `latent_row` = (k_dim, v_dim) of a latent pool. `expert_layers` and
+    `num_experts` size the engine's tokens-per-expert count;
+    `snapshot_tokens` / `snapshot_slots` are what it asks of the cache
+    for prefix reuse over state (an engine's own arguments override
+    them); `sparse_counts(start, length)` is what a step row reads of
+    one sparse layer. It implements `trunk(cx, batch, pools)` and
+    `logits(cx, rows)` (`serve_step`)."""
+    model_type: str
+    cache_layout: list
+    kv_row = None
+    latent_row = None
+    expert_layers = 0
+    num_experts = 0
+    snapshot_tokens = 0
+    snapshot_slots = 0
+    sparse_counts = None
+
+    def serve_metadata(self) -> dict:
+        """The manifest's `serve` block (io/inference.py
+        `save_inference_model(..., serve_meta=...)`): what
+        `from_serve_metadata` rebuilds the model from."""
+        return {"model_type": self.model_type, "config": dict(self.config),
+                "max_len": self.max_len,
+                "dtype": jnp.dtype(self.dtype).name,
+                "param_dtype": self.param_dtype.name}
+
+    @classmethod
+    def from_serve_metadata(cls, meta: dict):
+        return cls(**meta["config"], dtype=jnp.dtype(meta["dtype"]),
+                   param_dtype=jnp.dtype(meta["param_dtype"]))
+
+    def trunk(self, cx: Context, batch: StepBatch, pools):
+        raise NotImplementedError
+
+    def logits(self, cx: Context, rows):
+        raise NotImplementedError

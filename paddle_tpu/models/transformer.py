@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.module import Context, Module, PARAMS
 from paddle_tpu.kernels import attention as attn_kernel
 from paddle_tpu.kernels import paged_attention as paged
-from paddle_tpu.models.step_rows import step_rows
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.nn import initializers as I
 from paddle_tpu.nn.layers import Dropout, Embedding, LayerNorm, Linear
 from paddle_tpu.ops import functional as F
@@ -186,26 +186,24 @@ class MultiHeadAttention(Module):
         out = self.out_proj(cx, out)
         return (out, cache) if cache is not None else (out, None)
 
-    def ragged_step_paged(self, cx: Context, x, kv_pool,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, packing, tp=None, qpool=None):
+    def ragged_step(self, cx: Context, x, kv_pool, batch, qpool=None):
         """Mixed prefill+decode step over the FLAT ragged packing
         (kernels/paged_attention.py ragged_paged_attention): x: [T_c, D]
-        — the step's tokens at the compact width (`packing`, a
-        `models.step_rows.StepRows`), no batch axis. The step's k/v is
-        scattered into the pool at `slots` [T_c] first (past the tokens
-        into scratch block 0; in place when the caller donates the
-        pool), then the queries are laid out in the flat packing's
-        tiles and one attention launch reads the pool as it lies and
-        serves every row. Returns (out [T_c, D], new_kv_pool). `tp`
-        (parallel.serve_collective.ServeTP or None) routes the attention
-        through an explicit shard_map island over the mesh's "tp" axis
-        — heads/kv-heads device-local, metadata replicated; the
-        projections around it stay GSPMD ops at global shapes.
-        `qpool` = (kvq, k_scales, v_scales) threads this layer's
-        int8 compressed tier into the launch: bias-encoded (negative)
-        block-table entries read it in place. Writes always target the
-        fp pool — slots never point at int8 blocks."""
+        — the step's tokens at the compact width (`batch`, a
+        `models.step_rows.StepBatch`), no batch axis. The step's k/v is
+        scattered into the pool at the batch's slots [T_c] first (past
+        the tokens into scratch block 0; in place when the caller
+        donates the pool), then the queries are laid out in the flat
+        packing's tiles and one attention launch reads the pool as it
+        lies and serves every row. Returns (out [T_c, D], new_kv_pool).
+        The batch's `tp` (parallel.serve_collective.ServeTP or None)
+        routes the attention through an explicit shard_map island over
+        the mesh's "tp" axis — heads/kv-heads device-local, metadata
+        replicated; the projections around it stay GSPMD ops at global
+        shapes. `qpool` = (kvq, k_scales, v_scales) threads this
+        layer's int8 compressed tier into the launch: bias-encoded
+        (negative) block-table entries read it in place. Writes always
+        target the fp pool — slots never point at int8 blocks."""
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
         t = x.shape[0]
         if self.fused_qkv:
@@ -219,13 +217,15 @@ class MultiHeadAttention(Module):
                                             self.head_dim)
             vh = self.v_proj(cx, x).reshape(t, self.num_kv_heads,
                                             self.head_dim)
-        kv_pool = paged.write_kv(kv_pool, slots, kh, vh)
+        kv_pool = paged.write_kv(kv_pool, batch.slots, kh, vh)
         kvq, ksc, vsc = qpool if qpool is not None else (None,) * 3
-        attend = (paged.ragged_paged_attention if tp is None else
+        attend = (paged.ragged_paged_attention if batch.tp is None else
                   functools.partial(paged.ragged_paged_attention_tp,
-                                    tp.mesh))
-        out = attend(packing.expand(qh), kv_pool, block_tables,
-                     context_lens, q_starts, tile_rows, tile_offs,
+                                    batch.tp.mesh))
+        packing = batch.packing
+        out = attend(packing.expand(qh), kv_pool, batch.block_tables,
+                     batch.context_lens, batch.q_starts, batch.tile_rows,
+                     batch.tile_offs,
                      groups=self.num_heads // self.num_kv_heads,
                      kvq_pool=kvq, k_scales=ksc,
                      v_scales=vsc)                         # [T, H, hd]
@@ -440,22 +440,18 @@ class CausalBlock(Module):
         x = x + self.drop(cx, self.ffn(cx, self.ln2(cx, x)))
         return x, nc
 
-    def ragged_step_paged(self, cx: Context, x, kv_pool,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, packing, tp=None, qpool=None):
+    def ragged_step(self, cx: Context, x, kv_pool, batch, qpool=None):
         cx = cx.scope(self._name or type(self).__name__)  # see attend()
-        h, pools = self.attn.ragged_step_paged(
-            cx, self.ln1(cx, x), kv_pool, block_tables,
-            context_lens, q_starts, tile_rows, tile_offs, slots, packing,
-            tp=tp, qpool=qpool)
+        h, pools = self.attn.ragged_step(cx, self.ln1(cx, x), kv_pool,
+                                         batch, qpool=qpool)
         x = x + self.drop(cx, h)
-        f = (self.ffn.forward_serve_tp(cx, self.ln2(cx, x), tp)
-             if tp is not None else self.ffn(cx, self.ln2(cx, x)))
+        f = (self.ffn.forward_serve_tp(cx, self.ln2(cx, x), batch.tp)
+             if batch.tp is not None else self.ffn(cx, self.ln2(cx, x)))
         x = x + self.drop(cx, f)
         return x, pools
 
 
-class CausalLM(Module):
+class CausalLM(ServedModel):
     """Decoder-only autoregressive LM (GPT-style).
 
     The reference's LM story tops out at RNN language models
@@ -469,7 +465,9 @@ class CausalLM(Module):
     16k+ token sequences.
 
     tie_embeddings=True (default) shares the token table with the
-    output head (Embedding.attend)."""
+    output head (Embedding.attend). Served with a paged pool in every
+    layer."""
+    model_type = "causal_lm"
 
     def __init__(self, vocab: int, model_dim: int = 512,
                  num_heads: int = 8, num_layers: int = 6,
@@ -492,6 +490,45 @@ class CausalLM(Module):
         if not tie_embeddings:
             self.head = Linear(vocab, dtype=dtype)
         self.drop = Dropout(dropout)
+        self.cache_layout = [{"kind": "paged"}] * num_layers
+        attn = self.blocks[0].attn
+        self.kv_row = (attn.num_kv_heads, attn.head_dim)
+
+    def serve_metadata(self) -> dict:
+        """The manifest's `serve` block, flat (exports of every age
+        carry this one): everything `from_serve_metadata` needs to
+        rebuild the module and the engine to size its KV pools without
+        touching the checkpoint."""
+        attn = self.blocks[0].attn
+        return {
+            "model_type": "causal_lm",
+            "vocab": self.vocab,
+            "model_dim": self.model_dim,
+            "num_heads": attn.num_heads,
+            "num_kv_heads": attn.num_kv_heads,
+            "head_dim": attn.head_dim,
+            "num_layers": len(self.blocks),
+            "ffn_dim": self.blocks[0].ffn.fc1.features,
+            "max_len": self.max_len,
+            "tie_embeddings": self.tie_embeddings,
+            "fused_qkv": attn.fused_qkv,
+            # compute dtype: the rebuilt model's activations AND the KV
+            # pool's element type (a bf16 export must not come back
+            # float32 with a pool twice the size)
+            "dtype": jnp.dtype(self.dtype).name,
+        }
+
+    @classmethod
+    def from_serve_metadata(cls, meta: dict):
+        return cls(vocab=meta["vocab"], model_dim=meta["model_dim"],
+                   num_heads=meta["num_heads"],
+                   num_layers=meta["num_layers"], ffn_dim=meta["ffn_dim"],
+                   dropout=0.0, max_len=meta["max_len"],
+                   tie_embeddings=meta["tie_embeddings"],
+                   fused_qkv=meta["fused_qkv"],
+                   num_kv_heads=meta["num_kv_heads"],
+                   # exports older than the field were all float32
+                   dtype=jnp.dtype(meta.get("dtype", "float32")))
 
     def _head(self, cx: Context, x):
         return (self.embed.attend(cx, x) if self.tie_embeddings
@@ -560,54 +597,28 @@ class CausalLM(Module):
             new_caches.append(nc)
         return self._head(cx, self.ln_f(cx, x[:, -1:]))[:, 0], new_caches
 
-    def ragged_step_paged(self, cx: Context, tokens, positions, pools,
-                          block_tables, context_lens, q_starts, tile_rows,
-                          tile_offs, slots, last_idx, tp=None,
-                          qpools=None, qscales=None):
-        """ONE mixed prefill+decode serve step over the flat ragged
-        packing — the engine's single compiled path. tokens [T] ids and
-        positions [T] int32 are the flat packing (decode rows are
-        1-token windows at position seq_len; chunk rows are
-        budget-bounded prompt windows; pad positions carry token 0 at
-        position 0 and scatter to scratch slot 0). Per-ROW metadata
-        block_tables [R, MB] / context_lens [R] / q_starts [R] and
-        per-TILE tile_rows/tile_offs [NT] follow the
-        ragged_paged_attention contract. last_idx int32 gathers hidden
-        states by flat index: [B] yields logits [B, V] (one gather per
-        row — the pre-speculation contract), [B, S] yields [B, S, V]
-        (S gathers per row, used by speculative verification to score
-        every draft position from the same launch; non-speculative rows
-        just repeat their single real index across the S columns). The
-        engine samples only the rows whose window ended a prompt or
-        decoded a token. With qpools/qscales (the engine's in-device
-        compressed tier; empty lists when compression is off) each
-        layer's int8 pool + per-block scales join its attention launch,
-        and bias-encoded block-table entries read them in place.
-
-        Everything but the attention launch runs on the step's tokens
-        alone, at the compact width (`models/step_rows.py`)."""
-        packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
-                            last_idx, tokens.shape[0])
-        tokens, positions, slots = map(packing.compact,
-                                       (tokens, positions, slots))
-        x = self.embed(cx, tokens) * math.sqrt(self.model_dim)   # [T_c, D]
+    def trunk(self, cx: Context, batch, pools):
+        """The serving step's layers (`models/step_rows.py` `serve_step`
+        has the contract): the step's tokens at the compact width, one
+        paged pool a layer. With the batch's int8 tier (`qpools` /
+        `qscales`, the engine's in-device compressed tier; empty lists
+        when compression is off) each layer's int8 pool and per-block
+        scales join its attention launch, and bias-encoded block-table
+        entries read them in place."""
+        x = self.embed(cx, batch.tokens) * math.sqrt(self.model_dim)
         pe = sinusoid_position_encoding(self.max_len, self.model_dim)
-        pos_safe = jnp.clip(positions.astype(jnp.int32), 0, self.max_len - 1)
-        x = x + pe[pos_safe].astype(x.dtype)
+        x = x + pe[jnp.clip(batch.positions, 0, self.max_len - 1)
+                   ].astype(x.dtype)                             # [T_c, D]
         new_pools = []
         for li, (blk, kv_pool) in enumerate(zip(self.blocks, pools)):
-            qpool = (qpools[li],) + tuple(qscales[li]) if qpools else None
-            x, np_ = blk.ragged_step_paged(cx, x, kv_pool,
-                                           block_tables, context_lens,
-                                           q_starts, tile_rows, tile_offs,
-                                           slots, packing, tp=tp,
-                                           qpool=qpool)
-            new_pools.append(np_)
-        hidden = self.ln_f(cx, x)                                # [T_c, D]
-        last_h = jnp.take(hidden, packing.last.reshape(-1), axis=0)
-        logits = self._head(cx, last_h)
-        return (logits.reshape(packing.last.shape + (logits.shape[-1],)),
-                new_pools)
+            qpool = ((batch.qpools[li],) + tuple(batch.qscales[li])
+                     if batch.qpools else None)
+            x, kv_pool = blk.ragged_step(cx, x, kv_pool, batch, qpool=qpool)
+            new_pools.append(kv_pool)
+        return x, new_pools, None
+
+    def logits(self, cx: Context, rows):
+        return self._head(cx, self.ln_f(cx, rows))
 
     def decode_step(self, cx: Context, token, pos, caches):
         """One step: token [B] ids at position `pos` -> (logits [B, V],

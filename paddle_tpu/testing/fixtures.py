@@ -52,7 +52,6 @@ def export_causal_lm(path: str, vocab: int = 61, model_dim: int = 16,
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.engine.engine import serve_metadata
     from paddle_tpu.io.inference import save_inference_model
     from paddle_tpu.models.transformer import CausalLM
 
@@ -64,5 +63,5 @@ def export_causal_lm(path: str, vocab: int = 61, model_dim: int = 16,
                            jnp.zeros((1, 4), jnp.int32))
     save_inference_model(  # export the forward; engine rebuilds from serve
         path, model, variables, [jnp.zeros((1, 4), jnp.int32)],
-        input_names=["tokens"], serve_meta=serve_metadata(model))
+        input_names=["tokens"], serve_meta=model.serve_metadata())
     return path, model, variables

@@ -219,7 +219,8 @@ def test_tp4_step_updates_its_pool_shards_in_place(topo, heads, blocks,
                      max_len=1024, dtype=jnp.bfloat16)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 4), jnp.int32))
-    step, _ = compile_steps(model, shapes, False, ServeTP(mesh, 4, mode=mode))
+    step, _ = compile_steps(model, shapes, False, ServeTP(mesh, 4, mode=mode),
+                            ["paged"] * 2)
     t = 512 + batch * 8
 
     def i32(*shape):
@@ -427,7 +428,8 @@ def test_latent_expert_step_at_its_cell_sizes(one_chip):
         jnp.bfloat16, sharding=one_chip)
     assert pool.shape[-1] == 640
     pools = [pool] * len(model.blocks)
-    step, _ = compile_steps(model, shapes, False)
+    step, _ = compile_steps(model, shapes, False, None,
+                            ["paged"] * len(pools))
     with mock.patch.object(paged_attention, "_device_platform",
                            lambda: "tpu"):
         compiled = step.lower(

@@ -30,9 +30,9 @@ from benchmarks import reference_lfm2 as reference
 from benchmarks import weights_lfm2 as weights
 from benchmarks.common import build_model
 from paddle_tpu.engine import engine as engine_mod
-from paddle_tpu.engine.engine import ServeEngine, serve_metadata
+from paddle_tpu.engine.engine import ServeEngine
 from paddle_tpu.models import conv_moe_lm
-from paddle_tpu.models.latent_moe import RoutedExperts
+from paddle_tpu.models.shared_layers import RoutedExperts
 from paddle_tpu.obs.metrics import MetricsRegistry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -304,22 +304,3 @@ def test_each_mechanism_moves_the_logits_past_the_tolerance(toy, ablation):
         reqs, rows = _serve(_engine(model, variables), prompt, [6])
     want = _reference_rows(cfg, prompt[0], ServeEngine._generated_of(reqs[0]))
     assert np.abs(rows[0] - want).max() > 50 * TOL
-
-
-def test_export_and_from_saved_model(toy, tmp_path):
-    from paddle_tpu.io.inference import save_inference_model
-    cfg, model, variables = toy
-    meta = serve_metadata(model)
-    assert meta["model_type"] == "conv_moe_lm"
-    path = str(tmp_path / "m")
-    save_inference_model(path, model, variables,
-                         [jnp.zeros((1, 4), jnp.int32)],
-                         input_names=["tokens"], serve_meta=meta)
-    kw = dict(max_batch_size=2, block_size=8, num_blocks=32, max_seq_len=64,
-              max_prefill_tokens=16)
-    eng = ServeEngine.from_saved_model(path, **kw)
-    assert eng.cache.kinds == ["state", "paged", "state", "state", "rows"]
-    prompts = _tokens(cfg, np.random.default_rng(9), 13, 6)
-    assert (eng.generate(prompts, max_new_tokens=4)
-            == _engine(model, variables, **kw).generate(prompts,
-                                                        max_new_tokens=4))

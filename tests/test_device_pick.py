@@ -24,6 +24,7 @@ import paddle_tpu.engine.engine as engine_mod
 from paddle_tpu.engine.engine import (ServeEngine, _pick, _sample,
                                       compile_steps)
 from paddle_tpu.engine.scheduler import Request
+from paddle_tpu.models.step_rows import ServedModel
 from paddle_tpu.models.transformer import CausalLM
 from paddle_tpu.obs.metrics import MetricsRegistry
 
@@ -85,12 +86,18 @@ def _downloads(eng) -> int:
 
 # -- the pick itself --------------------------------------------------------
 
-class _Planted:
-    """A model whose step returns the logits it was handed."""
+class _Planted(ServedModel):
+    """A model whose step returns the logits it was handed: its rows are
+    the sampled rows' flat indices, and its head reads the planted
+    logits at them."""
+    cache_layout = [{"kind": "paged"}]
 
-    def ragged_step_paged(self, cx, tokens, positions, pools, *operands,
-                          **kw):
-        return cx._core.variables["logits"], pools
+    def trunk(self, cx, batch, pools):
+        return batch.packing.last.reshape(-1), pools, None
+
+    def logits(self, cx, rows):
+        planted = cx._core.variables["logits"]
+        return planted.reshape(-1, planted.shape[-1])[rows]
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -109,11 +116,11 @@ def test_the_steps_pick_is_samples_on_every_row_ties_planted(dtype):
             later = rng.integers(first + 1, v, size=1 + (i + j) % 3)
             row[first] = row[later] = row.max() + (0.5 if i % 2 else 0.0)
     logits = jnp.asarray(logits, dtype)
-    step, _ = compile_steps(_Planted(), None, compress=False)
+    step, _ = compile_steps(_Planted(), None, False, None, ["paged"])
     zeros = np.zeros((4,), np.int32)
     (out, lse, ids, top), _ = step(
         {"logits": logits}, zeros, zeros, [jnp.zeros((2, 2))], [], [],
-        *[zeros] * 7)
+        *[zeros] * 6, np.arange(b * s, dtype=np.int32).reshape(b, s))
     out, lse, ids, top = jax.device_get((out, lse, ids, top))
     assert ids.dtype == np.int32 and ids.shape == (b, s)
     assert top.dtype == lse.dtype == np.float32

@@ -27,7 +27,7 @@ sys.path.insert(0, ROOT)
 from benchmarks import reference_glm, weights_glm  # noqa: E402
 from benchmarks.common import build_model  # noqa: E402
 from paddle_tpu.engine import engine as engine_mod  # noqa: E402
-from paddle_tpu.engine.engine import ServeEngine, serve_metadata  # noqa: E402
+from paddle_tpu.engine.engine import ServeEngine  # noqa: E402
 
 SEED = 5
 TOL = 5e-5
@@ -267,23 +267,3 @@ def test_host_tier_round_trip_of_a_latent_block(cfg, model, params):
         assert (np.asarray(eng.cache.pack_block(k, v))
                 == np.asarray(pool[block])).all()
         assert np.abs(k).max() > 0 and np.abs(v).max() > 0
-
-
-def test_export_and_from_saved_model(cfg, model, params, tmp_path):
-    from paddle_tpu.io.inference import save_inference_model
-    meta = serve_metadata(model)
-    assert meta["model_type"] == "latent_moe_lm"
-    path = str(tmp_path / "m")
-    save_inference_model(path, model, {"params": params},
-                         [jnp.zeros((1, 4), jnp.int32)],
-                         input_names=["tokens"], serve_meta=meta)
-    kw = dict(max_batch_size=2, block_size=4, num_blocks=32,
-              max_seq_len=64)
-    eng = ServeEngine.from_saved_model(path, **kw)
-    assert eng.cache.latent == (cfg["kv_lora_rank"]
-                                + cfg["qk_rope_head_dim"],
-                                cfg["kv_lora_rank"])
-    prompts = [[3, 1, 4, 1, 5], [9, 2, 6]]
-    assert (eng.generate(prompts, max_new_tokens=4)
-            == _engine(model, params, **kw).generate(prompts,
-                                                     max_new_tokens=4))

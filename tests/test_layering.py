@@ -75,6 +75,36 @@ def test_cache_manager_and_kernels_name_no_model(path):
     assert [name for name in MODEL_NAMES if name in text] == []
 
 
+SHARED = ("paddle_tpu.models.step_rows", "paddle_tpu.models.shared_layers")
+MODEL_FILES = sorted(
+    name for name in os.listdir(os.path.join(ROOT, "paddle_tpu", "models"))
+    if name.endswith(".py") and name != "__init__.py"
+    and f"paddle_tpu.models.{name[:-3]}" not in SHARED)
+
+
+@pytest.mark.parametrize("name", MODEL_FILES)
+def test_a_model_file_imports_no_other_model_file(name):
+    """What the model files share (the step contract, the served layers
+    more than one of them builds from) lives in `models/step_rows.py`
+    and `models/shared_layers.py`; a model file reaches no other."""
+    path = f"paddle_tpu/models/{name}"
+    reached = {mod for file, _, mod in _imports("paddle_tpu/models")
+               if file == path and mod.startswith("paddle_tpu.models")}
+    assert {mod for mod in reached
+            if not any(mod == s or mod.startswith(s + ".")
+                       for s in SHARED)} == set()
+
+
+def test_the_engine_reads_a_declared_model_and_probes_nothing():
+    """The engine reads what a served model declares
+    (`models/step_rows.py` `ServedModel`): it asks no model whether it
+    has an attribute, and reaches into none of its blocks."""
+    with open(os.path.join(ROOT, "paddle_tpu/engine/engine.py")) as f:
+        text = f.read()
+    assert [probe for probe in ("getattr(model", "hasattr(model",
+                                "model.blocks") if probe in text] == []
+
+
 def test_the_engine_names_models_only_to_rebuild_an_export():
     """`engine.py` reads the cache layout, the row and the expert
     layers off the model it is handed; the one place it names a model's

@@ -404,8 +404,7 @@ def test_a_layer_of_two_kinds_holds_blocks_and_a_slot():
     with pytest.raises(ValueError, match="spec_k"):
         refuse_slots(2, 0, 0, 1, False)
     with pytest.raises(ValueError, match="host_tier"):
-        PagedKVCache(1, 32, 8, 2, 4, layout=layout,
-                     host_tier=mock.Mock(byte_budget=1 << 20))
+        refuse_slots(0, 1 << 20, 0, 1, False)
     with pytest.raises(ValueError, match="keeps no state"):
         CacheLayout([{"kind": "window", "window": 8, "arrays": STATE}], 8,
                     2, 16)
