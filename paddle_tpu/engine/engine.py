@@ -155,7 +155,9 @@ def compile_steps(model, variables, compress: bool, serve_tp, kinds):
                  tile_offs, slots, last_idx):
         # ((logits, their log-sum-exp, the greedy pick, its logit),
         # pools); a model with expert layers adds the step's tokens per
-        # expert, int32 [expert layers, experts]. The three numbers a
+        # expert, int32 [expert layers, experts] (a share of an
+        # expert-parallel layer one column more: the pairs it sent
+        # away, `ServedModel.expert_shards`). The three numbers a
         # row are taken here, where the rows lie, so that a greedy
         # token costs the host one subtraction and the logits need not
         # leave the device (`_pick`): the first best id, as np.argmax,
@@ -634,6 +636,7 @@ class ServeEngine:
         from paddle_tpu.models.parallel_hybrid_lm import ParallelHybridLM
         from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
         from paddle_tpu.models.transformer import CausalLM
+        from paddle_tpu.models.window_moe_lm import WindowMoELM
 
         with open(os.path.join(model_dir, "signature.json")) as f:
             sig = json.load(f)
@@ -645,7 +648,7 @@ class ServeEngine:
                 "serve_meta=model.serve_metadata())")
         served = {c.model_type: c for c in (
             CausalLM, LatentMoELM, HybridLM, SparseLinearLM,
-            ParallelHybridLM, ConvMoELM)}
+            ParallelHybridLM, ConvMoELM, WindowMoELM)}
         model = served[meta.get("model_type", CausalLM.model_type)
                        ].from_serve_metadata(meta)
         variables = load_checkpoint(os.path.join(model_dir, "params"))
@@ -755,6 +758,12 @@ class ServeEngine:
             "ptpu_moe_active_experts_total",
             "(layer, expert) pairs that received at least one token in "
             "a step")
+        self._m_moe_pairs = m.counter(
+            "ptpu_moe_pairs_total",
+            "Real (row, choice) pairs over the expert layers, by where "
+            "their expert is held: here, or on another chip of an "
+            "expert-parallel deployment (its share of the work is not "
+            "this chip's)", labelnames=("where",))    # where=held|away
         self._m_compiles = m.gauge(
             "ptpu_engine_compiles",
             "jit cache size of the unified step (the one-compile "
@@ -1021,6 +1030,10 @@ class ServeEngine:
         if "moe_assignments" in asked:
             self._m_moe_assign.inc(asked["moe_assignments"])
             self._m_moe_active.inc(asked["moe_active_experts"])
+            self._m_moe_pairs.labels(where="held").inc(
+                asked["moe_assignments"])
+            self._m_moe_pairs.labels(where="away").inc(
+                asked["moe_assignments_away"])
         if "ssm_tokens" in asked:
             self._m_ssm_tokens.inc(asked["ssm_tokens"])
             for kind in ("full", "window"):
@@ -1589,11 +1602,15 @@ class ServeEngine:
             if logits is not None:
                 self._m_logit_downloads.inc()
             if per_expert:
-                per_expert = per_expert[0]
-                self.expert_tokens += per_expert
+                # the held experts' columns; a share's last column is the
+                # pairs it sent away
+                held = per_expert[0][:, :self.expert_tokens.shape[1]]
+                self.expert_tokens += held
                 asked.update(
-                    moe_assignments=int(per_expert.sum()),
-                    moe_active_experts=int((per_expert > 0).sum()))
+                    moe_assignments=int(held.sum()),
+                    moe_active_experts=int((held > 0).sum()),
+                    moe_assignments_away=int(
+                        per_expert[0][:, held.shape[1]:].sum()))
         with annotate("engine.sample", step=step) as span:
             # the picks reached the host: every first token and finish
             # of this step is stamped with the span's opening reading

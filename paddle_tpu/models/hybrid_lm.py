@@ -443,15 +443,7 @@ class HybridLM(ServedModel):
         ring)."""
         *arrays, rows = pools
         arrays = iter(arrays)
-        ring = rows[:, 1:]
         meta = batch.tile_meta(rows[:, 0])
-        packing, positions = batch.packing, batch.flat_positions
-        # the window pools' block table by logical block, and the flat
-        # pool row of each position (padding: scratch block 0)
-        mb = batch.block_tables.shape[1]
-        places = jnp.arange(mb, dtype=jnp.int32) % ring.shape[1]
-        window_table = ring[:, places]
-        row_of = jnp.repeat(batch.tile_rows, batch.tq)
         out_pools, memories, full = [], {}, {}
         x = self.embed(cx, batch.tokens)                         # [T_c, D]
         for i, blk in enumerate(self.blocks):
@@ -472,13 +464,8 @@ class HybridLM(ServedModel):
             else:
                 pool = next(arrays)
                 if blk.kind == "window":
-                    bs = pool.shape[1]
-                    block = jnp.take_along_axis(
-                        window_table[row_of], (positions // bs)[:, None],
-                        axis=1)[:, 0]
-                    table = window_table
-                    rows_at = packing.compact(jnp.where(
-                        packing.flat_real, block * bs + positions % bs, 0))
+                    table, rows_at = batch.ring_rows(rows[:, 1:],
+                                                     pool.shape[1])
                 else:
                     table, rows_at = batch.block_tables, batch.slots
                 mixed, pool = blk.mixer.ragged_step(m, y, pool, table,

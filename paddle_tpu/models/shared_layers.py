@@ -55,16 +55,20 @@ def rotate(x, positions, theta: float):
 class Attention(Module):
     """GQA with rotary over the whole head and a key multiplier; with
     `qk_norm_eps`, q and k each through an RMSNorm over the head (a
-    learned scale of head_dim) before the rotary. `kv_row` is what one
-    pool's row holds."""
+    learned scale of head_dim) before the rotary. `rotary=False` leaves
+    out the rotary; `window` lets a query see only the `window` newest
+    positions up to its own; `name` names the ragged kernel's call.
+    `kv_row` is what one pool's row holds."""
 
     def __init__(self, model_dim, num_heads, num_kv_heads, head_dim, theta,
-                 key_multiplier, dtype, param_dtype, qk_norm_eps=None):
+                 key_multiplier, dtype, param_dtype, qk_norm_eps=None,
+                 rotary: bool = True, window=None, name=None):
         super().__init__()
         self.model_dim, self.num_heads = model_dim, num_heads
         self.num_kv_heads, self.head_dim = num_kv_heads, head_dim
         self.groups = num_heads // num_kv_heads
         self.theta, self.key_multiplier = float(theta), float(key_multiplier)
+        self.rotary, self.window, self.kernel_name = rotary, window, name
         self.dtype, self.param_dtype = dtype, param_dtype
         self.scale = 1.0 / math.sqrt(head_dim)
         self.kv_row = (num_kv_heads, head_dim)
@@ -86,9 +90,11 @@ class Attention(Module):
         v = qkv[..., (h + kvh) * hd:].reshape(lead + (kvh, hd))
         if self.qk_norm:
             q, k = self.q_norm(cx, q), self.k_norm(cx, k)
-        q = rotate(q.astype(jnp.float32), positions, self.theta)
-        k = rotate(k.astype(jnp.float32) * self.key_multiplier, positions,
-                   self.theta)
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32) * \
+            self.key_multiplier
+        if self.rotary:
+            q = rotate(q, positions, self.theta)
+            k = rotate(k, positions, self.theta)
         return q.astype(self.dtype), k.astype(self.dtype), v
 
     def _finish(self, cx: Context, att):
@@ -105,22 +111,31 @@ class Attention(Module):
         s = jnp.einsum("bqkgd,bjkd->bkgqj", qg, k,
                        preferred_element_type=jnp.float32) * self.scale
         pos = jnp.arange(t)
-        a = jax.nn.softmax(jnp.where(pos[None, :] <= pos[:, None], s,
-                                     -jnp.inf), axis=-1)
+        seen = pos[None, :] <= pos[:, None]
+        if self.window is not None:
+            seen = seen & (pos[None, :] > pos[:, None] - self.window)
+        a = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
         att = jnp.einsum("bkgqj,bjkd->bqkgd", a.astype(v.dtype), v)
         return self._finish(cx, att.reshape(b, t, self.num_heads,
                                             self.head_dim))
 
-    def ragged_step(self, cx: Context, y, pool, batch):
+    def ragged_step(self, cx: Context, y, pool, batch, table=None,
+                    rows_at=None):
         """y [T_c, d], the step's tokens (`batch`, a
         `models.step_rows.StepBatch`); the kernel runs over the flat
-        packing. Returns (output, pool)."""
+        packing. `table` and `rows_at` are the pool's block tables and
+        the pool rows the tokens are written to (a window ring's,
+        `StepBatch.ring_rows`), the step's paged ones by default.
+        Returns (output, pool)."""
+        if table is None:
+            table, rows_at = batch.block_tables, batch.slots
         q, k, v = self._project(cx, y, batch.positions)
-        pool = paged.write_kv(pool, batch.slots, k, v)
+        pool = paged.write_kv(pool, rows_at, k, v)
         att = paged.ragged_paged_attention(
-            batch.packing.expand(q), pool, batch.block_tables,
-            batch.context_lens, batch.q_starts, batch.tile_rows,
-            batch.tile_offs, scale=self.scale, groups=self.groups)
+            batch.packing.expand(q), pool, table, batch.context_lens,
+            batch.q_starts, batch.tile_rows, batch.tile_offs,
+            scale=self.scale, groups=self.groups, window=self.window,
+            name=self.kernel_name)
         return self._finish(cx, batch.packing.compact(att)), pool
 
 
@@ -173,15 +188,28 @@ class RoutedExperts(Module):
     gate and up, one for the down, each reading a touched expert's
     weights once), the results are un-sorted, weighted and summed, and
     the shared expert is added. No capacity, so no token is dropped
-    whatever the imbalance."""
+    whatever the imbalance.
+
+    A SHARE of an expert-parallel layer: with `expert_shards` S > 1 the
+    router scores S x `num_experts` experts and this layer holds
+    `num_experts` of them, those of `rank` r, [r E, (r + 1) E); a
+    token's weights are normalised over all its top_k, held or not. A
+    pair whose expert is held elsewhere takes the padding id, sorts
+    behind every held expert, is visited by no row tile and adds
+    nothing: the layer's output is this share's part of the sum (and the
+    shared expert, which every share computes alike). Nothing stands in
+    for the other shares or the exchange with them."""
 
     def __init__(self, model_dim: int, expert_dim: int, num_experts: int,
                  top_k: int, num_shared: int = 1, scaling: float = 1.0,
                  dtype=jnp.float32, param_dtype=jnp.float32,
-                 eps: float = 1e-20):
+                 eps: float = 1e-20, expert_shards: int = 1, rank: int = 0):
         super().__init__()
+        if not 0 <= rank < expert_shards:
+            raise ValueError(f"rank {rank} of {expert_shards} expert shards")
         self.model_dim, self.expert_dim = model_dim, expert_dim
         self.num_experts, self.top_k = num_experts, top_k
+        self.expert_shards, self.rank = expert_shards, rank
         self.scaling, self.eps = scaling, eps
         self.dtype, self.param_dtype = dtype, param_dtype
         self.num_shared = num_shared
@@ -192,9 +220,10 @@ class RoutedExperts(Module):
     def _route(self, cx: Context, x):
         """x [T, d] -> (chosen [T, k] int32, weights [T, k] float32)."""
         c = cx.scope("router")
-        w = c.param("weight", (self.model_dim, self.num_experts),
-                    I.glorot_uniform, self.param_dtype)
-        b = c.param("bias", (self.num_experts,), I.normal(0.0, 0.02),
+        routed = self.num_experts * self.expert_shards
+        w = c.param("weight", (self.model_dim, routed), I.glorot_uniform,
+                    self.param_dtype)
+        b = c.param("bias", (routed,), I.normal(0.0, 0.02),
                     self.param_dtype)
         scores = jax.nn.sigmoid(jnp.matmul(
             x.astype(jnp.float32), w.astype(jnp.float32),
@@ -206,11 +235,13 @@ class RoutedExperts(Module):
         return chosen.astype(jnp.int32), weights
 
     def forward(self, cx: Context, x, real=None):
-        """x [T, d] -> (y [T, d], tokens per expert [E] int32, the
-        router's choices [T, k]). `real` [T] bool marks the rows that
-        are tokens; the others are routed to no expert, counted nowhere,
-        and come out as the shared expert's output alone, or zeros
-        (nobody reads them)."""
+        """x [T, d] -> (y [T, d], tokens per held expert [E] int32, the
+        router's choices [T, k] over all the experts). `real` [T] bool
+        marks the rows that are tokens; the others are routed to no
+        expert, counted nowhere, and come out as the shared expert's
+        output alone, or zeros (nobody reads them). A share
+        (`expert_shards` > 1) counts one entry more, [E + 1]: the real
+        pairs sent to experts held elsewhere."""
         t, d = x.shape
         e, k, f = self.num_experts, self.top_k, self.expert_dim
         routed, weights = self._route(cx, x)
@@ -219,9 +250,16 @@ class RoutedExperts(Module):
         up = c.param("up", (e, d, f), I.glorot_uniform, self.param_dtype)
         down = c.param("down", (e, f, d), I.glorot_uniform, self.param_dtype)
         with jax.named_scope("moe_experts"):
+            ids, away = routed, None
+            if self.expert_shards > 1:
+                ids = routed - self.rank * e
+                held = (ids >= 0) & (ids < e)
+                away = jnp.sum(~held if real is None else
+                               ~held & real[:, None], dtype=jnp.int32)
+                ids = jnp.where(held, ids, e)
             # padding takes expert id E, which sorts behind every expert
-            flat = (routed if real is None else
-                    jnp.where(real[:, None], routed, e)).reshape(-1)  # [T*k]
+            flat = (ids if real is None else
+                    jnp.where(real[:, None], ids, e)).reshape(-1)  # [T*k]
             order = jnp.argsort(flat, stable=True)
             counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
             xs = jnp.take(x.astype(self.dtype), order // k, axis=0)
@@ -236,4 +274,6 @@ class RoutedExperts(Module):
         y = y.astype(self.dtype)
         if self.num_shared:
             y = y + self.shared(cx, x)
+        if away is not None:
+            counts = jnp.concatenate([counts, away[None]])
         return y, counts, routed

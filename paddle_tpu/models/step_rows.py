@@ -133,6 +133,24 @@ class StepBatch(NamedTuple):
     qpools: List
     qscales: List
 
+    def ring_rows(self, ring, block_size: int):
+        """(table, rows_at) of a window pool: the block table of a
+        step row's ring by logical block (logical block b lives in ring
+        place b mod ring; `ring` [R, places] the pool blocks of a step
+        row's ring, the ROWS table's), and the pool row each of the
+        step's tokens is written to [T_c] (padding: scratch block 0)."""
+        mb = self.block_tables.shape[1]
+        places = jnp.arange(mb, dtype=jnp.int32) % ring.shape[1]
+        table = ring[:, places]
+        row_of = jnp.repeat(self.tile_rows, self.tq)
+        positions = self.flat_positions
+        block = jnp.take_along_axis(
+            table[row_of], (positions // block_size)[:, None], axis=1)[:, 0]
+        rows_at = self.packing.compact(jnp.where(
+            self.packing.flat_real,
+            block * block_size + positions % block_size, 0))
+        return table, rows_at
+
     def tile_meta(self, row_slots):
         """(slot, real, fresh, last) a flat position for the recurrent
         kernels (kernels/selective_scan.py `tile_meta`), `row_slots`
@@ -150,7 +168,9 @@ def serve_step(model, cx: Context, tokens, positions, pools, qpools,
     [*last_idx.shape, V], the pools updated) and, for a model that
     counts its experts, the step's tokens per expert int32
     [expert layers, E]. `last_idx` [B] or [B, S] gathers the rows the
-    engine samples by flat index."""
+    engine samples by flat index; a share of an expert-parallel layer
+    counts [expert layers, E + 1], its last column the pairs sent
+    away."""
     packing = step_rows(tile_rows, tile_offs, q_starts, context_lens,
                         last_idx, tokens.shape[0])
     positions = positions.astype(jnp.int32)
@@ -174,7 +194,11 @@ class ServedModel(Module):
     keeps between steps, engine/paged_cache.py `CacheLayout`) and its
     pool's row: `kv_row` = (kv heads, head width) of a [k | v] row, or
     `latent_row` = (k_dim, v_dim) of a latent pool. `expert_layers` and
-    `num_experts` size the engine's tokens-per-expert count;
+    `num_experts` (the experts a layer holds) size the engine's
+    tokens-per-expert count; a model whose expert layers are one chip's
+    share of an expert-parallel deployment says over how many chips
+    (`expert_shards` > 1), and its count has one column more, the pairs
+    sent to experts held elsewhere;
     `snapshot_tokens` / `snapshot_slots` are what it asks of the cache
     for prefix reuse over state (an engine's own arguments override
     them); `sparse_counts(start, length)` is what a step row reads of
@@ -186,6 +210,7 @@ class ServedModel(Module):
     latent_row = None
     expert_layers = 0
     num_experts = 0
+    expert_shards = 1
     snapshot_tokens = 0
     snapshot_slots = 0
     sparse_counts = None

@@ -863,3 +863,83 @@ def test_conv_moe_step_at_its_cell_sizes(one_chip):
     print(f"lfm2-reason step: {total} bytes compiled, "
           f"{mem.temp_size_in_bytes} of them temporaries")
     assert 0.25 * 16e9 < total < 15.5e9, total
+
+
+def test_window_moe_step_at_its_cell_sizes(one_chip):
+    """The engine's step over window layers that keep a ring a slot and
+    a full layer that keeps a paged pool, with one chip's share of an
+    expert-parallel expert layer in four of its five layers, compiled
+    for the described chip at `kexaone-reason`'s own sizes (64 query
+    heads over 8 of 128, 16 held experts of 2,048 behind a router over
+    128, the 19,200-id slice, 1,024 blocks of 128, rings of 4 blocks in
+    33 slots; shapes only): both named ragged calls and the expert
+    scope are in it, every pool and ring is aliased to the step's
+    output, no copy of a pool's or a ring's size is in the program, the
+    tokens per held expert come out [4, 16 + 1] (the last column the
+    pairs sent away), and everything the step holds fits the chip."""
+    import json
+    from unittest import mock
+
+    from paddle_tpu.engine.engine import compile_steps
+    from paddle_tpu.engine.paged_cache import CacheLayout
+    from paddle_tpu.kernels import paged_attention
+    from paddle_tpu.models.window_moe_lm import WindowMoELM
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        cfg = json.load(f)
+    model = WindowMoELM(
+        dtype=jnp.bfloat16,
+        **{k: cfg[v] for k, v in cfg["constructor_args"].items()})
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    s = cfg["serve"]
+    tq, b = s["tile_q"], s["max_batch_size"]
+    t = -(-s["max_prefill_tokens"] // tq) * tq + b * tq
+    mb = -(-s["max_seq_len"] // s["block_size"])
+    layout = CacheLayout(model.cache_layout, s["block_size"], b,
+                         s["max_prefill_tokens"])
+    assert layout.ring_blocks == 4
+    heads, head_dim = model.kv_row
+    arrays = layout.arrays(
+        (s["num_blocks"], s["block_size"],
+         heads * paged_attention.head_lanes(head_dim)), jnp.bfloat16)
+    kinds = [kind for kind, _, _ in arrays]
+    assert kinds == ["window"] * 3 + ["paged", "window", "rows"]
+    pools = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for _, shape, dtype in arrays]
+    assert [p.shape for p in pools[2:4]] == [(1 + 32 * 4, 128, 2048),
+                                             (1024, 128, 2048)]
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    step, _ = compile_steps(model, shapes, False, None, kinds)
+    with mock.patch.object(paged_attention, "_device_platform",
+                           lambda: "tpu"):
+        compiled = step.lower(
+            jax.tree.map(on_chip, shapes), i32(t), i32(t), pools, [], [],
+            i32(b + 1, mb), i32(b + 1), i32(b + 1), i32(t // tq),
+            i32(t // tq), i32(t), i32(b, 1)).compile()
+    text = compiled.as_text()
+    for name in ("tpu_custom_call", "ragged_gqa_window", "ragged_gqa_full",
+                 "moe_experts"):
+        assert name in text, name
+    _experts_are_the_kernel(text, compiled, cfg)
+    for size in sorted({p.size for p in pools[:-1]}):
+        assert _pool_sized_copies(text, jax.ShapeDtypeStruct(
+            (size,), jnp.int8)) == [], size
+    _holds_the_picks(compiled, b, cfg["vocab_size"], jnp.float32)
+    *_, per_expert = compiled.out_info
+    assert (per_expert.shape, per_expert.dtype) == ((4, 17), jnp.int32)
+    mem = compiled.memory_analysis()
+    held = sum(p.size * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= held > 0.8e9
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"kexaone-reason step: {total} bytes compiled, "
+          f"{mem.temp_size_in_bytes} of them temporaries")
+    assert 0.25 * 16e9 < total < 15.5e9, total

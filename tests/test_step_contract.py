@@ -46,7 +46,8 @@ def test_the_model_declares_the_step_contract(name):
     assert (model.kv_row is None) != (model.latent_row is None)
     routed = sum(getattr(b, "routed", False) for b in model.blocks)
     assert model.expert_layers == routed
-    assert (model.num_experts > 0) == (name in ("latent_moe", "conv_moe"))
+    assert (model.num_experts > 0) == (name in ("latent_moe", "conv_moe",
+                                                "window_moe"))
     for method in ("trunk", "logits"):
         assert getattr(type(model), method) is not getattr(ServedModel,
                                                            method)
@@ -97,6 +98,9 @@ def test_the_benchmark_lowers_the_engine_step(name):
     assert logits.shape == (b, s, VOCAB)
     assert lse.shape == ids.shape == top.shape == (b, s)
     assert [p.shape for p in pools] == [p.shape for p in eng.cache.pools]
+    # a share of an expert-parallel layer counts the pairs it sent away
+    # in one column more
     assert [r.shape for r in rest] == (
-        [(model.expert_layers, model.num_experts)] if model.num_experts
-        else [])
+        [(model.expert_layers,
+          model.num_experts + (model.expert_shards > 1))]
+        if model.num_experts else [])

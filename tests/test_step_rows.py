@@ -80,6 +80,7 @@ def _models():
     from paddle_tpu.models.parallel_hybrid_lm import ParallelHybridLM
     from paddle_tpu.models.sparse_linear_lm import SparseLinearLM
     from paddle_tpu.models.transformer import CausalLM
+    from paddle_tpu.models.window_moe_lm import WindowMoELM
     sel = dict(dense_len=16, kernel=4, stride=2, block=4, init_blocks=1,
                local=8, topk=1)
     return {
@@ -110,6 +111,13 @@ def _models():
             ffn_dim=32, expert_dim=8, num_experts=8, top_k=2,
             layer_types=["conv", "full_attention", "conv"],
             num_dense_layers=1, max_len=64),
+        "window_moe": lambda: WindowMoELM(
+            vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2,
+            head_dim=8, ffn_dim=32, expert_dim=8, num_experts=4, top_k=2,
+            layer_types=["sliding_attention", "full_attention",
+                         "sliding_attention"],
+            mlp_layer_types=["dense", "sparse", "sparse"], window=8,
+            expert_shards=2, expert_rank=1, max_len=64),
     }
 
 
@@ -162,7 +170,8 @@ def _real(step, t):
 @pytest.mark.parametrize("name,spec", [
     ("causal_lm", False), ("causal_lm", True), ("latent_moe", False),
     ("latent_moe", True), ("hybrid", False), ("sparse_linear", False),
-    ("parallel_hybrid", False), ("conv_moe", False)])
+    ("parallel_hybrid", False), ("conv_moe", False),
+    ("window_moe", False)])
 def test_a_full_step_is_the_model_s_forward(name, spec):
     """Three rows decode while a fourth's prompt fills the whole chunk
     budget: 8 + 3 of T_c's 12 rows (8 + 3 x 3 of 20 with every decode

@@ -758,6 +758,53 @@ def test_expert_counts_over_a_slotted_layout_agree_with_the_counters():
     assert eng._step_fn._cache_size() == 1
 
 
+def test_a_share_of_an_expert_layer_counts_the_pairs_it_sends_away():
+    """A model whose expert layers hold 4 of 16 experts (rank 2 of 4) in
+    window and full attention layers: `moe_assignments` and
+    `moe_active_experts` count the held experts alone, and
+    `moe_assignments_away` the pairs routed to experts held elsewhere;
+    held and away add up to every pair, and each sums to its counter
+    (`ptpu_moe_pairs_total{where}`), beside the window layout's rows
+    and keys of each kind."""
+    from paddle_tpu.models.window_moe_lm import WindowMoELM
+    model = WindowMoELM(
+        vocab=VOCAB, model_dim=16, num_heads=4, num_kv_heads=2, head_dim=8,
+        ffn_dim=32, expert_dim=8, num_experts=4, top_k=3,
+        layer_types=["sliding_attention", "full_attention",
+                     "sliding_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse"], window=4,
+        expert_shards=4, expert_rank=2, max_len=64)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = _engine(model, variables, max_prefill_tokens=8)
+    assert eng.cache.kinds == ["window", "paged", "window", "rows"]
+    prof.reset_profiler()
+    eng.generate(PROMPTS, max_new_tokens=6)
+    steps = _spans(prof.get_events(), "engine.step")
+
+    def total(field):
+        return sum(st["args"][field] for st in steps)
+    computed = sum(len(p) + 5 for p in PROMPTS)
+    pairs = eng.obs.get("ptpu_moe_pairs_total")
+    # two expert layers, three choices a token, a quarter held here
+    assert total("moe_assignments") + total("moe_assignments_away") \
+        == computed * 2 * 3
+    assert total("moe_assignments") == pairs.labels(where="held").value \
+        == eng.obs.get("ptpu_moe_assignments_total").value \
+        == eng.expert_tokens.sum() > 0
+    assert total("moe_assignments_away") == pairs.labels(
+        where="away").value > total("moe_assignments")
+    assert total("moe_active_experts") == eng.obs.get(
+        "ptpu_moe_active_experts_total").value
+    assert eng.expert_tokens.shape == (2, 4)
+    assert all(st["args"]["moe_active_experts"] <= 2 * 4 for st in steps)
+    for kind in ("full", "window"):
+        assert total("kv_rows_" + kind) == eng.obs.get(
+            "ptpu_attn_kv_rows_total").labels(kind=kind).value
+    assert total("attn_keys_window") == sum(
+        min(p + 1, 4) for prompt in PROMPTS for p in range(len(prompt) + 5))
+    assert eng._step_fn._cache_size() == 1
+
 @pytest.mark.parametrize("layout", ["paged", "slots"])
 def test_product_rows_agree_with_the_counters(model_and_vars, layout):
     """`product_rows` is the compact width every product of the step ran
