@@ -21,11 +21,13 @@ What the loop buys over ThreadingHTTPServer:
 - DISCONNECTS come from the transport: a parked `reader.read()`
   coroutine resolves the moment the peer closes, replacing the old
   per-stream `select` + `MSG_PEEK` poll.
-- BACKPRESSURE is per-connection: every write awaits
+- BACKPRESSURE is per-connection: a write that finds the transport
+  paused (its buffer above the high-water mark) awaits
   `writer.drain()` under a deadline (`write_deadline_s`); a client
   that stops reading trips `SlowClientError`, the transport is
   aborted, and the caller evicts the stream (frees its KV) instead of
-  wedging a handler thread on a full socket buffer.
+  wedging a handler thread on a full socket buffer. Any other write
+  returns at once, with no timer armed.
 - TLS is one `ssl.SSLContext` on the listening transport
   (`make_server_tls_context`), no extra moving parts.
 
@@ -77,7 +79,9 @@ class AioRequest:
 
 class AioConnection:
     """The write half handed to handlers: deadline-bounded writes,
-    response helpers, and the transport-level disconnect watch."""
+    response helpers, and the transport-level disconnect watch.
+    `on_drain_wait`, if a handler sets it, is called by every write
+    that found the transport paused, before it waits for the drain."""
 
     def __init__(self, reader: asyncio.StreamReader,
                  writer: asyncio.StreamWriter,
@@ -85,14 +89,26 @@ class AioConnection:
         self.reader = reader
         self.writer = writer
         self.write_deadline_s = write_deadline_s
+        self.on_drain_wait: Optional[Callable[[], None]] = None
         self._watch_task: Optional[asyncio.Task] = None
 
     async def write(self, data: bytes) -> None:
-        """Write + drain under the slow-client deadline. On deadline
-        the transport is ABORTED (RST, not a lingering FIN) before
-        SlowClientError raises, so the stalled peer can never pin
-        kernel buffers for a closed stream."""
+        """Write, and drain only where `drain()` could wait or fail:
+        a transport whose buffer is above its high-water mark (paused)
+        drains under the slow-client deadline, and so does a closing
+        one, to raise its loss. Any other write returns without
+        yielding or arming a timer. On deadline the transport is
+        ABORTED (RST, not a lingering FIN) before SlowClientError
+        raises, so the stalled peer can never pin kernel buffers for a
+        closed stream."""
         self.writer.write(data)
+        transport = self.writer.transport
+        if not transport.is_closing():
+            if (transport.get_write_buffer_size()
+                    <= transport.get_write_buffer_limits()[1]):
+                return
+            if self.on_drain_wait is not None:
+                self.on_drain_wait()
         try:
             await asyncio.wait_for(self.writer.drain(),
                                    self.write_deadline_s)

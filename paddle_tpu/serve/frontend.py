@@ -127,6 +127,9 @@ _DIR_INTERVAL_S = 0.25   # default /kvprefixes + /debug refresh cadence
 # the longest the engine loop waits for a hand-over's frames to be
 # written (`ServeFrontend._hand_over`)
 _DELIVER_WAIT_S = 0.05
+# a token frame: the bytes of `sse_event({"token": t, "index": c,
+# "pos": p})` for ints t, c, p, without a dict and a json.dumps a frame
+_TOKEN_FRAME = b'data: {"token": %d, "index": %d, "pos": %d}\n\n'
 
 
 def _set_events(events, note=None) -> None:
@@ -134,8 +137,10 @@ def _set_events(events, note=None) -> None:
     `events`. With the `note` of an engine-loop hand-over, also open
     its `frontdoor.deliver`: `_delivered` is queued behind the woken
     consumers, each of which writes every frame it was handed in its
-    one turn (a write yields only on a transport that is paused)."""
-    opened = note and (now_us(), time.thread_time_ns(), note[0].written)
+    one turn, in one write that yields only on a transport that is
+    paused."""
+    opened = note and (now_us(), time.thread_time_ns(), note[0].written,
+                       note[0].writes)
     for ev in events:
         ev.set()
     if opened:
@@ -143,7 +148,7 @@ def _set_events(events, note=None) -> None:
             _delivered, note, *opened, len(events))
 
 
-def _delivered(note, ts: float, cpu_ns: int, written: int,
+def _delivered(note, ts: float, cpu_ns: int, written: int, writes: int,
                streams: int) -> None:
     """Close a hand-over's `frontdoor.deliver` (OBSERVABILITY.md "Host
     spans"): from `_set_events` to here on the event loop's thread,
@@ -152,7 +157,7 @@ def _delivered(note, ts: float, cpu_ns: int, written: int,
     record("frontdoor.deliver", ts, now_us() - ts,
            (time.thread_time_ns() - cpu_ns) / 1e3, step=step,
            streams=streams, frames=handover.written - written,
-           wake_us=ts - flushed_us)
+           writes=handover.writes - writes, wake_us=ts - flushed_us)
     handover.delivered.set()
 
 
@@ -176,17 +181,19 @@ class _HandOver:
     frames are all enqueued), again for what an iteration pushed
     outside a step, and on every exit, so nothing stays parked.
     `tid` is the engine loop's thread; `pending` belongs to it;
-    `written`, the frames the handlers have written to their sockets,
-    belongs to the event loop's thread. `delivered` is clear while a
-    hand-over is out: from its `flush()` until the woken consumers
-    have written its frames (`_delivered`)."""
+    `written` and `writes`, the frames the handlers have written to
+    their sockets and the writes that carried them, belong to the event
+    loop's thread. `delivered` is clear while a hand-over is out: from
+    its `flush()` until the woken consumers have written its frames
+    (`_delivered`)."""
 
-    __slots__ = ("tid", "pending", "written", "delivered")
+    __slots__ = ("tid", "pending", "written", "writes", "delivered")
 
     def __init__(self):
         self.tid: Optional[int] = None
         self.pending: set = set()
         self.written = 0
+        self.writes = 0
         self.delivered = threading.Event()
         self.delivered.set()
 
@@ -224,10 +231,11 @@ class _Stream:
     once; the wake-up of a push from the engine loop's thread is the
     loop's one hand-over an iteration (`_HandOver`), that of a push
     from any other thread is made on the spot. `gone` is flipped
-    in-loop by the transport disconnect watcher."""
+    in-loop by the transport disconnect watcher, `expired` by the
+    response's one deadline timer (`expire`)."""
 
     __slots__ = ("params", "arrival_us", "q", "req", "streamed",
-                 "cand_pos", "loop", "ev", "handover", "gone")
+                 "cand_pos", "loop", "ev", "handover", "gone", "expired")
 
     def __init__(self, params: dict, arrival_us: Optional[float] = None):
         self.params = params
@@ -242,6 +250,7 @@ class _Stream:
         self.ev: Optional[asyncio.Event] = None
         self.handover: Optional[_HandOver] = None
         self.gone = False
+        self.expired = False
 
     def attach(self, loop: asyncio.AbstractEventLoop, ev: asyncio.Event,
                handover: _HandOver) -> None:
@@ -264,6 +273,12 @@ class _Stream:
             handover.pending.add(self)
         else:
             _wake(loop, (ev,))
+
+    def expire(self) -> None:
+        """The deadline timer's callback, on the event loop's thread:
+        the consumer wakes to find the stream expired."""
+        self.expired = True
+        self.ev.set()
 
 
 class ServeFrontend:
@@ -436,7 +451,12 @@ class ServeFrontend:
             "stream (generated tokens over this: tokens a wake-up)")
         self._m_token_write = m.histogram(
             "ptpu_serve_token_write_seconds",
-            "Per-token SSE write+drain latency")
+            "One write of a consumer's turn of SSE token frames "
+            "(and the drain, where the transport was paused)")
+        self._m_drain_waits = m.counter(
+            "ptpu_frontdoor_drain_waits_total",
+            "Front-door writes that found the transport paused (its "
+            "buffer above the high-water mark) and awaited the drain")
 
     # -- readiness --------------------------------------------------------
     def readiness(self):
@@ -984,6 +1004,7 @@ class ServeFrontend:
     # -- HTTP handlers (coroutines on the serve/aio.py loop) --------------
     async def _a_dispatch(self, req: AioRequest,
                           conn: AioConnection) -> None:
+        conn.on_drain_wait = self._m_drain_waits.inc
         if self.auth_token and req.path.split("?")[0] != "/healthz":
             # /healthz stays credential-free: a liveness probe must
             # never fail for a config (secret-rotation) reason
@@ -1169,36 +1190,41 @@ class ServeFrontend:
                 self._open_streams -= 1
 
     def _stream_timeout(self, params: dict) -> float:
-        """Worst-case seconds to wait for the next queue item before
-        declaring the engine wedged."""
+        """Worst-case seconds a response waits on the engine before
+        declaring it wedged."""
         if params["deadline_ms"] is not None:
             return max(params["deadline_ms"] / 1e3 * 4, 30.0)
         return 300.0
 
-    @staticmethod
-    async def _a_next_item(stream: _Stream,
-                           deadline: float) -> Optional[tuple]:
-        """Next queue item, or None at the absolute loop-time
-        deadline, or ("gone",) when the disconnect watcher fired. The
-        clear-check-wait order makes the wake-up race-free: a push
-        landing between the empty get and the wait re-sets the event
-        AFTER the clear, so the wait returns immediately."""
+    def _arm_deadline(self, stream: _Stream) -> asyncio.TimerHandle:
+        """A response's one timer: `_stream_timeout` from now the stream
+        expires (`_Stream.expire`). The response cancels it when it
+        ends."""
         loop = asyncio.get_running_loop()
+        return loop.call_at(
+            loop.time() + self._stream_timeout(stream.params), stream.expire)
+
+    @staticmethod
+    async def _a_next_items(stream: _Stream) -> Optional[List[tuple]]:
+        """Every item queued for the stream, in order, once there is
+        one; None once its deadline passed with nothing queued, or
+        [("gone",)] when the disconnect watcher fired. The
+        clear-check-wait order makes the wake-up race-free: a push
+        landing between the look at the queue and the wait re-sets the
+        event AFTER the clear, so the wait returns immediately. The
+        handler is the queue's one consumer: what `qsize` counts is
+        there to take."""
         while True:
             stream.ev.clear()
-            try:
-                return stream.q.get_nowait()
-            except queue.Empty:
-                pass
+            n = stream.q.qsize()
+            if n:
+                get = stream.q.get_nowait
+                return [get() for _ in range(n)]
             if stream.gone:
-                return ("gone",)
-            remaining = deadline - loop.time()
-            if remaining <= 0:
+                return [("gone",)]
+            if stream.expired:
                 return None
-            try:
-                await asyncio.wait_for(stream.ev.wait(), remaining)
-            except asyncio.TimeoutError:
-                pass
+            await stream.ev.wait()
 
     async def _a_stream_response(self, conn: AioConnection,
                                  stream: _Stream) -> None:
@@ -1210,51 +1236,16 @@ class ServeFrontend:
             stream.gone = True
             stream.ev.set()
         conn.watch_disconnect(_gone)
-        deadline = (asyncio.get_running_loop().time()
-                    + self._stream_timeout(stream.params))
+        deadline = self._arm_deadline(stream)
         try:
             await conn.start_sse()
             while True:
-                item = await self._a_next_item(stream, deadline)
-                if item is None or item[0] == "gone":
+                items = await self._a_next_items(stream)
+                if items is None or items[0][0] == "gone":
                     # engine wedged past the deadline, or client left
                     self._request_cancel(stream)
                     return
-                if item[0] == "token":
-                    _, tok, cand = item
-                    pos = stream.cand_pos.get(cand, 0)
-                    # `index` tags the CANDIDATE (parallel sampling);
-                    # `pos` is the token's position within that
-                    # candidate's stream
-                    t0 = now_us()
-                    await conn.write(sse_event(
-                        {"token": tok, "index": cand, "pos": pos}))
-                    t1 = now_us()
-                    self._m_token_write.observe((t1 - t0) / 1e6)
-                    if not stream.streamed:
-                        self.engine.tracer.on_first_write(
-                            stream.req.req_id, t1)
-                    stream.cand_pos[cand] = pos + 1
-                    stream.streamed += 1
-                    self._handover.written += 1
-                elif item[0] == "done":
-                    _, reason, tokens, extra = item
-                    frame = {"done": True, "reason": reason,
-                             "tokens": tokens,
-                             "req_id": stream.req.req_id
-                             if stream.req else None,
-                             "trace_id": stream.params.get("trace_id")}
-                    if extra is not None:
-                        frame.update(extra)
-                    await conn.write(sse_event(frame)
-                                     + sse_event(DONE_SENTINEL))
-                    self._handover.written += 1
-                    return
-                else:                              # ("error", msg)
-                    await conn.write(sse_event(
-                        {"error": item[1], "done": True,
-                         "reason": "error"}) + sse_event(DONE_SENTINEL))
-                    self._handover.written += 1
+                if await self._a_write_turn(conn, stream, items):
                     return
         except SlowClientError:
             # the peer stopped draining: its transport is already
@@ -1269,40 +1260,89 @@ class ServeFrontend:
             # client went away mid-stream: free its KV now
             self._request_cancel(stream)
         finally:
+            deadline.cancel()
             conn.cancel_watch()
+
+    async def _a_write_turn(self, conn: AioConnection, stream: _Stream,
+                            items: List[tuple]) -> bool:
+        """A consumer's turn: the frames of `items`, in order, in one
+        write; True once the stream's last frame (done or error) is
+        written. A token frame is tagged with its CANDIDATE (`index`,
+        parallel sampling) and its position within that candidate's
+        stream (`pos`)."""
+        frames = []
+        tokens = 0
+        ended = False
+        for item in items:
+            if item[0] == "token":
+                _, tok, cand = item
+                pos = stream.cand_pos.get(cand, 0)
+                frames.append(_TOKEN_FRAME % (tok, cand, pos))
+                stream.cand_pos[cand] = pos + 1
+                tokens += 1
+                continue
+            if item[0] == "done":
+                _, reason, done_tokens, extra = item
+                frame = {"done": True, "reason": reason,
+                         "tokens": done_tokens,
+                         "req_id": stream.req.req_id
+                         if stream.req else None,
+                         "trace_id": stream.params.get("trace_id")}
+                if extra is not None:
+                    frame.update(extra)
+            else:                                  # ("error", msg)
+                frame = {"error": item[1], "done": True, "reason": "error"}
+            frames.append(sse_event(frame) + sse_event(DONE_SENTINEL))
+            ended = True
+            break
+        t0 = now_us()
+        await conn.write(b"".join(frames))
+        if tokens:
+            t1 = now_us()
+            self._m_token_write.observe((t1 - t0) / 1e6)
+            if not stream.streamed:
+                self.engine.tracer.on_first_write(stream.req.req_id, t1)
+            stream.streamed += tokens
+        self._handover.written += len(frames)
+        self._handover.writes += 1
+        return ended
 
     async def _a_aggregate_response(self, conn: AioConnection,
                                     stream: _Stream) -> None:
         tokens: List[int] = []
-        timeout = self._stream_timeout(stream.params)
-        loop = asyncio.get_running_loop()
-        while True:
-            item = await self._a_next_item(stream, loop.time() + timeout)
-            if item is None:
-                self._request_cancel(stream)
-                await conn.send(504, "application/json",
-                                b'{"error": "timed out"}\n')
-                return
-            if item[0] == "token":
-                if item[2] == 0:        # aggregate body reports best /
-                    tokens.append(item[1])   # candidate list, not a mix
-            elif item[0] == "done":
-                _, reason, full, extra = item
-                payload = {
-                    "tokens": full or tokens, "reason": reason,
-                    "req_id": stream.req.req_id if stream.req else None,
-                    "trace_id": stream.params.get("trace_id"),
-                }
-                if extra is not None:
-                    payload.update(extra)
-                body = json.dumps(payload).encode() + b"\n"
-                await conn.send(200, "application/json", body)
-                return
-            elif item[0] == "error":
-                await conn.send(400, "application/json",
-                                json.dumps({"error": item[1]}).encode()
-                                + b"\n")
-                return
+        deadline = self._arm_deadline(stream)
+        try:
+            while True:
+                items = await self._a_next_items(stream)
+                if items is None:
+                    self._request_cancel(stream)
+                    await conn.send(504, "application/json",
+                                    b'{"error": "timed out"}\n')
+                    return
+                for item in items:
+                    if item[0] == "token":
+                        if item[2] == 0:   # aggregate body reports best /
+                            tokens.append(item[1])  # candidates, not a mix
+                    elif item[0] == "done":
+                        _, reason, full, extra = item
+                        payload = {
+                            "tokens": full or tokens, "reason": reason,
+                            "req_id": stream.req.req_id
+                            if stream.req else None,
+                            "trace_id": stream.params.get("trace_id"),
+                        }
+                        if extra is not None:
+                            payload.update(extra)
+                        body = json.dumps(payload).encode() + b"\n"
+                        await conn.send(200, "application/json", body)
+                        return
+                    elif item[0] == "error":
+                        await conn.send(400, "application/json",
+                                        json.dumps({"error": item[1]})
+                                        .encode() + b"\n")
+                        return
+        finally:
+            deadline.cancel()
 
     def _request_cancel(self, stream: _Stream) -> None:
         self._cancel.append(stream)

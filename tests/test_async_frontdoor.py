@@ -10,6 +10,7 @@ dead peer), TLS/auth wrap the same byte-identical SSE stream, and the
 router sheds at the fleet's front door off the scraped
 `ptpu_slo_burning` gauges before a burning replica sees the request.
 """
+import asyncio
 import json
 import os
 import socket
@@ -24,6 +25,7 @@ from paddle_tpu.engine.engine import ServeEngine
 from paddle_tpu.models.transformer import CausalLM
 from paddle_tpu.obs.metrics import MetricsRegistry
 from paddle_tpu.obs.slo import SLOMonitor, SLOObjective
+from paddle_tpu.serve.aio import AioConnection, AioRequest
 from paddle_tpu.serve.frontend import ServeFrontend
 from paddle_tpu.serve.router import ReplicaState, Router
 from paddle_tpu.serve.sse import (collect_stream, http_get,
@@ -193,6 +195,51 @@ class TestSlowClient:
             sock.close()
         finally:
             fe.stop()
+
+    def test_paused_transport_past_its_deadline_is_evicted(
+            self, model_and_vars):
+        """A write that finds the transport paused (its buffer above the
+        high-water mark) waits for the drain and counts in
+        `ptpu_frontdoor_drain_waits_total`; a peer that never reads is
+        aborted at `write_deadline_s`, and `SlowClientError` evicts the
+        stream and cancels its request. 4,000 token frames handed over
+        at once are one write, far above the 1 KiB mark and the socket's
+        smallest buffers."""
+        eng = _engine(*model_and_vars)
+        fe = ServeFrontend(eng, warmup=False, write_deadline_s=0.3)
+        server, client = socket.socketpair()
+        server.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1)
+        client.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 1)
+
+        async def stalled():
+            loop = asyncio.get_running_loop()
+            reader, writer = await asyncio.open_connection(sock=server)
+            writer.transport.set_write_buffer_limits(high=1024)
+            conn = AioConnection(reader, writer, write_deadline_s=0.3)
+            body = json.dumps({"prompt": [1, 2, 3],
+                               "max_new_tokens": 8}).encode()
+            handler = loop.create_task(fe._a_dispatch(
+                AioRequest("POST", "/v1/completions", {}, body), conn))
+            while not fe._submit:
+                await asyncio.sleep(0.01)
+            stream = fe._submit[0]
+            fe._drain_control_queues()
+            for i in range(4000):
+                stream.push(("token", i % VOCAB, 0))
+            t0 = loop.time()
+            await asyncio.wait_for(handler, 30)
+            return stream, writer.transport, loop.time() - t0
+
+        stream, transport, waited = asyncio.run(stalled())
+        assert waited >= 0.3 and transport.is_closing()
+        assert _counter_value(
+            eng.obs, "ptpu_serve_slow_client_evictions_total") == 1.0
+        assert _counter_value(
+            eng.obs, "ptpu_frontdoor_drain_waits_total") == 1.0
+        assert list(fe._cancel) == [stream]
+        fe._drain_control_queues()
+        assert not fe._active and not eng.scheduler.has_work()
+        client.close()
 
 
 # -- fleet admission -------------------------------------------------------

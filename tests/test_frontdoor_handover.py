@@ -9,13 +9,18 @@ its tokens in order and its done frame after its last token, and no
 frame pushed outside a step (a refused submission's error, the aborts
 of a shutdown and of the drain deadline) leaves a consumer parked.
 
-The consumers here are the front door's own `_a_next_item` loop, on an
-event loop of the test's; where a test counts a step at a time, the
-test's thread plays the engine loop.
+The consumers here are the front door's own `_a_next_items` loop, or
+its whole handler over a socket pair, on an event loop of the test's;
+where a test counts a step at a time, the test's thread plays the
+engine loop. A woken handler writes every frame it was handed in one
+write, arms one timer a response (its deadline) and waits for the
+drain only on a paused transport.
 """
 
 import asyncio
 import importlib
+import json
+import socket
 import threading
 import time
 
@@ -26,8 +31,10 @@ import pytest
 from paddle_tpu.engine.engine import ServeEngine
 from paddle_tpu.models.transformer import CausalLM
 from paddle_tpu.obs.metrics import MetricsRegistry
-from paddle_tpu.serve.frontend import ServeFrontend, _HandOver, _Stream
-from paddle_tpu.serve.sse import stream_completion
+from paddle_tpu.serve.aio import AioConnection, AioRequest
+from paddle_tpu.serve.frontend import ServeFrontend, _HandOver, _Stream, \
+    _TOKEN_FRAME
+from paddle_tpu.serve.sse import DONE_SENTINEL, sse_event, stream_completion
 
 prof = importlib.import_module("paddle_tpu.profiler.profiler")
 
@@ -78,14 +85,18 @@ class _ParkingEvent(asyncio.Event):
 
 async def _drain(stream, timeout=60.0):
     """A handler's loop without the socket: every item up to the one
-    that ends the stream."""
+    that ends the stream (None at its deadline)."""
     loop = asyncio.get_running_loop()
+    deadline = loop.call_at(loop.time() + timeout, stream.expire)
     items = []
-    while True:
-        item = await ServeFrontend._a_next_item(stream, loop.time() + timeout)
-        items.append(item)
-        if item is None or item[0] in ("done", "error", "gone"):
-            return items
+    try:
+        while True:
+            items.extend(await ServeFrontend._a_next_items(stream) or [None])
+            if items[-1] is None or items[-1][0] in ("done", "error",
+                                                     "gone"):
+                return items
+    finally:
+        deadline.cancel()
 
 
 class Consumers:
@@ -361,3 +372,198 @@ def test_parallel_candidates_keep_their_order_beside_other_streams(
     finally:
         fe.stop()
 
+
+
+class _Wire(AioConnection):
+    """The front door's connection over a socket pair, its writes
+    logged; the test's thread reads the client's half."""
+
+    def __init__(self, reader, writer):
+        super().__init__(reader, writer)
+        self.writes = []
+
+    async def write(self, data):
+        self.writes.append(data)
+        await super().write(data)
+
+
+def _respond(fe, consumers, **body):
+    """A POST of `body` to the front door's own handler, on the
+    consumers' loop over a socket pair; the test's thread, as the engine
+    loop, admits the stream it submitted. Returns the wire, the client's
+    socket, the handler's future and the stream, once the handler has
+    written the response's head."""
+    server, client = socket.socketpair()
+    client.settimeout(30)
+
+    async def _open():
+        return _Wire(*await asyncio.open_connection(sock=server))
+    wire = asyncio.run_coroutine_threadsafe(_open(), consumers.loop).result(10)
+    request = AioRequest("POST", "/v1/completions", {},
+                         json.dumps(dict({"prompt": [1, 2, 3],
+                                          "max_new_tokens": 8},
+                                         **body)).encode())
+    fut = asyncio.run_coroutine_threadsafe(fe._a_dispatch(request, wire),
+                                           consumers.loop)
+    assert _wait_until(lambda: fe._submit)
+    stream = fe._submit[0]
+    fe._drain_control_queues()
+    assert stream.req is not None
+    if body.get("stream", True):
+        assert _wait_until(lambda: wire.writes)
+    return wire, client, fut, stream
+
+
+def _hand_over(fe):
+    """The engine loop's hand-over, then the wait for its frames."""
+    woken = fe._hand_over()
+    assert fe._handover.delivered.wait(10)
+    return woken
+
+
+def _received(wire, client, consumers):
+    """Everything the client got once the handler closed the wire."""
+    asyncio.run_coroutine_threadsafe(wire.close(), consumers.loop).result(10)
+    data = b""
+    while chunk := client.recv(65536):
+        data += chunk
+    client.close()
+    return data
+
+
+def _frames(turn, pos=None):
+    """`sse_event`'s bytes for a turn of (token, candidate) pairs."""
+    pos = {} if pos is None else pos
+    out = []
+    for tok, cand in turn:
+        out.append(sse_event({"token": tok, "index": cand,
+                              "pos": pos.get(cand, 0)}))
+        pos[cand] = pos.get(cand, 0) + 1
+    return b"".join(out)
+
+
+# one hand-over's tokens: a list of (token, candidate) a stream
+TURNS = {
+    "k1": [[(5, 0)]],
+    "k3": [[(5, 0), (9, 0), (2, 0)]],
+    "k8": [[(t, 0) for t in (5, 9, 2, 7, 1, 3, 60, 0)]],
+    "n2_beside_another": [[(5, 0), (9, 1), (2, 0), (7, 1), (4, 1)],
+                          [(3, 0), (4, 0)]],
+}
+DONE = ("done", "length", [], None)
+
+
+@pytest.mark.parametrize("case", sorted(TURNS))
+def test_a_hand_over_reaches_each_socket_in_one_write(model_and_vars,
+                                                      consumers, case):
+    """The frames a hand-over gives a stream leave in one write, in
+    order; with n = 2 its candidates' frames keep their order (`pos`
+    counts a candidate's own) beside another stream's. The hand-over's
+    `frontdoor.deliver` counts the frames and the writes that carried
+    them; the done frame leaves in a write of its own hand-over."""
+    fe = ServeFrontend(_engine(*model_and_vars), warmup=False)
+    fe._handover.tid = threading.get_ident()
+    turns = TURNS[case]
+    opened = [_respond(fe, consumers,
+                       **({"n": 2} if any(c for _, c in turn) else {}))
+              for turn in turns]
+    prof.reset_profiler()
+    for (_, _, _, stream), turn in zip(opened, turns):
+        for tok, cand in turn:
+            stream.push(("token", tok, cand))
+    assert _hand_over(fe) == len(turns)
+    for (wire, _, _, stream), turn in zip(opened, turns):
+        assert wire.writes[1:] == [_frames(turn)]
+        assert stream.streamed == len(turn)
+    delivers = [e["args"] for e in prof.get_events()
+                if e["name"] == "frontdoor.deliver"]
+    assert len(delivers) == 1
+    assert (delivers[0]["streams"], delivers[0]["frames"],
+            delivers[0]["writes"]) == (len(turns), sum(map(len, turns)),
+                                       len(turns))
+
+    for _, _, _, stream in opened:
+        stream.push(DONE)
+    assert _hand_over(fe) == len(turns)
+    for (wire, client, fut, stream), turn in zip(opened, turns):
+        fut.result(timeout=10)
+        done = sse_event({"done": True, "reason": "length", "tokens": [],
+                          "req_id": stream.req.req_id,
+                          "trace_id": stream.params["trace_id"]})
+        assert wire.writes[2:] == [done + sse_event(DONE_SENTINEL)]
+        assert _received(wire, client, consumers) == \
+            b"".join(wire.writes)
+    assert fe._handover.written == sum(map(len, turns)) + len(turns)
+    assert fe._handover.writes == 2 * len(turns)
+
+
+def test_a_stream_arms_one_timer_and_waits_for_no_drain(
+        model_and_vars, consumers, monkeypatch):
+    """A stream of 64 tokens, a hand-over each, arms one timer on its
+    event loop, its deadline, and cancels it at its end: no write waits
+    under `asyncio.wait_for` while the transport is not paused."""
+    fe = ServeFrontend(_engine(*model_and_vars), warmup=False)
+    fe._handover.tid = threading.get_ident()
+    loop = consumers.loop
+    timers, waits = [], []
+    call_at, wait_for = loop.call_at, asyncio.wait_for
+
+    def counted_call_at(*args, **kw):
+        timers.append(call_at(*args, **kw))
+        return timers[-1]
+
+    def counted_wait_for(aw, timeout):
+        waits.append(timeout)
+        return wait_for(aw, timeout)
+    monkeypatch.setattr(loop, "call_at", counted_call_at)
+    monkeypatch.setattr(asyncio, "wait_for", counted_wait_for)
+    wire, client, fut, stream = _respond(fe, consumers, max_new_tokens=64)
+    tokens = [(t % VOCAB, 0) for t in range(64)]
+    for tok, cand in tokens:
+        stream.push(("token", tok, cand))
+        assert _hand_over(fe) == 1
+    stream.push(DONE)
+    _hand_over(fe)
+    fut.result(timeout=10)
+    assert len(timers) == 1 and timers[0].cancelled()
+    assert waits == []
+    assert len(wire.writes) == 1 + 64 + 1
+    assert b"".join(wire.writes[1:-1]) == _frames(tokens)
+    assert _counter(fe.engine, "ptpu_frontdoor_drain_waits_total").value == 0
+    monkeypatch.undo()
+    assert _received(wire, client, consumers) == b"".join(wire.writes)
+
+
+@pytest.mark.parametrize("streaming", [True, False],
+                         ids=["stream", "aggregate"])
+def test_an_engine_that_never_answers_times_out_and_cancels(
+        model_and_vars, consumers, monkeypatch, streaming):
+    """A response whose engine never answers ends at its deadline, and
+    its request is cancelled: a stream after its head, an aggregate
+    response with a 504."""
+    fe = ServeFrontend(_engine(*model_and_vars), warmup=False)
+    fe._handover.tid = threading.get_ident()
+    monkeypatch.setattr(fe, "_stream_timeout", lambda params: 0.3)
+    t0 = time.monotonic()
+    wire, client, fut, stream = _respond(fe, consumers, stream=streaming)
+    fut.result(timeout=10)
+    assert time.monotonic() - t0 >= 0.3
+    assert stream.expired and list(fe._cancel) == [stream]
+    fe._drain_control_queues()
+    assert not fe._active and not fe.engine.scheduler.has_work()
+    got = _received(wire, client, consumers)
+    if streaming:
+        assert got == wire.writes[0] and b"text/event-stream" in got
+    else:
+        assert got.startswith(b"HTTP/1.0 504 ")
+        assert got.endswith(b'{"error": "timed out"}\n')
+
+
+@pytest.mark.parametrize("tok,cand,pos", [
+    (0, 0, 0), (7, 0, 0), (60, 1, 0), (50256, 0, 17), (151935, 3, 0),
+    (2 ** 31 - 1, 7, 4095)])
+def test_token_frame_template_is_sse_events_bytes(tok, cand, pos):
+    """The wire format: a token frame from the template is byte for byte
+    what `sse_event` makes of the frame's dict."""
+    assert _TOKEN_FRAME % (tok, cand, pos) == \
+        sse_event({"token": tok, "index": cand, "pos": pos})

@@ -533,8 +533,8 @@ def test_one_deliver_a_hand_over_on_the_event_loops_thread(served):
     loop_tid = _spans(events, "engine.step")[-1]["tid"]
     assert {e["tid"] for e in delivers} != {loop_tid}
     assert len({e["tid"] for e in delivers}) == 1
-    assert all(set(e["args"]) == {"step", "streams", "frames", "wake_us"}
-               for e in delivers)
+    assert all(set(e["args"]) == {"step", "streams", "frames", "writes",
+                                  "wake_us"} for e in delivers)
     assert all(e["args"]["streams"] == 1 for e in delivers)
     grain = _thread_clock_grain_us()
     # its step is a step of the ring, the one that had just sampled
@@ -556,9 +556,10 @@ def test_delivers_count_the_frames_the_client_received(served):
     delivers = _spans(events, "frontdoor.deliver")
     assert sum(e["args"]["frames"] for e in delivers) == \
         len(out["tokens"]) + 1
-    # the done frame left with the last token's hand-over
+    # the done frame left with the last token's hand-over, in its write
     assert [e["args"]["frames"] for e in delivers] == \
         [1] * (len(delivers) - 1) + [2]
+    assert all(e["args"]["writes"] == 1 for e in delivers)
 
 
 def test_a_wake_up_takes_no_negative_time(served):
